@@ -372,9 +372,9 @@ class ElasticAgent:
                 register_context,
             )
 
-            cache_dir = envs.get_str("DLROVER_TPU_COMPILE_CACHE")
-            if cache_dir.lower() == "off":
-                cache_dir = ""
+            from dlrover_tpu.trainer.bootstrap import compile_cache_dir
+
+            cache_dir = compile_cache_dir()
             self._peer_serve = PeerServeEndpoint(
                 self._client.node_id, cache_dir=cache_dir,
             ).start()

@@ -20,8 +20,8 @@ Vocabulary:
   ``--show-suppressed`` lists them; they never affect the exit code.
 
 Config comes from ``[tool.graftlint]`` in ``pyproject.toml`` (found by
-walking up from the first scanned path), parsed with ``tomli`` when
-available; without it the built-in defaults apply.
+walking up from the first scanned path), parsed with the stdlib
+``tomllib``; without a file the built-in defaults apply.
 """
 
 import ast
@@ -195,6 +195,7 @@ class Config:
             "trace_smoke.py",
             "incident_smoke.py",
             "goodput_smoke.py",
+            "data_smoke.py",
             "comm_smoke.py",
             "mem_smoke.py",
             "hierarchy_smoke.py",
@@ -226,19 +227,17 @@ class Config:
     @staticmethod
     def load(start_path: str) -> "Config":
         """Find pyproject.toml upward from ``start_path``; read
-        ``[tool.graftlint]``.  Missing file/section/tomli => defaults."""
+        ``[tool.graftlint]``.  Missing file/section => defaults."""
         cfg = Config()
         pyproject = _find_pyproject(start_path)
         if not pyproject:
             return cfg
         cfg.root = os.path.dirname(pyproject)
-        try:
-            import tomli
-        except ImportError:  # pragma: no cover - tomli baked into the image
-            return cfg
+        import tomllib
+
         try:
             with open(pyproject, "rb") as f:
-                data = tomli.load(f)
+                data = tomllib.load(f)
         except (OSError, ValueError):
             return cfg
         section = data.get("tool", {}).get("graftlint", {})

@@ -289,8 +289,10 @@ def _fabric_reroute(seed: int) -> ChaosPlan:
     # The r21 re-route drill: a healthy window (4 clean probe rounds /
     # tolled exchanges) lets the fabric tuner commit a dual-fabric
     # striped plan, then the slice boundary degrades — every later
-    # comm.axis_delay.slice crossing pays a 4 ms injected latency, far
-    # past the slow-link breach threshold.  The expected cure is the
+    # comm.axis_delay.slice crossing pays a 20 ms injected latency, far
+    # past the slow-link breach threshold (and far enough past the
+    # healthy axis that a 0.5 ms sleep stretched to 2 ms by a busy host
+    # still reads as healthy next to it).  The expected cure is the
     # CHEAP one: the tuner re-routes the stripe off the degraded DCN
     # (a plan swap at the next train_step) BEFORE the quantization
     # demotion backstop fires.
@@ -301,7 +303,7 @@ def _fabric_reroute(seed: int) -> ChaosPlan:
             FaultSpec(
                 point="comm.axis_delay.slice",
                 kind=DELAY,
-                delay_s=0.004,
+                delay_s=0.02,
                 after=4,
             ),
         ],

@@ -379,7 +379,9 @@ register("DLROVER_TPU_PY_TRACE", "str", "",
 register("DLROVER_TPU_FA_TUNING", "str", "",
          "flash-attention tuning table path override")
 register("DLROVER_TPU_COMPILE_CACHE", "str", "",
-         "persistent XLA compile-cache dir; empty = off")
+         "persistent XLA compile-cache dir when JAX_COMPILATION_CACHE_DIR "
+         "is unset (empty = .cache/xla in the checkout; CPU backends "
+         "cache only when a dir is named); 'off' disables")
 register("DLROVER_TPU_FASTCOPY_LIB", "str", "",
          "explicit libfastcopy.so path; empty = search defaults")
 register(ConfigPath.ENV_PARAL_CONFIG, "str", ConfigPath.PARAL_CONFIG,
@@ -881,16 +883,8 @@ register("DLROVER_TPU_STAGING_DRILL_CHUNK_MB", "int", 4,
          "staging drill: pinned chunk size in MB")
 register("DLROVER_TPU_BENCH_PRESET", "str", "default",
          "bench.py preset (tiny for smoke runs)")
-register("DLROVER_TPU_BENCH_PROBE_TRIES", "int", 4,
-         "bench.py: TPU probe attempts before giving up")
-register("DLROVER_TPU_BENCH_PROBE_WAIT_S", "float", 60.0,
-         "bench.py: wait between TPU probe attempts (s)")
-register("DLROVER_TPU_BENCH_PROBE_LOG", "str", "",
-         "bench.py: where probe-failure causes are appended")
 register("DLROVER_TPU_BENCH_SKIP_GOODPUT", "bool", False,
          "bench.py: skip the goodput drill leg")
-register("DLROVER_TPU_FROM_WATCHER", "bool", False,
-         "set by scripts/tpu_watch.py on bench runs it supervises")
 register("DLROVER_TPU_RESHARD_FIT_GATE", "bool", True,
          "live reshard (r22): refuse transition plans the r17 measured "
          "fit report says do not fit the surviving per-chip HBM; "

@@ -1132,7 +1132,7 @@ def _scenario_fabric_reroute(ctx: Dict) -> Dict:
     cold-starts its comm plan from the persisted fabric seed (a
     DCN-idle shape, so the tuner commits a dual-fabric STRIPED plan),
     then the slice boundary degrades — ``comm.axis_delay.slice`` lands
-    a 4 ms injected latency inside the probe's timed window after a
+    a 20 ms injected latency inside the probe's timed window after a
     4-fire healthy baseline.  The probes price the degradation into
     the FabricModel, the slow-link sentinel breaches on exactly the
     slice series, and the demotion hook's FAST cure fires first: the

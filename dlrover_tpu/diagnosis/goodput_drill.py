@@ -35,6 +35,7 @@ import urllib.request
 import uuid
 from typing import Dict, Optional, Tuple
 from dlrover_tpu.common import envs
+from dlrover_tpu.trainer.bootstrap import compile_cache_dir
 
 REPO = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -280,10 +281,11 @@ def _run_goodput_drill_once(
             # persistent XLA compile cache: the startup compile populates
             # it, so each post-crash restart reloads the step function
             # from disk instead of recompiling — the recovery-cost lever
-            # restart-based elasticity depends on (bootstrap.py).  Safe
-            # here despite the CPU backend: the cache dir is private to
-            # this drill run on this machine.
-            "DLROVER_TPU_COMPILE_CACHE": os.path.join(workdir, "xla_cache"),
+            # restart-based elasticity depends on (bootstrap.py).  Naming
+            # the job-wide cache dir explicitly is what opts this CPU
+            # drill in; the dir is git-ignored, so its host-specific CPU
+            # entries never travel with the checkout.
+            "DLROVER_TPU_COMPILE_CACHE": compile_cache_dir(),
         }
     )
     master = agent = None

@@ -628,6 +628,11 @@ class WatchedFunction:
         self._static = dict(static or {})
         self.last_event: Optional[Dict[str, Any]] = None
 
+    def lower(self, *args: Any, **kwargs: Any) -> Any:
+        """The wrapped jit's ``lower``: a watched call site stays
+        inspectable ahead of time (which kernels, which collectives)."""
+        return self._fn.lower(*args, **kwargs)
+
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         if not enabled():
             return self._fn(*args, **kwargs)

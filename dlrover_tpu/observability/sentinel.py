@@ -933,7 +933,7 @@ def _comparable(entry: Dict[str, Any], current: Dict[str, Any]) -> bool:
     """Only rounds measured under the same conditions feed the
     baseline: a CPU-fallback round must not judge (or be judged by) a
     real-hardware trajectory, and a degraded round whose HEADLINE was
-    adopted from the TPU watcher's capture (hardware headline, CPU
+    adopted from a chip run's capture (hardware headline, CPU
     drill numbers) is comparable only to other such mixed rounds."""
     return (
         bool(entry.get("tpu_unavailable"))

@@ -2,9 +2,12 @@
 
 The reference math lives in exactly one place so numerics policy (fp32
 logits, mask fill value, fp32 softmax) can never diverge between model
-families.  ``flash_attention`` lowers to the Pallas TPU kernel when running
-on TPU (ops/pallas/flash_attention.py) and falls back to the reference core
-elsewhere (CPU tests, debugging).
+families.  ``flash_attention`` is the Pallas TPU kernel
+(ops/pallas/flash_attention.py) and nothing else: it never turns into the
+reference.  Off the chip it raises unless the caller asks for the Pallas
+interpreter by argument; under a mesh of several devices it runs the kernel
+per shard through ``shard_map`` (a Mosaic kernel cannot be partitioned
+automatically).
 """
 
 from typing import Optional
@@ -38,6 +41,34 @@ def reference_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _flash_shard_specs(mesh):
+    """(q_spec, kv_spec) for the per-shard kernel call on ``mesh``, from
+    the logical rules table (``ring_attention_sharded`` does the same):
+    batch over the data axes, heads over ``tp``.  The sequence stays whole
+    on every shard — the kernel's causal mask is positional."""
+    import flax.linen as nn
+    from jax.sharding import PartitionSpec
+
+    from dlrover_tpu.parallel.sharding import spec_for_logical_axes
+
+    rules = list(nn.get_logical_axis_rules()) or None
+
+    def spec(heads_axis):
+        full = spec_for_logical_axes(
+            ("batch", None, heads_axis, None), rules
+        )
+        out = []
+        for axis in full:
+            names = axis if isinstance(axis, tuple) else (axis,)
+            # only axes this mesh really splits (and really has)
+            out.append(
+                tuple(a for a in names if mesh.shape.get(a, 1) > 1) or None
+            )
+        return PartitionSpec(*out)
+
+    return spec("heads"), spec("kv_heads")
+
+
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -45,30 +76,49 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 0,
     block_kv: int = 0,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused attention: Pallas TPU kernel on TPU, reference core elsewhere.
+    """Fused attention: the Pallas TPU kernel, never the reference.
 
     Block sizes default to the autotuned table (``ops/pallas/tuning.py``)
     for this (seq_len, head_dim); pass explicit values to override.
+    ``interpret=True`` runs the kernel in the Pallas interpreter (tests off
+    the chip); without it a backend that is not a TPU is an error.  Under
+    an active mesh of more than one device, outside any ``shard_map``, the
+    call is wrapped in one.
     """
-    if jax.default_backend() == "tpu":
-        try:
-            from dlrover_tpu.ops.pallas.flash_attention import (
-                pallas_flash_attention,
-            )
-            from dlrover_tpu.ops.pallas.tuning import tuned_blocks
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "flash_attention needs a TPU backend (found "
+            f"{jax.default_backend()!r}); use attention_impl='reference' "
+            "off the chip, or pass interpret=True"
+        )
+    from dlrover_tpu.ops.pallas.flash_attention import pallas_flash_attention
+    from dlrover_tpu.ops.pallas.tuning import tuned_blocks
+    from dlrover_tpu.ops.ring_attention import active_mesh
 
-            if not block_q or not block_kv:
-                tuned_q, tuned_kv = tuned_blocks(q.shape[1], q.shape[-1])
-                block_q = block_q or tuned_q
-                block_kv = block_kv or tuned_kv
-            return pallas_flash_attention(
-                q, k, v, causal=causal, block_q=block_q, block_kv=block_kv
-            )
-        except ImportError:
-            pass
-    mask = None
-    if causal:
-        S = q.shape[1]
-        mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
-    return reference_attention(q, k, v, mask)
+    if not block_q or not block_kv:
+        tuned_q, tuned_kv = tuned_blocks(q.shape[1], q.shape[-1])
+        block_q = block_q or tuned_q
+        block_kv = block_kv or tuned_kv
+
+    def kernel(q_, k_, v_):
+        return pallas_flash_attention(
+            q_, k_, v_, causal, block_q, block_kv, interpret
+        )
+
+    mesh = active_mesh()
+    if (
+        mesh is None
+        or mesh.size == 1
+        # already per-shard: the enclosing shard_map owns the mesh axes
+        or jax.sharding.get_abstract_mesh().manual_axes
+    ):
+        return kernel(q, k, v)
+    from dlrover_tpu.parallel.collectives import shard_map_unchecked
+
+    q_spec, kv_spec = _flash_shard_specs(mesh)
+    return shard_map_unchecked(
+        kernel, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec,
+    )(q, k, v)

@@ -23,12 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.4.36; accept
-# either so the kernels track the installed jax
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 NEG_INF = -1e30
 
 # TPU vector lanes: per-row scalars (LSE, delta) are stored broadcast
@@ -176,7 +170,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
             pltpu.VMEM((block_q, MIN_LANES), jnp.float32),
             pltpu.VMEM((block_q, MIN_LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -331,7 +325,7 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -368,7 +362,7 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
             pltpu.VMEM((block_kv, D), jnp.float32),
             pltpu.VMEM((block_kv, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

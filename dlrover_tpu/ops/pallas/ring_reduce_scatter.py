@@ -60,10 +60,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports fail on builds without the TPU plugin pieces
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - CPU-only jaxlib
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 RING_TRANSPORTS = ("ring", "ring_pallas", "ring_rdma", "ring_pallas_q")
 
@@ -328,17 +325,10 @@ def rdma_ring_reduce_scatter(x, axis: str, world: int):
     TPU pipeline is exercised by the bench's degraded-mode evidence;
     on-device execution awaits a multi-chip round.
     """
-    if pltpu is None:  # pragma: no cover - CPU-only jaxlib
-        raise NotImplementedError("pallas TPU backend unavailable")
     width = x.shape[1]
     rows = width // 128
     kernel = functools.partial(_rdma_ring_kernel, axis=axis, world=world)
-    compiler_params = None
-    params_cls = getattr(
-        pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-    )
-    if params_cls is not None:
-        compiler_params = params_cls(collective_id=13)
+    compiler_params = pltpu.CompilerParams(collective_id=13)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((rows, 128), x.dtype),
@@ -400,7 +390,6 @@ def select_transport(transport: str, quantized: bool, world: int,
     if transport == "ring_rdma":
         if (
             rdma_enabled
-            and pltpu is not None
             and jax.default_backend() == "tpu"
             and width % 128 == 0
         ):

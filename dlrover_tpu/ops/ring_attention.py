@@ -115,10 +115,7 @@ def ring_attention_sharded(
     """Mesh-level ring attention.  PartitionSpecs come from the logical
     rules table (q: batch/seq/heads/head_dim, kv: batch/seq/kv_heads/
     head_dim) so a strategy change in the table never touches this code."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.4.35 keeps it under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from dlrover_tpu.parallel.sharding import spec_for_logical_axes
 
     q_spec = spec_for_logical_axes(

@@ -43,27 +43,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across the jax
-    rename of the flag (``check_rep`` -> ``check_vma``).  Needed because
-    values produced from psum'd inputs through an optax update ARE
-    replicated, but the checker cannot prove it."""
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    except TypeError:  # pragma: no cover - newer jax renamed the flag
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
+    """``shard_map`` with replication checking off (``check_vma``).
+    Needed because values produced from psum'd inputs through an optax
+    update ARE replicated, but the checker cannot prove it."""
+    return _shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 GRAD_SYNC_MODES = (

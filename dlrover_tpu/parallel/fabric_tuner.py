@@ -25,7 +25,7 @@ hardware the measured snapshot feeds the same formulas real numbers.
 Cold start: before the first live probe fires, the last
 ``BENCH_comm.json``'s ``fabric`` section (:func:`seed_snapshot`) seeds
 the plan; with no bench file either, the static ladder stands.  The
-``ring_rdma`` tier is only eligible once the TPU-watcher bench proved it
+``ring_rdma`` tier is only eligible once a bench run on the chip proved it
 end-to-end (:func:`rdma_proven` on ``BENCH_grad_overlap.json``)."""
 
 from __future__ import annotations
@@ -130,7 +130,7 @@ def seed_snapshot(path: Optional[str] = None) -> Optional[Dict]:
 
 
 def rdma_proven(path: str = "BENCH_grad_overlap.json") -> bool:
-    """True only when the TPU-watcher bench drove the ``ring_rdma``
+    """True only when a bench run on the chip drove the ``ring_rdma``
     Pallas kernel end-to-end on real hardware and recorded ``status ==
     "ok"`` — the tuner must never route production gradients through a
     tier whose lowering was never executed."""

@@ -21,7 +21,7 @@ fixing the r6 single-tensor estimate that under-counted blockwise
 formats.  CPU step times bound the NUMERICS overhead (the XLA program
 is the same shape the TPU runs); wire bytes are topology math, valid
 for any backend.  Consumed by ``bench.py`` (``detail.grad_sync``) and
-written standalone to ``BENCH_grad_overlap.json`` so the TPU watcher's
+written standalone to ``BENCH_grad_overlap.json`` so a chip run's
 bench stage captures real-hardware numbers automatically when the
 probe succeeds.
 
@@ -149,7 +149,7 @@ def _comm_observatory(trainer, exposed_ms: float, steps: int) -> Dict:
       transport/axis);
     * probe-measured per-axis fabric latency/bandwidth
       (``commscope.MeshProbe`` on the real mesh — hardware numbers
-      when the TPU watcher runs this bench on-device).
+      when this bench runs on the chip).
     """
     from dlrover_tpu.observability import commscope
 
@@ -192,9 +192,8 @@ def _hierarchy_bench(model, batch_host, devices, steps: int) -> Dict:
     simulated DCN boundary (``DLROVER_TPU_SLICE_SIM``) prices the
     cross-slice exchanges so wall times genuinely separate.  The
     returned dict is the flat-vs-hierarchical comparison the round
-    file carries (hardware numbers land automatically when the TPU
-    watcher runs this bench on a real multi-slice topology with the
-    sim off)."""
+    file carries (hardware numbers need a run on a real multi-slice
+    topology with the sim off)."""
     import jax
     import optax
 
@@ -491,28 +490,9 @@ def _ring_rdma_evidence(devices) -> Dict:
     return out
 
 
-def append_probe_log(rec: Dict, path: str = None):
-    """Append one JSONL record to ``TPU_PROBE_bench.jsonl`` at the repo
-    root — the bench-stage twin of the TPU watcher's probe log, so
-    real-hardware runs auto-capture per-attempt ring_rdma / tuner
-    outcomes even when the round file is later overwritten."""
-    if path is None:
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))),
-            "TPU_PROBE_bench.jsonl",
-        )
-    try:
-        with open(path, "a") as f:
-            f.write(json.dumps(rec) + "\n")
-    except OSError as e:
-        print(f"grad_sync_bench: probe log append failed: {e}",
-              file=sys.stderr, flush=True)
-
-
 def write_comm_file(comm: Dict, path: str = None):
     """Persist the standalone comm round file (BENCH_comm.json) at the
-    repo root so the TPU watcher / driver capture probe-measured axis
+    repo root so a chip run capture probe-measured axis
     bandwidths + per-bucket exposed ms even when the parent bench
     dies."""
     _write_repo_file(comm, "BENCH_comm.json", path)
@@ -524,7 +504,7 @@ ALL_LEGS = ("modes", "comm", "hierarchy", "tuner", "rdma")
 def _selected_legs() -> set:
     """``DLROVER_TPU_BENCH_LEGS``: 'all' or a comma subset of
     :data:`ALL_LEGS`.  A partial run refreshes only the named legs and
-    keeps the prior round file's other sections — the TPU watcher can
+    keeps the prior round file's other sections — a chip run can
     re-prove one leg's evidence (say ``rdma`` after a driver fix)
     without paying the full matrix, and one wedged leg (host-callback
     + collective starvation on small CPU hosts) stops blocking fresh
@@ -724,15 +704,6 @@ def run_grad_sync_bench(n_devices: int = 4, steps: int = 8) -> Dict:
         except Exception as e:  # noqa: BLE001 - the leg must not kill
             # the bench's contractual JSON line
             tuner_leg = {"error": f"{type(e).__name__}: {e}"}
-        append_probe_log({
-            "ts": time.time(),
-            "event": "fabric_tuner",
-            "asym_beats_static": tuner_leg.get(
-                "asymmetric_fabric", {}).get("tuner_beats_all_static"),
-            "dcn_idle_stripe": tuner_leg.get(
-                "dcn_idle", {}).get("stripe_used"),
-            "error": tuner_leg.get("error"),
-        })
     rdma = prior.get("ring_rdma", {})
     if "rdma" in legs:
         try:
@@ -740,11 +711,6 @@ def run_grad_sync_bench(n_devices: int = 4, steps: int = 8) -> Dict:
         except Exception as e:  # noqa: BLE001
             rdma = {"status": "failed",
                     "cause": f"{type(e).__name__}: {e}"[:300]}
-        append_probe_log({
-            "ts": time.time(),
-            "event": "ring_rdma",
-            **rdma,
-        })
 
     policy = GradSyncPolicy(mode="int8_sharded")
     if abstract_params is None:
@@ -794,7 +760,7 @@ def _write_repo_file(payload: Dict, filename: str, path: str = None):
 
 def write_round_file(result: Dict, path: str = None):
     """Persist the standalone round file (BENCH_grad_overlap.json) next
-    to the repo root so the TPU watcher / driver pick it up even when
+    to the repo root so a chip run pick it up even when
     the parent bench dies before printing."""
     _write_repo_file(result, "BENCH_grad_overlap.json", path)
 

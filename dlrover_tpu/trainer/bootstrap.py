@@ -161,15 +161,48 @@ def _note_cache_disabled(reason: str, cache_dir: str = "") -> None:
         pass
 
 
+#: the env var JAX itself reads into ``jax_compilation_cache_dir``
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+#: the fixed in-checkout cache directory (``.cache/xla`` beside
+#: ``pyproject.toml``).  The path is part of the cache's key, so it is
+#: never made from a temporary name, a pid or the time.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".cache", "xla",
+)
+
+
+def compile_cache_dir() -> str:
+    """The persistent compile cache every process of this job shares:
+    ``JAX_COMPILATION_CACHE_DIR`` where the machine sets it (then JAX
+    configures itself and this code sets no other directory), else the
+    explicit ``DLROVER_TPU_COMPILE_CACHE``, else the fixed in-checkout
+    default.  Empty string = switched off (``DLROVER_TPU_COMPILE_CACHE=
+    off``).  JAX-free: the agent and the drills call it too."""
+    explicit = envs.get_str("DLROVER_TPU_COMPILE_CACHE")
+    if explicit.lower() == "off":
+        return ""
+    return (
+        os.environ.get(JAX_CACHE_ENV, "")
+        or explicit
+        or _DEFAULT_CACHE_DIR
+    )
+
+
 def _setup_compile_cache(jax):
     """Persistent XLA compile cache: restart-based elasticity re-traces
     the train step on every membership change, and a warm cache turns
     that recompile into a disk read (SURVEY §7 hard-part (a)); the dir
     survives worker restarts because the host owns it.
 
-    Default on for accelerator backends only — XLA:CPU AOT entries bake
-    in host CPU features and reloading them can SIGILL on a different
-    machine, so CPU requires the explicit env opt-in.  Gated on the
+    Which directory: :func:`compile_cache_dir`.  Default on for
+    accelerator backends only — XLA:CPU AOT entries bake in host CPU
+    features and reloading them can SIGILL on a different machine, so
+    CPU requires an explicit env opt-in (either variable).  Gated on the
     RESOLVED backend (not the requested platform string): runs after the
     platform config is final, before any compile.
 
@@ -180,13 +213,15 @@ def _setup_compile_cache(jax):
     not a log line).
     """
     _cache_status["restart"] = bool(worker_context().restart_count > 0)
-    cache_dir = envs.get_str("DLROVER_TPU_COMPILE_CACHE")
-    if cache_dir.lower() == "off":
+    cache_dir = compile_cache_dir()
+    if not cache_dir:
+        jax.config.update("jax_enable_compilation_cache", False)
         _cache_status.update(
             enabled=False, dir="", reason="env-off",
         )
         return
-    if not cache_dir:
+    from_jax_env = bool(os.environ.get(JAX_CACHE_ENV))
+    if not from_jax_env and not envs.get_str("DLROVER_TPU_COMPILE_CACHE"):
         try:
             if jax.default_backend() == "cpu":
                 _cache_status.update(
@@ -196,12 +231,12 @@ def _setup_compile_cache(jax):
         except Exception:  # noqa: BLE001 - no backend: no cache
             _note_cache_disabled("no-backend")
             return
-        cache_dir = "/tmp/dlrover_tpu/xla_cache"
     try:
         os.makedirs(cache_dir, exist_ok=True)
         _prewarm_cache_from_peers(cache_dir)
         entries = _count_cache_entries(cache_dir)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if not from_jax_env:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs",
             envs.get_float("DLROVER_TPU_COMPILE_CACHE_MIN_S"),

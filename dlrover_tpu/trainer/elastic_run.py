@@ -18,6 +18,7 @@ Examples::
 
 import argparse
 import atexit
+import contextlib
 import os
 import subprocess
 import sys
@@ -117,6 +118,8 @@ def _launch_local_master(node_num: int) -> Tuple[subprocess.Popen, str]:
                 addr = f"localhost:{port}"
                 if port_reachable("localhost", port, timeout=1.0):
                     logger.info("local master ready at %s", addr)
+                    with contextlib.suppress(OSError):
+                        os.unlink(port_file)  # read once; leave no litter
                     return proc, addr
         if proc.poll() is not None:
             raise RuntimeError("local master exited during startup")

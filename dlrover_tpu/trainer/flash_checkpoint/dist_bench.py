@@ -13,7 +13,7 @@ bytes read for a half-state partial restore vs the full-read baseline.
 Prints ONE ``DIST_CKPT_BENCH {json}`` line; ``bench.py`` runs it as a
 subprocess (so the forced CPU backend never collides with a TPU
 session) and folds the JSON into the round detail — which means the
-TPU watcher's bench stage captures real-hardware numbers automatically
+bench run on the chip captures real-hardware numbers
 whenever the probe succeeds.
 
 Run standalone::

@@ -63,9 +63,9 @@ def run_check(out_path: str) -> float:
 
     # warm-up excludes compile time: every host pays a similar multi-second
     # compile, which drowned the actual execution-speed signal the
-    # straggler ratio needs.  hard_block, not block_until_ready: on a
-    # proxied PJRT plugin the ready event can resolve at enqueue time,
-    # which would time dispatch latency and blind straggler detection.
+    # straggler ratio needs.  hard_block, not block_until_ready: a ready
+    # event that resolved at enqueue time would time dispatch latency
+    # and blind straggler detection (utils/timing.py).
     from dlrover_tpu.utils.timing import hard_block
 
     hard_block(matmul_loop(x))

@@ -1009,6 +1009,16 @@ class Trainer:
         self._jit_step = jit_step
         return self._jit_step
 
+    def lower_train_step(self, state, batch):
+        """The step program lowered (not compiled) for these arguments,
+        arrays or ``ShapeDtypeStruct``s alike: ``.as_text()`` shows
+        whether the kernel is in it, ``.compile()`` what the chip's
+        compiler makes of it."""
+        if self._jit_step is None:
+            self.compile_train_step()
+        with self.mesh:
+            return self._jit_step.lower(state, batch)
+
     def _dispatch(self, state, batch):
         with self.mesh:
             return self._jit_step(state, batch)

@@ -405,9 +405,8 @@ class UnifiedPrimeMaster:
         })
         if spec.platform:
             # both knobs: JAX_PLATFORMS for plain jax processes, and
-            # DLROVER_TPU_PLATFORM for roles calling runtime.init() —
-            # the latter survives sitecustomize PJRT plugins that
-            # override the env var (see runtime.init docstring)
+            # DLROVER_TPU_PLATFORM for roles calling runtime.init()
+            # (which pins through jax.config, see its docstring)
             env["JAX_PLATFORMS"] = spec.platform
             env["DLROVER_TPU_PLATFORM"] = spec.platform
         cmd = [sys.executable, spec.entrypoint, *spec.args]

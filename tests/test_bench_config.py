@@ -14,8 +14,8 @@ class TestPickCkptConfig:
         assert tag == "llama-0.7B"
         assert "projected" in note
 
-    def test_slow_tunnel_picks_smaller(self):
-        # 0.02 GB/s tunnel: 0.8B would need 3*6.6GB/0.02 ~= 1000s... per
+    def test_slow_link_picks_smaller(self):
+        # 0.02 GB/s link: 0.8B would need 3*6.6GB/0.02 ~= 1000s... per
         # leg; the 350M config is the one that fits a 900s budget
         tag, cfg, B, S, note = pick_ckpt_config(
             budget_s=420, bw_gbps=0.02, hbm_gb=16.0

@@ -200,3 +200,12 @@ class TestTier1Dots:
             lambda *a, **k: (_ for _ in ()).throw(OSError()),
         )
         assert bench._tier1_dots() == -1
+
+
+class TestPeakTable:
+    def test_known_device(self):
+        assert bench._peak_bf16_flops("TPU v5 lite") == 197e12
+
+    def test_unknown_device_is_an_error_not_a_default(self):
+        with pytest.raises(KeyError, match="no published bf16 peak"):
+            bench._peak_bf16_flops("cpu")

@@ -22,6 +22,7 @@ def _run(env_extra):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("DLROVER_TPU_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop("DLROVER_TPU_MASTER_ADDR", None)
     env.update(env_extra)
     out = subprocess.run(
@@ -48,6 +49,42 @@ class TestCompileCacheWiring:
     def test_off_sentinel_disables(self):
         stdout = _run({"DLROVER_TPU_COMPILE_CACHE": "off"})
         assert "cache_dir=None" in stdout or "cache_dir=''" in stdout
+
+    def test_jax_env_dir_is_the_only_cache(self, tmp_path):
+        """Where the machine sets JAX_COMPILATION_CACHE_DIR that
+        directory is the cache: the code names no other, not even
+        through its own knob."""
+        theirs = str(tmp_path / "machine_cache")
+        ours = str(tmp_path / "knob_cache")
+        stdout = _run({
+            "JAX_COMPILATION_CACHE_DIR": theirs,
+            "DLROVER_TPU_COMPILE_CACHE": ours,
+        })
+        assert f"cache_dir={theirs!r}" in stdout
+        info = _probe_info(stdout)
+        assert info["enabled"] is True and info["dir"] == theirs
+        assert not os.path.exists(ours)
+
+    def test_off_wins_over_jax_env(self, tmp_path):
+        stdout = _run({
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "machine_cache"),
+            "DLROVER_TPU_COMPILE_CACHE": "off",
+        })
+        info = _probe_info(stdout)
+        assert info["enabled"] is False and info["reason"] == "env-off"
+
+    def test_default_dir_is_fixed_inside_the_checkout(self, monkeypatch):
+        """Unset, the cache is .cache/xla beside pyproject.toml: never
+        /tmp, never a name made from a pid, a time or a temp name (the
+        path is part of the cache key)."""
+        from dlrover_tpu.trainer import bootstrap
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("DLROVER_TPU_COMPILE_CACHE", raising=False)
+        path = bootstrap.compile_cache_dir()
+        assert path == os.path.join(REPO, ".cache", "xla")
+        assert os.path.exists(os.path.join(REPO, "pyproject.toml"))
+        assert path == bootstrap.compile_cache_dir()
 
 
 def _probe_info(stdout):

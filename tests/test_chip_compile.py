@@ -1,0 +1,213 @@
+"""Compile the main path's kernels and step for a DESCRIBED TPU v5e.
+
+The TPU compiler is installed where the tests run, and it compiles for a
+chip that is described and not attached: what it refuses here (a kernel
+that cannot be partitioned, a tile that does not align, a program that
+does not fit) costs no chip time.  Nothing here runs, so nothing here
+says anything about results or times.
+
+This is the only test file that describes a chip.  The topology is
+described inside a module-scoped fixture — never at import, in a
+``skipif``, in ``parametrize`` or in ``conftest.py``: only one process at
+a time may load the TPU's library, every xdist worker imports every test
+file, and the worker that is given this file is the one that loads it.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from dlrover_tpu.ops import attention
+from dlrover_tpu.ops.pallas import ring_reduce_scatter as ring
+from dlrover_tpu.ops.pallas.flash_attention import pallas_flash_attention
+from dlrover_tpu.ops.pallas.tuning import tuned_blocks
+from dlrover_tpu.parallel.collectives import shard_map_unchecked
+from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - no compiler: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def ring_mesh(topo):
+    return Mesh(np.array(topo.devices), ("dp",))
+
+
+@pytest.fixture
+def as_if_on_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` still sees the CPU here;
+    the test steers it, the program has no option for it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _fa2_fwd_bwd(q, k, v):
+    block_q, block_kv = tuned_blocks(q.shape[1], q.shape[-1])
+
+    def loss(q, k, v):
+        out = pallas_flash_attention(q, k, v, True, block_q, block_kv)
+        return out.astype(jnp.float32).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize(
+    "shape", [(4, 2048, 16, 128), (16, 1024, 16, 64)],
+    ids=["b4_s2048_h16_d128", "b16_s1024_h16_d64"],
+)
+class TestFlashAttentionKernel:
+    def test_forward_compiles(self, one_chip, shape):
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        block_q, block_kv = tuned_blocks(shape[1], shape[-1])
+        compiled = jax.jit(
+            lambda q, k, v: pallas_flash_attention(
+                q, k, v, True, block_q, block_kv
+            )
+        ).lower(x, x, x).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+    def test_backward_compiles(self, one_chip, shape):
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        compiled = jax.jit(_fa2_fwd_bwd).lower(x, x, x).compile()
+        # forward, dQ, and dK/dV kernels
+        assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_sharded_flash_attention_compiles_on_fsdp4(topo, as_if_on_tpu):
+    """The production call (``ops.attention.flash_attention``) inside a
+    program sharded over four chips: a bare Mosaic call cannot be
+    partitioned, the shard_map wrap can."""
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=list(topo.devices))
+    data = NamedSharding(mesh, P(("dp", "fsdp")))
+    x = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16, sharding=data)
+
+    def loss(q, k, v):
+        return attention.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    with mesh:
+        compiled = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2))
+        ).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # each chip holds its quarter of the batch, whole sequences
+    grads = compiled.output_shardings[1]
+    assert all(g.spec[0] in ("fsdp", ("dp", "fsdp"), ("fsdp",))
+               for g in grads)
+
+
+def test_pallas_accumulate_ring_compiles_on_four_chips(ring_mesh):
+    fn = shard_map_unchecked(
+        # interpret=False by argument: left to itself the ring asks
+        # jax.default_backend(), which is still the CPU here
+        lambda t: ring.ring_reduce_scatter(
+            t[0], "dp", 4, accum="pallas", interpret=False
+        )[None],
+        mesh=ring_mesh, in_specs=P("dp"), out_specs=P("dp"),
+    )
+    x = jax.ShapeDtypeStruct(
+        (4, 4, 1024), jnp.float32,
+        sharding=NamedSharding(ring_mesh, P("dp")),
+    )
+    compiled = jax.jit(fn).lower(x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
+
+
+def test_rdma_ring_compiles_on_four_chips(ring_mesh):
+    fn = shard_map_unchecked(
+        lambda t: ring.rdma_ring_reduce_scatter(t[0], "dp", 4)[None],
+        mesh=ring_mesh, in_specs=P("dp"), out_specs=P("dp"),
+    )
+    x = jax.ShapeDtypeStruct(
+        (4, 4, 1024), jnp.float32,
+        sharding=NamedSharding(ring_mesh, P("dp")),
+    )
+    compiled = jax.jit(fn).lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _trainer_step_compiled(mesh):
+    """The whole ``Trainer`` step at the 1.24B widths cut to 2 layers,
+    with the optimizer and dtypes of bench.py's throughput run, lowered
+    from shapes (a described device holds no array) and compiled."""
+    from dlrover_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from dlrover_tpu.trainer.optim import create_optimizer
+    from dlrover_tpu.trainer.train import Trainer
+
+    cfg = dataclasses.replace(
+        LlamaConfig.llama2_1b(max_seq_len=2048, attention_impl="flash"),
+        num_layers=2,
+    )
+    opt = create_optimizer(
+        peak_lr=3e-4, warmup_steps=10, total_steps=10_000,
+        moment_dtype=jnp.bfloat16,
+    )
+    trainer = Trainer(
+        LlamaForCausalLM(cfg), opt, mesh, grads_dtype=jnp.bfloat16
+    )
+    rng = jax.random.PRNGKey(0)
+    sample = np.zeros((4, 2048), np.int32)
+    shardings = trainer.state_sharding_for(rng, sample)
+    trainer.state_shardings = shardings
+    # the shardings are a prefix tree of the (boxed) state: one
+    # NamedSharding stands for everything inside a partitioning box
+    state = jax.tree.map(
+        lambda s, sub: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            sub,
+        ),
+        shardings, trainer.abstract_state(rng, sample),
+    )
+    data = NamedSharding(mesh, P(trainer.data_axes))
+    ids = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=data)
+    batch = {"input_ids": ids, "labels": ids}
+    return trainer.lower_train_step(state, batch).compile()
+
+
+class TestTrainerStep:
+    def test_one_chip(self, topo, as_if_on_tpu):
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh)
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert "all-gather" not in text and "all-reduce" not in text
+
+    def test_fsdp4(self, topo, as_if_on_tpu):
+        mesh = build_mesh(MeshConfig(fsdp=4), devices=list(topo.devices))
+        compiled = _trainer_step_compiled(mesh)
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert "all-gather" in text  # ZeRO-3: params gathered per layer
+        mem = compiled.memory_analysis()
+        # 2 layers at these widths: 266M params, fp32 masters + bf16
+        # moments = 8 bytes each, a quarter of it on every chip
+        assert mem.argument_size_in_bytes < 0.3 * 266e6 * 8 + (1 << 20)
+

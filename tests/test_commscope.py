@@ -104,7 +104,11 @@ class TestMeshProbe:
             name="t", seed=3,
             faults=[chaos.FaultSpec(
                 point="comm.axis_delay.dp", kind=chaos.DELAY,
-                delay_s=0.03,
+                # 0.15 s against a 0.5 ms runner: the 10x margin below
+                # then survives a 0.5 ms sleep stretching to 15 ms on a
+                # host busy with five other test workers (at 0.03 s it
+                # failed there: host-clock noise, no JAX involved)
+                delay_s=0.15,
             )],
         ))
         model = commscope.FabricModel(alpha=1.0)

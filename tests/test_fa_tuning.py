@@ -9,9 +9,8 @@ from dlrover_tpu.ops.pallas import tuning
 
 @pytest.fixture(autouse=True)
 def isolated_tables(monkeypatch, tmp_path):
-    """No shipped/user/env table leaks into (or out of) a test."""
+    """No shipped/env table leaks into (or out of) a test."""
     monkeypatch.setattr(tuning, "_SHIPPED", str(tmp_path / "shipped.json"))
-    monkeypatch.setattr(tuning, "_USER_TABLE", str(tmp_path / "user.json"))
     monkeypatch.delenv("DLROVER_TPU_FA_TUNING", raising=False)
     tuning._load_one.cache_clear()
     yield tmp_path
@@ -33,15 +32,36 @@ class TestTunedBlocks:
         monkeypatch.setenv("DLROVER_TPU_FA_TUNING", str(path))
         assert tuning.tuned_blocks(2048, 128) == (1024, 256)
 
-    def test_user_cache_overrides_shipped(self, isolated_tables):
+    def test_env_table_overrides_shipped(self, monkeypatch, isolated_tables):
         (isolated_tables / "shipped.json").write_text(json.dumps({
             "s1024_d64": {"block_q": 512, "block_kv": 512},
         }))
-        (isolated_tables / "user.json").write_text(json.dumps({
+        (isolated_tables / "env.json").write_text(json.dumps({
             "s1024_d64": {"block_q": 256, "block_kv": 128},
         }))
         tuning._load_one.cache_clear()
+        assert tuning.tuned_blocks(1024, 64) == (512, 512)
+        monkeypatch.setenv(
+            "DLROVER_TPU_FA_TUNING", str(isolated_tables / "env.json")
+        )
         assert tuning.tuned_blocks(1024, 64) == (256, 128)
+
+    def test_no_per_user_table_is_read(self, monkeypatch, isolated_tables):
+        """The table is the shipped file plus the env file: a file under
+        the home directory must change nothing."""
+        home = isolated_tables / "home"
+        cache = home / ".cache" / "dlrover_tpu"
+        cache.mkdir(parents=True)
+        (cache / "fa_tuned.json").write_text(json.dumps({
+            "s1024_d64": {"block_q": 128, "block_kv": 128},
+        }))
+        monkeypatch.setenv("HOME", str(home))
+        tuning._load_one.cache_clear()
+        assert tuning.tuned_blocks(1024, 64) == (512, 512)
+
+    def test_autotune_without_a_target_refuses(self):
+        with pytest.raises(RuntimeError, match="DLROVER_TPU_FA_TUNING"):
+            tuning.autotune(256, 64, require_tpu=False)
 
     def test_nearest_seq_borrow_shrinks_to_divisor(
         self, monkeypatch, isolated_tables
@@ -90,7 +110,7 @@ class TestTunedBlocks:
         with pytest.raises(RuntimeError, match="TPU backend"):
             tuning.autotune(256, 64)
 
-    def test_autotune_writes_user_cache_on_cpu_interpret(
+    def test_autotune_writes_env_table_on_cpu_interpret(
         self, monkeypatch, isolated_tables
     ):
         """The sweep plumbing itself (candidate loop, persist, reload) is
@@ -111,7 +131,10 @@ class TestTunedBlocks:
             tuning, "_candidates", lambda s: [(128, 128), (256, 256)]
         )
         monkeypatch.setattr(fa_mod, "pallas_flash_attention", interp)
-        # no out_path: must land in the USER cache, never the package dir
+        # no out_path: must land where the env points, never the package
+        monkeypatch.setenv(
+            "DLROVER_TPU_FA_TUNING", str(isolated_tables / "user.json")
+        )
         entry = tuning.autotune(
             256, 64, heads=2, batch=1, require_tpu=False
         )

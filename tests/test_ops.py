@@ -7,7 +7,7 @@ import numpy as np
 import optax
 import pytest
 
-from dlrover_tpu.ops.attention import reference_attention
+from dlrover_tpu.ops.attention import flash_attention, reference_attention
 from dlrover_tpu.ops.pallas.flash_attention import pallas_flash_attention
 from dlrover_tpu.ops.ring_attention import ring_attention_sharded
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -109,6 +109,89 @@ class TestPallasFlashAttention:
         q, k, v = _qkv(4, 1, 100, 2, 32)
         with pytest.raises(ValueError):
             pallas_flash_attention(q, k, v, True, 64, 64, True)
+
+
+class TestFlashAttentionDispatch:
+    """``ops.attention.flash_attention`` is the kernel and nothing else:
+    it never turns into the reference, and under a mesh of several
+    devices it runs per shard through ``shard_map``."""
+
+    def test_off_the_chip_it_raises(self):
+        q, k, v = _qkv(0, 1, 128, 2, 64)
+        with pytest.raises(RuntimeError, match="needs a TPU backend"):
+            flash_attention(q, k, v)
+
+    def test_model_with_flash_raises_off_the_chip(self):
+        from dlrover_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+        model = LlamaForCausalLM(LlamaConfig.tiny(attention_impl="flash"))
+        ids = jnp.zeros((2, 16), jnp.int32)
+        with pytest.raises(RuntimeError, match="needs a TPU backend"):
+            model.init(jax.random.PRNGKey(0), ids)
+
+    def test_interpret_by_argument_matches_reference(self):
+        q, k, v = _qkv(1, 2, 128, 4, 64, kv_heads=2)
+        out = flash_attention(q, k, v, interpret=True)
+        ref = reference_attention(q, k, v, _causal_mask(128))
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize(
+        "layout, batch_axes",
+        [(dict(fsdp=4), ("fsdp",)), (dict(dp=2, fsdp=2), ("dp", "fsdp")),
+         (dict(fsdp=2, tp=2), ("fsdp",))],
+        ids=["fsdp4", "dp2_fsdp2", "fsdp2_tp2"],
+    )
+    def test_sharded_under_a_mesh(self, layout, batch_axes):
+        """Forward and backward through the wrap agree with the
+        reference, and the output stays sharded: batch over the data
+        axes, heads over tp."""
+        mesh = build_mesh(
+            MeshConfig(**{"dp": 1, **layout}), devices=jax.devices()[:4]
+        )
+        q, k, v = _qkv(2, 4, 128, 4, 64, kv_heads=2)
+        mask = _causal_mask(128)
+
+        def flash_loss(q, k, v):
+            out = flash_attention(q, k, v, interpret=True)
+            return (out ** 2).sum(), out
+
+        def ref_loss(q, k, v):
+            return (reference_attention(q, k, v, mask) ** 2).sum()
+
+        with mesh:
+            (_, out), grads = jax.jit(
+                jax.value_and_grad(flash_loss, argnums=(0, 1, 2),
+                                   has_aux=True)
+            )(q, k, v)
+        want = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(
+            out, reference_attention(q, k, v, mask), atol=2e-5, rtol=2e-5
+        )
+        for g, w in zip(grads, want):
+            np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
+        spec = out.sharding.spec
+        got = spec[0] if isinstance(spec[0], tuple) else (spec[0],)
+        assert got == batch_axes
+        if "tp" in layout:
+            assert spec[2] == "tp"
+
+    def test_inside_a_shard_map_it_does_not_wrap_again(self):
+        """The manual grad-sync step runs the model inside a shard_map:
+        the mesh axes are already manual there, the call is per shard."""
+        from jax.sharding import PartitionSpec as P
+
+        from dlrover_tpu.parallel.collectives import shard_map_unchecked
+
+        mesh = build_mesh(MeshConfig(dp=4), devices=jax.devices()[:4])
+        q, k, v = _qkv(3, 4, 128, 2, 64)
+        fn = shard_map_unchecked(
+            lambda q, k, v: flash_attention(q, k, v, interpret=True),
+            mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+        )
+        with mesh:
+            out = jax.jit(fn)(q, k, v)
+        ref = reference_attention(q, k, v, _causal_mask(128))
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
 class TestRingAttention:

@@ -68,7 +68,7 @@ class TestDeviceLanes:
         mesh = jax.sharding.Mesh(jax.devices(), ("dp",))
         from functools import partial
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         @jax.jit
