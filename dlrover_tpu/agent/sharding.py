@@ -274,7 +274,7 @@ class ShardingClient:
             },
         ) as sp:
             if sp.sampled and fetched_at > 0:
-                sp.start_ts = fetched_at
+                sp.start_ns = int(fetched_at * 1e9)
         datascope.record_consume(self._dataset_name, process_s)
 
     def get_shard_checkpoint(self) -> str:

@@ -594,8 +594,10 @@ register("DLROVER_TPU_RECORDER", "bool", True,
          "always-on in-process flight recorder: bounded rings of recent "
          "spans/events/step timings/log tail, snapshotted into incident "
          "dumps (0 turns every append into a flag check)")
-register("DLROVER_TPU_RECORDER_SPANS", "int", 1024,
-         "flight recorder: finished-span ring capacity")
+register("DLROVER_TPU_RECORDER_SPANS", "int", 2048,
+         "flight recorder: finished-span ring capacity (tuples; the "
+         "default holds a minute of steps at 7 a second with their "
+         "three spans each, and a save's, in about 1.3 MB)")
 register("DLROVER_TPU_RECORDER_EVENTS", "int", 1024,
          "flight recorder: training-event/chaos-fault ring capacity")
 register("DLROVER_TPU_RECORDER_STEPS", "int", 512,

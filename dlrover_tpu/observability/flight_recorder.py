@@ -17,6 +17,11 @@ Design constraints, in order:
    ``deque.append`` calls on ``maxlen`` deques — atomic under CPython,
    no lock, O(1), nothing ever blocks.  Capacities come from the
    ``DLROVER_TPU_RECORDER_*`` knobs; total resident size is a few MB.
+   The span ring holds finished spans as tuples
+   (``trace.SpanTuple``) and is rendered to records only when
+   :func:`snapshot` is asked; beside it ``span_totals`` keeps, for each
+   span name, a count, the summed and the longest duration, which no
+   eviction touches.
    The totals counters are intentionally unlocked (a lost increment
    under a race is an off-by-one in an informational field, never
    corruption).
@@ -25,12 +30,14 @@ Design constraints, in order:
    fraction of a measured step so regressions show in the BENCH
    trajectory (acceptance: < 1% of step time).
 3. **Feeds are one-directional.**  ``trace._export`` pushes finished
-   SPAN records, ``training_event.emitter`` pushes BEGIN/END/INSTANT
+   spans, ``training_event.emitter`` pushes BEGIN/END/INSTANT
    events, the chaos engine pushes fired faults, ``Trainer.train_step``
    pushes step durations — all via the module-level helpers here, all
    guarded so a broken recorder can never break training.
 
-``DLROVER_TPU_RECORDER=0`` turns every append into a flag check.
+``DLROVER_TPU_RECORDER=0`` turns every append into a flag check (the
+flag is read when the rings are built; :meth:`FlightRecorder.reset`
+re-reads it).
 """
 
 import json
@@ -41,10 +48,15 @@ import threading
 import time
 import traceback
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Union
 
 from dlrover_tpu.common import envs
 from dlrover_tpu.common.log import logger
+
+
+#: span names the aggregate will hold (request types and bucket numbers
+#: ride in names; a runaway name scheme must not grow it without bound)
+_MAX_SPAN_NAMES = 1024
 
 
 def enabled() -> bool:
@@ -97,6 +109,9 @@ class FlightRecorder:
             logger.addHandler(self._log_handler)
 
     def _build_rings(self) -> None:
+        self._on = enabled()
+        #: span name -> [count, total ns, longest ns]; never evicted
+        self.span_totals: Dict[str, List[int]] = {}
         self.spans: deque = deque(
             maxlen=max(1, envs.get_int("DLROVER_TPU_RECORDER_SPANS"))
         )
@@ -123,28 +138,39 @@ class FlightRecorder:
 
     # -- appends (the hot path) --------------------------------------------
 
-    def record_span(self, record: Dict[str, Any]) -> None:
-        """A finished SPAN record (``trace.Span.to_record`` shape)."""
-        if not enabled():
+    def record_span(self, span: Union[tuple, Dict[str, Any]]) -> None:
+        """A finished span: a ``trace.SpanTuple``, or a SPAN record
+        already rendered (``trace.record_of`` shape)."""
+        if not self._on:
             return
-        self.spans.append(record)
+        self.spans.append(span)
         self.total_spans += 1
+        if type(span) is not dict:
+            dur = span.end_ns - span.start_ns
+            agg = self.span_totals.get(span.name)
+            if agg is not None:
+                agg[0] += 1
+                agg[1] += dur
+                if dur > agg[2]:
+                    agg[2] = dur
+            elif len(self.span_totals) < _MAX_SPAN_NAMES:
+                self.span_totals[span.name] = [1, dur, dur]
 
     def record_event(self, record: Dict[str, Any]) -> None:
         """A training event (BEGIN/END/INSTANT) or a chaos-fault record."""
-        if not enabled():
+        if not self._on:
             return
         self.events.append(record)
         self.total_events += 1
 
     def record_step(self, step: int, dur_s: float) -> None:
-        if not enabled():
+        if not self._on:
             return
         self.steps.append((round(time.time(), 6), int(step), float(dur_s)))
         self.total_steps += 1
 
     def record_log(self, line: str) -> None:
-        if not enabled():
+        if not self._on:
             return
         self.logs.append(line)
 
@@ -166,6 +192,13 @@ class FlightRecorder:
             "ts": round(samples[-1][0], 6),
         }
 
+    def span_records(self) -> List[Dict[str, Any]]:
+        """The span ring as SPAN records, oldest first."""
+        from dlrover_tpu.observability import trace
+
+        return [s if type(s) is dict else trace.record_of(s)
+                for s in list(self.spans)]
+
     def snapshot(self, stacks: bool = True) -> Dict[str, Any]:
         """Freeze the rings + live-thread stacks + open spans + metrics
         into one JSON-serializable document (the incident dump unit)."""
@@ -179,7 +212,13 @@ class FlightRecorder:
                 "events": self.total_events,
                 "steps": self.total_steps,
             },
-            "spans": list(self.spans),
+            "spans": self.span_records(),
+            "span_totals": {
+                name: {"count": c, "total_s": round(total * 1e-9, 6),
+                       "max_s": round(longest * 1e-9, 6)}
+                for name, (c, total, longest) in list(
+                    self.span_totals.items())
+            },
             "events": list(self.events),
             "steps": [list(s) for s in self.steps],
             "logs": list(self.logs),
@@ -248,8 +287,8 @@ def recorder() -> FlightRecorder:
 # wraps in try/except so instrumentation can never break the host) ----------
 
 
-def on_span(record: Dict[str, Any]) -> None:
-    recorder().record_span(record)
+def on_span(span: Union[tuple, Dict[str, Any]]) -> None:
+    recorder().record_span(span)
 
 
 def on_event(record: Dict[str, Any]) -> None:

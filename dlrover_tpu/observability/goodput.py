@@ -45,6 +45,7 @@ goodput time series the regression sentinel watches.
 ``DLROVER_TPU_GOODPUT_LEDGER=0`` turns every feed into a flag check.
 """
 
+import functools
 import threading
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -130,6 +131,10 @@ _CLAIM_OF_PHASE: Dict[str, str] = {
 #: past DLROVER_TPU_DATA_STARVED_MIN_S.
 SPAN_PHASE: Tuple[Tuple[str, str], ...] = (
     ("flash.persist", "ckpt_background"),
+    # the stager thread drains a snapshot BEHIND the steps: a save whose
+    # call cost one step must not book its twenty seconds of staging as
+    # a stall (the call itself is ``flash.save``, blocking, below)
+    ("flash.stage", "ckpt_background"),
     ("flash.", "ckpt_blocking"),
     ("snapshot.", "ckpt_blocking"),
     ("storage.", "ckpt_background"),
@@ -140,7 +145,9 @@ SPAN_PHASE: Tuple[Tuple[str, str], ...] = (
 )
 
 
-def _span_phase(name: str) -> str:
+@functools.lru_cache(maxsize=512)
+def span_phase(name: str) -> str:
+    """The claim a span of this name is charged to ("" for none)."""
     for prefix, claim in SPAN_PHASE:
         if name.startswith(prefix):
             return claim
@@ -237,9 +244,9 @@ class GoodputLedger:
     # -- feeds --------------------------------------------------------------
 
     def on_span(self, record: Dict[str, Any]) -> None:
-        """A finished SPAN record (``trace.Span.to_record`` shape):
+        """A finished SPAN record (``trace.record_of`` shape):
         charged when its name maps to a phase."""
-        phase = _span_phase(str(record.get("name", "")))
+        phase = span_phase(str(record.get("name", "")))
         if not phase:
             return
         ts = float(record.get("ts", 0.0))

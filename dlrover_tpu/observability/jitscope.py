@@ -735,9 +735,8 @@ def _emit_span(name: str, start_ts: float, end_ts: float,
             name, trace.INTERNAL, trace.new_trace_id(),
             trace.new_span_id(), attrs=attrs,
         )
-        sp.start_ts = start_ts
         sp.end()
-        sp.end_ts = end_ts
+        sp.start_ns, sp.end_ns = int(start_ts * 1e9), int(end_ts * 1e9)
         trace._export(sp)
     except Exception as e:  # noqa: BLE001 - telemetry must not break
         logger.debug("jitscope span emit failed: %s", e)

@@ -20,9 +20,22 @@ Design constraints:
    deterministic for drills and golden-output tests — the same
    discipline the chaos engine uses.  Seeded mode is meant for
    single-process drills; multi-process jobs keep the entropy default.
-3. **Cheap when off.**  ``DLROVER_TPU_TRACE=0`` turns :func:`span` into
-   a no-op yielding the shared :data:`NOOP_SPAN`; the flag is read at
-   call time so tests can flip it.
+3. **Cheap when off, cheap enough when on to stand on the hot path.**
+   ``DLROVER_TPU_TRACE=0`` turns :func:`span` into a no-op yielding the
+   shared :data:`NOOP_SPAN`; the flag is read once per process
+   (:func:`seed_ids` and :func:`set_span_sink`, the test hooks, re-read
+   it).  A span is one slotted object, integer ``time.time_ns()``
+   stamps, lock-free ids, and at close one tuple into the flight
+   recorder's ring plus a per-name aggregate.  Spans of the step and
+   stager path (:data:`IN_MEMORY_PREFIXES`) stop there: they are never
+   serialised one by one, ``flight_recorder.snapshot()`` renders them
+   when an incident asks.
+4. **One clock with the device trace.**  In a process that has already
+   imported ``jax`` (this module never imports it) a span also enters a
+   ``jax.profiler.TraceAnnotation`` of its name, so while ANY profiler
+   session is active the span is in the xplane's ``/host:CPU`` plane on
+   the line of its thread.  No session, no trace output: the session is
+   the switch, there is no knob.
 
 Span *events* are the attachment point for the PR-4 subsystems: retry
 attempts, circuit-breaker flips, and chaos injections call
@@ -30,17 +43,18 @@ attempts, circuit-breaker flips, and chaos injections call
 drill therefore yields a fully attributed fault trace.
 """
 
-import contextlib
 import contextvars
 import dataclasses
 import os
 import random
+import sys
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from dlrover_tpu.common import envs
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.observability import flight_recorder, goodput
 
 #: span kinds (OpenTelemetry vocabulary, lowercase)
 INTERNAL = "internal"
@@ -59,41 +73,37 @@ _ids_rng: Optional[random.Random] = None
 
 def seed_ids(seed: int) -> None:
     """Re-seed the id stream (tests/drills).  ``seed=0`` restores the
-    entropy default."""
-    global _ids_rng
+    entropy default.  Also re-reads ``DLROVER_TPU_TRACE`` and the
+    sampling rate, which are otherwise read once per process."""
+    global _ids_rng, _ENABLED
+    _ENABLED = None
     with _ids_mu:
-        if seed:
-            _ids_rng = random.Random(seed)
-        else:
-            _ids_rng = None
+        _ids_rng = random.Random(seed) if seed else None
 
 
-def _rng() -> random.Random:
+def _make_rng() -> random.Random:
     global _ids_rng
     with _ids_mu:
         if _ids_rng is None:
             seed = envs.get_int("DLROVER_TPU_TRACE_SEED")
-            if seed:
-                _ids_rng = random.Random(seed)
-            else:
-                _ids_rng = random.Random(
+            _ids_rng = random.Random(
+                seed or (
                     int.from_bytes(os.urandom(8), "big")
                     ^ (os.getpid() << 17)
                     ^ time.time_ns()
                 )
+            )
         return _ids_rng
 
 
+# ``Random.getrandbits`` is one C call, atomic under the GIL: the id
+# stream needs the lock only to be (re)made, not to be drawn from.
 def new_trace_id() -> str:
-    rng = _rng()
-    with _ids_mu:
-        return f"{rng.getrandbits(128):032x}"
+    return "%032x" % (_ids_rng or _make_rng()).getrandbits(128)
 
 
 def new_span_id() -> str:
-    rng = _rng()
-    with _ids_mu:
-        return f"{rng.getrandbits(64):016x}"
+    return "%016x" % (_ids_rng or _make_rng()).getrandbits(64)
 
 
 # ---------------------------------------------------------------------------
@@ -138,13 +148,56 @@ def parse_traceparent(header: str) -> Optional[TraceContext]:
     return TraceContext(trace_id=trace_id, span_id=span_id, sampled=sampled)
 
 
+class SpanTuple(NamedTuple):
+    """A finished span as the flight recorder's ring holds it."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    tid: int
+    thread: str
+    trace_id: str
+    span_id: str
+    parent_span_id: str
+    kind: str
+    status: str
+    error: str
+    attrs: Dict[str, Any]
+    events: List[Dict[str, Any]]
+
+
+_new_tuple = tuple.__new__  # SpanTuple's own __new__ costs a Python call
+
+
+def record_of(t: SpanTuple) -> Dict[str, Any]:
+    """The JSONL record the timeline assembler and the incident dump
+    consume (``ts``/``dur`` in seconds)."""
+    return {
+        "ts": round(t.start_ns * 1e-9, 6),
+        "dur": round(max(0, t.end_ns - t.start_ns) * 1e-9, 6),
+        "name": t.name,
+        "type": "SPAN",
+        "kind": t.kind,
+        "trace_id": t.trace_id,
+        "span_id": t.span_id,
+        "parent_span_id": t.parent_span_id,
+        "status": t.status,
+        **({"error": t.error} if t.error else {}),
+        "tid": t.tid,
+        "thread": t.thread,
+        "attrs": t.attrs,
+        "events": t.events,
+    }
+
+
 class Span:
-    """One traced operation.  Mutable until :meth:`end`; exported once."""
+    """One traced operation, and its own context manager.  Mutable
+    until :meth:`end`; exported once."""
 
     __slots__ = (
         "name", "kind", "trace_id", "span_id", "parent_span_id",
-        "start_ts", "end_ts", "attrs", "events", "status", "error",
-        "sampled", "_ended",
+        "start_ns", "end_ns", "tid", "thread", "attrs", "events",
+        "status", "error", "sampled", "_token", "_anno",
     )
 
     def __init__(self, name: str, kind: str, trace_id: str, span_id: str,
@@ -155,19 +208,24 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_span_id = parent_span_id
-        self.start_ts = time.time()
-        self.end_ts = 0.0
+        self.tid = threading.get_ident()
+        self.thread = threading.current_thread().name
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.events: List[Dict[str, Any]] = []
         self.status = "ok"
         self.error = ""
         self.sampled = sampled
-        self._ended = False
+        self._anno = None
+        self.end_ns = 0
+        self.start_ns = time.time_ns()
 
     # -- mutation ----------------------------------------------------------
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
+
+    def set_attrs(self, attrs: Dict[str, Any]) -> None:
+        self.attrs.update(attrs)
 
     def add_event(self, name: str, **attrs: Any) -> None:
         """Attach a timestamped event (retry attempt, breaker flip,
@@ -180,10 +238,9 @@ class Span:
         )
 
     def end(self, status: Optional[str] = None, error: str = "") -> None:
-        if self._ended:
+        if self.end_ns:
             return
-        self._ended = True
-        self.end_ts = time.time()
+        self.end_ns = time.time_ns()
         if status is not None:
             self.status = status
         if error:
@@ -198,23 +255,39 @@ class Span:
     def traceparent(self) -> str:
         return self.context().traceparent()
 
-    def to_record(self) -> Dict[str, Any]:
-        """The JSONL record the timeline assembler consumes."""
-        return {
-            "ts": round(self.start_ts, 6),
-            "dur": round(max(0.0, (self.end_ts or time.time())
-                             - self.start_ts), 6),
-            "name": self.name,
-            "type": "SPAN",
-            "kind": self.kind,
-            "trace_id": self.trace_id,
-            "span_id": self.span_id,
-            "parent_span_id": self.parent_span_id,
-            "status": self.status,
-            **({"error": self.error} if self.error else {}),
-            "attrs": self.attrs,
-            "events": self.events,
-        }
+    def as_tuple(self) -> SpanTuple:
+        return _new_tuple(SpanTuple, (
+            self.name, self.start_ns, self.end_ns or time.time_ns(),
+            self.tid, self.thread, self.trace_id, self.span_id,
+            self.parent_span_id, self.kind, self.status, self.error,
+            self.attrs, self.events,
+        ))
+
+    # -- the context manager ------------------------------------------------
+
+    def __enter__(self) -> "Span":
+        self._token = _CURRENT.set(self)
+        # plain dict writes, atomic under the GIL: open_spans() copies
+        # the values in one C call
+        _OPEN[id(self)] = self
+        anno_cls = _ANNOTATION or _find_annotation()
+        if anno_cls is not None:
+            self._anno = anno_cls(self.name)
+            self._anno.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._anno is not None:
+            self._anno.__exit__(exc_type, exc, tb)
+            self._anno = None
+        if exc is not None:
+            self.end(status="error", error=f"{exc_type.__name__}: {exc}")
+        else:
+            self.end()
+        _OPEN.pop(id(self), None)
+        _CURRENT.reset(self._token)
+        _export(self)
+        return False
 
 
 class _NoopSpan:
@@ -233,14 +306,26 @@ class _NoopSpan:
     def set_attr(self, key: str, value: Any) -> None:
         pass
 
+    def set_attrs(self, attrs: Dict[str, Any]) -> None:
+        pass
+
     def add_event(self, name: str, **attrs: Any) -> None:
         pass
 
     def end(self, status: Optional[str] = None, error: str = "") -> None:
         pass
 
+    def context(self) -> None:
+        return None
+
     def traceparent(self) -> str:
         return ""
+
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
 
 
 NOOP_SPAN = _NoopSpan()
@@ -253,16 +338,37 @@ _CURRENT: contextvars.ContextVar[Optional[Span]] = contextvars.ContextVar(
 # recorder's snapshot reads this to name the operation that never
 # finished — in a hang, the stuck span IS the diagnosis, and it is by
 # definition absent from the finished-span ring
-_open_mu = threading.Lock()
 _OPEN: Dict[int, Span] = {}
+
+# -- the bridge to the profiler ----------------------------------------------
+#
+# ``jax.profiler.TraceAnnotation`` costs an atomic flag check while no
+# profiler session is active, and while one is (the benchmark's
+# ``--trace 1``, ``timer/device_events.py``'s sampled capture, an
+# operator's own) puts the span into the xplane's host plane on the
+# device trace's clock.  This module never imports jax: masters and
+# agents stay off it, and a worker has imported it long before its
+# first span of the step path.
+_ANNOTATION: Optional[type] = None
+
+
+def _find_annotation() -> Optional[type]:
+    global _ANNOTATION
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    except AttributeError:  # jax is only half imported yet: look again
+        return None
+    return _ANNOTATION
 
 
 def open_spans() -> List[Dict[str, Any]]:
     """Records of every currently-open span (any thread), longest-open
     first, with a live ``open_for_s``."""
-    now = time.time()
-    with _open_mu:
-        spans = list(_OPEN.values())
+    now_ns = time.time_ns()
+    spans = list(_OPEN.values())
     out = []
     for sp in spans:
         # per-span fault isolation: these spans are LIVE and owned by
@@ -281,8 +387,12 @@ def open_spans() -> List[Dict[str, Any]]:
                     "trace_id": sp.trace_id,
                     "span_id": sp.span_id,
                     "parent_span_id": sp.parent_span_id,
-                    "start_ts": round(sp.start_ts, 6),
-                    "open_for_s": round(max(0.0, now - sp.start_ts), 6),
+                    "start_ts": round(sp.start_ns * 1e-9, 6),
+                    "open_for_s": round(
+                        max(0, now_ns - sp.start_ns) * 1e-9, 6
+                    ),
+                    "tid": sp.tid,
+                    "thread": sp.thread,
                     "attrs": attrs,
                 }
             )
@@ -292,8 +402,16 @@ def open_spans() -> List[Dict[str, Any]]:
     return out
 
 
+_ENABLED: Optional[bool] = None
+_SAMPLE = 1.0
+
+
 def enabled() -> bool:
-    return envs.get_bool("DLROVER_TPU_TRACE")
+    global _ENABLED, _SAMPLE
+    if _ENABLED is None:
+        _SAMPLE = envs.get_float("DLROVER_TPU_TRACE_SAMPLE")
+        _ENABLED = envs.get_bool("DLROVER_TPU_TRACE")
+    return _ENABLED
 
 
 def current_span() -> Optional[Span]:
@@ -320,87 +438,78 @@ def add_event(name: str, **attrs: Any) -> bool:
     return True
 
 
-def _sampled_root() -> bool:
-    sample = envs.get_float("DLROVER_TPU_TRACE_SAMPLE")
-    if sample >= 1.0:
-        return True
-    rng = _rng()
-    with _ids_mu:
-        return rng.random() < sample
-
-
-@contextlib.contextmanager
 def span(name: str, kind: str = INTERNAL,
          attrs: Optional[Dict[str, Any]] = None,
          parent: Optional[TraceContext] = None):
-    """Open a span as the new current context.
+    """A span to open with ``with`` as the new current context.
 
-    Parentage: an explicit ``parent`` (a remote TraceContext) wins;
-    else the live span; else this is a root (new trace id, head
-    sampling applies).  An exception ends the span with
-    ``status="error"`` and re-raises.
+    Parentage: an explicit ``parent`` (a remote TraceContext, or the
+    context a queue item carried from another thread) wins; else the
+    live span; else this is a root (new trace id, head sampling
+    applies).  An exception ends the span with ``status="error"`` and
+    re-raises.
     """
-    if not enabled():
-        yield NOOP_SPAN
-        return
-    live = _CURRENT.get()
+    if not (_ENABLED if _ENABLED is not None else enabled()):
+        return NOOP_SPAN
+    if parent is None:
+        parent = _CURRENT.get()
+    rng = _ids_rng or _make_rng()
     if parent is not None:
-        sp = Span(
-            name, kind, parent.trace_id, new_span_id(),
-            parent_span_id=parent.span_id, sampled=parent.sampled,
-            attrs=attrs,
+        return Span(
+            name, kind, parent.trace_id, "%016x" % rng.getrandbits(64),
+            parent.span_id, parent.sampled, attrs,
         )
-    elif live is not None:
-        sp = Span(
-            name, kind, live.trace_id, new_span_id(),
-            parent_span_id=live.span_id, sampled=live.sampled, attrs=attrs,
-        )
-    else:
-        sp = Span(
-            name, kind, new_trace_id(), new_span_id(),
-            sampled=_sampled_root(), attrs=attrs,
-        )
-    token = _CURRENT.set(sp)
-    with _open_mu:
-        _OPEN[id(sp)] = sp
-    try:
-        yield sp
-    except BaseException as e:
-        sp.end(status="error", error=f"{type(e).__name__}: {e}")
-        raise
-    finally:
-        with _open_mu:
-            _OPEN.pop(id(sp), None)
-        _CURRENT.reset(token)
-        sp.end()
-        _export(sp)
+    return Span(
+        name, kind, "%032x" % rng.getrandbits(128),
+        "%016x" % rng.getrandbits(64), "",
+        _SAMPLE >= 1.0 or rng.random() < _SAMPLE, attrs,
+    )
 
 
-@contextlib.contextmanager
 def server_span(name: str, traceparent: str,
                 attrs: Optional[Dict[str, Any]] = None):
     """Open the server side of an RPC: parented to the remote caller's
     span when ``traceparent`` parses, a fresh root otherwise."""
-    with span(
+    return span(
         name, kind=SERVER, attrs=attrs, parent=parse_traceparent(traceparent)
-    ) as sp:
-        yield sp
+    )
 
 
 # ---------------------------------------------------------------------------
-# Export: finished spans become SPAN records in the per-process event
-# stream (or a dedicated DLROVER_TPU_TRACE_FILE), which the timeline
-# assembler later joins across processes.
+# Where a finished span goes.  Every span: the flight recorder's ring (a
+# tuple) and per-name aggregate, and the goodput ledger where its name
+# maps to a phase.  Control-plane spans also become SPAN records in the
+# per-process event stream (or a dedicated DLROVER_TPU_TRACE_FILE),
+# which the timeline assembler later joins across processes; spans of
+# the step and stager path do not — a JSON line a span is not a cost the
+# hot path pays.
 # ---------------------------------------------------------------------------
+
+#: names kept in memory only (docs/observability.md, span taxonomy)
+IN_MEMORY_PREFIXES: Tuple[str, ...] = (
+    "trainer.", "flash.save", "flash.stage",
+)
 
 _sink_mu = threading.Lock()
 _sink: Optional[Callable[[Dict[str, Any]], None]] = None
 
+#: the process's ExecutionTimer once one exists (``timer.get_timer``
+#: attaches it): checkpoint spans feed its per-name aggregates and
+#: timeline at close, on its own clock
+_TIMER = None
+
+
+def attach_timer(timer) -> None:
+    global _TIMER
+    _TIMER = timer
+
 
 def set_span_sink(sink: Optional[Callable[[Dict[str, Any]], None]]) -> None:
     """Override where span records go (tests, the CI smoke).  ``None``
-    restores the default (the training-event exporter / trace file)."""
-    global _sink
+    restores the default (the training-event exporter / trace file).
+    Also re-reads ``DLROVER_TPU_TRACE``."""
+    global _sink, _ENABLED
+    _ENABLED = None
     with _sink_mu:
         _sink = sink
 
@@ -426,23 +535,33 @@ def _default_sink() -> Callable[[Dict[str, Any]], None]:
 def _export(sp: Span) -> None:
     if not sp.sampled:
         return
-    record = sp.to_record()
+    t = sp.as_tuple()
+    name = t.name
     try:
         # flight recorder first: the ring must hold the span even when
         # the export sink is broken/replaced (tests) — the incident
         # dump is the consumer that must never miss evidence
-        from dlrover_tpu.observability import flight_recorder
-
-        flight_recorder.on_span(record)
+        flight_recorder.on_span(t)
     except Exception:  # noqa: BLE001 - never break the RPC
         pass
     try:
         # goodput ledger: ckpt/rendezvous spans are wall-clock phases
-        from dlrover_tpu.observability import goodput
-
-        goodput.on_span(record)
+        claim = goodput.span_phase(name)
+        if claim and t.end_ns > t.start_ns:
+            goodput.charge_interval(
+                claim, t.start_ns * 1e-9, t.end_ns * 1e-9
+            )
     except Exception:  # noqa: BLE001 - never break the RPC
         pass
+    timer = _TIMER
+    if timer is not None and name.startswith("flash."):
+        try:
+            dur = t.end_ns - t.start_ns
+            timer.record(name, timer.now_ns() - dur, dur, timer.KIND_CKPT)
+        except Exception:  # noqa: BLE001 - never break a save
+            pass
+    if name.startswith(IN_MEMORY_PREFIXES):
+        return
     global _sink
     with _sink_mu:
         sink = _sink
@@ -453,6 +572,6 @@ def _export(sp: Span) -> None:
                 logger.debug("span sink unavailable: %s", e)
                 return
     try:
-        sink(record)
+        sink(record_of(t))
     except Exception as e:  # noqa: BLE001 - never break the RPC
         logger.debug("span export failed: %s", e)

@@ -351,6 +351,14 @@ class ExecutionTimer:
         with self._inflight_lock:
             items = [s for stack in self._inflight.values() for s in stack]
         spans = [(n, (now - t0) / 1e9, k) for n, t0, k in items]
+        # checkpoint boundaries are trace spans, fed to this timer when
+        # they close: one that never closes is a hang's diagnosis too
+        from dlrover_tpu.observability import trace
+
+        spans += [
+            (r["name"], r["open_for_s"], self.KIND_CKPT)
+            for r in trace.open_spans() if r["name"].startswith("flash.")
+        ]
         spans.sort(key=lambda s: -s[1])
         return spans
 
@@ -453,6 +461,11 @@ def get_timer(metrics_port: Optional[int] = None,
                         else envs.get_float("DLROVER_TPU_TIMER_HANG_SECS")
                     ),
                 )
+                # checkpoint spans (``flash.*``) feed this timer's
+                # per-name aggregates and timeline when they close
+                from dlrover_tpu.observability import trace
+
+                trace.attach_timer(_timer)
     return _timer
 
 

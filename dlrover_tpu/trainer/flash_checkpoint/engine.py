@@ -42,6 +42,7 @@ from dlrover_tpu.common.multi_process import (
     SharedQueue,
 )
 from dlrover_tpu.common.storage import get_checkpoint_storage
+from dlrover_tpu.observability import trace
 from dlrover_tpu.training_event.emitter import (
     TrainerEvents,
     get_default_emitter,
@@ -83,10 +84,14 @@ class _DeviceCopy:
     transient extra copy, and that promise is enforced here rather than
     hoped for."""
 
-    def __init__(self, snap, on_free):
+    def __init__(self, snap, on_free, ctx=None):
         self._snap = snap
         self._on_free = on_free
         self._freed = False
+        #: the trace context of the ``flash.save`` that made the copy:
+        #: it rides the queue item to the stager thread, whose
+        #: ``flash.stage`` span is that save's child
+        self.ctx = ctx
 
     def take(self):
         snap, self._snap = self._snap, None
@@ -492,16 +497,14 @@ class CheckpointEngine:
         behavior); storage saves pass ``block_on_busy=True`` because the
         caller explicitly asked for durability."""
         from dlrover_tpu.observability import metrics as obs_metrics
-        from dlrover_tpu.observability import trace
 
         t0, blocked = time.monotonic(), -1.0
         try:
-            with trace.span(
-                "flash.save",
-                attrs={"step": int(step), "storage": bool(block_on_busy)},
-            ):
+            with self._save_span(
+                step, {"async": False, "storage": bool(block_on_busy)}
+            ) as sp:
                 blocked = self._save_to_memory_traced(
-                    step, state, extras, block_on_busy
+                    step, state, extras, block_on_busy, sp
                 )
             return blocked
         finally:
@@ -513,17 +516,34 @@ class CheckpointEngine:
                 ok=blocked >= 0 or not block_on_busy,
             )
 
+    @contextmanager
+    def _save_span(self, step: int, attrs: Dict):
+        """``flash.save``: one span a save call.  A synchronous save
+        made from inside an asynchronous one (small state, a fallback)
+        is that call's, not a second save."""
+        live = trace.current_span()
+        if live is not None and live.name == "flash.save":
+            yield live
+            return
+        with trace.span(
+            "flash.save", attrs={"step": int(step), **attrs}
+        ) as sp:
+            yield sp
+
     def _save_to_memory_traced(
         self,
         step: int,
         state: Any,
         extras: Optional[Dict],
         block_on_busy: bool,
+        save_span,
     ) -> float:
         from dlrover_tpu import chaos
 
         chaos.point("flash.save", step=step)  # exception/delay kinds
         t0 = time.time()
+        if "outcome" not in save_span.attrs:
+            save_span.set_attr("outcome", "sync")
         if not block_on_busy:
             # cheap skip probe: an in-process stager mid-stream, or the
             # agent's saver reading the buffer, must not stall a plain
@@ -535,26 +555,32 @@ class CheckpointEngine:
                     "skip memory snapshot step=%d: stager/saver holds "
                     "the buffer", step,
                 )
+                save_span.set_attr("outcome", "skipped")
                 self._replicate()
                 return 0.0
         self._ensure_registered()
-        from dlrover_tpu.timer import get_timer
-
-        timer = get_timer()
-        with timer.span("ckpt_device_to_host", timer.KIND_CKPT):
-            leaves = snapshot.extract_host_shards(state)
-        # Re-acquire for the write.  A plain memory save must never
-        # stall the training loop, so it skips if the stager or saver
-        # won the buffer between the probe above and here; only explicit
-        # storage saves block (bounded).
+        counters = snapshot.StageCounters()
         written = False
-        with self._buffer_write_lock(
-            self._lock_timeout_s if block_on_busy else None
-        ) as held:
-            if held:
-                with timer.span("ckpt_shm_write", timer.KIND_CKPT):
-                    snapshot.write_snapshot(self._shm, step, leaves, extras)
-                written = True
+        with trace.span("flash.stage", attrs={"step": int(step)}) as sp:
+            leaves = snapshot.extract_host_shards(state, counters=counters)
+            # Re-acquire for the write.  A plain memory save must never
+            # stall the training loop, so it skips if the stager or
+            # saver won the buffer between the probe above and here;
+            # only explicit storage saves block (bounded).
+            t_lock = time.perf_counter()
+            with self._buffer_write_lock(
+                self._lock_timeout_s if block_on_busy else None
+            ) as held:
+                lock_wait_s = time.perf_counter() - t_lock
+                if held:
+                    snapshot.write_snapshot(
+                        self._shm, step, leaves, extras, counters=counters
+                    )
+                    written = True
+            sp.set_attrs({
+                **counters.as_attrs(), "lock_wait_s": round(lock_wait_s, 6),
+            })
+        save_span.set_attr("bytes", counters.bytes)
         if not written:
             # writing anyway would tear the snapshot the saver is reading
             logger.log(
@@ -562,6 +588,7 @@ class CheckpointEngine:
                 "could not acquire ckpt buffer for step %d; snapshot skipped",
                 step,
             )
+            save_span.set_attr("outcome", "skipped")
             self._replicate()
             return -1.0
         self.latest_memory_step = step
@@ -693,16 +720,33 @@ class CheckpointEngine:
         return total
 
     def _async_save(self, step, state, extras, persist: bool) -> float:
+        nbytes = self._local_state_nbytes(state)
+        with self._save_span(step, {
+            "async": True, "storage": persist, "bytes": nbytes,
+        }) as sp:
+            return self._async_save_traced(
+                step, state, extras, persist, nbytes, sp
+            )
+
+    def _async_save_traced(
+        self, step, state, extras, persist: bool, nbytes: int, save_span
+    ) -> float:
         import jax
         import jax.numpy as jnp
 
-        t0 = time.time()
-        if self._local_state_nbytes(state) <= self._async_min_bytes:
-            # small state: sync staging is ~free and leaves no window
-            # where a crash right after save() loses the snapshot
+        def sync_save(outcome: str, block_on_busy: bool = False) -> float:
+            save_span.set_attr("outcome", outcome)
             if persist:
                 return self.save_to_storage(step, state, extras)
-            return self.save_to_memory(step, state, extras)
+            return self.save_to_memory(
+                step, state, extras, block_on_busy=block_on_busy
+            )
+
+        t0 = time.time()
+        if nbytes <= self._async_min_bytes:
+            # small state: sync staging is ~free and leaves no window
+            # where a crash right after save() loses the snapshot
+            return sync_save("sync")
         # HBM accounting: never dispatch a second on-device state copy
         # while one is still live (queued or staging pre-extraction).  A
         # newer snapshot must NEVER lose to an older in-flight one — the
@@ -716,26 +760,30 @@ class CheckpointEngine:
         # faster than staging drains, and a skip would age the recovery
         # point without bound.
         sync_fallback = False
-        # Not under _copy_cv: freeing the queued copy runs _on_copy_freed,
-        # which locks _copy_cv from under the stager's own lock — taking
-        # the two locks here in the opposite order would deadlock against
-        # the stager thread's box.free().  Storage saves supersede a
-        # queued memory item too: its purpose is subsumed by the same-or-
-        # newer shm write, and freeing it hands us the slot instantly
-        # instead of waiting out its throttled extraction.
-        if self._live_copies > 0:
-            self._stager.drop_queued_memory()
-        with self._copy_cv:
+        with trace.span(
+            "flash.save.slot_wait", attrs={"live_copies": self._live_copies}
+        ):
+            # Not under _copy_cv: freeing the queued copy runs
+            # _on_copy_freed, which locks _copy_cv from under the
+            # stager's own lock — taking the two locks here in the
+            # opposite order would deadlock against the stager thread's
+            # box.free().  Storage saves supersede a queued memory item
+            # too: its purpose is subsumed by the same-or-newer shm
+            # write, and freeing it hands us the slot instantly instead
+            # of waiting out its throttled extraction.
             if self._live_copies > 0:
-                deadline = t0 + self._slot_wait_s
-                while self._live_copies > 0:
-                    left = deadline - time.time()
-                    if left <= 0:
-                        break
-                    self._copy_cv.wait(left)
-                sync_fallback = self._live_copies > 0
-            if not sync_fallback:
-                self._live_copies += 1
+                self._stager.drop_queued_memory()
+            with self._copy_cv:
+                if self._live_copies > 0:
+                    deadline = t0 + self._slot_wait_s
+                    while self._live_copies > 0:
+                        left = deadline - time.time()
+                        if left <= 0:
+                            break
+                        self._copy_cv.wait(left)
+                    sync_fallback = self._live_copies > 0
+                if not sync_fallback:
+                    self._live_copies += 1
         if sync_fallback:
             # NOT under the cv: the sync save takes minutes and the
             # stager must still be able to report its copy freed
@@ -748,14 +796,10 @@ class CheckpointEngine:
                 TrainerEvents.CKPT_SYNC_FALLBACK,
                 {"step": int(step), "storage": persist},
             )
-            if persist:
-                return self.save_to_storage(step, state, extras)
             # block_on_busy: the fallback exists to GUARANTEE the
             # recovery point advances; a skippable save here would
             # re-open the silent-staleness hole
-            return self.save_to_memory(
-                step, state, extras, block_on_busy=True
-            )
+            return sync_save("sync_fallback", block_on_busy=True)
         cast_to = None
         if self._snapshot_dtype == "bf16":
             cast_to = jnp.bfloat16
@@ -770,7 +814,9 @@ class CheckpointEngine:
             return jnp.copy(a)
 
         try:
-            snap = jax.tree.map(_snapshot_copy, state)
+            with trace.span("flash.save.device_copy") as sp:
+                snap = jax.tree.map(_snapshot_copy, state)
+                sp.set_attr("leaves", len(jax.tree.leaves(snap)))
         except Exception as e:  # noqa: BLE001 - HBM pressure, backend quirks
             self._on_copy_freed()
             logger.warning(
@@ -781,17 +827,19 @@ class CheckpointEngine:
                 {"step": int(step), "storage": persist,
                  "reason": "device-copy-failed"},
             )
+            return sync_save("sync_fallback")
+        with trace.span("flash.save.submit") as sp:
+            box = _DeviceCopy(
+                snap, self._on_copy_freed, ctx=save_span.context()
+            )
+            del snap
             if persist:
-                return self.save_to_storage(step, state, extras)
-            return self.save_to_memory(step, state, extras)
-        box = _DeviceCopy(snap, self._on_copy_freed)
-        del snap
-        if persist:
-            with self._persist_mu:
-                self._persist_requested = max(
-                    self._persist_requested, int(step)
-                )
-        submitted = self._stager.submit(int(step), box, extras, persist)
+                with self._persist_mu:
+                    self._persist_requested = max(
+                        self._persist_requested, int(step)
+                    )
+            submitted = self._stager.submit(int(step), box, extras, persist)
+            sp.set_attr("result", submitted)
         if submitted is not True:
             box.free()
             if submitted == "busy":
@@ -803,17 +851,15 @@ class CheckpointEngine:
                     "async %s save step=%d: stager busy; sync fallback",
                     "storage" if persist else "memory", step,
                 )
-                if persist:
-                    return self.save_to_storage(step, state, extras)
-                return self.save_to_memory(
-                    step, state, extras, block_on_busy=True
-                )
+                return sync_save("sync_fallback", block_on_busy=True)
             # stager stopped (engine closing): same contract as the sync
             # path's skip — the caller must not believe this step is safe
             logger.warning(
                 "async snapshot step=%d dropped: stager stopped", step
             )
+            save_span.set_attr("outcome", "dropped")
             return -1.0
+        save_span.set_attr("outcome", "async")
         blocked = time.time() - t0
         self._events.instant(
             TrainerEvents.CKPT_SAVE,
@@ -832,11 +878,42 @@ class CheckpointEngine:
         marks it dirty for lock-free readers), and each paced D2H chunk
         lands directly at its final offset, releasing its share of the
         on-device copy as it goes.  Two-phase (opt-out): host-stage the
-        whole copy first, then lock briefly for one packed write."""
-        self._ensure_registered()
-        from dlrover_tpu.timer import get_timer
+        whole copy first, then lock briefly for one packed write.
 
-        timer = get_timer()
+        All of it is one ``flash.stage`` span, child of the ``flash.save``
+        that submitted it (``box.ctx``), which carries at its close what the
+        stage counted: where the seconds between the call and the
+        landing went."""
+        from dlrover_tpu.observability import jitscope
+
+        if jitscope.enabled():
+            jitscope.install()
+        compiled0 = jitscope._thread_counters()
+        counters = snapshot.StageCounters()
+        pacer = snapshot.StagePacer()
+        with trace.span(
+            "flash.stage", attrs={"step": int(step)}, parent=box.ctx
+        ) as sp:
+            try:
+                self._stage_snapshot_traced(
+                    step, box, extras, persist, counters, pacer, sp
+                )
+            finally:
+                compile_s, hits, misses = (
+                    b - a for a, b in
+                    zip(compiled0, jitscope._thread_counters())
+                )
+                sp.set_attrs({
+                    **counters.as_attrs(),
+                    "compile_s": round(compile_s, 6),
+                    "compiles": hits + misses,
+                    "pacer": pacer.summary(),
+                })
+
+    def _stage_snapshot_traced(
+        self, step, box, extras, persist, counters, pacer, sp
+    ):
+        self._ensure_registered()
         snap = box.take()
         if self._stream_staging:
             # plan only (no transfer): refs move into the leaves list so
@@ -844,11 +921,11 @@ class CheckpointEngine:
             leaves = snapshot.plan_shards(snap)
             del snap
         else:
-            with timer.span("ckpt_device_to_host", timer.KIND_CKPT):
-                # throttled: bound the device-queue transfer backlog so
-                # concurrent train steps wait behind one leaf, not the
-                # state
-                leaves = snapshot.extract_host_shards(snap, throttled=True)
+            # throttled: bound the device-queue transfer backlog so
+            # concurrent train steps wait behind one leaf, not the state
+            leaves = snapshot.extract_host_shards(
+                snap, throttled=True, pacer=pacer, counters=counters
+            )
             del snap
             # the on-device copy is host-staged: release the HBM
             # accounting slot so the next async save may dispatch while
@@ -856,7 +933,11 @@ class CheckpointEngine:
             box.free()
         persist_step = step if persist else None
         staged = False
+        t_lock = time.perf_counter()
         with self._buffer_write_lock(self._lock_timeout_s) as held:
+            sp.set_attr(
+                "lock_wait_s", round(time.perf_counter() - t_lock, 6)
+            )
             if held:
                 try:
                     meta = snapshot.read_snapshot_meta(self._shm)
@@ -879,25 +960,19 @@ class CheckpointEngine:
                         step = int(meta["step"])
                     elif not (meta and meta["step"] == step):
                         if self._stream_staging:
-                            pacer = snapshot.StagePacer()
                             pacer.clock.staging_started()
                             try:
-                                with timer.span(
-                                    "ckpt_stream_stage", timer.KIND_CKPT
-                                ):
-                                    snapshot.stream_snapshot(
-                                        self._shm, step, leaves, extras,
-                                        pacer=pacer,
-                                    )
+                                snapshot.stream_snapshot(
+                                    self._shm, step, leaves, extras,
+                                    pacer=pacer, counters=counters,
+                                )
                             finally:
                                 pacer.clock.staging_finished()
                         else:
-                            with timer.span(
-                                "ckpt_shm_write", timer.KIND_CKPT
-                            ):
-                                snapshot.write_snapshot(
-                                    self._shm, step, leaves, extras
-                                )
+                            snapshot.write_snapshot(
+                                self._shm, step, leaves, extras,
+                                counters=counters,
+                            )
                     staged = True
                 finally:
                     box.free()
@@ -1113,7 +1188,6 @@ class CheckpointEngine:
         COLLECTIVELY (allgather of each process's feasible step) — a mixed
         restore would silently diverge the replicas."""
         from dlrover_tpu.observability import metrics as obs_metrics
-        from dlrover_tpu.observability import trace
 
         t0, step_out = time.monotonic(), -1
         try:
@@ -1198,12 +1272,7 @@ class CheckpointEngine:
         try:
             from jax.experimental import multihost_utils
 
-            from dlrover_tpu.timer import get_timer
-
-            timer = get_timer()
-            with timer.span(
-                "ckpt_restore_agreement", timer.KIND_COLLECTIVE
-            ):
+            with trace.span("flash.restore.agreement"):
                 steps = np.asarray(
                     multihost_utils.process_allgather(
                         np.asarray([step], dtype=np.int64)

@@ -26,8 +26,8 @@ Two write paths share the format:
   BEFORE any transfer, then each paced D2H chunk lands directly at its
   final shm offset.  No intermediate full host copy exists, so host peak
   RSS is bounded by shm + one chunk instead of 2x state, and each chunk
-  costs exactly ONE host-side copy (the zero-copy invariant,
-  instrumented via ``set_copy_observer``).
+  costs exactly ONE host-side copy (the zero-copy invariant, counted
+  by ``StageCounters``: ``host_copies == chunks``).
 
 Both paths run the seqlock-style generation commit: the generation word
 is bumped to ODD before any byte of meta/payload changes and bumped back
@@ -70,6 +70,60 @@ _DEFAULT_CHUNK = 8 << 20
 _MIN_BASELINE_S = 0.005
 
 
+class StageCounters:
+    """What one staging of a snapshot did, counted where it happens: the
+    attributes of the ``flash.stage`` span, and what ``staging_drill``
+    and the tests read when they stage without an engine.  Chunks are
+    counted, not spanned: a save has hundreds to thousands of them.
+
+    ``host_copies`` counts every host-side buffer copy: the streaming
+    path's promise is one per chunk, and any refactor that slips an
+    intermediate host buffer back in still yields bit-exact snapshots,
+    so a tier-1 test holds ``host_copies == chunks`` there."""
+
+    __slots__ = (
+        "bytes", "chunk_sizes", "host_copies", "host_copy_bytes",
+        "pace_sleep_s", "slice_s", "d2h_wait_s", "shm_copy_s",
+    )
+
+    def __init__(self):
+        self.bytes = 0
+        self.chunk_sizes: List[int] = []
+        self.host_copies = 0
+        self.host_copy_bytes = 0
+        self.pace_sleep_s = 0.0  # StagePacer.gate's sleeps
+        self.slice_s = 0.0  # dispatch of slice_in_dim / reshape
+        self.d2h_wait_s = 0.0  # blocked in np.asarray(device array)
+        self.shm_copy_s = 0.0  # memcpy into the segment
+
+    def chunk(self, nbytes: int) -> None:
+        self.bytes += nbytes
+        self.chunk_sizes.append(nbytes)
+
+    def host_copy(self, nbytes: int) -> None:
+        self.host_copies += 1
+        self.host_copy_bytes += nbytes
+
+    @property
+    def chunks(self) -> int:
+        return len(self.chunk_sizes)
+
+    def as_attrs(self) -> Dict[str, Any]:
+        sizes = sorted(self.chunk_sizes)
+        return {
+            "bytes": self.bytes,
+            "chunks": len(sizes),
+            "chunk_bytes_min": sizes[0] if sizes else 0,
+            "chunk_bytes_median": sizes[len(sizes) // 2] if sizes else 0,
+            "chunk_bytes_max": sizes[-1] if sizes else 0,
+            "host_copies": self.host_copies,
+            "pace_sleep_s": round(self.pace_sleep_s, 6),
+            "slice_s": round(self.slice_s, 6),
+            "d2h_wait_s": round(self.d2h_wait_s, 6),
+            "shm_copy_s": round(self.shm_copy_s, 6),
+        }
+
+
 class StagePacer:
     """Closed-loop throttle for background device->host staging.
 
@@ -103,6 +157,7 @@ class StagePacer:
         self.sleep_ratio = 0.0  # sleep = ratio * last chunk transfer time
         self.best_bw = 0.0  # bytes/s, max observed (robust to overhead)
         self.last_chunk_s = 0.0
+        self.slept_s = 0.0  # what gate() has slept, summed
         self._mark = time.monotonic()
         self._calibrated = False
 
@@ -176,9 +231,7 @@ class StagePacer:
         sleep and adapts chunking to the latest observed steps."""
         if self.manual_pace > 0:
             if self.last_chunk_s > 0:
-                time.sleep(
-                    min(30.0, self.manual_pace * self.last_chunk_s)
-                )
+                self._sleep(min(30.0, self.manual_pace * self.last_chunk_s))
             return
         if self.clock.idle():
             # nothing is training: drain at full speed
@@ -187,16 +240,34 @@ class StagePacer:
             return
         self._adjust()
         if self.sleep_ratio > 0 and self.last_chunk_s > 0:
-            time.sleep(min(10.0, self.sleep_ratio * self.last_chunk_s))
+            self._sleep(min(10.0, self.sleep_ratio * self.last_chunk_s))
+
+    def _sleep(self, seconds: float) -> None:
+        t0 = time.perf_counter()
+        time.sleep(seconds)
+        self.slept_s += time.perf_counter() - t0
+
+    def summary(self) -> Dict[str, Any]:
+        """Where the control loop stands: the ``pacer`` attribute of the
+        ``flash.stage`` span."""
+        return {
+            "best_bw": round(self.best_bw, 1),
+            "baseline_step_s": self.clock.baseline(),
+            "chunk_bytes": self.chunk_bytes,
+            "sleep_ratio": round(self.sleep_ratio, 4),
+        }
 
 
-def _chunked_to_host(arr, pacer: StagePacer) -> np.ndarray:
+def _chunked_to_host(
+    arr, pacer: StagePacer, counters: Optional[StageCounters] = None,
+) -> np.ndarray:
     """Device->host copy of one shard in pacer-sized chunks.
 
     Chunks are on-device slices along the widest axis; each slice is a
     tiny HBM-to-HBM copy, so the device queue is occupied in chunk-sized
     grains and a train step dispatched mid-staging waits behind at most
     one chunk instead of the whole shard."""
+    c = counters if counters is not None else StageCounters()
     np_dtype = np.dtype(arr.dtype)
     nbytes = int(np.prod(arr.shape)) * np_dtype.itemsize if arr.shape else (
         np_dtype.itemsize
@@ -205,11 +276,13 @@ def _chunked_to_host(arr, pacer: StagePacer) -> np.ndarray:
         pacer.gate()
         t0 = time.perf_counter()
         out = np.asarray(arr)
-        pacer.note_transfer(nbytes, time.perf_counter() - t0)
-        # no host_copy note: the D2H lands DIRECTLY in the returned
-        # array — unlike the chunked branch below, no intermediate
-        # host buffer exists here (transfers are not host-side copies)
-        _note("chunk", nbytes)
+        waited = time.perf_counter() - t0
+        pacer.note_transfer(nbytes, waited)
+        c.d2h_wait_s += waited
+        # no host copy: the D2H lands DIRECTLY in the returned array —
+        # unlike the chunked branch below, no intermediate host buffer
+        # exists here (transfers are not host-side copies)
+        c.chunk(nbytes)
         return out
     axis = int(np.argmax(arr.shape))
     n_rows = arr.shape[axis]
@@ -223,38 +296,23 @@ def _chunked_to_host(arr, pacer: StagePacer) -> np.ndarray:
         pacer.gate()
         import jax.lax
 
-        chunk = jax.lax.slice_in_dim(arr, start, stop, axis=axis)
         t0 = time.perf_counter()
+        chunk = jax.lax.slice_in_dim(arr, start, stop, axis=axis)
+        t1 = time.perf_counter()
         host = np.asarray(chunk)
-        pacer.note_transfer(
-            (stop - start) * row_bytes, time.perf_counter() - t0
-        )
-        _note("chunk", (stop - start) * row_bytes)
+        t2 = time.perf_counter()
+        pacer.note_transfer((stop - start) * row_bytes, t2 - t1)
+        c.slice_s += t1 - t0
+        c.d2h_wait_s += t2 - t1
+        c.chunk((stop - start) * row_bytes)
         # the intermediate host materialization the streaming path avoids
-        _note("host_copy", (stop - start) * row_bytes)
+        c.host_copy((stop - start) * row_bytes)
         dst[start:stop] = np.moveaxis(host, axis, 0)
         start = stop
     return out
 
 
 from dlrover_tpu.common.pytree import path_str as _path_str  # noqa: E402
-
-
-# -- instrumentation hooks ---------------------------------------------------
-#
-# The zero-copy invariant of the streaming path ("at most one host-side
-# copy per shard chunk") is cheap to break silently — any refactor that
-# re-introduces an intermediate host buffer still produces bit-exact
-# snapshots, just with 2x the memory traffic.  Every host-side buffer
-# copy in this module therefore reports through the observer, and a
-# tier-1 test asserts copies == chunks on the streaming path.
-_copy_observer: Optional[Callable[[str, int], None]] = None
-
-
-def set_copy_observer(fn: Optional[Callable[[str, int], None]]) -> None:
-    """``fn(event, nbytes)`` with event in {"chunk", "host_copy"}."""
-    global _copy_observer
-    _copy_observer = fn
 
 
 def set_stream_fault(fn: Optional[Callable[[int], None]]) -> None:
@@ -278,11 +336,6 @@ def set_stream_fault(fn: Optional[Callable[[int], None]]) -> None:
                 callback=lambda chunk=0: fn(chunk),
             )
         )
-
-
-def _note(event: str, nbytes: int) -> None:
-    if _copy_observer is not None:
-        _copy_observer(event, nbytes)
 
 
 def _enumerate_shards(state: Any) -> List[Dict]:
@@ -350,6 +403,7 @@ def _enumerate_shards(state: Any) -> List[Dict]:
 def extract_host_shards(
     state: Any, throttled: bool = False,
     pacer: Optional["StagePacer"] = None,
+    counters: Optional[StageCounters] = None,
 ) -> List[Dict]:
     """Flatten a pytree of (possibly sharded) jax Arrays into this
     process's shard list.
@@ -391,17 +445,22 @@ def extract_host_shards(
     ]
 
     # phase 2: device->host with the chosen pipelining policy
+    c = counters if counters is not None else StageCounters()
     if throttled:
         pacer = pacer or StagePacer()
+        slept = pacer.slept_s
         pacer.clock.staging_started()
         try:
             for leaf in leaves:
                 for shard in leaf["shards"]:
                     if isinstance(shard["data"], np.ndarray):
                         continue
-                    shard["data"] = _chunked_to_host(shard["data"], pacer)
+                    shard["data"] = _chunked_to_host(
+                        shard["data"], pacer, c
+                    )
         finally:
             pacer.clock.staging_finished()
+            c.pace_sleep_s += pacer.slept_s - slept
         return leaves
 
     def _kick(arr) -> bool:
@@ -415,12 +474,15 @@ def extract_host_shards(
         if not _kick(arr):
             break
 
+    t0 = time.perf_counter()
     for leaf in leaves:
         for shard in leaf["shards"]:
             data = shard["data"]
             if isinstance(data, np.ndarray):
                 continue
             shard["data"] = np.asarray(data)
+            c.chunk(shard["data"].nbytes)
+    c.d2h_wait_s += time.perf_counter() - t0
     return leaves
 
 
@@ -559,7 +621,7 @@ byte_view = _byte_view
 
 def _stream_shard(
     buf, dst_off: int, arr, pacer: "StagePacer",
-    chunk_override: int, chunk_counter: List[int],
+    chunk_override: int, c: StageCounters,
 ) -> None:
     """Stream one shard into its final shm offset, chunk by chunk.
 
@@ -578,11 +640,12 @@ def _stream_shard(
             n = min(max(1, chunk_override or pacer.chunk_bytes),
                     nbytes - pos)
             pacer.gate()
+            t0 = time.perf_counter()
             buf[dst_off + pos : dst_off + pos + n] = view[pos : pos + n]
-            _note("chunk", n)
-            _note("host_copy", n)
-            chunk_counter[0] += 1
-            _chaos_point("snapshot.stream_chunk", chunk=chunk_counter[0] - 1)
+            c.shm_copy_s += time.perf_counter() - t0
+            c.chunk(n)
+            c.host_copy(n)
+            _chaos_point("snapshot.stream_chunk", chunk=c.chunks - 1)
             pos += n
         return
 
@@ -600,12 +663,14 @@ def _stream_shard(
     def _land(dev, off: int, n: int) -> None:
         t0 = time.perf_counter()
         host = np.asarray(dev)
-        pacer.note_transfer(n, time.perf_counter() - t0)
+        t1 = time.perf_counter()
+        pacer.note_transfer(n, t1 - t0)
         buf[off : off + n] = _byte_view(host)
-        _note("chunk", n)
-        _note("host_copy", n)
-        chunk_counter[0] += 1
-        _chaos_point("snapshot.stream_chunk", chunk=chunk_counter[0] - 1)
+        c.d2h_wait_s += t1 - t0
+        c.shm_copy_s += time.perf_counter() - t1
+        c.chunk(n)
+        c.host_copy(n)
+        _chaos_point("snapshot.stream_chunk", chunk=c.chunks - 1)
 
     chunk_bytes = chunk_override or pacer.chunk_bytes
     if not arr.shape or nbytes <= chunk_bytes or nbytes <= 2 * _MIN_CHUNK:
@@ -622,7 +687,9 @@ def _stream_shard(
         # device: a row-major reshape of a contiguous array is a
         # metadata-level bitcast for XLA, and element granularity makes
         # every chunk size reachable.
+        t0 = time.perf_counter()
         arr = jax.numpy.reshape(arr, (-1,))
+        c.slice_s += time.perf_counter() - t0
         n_rows = int(arr.shape[0])
         row_bytes = max(1, nbytes // n_rows)
     pending: Optional[Tuple[Any, int, int]] = None
@@ -632,11 +699,13 @@ def _stream_shard(
         rows = max(1, int(chunk_bytes // row_bytes))
         stop = min(n_rows, start + rows)
         pacer.gate()
+        t0 = time.perf_counter()
         dev = (
             arr if (start == 0 and stop == n_rows)
             else jax.lax.slice_in_dim(arr, start, stop, axis=0)
         )
         _kick(dev)
+        c.slice_s += time.perf_counter() - t0
         if pending is not None:
             _land(*pending)
         pending = (dev, dst_off + start * row_bytes,
@@ -654,7 +723,8 @@ def stream_snapshot(
     pacer: Optional["StagePacer"] = None,
     chunk_bytes: int = 0,
     release_shards: bool = True,
-) -> int:
+    counters: Optional[StageCounters] = None,
+) -> StageCounters:
     """Streaming zero-copy write: precomputed layout, paced D2H chunks
     landing directly at their final shm offsets, seqlock commit.
 
@@ -662,10 +732,17 @@ def stream_snapshot(
     place).  ``release_shards`` drops each shard's device reference as
     soon as its bytes land, so the async-save HBM overhead shrinks as
     staging progresses instead of persisting until the end.  Returns
-    total segment bytes.  Raising mid-stream (fault, kill) leaves the
-    generation dirty — readers fall back to storage candidates."""
+    the counters of what it did (``counters`` where given, so that a
+    caller's span and an engine-less drill read the same numbers), with
+    one ``flash.stage.shard`` span a placement.  Raising mid-stream
+    (fault, kill) leaves the generation dirty — readers fall back to
+    storage candidates."""
+    from dlrover_tpu.observability import trace
+
+    c = counters if counters is not None else StageCounters()
     if pacer is None:
         pacer = StagePacer()
+    slept = pacer.slept_s
     if not chunk_bytes:
         chunk_bytes = envs.get_int("DLROVER_TPU_STREAM_CHUNK_BYTES")
     meta_bytes, placements, total = compute_layout(step, leaves, extras)
@@ -674,18 +751,25 @@ def stream_snapshot(
     gen = _begin_write(buf)
     buf[_META_OFF : _META_OFF + len(meta_bytes)] = meta_bytes
     base = _META_OFF + len(meta_bytes)
-    chunk_counter = [0]
-    for offset, shard in placements:
-        _stream_shard(
-            buf, base + offset, shard["data"], pacer, chunk_bytes,
-            chunk_counter,
-        )
-        if release_shards:
-            # free the device chunk as soon as it has landed: the HBM
-            # held by the async-save copy drains with staging progress
-            shard["data"] = None
+    # placements are in storage order: leaf by leaf, shard by shard
+    paths = [leaf["path"] for leaf in leaves for _ in leaf["shards"]]
+    try:
+        for (offset, shard), path in zip(placements, paths):
+            with trace.span("flash.stage.shard", attrs={"path": path}) as sp:
+                bytes0, chunks0 = c.bytes, c.chunks
+                _stream_shard(
+                    buf, base + offset, shard["data"], pacer, chunk_bytes, c,
+                )
+                sp.set_attr("bytes", c.bytes - bytes0)
+                sp.set_attr("chunks", c.chunks - chunks0)
+            if release_shards:
+                # free the device chunk as soon as it has landed: the HBM
+                # held by the async-save copy drains with staging progress
+                shard["data"] = None
+    finally:
+        c.pace_sleep_s += pacer.slept_s - slept
     _commit_write(buf, gen, len(meta_bytes))
-    return total
+    return c
 
 
 def write_snapshot(
@@ -693,6 +777,7 @@ def write_snapshot(
     step: int,
     leaves: List[Dict],
     extras: Optional[Dict] = None,
+    counters: Optional[StageCounters] = None,
 ) -> int:
     """Two-phase pack of host-staged leaves into shm; returns total
     bytes used.  (The streaming path is ``plan_shards`` +
@@ -718,13 +803,16 @@ def write_snapshot(
     ]
     from dlrover_tpu.common import fastcopy
 
+    t0 = time.perf_counter()
     if not fastcopy.copy_into(buf, flat):
         # no native copier (or batch too small for threads to pay)
         for offset, data in flat:
             view = memoryview(data).cast("B")
             buf[offset : offset + data.nbytes] = view
-    for _, data in flat:
-        _note("host_copy", data.nbytes)
+    if counters is not None:
+        counters.shm_copy_s += time.perf_counter() - t0
+        for _, data in flat:
+            counters.host_copy(data.nbytes)
     # commit: only a fully-written snapshot ever becomes readable
     _commit_write(buf, gen, len(meta_bytes))
     return total

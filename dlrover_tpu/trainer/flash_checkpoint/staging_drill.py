@@ -154,17 +154,10 @@ def run_role(role: str) -> Dict:
     payload = sum(int(a.size) * 4 for a in state.values())
     expect = {k: np.asarray(v) for k, v in state.items()}
 
-    # count EVENTS and BYTES: the two-phase path's second full memcpy
+    # EVENTS and BYTES both: the two-phase path's second full memcpy
     # (write_snapshot) is one event per SHARD but a whole shard's bytes,
     # so the honest copies-per-chunk ratio is byte-weighted
-    counters = {"chunk": 0, "host_copy": 0}
-    nbytes_by = {"chunk": 0, "host_copy": 0}
-
-    def observer(event, nbytes):
-        counters[event] += 1
-        nbytes_by[event] += nbytes
-
-    snapshot.set_copy_observer(observer)
+    staged = snapshot.StageCounters()
     clock = get_step_clock()
     clock.reset()
     # calm baseline: a few steps before staging starts
@@ -197,15 +190,16 @@ def run_role(role: str) -> Dict:
                 if role == "two_phase":
                     t_d2h = time.perf_counter()
                     leaves = snapshot.extract_host_shards(
-                        state, throttled=True, pacer=pacer
+                        state, throttled=True, pacer=pacer, counters=staged
                     )
                     d2h_s = time.perf_counter() - t_d2h
-                    snapshot.write_snapshot(shm, 1, leaves)
+                    snapshot.write_snapshot(shm, 1, leaves, counters=staged)
                 else:
                     leaves = snapshot.plan_shards(state)
                     snapshot.stream_snapshot(
                         shm, 1, leaves, pacer=pacer,
                         chunk_bytes=_chunk_bytes(), release_shards=False,
+                        counters=staged,
                     )
                     d2h_s = None  # fused with the shm write by design
             finally:
@@ -231,7 +225,6 @@ def run_role(role: str) -> Dict:
                 roundtrip_ok = False
     finally:
         stop.set()
-        snapshot.set_copy_observer(None)
         shm.unlink()
 
     olap = sorted(overlap) if overlap else [base_step_s]
@@ -241,16 +234,16 @@ def run_role(role: str) -> Dict:
         "staging_wall_s": round(wall_s, 3),
         "staging_gbps": round(payload / 1e9 / max(wall_s, 1e-9), 3),
         "host_peak_rss_delta_mb": round(rss.peak_delta / (1 << 20), 1),
-        "chunks": counters["chunk"],
-        "host_copies": counters["host_copy"],
+        "chunks": staged.chunks,
+        "host_copies": staged.host_copies,
         "host_copies_per_chunk": round(
-            counters["host_copy"] / max(counters["chunk"], 1), 2
+            staged.host_copies / max(staged.chunks, 1), 2
         ),
         # byte-weighted: total host-side bytes copied per byte staged —
         # the metric the zero-copy claim is actually about (2.0 for the
         # two-phase intermediate+memcpy, 1.0 for streaming)
         "host_copy_bytes_x": round(
-            nbytes_by["host_copy"] / max(nbytes_by["chunk"], 1), 2
+            staged.host_copy_bytes / max(staged.bytes, 1), 2
         ),
         "step_s_base": round(base_step_s, 4),
         "step_s_during_staging": round(overlap_med, 4),

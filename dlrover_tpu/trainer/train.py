@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from dlrover_tpu.observability import trace
 from dlrover_tpu.parallel import collectives
 from dlrover_tpu.parallel.collectives import GradSyncPolicy
 from dlrover_tpu.parallel.sharding import DEFAULT_LOGICAL_RULES
@@ -211,6 +212,7 @@ class Trainer:
 
                 logger.debug("comm probe unavailable: %s", e)
         self._steps_done = 0
+        self._step_calls = 0  # the ``step`` of the ``trainer.step`` span
         # recorder-feed step counter: _steps_done only advances when the
         # native timer is attached, but the flight-recorder ring and the
         # per-rank digest file must count steps on EVERY loop shape
@@ -1019,11 +1021,23 @@ class Trainer:
         with self.mesh:
             return self._jit_step.lower(state, batch)
 
-    def _dispatch(self, state, batch):
-        with self.mesh:
+    def _dispatch(self, state, batch, compiled: bool = False):
+        # ``compiled``: the first call of a program, which compiles it
+        with trace.span(
+            "trainer.step.dispatch", attrs={"compiled": compiled}
+        ), self.mesh:
             return self._jit_step(state, batch)
 
     def train_step(self, state: TrainState, batch):
+        """One optimizer step.  All of it is the span ``trainer.step``
+        (``step``: calls of this trainer so far); its child
+        ``trainer.step.dispatch`` is the jitted call, and the rest is
+        the host's bookkeeping around it."""
+        self._step_calls += 1
+        with trace.span("trainer.step", attrs={"step": self._step_calls}):
+            return self._step_on_host(state, batch)
+
+    def _step_on_host(self, state: TrainState, batch):
         import time as _time
 
         if self._pending_reshard is not None:
@@ -1082,7 +1096,7 @@ class Trainer:
                 from dlrover_tpu.utils.timing import hard_block
 
                 compile_t0 = _time.time()
-                result = self._dispatch(state, batch)
+                result = self._dispatch(state, batch, compiled=True)
                 hard_block(result)
             try:
                 from dlrover_tpu.observability import goodput
@@ -1280,7 +1294,10 @@ class Trainer:
     def shard_batch(self, batch):
         from dlrover_tpu.parallel.sharding import shard_batch
 
-        return shard_batch(self.mesh, batch, self.data_axes)
+        with trace.span("trainer.shard_batch", attrs={"bytes": sum(
+            getattr(x, "nbytes", 0) for x in jax.tree.leaves(batch)
+        )}):
+            return shard_batch(self.mesh, batch, self.data_axes)
 
     # -- elasticity --------------------------------------------------------
 

@@ -41,6 +41,7 @@ class TestRings:
 
     def test_kill_switch_makes_appends_noops(self, _isolate, monkeypatch):
         monkeypatch.setenv("DLROVER_TPU_RECORDER", "0")
+        _isolate.reset()  # the switch is read when the rings are built
         _isolate.record_event({"x": 1})
         _isolate.record_span({"name": "s"})
         _isolate.record_step(1, 0.5)
@@ -128,7 +129,8 @@ class TestFeeds:
     def test_finished_spans_feed_the_ring(self, _isolate):
         with trace.span("fed.op"):
             pass
-        assert any(r["name"] == "fed.op" for r in _isolate.spans)
+        assert any(t.name == "fed.op" for t in _isolate.spans)
+        assert any(r["name"] == "fed.op" for r in _isolate.span_records())
 
     def test_emitter_events_feed_the_ring(self, _isolate):
         from dlrover_tpu.training_event.emitter import Process

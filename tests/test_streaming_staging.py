@@ -133,25 +133,22 @@ class TestStreamSnapshot:
         memcpy into shm); any reintroduced intermediate host buffer
         shows up as copies > chunks."""
         state = _sharded_state()
-        counts = {"chunk": 0, "host_copy": 0}
-        snapshot.set_copy_observer(
-            lambda ev, n: counts.__setitem__(ev, counts[ev] + 1)
-        )
         shm = SharedMemoryBuffer(f"zc_{_scope()}")
         try:
             # tiny chunks: every shard streams in several chunks
-            snapshot.stream_snapshot(
+            counts = snapshot.stream_snapshot(
                 shm, 1, snapshot.plan_shards(state), chunk_bytes=1 << 10
             )
         finally:
-            snapshot.set_copy_observer(None)
             shm.unlink()
-        assert counts["chunk"] > len(jax.tree.leaves(state))
-        assert counts["host_copy"] == counts["chunk"], (
+        assert counts.chunks > len(jax.tree.leaves(state))
+        assert counts.host_copies == counts.chunks, (
             "streaming must cost exactly one host-side copy per chunk, "
-            f"got {counts['host_copy']} copies over {counts['chunk']} "
+            f"got {counts.host_copies} copies over {counts.chunks} "
             "chunks"
         )
+        assert counts.bytes == counts.host_copy_bytes == sum(
+            counts.chunk_sizes)
 
     def test_coarse_leading_dim_still_chunks(self):
         """A (1, big) shard must not stream as one giant unpaced
@@ -161,24 +158,19 @@ class TestStreamSnapshot:
         # yet unchunkable along axis 0 without the device flatten
         arr = jnp.arange(1 << 20, dtype=jnp.float32).reshape(1, 1 << 20)
         state = {"w": arr}
-        counts = {"chunk": 0, "host_copy": 0}
-        snapshot.set_copy_observer(
-            lambda ev, n: counts.__setitem__(ev, counts[ev] + 1)
-        )
         shm = SharedMemoryBuffer(f"coarse_{_scope()}")
         try:
-            snapshot.stream_snapshot(
+            counts = snapshot.stream_snapshot(
                 shm, 1, snapshot.plan_shards(state), chunk_bytes=1 << 18
             )
             meta, data = _read_all(shm)
             np.testing.assert_array_equal(data["w"], np.asarray(arr))
         finally:
-            snapshot.set_copy_observer(None)
             shm.unlink()
-        assert counts["chunk"] >= 8, (
-            f"coarse leading dim must still chunk, got {counts['chunk']}"
+        assert counts.chunks >= 8, (
+            f"coarse leading dim must still chunk, got {counts.chunks}"
         )
-        assert counts["host_copy"] == counts["chunk"]
+        assert counts.host_copies == counts.chunks
 
     def test_release_shards_drops_device_refs(self):
         state = _sharded_state()

@@ -98,6 +98,7 @@ class TestTraceContext:
 
     def test_disabled_tracing_is_noop(self, _isolate, monkeypatch):
         monkeypatch.setenv("DLROVER_TPU_TRACE", "0")
+        trace.seed_ids(1234)  # the switch is read once; this re-reads it
         with trace.span("x") as sp:
             assert sp is trace.NOOP_SPAN
             assert trace.current_traceparent() == ""
