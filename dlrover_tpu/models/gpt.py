@@ -3,7 +3,10 @@
 Parity target: the reference's canonical demo job is nanoGPT trained via
 ``dlrover-run`` (``examples/pytorch/nanogpt/train.py`` in the reference);
 this is its mesh-native equivalent, sharing the logical-axis vocabulary of
-the Llama family so the same sharding rules apply.
+the Llama family so the same sharding rules apply.  Attention is fused
+where it can be, as nanoGPT's is: ``ops.attention.causal_attention`` takes
+the FA2 kernel on a TPU at a shape the kernel runs and the reference core
+elsewhere; the configuration has no field for it.
 """
 
 import dataclasses
@@ -64,8 +67,6 @@ class Block(nn.Module):
             nn.DenseGeneral, dtype=cfg.dtype, param_dtype=cfg.param_dtype
         )
 
-        from dlrover_tpu.ops.attention import reference_attention
-
         h = ln(name="ln_1")(x)
         qkv = dense(
             features=(3, cfg.n_head, head_dim),
@@ -75,7 +76,7 @@ class Block(nn.Module):
             name="attn_qkv",
         )(h)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        att = reference_attention(q, k, v, mask)
+        att = self._attend(q, k, v, mask)
         att = dense(
             features=cfg.n_embd,
             axis=(-2, -1),
@@ -106,6 +107,14 @@ class Block(nn.Module):
         h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
         x = x + h
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+
+    def _attend(self, q, k, v, mask):
+        # a method of this name, as in models/llama.py: the device trace
+        # names the kernel's custom calls after it.  ``mask`` is the
+        # reference path's; the kernel's causal mask is positional
+        from dlrover_tpu.ops.attention import causal_attention
+
+        return causal_attention(q, k, v, mask)
 
 
 class _ScannedBlock(nn.Module):

@@ -7,13 +7,18 @@ families.  ``flash_attention`` is the Pallas TPU kernel
 reference.  Off the chip it raises unless the caller asks for the Pallas
 interpreter by argument; under a mesh of several devices it runs the kernel
 per shard through ``shard_map`` (a Mosaic kernel cannot be partitioned
-automatically).
+automatically).  ``causal_attention`` is the one place that chooses between
+the two for a model that has no opinion (the GPT family): from the backend
+and the shape, once, while the step is traced.
 """
 
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from dlrover_tpu.common.log import logger
+from dlrover_tpu.observability import trace
 
 
 def reference_attention(
@@ -122,3 +127,66 @@ def flash_attention(
         kernel, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,
     )(q, k, v)
+
+
+def attention_path(backend: str, seq_len: int, head_dim: int) -> str:
+    """``"flash"`` or ``"reference"``: the kernel wherever it can run, from
+    what the code can observe and nothing else."""
+    from dlrover_tpu.ops.pallas.flash_attention import kernel_takes
+
+    if backend == "tpu" and kernel_takes(seq_len, head_dim):
+        return "flash"
+    return "reference"
+
+
+#: ``attention.path`` records already made with no span open (a bare
+#: ``model.init``): one for each distinct reading, not one a layer
+_paths_noted_without_span = set()
+
+
+def _note_path(**attrs) -> None:
+    """One ``attention.path`` record for each trace of a step, and one
+    line in the log: an event on the span open while the step is traced
+    (``trainer.step.dispatch``), a span of its own where none is open.
+    The layers of one trace make the same reading; only the first is
+    kept.  Python runs this while tracing: it costs a step nothing."""
+    open_span = trace.current_span()
+    if open_span is not None:
+        if any(e["name"] == "attention.path" and e["attrs"] == attrs
+               for e in open_span.events):
+            return
+        open_span.add_event("attention.path", **attrs)
+    else:
+        key = tuple(attrs.items())
+        if key in _paths_noted_without_span:
+            return
+        _paths_noted_without_span.add(key)
+        with trace.span("attention.path", attrs=attrs):
+            pass
+    logger.info(
+        "attention.path %s", " ".join(f"{k}={v}" for k, v in attrs.items())
+    )
+
+
+def causal_attention(
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mask: jnp.ndarray
+) -> jnp.ndarray:
+    """Causal self-attention, q/k/v: [B, S, H, D]: the FA2 kernel on a TPU
+    at a shape it takes (no [B, H, S, S] tensor in HBM, forward or
+    backward), the reference core with the caller's causal ``mask``
+    everywhere else.  Both compute float32 scores and softmax from the
+    operands as given and accumulate in float32."""
+    seq_len, heads, head_dim = q.shape[1:]
+    impl = attention_path(jax.default_backend(), seq_len, head_dim)
+    blocks = None
+    if impl == "flash":
+        from dlrover_tpu.ops.pallas.tuning import tuned_blocks
+
+        blocks = tuned_blocks(seq_len, head_dim)
+    _note_path(impl=impl, seq=seq_len, head_dim=head_dim, heads=heads,
+               blocks=blocks)
+    if blocks is None:
+        return reference_attention(q, k, v, mask)
+    return flash_attention(
+        q, k, v, causal=True, block_q=blocks[0], block_kv=blocks[1]
+    )

@@ -30,6 +30,21 @@ NEG_INF = -1e30
 # tiling constraint (same layout as jax's reference TPU kernel).
 MIN_LANES = 128
 
+# What a caller that chooses for itself may send here.  The compiler
+# takes more (head sizes 16 to 256 and blocks down to 8 rows compile for
+# a described v5e), but these are the shapes the kernel has run at on
+# the chip against the reference (tests_tpu/): the head sizes whose
+# [block, D] tiles fill half or all of the 128 lanes, and sequences that
+# a block of the tuner's sweep divides.  A shape outside them is the
+# reference's until a test on the chip says otherwise.
+KERNEL_HEAD_DIMS = (64, 128)
+KERNEL_MIN_BLOCK = 128
+
+
+def kernel_takes(seq_len: int, head_dim: int) -> bool:
+    """Whether the kernel runs causal self-attention at this shape."""
+    return head_dim in KERNEL_HEAD_DIMS and seq_len % KERNEL_MIN_BLOCK == 0
+
 
 def _masked_scores(q, k, scale, causal, q_start, kv_start, block_q,
                    block_kv):

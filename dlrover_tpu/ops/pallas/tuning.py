@@ -15,6 +15,17 @@ TPU generation, and guessing them costs real throughput.  This module
 
 Sweeping requires a real TPU backend — on CPU the kernel only interprets,
 whose timings say nothing about Mosaic codegen, so the CLI refuses.
+
+An entry says how it was measured.  ``"sync": "hard_block"`` is this
+module's sweep: the kernel alone, forward and backward, timed from the host
+around a read-back (``s2048_d128``).  ``"measured"`` is
+``scripts/fa_blocks_in_step.py``: the kernels' own time in the device trace
+of a whole training step, forward, recomputed forward and backward of
+every layer, with ``kernel_ms_per_step``, ``device_kind`` and ``date``
+(``s1024_d64``, GPT-2's shape: TPU v5 lite, 2026-09-27, 1024x1024 at
+118.8 ms a step of 96 calls; 512x1024 134.95, 512x512 183.99, 128x128
+737.4: at this shape one block a head, with nothing skipped, beat every
+split that skips the masked blocks).
 """
 
 import argparse

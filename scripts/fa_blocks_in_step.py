@@ -1,0 +1,149 @@
+"""FA2 block sizes for one shape, ranked by the kernels' time inside a step.
+
+``ops/pallas/tuning.py::autotune`` times the kernel alone from the host.
+This sweep runs the whole training step of a benchmark configuration (its
+``Trainer``, optimizer and batch: forward, recomputed forward and backward
+of every layer) once for each candidate, under a profiler session, and
+sums the device time of the attention module's ``tpu_custom_call``s in
+the trace.  That is the time the step pays, and it is what the shipped
+``s1024_d64`` entry of ``fa_tuned.json`` was chosen by.  On the chip::
+
+    python3 scripts/fa_blocks_in_step.py --config gpt2m
+
+Candidates reach the kernel through the table ``DLROVER_TPU_FA_TUNING``
+names, as a user's own table would.  One JSON line a candidate, the
+winner's table entry last.
+"""
+
+import argparse
+import datetime
+import json
+import os
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def kernel_seconds(trace_dir):
+    """kind (``fwd``, ``dq``, ``dkv``) -> [events, seconds] of the
+    attention custom calls on the first chip, told apart as the
+    benchmark's ``fa2_ms_per_step`` tells them."""
+    from benchmarks import common
+    from benchmarks import trace as trace_mod
+
+    kind_of = common.load_module("layer_metrics", "fa2_ms_per_step").kind_of
+    loaded = trace_mod.load(trace_mod.find_xplane(trace_dir))
+    found = {"fwd": [0, 0.0], "dq": [0, 0.0], "dkv": [0, 0.0]}
+    if loaded.device_ops:
+        for name, start, end in loaded.device_ops[min(loaded.device_ops)]:
+            kind = kind_of(name)
+            if kind:
+                found[kind][0] += 1
+                found[kind][1] += end - start
+    return found
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    parser.add_argument("--config", default="gpt2m")
+    parser.add_argument("--steps", type=int, default=3)
+    parser.add_argument("--blocks", default="",
+                        help="candidates as q,kv;q,kv (default: the sweep's)")
+    parser.add_argument("--rehearse", action="store_true",
+                        help="tiny sizes, any backend: control flow only")
+    args = parser.parse_args(argv)
+    sys.path.insert(0, ROOT)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+    import jax
+
+    from benchmarks import program
+    from dlrover_tpu.ops.pallas import tuning
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           args.config + ".json")) as f:
+        config = json.load(f)
+    family, _, trainer = program.make_trainer(config, args.rehearse)
+    pool = program.make_pool(config, args.rehearse, 0, family)
+    m = family.sizes(config, args.rehearse)
+    batch, seq = pool[0]["input_ids"].shape
+    heads = m.get("n_head") or m["num_attention_heads"]
+    head_dim = m.get("head_dim") or m["n_embd"] // heads
+    if args.blocks:
+        candidates = [tuple(int(b) for b in pair.split(","))
+                      for pair in args.blocks.split(";")]
+    else:
+        candidates = tuning._candidates(seq)
+    state = trainer.create_state(program.make_key(0), pool[0]["input_ids"])
+    results = []
+    with tempfile.TemporaryDirectory() as scratch:
+        table = os.path.join(scratch, "candidate.json")
+        os.environ["DLROVER_TPU_FA_TUNING"] = table
+        for block_q, block_kv in candidates:
+            with open(table, "w") as f:
+                json.dump({f"s{seq}_d{head_dim}": {
+                    "block_q": block_q, "block_kv": block_kv}}, f)
+            tuning._load_one.cache_clear()
+            # a new Trainer traces the step anew, with this candidate
+            _, _, trainer = program.make_trainer(config, args.rehearse)
+            trainer.state_shardings = trainer.state_sharding_for(
+                program.make_key(0), pool[0]["input_ids"])
+            line = {"block_q": block_q, "block_kv": block_kv}
+            try:
+                sharded = [trainer.shard_batch(b) for b in pool[:args.steps]]
+                state, metrics = trainer.train_step(state, sharded[0])
+                float(metrics["loss"])  # compiled, and one step through
+                trace_dir = os.path.join(scratch, f"t{block_q}_{block_kv}")
+                jax.profiler.start_trace(trace_dir)
+                try:
+                    t0 = time.perf_counter()
+                    for on_mesh in sharded:
+                        state, metrics = trainer.train_step(state, on_mesh)
+                    float(metrics["loss"])
+                    step_s = (time.perf_counter() - t0) / len(sharded)
+                finally:
+                    jax.profiler.stop_trace()
+                found = kernel_seconds(trace_dir)
+                per_step = 1e3 / len(sharded)
+                line.update(
+                    kernel_ms_per_step=round(
+                        per_step * sum(s for _, s in found.values()), 3),
+                    **{f"{kind}_ms_per_step": round(per_step * s, 3)
+                       for kind, (_, s) in found.items()},
+                    kernel_calls_per_step=sum(
+                        n for n, _ in found.values()) / len(sharded),
+                    step_ms_under_trace=round(1e3 * step_s, 2))
+            except Exception as e:  # noqa: BLE001 - a candidate that cannot
+                line["error"] = f"{type(e).__name__}: {e}"[:300]  # compile
+            print(json.dumps(line), flush=True)
+            results.append(line)
+    ranked = sorted((r for r in results if r.get("kernel_ms_per_step")),
+                    key=lambda r: r["kernel_ms_per_step"])
+    if not ranked:
+        print(json.dumps({"error": "no attention custom call in any trace "
+                          f"(backend {jax.default_backend()!r})"}))
+        return 1
+    best = ranked[0]
+    runner_up = ("; %d candidates, next best %dx%d at %s" % (
+        len(ranked), ranked[1]["block_q"], ranked[1]["block_kv"],
+        ranked[1]["kernel_ms_per_step"])) if len(ranked) > 1 else ""
+    print(json.dumps({f"s{seq}_d{head_dim}": {
+        "block_q": best["block_q"], "block_kv": best["block_kv"],
+        "kernel_ms_per_step": best["kernel_ms_per_step"],
+        "kernel_calls_per_step": int(best["kernel_calls_per_step"]),
+        "measured": "kernel time in the device trace of a whole step "
+                    f"({args.config}: forward, recomputed forward, backward) "
+                    f"by scripts/fa_blocks_in_step.py{runner_up}",
+        "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "date": datetime.date.today().isoformat(),
+        "causal": True,
+        "shape": [batch, seq, heads, head_dim],
+    }}, indent=1, sort_keys=True))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

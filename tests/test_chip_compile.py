@@ -154,27 +154,32 @@ def test_rdma_ring_compiles_on_four_chips(ring_mesh):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _trainer_step_compiled(mesh):
-    """The whole ``Trainer`` step at the 1.24B widths cut to 2 layers,
-    with the optimizer and dtypes of bench.py's throughput run, lowered
-    from shapes (a described device holds no array) and compiled."""
+def _llama_1b_2_layers():
+    """The 1.24B widths cut to 2 layers, B4 S2048."""
     from dlrover_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from dlrover_tpu.trainer.optim import create_optimizer
-    from dlrover_tpu.trainer.train import Trainer
 
     cfg = dataclasses.replace(
         LlamaConfig.llama2_1b(max_seq_len=2048, attention_impl="flash"),
         num_layers=2,
     )
+    return LlamaForCausalLM(cfg), (4, 2048)
+
+
+def _trainer_step_compiled(mesh, model_and_batch=_llama_1b_2_layers):
+    """The whole ``Trainer`` step with the optimizer and dtypes of
+    bench.py's throughput run, lowered from shapes (a described device
+    holds no array) and compiled."""
+    from dlrover_tpu.trainer.optim import create_optimizer
+    from dlrover_tpu.trainer.train import Trainer
+
+    model, batch_shape = model_and_batch()
     opt = create_optimizer(
         peak_lr=3e-4, warmup_steps=10, total_steps=10_000,
         moment_dtype=jnp.bfloat16,
     )
-    trainer = Trainer(
-        LlamaForCausalLM(cfg), opt, mesh, grads_dtype=jnp.bfloat16
-    )
+    trainer = Trainer(model, opt, mesh, grads_dtype=jnp.bfloat16)
     rng = jax.random.PRNGKey(0)
-    sample = np.zeros((4, 2048), np.int32)
+    sample = np.zeros(batch_shape, np.int32)
     shardings = trainer.state_sharding_for(rng, sample)
     trainer.state_shardings = shardings
     # the shardings are a prefix tree of the (boxed) state: one
@@ -187,7 +192,7 @@ def _trainer_step_compiled(mesh):
         shardings, trainer.abstract_state(rng, sample),
     )
     data = NamedSharding(mesh, P(trainer.data_axes))
-    ids = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=data)
+    ids = jax.ShapeDtypeStruct(batch_shape, jnp.int32, sharding=data)
     batch = {"input_ids": ids, "labels": ids}
     return trainer.lower_train_step(state, batch).compile()
 
@@ -199,6 +204,22 @@ class TestTrainerStep:
         text = compiled.as_text()
         assert "tpu_custom_call" in text
         assert "all-gather" not in text and "all-reduce" not in text
+
+    def test_gpt2_medium_one_chip(self, topo, as_if_on_tpu):
+        """The GPT code has no attention option: at B16 S1024 with 16
+        heads of 64 it takes the kernel by itself, in the forward, the
+        recomputed forward and both halves of the backward, and no
+        [B, H, S, S] array is left in the step."""
+        from dlrover_tpu.models.gpt import GPT, GPTConfig
+
+        def gpt2_medium():
+            return GPT(GPTConfig(n_embd=1024, n_layer=24, n_head=16)), (
+                16, 1024)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        text = _trainer_step_compiled(mesh, gpt2_medium).as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 4
+        assert "[16,16,1024,1024]" not in text
 
     def test_fsdp4(self, topo, as_if_on_tpu):
         mesh = build_mesh(MeshConfig(fsdp=4), devices=list(topo.devices))

@@ -7,8 +7,10 @@ import numpy as np
 import optax
 import pytest
 
+from dlrover_tpu.ops import attention
 from dlrover_tpu.ops.attention import flash_attention, reference_attention
 from dlrover_tpu.ops.pallas.flash_attention import pallas_flash_attention
+from dlrover_tpu.ops.pallas.tuning import tuned_blocks
 from dlrover_tpu.ops.ring_attention import ring_attention_sharded
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -26,13 +28,23 @@ def _causal_mask(S):
     return jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
 
 
+def _shipped_gpt_blocks(seq):
+    """The blocks shipped for GPT-2's attention (S 1024, head size 64),
+    scaled to a sequence the interpreter gets through."""
+    block_q, block_kv = tuned_blocks(1024, 64)
+    return block_q * seq // 1024, block_kv * seq // 1024
+
+
 class TestPallasFlashAttention:
     @pytest.mark.parametrize("causal", [True, False])
-    def test_matches_reference_multi_block(self, causal):
+    @pytest.mark.parametrize("blocks", [(64, 64), "shipped_s1024_d64"])
+    def test_matches_reference_multi_block(self, causal, blocks):
         B, S, H, D = 2, 256, 4, 64
         q, k, v = _qkv(0, B, S, H, D)
+        if blocks == "shipped_s1024_d64":
+            blocks = _shipped_gpt_blocks(S)
         out = pallas_flash_attention(
-            q, k, v, causal, 64, 64, True  # interpret mode
+            q, k, v, causal, *blocks, True  # interpret mode
         )
         mask = _causal_mask(S) if causal else None
         ref = reference_attention(q, k, v, mask)
@@ -60,13 +72,21 @@ class TestPallasFlashAttention:
             rtol=3e-2, atol=3e-2,
         )
 
-    def test_gradients_match_reference(self):
-        B, S, H, D = 1, 128, 2, 32
+    @pytest.mark.parametrize(
+        "shape, blocks",
+        [((1, 128, 2, 32), (64, 64)),
+         ((1, 256, 2, 64), "shipped_s1024_d64")],
+        ids=["d32", "d64_shipped_blocks"],
+    )
+    def test_gradients_match_reference(self, shape, blocks):
+        B, S, H, D = shape
         q, k, v = _qkv(3, B, S, H, D)
+        if blocks == "shipped_s1024_d64":
+            blocks = _shipped_gpt_blocks(S)
 
         def loss_flash(q_, k_, v_):
             return jnp.sum(
-                pallas_flash_attention(q_, k_, v_, True, 64, 64, True) ** 2
+                pallas_flash_attention(q_, k_, v_, True, *blocks, True) ** 2
             )
 
         def loss_ref(q_, k_, v_):
@@ -192,6 +212,174 @@ class TestFlashAttentionDispatch:
             out = jax.jit(fn)(q, k, v)
         ref = reference_attention(q, k, v, _causal_mask(128))
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+def _gpt_head_dims():
+    from dlrover_tpu.models.gpt import GPTConfig
+
+    medium = GPTConfig(n_embd=1024, n_layer=24, n_head=16)
+    return {
+        name: (cfg.block_size, cfg.n_embd // cfg.n_head)
+        for name, cfg in [("gpt2_medium", medium),
+                          ("gpt2_xl", GPTConfig.gpt2_xl()),
+                          ("gpt_tiny", GPTConfig.tiny())]
+    }
+
+
+@pytest.fixture
+def kernel_by_interpreter(monkeypatch):
+    """Steer ``causal_attention`` onto the kernel path off the chip: the
+    backend reads as a TPU and the kernel runs in the Pallas interpreter.
+    The program has no option for either."""
+    import functools
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        attention, "flash_attention",
+        functools.partial(flash_attention, interpret=True),
+    )
+
+
+class TestCausalAttentionChoice:
+    """``ops.attention.causal_attention``: the kernel wherever it can
+    run, chosen from the backend and the shape while the step is traced."""
+
+    @pytest.mark.parametrize(
+        "backend, shape, path",
+        [("tpu", "gpt2_medium", "flash"),
+         ("tpu", "gpt2_xl", "flash"),
+         ("tpu", (2048, 128), "flash"),
+         ("tpu", "gpt_tiny", "reference"),   # head size 16, S 64
+         ("tpu", (1000, 64), "reference"),   # no block divides it
+         ("tpu", (1024, 80), "reference"),   # a head size not run yet
+         ("cpu", "gpt2_medium", "reference"),
+         ("gpu", (2048, 128), "reference")],
+    )
+    def test_path_by_backend_and_shape(self, backend, shape, path):
+        if isinstance(shape, str):
+            shape = _gpt_head_dims()[shape]
+        assert attention.attention_path(backend, *shape) == path
+
+    def test_the_kernel_path_agrees_with_the_reference(
+        self, kernel_by_interpreter
+    ):
+        q, k, v = _qkv(4, 2, 256, 2, 64)
+        mask = _causal_mask(256)
+        got = attention.causal_attention(q, k, v, mask)
+        np.testing.assert_allclose(
+            got, reference_attention(q, k, v, mask), atol=2e-5, rtol=2e-5
+        )
+
+    def test_off_the_chip_it_is_the_reference_to_the_bit(self):
+        q, k, v = _qkv(5, 2, 128, 2, 64)
+        mask = _causal_mask(128)
+        np.testing.assert_array_equal(
+            attention.causal_attention(q, k, v, mask),
+            reference_attention(q, k, v, mask),
+        )
+
+
+def _gpt_d64(dtype, **kw):
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+
+    cfg = GPTConfig(vocab_size=256, n_embd=128, n_layer=2, n_head=2,
+                    block_size=128, dtype=dtype, **kw)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 256)
+    return GPT(cfg), ids
+
+
+class TestGPTThroughTheKernel:
+    """A GPT of head size 64: the kernel path (steered, in the
+    interpreter) against the reference path the CPU takes by itself."""
+
+    @pytest.mark.parametrize(
+        "dtype, tol",
+        # float32 holds the wiring (mask, scale, layout) to rounding;
+        # bfloat16 is what trains, at the tolerance of the Llama ring
+        # parity test below
+        [(jnp.float32, 2e-4), (jnp.bfloat16, 5e-2)],
+        ids=["float32", "bfloat16"],
+    )
+    def test_forward_and_parameter_gradients_match(
+        self, request, monkeypatch, dtype, tol
+    ):
+        from dlrover_tpu.trainer.train import cross_entropy_loss
+
+        model, ids = _gpt_d64(dtype)
+        params = model.init(jax.random.PRNGKey(0), ids)["params"]
+
+        def loss(p):
+            logits = model.apply({"params": p}, ids)
+            return cross_entropy_loss(logits, ids), logits
+
+        run = jax.jit(jax.value_and_grad(loss, has_aux=True))
+        (want_loss, want_logits), want_grads = run(params)
+        request.getfixturevalue("kernel_by_interpreter")
+        paths = []
+        monkeypatch.setattr(
+            attention, "_note_path", lambda **kw: paths.append(kw["impl"])
+        )
+        run = jax.jit(jax.value_and_grad(loss, has_aux=True))
+        (got_loss, got_logits), got_grads = run(params)
+        assert paths and set(paths) == {"flash"}
+        np.testing.assert_allclose(got_loss, want_loss, rtol=tol)
+        np.testing.assert_allclose(
+            got_logits, want_logits, rtol=tol, atol=tol
+        )
+        for got, want in zip(jax.tree.leaves(got_grads),
+                             jax.tree.leaves(want_grads)):
+            scale = max(1e-6, float(jnp.abs(want).max()))
+            np.testing.assert_allclose(
+                got / scale, want / scale, atol=tol, rtol=tol
+            )
+
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scanned", "unrolled"])
+    @pytest.mark.parametrize("steered", [False, True],
+                             ids=["reference", "flash"])
+    def test_one_path_record_for_each_trace(
+        self, request, scan_layers, steered
+    ):
+        from dlrover_tpu.observability import trace
+
+        if steered:
+            request.getfixturevalue("kernel_by_interpreter")
+        model, ids = _gpt_d64(jnp.bfloat16, scan_layers=scan_layers)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
+
+        def traced_under_a_span():
+            with trace.span("trainer.step.dispatch") as open_span:
+                jax.eval_shape(
+                    jax.grad(lambda p: model.apply(p, ids).sum()), params
+                )
+            return [e for e in open_span.events
+                    if e["name"] == "attention.path"]
+
+        for _ in range(2):  # a second trace makes a second record
+            (record,) = traced_under_a_span()
+            want = dict(impl="reference", seq=128, head_dim=64, heads=2,
+                        blocks=None)
+            if steered:
+                want.update(impl="flash", blocks=tuned_blocks(128, 64))
+            assert record["attrs"] == want
+
+    def test_with_no_span_open_the_record_is_a_span_of_its_own(
+        self, monkeypatch
+    ):
+        from dlrover_tpu.observability import trace
+
+        monkeypatch.setattr(attention, "_paths_noted_without_span", set())
+        exported = []
+        trace.set_span_sink(exported.append)
+        try:
+            model, ids = _gpt_d64(jnp.bfloat16, scan_layers=False)
+            assert trace.current_span() is None
+            jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
+        finally:
+            trace.set_span_sink(None)
+        (record,) = [r for r in exported if r["name"] == "attention.path"]
+        assert record["attrs"]["impl"] == "reference"
+        assert record["attrs"]["head_dim"] == 64
 
 
 class TestRingAttention:

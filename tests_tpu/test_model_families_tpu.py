@@ -40,6 +40,49 @@ def test_gpt_scan_remat_trains_on_device(tpu_backend):
     assert losses[-1] < losses[0], f"gpt loss did not drop on TPU: {losses}"
 
 
+def test_gpt_head_64_trains_through_the_kernel(tpu_backend, monkeypatch):
+    """A GPT whose shape the FA2 kernel takes (4 heads of 64, S 256)
+    reaches it with no option set: the compiled step holds the kernel's
+    custom calls and no [B, H, S, S] result, and three steps give the
+    losses of the reference path."""
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+    from dlrover_tpu.ops import attention
+
+    cfg = GPTConfig(vocab_size=512, n_embd=256, n_layer=2, n_head=4,
+                    block_size=256, scan_layers=True, remat=True)
+    mesh = build_mesh(MeshConfig(dp=1))
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, cfg.vocab_size, size=(4, cfg.block_size + 1))
+    batch = {
+        "input_ids": np.asarray(ids[:, :-1], np.int32),
+        "labels": np.asarray(ids[:, 1:], np.int32),
+    }
+
+    def compiled_text_and_losses():
+        trainer = Trainer(GPT(cfg), optax.adamw(1e-2), mesh)
+        state = trainer.create_state(
+            jax.random.PRNGKey(0), batch["input_ids"]
+        )
+        text = trainer.lower_train_step(
+            state, trainer.shard_batch(batch)
+        ).compile().as_text()
+        return text, _train_losses(trainer, state, batch)
+
+    text, losses = compiled_text_and_losses()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
+    assert "[4,4,256,256]" not in text
+    monkeypatch.setattr(
+        attention, "attention_path", lambda *shape: "reference"
+    )
+    ref_text, ref_losses = compiled_text_and_losses()
+    assert "tpu_custom_call" not in ref_text
+    assert "[4,4,256,256]" in ref_text
+    assert all(np.isfinite(l) for l in losses), losses
+    assert losses[-1] < losses[0], losses
+    # bf16 kernel noise only, as the Llama flash-against-reference test
+    np.testing.assert_allclose(losses, ref_losses, rtol=0.05)
+
+
 def test_vit_trains_on_device(tpu_backend):
     from dlrover_tpu.models.vit import ViTConfig, ViTForImageClassification
 
