@@ -43,6 +43,9 @@ class LlamaConfig:
     remat: bool = True
     scan_layers: bool = True
     attention_impl: str = "reference"  # reference | flash | ring
+    # q and k each RMS-normalised over their whole projected width before
+    # the split into heads and RoPE (OLMoE, arXiv:2409.02060)
+    qk_norm: bool = False
 
     def __post_init__(self):
         valid = ("reference", "flash", "ring")
@@ -52,6 +55,15 @@ class LlamaConfig:
             )
         if self.num_heads % self.num_kv_heads != 0:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
+
+    def feed_forward(self):
+        """The module class of the block after attention, built as
+        ``cls(config, name="mlp")``: the model's choice (the dense SwiGLU
+        here, the routed experts of ``models/moe.py`` there)."""
+        return MLP
+
+    def feed_forward_params(self) -> int:
+        return 3 * self.hidden_size * self.intermediate_size
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
@@ -142,6 +154,15 @@ class Attention(nn.Module):
             ),
             name="v_proj",
         )(x)
+        if cfg.qk_norm:
+            def whole_width_norm(t, name):
+                flat = t.reshape(*t.shape[:2], -1)
+                norm = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                               name=name)
+                return norm(flat).reshape(t.shape)
+
+            q = whole_width_norm(q, "q_norm")
+            k = whole_width_norm(k, "k_norm")
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
         k = nn.with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"))
         v = nn.with_logical_constraint(v, ("batch", "seq", "kv_heads", "head_dim"))
@@ -247,7 +268,7 @@ class DecoderLayer(nn.Module):
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                     name="post_attn_norm")(x)
-        x = x + MLP(cfg, name="mlp")(h)
+        x = x + cfg.feed_forward()(cfg, name="mlp")(h)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
@@ -338,7 +359,9 @@ class LlamaForCausalLM(nn.Module):
         if cfg.scan_layers:
             x, _ = nn.scan(
                 layer_cls,
-                variable_axes={"params": 0},
+                # what a layer sows (a routed block's loss terms and
+                # counts) stacks on the layer axis beside its parameters
+                variable_axes={"params": 0, "losses": 0, "stats": 0},
                 split_rngs={"params": True},
                 in_axes=nn.broadcast,  # positions/mask shared by all layers
                 length=cfg.num_layers,
@@ -358,8 +381,9 @@ class LlamaForCausalLM(nn.Module):
         attn = cfg.hidden_size * cfg.head_dim * (
             cfg.num_heads * 2 + cfg.num_kv_heads * 2
         )
-        mlp = 3 * cfg.hidden_size * cfg.intermediate_size
-        per_layer = attn + mlp + 2 * cfg.hidden_size
+        if cfg.qk_norm:
+            attn += cfg.head_dim * (cfg.num_heads + cfg.num_kv_heads)
+        per_layer = attn + cfg.feed_forward_params() + 2 * cfg.hidden_size
         return (
             cfg.vocab_size * cfg.hidden_size * 2
             + cfg.num_layers * per_layer

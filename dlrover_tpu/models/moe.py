@@ -1,50 +1,68 @@
-"""Mixture-of-Experts Llama variant: the ``ep`` mesh axis in action.
+"""Mixture-of-experts feed-forward block for the Llama decoder: dropless
+top-k routing, experts sharded over the ``ep`` mesh axis.
 
-Beyond-parity capability (the reference orchestrates MoE jobs but has no
-model math in-tree): a top-k routed MoE feed-forward whose expert weights
-carry the "expert" logical axis, sharded over the ``ep`` mesh axis by the
-standard rules table — GSPMD places each expert's parameters on its ep
-shard and inserts the token all-to-alls.
+``MoELlamaConfig.feed_forward`` hands ``models/llama.py``'s one decoder this
+block in place of the dense SwiGLU; everything else (attention, norms,
+``remat``, the scanned layer stack, the head) is the dense model's code.
 
-Routing is **capacity-based dispatch** (GShard/Switch style): each expert
-processes at most ``C = ceil(capacity_factor * top_k * S / E)`` tokens per
-batch group, selected by top-k gate priority.  Dispatch/combine are
-static-shape one-hot einsums — fully SPMD, no sorting, no dynamic shapes —
-so per-step expert FLOPs scale with ``top_k * capacity_factor`` and NOT
-with the number of experts.  Tokens over capacity are dropped (their MoE
-output is zero; the residual connection carries them through), the
-standard trade for static shapes on TPU.
+Routing (OLMoE, arXiv:2409.02060): ``softmax(x W_r)`` in float32 over all
+experts, the ``top_k`` largest kept with their softmax weights as they are
+(no renormalisation).  No token is ever dropped and every shape is static:
+the ``tokens x top_k`` assignments are sorted by expert, one grouped matmul
+(``jax.lax.ragged_dot``, which the TPU compiler turns into its own grouped
+kernel and skips the rows no group holds) runs over the sorted rows, and
+the results go back weighted.  The buffer of sorted rows is sized for the
+worst case, every assignment on this chip's experts.
 
-``router_impl="dense"`` keeps the old dense-mixture formulation (every
-expert computes every token) as a numerical oracle: with capacity high
-enough that nothing drops, dispatch must match it exactly — that's the
-parity test.
+Expert parallelism: ``ep`` ranks hold ``num_experts / ep`` experts each and
+are data ranks for everything else.  Inside a ``shard_map`` the tokens of
+an ``ep`` group are all-gathered, each rank runs its own experts over the
+rows routed to them, one source rank's tokens at a time, and a
+reduce-scatter sums the partial results back to the rank that owns the
+token.  With 8 of 64 experts a token and 16 a rank, a
+token has an expert on a given rank with probability 0.91: an all-to-all
+would move about the same bytes and need a bound on what a rank receives.
+With ``ep == 1`` the same code runs with no collective.
 
-A Switch-Transformer load-balancing auxiliary loss is sown under
-``intermediates``; use ``moe_loss_fn`` to train with it.
+Each layer sows two loss terms into the ``losses`` collection, already
+weighted and divided by the number of layers (``Trainer``'s default loss
+adds whatever a model sows there): the load-balancing loss
+``E * sum_e f_e P_e`` (``f_e``: share of the assignments that went to expert
+``e``, ``P_e``: its mean router probability; 1 at uniform routing) and the
+router z-loss ``mean(logsumexp(logits)^2)``.  Into ``stats`` it sows the
+largest expert's rows over the mean, counted from the groups the matmul was
+given.  There is no count of dropped rows: the buffers hold the worst case,
+so none can be, and ``tests/test_moe.py`` holds a forced routing to the
+reference's result.
 """
 
 import dataclasses
-import math
+import functools
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import (
-    Attention,
-    LlamaConfig,
-    RMSNorm,
-)
+from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.observability import trace
 
 
 @dataclasses.dataclass(frozen=True)
 class MoELlamaConfig(LlamaConfig):
+    """``intermediate_size`` is the width of one expert."""
+
     num_experts: int = 8
     top_k: int = 2
-    # >= num_experts/top_k guarantees zero dropped tokens (oracle mode)
-    capacity_factor: float = 1.25
-    router_impl: str = "dispatch"  # "dispatch" | "dense"
+    load_balance_coef: float = 0.01
+    router_z_coef: float = 0.001
+
+    def feed_forward(self):
+        return MoEMLP
+
+    def feed_forward_params(self) -> int:
+        return self.num_experts * super().feed_forward_params() + (
+            self.hidden_size * self.num_experts
+        )
 
     @classmethod
     def tiny_moe(cls, **kw) -> "MoELlamaConfig":
@@ -52,17 +70,75 @@ class MoELlamaConfig(LlamaConfig):
             vocab_size=256, hidden_size=64, intermediate_size=128,
             num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
             max_seq_len=128, num_experts=4, top_k=2,
-            remat=False, scan_layers=False,
+        )
+        defaults.update(kw)
+        return cls(**defaults)
+
+    @classmethod
+    def olmoe_1b_7b(cls, **kw) -> "MoELlamaConfig":
+        """allenai/OLMoE-1B-7B-0125 (``config.json``).  The two loss
+        coefficients are the paper's (section 3, "auxiliary losses")."""
+        defaults = dict(
+            vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+            num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
+            max_seq_len=4096, rope_theta=10000.0, rms_norm_eps=1e-5,
+            qk_norm=True, num_experts=64, top_k=8,
         )
         defaults.update(kw)
         return cls(**defaults)
 
 
-def expert_capacity(seq_len: int, num_experts: int, top_k: int,
-                    capacity_factor: float) -> int:
-    """Per-expert token budget per batch group, sublane-aligned (mult of 8)."""
-    c = math.ceil(capacity_factor * top_k * seq_len / num_experts)
-    return max(8, ((c + 7) // 8) * 8)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take(x, perm, inv_perm, fan):
+    """``x[perm // fan]``, where ``perm`` is a permutation of
+    ``range(fan * len(x))`` and ``inv_perm`` its inverse: each row of ``x``
+    feeds ``fan`` rows of the result.  The transpose is a gather through
+    ``inv_perm`` and a sum over the ``fan`` copies, where autodiff would
+    scatter-add."""
+    return x[perm // fan]
+
+
+def _take_fwd(x, perm, inv_perm, fan):
+    return x[perm // fan], inv_perm
+
+
+def _take_bwd(fan, inv_perm, g):
+    copies = g[inv_perm].reshape(-1, fan, g.shape[-1])
+    return copies.sum(axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_take.defvjp(_take_fwd, _take_bwd)
+
+
+def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert):
+    """What the experts ``[first_expert, first_expert + len(gate_w))`` add
+    to the layer's result for tokens ``x`` [T, D] routed by ``top_i`` and
+    weighted by ``top_w`` (both [T, k]): ``([T, D] float32, rows each of
+    these experts processed)``."""
+    tokens, k = top_i.shape
+    n_local = gate_w.shape[0]
+    local = top_i - first_expert
+    mine = (local >= 0) & (local < n_local)
+    # assignments of other ranks' experts sort behind every group
+    key = jnp.where(mine, local, n_local).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    slots = jnp.arange(tokens * k, dtype=order.dtype)
+    inverse = jnp.zeros_like(order).at[order].set(slots, unique_indices=True)
+    sizes = (key[:, None] == jnp.arange(n_local)).sum(axis=0, dtype=jnp.int32)
+    # the grouped matmul leaves the rows behind the last group undefined:
+    # they are masked on the way in (so no gradient comes back through
+    # them) and on the way out
+    live = (slots < sizes.sum())[:, None]
+    rows = jnp.where(live, _take(x, order, inverse, k), 0)
+    grouped = functools.partial(
+        jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=x.dtype
+    )
+    hidden = nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
+    out = jnp.where(live, grouped(hidden, down_w), 0)
+    out = _take(out, inverse, order, 1).reshape(tokens, k, -1)
+    weights = jnp.where(mine, top_w, 0).astype(x.dtype)
+    return jnp.einsum("tkd,tk->td", out, weights,
+                      preferred_element_type=jnp.float32), sizes
 
 
 class MoEMLP(nn.Module):
@@ -74,205 +150,132 @@ class MoEMLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         B, S, D = x.shape
-        E, top_k = cfg.num_experts, cfg.top_k
+        E, k = cfg.num_experts, cfg.top_k
+        with jax.named_scope("moe"):
+            logits = nn.DenseGeneral(
+                features=E, use_bias=False,
+                # routing decisions in float32: on a TPU a float32 matmul
+                # at the default precision multiplies in bfloat16
+                dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                param_dtype=cfg.param_dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", None)
+                ),
+                name="router",
+            )(x)
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_w, top_i = jax.lax.top_k(probs, k)
 
-        router = nn.DenseGeneral(
-            features=E,
-            use_bias=False,
-            dtype=jnp.float32,  # routing decisions in fp32
-            param_dtype=cfg.param_dtype,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("embed", "expert")
-            ),
-            name="router",
-        )(x)
-        probs = jax.nn.softmax(router, axis=-1)  # [B, S, E]
-        top_vals, top_idx = jax.lax.top_k(probs, top_k)
-        # normalized top-k gate values
-        norm_vals = top_vals / jnp.maximum(
-            top_vals.sum(axis=-1, keepdims=True), 1e-9
-        )  # [B, S, k]
+            def expert_weight(name, shape, axes):
+                return self.param(
+                    name,
+                    nn.with_logical_partitioning(
+                        nn.initializers.lecun_normal(), axes
+                    ),
+                    shape, cfg.param_dtype,
+                ).astype(cfg.dtype)
 
-        def expert_init(axes):
-            return nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), axes
+            F = cfg.intermediate_size
+            gate_w = expert_weight("gate_proj", (E, D, F),
+                                   ("expert", "embed", "mlp"))
+            up_w = expert_weight("up_proj", (E, D, F),
+                                 ("expert", "embed", "mlp"))
+            down_w = expert_weight("down_proj", (E, F, D),
+                                   ("expert", "mlp", "embed"))
+            mixed, rows = self._experts(
+                x.astype(cfg.dtype), top_i, top_w, gate_w, up_w, down_w
             )
-
-        gate_w = self.param(
-            "gate_proj", expert_init(("expert", "embed", "mlp")),
-            (E, D, cfg.intermediate_size), cfg.param_dtype,
-        )
-        up_w = self.param(
-            "up_proj", expert_init(("expert", "embed", "mlp")),
-            (E, D, cfg.intermediate_size), cfg.param_dtype,
-        )
-        down_w = self.param(
-            "down_proj", expert_init(("expert", "mlp", "embed")),
-            (E, cfg.intermediate_size, D), cfg.param_dtype,
-        )
-
-        # Switch load-balancing aux loss: E * sum_e(frac_assigned_e *
-        # mean_prob_e) — minimized (=1) at uniform routing.  Uses the
-        # pre-capacity assignment so the gradient pushes the ROUTER, not
-        # the drop behavior.
-        assign = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B,S,k,E]
-        frac = assign.sum(axis=2).mean(axis=(0, 1)) / top_k  # [E]
-        mean_prob = probs.mean(axis=(0, 1))  # [E]
-        self.sow(
-            "intermediates", "aux_loss", E * jnp.sum(frac * mean_prob)
-        )
-
-        if cfg.router_impl == "dense":
-            mixed = self._dense_mixture(
-                x, probs, top_vals, top_idx, gate_w, up_w, down_w
+            assigned = rows.astype(jnp.float32) / (B * S * k)
+            self.sow(
+                "losses", "load_balance",
+                (cfg.load_balance_coef / cfg.num_layers) * E
+                * jnp.sum(assigned * probs.mean(axis=(0, 1))),
             )
-        else:
-            mixed = self._dispatch(
-                x, norm_vals, top_idx, gate_w, up_w, down_w
+            self.sow(
+                "losses", "router_z",
+                (cfg.router_z_coef / cfg.num_layers)
+                * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
             )
+            self.sow("stats", "load_max_over_mean", rows.max() / rows.mean())
         return nn.with_logical_constraint(mixed, ("batch", "seq", "embed"))
 
-    def _expert_ffn(self, expert_in, gate_w, up_w, down_w):
-        """SwiGLU per expert on dispatched buffers [B, E, C, D]."""
+    def _experts(self, x, top_i, top_w, gate_w, up_w, down_w):
+        """``(the experts' weighted results [B, S, D], rows each of the E
+        experts processed over the whole batch)``."""
+        from dlrover_tpu.ops.ring_attention import active_mesh
+
         cfg = self.config
-        h = jnp.einsum(
-            "becd,edh->bech", expert_in, gate_w.astype(cfg.dtype)
+        D, k = x.shape[-1], cfg.top_k
+        mesh = active_mesh()
+        sharded = (
+            mesh is not None and mesh.size > 1
+            # already per-shard: the enclosing shard_map owns the mesh
+            # axes, and its parameters are whole on every shard
+            and not jax.sharding.get_abstract_mesh().manual_axes
         )
-        u = jnp.einsum(
-            "becd,edh->bech", expert_in, up_w.astype(cfg.dtype)
+        ep = int(mesh.shape.get("ep", 1)) if sharded else 1
+        if cfg.num_experts % ep:
+            raise ValueError(
+                f"{cfg.num_experts} experts do not split over ep={ep}"
+            )
+
+        x_spec = w_spec = None
+        others = ()
+        if sharded:
+            from dlrover_tpu.parallel.sharding import spec_on_mesh
+
+            rules = list(nn.get_logical_axis_rules()) or None
+            x_spec = spec_on_mesh(mesh, ("batch", "seq", None), rules)
+            w_spec = spec_on_mesh(mesh, ("expert", None, None), rules)
+            # the axes that split tokens beside ``ep``: each holds other
+            # tokens, so the rows an expert processed add up over them
+            others = tuple(
+                a for axes in x_spec if axes
+                for a in (axes if isinstance(axes, tuple) else (axes,))
+                if a != "ep"
+            )
+
+        def per_shard(x, top_i, top_w, gate_w, up_w, down_w):
+            tokens = (x.reshape(-1, D), top_i.reshape(-1, k),
+                      top_w.reshape(-1, k))
+            if ep == 1:
+                out, rows = local_experts(*tokens, gate_w, up_w, down_w, 0)
+                out = out.astype(cfg.dtype)
+            else:
+                first = jax.lax.axis_index("ep") * gate_w.shape[0]
+
+                # one rank's tokens at a time: the buffer of sorted rows
+                # is sized for the worst case, and only one is alive (the
+                # backward pass recomputes each in its turn)
+                @jax.checkpoint
+                def one_rank(its_tokens):
+                    out, rows = local_experts(
+                        *its_tokens, gate_w, up_w, down_w, first)
+                    return out.astype(cfg.dtype), rows
+
+                out, rows = jax.lax.map(one_rank, [
+                    jax.lax.all_gather(t, "ep", axis=0) for t in tokens])
+                out = jax.lax.psum_scatter(out, "ep", scatter_dimension=0)
+                rows = jax.lax.all_gather(
+                    rows.sum(axis=0), "ep", axis=0, tiled=True)
+            if others:
+                rows = jax.lax.psum(rows, others)
+            return out.reshape(x.shape), rows
+
+        if sharded:
+            from jax.sharding import PartitionSpec
+
+            from dlrover_tpu.parallel.collectives import shard_map_unchecked
+
+            per_shard = shard_map_unchecked(
+                per_shard, mesh=mesh,
+                in_specs=(x_spec, x_spec, x_spec, w_spec, w_spec, w_spec),
+                out_specs=(x_spec, PartitionSpec()),
+            )
+        out, rows = per_shard(x, top_i, top_w, gate_w, up_w, down_w)
+        trace.note_trace_time(
+            "moe.path", impl="ragged_dot", experts=cfg.num_experts,
+            top_k=k, ep=ep, tokens=x.shape[0] * x.shape[1],
+            rows=x.shape[0] * x.shape[1] * k, layers=cfg.num_layers,
         )
-        act = nn.silu(h) * u
-        act = nn.with_logical_constraint(
-            act, ("batch", "expert", "capacity", "mlp")
-        )
-        return jnp.einsum("bech,ehd->becd", act, down_w.astype(cfg.dtype))
-
-    def _dispatch(self, x, norm_vals, top_idx, gate_w, up_w, down_w):
-        """Capacity-based one-hot dispatch: FLOPs ∝ top_k, not E."""
-        cfg = self.config
-        B, S, D = x.shape
-        E, top_k = cfg.num_experts, cfg.top_k
-        C = expert_capacity(S, E, top_k, cfg.capacity_factor)
-
-        mask = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B,S,k,E]
-        # priority: all 1st choices beat all 2nd choices (GShard ordering)
-        mask_prio = mask.transpose(0, 2, 1, 3).reshape(B, top_k * S, E)
-        pos = jnp.cumsum(mask_prio, axis=1) * mask_prio - 1.0
-        pos = pos.reshape(B, top_k, S, E).transpose(0, 2, 1, 3)  # [B,S,k,E]
-        keep = mask * (pos >= 0.0) * (pos < C)  # [B,S,k,E]
-        pos_idx = jnp.clip(pos.astype(jnp.int32), 0, C - 1)
-
-        # dispatch [B,S,E,C]: one-hot of each kept token's buffer slot
-        disp = (
-            keep[..., None]
-            * jax.nn.one_hot(pos_idx, C, dtype=jnp.float32)
-        ).sum(axis=2)
-        gate_te = (norm_vals[..., None] * keep).sum(axis=2)  # [B,S,E]
-        combine = disp * gate_te[..., None]  # [B,S,E,C]
-
-        xc = x.astype(cfg.dtype)
-        expert_in = jnp.einsum(
-            "bsec,bsd->becd", disp.astype(cfg.dtype), xc
-        )
-        expert_in = nn.with_logical_constraint(
-            expert_in, ("batch", "expert", "capacity", "embed")
-        )
-        out_e = self._expert_ffn(expert_in, gate_w, up_w, down_w)
-        out_e = nn.with_logical_constraint(
-            out_e, ("batch", "expert", "capacity", "embed")
-        )
-        return jnp.einsum("becd,bsec->bsd", out_e, combine.astype(cfg.dtype))
-
-    def _dense_mixture(self, x, probs, top_vals, top_idx, gate_w, up_w,
-                       down_w):
-        """Numerical oracle: every expert computes every token (E× FLOPs).
-        Kept for parity tests only — do not use at scale."""
-        cfg = self.config
-        gates = jnp.zeros_like(probs)
-        gates = jax.vmap(
-            jax.vmap(lambda g, idx, val: g.at[idx].set(val))
-        )(gates, top_idx, top_vals)
-        gates = gates / jnp.maximum(
-            gates.sum(axis=-1, keepdims=True), 1e-9
-        )  # [B, S, E]
-        xc = x.astype(cfg.dtype)
-        h = jnp.einsum("bsd,edh->bseh", xc, gate_w.astype(cfg.dtype))
-        u = jnp.einsum("bsd,edh->bseh", xc, up_w.astype(cfg.dtype))
-        act = nn.silu(h) * u
-        act = nn.with_logical_constraint(
-            act, ("batch", "seq", "expert", "mlp")
-        )
-        out = jnp.einsum("bseh,ehd->bsed", act, down_w.astype(cfg.dtype))
-        return jnp.einsum("bsed,bse->bsd", out, gates.astype(cfg.dtype))
-
-
-class MoEDecoderLayer(nn.Module):
-    config: MoELlamaConfig
-
-    @nn.compact
-    def __call__(self, x, positions, mask):
-        cfg = self.config
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                    name="input_norm")(x)
-        x = x + Attention(cfg, name="attn")(h, positions, mask)
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                    name="post_attn_norm")(x)
-        x = x + MoEMLP(cfg, name="moe_mlp")(h)
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-
-
-class MoELlamaForCausalLM(nn.Module):
-    config: MoELlamaConfig
-
-    @nn.compact
-    def __call__(self, input_ids: jnp.ndarray) -> jnp.ndarray:
-        cfg = self.config
-        B, S = input_ids.shape
-        embed = self.param(
-            "embed_tokens",
-            nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ("vocab", "embed")
-            ),
-            (cfg.vocab_size, cfg.hidden_size),
-            cfg.param_dtype,
-        )
-        x = embed.astype(cfg.dtype)[input_ids]
-        positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
-        for i in range(cfg.num_layers):
-            x = MoEDecoderLayer(cfg, name=f"layers_{i}")(x, positions, mask)
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                    name="final_norm")(x)
-        # shared head semantics: bf16 operands / fp32 accumulation
-        # (models/llama.py LMHead — duck-typed over any config carrying
-        # hidden_size/vocab_size/param_dtype)
-        from dlrover_tpu.models.llama import LMHead
-
-        logits = LMHead(cfg, name="lm_head")(x)
-        return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
-
-
-def moe_loss_fn(model: MoELlamaForCausalLM, aux_weight: float = 0.01):
-    """Trainer ``loss_fn`` adding the sown load-balancing loss: without it
-    top-k routing collapses onto a few experts and capacity dispatch drops
-    most tokens."""
-
-    def loss_fn(params, batch):
-        from dlrover_tpu.trainer.train import cross_entropy_loss
-
-        logits, mutated = model.apply(
-            {"params": params}, batch["input_ids"],
-            mutable=["intermediates"],
-        )
-        loss = cross_entropy_loss(
-            logits, batch["labels"], batch.get("mask")
-        )
-        aux_leaves = [
-            jnp.mean(v)
-            for v in jax.tree.leaves(mutated.get("intermediates", {}))
-        ]
-        if aux_leaves:
-            loss = loss + aux_weight * sum(aux_leaves) / len(aux_leaves)
-        return loss
-
-    return loss_fn
+        return out, rows

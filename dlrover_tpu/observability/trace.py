@@ -438,6 +438,34 @@ def add_event(name: str, **attrs: Any) -> bool:
     return True
 
 
+#: trace-time records already made with no span open (a bare
+#: ``model.init``): one for each distinct reading, not one a layer
+_noted_without_span = set()
+
+
+def note_trace_time(name: str, **attrs: Any) -> None:
+    """One record, and one line in the log, of what Python chose while a
+    step was being traced (``attention.path``, ``moe.path``): an event on
+    the span open at the time (``trainer.step.dispatch``), a span of its
+    own where none is open.  The layers of one trace make the same
+    reading; only the first is kept.  It runs while tracing and costs a
+    step nothing."""
+    open_span = _CURRENT.get()
+    if open_span is not None:
+        if any(e["name"] == name and e["attrs"] == attrs
+               for e in open_span.events):
+            return
+        open_span.add_event(name, **attrs)
+    else:
+        key = (name, tuple(attrs.items()))
+        if key in _noted_without_span:
+            return
+        _noted_without_span.add(key)
+        with span(name, attrs=attrs):
+            pass
+    logger.info("%s %s", name, " ".join(f"{k}={v}" for k, v in attrs.items()))
+
+
 def span(name: str, kind: str = INTERNAL,
          attrs: Optional[Dict[str, Any]] = None,
          parent: Optional[TraceContext] = None):

@@ -17,7 +17,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.common.log import logger
 from dlrover_tpu.observability import trace
 
 
@@ -52,26 +51,14 @@ def _flash_shard_specs(mesh):
     batch over the data axes, heads over ``tp``.  The sequence stays whole
     on every shard — the kernel's causal mask is positional."""
     import flax.linen as nn
-    from jax.sharding import PartitionSpec
 
-    from dlrover_tpu.parallel.sharding import spec_for_logical_axes
+    from dlrover_tpu.parallel.sharding import spec_on_mesh
 
     rules = list(nn.get_logical_axis_rules()) or None
-
-    def spec(heads_axis):
-        full = spec_for_logical_axes(
-            ("batch", None, heads_axis, None), rules
-        )
-        out = []
-        for axis in full:
-            names = axis if isinstance(axis, tuple) else (axis,)
-            # only axes this mesh really splits (and really has)
-            out.append(
-                tuple(a for a in names if mesh.shape.get(a, 1) > 1) or None
-            )
-        return PartitionSpec(*out)
-
-    return spec("heads"), spec("kv_heads")
+    return tuple(
+        spec_on_mesh(mesh, ("batch", None, heads_axis, None), rules)
+        for heads_axis in ("heads", "kv_heads")
+    )
 
 
 def flash_attention(
@@ -122,9 +109,16 @@ def flash_attention(
         return kernel(q, k, v)
     from dlrover_tpu.parallel.collectives import shard_map_unchecked
 
+    def per_shard(q_, k_, v_):
+        # a device trace names a kernel by the innermost scope around it,
+        # which the shard_map would make "shard_map": keep the name the
+        # unsharded call has from its module (``attn._attend``)
+        with jax.named_scope("shard._attend"):
+            return kernel(q_, k_, v_)
+
     q_spec, kv_spec = _flash_shard_specs(mesh)
     return shard_map_unchecked(
-        kernel, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        per_shard, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,
     )(q, k, v)
 
@@ -137,35 +131,6 @@ def attention_path(backend: str, seq_len: int, head_dim: int) -> str:
     if backend == "tpu" and kernel_takes(seq_len, head_dim):
         return "flash"
     return "reference"
-
-
-#: ``attention.path`` records already made with no span open (a bare
-#: ``model.init``): one for each distinct reading, not one a layer
-_paths_noted_without_span = set()
-
-
-def _note_path(**attrs) -> None:
-    """One ``attention.path`` record for each trace of a step, and one
-    line in the log: an event on the span open while the step is traced
-    (``trainer.step.dispatch``), a span of its own where none is open.
-    The layers of one trace make the same reading; only the first is
-    kept.  Python runs this while tracing: it costs a step nothing."""
-    open_span = trace.current_span()
-    if open_span is not None:
-        if any(e["name"] == "attention.path" and e["attrs"] == attrs
-               for e in open_span.events):
-            return
-        open_span.add_event("attention.path", **attrs)
-    else:
-        key = tuple(attrs.items())
-        if key in _paths_noted_without_span:
-            return
-        _paths_noted_without_span.add(key)
-        with trace.span("attention.path", attrs=attrs):
-            pass
-    logger.info(
-        "attention.path %s", " ".join(f"{k}={v}" for k, v in attrs.items())
-    )
 
 
 def causal_attention(
@@ -183,8 +148,8 @@ def causal_attention(
         from dlrover_tpu.ops.pallas.tuning import tuned_blocks
 
         blocks = tuned_blocks(seq_len, head_dim)
-    _note_path(impl=impl, seq=seq_len, head_dim=head_dim, heads=heads,
-               blocks=blocks)
+    trace.note_trace_time("attention.path", impl=impl, seq=seq_len,
+                          head_dim=head_dim, heads=heads, blocks=blocks)
     if blocks is None:
         return reference_attention(q, k, v, mask)
     return flash_attention(

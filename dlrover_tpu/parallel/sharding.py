@@ -11,9 +11,15 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 MeshAxis = Union[str, Tuple[str, ...], None]
 
+#: the mesh axes a batch is split over
+DATA_AXES: Tuple[str, ...] = ("dp", "fsdp", "ep")
+
 # logical axis -> mesh axis (or tuple of axes, or None = replicated)
 DEFAULT_LOGICAL_RULES: List[Tuple[str, MeshAxis]] = [
-    ("batch", ("dp", "fsdp")),  # global batch over all data-ish axes
+    # global batch over all data-ish axes: ``ep`` ranks hold different
+    # experts and different tokens (everything outside an expert layer is
+    # plain data parallelism over them)
+    ("batch", DATA_AXES),
     ("seq", "cp"),              # context parallelism over sequence
     ("vocab", "tp"),
     ("embed", "fsdp"),          # ZeRO-3-style param shard over fsdp
@@ -22,7 +28,6 @@ DEFAULT_LOGICAL_RULES: List[Tuple[str, MeshAxis]] = [
     ("head_dim", None),
     ("mlp", "tp"),
     ("expert", "ep"),
-    ("capacity", None),         # per-expert token buffer (MoE dispatch)
     ("layers", None),           # scanned-layer leading axis stays replicated
 ]
 
@@ -77,7 +82,25 @@ def logical_to_mesh_sharding(
     )
 
 
-def shard_batch(mesh, batch, data_axes: Tuple[str, ...] = ("dp", "fsdp")):
+def spec_on_mesh(
+    mesh,
+    logical_axes: Sequence[Optional[str]],
+    rules: Optional[Sequence[Tuple[str, MeshAxis]]] = None,
+):
+    """``spec_for_logical_axes`` cut to the axes ``mesh`` really splits:
+    the specs of a ``shard_map`` around a per-shard kernel."""
+    from jax.sharding import PartitionSpec
+
+    out = []
+    for axis in spec_for_logical_axes(logical_axes, rules):
+        names = axis if isinstance(axis, tuple) else (axis,)
+        out.append(
+            tuple(a for a in names if mesh.shape.get(a, 1) > 1) or None
+        )
+    return PartitionSpec(*out)
+
+
+def shard_batch(mesh, batch, data_axes: Tuple[str, ...] = DATA_AXES):
     """Shard a host-local batch pytree onto the mesh's data axes.
 
     Every process passes its local portion; returns global jax Arrays

@@ -16,6 +16,7 @@ import threading
 from typing import Any, Iterable, Iterator, Optional, Tuple
 
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.parallel.sharding import DATA_AXES
 
 _END = object()
 
@@ -42,7 +43,7 @@ class DevicePrefetcher:
         self,
         batches: Iterable[Any],
         mesh,
-        data_axes: Tuple[str, ...] = ("dp", "fsdp"),
+        data_axes: Tuple[str, ...] = DATA_AXES,
         depth: int = 2,
     ):
         from dlrover_tpu.parallel.sharding import shard_batch

@@ -20,7 +20,7 @@ import optax
 from dlrover_tpu.observability import trace
 from dlrover_tpu.parallel import collectives
 from dlrover_tpu.parallel.collectives import GradSyncPolicy
-from dlrover_tpu.parallel.sharding import DEFAULT_LOGICAL_RULES
+from dlrover_tpu.parallel.sharding import DATA_AXES, DEFAULT_LOGICAL_RULES
 from dlrover_tpu.training_event.emitter import (
     TrainerEvents,
     get_default_emitter,
@@ -76,7 +76,7 @@ class Trainer:
         rules=None,
         loss_fn: Optional[Callable] = None,
         grad_accum_steps: int = 1,
-        data_axes: Tuple[str, ...] = ("dp", "fsdp"),
+        data_axes: Tuple[str, ...] = DATA_AXES,
         timer=None,
         grads_dtype=None,
         accum_dtype=None,
@@ -163,7 +163,10 @@ class Trainer:
         if self.grad_sync.active and mesh is not None:
             self._configure_grad_sync()
         self._warn_fp32_accum_if_needed()
-        self._loss_fn = loss_fn or self._default_loss
+        # (params, batch) -> (loss, what the model sowed into ``stats``)
+        self._loss_fn = self._default_loss if loss_fn is None else (
+            lambda params, batch: (loss_fn(params, batch), {})
+        )
         self.state_shardings = None
         self._jit_step = None
         self._jit_init = None
@@ -217,6 +220,9 @@ class Trainer:
         # native timer is attached, but the flight-recorder ring and the
         # per-rank digest file must count steps on EVERY loop shape
         self._digest_steps = 0
+        # (digest step, the model's sown ``stats`` of that step) of the
+        # last cadence tick, read at the next one
+        self._stats_kept = None
         # brain_demote staged-file watermark — _configure_grad_sync
         # already baselined it on slice meshes (a stale staging file
         # must not demote a fresh trainer); flat meshes never poll
@@ -704,21 +710,34 @@ class Trainer:
     # -- train step ----------------------------------------------------------
 
     def _default_loss(self, params, batch):
-        logits = self.model.apply({"params": params}, batch["input_ids"])
-        mask = batch.get("mask")
-        return cross_entropy_loss(logits, batch["labels"], mask)
+        """Cross entropy plus every term the model sows into its
+        ``losses`` collection, weighted by the model (a routed block's
+        load-balancing and z-loss; nothing for a dense model), and what
+        it sows into ``stats``."""
+        logits, sown = self.model.apply(
+            {"params": params}, batch["input_ids"],
+            mutable=["losses", "stats"],
+        )
+        loss = cross_entropy_loss(logits, batch["labels"], batch.get("mask"))
+        for term in jax.tree.leaves(sown.get("losses", {})):
+            loss = loss + jnp.sum(term)
+        return loss, sown.get("stats", {})
+
+    def _loss_and_grads(self, params, batch):
+        """``((loss, stats), grads)``, optionally w.r.t. a low-precision
+        param view."""
+        if self.grads_dtype is not None:
+            params = jax.tree.map(
+                lambda p: p.astype(self.grads_dtype)
+                if jnp.issubdtype(p.dtype, jnp.floating)
+                else p,
+                params,
+            )
+        return jax.value_and_grad(self._loss_fn, has_aux=True)(params, batch)
 
     def _grad_fn(self, params, batch):
-        """value_and_grad, optionally w.r.t. a low-precision param view."""
-        if self.grads_dtype is None:
-            return jax.value_and_grad(self._loss_fn)(params, batch)
-        low = jax.tree.map(
-            lambda p: p.astype(self.grads_dtype)
-            if jnp.issubdtype(p.dtype, jnp.floating)
-            else p,
-            params,
-        )
-        return jax.value_and_grad(self._loss_fn)(low, batch)
+        (loss, _), grads = self._loss_and_grads(params, batch)
+        return loss, grads
 
     def _train_step(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
         if self._sync_active:
@@ -728,8 +747,9 @@ class Trainer:
     def _exact_train_step(
         self, state: TrainState, batch
     ) -> Tuple[TrainState, Dict]:
+        stats = {}
         if self.grad_accum_steps == 1:
-            loss, grads = self._grad_fn(state.params, batch)
+            (loss, stats), grads = self._loss_and_grads(state.params, batch)
         else:
             loss_sum, grad_sum, w_sum = self._accumulate_scan(
                 state.params, batch
@@ -760,7 +780,10 @@ class Trainer:
         new_state = state.replace(
             step=state.step + 1, params=params, opt_state=opt_state
         )
-        return new_state, {"loss": loss, "grad_norm": grad_norm}
+        metrics = {"loss": loss, "grad_norm": grad_norm}
+        if stats:
+            metrics["stats"] = stats
+        return new_state, metrics
 
     # -- shared gradient accumulation --------------------------------------
 
@@ -1135,6 +1158,7 @@ class Trainer:
                 self._step_clock.record(dur)
                 self._digest_steps += 1
                 self._note_step_time(self._digest_steps, dur)
+                self._note_model_stats(self._digest_steps, result[1])
                 self._maybe_probe_comm(self._digest_steps)
             self._last_step_ts = now
         if self._timer is not None:
@@ -1288,6 +1312,37 @@ class Trainer:
             from dlrover_tpu.common.log import logger
 
             logger.debug("step digest drop failed: %s", e)
+
+    def _note_model_stats(self, step: int, metrics):
+        """Every ``DLROVER_TPU_DIGEST_EVERY`` steps, keep what the model
+        sowed into ``stats`` in this step (still in flight) and record
+        what was kept the time before, finished long since, as one
+        ``trainer.model_stats`` span whose attributes are the sown names
+        with their values layer by layer.  The stepping thread never
+        waits for the device here."""
+        stats = metrics.get("stats")
+        if not stats:
+            return
+        from dlrover_tpu.common import envs
+
+        every = envs.get_int("DLROVER_TPU_DIGEST_EVERY")
+        if every <= 0 or step % every != 0:
+            return
+        kept, self._stats_kept = self._stats_kept, (step, stats)
+        if kept is None:
+            return
+        leaves = jax.tree_util.tree_leaves_with_path(kept[1])
+        if not all(leaf.is_ready() for _, leaf in leaves):
+            return
+        attrs = {"step": kept[0]}
+        for path, leaf in leaves:
+            name = next(str(key.key) for key in reversed(path)
+                        if hasattr(key, "key"))
+            attrs.setdefault(name, []).extend(
+                jax.device_get(leaf).ravel().tolist()
+            )
+        with trace.span("trainer.model_stats", attrs=attrs):
+            pass
 
     # -- data --------------------------------------------------------------
 

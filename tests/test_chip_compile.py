@@ -232,3 +232,36 @@ class TestTrainerStep:
         # moments = 8 bytes each, a quarter of it on every chip
         assert mem.argument_size_in_bytes < 0.3 * 266e6 * 8 + (1 << 20)
 
+
+    def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
+        """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped
+        matmuls are the compiler's own kernel, forward and both gradients;
+        tokens cross chips by all-gather and reduce-scatter; the FA2 calls
+        keep ``_attend`` in their names under the shard_map (the
+        benchmark's readers find them by it); 16 of 64 experts a chip."""
+        import re
+
+        from dlrover_tpu.models.llama import LlamaForCausalLM
+        from dlrover_tpu.models.moe import MoELlamaConfig
+
+        def olmoe_one_layer():
+            cfg = MoELlamaConfig.olmoe_1b_7b(
+                num_layers=1, attention_impl="flash")
+            return LlamaForCausalLM(cfg), (8, 4096)
+
+        mesh = build_mesh(MeshConfig(ep=4), devices=list(topo.devices))
+        compiled = _trainer_step_compiled(mesh, olmoe_one_layer)
+        text = compiled.as_text()
+        calls = re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        assert sum("_attend" in name for name in calls) == 4
+        # gate, up, down: forward, recomputed forward, and two gradients
+        assert sum(name.startswith("ragged-dot-none") for name in calls) == 12
+        assert "bf16[65536,2048]" in text       # 8192 tokens x 8, one rank's
+        assert "[262144," not in text           # never all four at once
+        assert re.search(r"%all-gather[\w.\-]* = bf16\[4,8192,2048\]", text)
+        assert re.search(r"%reduce[_-]scatter[\w.\-]* = bf16\[1,8192,2048\]",
+                         text)
+        params = 16 * 3 * 2048 * 1024 + 625_000_000 - 64 * 3 * 2048 * 1024
+        assert compiled.memory_analysis().argument_size_in_bytes < (
+            params * 8 * 1.02)

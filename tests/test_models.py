@@ -42,7 +42,7 @@ class TestMesh:
     def test_spec_mapping(self):
         # "embed"->fsdp is dropped (fsdp already used by batch), then trimmed
         spec = spec_for_logical_axes(("batch", "seq", "embed"))
-        assert spec == jax.sharding.PartitionSpec(("dp", "fsdp"), "cp")
+        assert spec == jax.sharding.PartitionSpec(("dp", "fsdp", "ep"), "cp")
         # an already-used mesh axis drops the whole later mapping
         spec = spec_for_logical_axes(("embed", "batch"))
         assert spec == jax.sharding.PartitionSpec("fsdp")

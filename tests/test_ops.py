@@ -317,7 +317,8 @@ class TestGPTThroughTheKernel:
         request.getfixturevalue("kernel_by_interpreter")
         paths = []
         monkeypatch.setattr(
-            attention, "_note_path", lambda **kw: paths.append(kw["impl"])
+            attention.trace, "note_trace_time",
+            lambda name, **kw: paths.append(kw["impl"]),
         )
         run = jax.jit(jax.value_and_grad(loss, has_aux=True))
         (got_loss, got_logits), got_grads = run(params)
@@ -368,7 +369,7 @@ class TestGPTThroughTheKernel:
     ):
         from dlrover_tpu.observability import trace
 
-        monkeypatch.setattr(attention, "_paths_noted_without_span", set())
+        monkeypatch.setattr(trace, "_noted_without_span", set())
         exported = []
         trace.set_span_sink(exported.append)
         try:
