@@ -8,11 +8,29 @@ block in place of the dense SwiGLU; everything else (attention, norms,
 Routing (OLMoE, arXiv:2409.02060): ``softmax(x W_r)`` in float32 over all
 experts, the ``top_k`` largest kept with their softmax weights as they are
 (no renormalisation).  No token is ever dropped and every shape is static:
-the ``tokens x top_k`` assignments are sorted by expert, one grouped matmul
-(``jax.lax.ragged_dot``, which the TPU compiler turns into its own grouped
-kernel and skips the rows no group holds) runs over the sorted rows, and
-the results go back weighted.  The buffer of sorted rows is sized for the
-worst case, every assignment on this chip's experts.
+the ``tokens x top_k`` assignments are sorted by expert, this chip's
+first, one grouped matmul (``jax.lax.ragged_dot``, which the TPU compiler
+turns into its own grouped kernel and skips the rows no group holds) runs
+over the sorted rows, and the results go back weighted.
+
+The passes over the sorted rows (the row gather, the masks, the matmuls'
+operands, SwiGLU, the weighted sum) run at an extent chosen from the
+routing.  ``ladder`` derives a few static extents from the shapes alone:
+1.25, 1.5 and 2 times the rows expected on a chip that holds ``n_local``
+of ``num_experts`` experts, and the worst case, every assignment on this
+chip's experts.  ``jax.lax.switch`` takes the smallest that holds the rows
+in use (``sizes.sum()``, a value on the device).  The last rung holds
+whatever the router does, so dropless still holds, and every rung is the
+same mathematics: ``tests/test_moe.py`` holds a forced routing in each rung
+to the reference and to the last rung's gradients.  Inside a rung only the
+two index vectors of the sort have the extent of all assignments, and the
+gathered operand of the two sums by token (each token's ``top_k`` slots,
+those with no row here reading one row).  The ``switch`` sits under a
+``custom_vjp``: differentiated as it stands it pads every branch's
+residuals to the union of all branches, which writes the worst case in the
+small rungs; the backward pass switches over ``jax.vjp`` of the same rung
+and keeps the layer's inputs alone.  Where one chip holds every expert the
+ladder has one rung and there is no ``switch``.
 
 Expert parallelism: ``ep`` ranks hold ``num_experts / ep`` experts each and
 are data ranks for everything else.  Inside a ``shard_map`` the tokens of
@@ -30,14 +48,17 @@ adds whatever a model sows there): the load-balancing loss
 ``E * sum_e f_e P_e`` (``f_e``: share of the assignments that went to expert
 ``e``, ``P_e``: its mean router probability; 1 at uniform routing) and the
 router z-loss ``mean(logsumexp(logits)^2)``.  Into ``stats`` it sows the
-largest expert's rows over the mean, counted from the groups the matmul was
-given.  There is no count of dropped rows: the buffers hold the worst case,
-so none can be, and ``tests/test_moe.py`` holds a forced routing to the
-reference's result.
+largest expert's rows over the mean (``load_max_over_mean``), counted from
+the groups the matmul was given; the extents the chips' passes ran at over
+the rows in use (``rows_held_over_live``: 1 is no wasted row, ``ep`` the
+worst case everywhere); and the hottest chip's rows over the chips' mean
+(``chip_rows_max_over_mean``: what picks the rung on the chip the others
+wait for).  There is no count of dropped rows: none can be.
 """
 
 import dataclasses
 import functools
+import math
 
 import flax.linen as nn
 import jax
@@ -88,33 +109,152 @@ class MoELlamaConfig(LlamaConfig):
         return cls(**defaults)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take(x, perm, inv_perm, fan):
-    """``x[perm // fan]``, where ``perm`` is a permutation of
-    ``range(fan * len(x))`` and ``inv_perm`` its inverse: each row of ``x``
-    feeds ``fan`` rows of the result.  The transpose is a gather through
-    ``inv_perm`` and a sum over the ``fan`` copies, where autodiff would
-    scatter-add."""
-    return x[perm // fan]
+#: rows a rung of the ladder is rounded up to (the MXU's edge)
+_TILE = 128
 
 
-def _take_fwd(x, perm, inv_perm, fan):
-    return x[perm // fan], inv_perm
+def ladder(rows, n_local, num_experts):
+    """The static extents a pass over ``rows`` sorted assignments may run
+    at when ``n_local`` of ``num_experts`` experts are here: 1.25, 1.5 and
+    2 times the rows expected under even routing, each rounded up to a
+    tile, then ``rows`` itself, which holds whatever the routing does.
+    Where every expert is local, or the buffer is a few tiles, ``rows``
+    alone."""
+    below = {
+        -(-rows * n_local * num // (num_experts * den * _TILE)) * _TILE
+        for num, den in ((5, 4), (3, 2), (2, 1))
+    }
+    return tuple(sorted(e for e in below if e < rows)) + (rows,)
 
 
-def _take_bwd(fan, inv_perm, g):
-    copies = g[inv_perm].reshape(-1, fan, g.shape[-1])
-    return copies.sum(axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+def _sum_by_token(rows, slot, weights=None):
+    """[tokens, D] float32: for each token the sum of its assignments'
+    rows, each times its weight where ``weights`` [tokens, fan] is given.
+    ``slot`` [tokens, fan] is the row of each assignment, ``len(rows)`` or
+    more for one that has no row here and adds nothing.  A gather and a
+    sum over ``fan``; on the chip a segment sum of the rows (a
+    scatter-add) takes twice as long (PERF.md, PR 28)."""
+    if len(rows) < slot.size:
+        mine = rows.at[slot].get(mode="fill", fill_value=0)
+    else:
+        mine = rows[slot]
+    if weights is None:
+        return mine.sum(axis=1, dtype=jnp.float32)
+    return jnp.einsum("tkd,tk->td", mine, weights,
+                      preferred_element_type=jnp.float32)
 
 
-_take.defvjp(_take_fwd, _take_bwd)
+@jax.custom_vjp
+def _rows_of(x, picked, slot):
+    """``x[picked // fan]``: for each sorted row its token's.  The
+    transpose is a sum by token, where autodiff would scatter-add."""
+    return x[picked // slot.shape[1]]
 
 
-def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert):
-    """What the experts ``[first_expert, first_expert + len(gate_w))`` add
-    to the layer's result for tokens ``x`` [T, D] routed by ``top_i`` and
-    weighted by ``top_w`` (both [T, k]): ``([T, D] float32, rows each of
-    these experts processed)``."""
+def _rows_of_fwd(x, picked, slot):
+    return _rows_of(x, picked, slot), slot
+
+
+def _rows_of_bwd(slot, g):
+    return _sum_by_token(g, slot).astype(g.dtype), None, None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@jax.custom_vjp
+def _weighted_sum(rows, weights, order, slot):
+    """[tokens, D] float32: each token's rows times their weights, summed
+    (``order[:len(rows)]`` is the assignment of each row).  The transpose
+    gathers the token's cotangent for each row and weighs it there, so
+    nothing of the extent ``tokens x fan`` but a vector is built."""
+    return _sum_by_token(rows, slot, weights)
+
+
+def _weighted_sum_fwd(rows, weights, order, slot):
+    return _weighted_sum(rows, weights, order, slot), (
+        rows, weights, order, slot)
+
+
+def _weighted_sum_bwd(res, g):
+    rows, weights, order, slot = res
+    picked = order[:len(rows)]
+    g = g[picked // slot.shape[1]]
+    by_row = weights.reshape(-1)[picked].astype(jnp.float32)[:, None]
+    d_by_row = (g * rows).sum(axis=-1)
+    # back to the assignments' order: a sort by the permutation, a tenth
+    # of the time of a gather of single elements on the chip
+    d_weights = jax.lax.sort(
+        (order, jnp.pad(d_by_row, (0, len(order) - len(rows)))),
+        num_keys=1)[1]
+    return ((g * by_row).astype(rows.dtype),
+            d_weights.reshape(slot.shape).astype(weights.dtype), None, None)
+
+
+_weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
+
+
+def _rung(extent, x, weights, order, inverse, sizes, gate_w, up_w, down_w):
+    """The experts' weighted results [tokens, D] float32 from the first
+    ``extent`` sorted rows, which hold every row of ``sizes``.  Only the
+    index vectors ``order`` and ``inverse`` have the extent of all
+    assignments."""
+    picked = order[:extent]
+    slot = inverse.reshape(weights.shape)
+    # the grouped matmul leaves the rows behind the last group undefined:
+    # they are masked on the way in (so no gradient comes back through
+    # them) and on the way out
+    live = (jnp.arange(extent) < sizes.sum())[:, None]
+    rows = jnp.where(live, _rows_of(x, picked, slot), 0)
+    grouped = functools.partial(
+        jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=x.dtype
+    )
+    hidden = nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
+    out = jnp.where(live, grouped(hidden, down_w), 0)
+    return _weighted_sum(out, weights, order, slot)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _at_rung(extents, rung, x, weights, order, inverse, sizes, *expert_w):
+    """``_rung`` at ``extents[rung]``, ``rung`` a value on the device.
+    ``jax.lax.switch`` differentiated as it stands pads every branch's
+    residuals to the union of all branches, which would write the worst
+    case in the small rungs: the backward pass switches over ``jax.vjp``
+    of the same rung instead, and keeps the inputs alone."""
+    return jax.lax.switch(
+        rung, [functools.partial(_rung, extent) for extent in extents],
+        x, weights, order, inverse, sizes, *expert_w)
+
+
+def _at_rung_fwd(extents, rung, *args):
+    return _at_rung(extents, rung, *args), (rung, args)
+
+
+def _at_rung_bwd(extents, res, g):
+    def pull_back(extent):
+        def branch(x, weights, order, inverse, sizes, *expert_w):
+            return jax.vjp(
+                lambda x, weights, *expert_w: _rung(
+                    extent, x, weights, order, inverse, sizes, *expert_w),
+                x, weights, *expert_w)[1](g)
+        return branch
+
+    rung, args = res
+    d_x, d_weights, *d_expert_w = jax.lax.switch(
+        rung, [pull_back(extent) for extent in extents], *args)
+    return (None, d_x, d_weights, None, None, None, *d_expert_w)
+
+
+_at_rung.defvjp(_at_rung_fwd, _at_rung_bwd)
+
+
+def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert,
+                  num_experts=None):
+    """What the experts ``[first_expert, first_expert + len(gate_w))`` of
+    ``num_experts`` (default: these are all) add to the layer's result
+    for tokens ``x`` [T, D] routed by ``top_i`` and weighted by ``top_w``
+    (both [T, k]): ``([T, D] float32, rows each of these experts
+    processed, rows the passes ran over)``."""
     tokens, k = top_i.shape
     n_local = gate_w.shape[0]
     local = top_i - first_expert
@@ -122,23 +262,17 @@ def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert):
     # assignments of other ranks' experts sort behind every group
     key = jnp.where(mine, local, n_local).reshape(-1)
     order = jnp.argsort(key, stable=True)
-    slots = jnp.arange(tokens * k, dtype=order.dtype)
-    inverse = jnp.zeros_like(order).at[order].set(slots, unique_indices=True)
+    inverse = jnp.argsort(order)
     sizes = (key[:, None] == jnp.arange(n_local)).sum(axis=0, dtype=jnp.int32)
-    # the grouped matmul leaves the rows behind the last group undefined:
-    # they are masked on the way in (so no gradient comes back through
-    # them) and on the way out
-    live = (slots < sizes.sum())[:, None]
-    rows = jnp.where(live, _take(x, order, inverse, k), 0)
-    grouped = functools.partial(
-        jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=x.dtype
-    )
-    hidden = nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
-    out = jnp.where(live, grouped(hidden, down_w), 0)
-    out = _take(out, inverse, order, 1).reshape(tokens, k, -1)
     weights = jnp.where(mine, top_w, 0).astype(x.dtype)
-    return jnp.einsum("tkd,tk->td", out, weights,
-                      preferred_element_type=jnp.float32), sizes
+    extents = ladder(tokens * k, n_local, num_experts or n_local)
+    args = (x, weights, order, inverse, sizes, gate_w, up_w, down_w)
+    if len(extents) == 1:
+        return _rung(extents[0], *args), sizes, jnp.float32(extents[0])
+    # the smallest extent that holds every row of ``sizes``
+    rung = (sizes.sum() > jnp.asarray(extents[:-1])).sum(dtype=jnp.int32)
+    held = jnp.asarray(extents, jnp.float32)[rung]
+    return _at_rung(extents, rung, *args), sizes, held
 
 
 class MoEMLP(nn.Module):
@@ -182,7 +316,7 @@ class MoEMLP(nn.Module):
                                  ("expert", "embed", "mlp"))
             down_w = expert_weight("down_proj", (E, F, D),
                                    ("expert", "mlp", "embed"))
-            mixed, rows = self._experts(
+            mixed, rows, held, chip_rows = self._experts(
                 x.astype(cfg.dtype), top_i, top_w, gate_w, up_w, down_w
             )
             assigned = rows.astype(jnp.float32) / (B * S * k)
@@ -197,11 +331,15 @@ class MoEMLP(nn.Module):
                 * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
             )
             self.sow("stats", "load_max_over_mean", rows.max() / rows.mean())
+            self.sow("stats", "rows_held_over_live", held / (B * S * k))
+            self.sow("stats", "chip_rows_max_over_mean",
+                     chip_rows.max() / chip_rows.mean())
         return nn.with_logical_constraint(mixed, ("batch", "seq", "embed"))
 
     def _experts(self, x, top_i, top_w, gate_w, up_w, down_w):
         """``(the experts' weighted results [B, S, D], rows each of the E
-        experts processed over the whole batch)``."""
+        experts processed over the whole batch, rows the chips' passes ran
+        over in all, live rows of each chip)``."""
         from dlrover_tpu.ops.ring_attention import active_mesh
 
         cfg = self.config
@@ -220,47 +358,56 @@ class MoEMLP(nn.Module):
             )
 
         x_spec = w_spec = None
-        others = ()
+        chips = ()
         if sharded:
             from dlrover_tpu.parallel.sharding import spec_on_mesh
 
             rules = list(nn.get_logical_axis_rules()) or None
             x_spec = spec_on_mesh(mesh, ("batch", "seq", None), rules)
             w_spec = spec_on_mesh(mesh, ("expert", None, None), rules)
-            # the axes that split tokens beside ``ep``: each holds other
-            # tokens, so the rows an expert processed add up over them
-            others = tuple(
+            # the axes that split tokens: each chip along them holds other
+            # tokens, so the rows an expert processed add up over those
+            # beside ``ep``
+            chips = tuple(
                 a for axes in x_spec if axes
                 for a in (axes if isinstance(axes, tuple) else (axes,))
-                if a != "ep"
             )
+        others = tuple(a for a in chips if a != "ep")
 
         def per_shard(x, top_i, top_w, gate_w, up_w, down_w):
             tokens = (x.reshape(-1, D), top_i.reshape(-1, k),
                       top_w.reshape(-1, k))
             if ep == 1:
-                out, rows = local_experts(*tokens, gate_w, up_w, down_w, 0)
+                out, rows, held = local_experts(
+                    *tokens, gate_w, up_w, down_w, 0)
                 out = out.astype(cfg.dtype)
+                live = rows.sum()
             else:
                 first = jax.lax.axis_index("ep") * gate_w.shape[0]
 
-                # one rank's tokens at a time: the buffer of sorted rows
-                # is sized for the worst case, and only one is alive (the
-                # backward pass recomputes each in its turn)
+                # one rank's tokens at a time: only one buffer of sorted
+                # rows is alive (the backward pass recomputes each in its
+                # turn), at the extent that rank's routing asks for
                 @jax.checkpoint
                 def one_rank(its_tokens):
-                    out, rows = local_experts(
-                        *its_tokens, gate_w, up_w, down_w, first)
-                    return out.astype(cfg.dtype), rows
+                    out, rows, held = local_experts(
+                        *its_tokens, gate_w, up_w, down_w, first,
+                        cfg.num_experts)
+                    return out.astype(cfg.dtype), rows, held
 
-                out, rows = jax.lax.map(one_rank, [
+                out, rows, held = jax.lax.map(one_rank, [
                     jax.lax.all_gather(t, "ep", axis=0) for t in tokens])
                 out = jax.lax.psum_scatter(out, "ep", scatter_dimension=0)
+                held, live = held.sum(), rows.sum()
                 rows = jax.lax.all_gather(
                     rows.sum(axis=0), "ep", axis=0, tiled=True)
             if others:
                 rows = jax.lax.psum(rows, others)
-            return out.reshape(x.shape), rows
+            counts = jnp.stack([held, live.astype(held.dtype)])[None]
+            if chips:
+                counts = jax.lax.all_gather(counts, chips, axis=0, tiled=True)
+            return (out.reshape(x.shape), rows,
+                    counts[:, 0].sum(), counts[:, 1])
 
         if sharded:
             from jax.sharding import PartitionSpec
@@ -270,12 +417,15 @@ class MoEMLP(nn.Module):
             per_shard = shard_map_unchecked(
                 per_shard, mesh=mesh,
                 in_specs=(x_spec, x_spec, x_spec, w_spec, w_spec, w_spec),
-                out_specs=(x_spec, PartitionSpec()),
+                out_specs=(x_spec,) + 3 * (PartitionSpec(),),
             )
-        out, rows = per_shard(x, top_i, top_w, gate_w, up_w, down_w)
+        rows = x.shape[0] * x.shape[1] * k
         trace.note_trace_time(
             "moe.path", impl="ragged_dot", experts=cfg.num_experts,
-            top_k=k, ep=ep, tokens=x.shape[0] * x.shape[1],
-            rows=x.shape[0] * x.shape[1] * k, layers=cfg.num_layers,
+            top_k=k, ep=ep, tokens=x.shape[0] * x.shape[1], rows=rows,
+            layers=cfg.num_layers,
+            # of one pass: a source rank's assignments on one chip
+            extents=ladder(rows // math.prod(mesh.shape[a] for a in chips),
+                           cfg.num_experts // ep, cfg.num_experts),
         )
-        return out, rows
+        return per_shard(x, top_i, top_w, gate_w, up_w, down_w)

@@ -236,6 +236,8 @@ class TestTrainerStep:
     def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
         """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped
         matmuls are the compiler's own kernel, forward and both gradients;
+        the passes over the sorted rows at four extents, the small ones
+        free of worst-case buffers;
         tokens cross chips by all-gather and reduce-scatter; the FA2 calls
         keep ``_attend`` in their names under the shard_map (the
         benchmark's readers find them by it); 16 of 64 experts a chip."""
@@ -255,8 +257,29 @@ class TestTrainerStep:
         calls = re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
                            r'custom_call_target="tpu_custom_call"', text)
         assert sum("_attend" in name for name in calls) == 4
-        # gate, up, down: forward, recomputed forward, and two gradients
-        assert sum(name.startswith("ragged-dot-none") for name in calls) == 12
+        # gate, up, down: forward, recomputed forward, and two gradients,
+        # once for each of the four extents the passes may run at
+        assert sum(name.startswith("ragged-dot-none") for name in calls) == 48
+        # one switch in the forward pass and one in the backward pass
+        switches = [[name.strip() for name in names.split(",")]
+                    for names in re.findall(
+                        r"branch_computations=\{([^}]*)\}", text)]
+        assert [len(names) for names in switches] == [4, 4]
+        for names in switches:
+            *small, top = [
+                text[text.index(f"\n{name} ("):].split("\n}\n")[0]
+                for name in names]
+            for extent, body in zip((20480, 24576, 32768), small):
+                assert f"bf16[{extent},2048]" in body
+                assert f"bf16[{extent},1024]" in body
+                # of the worst case a small rung holds the index vectors
+                # and the gathers of the two sums by token, nothing else
+                assert "[65536,1024]" not in body
+                whole = [line for line in body.split("\n")
+                         if re.search(r" = \(?\w+\[65536,2048\]", line)]
+                assert 1 <= len(whole) <= 2, whole
+                assert all("kind=kCustom" in line for line in whole), whole
+            assert "bf16[65536,1024]" in top
         assert "bf16[65536,2048]" in text       # 8192 tokens x 8, one rank's
         assert "[262144," not in text           # never all four at once
         assert re.search(r"%all-gather[\w.\-]* = bf16\[4,8192,2048\]", text)

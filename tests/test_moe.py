@@ -13,7 +13,8 @@ import pytest
 
 from dlrover_tpu.models import olmoe_reference as reference
 from dlrover_tpu.models.llama import Attention, LlamaConfig, LlamaForCausalLM
-from dlrover_tpu.models.moe import MoELlamaConfig, MoEMLP, local_experts
+from dlrover_tpu.models.moe import (
+    MoELlamaConfig, MoEMLP, ladder, local_experts)
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.train import Trainer
 
@@ -123,9 +124,9 @@ class TestAgainstReference:
         logits = jax.random.normal(jax.random.PRNGKey(1), (64, 8))
         top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits), cfg.top_k)
         w = jax.random.normal(jax.random.PRNGKey(2), (3, 8, 64, 128)) * 0.1
-        out, sizes = local_experts(
+        out, sizes, held = local_experts(
             x, top_i, top_w, w[0], w[1], w[2].swapaxes(1, 2), 0)
-        assert int(sizes.sum()) == 64 * cfg.top_k
+        assert int(sizes.sum()) == 64 * cfg.top_k == int(held)
         np.testing.assert_array_equal(
             sizes, np.bincount(np.asarray(top_i).ravel(), minlength=8))
         assert out.shape == x.shape and out.dtype == jnp.float32
@@ -184,6 +185,188 @@ class TestNoTokenIsLost:
         # all rows on len(chosen) of 8 experts
         np.testing.assert_allclose(
             sown["stats"]["load_max_over_mean"][0], 8 / len(chosen))
+
+
+def _split_router(params, flagged, others):
+    """Router weights that send the tokens whose second feature is 1 to
+    the experts ``flagged`` and the rest to ``others`` (the first feature
+    is held at 1, as for ``_forced_router``)."""
+    kernel = np.zeros(params["router"]["kernel"].shape, np.float32)
+    for rank, (e, o) in enumerate(zip(flagged, others)):
+        kernel[1, e] = 40.0 - rank
+        kernel[0, o] = 20.0 - rank
+    return {**params, "router": {"kernel": jnp.asarray(kernel)}}
+
+
+#: share of a source rank's assignments that the forced routing puts on
+#: ``ep`` rank 0 -> index of the rung that rank's passes run at
+RUNGS = [(1 / 4, 0), (1 / 3, 1), (1 / 2, 2), (1.0, 3)]
+
+
+class TestEveryRung:
+    """``ep=4``, 2 of 8 experts a rank, 1024 tokens a source rank with 2
+    experts each: extents of 640, 768, 1024 and 2048 rows."""
+
+    SEQ = 1024
+
+    def _inputs(self, share):
+        cfg = MoELlamaConfig.tiny_moe(
+            num_experts=8, top_k=2, num_layers=1, dtype=jnp.float32)
+        flagged = int(self.SEQ * share)
+        x = jax.random.normal(
+            jax.random.PRNGKey(0), (8, self.SEQ, cfg.hidden_size))
+        x = x.at[..., 0].set(1.0).at[..., 1].set(
+            (jnp.arange(self.SEQ) < flagged).astype(x.dtype))
+        mlp = MoEMLP(cfg)
+        params = _split_router(_perturbed(nn.meta.unbox(
+            mlp.init(jax.random.PRNGKey(1), x[:1, :16])["params"])),
+            flagged=[0, 1], others=[2, 4])
+        return cfg, mlp, params, x, flagged
+
+    def test_the_ladder_of_these_shapes(self):
+        assert ladder(2 * self.SEQ, 2, 8) == (640, 768, 1024, 2048)
+        # every expert here, or a buffer of a few tiles: the worst case alone
+        assert ladder(2 * self.SEQ, 8, 8) == (2 * self.SEQ,)
+        assert ladder(64, 2, 8) == (64,)
+        # the benchmark's cell
+        assert ladder(8192 * 8, 16, 64) == (20480, 24576, 32768, 65536)
+
+    @pytest.mark.parametrize("share,rung", RUNGS)
+    def test_forced_routing_equals_the_reference(self, share, rung):
+        """Each rank's passes run at the smallest extent that holds its
+        rows, none is dropped, and the layer's result is the reference's;
+        the two counters say which extents were taken."""
+        cfg, mlp, params, x, flagged = self._inputs(share)
+        published = dict(PUBLISHED, num_experts_per_tok=2)
+        with jax.default_matmul_precision("highest"):
+            want = reference.experts(x, params, published)[0]
+            with build_mesh(MeshConfig(dp=2, ep=4)):
+                got, sown = jax.jit(lambda p, x: mlp.apply(
+                    {"params": p}, x, mutable=["stats", "losses"]))(params, x)
+        np.testing.assert_allclose(got, want, atol=1e-5)
+        extents = ladder(2 * self.SEQ, 2, 8)
+        # rows of one source rank on ep ranks 0 to 3
+        live = np.array([2 * flagged, self.SEQ - flagged,
+                         self.SEQ - flagged, 0])
+        taken = [next(e for e in extents if e >= n) for n in live]
+        assert taken[0] == extents[rung]
+        np.testing.assert_allclose(
+            sown["stats"]["rows_held_over_live"][0],
+            sum(taken) / (2 * self.SEQ), rtol=1e-6)
+        np.testing.assert_allclose(
+            sown["stats"]["chip_rows_max_over_mean"][0],
+            live.max() / live.mean(), rtol=1e-6)
+
+    @pytest.mark.parametrize("share,rung", RUNGS)
+    def test_result_and_gradients_equal_the_top_rung(self, share, rung):
+        """One rank's share of the layer (experts 0 and 1 of 8) at the
+        extent its routing picks against the same function at the worst
+        case alone: the result, and the gradient of the tokens, of the
+        weights and of every expert matrix, to 1e-6 of the largest
+        entry."""
+        cfg, mlp, params, x, _ = self._inputs(share)
+        tokens = x[0]
+        probs = jax.nn.softmax(tokens @ params["router"]["kernel"])
+        top_w, top_i = jax.lax.top_k(probs, cfg.top_k)
+        top_w = top_w * jax.random.uniform(
+            jax.random.PRNGKey(3), top_w.shape, minval=0.5, maxval=1.0)
+        experts = [params[n][:2] for n in ("gate_proj", "up_proj",
+                                           "down_proj")]
+
+        def run(num_experts):
+            def loss(tokens, top_w, *experts):
+                out, rows, held = local_experts(
+                    tokens, top_i, top_w, *experts, 0, num_experts)
+                return jnp.sum(out * jnp.cos(out)), (out, rows, held)
+
+            with jax.default_matmul_precision("highest"):
+                return jax.jit(jax.value_and_grad(
+                    loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                        tokens, top_w, *experts)
+
+        (_, (out, rows, held)), grads = run(8)
+        (_, (top_out, top_rows, top_held)), top_grads = run(None)
+        extents = ladder(2 * self.SEQ, 2, 8)
+        assert float(held) == extents[rung] and float(top_held) == extents[-1]
+        np.testing.assert_array_equal(rows, top_rows)
+        for got, want in zip((out,) + grads, (top_out,) + top_grads):
+            assert float(jnp.abs(want).max()) > 0
+            assert float(jnp.abs(got - want).max()) <= 1e-6 * max(
+                1.0, float(jnp.abs(want).max()))
+
+
+def _sub_jaxprs(jaxpr):
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield inner
+
+
+def _conds(jaxpr):
+    """Every ``cond`` of a jaxpr, those inside its inner jaxprs too."""
+    found = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    for inner in _sub_jaxprs(jaxpr):
+        found += _conds(inner)
+    return found
+
+
+def _avals(jaxpr):
+    found = [v.aval for e in jaxpr.eqns for v in e.outvars]
+    found += [v.aval for v in jaxpr.invars]
+    for inner in _sub_jaxprs(jaxpr):
+        found += _avals(inner)
+    return found
+
+
+class TestExtentsInTheLoweredStep:
+    TOKENS = 192  # a source rank's; 3 experts each
+
+    def _step_jaxpr(self, mesh_cfg):
+        cfg = _config(max_seq_len=self.TOKENS)
+        _, trainer = _trainer(cfg, mesh_cfg)
+        batch = _batch(cfg, seq=self.TOKENS)
+        state = trainer.create_state(jax.random.PRNGKey(0), batch["input_ids"])
+        with trainer.mesh, nn.logical_axis_rules(trainer.rules):
+            return cfg, jax.make_jaxpr(trainer._loss_and_grads)(
+                state.params, trainer.shard_batch(batch)).jaxpr
+
+    def test_small_rungs_hold_no_buffer_of_the_worst_case(self):
+        """``ep=4``, 576 assignments a source rank: extents of 256, 384
+        and 576 rows, one ``switch`` in the forward pass and one in the
+        backward pass.  Inside the two small rungs the rows and the
+        experts' hidden rows have the rung's extent; what has the extent
+        of all assignments is an index or weight vector ([rows] or
+        [tokens, k]) or the gathered operand of a sum by token ([tokens,
+        k, hidden]): no [rows, hidden], nothing of the experts' width."""
+        cfg, jaxpr = self._step_jaxpr(MeshConfig(dp=2, ep=4))
+        tokens, k = self.TOKENS, cfg.top_k
+        rows, hidden, width = tokens * k, cfg.hidden_size, cfg.intermediate_size
+        extents = ladder(rows, 2, cfg.num_experts)
+        assert extents == (256, 384, 576)
+        switches = [e for e in _conds(jaxpr)
+                    if len(e.params["branches"]) == len(extents)]
+        assert len(switches) == 2
+        allowed = {(rows,), (tokens, k), (tokens, k, 1), (tokens, k, hidden)}
+        for switch in switches:
+            *small, top = [
+                {a.shape for a in _avals(b.jaxpr) if hasattr(a, "shape")}
+                for b in switch.params["branches"]]
+            for extent, shapes in zip(extents, small):
+                assert {(extent, hidden), (extent, width)} <= shapes
+                whole = {s for s in shapes
+                         if rows in s or s[:2] == (tokens, k)}
+                assert whole <= allowed, whole - allowed
+            assert {(rows, hidden), (rows, width)} <= top
+
+    def test_one_rank_of_experts_has_no_conditional(self):
+        """``ep=1``: every assignment is local, the rows in use are the
+        buffer, and the step holds no ``cond`` at all."""
+        _, jaxpr = self._step_jaxpr(MeshConfig(dp=2))
+        assert _conds(jaxpr) == []
+        _, jaxpr = self._step_jaxpr(MeshConfig(dp=1))
+        assert _conds(jaxpr) == []
 
 
 def _mesh(mesh_cfg):
@@ -316,6 +499,10 @@ class TestDefaultLoss:
         assert len(attrs["load_max_over_mean"]) == cfg.num_layers
         assert all(v >= 1.0 for v in attrs["load_max_over_mean"])
         assert attrs["step"] % 2 == 0
+        # every expert on the one chip: the passes ran over the rows in
+        # use and no chip holds more than another
+        assert attrs["rows_held_over_live"] == [1.0] * cfg.num_layers
+        assert attrs["chip_rows_max_over_mean"] == [1.0] * cfg.num_layers
 
     def test_dense_model_step_has_no_stats_and_the_same_loss(self):
         cfg = LlamaConfig.tiny(dtype=jnp.float32)
