@@ -756,7 +756,7 @@ def worker_train(args):
         rec["layout"] = layout
         mesh = build_mesh(MeshConfig(**{"dp": 1, **layout}))
         model = LlamaForCausalLM(cfg)
-        # the optimizer and dtypes of bench.py's throughput run
+        # the optimizer and dtypes the benchmark's cells train with
         opt = create_optimizer(
             peak_lr=3e-4, warmup_steps=10, total_steps=10_000,
             moment_dtype=jnp.bfloat16,

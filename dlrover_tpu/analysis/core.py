@@ -190,7 +190,6 @@ class Config:
             "chaos_drill.py",
             "goodput_drill.py",
             "reshard_drill.py",
-            "staging_drill.py",
             "multi_controller_drill.py",
             "trace_smoke.py",
             "incident_smoke.py",

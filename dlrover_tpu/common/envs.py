@@ -393,9 +393,6 @@ register("DLROVER_TPU_RPC_GAP_LEASE_S", "float", 45.0,
          "this long")
 
 # -- flash checkpoint --------------------------------------------------------
-register("DLROVER_TPU_STREAM_STAGING", "bool", True,
-         "stream D2H chunks straight into shm (0 restores the two-phase "
-         "extract+pack path)")
 register("DLROVER_TPU_STREAM_CHUNK_BYTES", "int", 0,
          "fixed streaming chunk size; 0 = adaptive pacer")
 register("DLROVER_TPU_STAGE_PACE", "float", 0.0,
@@ -663,16 +660,6 @@ register("DLROVER_TPU_SENTINEL_CONSECUTIVE", "int", 2,
          "perf-regression sentinel: consecutive breaching samples "
          "required before a detector fires (one noisy sample must not "
          "open an incident)")
-register("DLROVER_TPU_BENCH_HISTORY", "str", "",
-         "bench.py: path of the append-only BENCH_history.jsonl round "
-         "trajectory; empty = BENCH_history.jsonl next to bench.py")
-register("DLROVER_TPU_BENCH_REGRESSION_GATE", "bool", False,
-         "bench.py: exit nonzero when the sentinel flags the current "
-         "round as a regression against the recorded trajectory "
-         "(default: flag loudly in the JSON + stderr only)")
-register("DLROVER_TPU_BENCH_TIER1_DOTS", "int", -1,
-         "bench.py: tier-1 dot count the driver passes for the "
-         "BENCH_history.jsonl entry; -1 = parse /tmp/_t1.log if present")
 
 # -- comm observatory (fabric probes + per-bucket attribution) ---------------
 register("DLROVER_TPU_COMM_PROBE_EVERY", "int", 200,
@@ -876,17 +863,6 @@ register("DLROVER_TPU_CRASH_AT_STEP", "int", -1,
          "example trainers: simulate a hard crash at this step; -1 off")
 register("DLROVER_TPU_TOTAL_STEPS", "int", 0,
          "example trainers: total steps to run; 0 = per-example default")
-register("DLROVER_TPU_BENCH_BUDGET_S", "float", 1500.0,
-         "flash-checkpoint bench: wall budget that picks the largest "
-         "config")
-register("DLROVER_TPU_STAGING_DRILL_MB", "int", 192,
-         "staging drill: state size in MB")
-register("DLROVER_TPU_STAGING_DRILL_CHUNK_MB", "int", 4,
-         "staging drill: pinned chunk size in MB")
-register("DLROVER_TPU_BENCH_PRESET", "str", "default",
-         "bench.py preset (tiny for smoke runs)")
-register("DLROVER_TPU_BENCH_SKIP_GOODPUT", "bool", False,
-         "bench.py: skip the goodput drill leg")
 register("DLROVER_TPU_RESHARD_FIT_GATE", "bool", True,
          "live reshard (r22): refuse transition plans the r17 measured "
          "fit report says do not fit the surviving per-chip HBM; "

@@ -26,9 +26,8 @@ degrade goodput until cured or ridden out.  Timestamps are synthetic
 1s-spaced and anchored in the past (the r16/r17 drill pattern), so a
 400-tick fleet day runs in seconds, deterministically.
 
-Output: ``BENCH_brain.json`` — per-mode fleet goodput, the
-``fleet_goodput_gain`` headline the bench-history gate watches, the
-decision log, and the restart-vs-ride-out DRILL (one incident resolved
+Output (``--json-out``; nothing is written without it): per-mode fleet
+goodput, the ``fleet_goodput_gain`` headline, the decision log, and the restart-vs-ride-out DRILL (one incident resolved
 by ride-out with the incident engine confirming no restart, one by a
 Brain-ordered restart, each chosen by the priced cost model).
 

@@ -95,8 +95,8 @@ class FleetConfig:
 
 #: the headline >=500-agent workload shape: wait-dominated coordination,
 #: the regime the control plane actually lives in at fleet scale.
-#: Shared by bench.py's nightly 1k run and the CLI preset below so the
-#: two "1k headline" results stay comparable.
+#: One shape for every caller of the CLI preset below, so that "1k
+#: headline" results stay comparable.
 HEADLINE_SHAPE = dict(
     stagger_s=10.0, barriers=5, barrier_delay_s=20.0,
     heartbeats=6, shards_per_agent=2, straggler_s=10.0,
@@ -441,8 +441,8 @@ def _storm_session(session: int, master: _Master, cfg: FleetConfig,
 
 
 def _red_slice() -> Dict[str, Any]:
-    """The control-plane subset of the RED snapshot (full snapshots ride
-    bench.py; the fleet report keeps the attributable counters)."""
+    """The control-plane subset of the RED snapshot (the fleet report
+    keeps the attributable counters)."""
     snap = obs_metrics.registry().snapshot()
     keep = (
         "dlrover_tpu_rpc_requests_total",

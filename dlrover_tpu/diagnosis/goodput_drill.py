@@ -17,9 +17,8 @@ Run standalone::
 
     python -m dlrover_tpu.diagnosis.goodput_drill
 
-Wired callers: ``bench.py`` embeds the result under ``detail.goodput``
-(the BENCH goodput entry), and ``tests/test_goodput_drill.py`` (slow
-tier) asserts goodput_pct >= 90 with >= 2 injected faults.
+Wired caller: ``tests/test_goodput_drill.py`` (slow tier) asserts
+goodput_pct >= 90 with >= 2 injected faults.
 """
 
 import json
@@ -265,8 +264,8 @@ def _run_goodput_drill_once(
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("DLROVER_TPU_MASTER_ADDR", None)
     # the drill measures fault-tolerance goodput (a control-plane number),
-    # not device compute: pin the whole stack to CPU so a drill run inside
-    # bench.py can never contend with the bench's own TPU session
+    # not device compute: pin the whole stack to CPU so a drill run beside
+    # a process that holds the TPU can never contend for it
     env["JAX_PLATFORMS"] = "cpu"
     env.update(
         {

@@ -44,8 +44,8 @@ answer here.  This module is that measurement layer, three pieces:
     span carrying the resolved transport tier, the sync mesh axis, the
     wire bytes (``collectives.estimate_bucket_bytes``), and the
     achieved GB/s — the flight recorder and the merged Perfetto
-    timeline get comm lanes, ``grad_sync_bench`` gets its per-bucket
-    rows, and ``BENCH_comm.json`` gets hardware numbers.
+    timeline get comm lanes, and ``grad_sync_bench`` gets its
+    per-bucket rows.
 
 :class:`CommScope` (process singleton, :func:`scope`)
     Ties it together and keeps the ``exposed_comm`` SUB-account: when a
@@ -434,8 +434,7 @@ class BucketScope:
 
     def measure(self, reps: int = 3) -> List[Dict[str, Any]]:
         """Time every bucket's chain; returns per-bucket rows (the
-        shape ``grad_sync_bench`` reports and ``BENCH_comm.json``
-        stores)."""
+        shape ``grad_sync_bench`` reports)."""
         import time as _time
 
         import jax

@@ -26,9 +26,8 @@ Design constraints, in order:
    under a race is an off-by-one in an informational field, never
    corruption).
 2. **Overhead budgeted and measured.**  :func:`measure_overhead` times
-   the real append path; ``bench.py`` records it per round as a
-   fraction of a measured step so regressions show in the BENCH
-   trajectory (acceptance: < 1% of step time).
+   the real append path (acceptance: < 1% of step time;
+   ``tests/test_flight_recorder.py`` holds the per-append cost).
 3. **Feeds are one-directional.**  ``trace._export`` pushes finished
    spans, ``training_event.emitter`` pushes BEGIN/END/INSTANT
    events, the chaos engine pushes fired faults, ``Trainer.train_step``

@@ -203,8 +203,7 @@ class MetricsRegistry:
 
     def snapshot(self) -> Dict[str, Any]:
         """JSON-friendly dump: counters/gauges verbatim, histograms as
-        count/sum/avg per series — the shape bench.py records as the
-        per-round RED snapshot."""
+        count/sum/avg per series."""
         self._collect()
         with self._mu:
             out: Dict[str, Any] = {

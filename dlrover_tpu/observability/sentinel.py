@@ -1,20 +1,13 @@
 """Perf-regression sentinel: EWMA+MAD detectors over the perf timeline.
 
-Two deployment points, one detector:
-
-* **Master-side** — diagnosticians
-  (:class:`GoodputRegressionDiagnostician`,
-  :class:`StepTimeRegressionDiagnostician`,
-  :class:`ExposedCommDiagnostician`) watch the job series the
-  ``master/timeseries.py`` store accumulates from heartbeat digests
-  (``job.goodput``, ``job.step_p50_s``, ``job.share.exposed_comm``) and
-  fire through the normal ``DiagnosisManager`` loop — which opens a
-  classified incident via the r12 ``IncidentManager`` (the flight dumps
-  + chaos attribution then say *why* the curve moved).
-* **Bench-side** — :func:`compare_round` replays the recorded
-  ``BENCH_history.jsonl`` trajectory through the same detector and
-  judges the current round, so a perf regression fails loudly at bench
-  time instead of surfacing rounds later.
+Master-side diagnosticians (:class:`GoodputRegressionDiagnostician`,
+:class:`StepTimeRegressionDiagnostician`,
+:class:`ExposedCommDiagnostician`) watch the job series the
+``master/timeseries.py`` store accumulates from heartbeat digests
+(``job.goodput``, ``job.step_p50_s``, ``job.share.exposed_comm``) and
+fire through the normal ``DiagnosisManager`` loop — which opens a
+classified incident via the r12 ``IncidentManager`` (the flight dumps
++ chaos attribution then say *why* the curve moved).
 
 The detector is EWMA+MAD: an exponentially-weighted baseline plus an
 exponentially-weighted mean absolute deviation (the streaming MAD
@@ -26,8 +19,7 @@ samples do not feed the baseline (the regression must stay visible),
 and a fire re-baselines so one regime change is one alert.
 """
 
-import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional
 
 from dlrover_tpu.common import envs
 from dlrover_tpu.diagnosis.diagnosis_action import (
@@ -896,100 +888,3 @@ def register_sentinels(diagnosis_manager, timeseries,
     for sentinel in sentinels:
         diagnosis_manager.register(sentinel)
     return sentinels
-
-
-# ---------------------------------------------------------------------------
-# Bench-side gate: judge the current round against the recorded
-# trajectory (BENCH_history.jsonl).
-# ---------------------------------------------------------------------------
-
-#: watched history fields: dotted path into an entry -> the direction
-#: that is a REGRESSION
-BENCH_WATCH: Dict[str, str] = {
-    "step_ms": "up",
-    "tokens_per_sec": "down",
-    "vs_baseline": "down",
-    "blocking_save_s": "up",
-    "compile_s": "up",
-    "cache_hit_ratio": "down",
-    "fleet_goodput_gain": "down",
-    # r22: the live in-place transition must stay cheap, and keep its
-    # edge over the restart path it replaces
-    "live_reshard_s": "up",
-    "reshard_speedup_vs_restart": "down",
-    # r24: a failure must stay sub-budget, and the peer rung must keep
-    # its bandwidth edge over the storage path it bypasses
-    "recovery_mttr_s": "up",
-    "peer_read_gbps": "down",
-    # r25: the data pipeline must keep dispatching fast (lease p99,
-    # throughput) and the ledger must not drift toward starvation
-    "data_p99_ms": "up",
-    "shards_per_s": "down",
-    "gp_input_starved": "up",
-}
-
-
-def _comparable(entry: Dict[str, Any], current: Dict[str, Any]) -> bool:
-    """Only rounds measured under the same conditions feed the
-    baseline: a CPU-fallback round must not judge (or be judged by) a
-    real-hardware trajectory, and a degraded round whose HEADLINE was
-    adopted from a chip run's capture (hardware headline, CPU
-    drill numbers) is comparable only to other such mixed rounds."""
-    return (
-        bool(entry.get("tpu_unavailable"))
-        == bool(current.get("tpu_unavailable"))
-        and entry.get("preset") == current.get("preset")
-        and entry.get("headline_source") == current.get("headline_source")
-    )
-
-
-def compare_round(
-    history: Sequence[Dict[str, Any]],
-    current: Dict[str, Any],
-    watch: Optional[Dict[str, str]] = None,
-) -> Dict[str, Any]:
-    """Replay the comparable history through a fresh detector per
-    watched metric, then judge the current round's value.  Returns
-    ``{"regressions": [...], "checked": {metric: verdict}}``; a metric
-    without enough comparable history is reported ``"cold"`` and never
-    fails the gate."""
-    watch = watch or BENCH_WATCH
-    comparable = [e for e in history if _comparable(e, current)]
-    checked: Dict[str, Any] = {}
-    regressions: List[str] = []
-    for metric, bad_direction in watch.items():
-        value = current.get(metric)
-        if value is None:
-            continue
-        detector = EwmaMadDetector(
-            direction=bad_direction, consecutive=1
-        )
-        fed = 0
-        for entry in comparable:
-            past = entry.get(metric)
-            if past is None:
-                continue
-            detector.update(float(past))
-            fed += 1
-        if fed < detector.min_samples:
-            checked[metric] = {"verdict": "cold", "history": fed}
-            continue
-        breach = detector.update(float(value))
-        if breach is not None:
-            checked[metric] = {
-                "verdict": "regression", "history": fed, **breach,
-            }
-            regressions.append(metric)
-        else:
-            checked[metric] = {
-                "verdict": "ok", "history": fed,
-                "baseline": round(detector.baseline, 6),
-                "value": round(float(value), 6),
-            }
-    return {
-        "regressions": regressions,
-        "ok": not regressions,
-        "checked": checked,
-        "comparable_rounds": len(comparable),
-        "ts": round(time.time(), 3),
-    }

@@ -22,11 +22,14 @@ It only has to rank candidates consistently with the byte meter, which
 is what the tuner smoke and ``grad_sync_bench`` assert on CPU; on
 hardware the measured snapshot feeds the same formulas real numbers.
 
-Cold start: before the first live probe fires, the last
-``BENCH_comm.json``'s ``fabric`` section (:func:`seed_snapshot`) seeds
-the plan; with no bench file either, the static ladder stands.  The
-``ring_rdma`` tier is only eligible once a bench run on the chip proved it
-end-to-end (:func:`rdma_proven` on ``BENCH_grad_overlap.json``)."""
+Cold start: before the first live probe fires, the ``fabric`` section of
+the file ``DLROVER_TPU_TUNER_SEED_FILE`` names (:func:`seed_snapshot`; by
+default ``BENCH_comm.json`` in the working directory, which
+``grad_sync_bench`` writes and git does not hold) seeds the plan; with no
+such file, as in a checkout, the static ladder stands.  The ``ring_rdma``
+tier is only eligible once a ``grad_sync_bench`` run on the chip proved
+it end-to-end (:func:`rdma_proven` on the ``BENCH_grad_overlap.json``
+that run left in the working directory)."""
 
 from __future__ import annotations
 
@@ -102,10 +105,11 @@ class TunerPlan:
 
 
 def seed_snapshot(path: Optional[str] = None) -> Optional[Dict]:
-    """Cold-start fabric snapshot from the last ``BENCH_comm.json``
+    """Cold-start fabric snapshot from a ``grad_sync_bench`` comm file
     (its ``fabric`` section IS ``FabricModel.snapshot()`` output).
-    None when the file is missing/unreadable/empty — the static ladder
-    stands until the first live probe."""
+    None when the file is missing/unreadable/empty, as in a checkout
+    (git holds no such file) — the static ladder stands until the first
+    live probe."""
     if path is None:
         path = envs.get_str("DLROVER_TPU_TUNER_SEED_FILE")
     if not path:
@@ -130,10 +134,11 @@ def seed_snapshot(path: Optional[str] = None) -> Optional[Dict]:
 
 
 def rdma_proven(path: str = "BENCH_grad_overlap.json") -> bool:
-    """True only when a bench run on the chip drove the ``ring_rdma``
-    Pallas kernel end-to-end on real hardware and recorded ``status ==
-    "ok"`` — the tuner must never route production gradients through a
-    tier whose lowering was never executed."""
+    """True only when a ``grad_sync_bench`` run on the chip drove the
+    ``ring_rdma`` Pallas kernel end-to-end on real hardware and left
+    ``status == "ok"`` in the working directory's round file (git holds
+    none: False in a checkout) — the tuner must never route production
+    gradients through a tier whose lowering was never executed."""
     try:
         with open(path) as f:
             evidence = json.load(f).get("ring_rdma")

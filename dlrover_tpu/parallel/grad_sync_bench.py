@@ -20,10 +20,9 @@ refinement indices) itemized — ``collectives.estimate_bucket_bytes`` —
 fixing the r6 single-tensor estimate that under-counted blockwise
 formats.  CPU step times bound the NUMERICS overhead (the XLA program
 is the same shape the TPU runs); wire bytes are topology math, valid
-for any backend.  Consumed by ``bench.py`` (``detail.grad_sync``) and
-written standalone to ``BENCH_grad_overlap.json`` so a chip run's
-bench stage captures real-hardware numbers automatically when the
-probe succeeds.
+for any backend.  Written to ``BENCH_grad_overlap.json`` and
+``BENCH_comm.json`` at the checkout's root (git ignores both), where
+``fabric_tuner.rdma_proven`` and ``seed_snapshot`` look for them.
 
 Run standalone::
 
@@ -491,10 +490,9 @@ def _ring_rdma_evidence(devices) -> Dict:
 
 
 def write_comm_file(comm: Dict, path: str = None):
-    """Persist the standalone comm round file (BENCH_comm.json) at the
-    repo root so a chip run capture probe-measured axis
-    bandwidths + per-bucket exposed ms even when the parent bench
-    dies."""
+    """Persist the comm round file (BENCH_comm.json; git ignores it) at
+    the repo root: probe-measured axis bandwidths + per-bucket exposed
+    ms."""
     _write_repo_file(comm, "BENCH_comm.json", path)
 
 
@@ -759,15 +757,14 @@ def _write_repo_file(payload: Dict, filename: str, path: str = None):
 
 
 def write_round_file(result: Dict, path: str = None):
-    """Persist the standalone round file (BENCH_grad_overlap.json) next
-    to the repo root so a chip run pick it up even when
-    the parent bench dies before printing."""
+    """Persist the round file (BENCH_grad_overlap.json; git ignores
+    it) at the repo root."""
     _write_repo_file(result, "BENCH_grad_overlap.json", path)
 
 
 def main() -> int:
-    """Subprocess entry: force a virtual multi-device CPU backend and
-    print one JSON line (consumed by bench.py)."""
+    """Entry point: force a virtual multi-device CPU backend, write the
+    two round files and print one JSON line."""
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=4"

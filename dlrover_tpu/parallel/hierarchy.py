@@ -39,7 +39,7 @@ Per-tier bytes accounting
     :func:`estimate_tiered_bytes` itemizes a bucket layout's
     reduce-scatter + all-gather bytes per fabric tier (metadata
     included) for both the flat and hierarchical programs — the
-    numbers ``grad_sync_bench`` writes into ``BENCH_grad_overlap.json``
+    numbers ``grad_sync_bench`` reports in its ``hierarchy`` section
     and the smoke's DCN-reduction assertion reads.
 """
 

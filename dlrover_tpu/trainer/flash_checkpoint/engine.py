@@ -371,12 +371,6 @@ class CheckpointEngine:
         # trainer blocking at (remaining extraction time); the sync
         # fallback after it guarantees the recovery point still advances.
         self._slot_wait_s = envs.get_float("DLROVER_CKPT_SLOT_WAIT_S")
-        # Streaming staging (default): the stager precomputes the shm
-        # layout and lands each paced D2H chunk directly at its final
-        # offset — no intermediate full host copy, and the device copy
-        # frees as chunks land.  "0" restores the two-phase extract +
-        # pack path.
-        self._stream_staging = envs.get_bool("DLROVER_TPU_STREAM_STAGING")
         # Buffer-lock acquisition bound for the stager and blocking
         # saves.  The default must outlast a legitimate in-flight
         # STREAM, not just a memcpy: the streaming stager holds the
@@ -770,7 +764,7 @@ class CheckpointEngine:
             # box.free().  Storage saves supersede a queued memory item
             # too: its purpose is subsumed by the same-or-newer shm
             # write, and freeing it hands us the slot instantly instead
-            # of waiting out its throttled extraction.
+            # of waiting out its paced stream.
             if self._live_copies > 0:
                 self._stager.drop_queued_memory()
             with self._copy_cv:
@@ -872,13 +866,12 @@ class CheckpointEngine:
         """Stager thread body: stage the device copy into shm, maybe
         emit the persist event.
 
-        Streaming (default): the shm layout is precomputed from abstract
-        shapes, the buffer lock is taken for the WHOLE stream (shm is
-        mid-rewrite the entire time — the seqlock generation additionally
-        marks it dirty for lock-free readers), and each paced D2H chunk
-        lands directly at its final offset, releasing its share of the
-        on-device copy as it goes.  Two-phase (opt-out): host-stage the
-        whole copy first, then lock briefly for one packed write.
+        The shm layout is precomputed from abstract shapes, the buffer
+        lock is taken for the WHOLE stream (shm is mid-rewrite the entire
+        time — the seqlock generation additionally marks it dirty for
+        lock-free readers), and each paced D2H chunk lands directly at
+        its final offset, releasing its share of the on-device copy as
+        it goes.
 
         All of it is one ``flash.stage`` span, child of the ``flash.save``
         that submitted it (``box.ctx``), which carries at its close what the
@@ -915,22 +908,10 @@ class CheckpointEngine:
     ):
         self._ensure_registered()
         snap = box.take()
-        if self._stream_staging:
-            # plan only (no transfer): refs move into the leaves list so
-            # streaming can release them shard by shard
-            leaves = snapshot.plan_shards(snap)
-            del snap
-        else:
-            # throttled: bound the device-queue transfer backlog so
-            # concurrent train steps wait behind one leaf, not the state
-            leaves = snapshot.extract_host_shards(
-                snap, throttled=True, pacer=pacer, counters=counters
-            )
-            del snap
-            # the on-device copy is host-staged: release the HBM
-            # accounting slot so the next async save may dispatch while
-            # we write shm
-            box.free()
+        # plan only (no transfer): refs move into the leaves list so
+        # streaming can release them shard by shard
+        leaves = snapshot.plan_shards(snap)
+        del snap
         persist_step = step if persist else None
         staged = False
         t_lock = time.perf_counter()
@@ -959,20 +940,14 @@ class CheckpointEngine:
                         )
                         step = int(meta["step"])
                     elif not (meta and meta["step"] == step):
-                        if self._stream_staging:
-                            pacer.clock.staging_started()
-                            try:
-                                snapshot.stream_snapshot(
-                                    self._shm, step, leaves, extras,
-                                    pacer=pacer, counters=counters,
-                                )
-                            finally:
-                                pacer.clock.staging_finished()
-                        else:
-                            snapshot.write_snapshot(
+                        pacer.clock.staging_started()
+                        try:
+                            snapshot.stream_snapshot(
                                 self._shm, step, leaves, extras,
-                                counters=counters,
+                                pacer=pacer, counters=counters,
                             )
+                        finally:
+                            pacer.clock.staging_finished()
                     staged = True
                 finally:
                     box.free()

@@ -9,8 +9,8 @@ times it: create state on mesh A (dp1/fsdp2/tp2/cp2), train a step, save
 to storage, restore onto mesh B (dp2/fsdp4), assert bit-level loss
 continuity, then train one more step on the new mesh.
 
-Used by both the driver-facing ``__graft_entry__.dryrun_multichip`` (the
-"reshard OK" leg) and ``bench.py`` (the ``restore_reshard_s`` metric).
+Used by the driver-facing ``__graft_entry__.dryrun_multichip`` (the
+"reshard OK" leg).
 """
 
 import contextlib
@@ -197,9 +197,8 @@ def run_reshard_drill(
             result["grad_sync_reshard"] = {"error": str(e)[:300]}
         gs = result.get("grad_sync_reshard") or {}
         if "live_reshard_s" in gs:
-            # gate-watched columns (BENCH_history.jsonl): the live
-            # transition's ledger price and its edge over the restart
-            # path, both from the SAME ledger account
+            # the live transition's ledger price and its edge over the
+            # restart path, both from the SAME ledger account
             result["live_reshard_s"] = gs["live_reshard_s"]
             result["reshard_speedup_vs_restart"] = (
                 gs["reshard_speedup_vs_restart"]
@@ -313,9 +312,9 @@ def run_grad_sync_reshard_leg(devices, batch, tag: str) -> Dict:
 
         # -- live leg (r22): the SAME dp4 -> dp2 transition in place on
         # the still-running dp4 trainer, priced by the SAME ledger the
-        # restart restore was — the apples-to-apples speedup bench.py
-        # lifts into BENCH_history.jsonl.  Bit-exactness against the
-        # restart-restored state is the correctness gate.
+        # restart restore was — the apples-to-apples speedup.
+        # Bit-exactness against the restart-restored state is the
+        # correctness gate.
         live_phases: Dict = {}
         with _ledger_phases(live_phases):
             state_live, live_report = trainer_c.live_reshard(
@@ -367,7 +366,7 @@ def run_grad_sync_reshard_leg(devices, batch, tag: str) -> Dict:
 
 def main() -> int:
     """Subprocess entry: force an 8-virtual-device CPU backend and print
-    one JSON line (consumed by bench.py)."""
+    one JSON line."""
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"

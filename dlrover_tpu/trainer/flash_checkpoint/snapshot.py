@@ -17,11 +17,12 @@ The meta carries *global* index ranges, so any reader (the agent's async
 saver, a restore with a different mesh) can reassemble without knowing the
 original sharding.
 
-Two write paths share the format:
+Two writers share the format:
 
-- ``write_snapshot`` — the two-phase path: host arrays already staged
-  (``extract_host_shards``), packed with one memcpy per shard.
-- ``plan_shards`` + ``stream_snapshot`` — the streaming path: the shm
+- ``write_snapshot`` — the blocking save, the synchronous fallback and
+  peer restore: host arrays already staged (``extract_host_shards``,
+  every transfer kicked up front), packed with one memcpy per shard.
+- ``plan_shards`` + ``stream_snapshot`` — the background stager: the shm
   layout (every shard's byte offset) is computed from abstract shapes
   BEFORE any transfer, then each paced D2H chunk lands directly at its
   final shm offset.  No intermediate full host copy exists, so host peak
@@ -29,7 +30,7 @@ Two write paths share the format:
   costs exactly ONE host-side copy (the zero-copy invariant, counted
   by ``StageCounters``: ``host_copies == chunks``).
 
-Both paths run the seqlock-style generation commit: the generation word
+Both run the seqlock-style generation commit: the generation word
 is bumped to ODD before any byte of meta/payload changes and bumped back
 to EVEN only after the meta length is restored.  A writer killed
 mid-stream leaves an odd generation; readers (``read_snapshot_meta``,
@@ -72,9 +73,9 @@ _MIN_BASELINE_S = 0.005
 
 class StageCounters:
     """What one staging of a snapshot did, counted where it happens: the
-    attributes of the ``flash.stage`` span, and what ``staging_drill``
-    and the tests read when they stage without an engine.  Chunks are
-    counted, not spanned: a save has hundreds to thousands of them.
+    attributes of the ``flash.stage`` span, and what the tests read
+    when they stage without an engine.  Chunks are counted, not spanned:
+    a save has hundreds to thousands of them.
 
     ``host_copies`` counts every host-side buffer copy: the streaming
     path's promise is one per chunk, and any refactor that slips an
@@ -258,60 +259,6 @@ class StagePacer:
         }
 
 
-def _chunked_to_host(
-    arr, pacer: StagePacer, counters: Optional[StageCounters] = None,
-) -> np.ndarray:
-    """Device->host copy of one shard in pacer-sized chunks.
-
-    Chunks are on-device slices along the widest axis; each slice is a
-    tiny HBM-to-HBM copy, so the device queue is occupied in chunk-sized
-    grains and a train step dispatched mid-staging waits behind at most
-    one chunk instead of the whole shard."""
-    c = counters if counters is not None else StageCounters()
-    np_dtype = np.dtype(arr.dtype)
-    nbytes = int(np.prod(arr.shape)) * np_dtype.itemsize if arr.shape else (
-        np_dtype.itemsize
-    )
-    if not arr.shape or nbytes <= pacer.chunk_bytes or nbytes <= 2 * _MIN_CHUNK:
-        pacer.gate()
-        t0 = time.perf_counter()
-        out = np.asarray(arr)
-        waited = time.perf_counter() - t0
-        pacer.note_transfer(nbytes, waited)
-        c.d2h_wait_s += waited
-        # no host copy: the D2H lands DIRECTLY in the returned array —
-        # unlike the chunked branch below, no intermediate host buffer
-        # exists here (transfers are not host-side copies)
-        c.chunk(nbytes)
-        return out
-    axis = int(np.argmax(arr.shape))
-    n_rows = arr.shape[axis]
-    row_bytes = max(1, nbytes // n_rows)
-    out = np.empty(arr.shape, np_dtype)
-    dst = np.moveaxis(out, axis, 0)
-    start = 0
-    while start < n_rows:
-        rows = max(1, int(pacer.chunk_bytes // row_bytes))
-        stop = min(n_rows, start + rows)
-        pacer.gate()
-        import jax.lax
-
-        t0 = time.perf_counter()
-        chunk = jax.lax.slice_in_dim(arr, start, stop, axis=axis)
-        t1 = time.perf_counter()
-        host = np.asarray(chunk)
-        t2 = time.perf_counter()
-        pacer.note_transfer((stop - start) * row_bytes, t2 - t1)
-        c.slice_s += t1 - t0
-        c.d2h_wait_s += t2 - t1
-        c.chunk((stop - start) * row_bytes)
-        # the intermediate host materialization the streaming path avoids
-        c.host_copy((stop - start) * row_bytes)
-        dst[start:stop] = np.moveaxis(host, axis, 0)
-        start = stop
-    return out
-
-
 from dlrover_tpu.common.pytree import path_str as _path_str  # noqa: E402
 
 
@@ -401,12 +348,14 @@ def _enumerate_shards(state: Any) -> List[Dict]:
 
 
 def extract_host_shards(
-    state: Any, throttled: bool = False,
-    pacer: Optional["StagePacer"] = None,
-    counters: Optional[StageCounters] = None,
+    state: Any, counters: Optional[StageCounters] = None,
 ) -> List[Dict]:
     """Flatten a pytree of (possibly sharded) jax Arrays into this
-    process's shard list.
+    process's shard list, every shard copied to the host: what the
+    blocking save, the synchronous fallback and the tests hand to
+    ``write_snapshot``.  (The background stager never calls this: it
+    plans with ``plan_shards`` and ``stream_snapshot`` moves the bytes,
+    paced, chunk by chunk.)
 
     ALL addressable shards are snapshotted (not just replica 0): a
     process's shm must be self-sufficient for a same-mesh restart, and
@@ -416,26 +365,14 @@ def extract_host_shards(
     price of local restartability (same trade the reference makes for DDP
     shm snapshots).
 
-    ``throttled=False`` (the blocking save path) kicks every
-    device->host DMA up front so transfers overlap maximally — lowest
-    total staging time.  ``throttled=True`` (the background stager)
-    routes transfers through the auto-pacing ``StagePacer``: shards are
-    copied in bandwidth-calibrated CHUNKS so a train step dispatched
-    mid-staging waits behind at most one chunk (bounded to keep observed
-    step inflation under ``DLROVER_TPU_STAGE_FACTOR``, default 1.5x),
-    with full-speed draining whenever the step clock reports training
-    idle.  (History: un-throttled staging of a multi-GB state over a
-    slow device->host link stalled a concurrent step for minutes; a
-    manual per-shard pace knob cut that; chunked feedback pacing bounds
-    it to a factor.)
-
-    The async prefetch (unthrottled path) is issued on the per-shard
-    ``shard.data`` arrays — the same objects later converted — NOT on
-    the parent leaf: a parent-level ``copy_to_host_async`` caches on the
-    parent, and ``np.asarray(shard.data)`` would then run a second,
-    synchronous transfer, doubling D2H traffic and defeating the
-    pipeline."""
-    # phase 1: enumerate shards (dedup identical local replicas)
+    Every device->host DMA is kicked up front so transfers overlap
+    maximally — lowest total staging time, for a caller that is blocked
+    until the last byte lands anyway.  The async prefetch is issued on
+    the per-shard ``shard.data`` arrays — the same objects later
+    converted — NOT on the parent leaf: a parent-level
+    ``copy_to_host_async`` caches on the parent, and
+    ``np.asarray(shard.data)`` would then run a second, synchronous
+    transfer, doubling D2H traffic and defeating the pipeline."""
     leaves = _enumerate_shards(state)
     shard_arrays = [
         shard["data"]
@@ -443,25 +380,7 @@ def extract_host_shards(
         for shard in leaf["shards"]
         if not isinstance(shard["data"], np.ndarray)
     ]
-
-    # phase 2: device->host with the chosen pipelining policy
     c = counters if counters is not None else StageCounters()
-    if throttled:
-        pacer = pacer or StagePacer()
-        slept = pacer.slept_s
-        pacer.clock.staging_started()
-        try:
-            for leaf in leaves:
-                for shard in leaf["shards"]:
-                    if isinstance(shard["data"], np.ndarray):
-                        continue
-                    shard["data"] = _chunked_to_host(
-                        shard["data"], pacer, c
-                    )
-        finally:
-            pacer.clock.staging_finished()
-            c.pace_sleep_s += pacer.slept_s - slept
-        return leaves
 
     def _kick(arr) -> bool:
         try:
@@ -779,10 +698,11 @@ def write_snapshot(
     extras: Optional[Dict] = None,
     counters: Optional[StageCounters] = None,
 ) -> int:
-    """Two-phase pack of host-staged leaves into shm; returns total
-    bytes used.  (The streaming path is ``plan_shards`` +
-    ``stream_snapshot``; this one remains for the blocking save, whose
-    arrays were already host-staged with maximally overlapped D2H.)"""
+    """Pack host-staged leaves (``extract_host_shards``) into shm;
+    returns total bytes used.  For the callers that are blocked until
+    the snapshot lands: the blocking save, the synchronous fallback,
+    peer restore.  (The stager's writer is ``plan_shards`` +
+    ``stream_snapshot``.)"""
     for leaf in leaves:
         for shard in leaf["shards"]:
             shard["data"] = np.ascontiguousarray(shard["data"])

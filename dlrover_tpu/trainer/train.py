@@ -518,7 +518,7 @@ class Trainer:
 
     def _maybe_retune(self, source: str = "probe"):
         """Price the transport × stripe grid against the freshest
-        fabric view (live probe snapshot, else the ``BENCH_comm.json``
+        fabric view (live probe snapshot, else ``fabric_tuner``'s
         cold-start seed) and stage the winning plan when it clears the
         hysteresis gate.  Returns the staged plan or None.  Safe from
         the sentinel thread — staging rides the demotion lock."""

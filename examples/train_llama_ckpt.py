@@ -76,9 +76,9 @@ def main() -> int:
     for step in range(start_step + 1, total_steps + 1):
         state, metrics = trainer.train_step(state, batch)
         if first_resumed_step:
-            # recovery benchmark marker: the step is only claimed done
-            # once the device finished it (bench.py recovery_s parses
-            # the crash_ts -> resume_ts span)
+            # recovery marker: the step is only claimed done once the
+            # device finished it (a reader of the log takes the
+            # crash_ts -> resume_ts span as the recovery time)
             hard_block(metrics["loss"])
             print(
                 f"resume_ts={time.time():.3f} step={step}", flush=True

@@ -681,8 +681,3 @@ class TestBrainBench:
         drill = result["drill"]
         assert drill["ride_out"]["restarts"] == 0
         assert drill["restart"]["restarts"] >= 1
-
-    def test_fleet_goodput_gain_is_gate_watched(self):
-        from dlrover_tpu.observability.sentinel import BENCH_WATCH
-
-        assert BENCH_WATCH.get("fleet_goodput_gain") == "down"

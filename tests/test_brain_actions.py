@@ -348,6 +348,10 @@ class TestAgentSideHandling:
             "DLROVER_TPU_RUNTIME_METRICS_PATH",
             str(tmp_path / "runtime_metrics.json"),
         )
+        # an agent's process holds no trainer: a Trainer an earlier test
+        # of this worker left uncollected would take the demotion
+        # in-process, and nothing would be staged
+        monkeypatch.setattr(hierarchy, "_DEMOTION_TARGET", None)
         agent = self._agent()
         agent._handle_brain_demote(
             {"action": "brain_demote", "reason": "slow slice link"}

@@ -166,8 +166,8 @@ def _llama_1b_2_layers():
 
 
 def _trainer_step_compiled(mesh, model_and_batch=_llama_1b_2_layers):
-    """The whole ``Trainer`` step with the optimizer and dtypes of
-    bench.py's throughput run, lowered from shapes (a described device
+    """The whole ``Trainer`` step with the optimizer and dtypes the
+    benchmark's cells train with, lowered from shapes (a described device
     holds no array) and compiled."""
     from dlrover_tpu.trainer.optim import create_optimizer
     from dlrover_tpu.trainer.train import Trainer

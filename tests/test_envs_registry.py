@@ -46,10 +46,10 @@ class TestTypedReads:
     def test_defaults_when_unset(self, monkeypatch):
         monkeypatch.delenv(NodeEnv.NUM_PROCESSES, raising=False)
         monkeypatch.delenv("DLROVER_TPU_STAGE_FACTOR", raising=False)
-        monkeypatch.delenv("DLROVER_TPU_STREAM_STAGING", raising=False)
+        monkeypatch.delenv("DLROVER_TPU_DIST_DIFF", raising=False)
         assert envs.get_int(NodeEnv.NUM_PROCESSES) == 1
         assert envs.get_float("DLROVER_TPU_STAGE_FACTOR") == 1.5
-        assert envs.get_bool("DLROVER_TPU_STREAM_STAGING") is True
+        assert envs.get_bool("DLROVER_TPU_DIST_DIFF") is True
 
     def test_reads_are_live_not_import_frozen(self, monkeypatch):
         monkeypatch.setenv(NodeEnv.NUM_PROCESSES, "8")

@@ -1,11 +1,44 @@
 """Native parallel staging copier: correctness + fallback contract."""
 
 import mmap
+import os
+import shutil
+import subprocess
 
 import numpy as np
 import pytest
 
 from dlrover_tpu.common import fastcopy
+
+_SOURCE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "native", "fastcopy", "fastcopy.cc",
+)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def built_library(tmp_path_factory):
+    """The library compiled from the tree's source into a directory of
+    this module's own.  ``native/build/`` is not in git: whether it is
+    there depends on which test built it first (``test_timer.py`` runs
+    cmake on all of ``native/``), and these tests used to pass or skip
+    by that chance."""
+    cxx = next(
+        (c for c in ("c++", "g++", "clang++") if shutil.which(c)), None
+    )
+    if cxx is None:
+        pytest.skip("no C++ compiler to build native/fastcopy with")
+    out = str(tmp_path_factory.mktemp("fastcopy") / "libfastcopy.so")
+    subprocess.run(
+        [cxx, "-std=c++17", "-O2", "-shared", "-fPIC", _SOURCE, "-o", out,
+         "-lpthread"],
+        check=True, capture_output=True, timeout=300,
+    )
+    saved = (fastcopy._LIB_PATHS, fastcopy._lib, fastcopy._loaded)
+    fastcopy._LIB_PATHS, fastcopy._lib, fastcopy._loaded = [out], None, False
+    assert fastcopy.available()
+    yield out
+    fastcopy._LIB_PATHS, fastcopy._lib, fastcopy._loaded = saved
 
 
 @pytest.fixture()
@@ -15,8 +48,6 @@ def small_threshold(monkeypatch):
 
 class TestFastcopy:
     def test_batch_copy_correct(self, small_threshold):
-        if not fastcopy.available():
-            pytest.skip("libfastcopy not built")
         buf = mmap.mmap(-1, 1 << 20)
         view = memoryview(buf)
         rng = np.random.default_rng(0)
@@ -41,16 +72,12 @@ class TestFastcopy:
         assert bytes(view[0:16]) == b"\x00" * 16
 
     def test_small_batch_declined(self):
-        if not fastcopy.available():
-            pytest.skip("libfastcopy not built")
         buf = bytearray(1024)
         arr = np.arange(10, dtype=np.uint8)
         # under MIN_PARALLEL_BYTES: caller must use its fallback loop
         assert not fastcopy.copy_into(memoryview(buf), [(0, arr)])
 
     def test_non_contiguous_declined(self, small_threshold):
-        if not fastcopy.available():
-            pytest.skip("libfastcopy not built")
         buf = bytearray(1 << 12)
         arr = np.arange(100, dtype=np.uint8).reshape(10, 10)[:, ::2]
         assert not arr.flags["C_CONTIGUOUS"]
@@ -65,8 +92,6 @@ class TestFastcopy:
         """write_snapshot -> read back, with the parallel copier forced on
         for every size: the wire format must be identical to the Python
         loop's."""
-        if not fastcopy.available():
-            pytest.skip("libfastcopy not built")
         from dlrover_tpu.common.multi_process import SharedMemoryBuffer
         from dlrover_tpu.trainer.flash_checkpoint import snapshot as snap
 

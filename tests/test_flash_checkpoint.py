@@ -413,14 +413,15 @@ class TestSnapshotStager:
         must end at the LATEST saved step."""
         monkeypatch.setenv("DLROVER_TPU_ASYNC_MIN_BYTES", "0")
         trainer, state, batch = _make_trainer(MeshConfig(dp=8))
-        real_extract = snapshot.extract_host_shards
+        real_stream = snapshot.stream_snapshot
+        slowed = []
 
-        def slow_extract(tree, throttled=False):
-            if throttled:  # only the stager's path is slowed
-                time.sleep(0.4)
-            return real_extract(tree)
+        def slow_stream(*args, **kwargs):  # the stager's writer alone
+            slowed.append(args[1])
+            time.sleep(0.4)
+            return real_stream(*args, **kwargs)
 
-        monkeypatch.setattr(snapshot, "extract_host_shards", slow_extract)
+        monkeypatch.setattr(snapshot, "stream_snapshot", slow_stream)
         ckpt = Checkpointer(str(tmp_path), scope=_scope())
         try:
             last = 0
@@ -434,6 +435,7 @@ class TestSnapshotStager:
             assert ckpt.engine._flush_async(timeout=60)
             meta = snapshot.read_snapshot_meta(ckpt.engine._shm)
             assert meta is not None and meta["step"] == last
+            assert slowed and slowed[-1] == last
         finally:
             ckpt.close()
 

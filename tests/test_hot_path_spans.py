@@ -389,19 +389,30 @@ class TestFlashCheckpointSpans:
 
 
 class TestStageCounters:
-    def test_paced_extraction_counts_chunks_and_sleeps(self, monkeypatch):
+    def test_paced_stream_counts_chunks_and_sleeps(self, monkeypatch):
+        from dlrover_tpu.common.multi_process import SharedMemoryBuffer
+
         monkeypatch.setenv("DLROVER_TPU_STAGE_PACE", "0.5")
+        monkeypatch.delenv("DLROVER_TPU_STREAM_CHUNK_BYTES", raising=False)
         pacer = snapshot.StagePacer()
         pacer.chunk_bytes = 64 << 10
         pacer._calibrated = True
         counters = snapshot.StageCounters()
         state = {"w": jnp.ones((2048, 1024), jnp.float32)}
-        leaves = snapshot.extract_host_shards(
-            state, throttled=True, pacer=pacer, counters=counters)
-        np.testing.assert_array_equal(
-            leaves[0]["shards"][0]["data"], np.ones((2048, 1024), np.float32))
+        shm = SharedMemoryBuffer(f"cnt_{_scope()}")
+        try:
+            snapshot.stream_snapshot(
+                shm, 3, snapshot.plan_shards(state), pacer=pacer,
+                counters=counters)
+            meta = snapshot.read_snapshot_meta(shm)
+            (shard,) = meta["leaves"][0]["shards"]
+            np.testing.assert_array_equal(
+                snapshot.read_shard_bytes(shm, meta, shard, "float32"),
+                np.ones((2048, 1024), np.float32))
+        finally:
+            shm.unlink()
         assert counters.bytes == 8 << 20 and counters.chunks == 128
-        assert counters.host_copies == counters.chunks  # the two-phase path
+        assert counters.host_copies == counters.chunks  # one a chunk
         assert counters.pace_sleep_s == pytest.approx(pacer.slept_s)
         assert pacer.slept_s > 0 and counters.d2h_wait_s > 0
         attrs = counters.as_attrs()

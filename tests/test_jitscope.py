@@ -854,11 +854,3 @@ class TestTrainerIntegration:
         assert digest["js_seq"] >= 1.0
         assert digest["js_compile_s"] > 0
         goodput.reset_ledger()
-
-
-class TestBenchColumns:
-    def test_bench_watch_guards_compile_columns(self):
-        from dlrover_tpu.observability.sentinel import BENCH_WATCH
-
-        assert BENCH_WATCH["compile_s"] == "up"
-        assert BENCH_WATCH["cache_hit_ratio"] == "down"

@@ -1,6 +1,6 @@
 """Perf-regression sentinel: EWMA+MAD detector semantics, the series
 diagnosticians end-to-end (store -> detector -> DiagnosisManager ->
-incident), and the bench-side trajectory gate."""
+incident)."""
 
 import time
 
@@ -12,7 +12,6 @@ from dlrover_tpu.observability.sentinel import (
     ExposedCommDiagnostician,
     GoodputRegressionDiagnostician,
     StepTimeRegressionDiagnostician,
-    compare_round,
     register_sentinels,
 )
 
@@ -246,80 +245,3 @@ class TestSeriesDiagnosticians:
         assert any(s.name == "slow_link" for s in sentinels)
         # all quiet on an empty store
         assert manager.diagnose_once() == []
-
-
-def _round(step_ms, tokens, vs=1.0, tpu_down=False, preset="default",
-           **extra):
-    return {
-        "step_ms": step_ms, "tokens_per_sec": tokens,
-        "vs_baseline": vs, "tpu_unavailable": tpu_down,
-        "preset": preset, **extra,
-    }
-
-
-class TestBenchGate:
-    def test_cold_history_never_fails(self):
-        verdict = compare_round([], _round(100, 1000))
-        assert verdict["ok"]
-        assert all(
-            v["verdict"] == "cold" for v in verdict["checked"].values()
-        )
-
-    def test_stable_trajectory_ok(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_SENTINEL_MIN_SAMPLES", "4")
-        history = [_round(100 + i % 3, 1000 - i % 5) for i in range(10)]
-        verdict = compare_round(history, _round(101, 999))
-        assert verdict["ok"]
-        assert verdict["checked"]["step_ms"]["verdict"] == "ok"
-
-    def test_step_time_regression_flagged(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_SENTINEL_MIN_SAMPLES", "4")
-        history = [_round(100, 1000) for _ in range(10)]
-        verdict = compare_round(history, _round(250, 1000))
-        assert not verdict["ok"]
-        assert "step_ms" in verdict["regressions"]
-
-    def test_throughput_drop_flagged_improvement_not(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_SENTINEL_MIN_SAMPLES", "4")
-        history = [_round(100, 1000) for _ in range(10)]
-        assert "tokens_per_sec" in compare_round(
-            history, _round(100, 300)
-        )["regressions"]
-        assert compare_round(history, _round(100, 5000))["ok"]
-
-    def test_incomparable_rounds_excluded(self, monkeypatch):
-        """A CPU-fallback round neither judges nor is judged by the
-        real-hardware trajectory."""
-        monkeypatch.setenv("DLROVER_TPU_SENTINEL_MIN_SAMPLES", "4")
-        hw = [_round(100, 1000) for _ in range(10)]
-        degraded = _round(5000, 20, tpu_down=True, preset="tiny")
-        verdict = compare_round(hw, degraded)
-        assert verdict["ok"]
-        assert verdict["comparable_rounds"] == 0
-
-    def test_watcher_headline_rounds_form_their_own_cohort(
-        self, monkeypatch
-    ):
-        """A degraded round whose headline was adopted from the TPU
-        watcher's capture mixes hardware and CPU numbers — it must not
-        feed (or be judged by) either pure cohort's baseline."""
-        monkeypatch.setenv("DLROVER_TPU_SENTINEL_MIN_SAMPLES", "4")
-        mixed = [
-            dict(_round(5000, 20, vs=300.0, tpu_down=True,
-                        preset="tiny"), headline_source="watcher")
-            for _ in range(10)
-        ]
-        pure_degraded = _round(5000, 20, vs=0.0, tpu_down=True,
-                               preset="tiny")
-        verdict = compare_round(mixed, pure_degraded)
-        assert verdict["comparable_rounds"] == 0
-        assert verdict["ok"]  # vs_baseline 0.0 not judged vs 300.0
-
-    def test_missing_metric_skipped(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_SENTINEL_MIN_SAMPLES", "4")
-        history = [_round(100, 1000) for _ in range(10)]
-        current = {"preset": "default", "tpu_unavailable": False,
-                   "vs_baseline": 1.0}
-        verdict = compare_round(history, current)
-        assert "step_ms" not in verdict["checked"]
-        assert verdict["ok"]

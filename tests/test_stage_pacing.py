@@ -1,21 +1,42 @@
 """Auto-paced checkpoint staging: step clock, pacer control law, and
-chunked device->host transfers.
+chunked device->host transfers into the shm segment.
 
 Counterpart of VERDICT r02 item 4: the manual ``DLROVER_TPU_STAGE_PACE``
 knob became closed-loop control keeping step inflation bounded.
 """
 
+import uuid
+
 import numpy as np
 import pytest
 
+from dlrover_tpu.common.multi_process import SharedMemoryBuffer
+from dlrover_tpu.trainer.flash_checkpoint import snapshot
 from dlrover_tpu.trainer.flash_checkpoint.snapshot import (
     _MAX_CHUNK,
     _MIN_CHUNK,
     StagePacer,
-    _chunked_to_host,
     extract_host_shards,
 )
 from dlrover_tpu.utils.step_clock import StepClock
+
+
+def _read_all(shm):
+    """(meta, {path: the leaf assembled from its shards}) of a segment."""
+    meta = snapshot.read_snapshot_meta(shm)
+    assert meta is not None
+    out = {}
+    for leaf in meta["leaves"]:
+        pieces = snapshot.ShardIndexMap(leaf["dtype"], leaf["gshape"])
+        for sm in leaf["shards"]:
+            pieces.add(
+                sm["index"],
+                snapshot.read_shard_bytes(shm, meta, sm, leaf["dtype"]),
+            )
+        out[leaf["path"]] = pieces.read(
+            tuple(slice(0, d) for d in leaf["gshape"])
+        )
+    return meta, out
 
 
 class TestStepClock:
@@ -201,42 +222,77 @@ class TestPacerConvergence:
 
 
 class TestChunkedTransfer:
-    def _pacer(self, chunk_bytes):
-        pacer = StagePacer(factor=1.5, clock=StepClock())
-        pacer.chunk_bytes = chunk_bytes
-        pacer._calibrated = True  # pin the chunk size for the test
-        return pacer
+    """The stager's writer, chunk size pinned: what ``stream_snapshot``
+    lands in shm, read back, is the host copy of the array, and is what
+    ``extract_host_shards`` + ``write_snapshot`` land."""
+
+    @pytest.fixture(autouse=True)
+    def _small_shards_are_chunked(self, monkeypatch):
+        # a shard under 2 x _MIN_CHUNK goes in one transfer whatever the
+        # chunk size: lower the floor so that these shapes really stream
+        monkeypatch.setattr(snapshot, "_MIN_CHUNK", 1 << 10)
+        monkeypatch.delenv("DLROVER_TPU_STAGE_PACE", raising=False)
+
+    def _land_both(self, state, chunk_bytes, monkeypatch):
+        """(meta, {path: array}) read back from the streamed segment,
+        the same from the packed one, and the stream's counters."""
+        monkeypatch.setenv(
+            "DLROVER_TPU_STREAM_CHUNK_BYTES", str(chunk_bytes))
+        tag = uuid.uuid4().hex[:8]
+        streamed = SharedMemoryBuffer(f"pace_s_{tag}")
+        packed = SharedMemoryBuffer(f"pace_p_{tag}")
+        try:
+            counters = snapshot.stream_snapshot(
+                streamed, 2, snapshot.plan_shards(state),
+                pacer=StagePacer(factor=1.5, clock=StepClock()),
+            )
+            snapshot.write_snapshot(
+                packed, 2, extract_host_shards(state))
+            return _read_all(streamed), _read_all(packed), counters
+        finally:
+            streamed.unlink()
+            packed.unlink()
 
     @pytest.mark.parametrize(
-        "shape", [(1024, 300), (300, 1024), (7, 513, 11), (33,)]
+        "shape,chunks",
+        # rows of 1200, 4096 and 22572 bytes in chunks of 64 KiB
+        [((1024, 300), 19), ((300, 1024), 19), ((7, 513, 11), 4),
+         ((33,), 1)],
     )
-    def test_matches_plain_copy(self, shape):
+    def test_matches_plain_copy(self, shape, chunks, monkeypatch):
         import jax.numpy as jnp
 
         rng = np.random.default_rng(0)
         host = rng.standard_normal(shape).astype(np.float32)
-        arr = jnp.asarray(host)
-        out = _chunked_to_host(arr, self._pacer(64 * 1024))
-        np.testing.assert_array_equal(out, host)
+        (meta_s, got), (meta_p, ref), counters = self._land_both(
+            {"x": jnp.asarray(host)}, 64 * 1024, monkeypatch)
+        np.testing.assert_array_equal(got["x"], host)
+        np.testing.assert_array_equal(got["x"], ref["x"])
+        assert meta_s == meta_p
+        assert counters.bytes == host.nbytes
+        assert counters.host_copies == counters.chunks == chunks
 
-    def test_small_array_single_transfer(self):
+    def test_small_array_single_transfer(self, monkeypatch):
         import jax.numpy as jnp
 
-        arr = jnp.ones((8, 8), jnp.float32)
-        pacer = self._pacer(1 << 20)
-        out = _chunked_to_host(arr, pacer)
-        np.testing.assert_array_equal(out, np.ones((8, 8), np.float32))
+        (_, got), _, counters = self._land_both(
+            {"x": jnp.ones((8, 8), jnp.float32)}, 1 << 20, monkeypatch)
+        np.testing.assert_array_equal(got["x"], np.ones((8, 8), np.float32))
+        assert counters.chunks == 1
 
-    def test_bfloat16_roundtrip(self):
+    def test_bfloat16_roundtrip(self, monkeypatch):
         import jax.numpy as jnp
 
         rng = np.random.default_rng(1)
         host = rng.standard_normal((512, 700)).astype(np.float32)
         arr = jnp.asarray(host, jnp.bfloat16)
-        out = _chunked_to_host(arr, self._pacer(128 * 1024))
-        np.testing.assert_array_equal(out, np.asarray(arr))
+        (meta_s, got), (meta_p, ref), counters = self._land_both(
+            {"x": arr}, 128 * 1024, monkeypatch)
+        np.testing.assert_array_equal(got["x"], np.asarray(arr))
+        np.testing.assert_array_equal(got["x"], ref["x"])
+        assert meta_s == meta_p and counters.chunks > 1
 
-    def test_throttled_extract_equals_unthrottled(self):
+    def test_streamed_state_equals_packed(self, monkeypatch):
         import jax.numpy as jnp
 
         state = {
@@ -244,12 +300,10 @@ class TestChunkedTransfer:
             "b": jnp.ones((7,), jnp.bfloat16),
             "step": np.int64(3),
         }
-        fast = extract_host_shards(state, throttled=False)
-        paced = extract_host_shards(state, throttled=True)
-        assert len(fast) == len(paced)
-        for a, b in zip(fast, paced):
-            assert a["path"] == b["path"]
-            for sa, sb in zip(a["shards"], b["shards"]):
-                np.testing.assert_array_equal(
-                    np.asarray(sa["data"]), np.asarray(sb["data"])
-                )
+        (meta_s, got), (meta_p, ref), _ = self._land_both(
+            state, 4096, monkeypatch)
+        assert meta_s == meta_p
+        assert set(got) == set(ref) == {"w", "b", "step"}
+        for path in ref:
+            np.testing.assert_array_equal(got[path], ref[path])
+        np.testing.assert_array_equal(got["w"], np.asarray(state["w"]))

@@ -259,7 +259,6 @@ class TestStreamingEngine:
         trainer, state = self._trainer_state()
         ckpt = Checkpointer(str(tmp_path), scope=_scope())
         try:
-            assert ckpt.engine._stream_staging  # streaming is default
             blocked = ckpt.save_checkpoint(7, state, StorageType.MEMORY)
             assert blocked >= 0
             assert ckpt.engine._flush_async(timeout=60)
@@ -274,22 +273,6 @@ class TestStreamingEngine:
                 np.testing.assert_array_equal(
                     np.asarray(a), np.asarray(b)
                 )
-        finally:
-            ckpt.close()
-
-    def test_two_phase_opt_out(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_STREAM_STAGING", "0")
-        trainer, state = self._trainer_state()
-        ckpt = Checkpointer(str(tmp_path), scope=_scope())
-        try:
-            assert not ckpt.engine._stream_staging
-            ckpt.save_checkpoint(5, state, StorageType.MEMORY)
-            assert ckpt.engine._flush_async(timeout=60)
-            restored, step = ckpt.load_checkpoint(
-                jax.eval_shape(lambda s: s, state),
-                trainer.state_shardings,
-            )
-            assert step == 5
         finally:
             ckpt.close()
 
