@@ -85,14 +85,25 @@ def flash_attention(
             f"{jax.default_backend()!r}); use attention_impl='reference' "
             "off the chip, or pass interpret=True"
         )
-    from dlrover_tpu.ops.pallas.flash_attention import pallas_flash_attention
+    from dlrover_tpu.ops.pallas.flash_attention import (
+        heads_per_block,
+        pallas_flash_attention,
+    )
     from dlrover_tpu.ops.pallas.tuning import tuned_blocks
     from dlrover_tpu.ops.ring_attention import active_mesh
 
+    seq_len, heads, head_dim = q.shape[1:]
     if not block_q or not block_kv:
-        tuned_q, tuned_kv = tuned_blocks(q.shape[1], q.shape[-1])
+        tuned_q, tuned_kv = tuned_blocks(seq_len, head_dim)
         block_q = block_q or tuned_q
         block_kv = block_kv or tuned_kv
+    # ``layout``: the kernels read and write [B, S, H*D] in column blocks
+    # of 128 lanes, ``heads_per_block`` heads in each
+    trace.note_trace_time(
+        "attention.path", impl="flash", seq=seq_len, head_dim=head_dim,
+        heads=heads, blocks=(block_q, block_kv), layout="bsd",
+        heads_per_block=heads_per_block(head_dim),
+    )
 
     def kernel(q_, k_, v_):
         return pallas_flash_attention(
@@ -123,12 +134,13 @@ def flash_attention(
     )(q, k, v)
 
 
-def attention_path(backend: str, seq_len: int, head_dim: int) -> str:
+def attention_path(backend: str, seq_len: int, head_dim: int, heads: int,
+                   kv_heads: int) -> str:
     """``"flash"`` or ``"reference"``: the kernel wherever it can run, from
     what the code can observe and nothing else."""
     from dlrover_tpu.ops.pallas.flash_attention import kernel_takes
 
-    if backend == "tpu" and kernel_takes(seq_len, head_dim):
+    if backend == "tpu" and kernel_takes(seq_len, head_dim, heads, kv_heads):
         return "flash"
     return "reference"
 
@@ -142,16 +154,9 @@ def causal_attention(
     everywhere else.  Both compute float32 scores and softmax from the
     operands as given and accumulate in float32."""
     seq_len, heads, head_dim = q.shape[1:]
-    impl = attention_path(jax.default_backend(), seq_len, head_dim)
-    blocks = None
-    if impl == "flash":
-        from dlrover_tpu.ops.pallas.tuning import tuned_blocks
-
-        blocks = tuned_blocks(seq_len, head_dim)
-    trace.note_trace_time("attention.path", impl=impl, seq=seq_len,
-                          head_dim=head_dim, heads=heads, blocks=blocks)
-    if blocks is None:
-        return reference_attention(q, k, v, mask)
-    return flash_attention(
-        q, k, v, causal=True, block_q=blocks[0], block_kv=blocks[1]
-    )
+    if attention_path(jax.default_backend(), seq_len, head_dim, heads,
+                      k.shape[2]) == "flash":
+        return flash_attention(q, k, v, causal=True)  # writes the record
+    trace.note_trace_time("attention.path", impl="reference", seq=seq_len,
+                          head_dim=head_dim, heads=heads, blocks=None)
+    return reference_attention(q, k, v, mask)

@@ -3,14 +3,30 @@
 Forward: blocks of Q stay resident in VMEM while KV blocks stream through;
 softmax is computed online with running (max, sum) so the S x S score
 matrix never materializes in HBM — the memory win that lets long sequences
-fit.  The kernel targets the MXU with bf16 inputs and fp32 accumulation,
-and emits the per-row log-sum-exp (LSE) as the backward residual.
+fit.  Operands come as given (bfloat16 in training), are cast to float32
+in the kernel body, and every matmul runs at the default precision with
+float32 accumulation; the per-row log-sum-exp (LSE) is the backward
+residual.
 
 Backward: two blockwise kernels in the standard FA2 split — dQ iterates KV
 blocks for a resident Q block; dK/dV iterate Q blocks for a resident KV
 block — recomputing probabilities from (q, k, lse) so the backward is also
-O(S) memory.  GQA backward runs on group-expanded heads and sum-reduces
-dK/dV over each group afterwards (transient O(H) memory, no S x S).
+O(S) memory.  ``delta = rowsum(dO * O)`` is computed in both from the
+blocks of dO and O they hold.  GQA: K and V are read through the kv index
+map, forward and backward, never expanded; the dK/dV kernel walks the q
+heads of a kv head one after the other and sums the group in its
+accumulators, so dK and dV come out at the kv head count.
+
+HBM interface: every operand and result is the model's own ``[B, S, H, D]``
+array seen as ``[B, S, H*D]`` (a reshape of the trailing two dimensions,
+no transpose), and a block is ``(1, block, 128)``: 128 lanes at column
+block ``h``.  At head size 128 that is one head; at head size 64 it is
+heads ``2h`` and ``2h+1``, which the kernel body takes one after the other
+by selecting the head's lanes (the other lanes read as zero, so each
+matmul contracts over, or writes, a whole 128-lane tile).  An odd head
+count leaves the last block with one head: the absent head is skipped, and
+its lanes, which lie outside the array, reach nothing (a select, never a
+multiply by zero, keeps them out).
 
 Grids are sequential on TPU, so VMEM scratch carries accumulators across
 the innermost dimension.  Causal masking skips fully-masked blocks.
@@ -25,25 +41,111 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# TPU vector lanes: per-row scalars (LSE, delta) are stored broadcast
-# across a 128-lane trailing dim so their blocks meet Mosaic's (8, 128)
-# tiling constraint (same layout as jax's reference TPU kernel).
-MIN_LANES = 128
+# TPU vector lanes: a block's trailing dimension, and the width at which
+# per-row scalars (LSE) are stored broadcast so their blocks meet Mosaic's
+# (8, 128) tiling constraint (same layout as jax's reference TPU kernel).
+LANES = 128
 
 # What a caller that chooses for itself may send here.  The compiler
-# takes more (head sizes 16 to 256 and blocks down to 8 rows compile for
-# a described v5e), but these are the shapes the kernel has run at on
-# the chip against the reference (tests_tpu/): the head sizes whose
-# [block, D] tiles fill half or all of the 128 lanes, and sequences that
-# a block of the tuner's sweep divides.  A shape outside them is the
+# takes more (every head size that divides, or is a multiple of, the 128
+# lanes, and blocks down to 8 rows), but these are the shapes the kernel
+# has run at on the chip against the reference (tests_tpu/): the head
+# sizes whose heads fill half or all of a 128-lane block, and sequences
+# that a block of the tuner's sweep divides.  A shape outside them is the
 # reference's until a test on the chip says otherwise.
 KERNEL_HEAD_DIMS = (64, 128)
 KERNEL_MIN_BLOCK = 128
 
+# Mosaic's default of 16 MiB of VMEM a kernel holds every kernel here at
+# 1024 x 1024 blocks but the backward ones where two heads share a block
+# (the LSE tile is two heads': 18.4 MiB); a v5e core has 128 MiB.  Only
+# the calls of a shared block ask for more: what a kernel may take, the
+# program around it may not keep there.
+SHARED_BLOCK_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
-def kernel_takes(seq_len: int, head_dim: int) -> bool:
-    """Whether the kernel runs causal self-attention at this shape."""
-    return head_dim in KERNEL_HEAD_DIMS and seq_len % KERNEL_MIN_BLOCK == 0
+
+def heads_per_block(head_dim: int) -> int:
+    """How many heads one 128-lane column block of ``[B, S, H*D]`` holds."""
+    return max(1, LANES // head_dim)
+
+
+def kernel_takes(seq_len: int, head_dim: int, heads: int,
+                 kv_heads: int) -> bool:
+    """Whether the kernel runs causal self-attention at this shape.  Any
+    head count goes, odd ones too, as long as the kv heads divide it."""
+    return (head_dim in KERNEL_HEAD_DIMS
+            and seq_len % KERNEL_MIN_BLOCK == 0
+            and heads % kv_heads == 0)
+
+
+def _compiler_params(per_block: int):
+    """grid: (batch, head block, resident block, streamed block)."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=(
+            SHARED_BLOCK_VMEM_LIMIT_BYTES if per_block > 1 else None),
+    )
+
+
+def _block_lanes(head_dim: int) -> int:
+    """Lanes of one column block: 128, or the whole head where it is
+    wider."""
+    if LANES % head_dim == 0:
+        return LANES
+    if head_dim % LANES == 0:
+        return head_dim
+    raise ValueError(
+        f"head size {head_dim} neither divides nor is a multiple of "
+        f"{LANES} lanes"
+    )
+
+
+def _lanes_at(place, head_dim: int, width: int):
+    """[1, width] mask of the lanes of the head at ``place`` of its block
+    (a Python int, or traced); ``None`` where the block is one head and
+    nothing needs selecting."""
+    if head_dim == width:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return (lane >= place * head_dim) & (lane < (place + 1) * head_dim)
+
+
+def _head_tile(ref, place, onto, head_dim: int):
+    """The float32 tile of ``ref`` with the head at ``place`` of the block
+    turned onto the lanes of place ``onto`` and every other lane read as
+    zero: a select, so that what the other lanes hold (another head, or
+    nothing at all past the array's edge) cannot reach the result."""
+    x = ref[0].astype(jnp.float32)
+    width = x.shape[-1]
+    if head_dim == width:
+        return x
+    # the same Python int, or the same traced value: nothing to turn
+    if place is not onto:
+        per_block = width // head_dim
+        x = pltpu.roll(x, (onto - place + per_block) * head_dim % width, 1)
+    return jnp.where(_lanes_at(onto, head_dim, width), x, 0.0)
+
+
+def _kv_place(q_head_block, i: int, groups: int, per_block: int):
+    """Where in its kv block the kv head of q head ``i`` of
+    ``q_head_block`` sits: the q head's own place without GQA."""
+    if groups == 1:
+        return i
+    return (q_head_block * per_block + i) // groups % per_block
+
+
+def _each_head(q_head_block, heads: int, per_block: int, q_head_blocks: int,
+               body):
+    """Run ``body(i)`` for the q heads of this block, one after the other.
+    Where the grid's ``q_head_blocks`` hold more than ``heads`` (an odd
+    head count, a kv block that is not full), a head past the last one is
+    skipped."""
+    for i in range(per_block):
+        run = functools.partial(body, i)
+        if q_head_blocks * per_block > heads:
+            pl.when(q_head_block * per_block + i < heads)(run)
+        else:
+            run()
 
 
 def _masked_scores(q, k, scale, causal, q_start, kv_start, block_q,
@@ -73,10 +175,14 @@ def _masked_scores(q, k, scale, causal, q_start, kv_start, block_q,
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, out_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, block_q: int, block_kv: int, causal: bool, scale: float,
+    head_dim: int, heads: int, groups: int,
 ):
-    q_idx = pl.program_id(1)
-    kv_idx = pl.program_id(2)
-    num_kv = pl.num_programs(2)
+    head_block = pl.program_id(1)
+    q_idx = pl.program_id(2)
+    kv_idx = pl.program_id(3)
+    num_kv = pl.num_programs(3)
+    width = acc_ref.shape[-1]
+    per_block = width // head_dim
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -91,36 +197,71 @@ def _flash_fwd_kernel(
         jnp.logical_not(causal), kv_start <= q_start + block_q - 1
     )
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
+    def one_head(i):
+        q = _head_tile(q_ref, i, i, head_dim)
+        kv_at = _kv_place(head_block, i, groups, per_block)
+        k = _head_tile(k_ref, kv_at, i, head_dim)
+        v = _head_tile(v_ref, kv_at, i, head_dim)
         s = _masked_scores(q, k, scale, causal, q_start, kv_start,
                            block_q, block_kv)
 
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
+        m_prev = m_ref[i, :, :1]
+        l_prev = l_ref[i, :, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
         correction = jnp.exp(m_prev - m_new)
         l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
+        grown = acc_ref[:] * correction + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        lanes = _lanes_at(i, head_dim, width)
+        acc_ref[:] = (
+            grown if lanes is None else jnp.where(lanes, grown, acc_ref[:])
+        )
+        m_ref[i] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[i] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    @pl.when(needed)
+    def _compute():
+        _each_head(head_block, heads, per_block, pl.num_programs(1),
+                   one_head)
 
     @pl.when(kv_idx == num_kv - 1)
     def _finalize():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0] = (acc_ref[:] / safe_l).astype(out_ref.dtype)
-        if lse_ref is not None:
-            lse = m_ref[:, :1] + jnp.log(safe_l)  # [block_q, 1]
-            lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        out = None
+        for i in range(per_block):
+            l = l_ref[i, :, :1]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            lanes = _lanes_at(i, head_dim, width)
+            mine = acc_ref[:] / safe_l
+            out = mine if out is None else jnp.where(lanes, mine, out)
+            if lse_ref is not None:
+                lse = m_ref[i, :, :1] + jnp.log(safe_l)  # [block_q, 1]
+                lse_ref[0, i] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        out_ref[0] = out.astype(out_ref.dtype)
+
+
+def _last_kv_block(q_block, block_q: int, block_kv: int):
+    """The last kv block a causal q block attends to."""
+    return ((q_block + 1) * block_q - 1) // block_kv
+
+
+def _first_q_block(kv_block, block_q: int, block_kv: int):
+    """The first q block that attends to a causal kv block."""
+    return (kv_block * block_kv) // block_q
+
+
+def _checked_blocks(seq_len: int, block_q: int, block_kv: int):
+    block_q = min(block_q, seq_len)
+    block_kv = min(block_kv, seq_len)
+    if seq_len % block_q or seq_len % block_kv:
+        raise ValueError(
+            f"seq len {seq_len} must be divisible by block sizes "
+            f"({block_q}, {block_kv})"
+        )
+    return block_q, block_kv
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
@@ -131,69 +272,65 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
     if H % H_kv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {H_kv}")
     groups = H // H_kv
-    block_q = min(block_q, S)
-    block_kv = min(block_kv, S)
-    if S % block_q or S % block_kv:
-        raise ValueError(
-            f"seq len {S} must be divisible by block sizes "
-            f"({block_q}, {block_kv})"
-        )
-    scale = D ** -0.5
-    qt = q.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * H_kv, S, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H_kv, S, D)
+    block_q, block_kv = _checked_blocks(S, block_q, block_kv)
+    width = _block_lanes(D)
+    per_block = width // D
 
-    def kv_index(b, i, j):
-        return (b // H) * H_kv + (b % H) // groups, j, 0
+    def q_index(b, h, i, j):
+        return b, i, h
 
-    grid = (B * H, S // block_q, S // block_kv)
+    def kv_index(b, h, i, j):
+        if causal:  # a masked step asks for the block it already has
+            j = jnp.minimum(j, _last_kv_block(i, block_q, block_kv))
+        return b, j, h // groups
+
     kernel = functools.partial(
         _flash_fwd_kernel,
         block_q=block_q,
         block_kv=block_kv,
         causal=causal,
-        scale=scale,
+        scale=D ** -0.5,
+        head_dim=D,
+        heads=H,
+        groups=groups,
     )
     if with_residuals:
-        # lane-broadcast residual: [B*H, S, MIN_LANES] (see MIN_LANES)
+        # lane-broadcast residual: [B, H, S, LANES] (see LANES)
         lse_spec = pl.BlockSpec(
-            (1, block_q, MIN_LANES), lambda b, i, j: (b, i, 0)
+            (1, per_block, block_q, LANES), lambda b, h, i, j: (b, h, i, 0)
         )
-        lse_shape = jax.ShapeDtypeStruct(
-            (B * H, S, MIN_LANES), jnp.float32
-        )
+        lse_shape = jax.ShapeDtypeStruct((B, H, S, LANES), jnp.float32)
     else:
         lse_spec, lse_shape = None, None
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, pl.cdiv(H, per_block), S // block_q, S // block_kv),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, D), kv_index),
-            pl.BlockSpec((1, block_kv, D), kv_index),
+            pl.BlockSpec((1, block_q, width), q_index),
+            pl.BlockSpec((1, block_kv, width), kv_index),
+            pl.BlockSpec((1, block_kv, width), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, width), q_index),
             lse_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
             lse_shape,
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, MIN_LANES), jnp.float32),
-            pltpu.VMEM((block_q, MIN_LANES), jnp.float32),
+            pltpu.VMEM((block_q, width), jnp.float32),
+            pltpu.VMEM((per_block, block_q, LANES), jnp.float32),
+            pltpu.VMEM((per_block, block_q, LANES), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(per_block),
         interpret=interpret,
-    )(qt, kt, vt)
-    out4 = out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    )(q.reshape(B, S, H * D), k.reshape(B, S, H_kv * D),
+      v.reshape(B, S, H_kv * D))
+    out = out.reshape(B, S, H, D)
     if with_residuals:
-        return out4, lse  # [B*H, S, MIN_LANES]
-    return out4
+        return out, lse  # [B, H, S, LANES]
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -201,13 +338,31 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
 # ---------------------------------------------------------------------------
 
 
+def _recomputed(q, k, v, do, o, lse, scale, causal, q_start, kv_start,
+                block_q, block_kv):
+    """``(p, ds)`` of one head from its tiles, all on the same lanes, and
+    its LSE ``[block_q, 1]``."""
+    delta = jnp.sum(do * o, axis=-1, keepdims=True)
+    s = _masked_scores(q, k, scale, causal, q_start, kv_start,
+                       block_q, block_kv)
+    p = jnp.exp(s - lse)  # exact probabilities via saved LSE
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta) * scale
+
+
 def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, acc_ref,
     *, block_q: int, block_kv: int, causal: bool, scale: float,
+    head_dim: int, heads: int, groups: int,
 ):
-    q_idx = pl.program_id(1)
-    kv_idx = pl.program_id(2)
-    num_kv = pl.num_programs(2)
+    head_block = pl.program_id(1)
+    q_idx = pl.program_id(2)
+    kv_idx = pl.program_id(3)
+    num_kv = pl.num_programs(3)
+    per_block = acc_ref.shape[-1] // head_dim
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -219,26 +374,25 @@ def _flash_bwd_dq_kernel(
         jnp.logical_not(causal), kv_start <= q_start + block_q - 1
     )
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, :, :1]  # [block_q, 1] from lane-broadcast layout
-        delta = delta_ref[0, :, :1]
-        s = _masked_scores(q, k, scale, causal, q_start, kv_start,
-                           block_q, block_kv)
-        p = jnp.exp(s - lse)  # exact probabilities via saved LSE
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
+    def one_head(i):
+        # everything on the q head's lanes: dQ lands where q lies
+        q, do, o = (_head_tile(ref, i, i, head_dim)
+                    for ref in (q_ref, do_ref, o_ref))
+        kv_at = _kv_place(head_block, i, groups, per_block)
+        k = _head_tile(k_ref, kv_at, i, head_dim)
+        v = _head_tile(v_ref, kv_at, i, head_dim)
+        _, ds = _recomputed(
+            q, k, v, do, o, lse_ref[0, i, :, :1], scale, causal, q_start,
+            kv_start, block_q, block_kv)
         acc_ref[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    @pl.when(needed)
+    def _compute():
+        _each_head(head_block, heads, per_block, pl.num_programs(1),
+                   one_head)
 
     @pl.when(kv_idx == num_kv - 1)
     def _finalize():
@@ -246,15 +400,19 @@ def _flash_bwd_dq_kernel(
 
 
 def _flash_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
     *, block_q: int, block_kv: int, causal: bool, scale: float,
+    head_dim: int, heads: int, groups: int, num_q: int,
 ):
-    kv_idx = pl.program_id(1)
-    q_idx = pl.program_id(2)
-    num_q = pl.num_programs(2)
+    # the streamed axis walks the q blocks of every q head-block whose kv
+    # heads lie in this kv block, so a GQA group is summed here
+    kv_idx = pl.program_id(2)
+    q_head_block = pl.program_id(1) * groups + pl.program_id(3) // num_q
+    q_idx = pl.program_id(3) % num_q
+    per_block = dk_acc.shape[-1] // head_dim
 
-    @pl.when(q_idx == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -265,34 +423,33 @@ def _flash_bwd_dkv_kernel(
         jnp.logical_not(causal), kv_start <= q_start + block_q - 1
     )
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, :, :1]  # [block_q, 1] from lane-broadcast layout
-        delta = delta_ref[0, :, :1]
-        s = _masked_scores(q, k, scale, causal, q_start, kv_start,
-                           block_q, block_kv)
-        p = jnp.exp(s - lse)  # [block_q, block_kv]
+    def one_head(i):
+        # everything on the kv head's lanes: dK and dV land where k lies
+        kv_at = _kv_place(q_head_block, i, groups, per_block)
+        q, do, o = (_head_tile(ref, i, kv_at, head_dim)
+                    for ref in (q_ref, do_ref, o_ref))
+        k = _head_tile(k_ref, kv_at, kv_at, head_dim)
+        v = _head_tile(v_ref, kv_at, kv_at, head_dim)
+        p, ds = _recomputed(
+            q, k, v, do, o, lse_ref[0, i, :, :1], scale, causal, q_start,
+            kv_start, block_q, block_kv)
         # dV += P^T dO
         dv_acc[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
         # dK += dS^T Q
         dk_acc[:] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(q_idx == num_q - 1)
+    @pl.when(needed)
+    def _compute():
+        _each_head(q_head_block, heads, per_block,
+                   pl.num_programs(1) * groups, one_head)
+
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -300,93 +457,91 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
                     interpret):
-    """All inputs with EXPANDED heads: q,k,v,out,do: [B, S, H, D];
-    lse: [B*H, S, MIN_LANES].  Returns (dq, dk, dv) with expanded heads."""
+    """q, out, do: [B, S, H, D]; k, v: [B, S, H_kv, D]; lse:
+    [B, H, S, LANES].  Returns (dq, dk, dv) in the shapes of (q, k, v):
+    the dK/dV kernel sums a GQA group itself."""
     B, S, H, D = q.shape
-    block_q = min(block_q, S)
-    block_kv = min(block_kv, S)
-    scale = D ** -0.5
-    qt = q.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    ot = out.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    dot = grad_out.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    # delta_i = rowsum(dO_i * O_i): cheap elementwise, computed outside,
-    # lane-broadcast to match the residual layout
-    delta = jnp.sum(
-        dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1
-    )  # [B*H, S]
-    delta = jnp.broadcast_to(delta[:, :, None], (B * H, S, MIN_LANES))
+    H_kv = k.shape[2]
+    groups = H // H_kv
+    block_q, block_kv = _checked_blocks(S, block_q, block_kv)
+    num_q, num_kv = S // block_q, S // block_kv
+    width = _block_lanes(D)
+    per_block = width // D
+    q_head_blocks = pl.cdiv(H, per_block)
+    operands = [x.reshape(B, S, -1) for x in (q, k, v, grad_out, out)]
+    operands.append(lse)
+    settings = dict(block_q=block_q, block_kv=block_kv, causal=causal,
+                    scale=D ** -0.5, head_dim=D, heads=H, groups=groups)
 
-    lane_spec = pl.BlockSpec(
-        (1, block_q, MIN_LANES), lambda b, i, j: (b, i, 0)
-    )
-    common_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),  # q
-        pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),  # v
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),  # do
-        lane_spec,  # lse
-        lane_spec,  # delta
-    ]
+    def in_specs(where):
+        """Block specs of (q, k, v, do, o, lse) from ``where``, which gives
+        a grid step's (batch, q block, kv block, q head-block, kv
+        head-block)."""
+        def at_q(*ids):
+            b, i, _, h, _ = where(*ids)
+            return b, i, h
 
+        def at_kv(*ids):
+            b, _, j, _, h_kv = where(*ids)
+            return b, j, h_kv
+
+        def at_lse(*ids):
+            b, i, _, h, _ = where(*ids)
+            return b, h, i, 0
+
+        q_spec = pl.BlockSpec((1, block_q, width), at_q)
+        kv_spec = pl.BlockSpec((1, block_kv, width), at_kv)
+        lse_spec = pl.BlockSpec((1, per_block, block_q, LANES), at_lse)
+        return [q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec]
+
+    # Causal: the streamed block of a masked step is the one the next (or
+    # last) live step takes, so nothing is fetched for a step that waits.
+
+    # dq grid: q blocks resident, kv blocks streamed
+    def dq_step(b, h, i, j):
+        if causal:
+            j = jnp.minimum(j, _last_kv_block(i, block_q, block_kv))
+        return b, i, j, h, h // groups
+
+    specs = in_specs(dq_step)
     dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, block_q=block_q, block_kv=block_kv,
-            causal=causal, scale=scale,
-        ),
-        grid=(B * H, S // block_q, S // block_kv),
-        in_specs=common_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        functools.partial(_flash_bwd_dq_kernel, **settings),
+        grid=(B, q_head_blocks, num_q, num_kv),
+        in_specs=specs,
+        out_specs=specs[0],
+        out_shape=jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, width), jnp.float32)],
+        compiler_params=_compiler_params(per_block),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+    )(*operands)
 
-    # dkv grid: kv blocks outer (resident), q blocks inner (streamed)
-    lane_spec_kv = pl.BlockSpec(
-        (1, block_q, MIN_LANES), lambda b, j, i: (b, i, 0)
-    )
-    dkv_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),  # q
-        pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),  # v
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),  # do
-        lane_spec_kv,  # lse
-        lane_spec_kv,  # delta
-    ]
+    # dkv grid: kv blocks resident; streamed, the q blocks of each of the
+    # ``groups`` q head-blocks whose kv heads lie in the kv head-block
+    def dkv_step(b, h_kv, j, x):
+        # an odd head count: the last kv head-block's last q head-block
+        # may not be there (the kernel skips it), so ask for none past it
+        h = jnp.minimum(h_kv * groups + x // num_q, q_head_blocks - 1)
+        i = x % num_q
+        if causal:
+            i = jnp.maximum(i, _first_q_block(j, block_q, block_kv))
+        return b, i, j, h, h_kv
+
+    specs = in_specs(dkv_step)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel, block_q=block_q, block_kv=block_kv,
-            causal=causal, scale=scale,
-        ),
-        grid=(B * H, S // block_kv, S // block_q),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-        ],
+        functools.partial(_flash_bwd_dkv_kernel, num_q=num_q, **settings),
+        grid=(B, pl.cdiv(H_kv, per_block), num_kv, groups * num_q),
+        in_specs=specs,
+        out_specs=[specs[1], specs[1]],
+        out_shape=[jax.ShapeDtypeStruct((B, S, H_kv * D), k.dtype),
+                   jax.ShapeDtypeStruct((B, S, H_kv * D), v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((block_kv, D), jnp.float32),
-            pltpu.VMEM((block_kv, D), jnp.float32),
+            pltpu.VMEM((block_kv, width), jnp.float32),
+            pltpu.VMEM((block_kv, width), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(per_block),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
-
-    def unflat(x):
-        return x.reshape(B, H, S, D).transpose(0, 2, 1, 3)
-
-    return unflat(dq), unflat(dk), unflat(dv)
+    )(*operands)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -409,18 +564,9 @@ def _fwd(q, k, v, causal, block_q, block_kv, interpret):
 
 def _bwd(causal, block_q, block_kv, interpret, residuals, grad_out):
     q, k, v, out, lse = residuals
-    H, H_kv = q.shape[2], k.shape[2]
-    groups = H // H_kv
-    ke = jnp.repeat(k, groups, axis=2) if groups > 1 else k
-    ve = jnp.repeat(v, groups, axis=2) if groups > 1 else v
-    dq, dk, dv = _flash_backward(
-        q, ke, ve, out, lse, grad_out, causal, block_q, block_kv, interpret
+    return _flash_backward(
+        q, k, v, out, lse, grad_out, causal, block_q, block_kv, interpret
     )
-    if groups > 1:
-        B, S, _, D = dk.shape
-        dk = dk.reshape(B, S, H_kv, groups, D).sum(axis=3)
-        dv = dv.reshape(B, S, H_kv, groups, D).sum(axis=3)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 pallas_flash_attention.defvjp(_fwd, _bwd)

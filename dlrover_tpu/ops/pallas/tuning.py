@@ -22,10 +22,13 @@ around a read-back (``s2048_d128``).  ``"measured"`` is
 ``scripts/fa_blocks_in_step.py``: the kernels' own time in the device trace
 of a whole training step, forward, recomputed forward and backward of
 every layer, with ``kernel_ms_per_step``, ``device_kind`` and ``date``
-(``s1024_d64``, GPT-2's shape: TPU v5 lite, 2026-09-27, 1024x1024 at
-118.8 ms a step of 96 calls; 512x1024 134.95, 512x512 183.99, 128x128
-737.4: at this shape one block a head, with nothing skipped, beat every
-split that skips the masked blocks).
+(``s1024_d64``, GPT-2's shape, swept anew on the ``[B, S, H*D]`` interface
+where a grid step is two heads: TPU v5 lite, 2026-09-27, 1024x1024 at
+116.6 ms a step of 96 calls; 512x1024 129.8, 512x512 152.7, 128x128
+472.3: at this shape one block a head-pair, with nothing skipped, still
+beats every split that skips the masked blocks; the forward alone goes
+54.3 -> 108.4 -> 158.6 -> 210.2 ms as 1024 rows meet 1, 2, 4, 8 kv
+blocks: its cost is by kv block visited, not by score computed).
 """
 
 import argparse

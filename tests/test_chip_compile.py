@@ -14,6 +14,9 @@ file, and the worker that is given this file is the one that loads it.
 """
 
 import dataclasses
+import importlib.util
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +81,26 @@ def _fa2_fwd_bwd(q, k, v):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
+def _attend_calls(text):
+    """The optimized HLO's lines that are FA2 custom calls (named after
+    the ``_attend`` scope), as the device trace would name them."""
+    return [line.strip() for line in text.split("\n")
+            if "_attend" in line.split(" = ")[0]
+            and 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _benchmark_kind_of():
+    """``benchmarks/layer_metrics/fa2_ms_per_step.py::kind_of``, loaded by
+    path as ``benchmarks/common.py::load_module`` loads it."""
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks", "layer_metrics", "fa2_ms_per_step.py")
+    spec = importlib.util.spec_from_file_location("fa2_ms_per_step", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.kind_of
+
+
 @pytest.mark.parametrize(
     "shape", [(4, 2048, 16, 128), (16, 1024, 16, 64)],
     ids=["b4_s2048_h16_d128", "b16_s1024_h16_d64"],
@@ -98,6 +121,26 @@ class TestFlashAttentionKernel:
         compiled = jax.jit(_fa2_fwd_bwd).lower(x, x, x).compile()
         # forward, dQ, and dK/dV kernels
         assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize(
+    "q_shape, kv_heads",
+    [((3, 1024, 25, 64), 25), ((2, 1024, 4, 64), 2),
+     ((2, 2048, 32, 128), 8)],
+    ids=["25_heads_of_64_last_block_half_empty",
+         "gqa_inside_a_block_kv_lanes_turned", "gqa_32_over_8_of_128"],
+)
+def test_kernel_compiles_at_every_head_layout(one_chip, q_shape, kv_heads):
+    """What the interpreter cannot refuse: a 128-lane block that ends past
+    the array (an odd count of 64-wide heads), and the lane rotation that
+    brings a kv head under its q head when GQA meets two heads a block."""
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(
+        (*q_shape[:2], kv_heads, q_shape[3]), jnp.bfloat16,
+        sharding=one_chip)
+    compiled = jax.jit(_fa2_fwd_bwd).lower(q, kv, kv).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
 
 
 def test_sharded_flash_attention_compiles_on_fsdp4(topo, as_if_on_tpu):
@@ -197,13 +240,31 @@ def _trainer_step_compiled(mesh, model_and_batch=_llama_1b_2_layers):
     return trainer.lower_train_step(state, batch).compile()
 
 
-class TestTrainerStep:
-    def test_one_chip(self, topo, as_if_on_tpu):
+@pytest.fixture(scope="module")
+def llama_one_chip_text(topo):
+    """The Llama widths' step compiled once for the tests that read it."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
         mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
-        compiled = _trainer_step_compiled(mesh)
-        text = compiled.as_text()
-        assert "tpu_custom_call" in text
+        return _trainer_step_compiled(mesh).as_text()
+
+
+class TestTrainerStep:
+    def test_one_chip(self, llama_one_chip_text):
+        text = llama_one_chip_text
+        # forward, recomputed forward, dQ, dK/dV: found by their scope
+        assert len(_attend_calls(text)) == 4
         assert "all-gather" not in text and "all-reduce" not in text
+
+    def test_the_benchmark_tells_the_three_kernels_apart(
+        self, llama_one_chip_text
+    ):
+        """``fa2_ms_per_step`` reads the kernels' kinds from their result
+        forms: (out, float32 statistics), one array, two arrays."""
+        kind_of = _benchmark_kind_of()
+        assert sorted(
+            kind_of(call) for call in _attend_calls(llama_one_chip_text)
+        ) == ["dkv", "dq", "fwd", "fwd"]
 
     def test_gpt2_medium_one_chip(self, topo, as_if_on_tpu):
         """The GPT code has no attention option: at B16 S1024 with 16
@@ -219,7 +280,34 @@ class TestTrainerStep:
         mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
         text = _trainer_step_compiled(mesh, gpt2_medium).as_text()
         assert text.count('custom_call_target="tpu_custom_call"') == 4
+        assert len(_attend_calls(text)) == 4
         assert "[16,16,1024,1024]" not in text
+        # the kernels take [B, S, H*D] as it is: nothing of the
+        # [B, H, S, D] or [B*H, S, D] forms is left, in any operation,
+        # and delta is no array (the one f32 of 128 lanes a row is the
+        # LSE residual, [B, H, S, 128], written by the forward kernel)
+        for gone in ("[16,16,1024,64]", "[256,1024,64]", "[256,1024,128]"):
+            assert gone not in text
+        moved = re.findall(
+            r"= \w+\[16,16,1024,128\]\S* (copy|transpose|broadcast)\(", text)
+        assert not moved
+
+    def test_gpt2_xl_widths_one_block(self, topo, as_if_on_tpu):
+        """25 heads of 64: an odd count keeps the kernel (the last
+        128-lane block holds one head), and no [B, H, S, S] array."""
+        from dlrover_tpu.models.gpt import GPT, GPTConfig
+
+        def gpt2_xl_one_block():
+            cfg = dataclasses.replace(GPTConfig.gpt2_xl(), n_layer=1)
+            return GPT(cfg), (3, 1024)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        text = _trainer_step_compiled(mesh, gpt2_xl_one_block).as_text()
+        # one layer: the recomputed forward may fold into the forward
+        kind_of = _benchmark_kind_of()
+        assert {kind_of(call) for call in _attend_calls(text)} == {
+            "fwd", "dq", "dkv"}
+        assert "[3,25,1024,1024]" not in text
 
     def test_fsdp4(self, topo, as_if_on_tpu):
         mesh = build_mesh(MeshConfig(fsdp=4), devices=list(topo.devices))

@@ -6,6 +6,8 @@ backward vs the reference core, GQA head-grouping, non-causal, and the
 autotuned dispatch through ``ops.attention.flash_attention``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -74,6 +76,41 @@ def test_backward_matches_reference(tpu_backend, kv_heads):
     want = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
     for g, w, name in zip(got, want, "qkv"):
         # grads accumulate over S=256 terms; scale tolerance to magnitude
+        scale = max(1.0, float(jnp.abs(w.astype(jnp.float32)).max()))
+        _assert_close(g, w, atol=0.05 * scale)
+
+
+@pytest.mark.parametrize(
+    "batch, seq, heads, kv_heads, dim",
+    [(16, 1024, 16, 16, 64),    # gpt2m: two heads a 128-lane block
+     (2, 2048, 32, 8, 128),     # mistral7b: GQA, one head a block
+     (2, 4096, 16, 16, 128),    # olmoe, one chip's shard
+     (3, 1024, 25, 25, 64),     # GPT-2 XL: the last block holds one head
+     (2, 1024, 6, 3, 64)],      # GQA inside a block: kv heads turned
+    ids=["gpt2m", "mistral7b", "olmoe_shard", "gpt2xl_25_heads",
+         "gqa_d64"],
+)
+def test_benchmark_shapes_match_reference(tpu_backend, batch, seq, heads,
+                                          kv_heads, dim):
+    """The shapes the cells run (and the odd head count that waits), at
+    the shipped blocks: forward and all three gradients."""
+    q, k, v = _qkv(batch, seq, heads, kv_heads, dim, seed=4)
+    mask = _causal_mask(seq)
+
+    def flash_loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+
+    def ref_loss(q, k, v):
+        out = reference_attention(q, k, v, mask)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+
+    grad = functools.partial(jax.grad, argnums=(0, 1, 2), has_aux=True)
+    got, out = jax.jit(grad(flash_loss))(q, k, v)
+    want, ref = jax.jit(grad(ref_loss))(q, k, v)
+    _assert_close(out, ref, atol=3e-2)
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
         scale = max(1.0, float(jnp.abs(w.astype(jnp.float32)).max()))
         _assert_close(g, w, atol=0.05 * scale)
 
