@@ -1,6 +1,8 @@
 """The GPT-2 code of the program (``models/gpt.py``: LayerNorm, GELU
-(tanh), learned positions, biases, tied head, XLA attention) driven from a
-configuration file, its count of operations, and its plain reference."""
+(tanh), learned positions, biases, tied head; the attention is the one
+``ops.attention.causal_attention`` chooses, the FA2 kernel on a TPU) driven
+from a configuration file, its count of operations, and its plain
+reference."""
 
 import jax
 import jax.numpy as jnp
@@ -43,7 +45,17 @@ def flops_per_token(config, seq, rehearse=False):
 
 
 def fa2_shape(config, batch_per_chip, seq):
-    return None  # XLA attention: no FA2 kernel in this family's step
+    """Shape of one call of the FA2 kernels, which ``ops.attention.
+    causal_attention`` gives this family on a TPU at head size 64 or 128
+    and S a multiple of 128 (PR 26), and how often a step calls each: with
+    ``remat`` the forward runs again in the backward pass.  MHA: as many
+    kv heads as heads."""
+    m = sizes(config, False)
+    layers, heads = m["n_layer"], m["n_head"]
+    return {"batch": batch_per_chip, "seq": seq, "heads": heads,
+            "kv_heads": heads, "head_dim": m["n_embd"] // heads,
+            "causal": True,
+            "calls_per_step": {"fwd": 2 * layers, "dq": layers, "dkv": layers}}
 
 
 #: as in ``families/llama.py``; the GPT code multiplies in bfloat16 and takes

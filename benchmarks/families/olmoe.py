@@ -71,6 +71,65 @@ def build(config, rehearse, seq):
     return LlamaForCausalLM(cfg)
 
 
+def state_rule(config, rehearse):
+    """{path of a leaf of ``state.params``: the factor ``condition``
+    multiplies it by}: the whole rule, read from the configuration file
+    (none where the file names no ``run.state``)."""
+    if "state" not in config["run"]:     # ``create_state``'s own state
+        return {}
+    experts = float(sizes(config, rehearse)["num_experts"]) ** 0.5
+    mlp = ("layers", "layer", "mlp")
+    return {("embed_tokens",): float(config["run"]["state"]["embed_scale"]),
+            mlp + ("gate_proj",): experts, mlp + ("up_proj",): experts,
+            mlp + ("down_proj",): experts}
+
+
+def condition(state, config, rehearse):
+    """The state a cell of this family starts from (``program.make_state``):
+    ``Trainer.create_state``'s, with the leaves of ``state_rule`` multiplied
+    by its factors.  Same tree, shardings and dtypes; one multiply a leaf on
+    the device, no forward pass, no look at a batch.
+
+    The embedding table, times ``run.state.embed_scale``: the initialiser
+    makes the table at rms 0.02, and every branch of the decoder reads the
+    residual stream through an RMSNorm.  An untrained attention layer adds
+    the running mean of the values before a token (rms 0.1-0.9), the same
+    vector for a sequence's neighbouring positions, so from the first layer
+    on the router of ``create_state``'s state sees a sequence's slowly moving
+    context and not the token: a layer's tokens go to a few experts, which
+    ones differs by seed and by step, and the step's time with them
+    (PERF.md, section 6, PR 32).  Scaling the table up is scaling every
+    branch down by as much (each reads the stream normalised): the router
+    then sees the token's own vector first, and uniform random tokens
+    spread evenly over the experts, which is where the load-balancing loss
+    holds a mixture in training.
+
+    Each expert's three matrices, times the square root of the number of
+    experts: the initialiser draws the stacked ``[experts, in, out]`` arrays
+    with the expert axis counted into the fan-in, so every matrix of
+    ``create_state``'s experts is that factor smaller than a matrix of its
+    own shape would be drawn, and the routed branch, three matrices deep,
+    adds rms 0.0002 to a stream of 0.02: no comparison of the model's
+    output could see the expert layer at all (PR 32's review: one chip's
+    experts missing moved the worst token's loss by 0.0007).  At the
+    factor the branch adds rms 0.10 a layer beside attention's 0.12-0.20,
+    in a stream of 2.0 (CPU, float32, the published widths, 2 sequences of
+    1024): the layer the cell is there for counts in ``correct`` as much as
+    attention does.  The measured loads and readings are in the
+    configuration file's notes and under ``TOKEN_ATOL`` below."""
+    rule = state_rule(config, rehearse)
+
+    def scaled(path, leaf):
+        factor = rule.get(tuple(k.key for k in path[:-1]))   # [-1]: ``value``
+        if factor is None:
+            return leaf
+        return jax.jit(lambda t: (t * factor).astype(t.dtype),
+                       donate_argnums=0, out_shardings=leaf.sharding)(leaf)
+
+    return state.replace(
+        params=jax.tree_util.tree_map_with_path(scaled, state.params))
+
+
 def matmul_params(config, rehearse=False):
     """Parameters a token multiplies with: the four attention projections,
     the router, its ``num_experts_per_tok`` experts (three matrices each)
@@ -147,45 +206,81 @@ def gmm_step_bytes(shape, itemsize=2):
 # remat; every head and every expert looped over plainly
 # --------------------------------------------------------------------------
 
-#: |system - reference| allowed on the loss of each token and on their
-#: mean.  The system multiplies in bfloat16 with float32 accumulation, as
-#: the configuration states (the router in float32 at the highest
-#: precision); the reference is float32 throughout.  On top of the rounding
-#: a dense model shows, routing is discontinuous: where the k-th and k+1-th
-#: router logits of a token lie closer than the bfloat16 error of the
-#: hidden state that feeds the router, the system can keep the other
-#: expert.  The two experts' weights are then nearly equal, so the token's
-#: result moves by one expert's weighted output (some 3% of the weight
-#: mass) and not by a whole block.  The reference routes by its own
-#: logits; it counts the tokens whose margin is under ``LOW_MARGIN`` in each
-#: layer, prints the shares (``phase: reference_margin``), and no token
-#: leaves the comparison.  A share over ``LOW_MARGIN_SHARE_MAX`` means the
-#: router has collapsed towards ties and a comparison token by token says
-#: nothing: the reference then returns NaN and the run is not correct.
-#: Each limit stands between two readings on the chip at the published
-#: widths, four sequences of 4096 (``tests/precision_olmoe.py`` and the
-#: cell's own check; PERF.md, section 4): what the system gives over its
-#: seeds, and what the reference gives against itself with its parameters
-#: rounded through float8 (e4m3), the nearest precision below the bfloat16
-#: the configuration states, which has to come out as not correct.  That
-#: probe rounds the parameters only, reference against reference (the
-#: system never multiplies below bfloat16): it is the mildest reading below
-#: bfloat16, and rounding the activations too could only lie further off.
+#: |system - reference| allowed on the loss of the worst token, of the median
+#: token, and on the mean.  The system multiplies in bfloat16 with float32
+#: accumulation, as the configuration states (the router in float32 at the
+#: highest precision); the reference is float32 throughout.  On top of the
+#: rounding a dense model shows, routing is discontinuous: where the k-th and
+#: k+1-th router logits of a token lie closer than the bfloat16 error of the
+#: hidden state that feeds the router, the system can keep the other expert.
+#: The two experts' weights are then nearly equal, so the token's result
+#: moves by one expert's weighted output and not by a whole block.  The
+#: reference routes by its own logits; it counts the tokens whose margin is
+#: under ``LOW_MARGIN`` in each layer, prints the shares (``phase:
+#: reference_margin``), and no token leaves the comparison.  A share over
+#: ``LOW_MARGIN_SHARE_MAX`` means the router has collapsed towards ties and a
+#: comparison token by token says nothing: the run is not correct.
 #:
-#:   worst token  system 0.0238-0.0352 (19 seeds)  float8 0.328-0.441 (8)
-#:   mean         system 1.4e-5-1.1e-4 (19 seeds)  float8 8e-6-1.0e-3 (8)
+#: Each limit stands between readings on the chips at the published widths
+#: and the cell's own size, four sequences of 4096, on the state
+#: ``condition`` gives (``tests/precision_olmoe.py``, every set of losses
+#: through ``jobs_shared.compare_losses``; my chip runs, PR 32's review
+#: round): what the system gives, and what the control gives, the reference
+#: put in the program's place with its parameters rounded through float8
+#: (e4m3, ``_round_through``), the nearest precision below the bfloat16 the
+#: configuration states, which has to come out as not correct.  The control
+#: rounds the parameters only (the system never multiplies below bfloat16):
+#: the mildest reading below bfloat16, rounding the activations too could
+#: only lie further off.  Beside them a fault of the layer the cell is there
+#: for, planted the same way: one chip's experts missing.
 #:
-#: The token limit is the one a lower precision cannot pass: three times
-#: the system's worst, a third of float8's best.  The mean's error is the
-#: average of 16,384 token errors of either sign (median 0.0046 for the
-#: system, 0.059 for float8): noise of standard deviation 6e-5 for the
-#: system whatever the seed, and of some 6e-4 for float8, of whose eight
-#: seeds two read under the limit (8e-6, 1.9e-4) and six over it.  No
-#: value separates two such ranges; 2.5e-4 is four of the system's
-#: deviations (a sound run in some 30,000 fails it; at 2e-4 one in a
-#: thousand, in a cell every later PR runs a dozen times), and what it
-#: holds is a bias: 2.5e-4 on every token is already over it.
-TOKEN_ATOL = 1e-1
+#:                 system, 6 seeds     float8, 3 seeds    a chip's experts missing, 3
+#:   worst token   0.0619-0.0870       0.189-0.205        0.370-0.444
+#:   median token  0.00498-0.00510     0.0307-0.0314      0.0394-0.0408
+#:   mean          3.6e-5-1.13e-4      1.2e-5-3.3e-4      4.3e-4-5.5e-4
+#:
+#: (all the seeds the review round's 40 chip-minutes held: three in the
+#: probe, and the system's other three are the cell's own runs; on the CPU at
+#: 2 sequences of 1024, float32 reference against the program's bfloat16
+#: without the kernel, three seeds read 0.050-0.065 / 0.0051-0.0052,
+#: 0.141-0.165 / 0.031-0.032, 0.236-0.341 / 0.040, and every token's weakest
+#: expert dropped 0.088-0.098 / 0.016; PERF.md section 6 has every reading.)
+#: The median is the number that holds the cell: steady to 2% from seed to
+#: seed, the control 6.0 times and the fault 7.7 times the system's largest,
+#: so ``MEDIAN_ATOL`` 0.01 stands 2.0 times over the system's largest and
+#: 3.1 times under the control's smallest.  The worst token swings with the
+#: routing (one flip in one layer moves a token by some 0.03; deviation 0.009
+#: over the six seeds): the control's smallest is 2.2 times the system's
+#: largest, under the three times a limit wants, and the control need not
+#: fail it: ``TOKEN_ATOL`` 0.15 is there for one token or one row gone
+#: wrong, which no median sees.  It lies 1.7 times over the system's largest
+#: (seven deviations: a check draws thirty new seeds, and one false "not
+#: correct" refuses a sound PR) and 1.26 times under the control's smallest.
+#: The chip runs of the review round were made at 0.12 and the constant was
+#: raised when the sixth seed read 0.087; every reading is under both.  The
+#: mean's error is the average of 16,384 token errors of either sign, noise
+#: of some 6e-5 for system and control alike: no value separates them and
+#: none did (PR 27's review round); 2.5e-4 stays, four of those deviations,
+#: and what it holds is a bias: the missing chip is over it on every seed.
+#:
+#: How the limits got here.  PR 27 set 0.1 and 2.5e-4 on ``create_state``'s
+#: state (system 0.0238-0.0352, float8 by the backend's own conversion
+#: 0.328-0.441).  PR 32's first round scaled the table by 100 and read the
+#: system at 0.0192-0.0219 with a median of 0.0034, the control at
+#: 0.0719-0.1357 with a median of 0.0036, and one chip's experts missing at
+#: 0.0006: the comparison saw table, norm and head, not the branches.  Two
+#: causes, both found in the review round.  The routed branch added rms
+#: 0.0002 to a stream of 2.0, because the initialiser draws each expert's
+#: matrices 8 times too small (``condition``).  And that control was no
+#: float8: a conversion to float8 and back is removed by the chip's
+#: compiler where the two meet (0.0 relative rms on the head's kernel
+#: through ``astype``, 0.0316 through ``_round_through``, same call; the
+#: CPU keeps it, and read a median of 0.028 where the chips read 0.0036),
+#: so its median was the system's.  ``_round_through`` rounds in float32
+#: arithmetic, bit for bit the CPU's conversion, and cannot be optimised
+#: away.
+TOKEN_ATOL = 0.15
+MEDIAN_ATOL = 1e-2
 MEAN_ATOL = 2.5e-4
 LOW_MARGIN = 1e-2
 LOW_MARGIN_SHARE_MAX = 0.25
@@ -255,6 +350,23 @@ def _experts(h, p, m):
     return out, low
 
 
+def _round_through(t, dtype):
+    """``t`` rounded to the nearest value of a narrower float ``dtype`` (ties
+    to even, its subnormals kept, no overflow expected), in float32
+    arithmetic and bit for bit what ``t.astype(dtype).astype(float32)`` gives
+    on the CPU: the control's rounding then does not hang on how a backend
+    implements a conversion to a type its chip has no unit for."""
+    info = jnp.finfo(dtype)
+    _, exponent = jnp.frexp(t)          # |t| in [2**(exponent-1), 2**exponent)
+    k = jnp.maximum(exponent - 1, int(info.minexp)) - int(info.nmant)
+
+    def two_to(n):
+        return jax.lax.bitcast_convert_type(
+            ((n + 127) << 23).astype(jnp.int32), jnp.float32)
+
+    return jnp.round(t * two_to(-k)) * two_to(k)
+
+
 def _report_margin(shares):
     print(json.dumps({"phase": "reference_margin", "low_margin": LOW_MARGIN,
                       "share_by_layer": [float(s) for s in shares],
@@ -262,22 +374,23 @@ def _report_margin(shares):
           file=sys.stderr, flush=True)
 
 
-def reference_token_losses(params, input_ids, labels, config, rehearse=False,
-                           round_through=None):
-    """Loss of every token, [B, S] float32, from the same parameter tree
-    (unboxed, layers stacked on the leading axis by the program's scan).
-    The loops over the layers and over the experts are ``jax.lax.scan``s of
-    the plain body, so that the program compiles in seconds and holds one
-    layer's float32 weights at a time beside the training state.
-    ``round_through`` (``precision_olmoe.py``): a dtype every parameter is
-    rounded through before it is used, for the reading below bfloat16."""
+def reference_forward(params, input_ids, labels, config, rehearse=False,
+                      round_through=None):
+    """(loss of every token, [B, S] float32; share of each layer's tokens
+    with a router margin under ``LOW_MARGIN``, [layers]) from the same
+    parameter tree (unboxed, layers stacked on the leading axis by the
+    program's scan).  The loops over the layers and over the experts are
+    ``jax.lax.scan``s of the plain body, so that the program compiles in
+    seconds and holds one layer's float32 weights at a time beside the
+    training state.  ``round_through`` (``precision_olmoe.py``): a dtype
+    every parameter is rounded through before it is used, for the reading
+    below bfloat16.  The shares also go to standard error."""
     m = sizes(config, rehearse)
     eps = float(m["rms_norm_eps"])
 
     def f32(t):
-        if round_through is not None:
-            t = jnp.asarray(t, round_through)
-        return jnp.asarray(t, jnp.float32)
+        t = jnp.asarray(t, jnp.float32)
+        return t if round_through is None else _round_through(t, round_through)
 
     def layer(x, p):
         p = jax.tree.map(f32, p)
@@ -294,4 +407,13 @@ def reference_token_losses(params, input_ids, labels, config, rehearse=False,
         logp = jax.nn.log_softmax(x @ f32(params["lm_head"]["kernel"]), -1)
     losses = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     jax.debug.callback(_report_margin, low)
+    return losses, low
+
+
+def reference_token_losses(params, input_ids, labels, config, rehearse=False,
+                           round_through=None):
+    """``reference_forward``'s losses, NaN where a layer's low-margin share
+    is over ``LOW_MARGIN_SHARE_MAX``."""
+    losses, low = reference_forward(
+        params, input_ids, labels, config, rehearse, round_through)
     return jnp.where(jnp.max(low) <= LOW_MARGIN_SHARE_MAX, losses, jnp.nan)

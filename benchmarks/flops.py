@@ -34,17 +34,20 @@ def fa2_call_flops(kind, batch, seq, heads, head_dim, causal=True):
 def fa2_call_bytes(kind, batch, seq, heads, kv_heads, head_dim, itemsize=2):
     """Least bytes a call moves to and from HBM: each operand read once,
     each result written once, the per-row statistics as one float32 a
-    row.  The backward kernels run on group-expanded K and V (``heads``
-    wide), as ``ops/pallas/flash_attention.py::_bwd`` calls them."""
+    row.  As ``ops/pallas/flash_attention.py::_flash_backward`` calls the
+    kernels since PR 30: both backward kernels read (q, k, v, dO, O, lse)
+    with K and V at the kv head count (no expansion to the q heads, no
+    ``delta`` operand: the kernels take it from dO and O), and dK and dV
+    leave at the kv head count."""
     q = batch * seq * heads * head_dim * itemsize
     kv = batch * seq * kv_heads * head_dim * itemsize
     row = batch * seq * heads * 4
     if kind == "fwd":
         return q + 2 * kv + q + row           # q, k, v -> out, lse
     if kind == "dq":
-        return 4 * q + 2 * row + q            # q, k, v, do, lse, delta -> dq
+        return 3 * q + 2 * kv + row + q       # q, k, v, do, o, lse -> dq
     if kind == "dkv":
-        return 4 * q + 2 * row + 2 * q        # ... -> dk, dv
+        return 3 * q + 2 * kv + row + 2 * kv  # ... -> dk, dv
     raise KeyError(kind)
 
 

@@ -97,7 +97,8 @@ def main(argv=None):  # noqa: C901 - one script, told in order
         rec["restore_s"] = time.time() - t0
         if state is None:
             t0 = time.time()
-            state = trainer.create_state(key, sample)
+            state = program.make_state(
+                trainer, family, config, rehearse, spec["seed"], pool)
             jax.block_until_ready(state)
             rec["phases"]["create_state_s"] = time.time() - t0
             start_step = 0

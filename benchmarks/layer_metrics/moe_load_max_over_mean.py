@@ -12,23 +12,13 @@ import sys
 
 from benchmarks import program_spans
 
-STATS = "trainer.model_stats"
-
-
 def read(observed):
-    window = program_spans.select(observed)
-    if not window:
+    records = program_spans.model_stats(observed, "load_max_over_mean")
+    if not records:
         return None
-    first = window.steps[0].start_ns
-    spans = [s for s in program_spans.ring()
-             if s.name == STATS and s.start_ns >= first
-             and s.attrs.get("load_max_over_mean")]
-    if not spans:
-        return None
-    worst = max(max(s.attrs["load_max_over_mean"]) for s in spans)
+    worst = max(max(layers) for _, layers in records)
     print(json.dumps({"phase": "moe_routing", "load_max_over_mean": worst,
-                      "records": [{"step": s.attrs.get("step"),
-                                   "by_layer": s.attrs["load_max_over_mean"]}
-                                  for s in spans]}),
+                      "records": [{"step": step, "by_layer": layers}
+                                  for step, layers in records]}),
           file=sys.stderr, flush=True)
     return worst

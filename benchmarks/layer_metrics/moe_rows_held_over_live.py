@@ -15,25 +15,15 @@ import sys
 
 from benchmarks import program_spans
 
-STATS = "trainer.model_stats"
-
-
 def read(observed):
-    window = program_spans.select(observed)
-    if not window:
+    records = program_spans.model_stats(observed, "rows_held_over_live")
+    if not records:
         return None
-    first = window.steps[0].start_ns
-    spans = [s for s in program_spans.ring()
-             if s.name == STATS and s.start_ns >= first
-             and s.attrs.get("rows_held_over_live")]
-    if not spans:
-        return None
-    worst = max(max(s.attrs["rows_held_over_live"]) for s in spans)
+    chips = dict(program_spans.model_stats(observed, "chip_rows_max_over_mean"))
+    worst = max(max(layers) for _, layers in records)
     print(json.dumps({
         "phase": "moe_rows", "rows_held_over_live": worst,
-        "records": [{"step": s.attrs.get("step"),
-                     "held_over_live": s.attrs["rows_held_over_live"],
-                     "chip_rows_max_over_mean":
-                         s.attrs.get("chip_rows_max_over_mean")}
-                    for s in spans]}), file=sys.stderr, flush=True)
+        "records": [{"step": step, "held_over_live": layers,
+                     "chip_rows_max_over_mean": chips.get(step)}
+                    for step, layers in records]}), file=sys.stderr, flush=True)
     return worst

@@ -1,8 +1,8 @@
 """How a job of the benchmark builds the system under test from a
 configuration file and a seed: model, mesh, optimizer and ``Trainer`` as
 ``chip_smoke.py`` builds them, the pool of host batches, the key the weights
-are made from.  Imports JAX: only a process that may hold the chip calls
-it."""
+are made from, the state a cell starts from.  Imports JAX: only a process
+that may hold the chip calls it."""
 
 import numpy as np
 
@@ -54,3 +54,24 @@ def make_key(seed):
 
     return jax.random.fold_in(
         jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
+
+
+def make_state(trainer, family, config, rehearse, seed, pool):
+    """The state every job of a cell starts from, made from the seed alone:
+    ``Trainer.create_state`` and then, where the family has one, its
+    ``condition(state, config, rehearse)``: a rule on the parameters that
+    reads only the configuration file (``families/olmoe.py``; the dense
+    families have none, their states are ``create_state``'s bit for bit)."""
+    state = trainer.create_state(make_key(seed), pool[0]["input_ids"])
+    condition = getattr(family, "condition", None)
+    return state if condition is None else condition(state, config, rehearse)
+
+
+def stats_by_name(stats):
+    """{name: one value a layer} of a tree the model sowed into ``stats``
+    (``model.apply(..., mutable=["stats"])[1]["stats"]``, or a step's
+    ``metrics["stats"]``)."""
+    import jax
+
+    return {path[-2].key: leaf.ravel() for path, leaf in
+            jax.tree_util.tree_leaves_with_path(stats)}

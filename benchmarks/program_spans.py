@@ -47,6 +47,20 @@ def ring() -> list:
             if isinstance(s, tuple) and hasattr(s, "start_ns")]
 
 
+def model_stats(observed, name):
+    """What the model sowed under ``name`` in the window's
+    ``trainer.model_stats`` records, oldest first: ``[(step, [a value a
+    layer])]``; ``[]`` where the window or the name is not there (a dense
+    model sows nothing)."""
+    window = select(observed)
+    if not window:
+        return []
+    first = window.steps[0].start_ns
+    return [(s.attrs.get("step"), s.attrs[name]) for s in ring()
+            if s.name == "trainer.model_stats" and s.start_ns >= first
+            and s.attrs.get(name)]
+
+
 @dataclasses.dataclass
 class Window:
     steps: list                      # trainer.step, oldest first

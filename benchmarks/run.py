@@ -12,7 +12,9 @@ and entries and edits none of these.
 
 Earlier lines are free (one JSON object per phase); the last line of
 standard output is the contract's: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, traced, ``breakdown``.  With ``--trace 0`` the
+``metrics``, ``device``, traced ``breakdown``, and last ``checks``: every
+number ``correct`` compared beside its limit (also the last lines of
+standard error).  With ``--trace 0`` the
 metrics are the cell's end-to-end metrics, with ``--trace 1`` its per-layer
 metrics.  This file never imports JAX: a stepping job imports it inside the
 job, the resume job's orchestrator stays off it.
@@ -138,6 +140,15 @@ def main(argv=None):
         return EXIT_JOB_FAILED
     if args.trace and observed.get("breakdown"):
         line["breakdown"] = observed["breakdown"]
+    # what ``correct`` compared, each number beside its limit: the last
+    # lines of standard error and the last key of the result's line
+    checks = observed.get("checks") or {}
+    for name, (value, limit) in checks.items():
+        print(f"check {name}: {value} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
+    line["checks"] = {   # a NaN (the one value unequal to itself) as a word
+        name: {"value": value if value == value else "nan", "limit": limit}
+        for name, (value, limit) in checks.items()}
     if args.rehearse:
         run.emit({"phase": "result", "would_print": sorted(metrics),
                   "correct": line["correct"], "attempted": line["attempted"],

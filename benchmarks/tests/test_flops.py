@@ -29,8 +29,12 @@ def test_fa2_call_bytes_by_hand():
     # batch 1, seq 4, 4 heads over 2 kv heads of size 8, bf16:
     # q = 4*4*8*2 = 256 B, k = v = 128 B, one float a row = 4*4*4 = 64 B
     assert flops.fa2_call_bytes("fwd", 1, 4, 4, 2, 8) == 256 + 256 + 256 + 64
-    assert flops.fa2_call_bytes("dq", 1, 4, 4, 2, 8) == 4 * 256 + 128 + 256
-    assert flops.fa2_call_bytes("dkv", 1, 4, 4, 2, 8) == 4 * 256 + 128 + 512
+    # backward: q, dO, O at the q heads, K and V at the kv heads, lse; dQ a
+    # q head each, dK and dV a kv head each
+    assert flops.fa2_call_bytes("dq", 1, 4, 4, 2, 8) == 3 * 256 + 256 + 64 + 256
+    assert flops.fa2_call_bytes("dkv", 1, 4, 4, 2, 8) == 3 * 256 + 256 + 64 + 256
+    # MHA (kv heads = heads): K, V, dK, dV as wide as q
+    assert flops.fa2_call_bytes("dkv", 1, 4, 4, 4, 8) == 3 * 256 + 512 + 64 + 512
 
 
 def test_fa2_least_seconds_names_its_bound():
@@ -61,3 +65,16 @@ def test_gpt2_medium_matmul_params():
     family = load_module("families", "gpt")
     assert family.matmul_params(config) == (
         24 * 12 * 1024 * 1024 + 1024 * 50304)
+
+
+def test_gpt2_medium_fa2_shape():
+    """The GPT cell's kernel calls: 16 heads of 64 (MHA) at S 1024, with
+    remat the forward twice a layer."""
+    config = read_json(HERE, "configs", "gpt2m.json")
+    shape = load_module("families", "gpt").fa2_shape(config, 16, 1024)
+    assert [shape[key] for key in ("batch", "seq", "heads", "kv_heads",
+                                   "head_dim")] == [16, 1024, 16, 16, 64]
+    assert shape["calls_per_step"] == {"fwd": 48, "dq": 24, "dkv": 24}
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    for kind in ("fwd", "dq", "dkv"):
+        assert flops.fa2_call_least_seconds(kind, shape, peaks)[1] == "compute"

@@ -94,8 +94,12 @@ def test_empty_ring_reads_nothing(name, monkeypatch):
 @pytest.mark.parametrize("name", NEW)
 def test_a_program_without_the_recorder_reads_nothing(name, monkeypatch):
     """The parent commit's ring holds rendered records (dicts)."""
+    # the package holds the module once anything has imported it
+    import dlrover_tpu.observability as pkg
+
     monkeypatch.setitem(sys.modules, "dlrover_tpu.observability.flight_recorder",
                         None)
+    monkeypatch.delattr(pkg, "flight_recorder", raising=False)
     assert ps.ring() == []
     obs = observed(trace_loaded=trace_mod.Trace({}, [], {}))
     assert load_module("layer_metrics", name).read(obs) is None
@@ -206,15 +210,62 @@ def test_idle_attributed_pct_on_another_origin(monkeypatch, capsys):
 
 
 def test_every_new_metric_is_registered_for_its_cells():
+    """Present and in order: later PRs append metrics after them and cells
+    to the trainer ones' ``workloads``."""
     entries = read_json(ROOT, "BENCHMARK.json")["per_layer"]
     mine = {m["name"]: m for m in entries if m["name"] in NEW}
-    assert list(mine) == NEW        # appended, in the issue's order
-    assert [m["name"] for m in entries[-len(NEW):]] == NEW
+    assert list(mine) == NEW        # in the issue's order
     for name, m in mine.items():
         assert m["moves"] == "tokens_per_s"
-        assert m["workloads"] == (
-            ["mistral7b_l2.steady", "gpt2m.save_mem"] if name in TRAINER
-            else ["gpt2m.save_mem"])
+        if name in TRAINER:
+            assert m["workloads"][:2] == ["mistral7b_l2.steady",
+                                          "gpt2m.save_mem"]
+        else:
+            assert m["workloads"] == ["gpt2m.save_mem"]
+
+
+def stats(start_ms, step, **lists):
+    return span("trainer.model_stats", start_ms, 0.1, step=step, **lists)
+
+
+MOE = {"moe_load_max_over_mean": "load_max_over_mean",
+       "moe_rows_held_over_live": "rows_held_over_live",
+       "moe_chip_rows_max_over_mean": "chip_rows_max_over_mean"}
+
+
+@pytest.mark.parametrize("name", sorted(MOE))
+def test_moe_counters_read_the_worst_layer_of_the_windows_records(
+        name, monkeypatch, capsys):
+    """Two records inside the window, one before it: the worst layer of
+    the window's records, and the earlier record's 9.0 is not read."""
+    spans = made_up(with_save=False) + [
+        stats(-400, 0, **{attr: [9.0, 9.0] for attr in MOE.values()}),
+        stats(105, 20, load_max_over_mean=[1.1, 1.3],
+              rows_held_over_live=[1.25, 1.25],
+              chip_rows_max_over_mean=[1.02, 1.04]),
+        stats(405, 40, load_max_over_mean=[1.2, 1.15],
+              rows_held_over_live=[1.25, 1.3125],
+              chip_rows_max_over_mean=[1.07, 1.03]),
+    ]
+    want = {"moe_load_max_over_mean": 1.3, "moe_rows_held_over_live": 1.3125,
+            "moe_chip_rows_max_over_mean": 1.07}[name]
+    assert read(name, observed(save=False), spans, monkeypatch) == want
+    capsys.readouterr()
+    # a dense model sows nothing: nothing to read, never a 0
+    assert read(name, observed(save=False), made_up(with_save=False),
+                monkeypatch) is None
+
+
+def test_the_extent_counter_is_registered_for_the_routed_cell():
+    entries = read_json(ROOT, "BENCHMARK.json")["per_layer"]
+    mine = next(m for m in entries if m["name"] == "moe_chip_rows_max_over_mean")
+    assert mine == {"name": "moe_chip_rows_max_over_mean", "unit": "x",
+                    "better": "lower", "source": "program_counter",
+                    "layer": "trainer step", "moves": "tokens_per_s",
+                    "workloads": ["olmoe1b7b_ep4.steady"]}
+    fa2 = {m["name"]: m["workloads"] for m in entries
+           if m["name"].startswith("fa2_")}
+    assert all("gpt2m.save_mem" in cells for cells in fa2.values()) and fa2
 
 
 def test_rehearsal_lists_every_new_metric():
@@ -229,4 +280,4 @@ def test_rehearsal_lists_every_new_metric():
     assert all(line.startswith("REHEARSAL ") for line in lines)
     last = json.loads(lines[-1][len("REHEARSAL "):])
     assert last["phase"] == "result" and last["correct"] is True
-    assert set(NEW) <= set(last["would_print"])
+    assert set(NEW) | {"setup_wall_s"} <= set(last["would_print"])

@@ -13,7 +13,7 @@ from benchmarks.common import ROOT
 def test_rehearsal_of_the_four_chip_cell():
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
-         "--workload", "olmoe1b7b_ep4.steady", "--seed", "3000000019",
+         "--workload", "olmoe1b7b_ep4.steady", "--seed", "3200000017",
          "--seconds", "3", "--trace", "1", "--rehearse"],
         capture_output=True, text=True, timeout=300, cwd=ROOT,
     )
@@ -26,7 +26,16 @@ def test_rehearsal_of_the_four_chip_cell():
     last = records[-1]
     assert last["phase"] == "result" and last["correct"] is True
     assert last["failed"] == 0 and last["attempted"] >= 1
-    assert "moe_load_max_over_mean" in last["would_print"]
+    assert {"moe_load_max_over_mean", "moe_rows_held_over_live",
+            "moe_chip_rows_max_over_mean"} <= set(last["would_print"])
+    # every number ``correct`` compared, beside its limit, ends standard error
+    checks = [line for line in proc.stderr.splitlines()
+              if line.startswith("check ")]
+    assert {line.split()[1].rstrip(":") for line in checks} >= {
+        "token_max_abs_err", "token_median_abs_err", "mean_abs_err",
+        "low_margin_share",
+        "compiles_in_window", "non_finite_losses"}
+    assert proc.stderr.strip().splitlines()[-1].startswith("check ")
     routing = [json.loads(line) for line in proc.stderr.splitlines()
                if line.startswith('{"phase": "moe_routing"')]
     assert routing and routing[-1]["load_max_over_mean"] >= 1.0
