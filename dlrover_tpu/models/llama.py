@@ -43,9 +43,20 @@ class LlamaConfig:
     remat: bool = True
     scan_layers: bool = True
     attention_impl: str = "reference"  # reference | flash | ring
-    # q and k each RMS-normalised over their whole projected width before
-    # the split into heads and RoPE (OLMoE, arXiv:2409.02060)
-    qk_norm: bool = False
+    # q and k each RMS-normalised before RoPE: ``True`` over their whole
+    # projected width, before the split into heads (OLMoE,
+    # arXiv:2409.02060); ``"head"`` over each head's ``head_dim``, one
+    # learned scale for q and one for k (Qwen3's, Keye-VL-2.0's)
+    qk_norm: Any = False
+    # a learned sparse-attention indexer (DeepSeek-V3.2-Exp's lightning
+    # indexer; Keye-VL-2.0's ``sa_config``): ``index_heads`` query heads of
+    # ``index_head_dim`` over one shared key head score every earlier key,
+    # and a query attends to the ``index_topk`` highest.  0: none, every
+    # earlier key.  ``index_block`` queries are worked at a time
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_block: int = 512
 
     def __post_init__(self):
         valid = ("reference", "flash", "ring")
@@ -55,6 +66,11 @@ class LlamaConfig:
             )
         if self.num_heads % self.num_kv_heads != 0:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm={self.qk_norm!r} not in "
+                             "(False, True, 'head')")
+        if self.index_topk and not (self.index_heads and self.index_head_dim):
+            raise ValueError("index_topk needs index_heads and index_head_dim")
 
     def feed_forward(self):
         """The module class of the block after attention, built as
@@ -106,12 +122,14 @@ class RMSNorm(nn.Module):
     eps: float
     dtype: Dtype
     param_dtype: Dtype
+    axis_name: str = "embed"
 
     @nn.compact
     def __call__(self, x):
         scale = self.param(
             "scale",
-            nn.with_logical_partitioning(nn.initializers.ones, ("embed",)),
+            nn.with_logical_partitioning(
+                nn.initializers.ones, (self.axis_name,)),
             (x.shape[-1],),
             self.param_dtype,
         )
@@ -154,7 +172,12 @@ class Attention(nn.Module):
             ),
             name="v_proj",
         )(x)
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                        "head_dim", name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                        "head_dim", name="k_norm")(k)
+        elif cfg.qk_norm:
             def whole_width_norm(t, name):
                 flat = t.reshape(*t.shape[:2], -1)
                 norm = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
@@ -170,7 +193,10 @@ class Attention(nn.Module):
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
 
-        out = self._attend(q, k, v, mask)
+        if cfg.index_topk:
+            out = self._attend_indexed(x, q, k, v, positions, dense)
+        else:
+            out = self._attend(q, k, v, mask)
         out = nn.with_logical_constraint(
             out, ("batch", "seq", "heads", "head_dim")
         )
@@ -185,6 +211,38 @@ class Attention(nn.Module):
             ),
             name="o_proj",
         )(out)
+
+    def _attend_indexed(self, x, q, k, v, positions, dense):
+        """Attention over the keys the indexer selects.  The indexer reads
+        ``stop_gradient(x)`` and is taught by its own loss alone (sown into
+        ``losses`` like a router's terms), never by the language model's."""
+        from dlrover_tpu.ops.attention import indexed_sparse_attention
+
+        cfg = self.config
+        x = jax.lax.stop_gradient(x)
+
+        def project(name, *features):
+            return dense(
+                features=features, name=name,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(),
+                    ("embed",) + (None,) * len(features)),
+            )(x)
+
+        index_q = project("index_q_proj", cfg.index_heads, cfg.index_head_dim)
+        index_k = nn.LayerNorm(
+            epsilon=1e-6, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name="index_k_norm")(project("index_k_proj", cfg.index_head_dim))
+        index_w = project("index_w_proj", cfg.index_heads)
+        index_q = _rope(index_q, positions, cfg.rope_theta)
+        index_k = _rope(index_k[:, :, None], positions, cfg.rope_theta)[:, :, 0]
+        out, loss, low = indexed_sparse_attention(
+            q, k, v, index_q, index_k, index_w, cfg.index_topk,
+            cfg.index_block)
+        self.sow("losses", "index", loss / cfg.num_layers)
+        self.sow("stats", "index_loss", loss)
+        self.sow("stats", "index_low_margin_share", low)
+        return out
 
     def _attend(self, q, k, v, mask):
         cfg = self.config
@@ -346,7 +404,9 @@ class LlamaForCausalLM(nn.Module):
         x = embed.astype(cfg.dtype)[input_ids]
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
+        # an indexer's attention makes its own masks, a block at a time
+        mask = None if cfg.index_topk else (
+            jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :])
 
         layer_cls = _ScannedLayer
         if cfg.remat:
@@ -381,8 +441,14 @@ class LlamaForCausalLM(nn.Module):
         attn = cfg.hidden_size * cfg.head_dim * (
             cfg.num_heads * 2 + cfg.num_kv_heads * 2
         )
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            attn += 2 * cfg.head_dim
+        elif cfg.qk_norm:
             attn += cfg.head_dim * (cfg.num_heads + cfg.num_kv_heads)
+        if cfg.index_topk:      # q, k and weight projections, the LayerNorm
+            attn += cfg.hidden_size * (
+                cfg.index_heads * (cfg.index_head_dim + 1)
+                + cfg.index_head_dim) + 2 * cfg.index_head_dim
         per_layer = attn + cfg.feed_forward_params() + 2 * cfg.hidden_size
         return (
             cfg.vocab_size * cfg.hidden_size * 2

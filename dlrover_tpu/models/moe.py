@@ -6,8 +6,9 @@ block in place of the dense SwiGLU; everything else (attention, norms,
 ``remat``, the scanned layer stack, the head) is the dense model's code.
 
 Routing (OLMoE, arXiv:2409.02060): ``softmax(x W_r)`` in float32 over all
-experts, the ``top_k`` largest kept with their softmax weights as they are
-(no renormalisation).  No token is ever dropped and every shape is static:
+experts, the ``top_k`` largest kept with their softmax weights as they are,
+or divided by their sum where the model says so (``norm_topk_prob``).  No
+token is ever dropped and every shape is static:
 the ``tokens x top_k`` assignments are sorted by expert, this chip's
 first, one grouped matmul (``jax.lax.ragged_dot``, which the TPU compiler
 turns into its own grouped kernel and skips the rows no group holds) runs
@@ -42,6 +43,14 @@ token has an expert on a given rank with probability 0.91: an all-to-all
 would move about the same bytes and need a bound on what a rank receives.
 With ``ep == 1`` the same code runs with no collective.
 
+One chip's share of a layer whose other experts lie on chips that are not
+here (``experts_held``, ``first_expert``: the cut a benchmark makes of a
+model too large for its chips): the stacked expert arrays hold
+``experts_held`` experts, the router keeps its ``num_experts`` columns,
+``local_experts`` is told which experts these are and the ladder that
+``num_experts`` exist, and the layer's result is these experts' part.
+Nothing stands in for the absent chips or their traffic.
+
 Each layer sows two loss terms into the ``losses`` collection, already
 weighted and divided by the number of layers (``Trainer``'s default loss
 adds whatever a model sows there): the load-balancing loss
@@ -53,7 +62,10 @@ the groups the matmul was given; the extents the chips' passes ran at over
 the rows in use (``rows_held_over_live``: 1 is no wasted row, ``ep`` the
 worst case everywhere); and the hottest chip's rows over the chips' mean
 (``chip_rows_max_over_mean``: what picks the rung on the chip the others
-wait for).  There is no count of dropped rows: none can be.
+wait for).  There is no count of dropped rows: none can be.  A share's
+loss and ``load_max_over_mean`` are the routing's, over every expert;
+its ``rows_held_over_live`` is the pass's extent over the rows ITS experts
+took, and ``share_rows_over_expected`` those rows over a fair share.
 """
 
 import dataclasses
@@ -76,12 +88,30 @@ class MoELlamaConfig(LlamaConfig):
     top_k: int = 2
     load_balance_coef: float = 0.01
     router_z_coef: float = 0.001
+    # the kept router weights divided by their sum (``norm_topk_prob``)
+    norm_topk_prob: bool = False
+    # one chip's share of an expert layer that further chips hold the rest
+    # of: the experts ``[first_expert, first_expert + experts_held)`` of
+    # ``num_experts`` are here, the router keeps its ``num_experts`` columns
+    # and the layer's result is these experts' part.  0: every expert
+    experts_held: int = 0
+    first_expert: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.experts_held and not (
+                0 <= self.first_expert
+                <= self.num_experts - self.experts_held):
+            raise ValueError(
+                f"experts [{self.first_expert}, {self.first_expert} + "
+                f"{self.experts_held}) are not among {self.num_experts}")
 
     def feed_forward(self):
         return MoEMLP
 
     def feed_forward_params(self) -> int:
-        return self.num_experts * super().feed_forward_params() + (
+        held = self.experts_held or self.num_experts
+        return held * super().feed_forward_params() + (
             self.hidden_size * self.num_experts
         )
 
@@ -299,6 +329,8 @@ class MoEMLP(nn.Module):
             )(x)
             probs = jax.nn.softmax(logits, axis=-1)
             top_w, top_i = jax.lax.top_k(probs, k)
+            if cfg.norm_topk_prob:
+                top_w = top_w / top_w.sum(axis=-1, keepdims=True)
 
             def expert_weight(name, shape, axes):
                 return self.param(
@@ -310,15 +342,25 @@ class MoEMLP(nn.Module):
                 ).astype(cfg.dtype)
 
             F = cfg.intermediate_size
-            gate_w = expert_weight("gate_proj", (E, D, F),
+            here = cfg.experts_held or E
+            gate_w = expert_weight("gate_proj", (here, D, F),
                                    ("expert", "embed", "mlp"))
-            up_w = expert_weight("up_proj", (E, D, F),
+            up_w = expert_weight("up_proj", (here, D, F),
                                  ("expert", "embed", "mlp"))
-            down_w = expert_weight("down_proj", (E, F, D),
+            down_w = expert_weight("down_proj", (here, F, D),
                                    ("expert", "mlp", "embed"))
             mixed, rows, held, chip_rows = self._experts(
                 x.astype(cfg.dtype), top_i, top_w, gate_w, up_w, down_w
             )
+            live = B * S * k
+            if cfg.experts_held:
+                # the groups count this chip's experts; the loss and the
+                # balance are the routing's, over every expert
+                live = jnp.maximum(rows.sum(), 1)
+                self.sow("stats", "share_rows_over_expected",
+                         live * (E / (B * S * k * here)))
+                rows = (top_i[..., None] == jnp.arange(E)).sum(
+                    axis=(0, 1, 2), dtype=jnp.int32)
             assigned = rows.astype(jnp.float32) / (B * S * k)
             self.sow(
                 "losses", "load_balance",
@@ -331,7 +373,7 @@ class MoEMLP(nn.Module):
                 * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
             )
             self.sow("stats", "load_max_over_mean", rows.max() / rows.mean())
-            self.sow("stats", "rows_held_over_live", held / (B * S * k))
+            self.sow("stats", "rows_held_over_live", held / live)
             self.sow("stats", "chip_rows_max_over_mean",
                      chip_rows.max() / chip_rows.mean())
         return nn.with_logical_constraint(mixed, ("batch", "seq", "embed"))
@@ -356,6 +398,10 @@ class MoEMLP(nn.Module):
             raise ValueError(
                 f"{cfg.num_experts} experts do not split over ep={ep}"
             )
+        if cfg.experts_held and ep > 1:
+            raise ValueError(
+                "experts_held is one chip's share: it does not split over "
+                f"ep={ep}")
 
         x_spec = w_spec = None
         chips = ()
@@ -379,7 +425,8 @@ class MoEMLP(nn.Module):
                       top_w.reshape(-1, k))
             if ep == 1:
                 out, rows, held = local_experts(
-                    *tokens, gate_w, up_w, down_w, 0)
+                    *tokens, gate_w, up_w, down_w, cfg.first_expert,
+                    cfg.num_experts)
                 out = out.astype(cfg.dtype)
                 live = rows.sum()
             else:
@@ -426,6 +473,7 @@ class MoEMLP(nn.Module):
             layers=cfg.num_layers,
             # of one pass: a source rank's assignments on one chip
             extents=ladder(rows // math.prod(mesh.shape[a] for a in chips),
-                           cfg.num_experts // ep, cfg.num_experts),
+                           gate_w.shape[0] // ep, cfg.num_experts),
+            held=gate_w.shape[0], first_expert=cfg.first_expert,
         )
         return per_shard(x, top_i, top_w, gate_w, up_w, down_w)

@@ -10,6 +10,9 @@ per shard through ``shard_map`` (a Mosaic kernel cannot be partitioned
 automatically).  ``causal_attention`` is the one place that chooses between
 the two for a model that has no opinion (the GPT family): from the backend
 and the shape, once, while the step is traced.
+``indexed_sparse_attention`` (at the end) is the attention of a model
+whose configuration carries an indexer: each query attends to the keys a
+learned scorer ranks highest, by blocks of queries in ``jax.numpy``.
 """
 
 from typing import Optional
@@ -160,3 +163,146 @@ def causal_attention(
     trace.note_trace_time("attention.path", impl="reference", seq=seq_len,
                           head_dim=head_dim, heads=heads, blocks=None)
     return reference_attention(q, k, v, mask)
+
+
+# --------------------------------------------------------------------------
+# Attention over the keys a learned indexer selects (DeepSeek-V3.2-Exp's
+# "lightning indexer"; Keye-VL-2.0's ``sa_config``)
+# --------------------------------------------------------------------------
+
+#: index scores of the ``topk``-th and the next key closer than this: the
+#: selection of that query hangs on rounding (``index_low_margin_share``)
+INDEX_LOW_MARGIN = 1e-3
+
+
+def _sortable(x):
+    """float32 -> uint32 with the same order (negative zero below zero);
+    every real value maps above 0, which stands for "no key"."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    top = jnp.uint32(0x80000000)
+    return jnp.where(bits >= top, ~bits, bits | top)
+
+
+def _kth_largest(keys, k, bits=32):
+    """Per row of uint32 ``keys`` [..., n] the ``k``-th largest (``k`` [...]
+    int32, at least 1), 0 where a row has fewer than ``k`` nonzero keys (no
+    key of such a row is at 0, so none is kept by it):
+    the threshold is built from its highest bit down, one count of the
+    row for each bit, so nothing is sorted and the result is exact."""
+    def add_bit(i, found):
+        bit = jnp.left_shift(jnp.uint32(1), (bits - 1 - i).astype(jnp.uint32))
+        tried = found | bit
+        enough = (keys >= tried[..., None]).sum(-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, tried, found)
+
+    return jax.lax.fori_loop(
+        0, bits, add_bit, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def select_top_keys(scores, causal, topk):
+    """``(keep [..., q, n] bool, low [..., q] bool)``: for each query the
+    ``topk`` largest float32 ``scores`` among the keys ``causal`` allows,
+    ties to the earlier key (what ``jax.lax.top_k`` keeps), all of them
+    where there are no more than ``topk``; ``low`` where the ``topk``-th and
+    the next score lie closer than ``INDEX_LOW_MARGIN``.  Exact: a
+    threshold found by counting, then the earliest of the keys that equal
+    it, found the same way."""
+    n = scores.shape[-1]
+    keys = jnp.where(causal, _sortable(scores), jnp.uint32(0))
+    thr = _kth_largest(keys, jnp.int32(topk))[..., None]
+    above = keys > thr
+    equal = (keys == thr) & causal
+    # fewer than ``topk`` keys lie above the threshold, by its definition
+    need = topk - above.sum(-1, dtype=jnp.int32)
+    # of the keys at the threshold the ``need`` earliest: the same search
+    # on their positions counted from the end
+    from_end = jnp.where(equal, jnp.uint32(n) - jnp.arange(n, dtype=jnp.uint32),
+                         jnp.uint32(0))
+    latest = _kth_largest(from_end, need, n.bit_length())
+    keep = above | (equal & (from_end >= latest[..., None]))
+    # the next score below the kept ones: the threshold itself where more
+    # keys equal it than are needed
+    at_thr = jnp.min(jnp.where(above | equal, scores, jnp.inf), axis=-1)
+    below = jnp.max(jnp.where(causal & ~above & ~equal, scores, -jnp.inf), axis=-1)
+    tied = equal.sum(-1, dtype=jnp.int32) > need
+    bites = causal.sum(-1, dtype=jnp.int32) > topk
+    low = bites & (tied | (at_thr - below < INDEX_LOW_MARGIN))
+    return keep, low
+
+
+def _index_scores(index_q, index_k, index_w):
+    """[B, q, keys] float32: ``sum_j w[t, j] relu(q_I[t, j] . k_I[s])``."""
+    dots = jnp.einsum("bqjc,bkc->bqjk", index_q, index_k,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("bqjk,bqj->bqk", jax.nn.relu(dots), index_w)
+
+
+@jax.checkpoint
+def _attend_selected(q, k, v, index_q, index_k, index_w, keep):
+    """One block of queries over the keys ``keep`` allows: ``(out [B, q, G,
+    R, D], sum over the block's queries of KL(p || softmax(index scores)))``
+    with ``p`` the attention's own probabilities averaged over the heads,
+    under ``stop_gradient``.  Rematerialised, the index scores with it: the
+    backward pass holds one block's ``[heads, q, keys]`` scores at a time."""
+    lowest = jnp.finfo(jnp.float32).min
+    index_scores = _index_scores(index_q, index_k, index_w)
+    logits = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                        preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    logits = jnp.where(keep[:, None, None], logits, lowest)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(q.dtype), v)
+    target = jax.lax.stop_gradient(probs.mean(axis=(1, 2)))
+    log_index = jax.nn.log_softmax(jnp.where(keep, index_scores, lowest), axis=-1)
+    kl = jnp.where(keep, jax.scipy.special.xlogy(target, target)
+                   - target * log_index, 0.0)
+    return out, kl.sum()
+
+
+def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
+                             block=512):
+    """Causal attention in which each query attends to the ``topk`` keys
+    its indexer scores highest: ``(out [B, S, H, D], index loss, share of
+    queries with a low selection margin)``.
+
+    ``q`` [B, S, H, D], ``k``/``v`` [B, S, G, D] (GQA).  The indexer:
+    ``index_q`` [B, S, J, C], one shared key head ``index_k`` [B, S, C] and
+    head weights ``index_w`` [B, S, J] give, in float32, ``I[t, s] = sum_j
+    w[t, j] relu(q_I[t, j] . k_I[s]) (J C)^-0.5`` for ``s <= t``.  The
+    selection (``select_top_keys``: exact, on the float32 scores) carries
+    no gradient; the index loss ``mean_t KL(p[t, S_t] || softmax_{S_t}
+    I[t, .])`` teaches the indexer from the attention's own probabilities
+    and reaches nothing else.
+
+    Blocks of ``block`` queries, each over the keys up to its last query,
+    so nothing of size ``heads x S x S`` is ever whole: a block's scores
+    are ``[H, block, keys]``, its selection a mask over them."""
+    B, S, H, D = q.shape
+    G = k.shape[2]
+    J, C = index_q.shape[2:]
+    block = min(block, S)
+    if S % block:
+        raise ValueError(f"seq {S} is not a multiple of the block {block}")
+    trace.note_trace_time(
+        "attention.path", impl="indexed_sparse", seq=S, head_dim=D, heads=H,
+        topk=topk, index_heads=J, index_dim=C, block=block,
+        select="threshold_by_counting")
+    q = q.reshape(B, S, G, H // G, D)
+    index_w = index_w.astype(jnp.float32) * (J * C) ** -0.5
+    outs, loss, low = [], jnp.float32(0), jnp.float32(0)
+    for first in range(0, S, block):
+        last = first + block
+        index = (index_q[:, first:last], index_k[:, :last],
+                 index_w[:, first:last])
+        causal = (jnp.arange(first, last)[:, None] >= jnp.arange(last))[None]
+        if last <= topk:
+            keep = jnp.broadcast_to(causal, (B, block, last))
+        else:
+            keep, low_here = select_top_keys(
+                jax.lax.stop_gradient(_index_scores(*index)), causal, topk)
+            low = low + low_here.sum(dtype=jnp.float32)
+        out, kl = _attend_selected(
+            q[:, first:last], k[:, :last], v[:, :last], *index, keep)
+        outs.append(out)
+        loss = loss + kl
+    out = jnp.concatenate(outs, axis=1).reshape(B, S, H, D)
+    return out, loss / (B * S), low / (B * S)
