@@ -12,9 +12,13 @@ the two for a model that has no opinion (the GPT family): from the backend
 and the shape, once, while the step is traced.
 ``indexed_sparse_attention`` (at the end) is the attention of a model
 whose configuration carries an indexer: each query attends to the keys a
-learned scorer ranks highest, by blocks of queries in ``jax.numpy``.
+learned scorer ranks highest, by blocks of queries: index scores,
+selection and loss in ``jax.numpy``, the attention over the selection in
+Pallas kernels on a TPU (``ops/pallas/selected_attention.py``) and in
+``jax.numpy`` elsewhere.
 """
 
+import functools
 from typing import Optional
 
 import jax
@@ -237,25 +241,99 @@ def _index_scores(index_q, index_k, index_w):
     return jnp.einsum("bqjk,bqj->bqk", jax.nn.relu(dots), index_w)
 
 
-@jax.checkpoint
-def _attend_selected(q, k, v, index_q, index_k, index_w, keep):
-    """One block of queries over the keys ``keep`` allows: ``(out [B, q, G,
-    R, D], sum over the block's queries of KL(p || softmax(index scores)))``
-    with ``p`` the attention's own probabilities averaged over the heads,
-    under ``stop_gradient``.  Rematerialised, the index scores with it: the
-    backward pass holds one block's ``[heads, q, keys]`` scores at a time."""
-    lowest = jnp.finfo(jnp.float32).min
-    index_scores = _index_scores(index_q, index_k, index_w)
+def _dense_selected(q, k, v, keep):
+    """One block of queries ``q`` [B, q, H, D] over the keys ``keep`` [B,
+    q, keys] allows, in ``jax.numpy`` over dense ``[heads, q, keys]``
+    scores: ``(out [B, q, H, D], the probabilities' mean over the heads
+    [B, q, keys] float32)``.  The reference of the kernels
+    (``ops/pallas/selected_attention.py``) and the path wherever they do
+    not run."""
+    B, Q, H, D = q.shape
+    G = k.shape[2]
+    q = q.reshape(B, Q, G, H // G, D)
     logits = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                        preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
-    logits = jnp.where(keep[:, None, None], logits, lowest)
+                        preferred_element_type=jnp.float32) * D ** -0.5
+    logits = jnp.where(keep[:, None, None], logits,
+                       jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(q.dtype), v)
-    target = jax.lax.stop_gradient(probs.mean(axis=(1, 2)))
+    return out.reshape(B, Q, H, D), probs.mean(axis=(1, 2))
+
+
+def _index_kl(index_q, index_k, index_w, keep, target):
+    """Sum over a block's queries of ``KL(target || softmax over the kept
+    keys of the index scores)``."""
+    lowest = jnp.finfo(jnp.float32).min
+    index_scores = _index_scores(index_q, index_k, index_w)
     log_index = jax.nn.log_softmax(jnp.where(keep, index_scores, lowest), axis=-1)
     kl = jnp.where(keep, jax.scipy.special.xlogy(target, target)
                    - target * log_index, 0.0)
-    return out, kl.sum()
+    return kl.sum()
+
+
+@jax.checkpoint
+def _attend_selected(q, k, v, index_q, index_k, index_w, keep):
+    """One block of queries over the keys ``keep`` allows: ``(out [B, q, H,
+    D], sum over the block's queries of KL(p || softmax(index scores)))``
+    with ``p`` the attention's own probabilities averaged over the heads,
+    under ``stop_gradient``.  Rematerialised, the index scores with it: the
+    backward pass holds one block's ``[heads, q, keys]`` scores at a time."""
+    out, target = _dense_selected(q, k, v, keep)
+    return out, _index_kl(index_q, index_k, index_w, keep,
+                          jax.lax.stop_gradient(target))
+
+
+def _attend_selected_kernels(q, k, v, index_q, index_k, index_w, keep,
+                             tiling, interpret=False):
+    """``_attend_selected`` with the attention and the heads' mean
+    probabilities in Pallas kernels: no ``[heads, q, keys]`` tensor leaves
+    the chip's fast memory, forward or backward.  The kernels' custom
+    gradient keeps ``out`` and the LSE; the loss, rematerialised with its
+    index scores, keeps ``target``."""
+    from dlrover_tpu.ops.pallas.selected_attention import selected_attention
+
+    out, target = selected_attention(q, k, v, keep, tiling, interpret)
+    return out, jax.checkpoint(_index_kl)(
+        index_q, index_k, index_w, keep, jax.lax.stop_gradient(target))
+
+
+def selected_attend_path(backend: str, block: int, head_dim: int, heads: int,
+                         kv_heads: int) -> str:
+    """``"pallas"`` or ``"jnp"``: which body attends to a block's selected
+    keys, from what the code can observe (as ``attention_path``)."""
+    from dlrover_tpu.ops.pallas.selected_attention import kernels_take
+
+    if backend == "tpu" and kernels_take(block, head_dim, heads, kv_heads):
+        return "pallas"
+    return "jnp"
+
+
+@functools.partial(jax.jit, static_argnames=("first", "topk", "tiling"))
+def _attend_block(q, k, v, index_q, index_k, index_w, *, first, topk, tiling):
+    """One block of queries, the ``first``-th of the sequence on, over the
+    keys up to its last query: ``(out, the block's sum of KL, its count of
+    queries with a low selection margin)``.  ``tiling`` ``None``: the
+    attention in ``jax.numpy``.  Under ``jax.jit`` so that a program which
+    traces the model more than once (its initialisation, a forward pass,
+    the step) traces a block's selection, kernels and loss once: on the
+    host of a v5e a trace of the 48 kernel calls of one layer takes a second
+    and there are five in a benchmark run's set-up."""
+    B, block = q.shape[:2]
+    last = k.shape[1]
+    index = (index_q, index_k, index_w)
+    causal = (jnp.arange(first, last)[:, None] >= jnp.arange(last))[None]
+    low = jnp.float32(0)
+    if last <= topk:
+        keep = jnp.broadcast_to(causal, (B, block, last))
+    else:
+        keep, low_margin = select_top_keys(
+            jax.lax.stop_gradient(_index_scores(*index)), causal, topk)
+        low = low_margin.sum(dtype=jnp.float32)
+    if tiling is None:
+        out, kl = _attend_selected(q, k, v, *index, keep)
+    else:
+        out, kl = _attend_selected_kernels(q, k, v, *index, keep, tiling)
+    return out, kl, low
 
 
 def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
@@ -274,35 +352,35 @@ def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
     and reaches nothing else.
 
     Blocks of ``block`` queries, each over the keys up to its last query,
-    so nothing of size ``heads x S x S`` is ever whole: a block's scores
-    are ``[H, block, keys]``, its selection a mask over them."""
+    so nothing of size ``heads x S x S`` is ever whole: a block's selection
+    is a mask ``[block, keys]``, its scores ``[H, block, keys]`` in
+    ``jax.numpy`` and tiles in fast memory in the kernels, which run on a
+    TPU at the shapes they take (``selected_attend_path``)."""
     B, S, H, D = q.shape
-    G = k.shape[2]
     J, C = index_q.shape[2:]
     block = min(block, S)
     if S % block:
         raise ValueError(f"seq {S} is not a multiple of the block {block}")
+    tiling = None
+    path = dict(attend=selected_attend_path(
+        jax.default_backend(), block, D, H, k.shape[2]))
+    if path["attend"] == "pallas":
+        from dlrover_tpu.ops.pallas.tuning import selected_tiling
+
+        tiling = selected_tiling(block, D)
+        path.update(block_kv=tiling[0], mean_block_kv=tiling[1])
     trace.note_trace_time(
         "attention.path", impl="indexed_sparse", seq=S, head_dim=D, heads=H,
         topk=topk, index_heads=J, index_dim=C, block=block,
-        select="threshold_by_counting")
-    q = q.reshape(B, S, G, H // G, D)
+        select="threshold_by_counting", **path)
     index_w = index_w.astype(jnp.float32) * (J * C) ** -0.5
     outs, loss, low = [], jnp.float32(0), jnp.float32(0)
     for first in range(0, S, block):
         last = first + block
-        index = (index_q[:, first:last], index_k[:, :last],
-                 index_w[:, first:last])
-        causal = (jnp.arange(first, last)[:, None] >= jnp.arange(last))[None]
-        if last <= topk:
-            keep = jnp.broadcast_to(causal, (B, block, last))
-        else:
-            keep, low_here = select_top_keys(
-                jax.lax.stop_gradient(_index_scores(*index)), causal, topk)
-            low = low + low_here.sum(dtype=jnp.float32)
-        out, kl = _attend_selected(
-            q[:, first:last], k[:, :last], v[:, :last], *index, keep)
+        out, kl, low_here = _attend_block(
+            q[:, first:last], k[:, :last], v[:, :last],
+            index_q[:, first:last], index_k[:, :last], index_w[:, first:last],
+            first=first, topk=topk, tiling=tiling)
         outs.append(out)
-        loss = loss + kl
-    out = jnp.concatenate(outs, axis=1).reshape(B, S, H, D)
-    return out, loss / (B * S), low / (B * S)
+        loss, low = loss + kl, low + low_here
+    return jnp.concatenate(outs, axis=1), loss / (B * S), low / (B * S)

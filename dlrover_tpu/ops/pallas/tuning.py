@@ -142,6 +142,25 @@ def tuned_blocks(seq_len: int, head_dim: int) -> Tuple[int, int]:
     return fallback
 
 
+def selected_tiling(block_q: int, head_dim: int) -> Tuple[int, int]:
+    """``(block_kv, mean_block_kv)`` of the selected attention's kernels
+    (``selected_attention.py``) for blocks of ``block_q`` queries: the keys
+    a tile at most in the forward and backward kernels and in the kernel
+    of the heads' mean (a call takes the largest tile under it that
+    divides its keys).  The table's ``selected_q<block_q>_d<head_dim>_kv``
+    entry (swept in a whole step on the chip by
+    ``scripts/fa_blocks_in_step.py --selected``), else the untuned
+    default."""
+    try:
+        entry = _load_table().get(f"selected_q{block_q}_d{head_dim}_kv") or {}
+        tiling = int(entry["block_kv"]), int(entry["mean_block_kv"])
+        if min(tiling) > 0:
+            return tiling
+    except (TypeError, KeyError, ValueError):
+        pass
+    return DEFAULT_BLOCKS[1], DEFAULT_BLOCKS[1]
+
+
 def _current_device_kind() -> str:
     try:
         import jax

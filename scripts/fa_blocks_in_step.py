@@ -10,6 +10,14 @@ the trace.  That is the time the step pays, and it is what the shipped
 
     python3 scripts/fa_blocks_in_step.py --config gpt2m
 
+``--selected`` sweeps instead the selected attention's kernels
+(``ops/pallas/selected_attention.py``): keys a tile in the forward and
+backward and in the heads' mean (``--blocks kv,mean_kv;kv,mean_kv``; the table's
+``selected_q<block>_d<head_dim>_kv`` entry) in a configuration with an
+indexer, forward, heads' mean and backward told apart by their results::
+
+    python3 scripts/fa_blocks_in_step.py --config keyevl2_30b_1of8 --selected
+
 Candidates reach the kernel through the table ``DLROVER_TPU_FA_TUNING``
 names, as a user's own table would.  One JSON line a candidate, the
 winner's table entry last.
@@ -19,6 +27,7 @@ import argparse
 import datetime
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -45,12 +54,44 @@ def kernel_seconds(trace_dir):
     return found
 
 
+SELECTED_CALL = re.compile(r"^%[\w.]+ = (.*) custom-call\(.*"
+                           r'custom_call_target="tpu_custom_call"')
+
+
+def selected_kernel_seconds(trace_dir, shape):
+    """kind (``fwd``, ``mean``, ``bwd``) -> [events, seconds] of the
+    custom calls that carry a block's mask, known as the benchmark's
+    ``sparse_attn_ms_per_step`` knows them: the forward returns two
+    arrays, the heads' mean one, the backward three."""
+    from benchmarks import common
+    from benchmarks import trace as trace_mod
+
+    block_by_keys = common.load_module(
+        "layer_metrics", "sparse_attn_ms_per_step").block_by_keys
+    loaded = trace_mod.load(trace_mod.find_xplane(trace_dir))
+    found = {"fwd": [0, 0.0], "mean": [0, 0.0], "bwd": [0, 0.0]}
+    if loaded.device_ops:
+        for name, start, end in loaded.device_ops[min(loaded.device_ops)]:
+            call = SELECTED_CALL.match(name)
+            if not call or not block_by_keys(name, shape):
+                continue
+            result = call.group(1)
+            kind = ("mean" if not result.startswith("(") else
+                    {2: "fwd", 3: "bwd"}[result.count("[")])
+            found[kind][0] += 1
+            found[kind][1] += end - start
+    return found
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--config", default="gpt2m")
     parser.add_argument("--steps", type=int, default=3)
     parser.add_argument("--blocks", default="",
                         help="candidates as q,kv;q,kv (default: the sweep's)")
+    parser.add_argument("--selected", action="store_true",
+                        help="sweep the selected attention's kernels; "
+                             "--blocks then takes kv,mean_kv;kv,mean_kv")
     parser.add_argument("--rehearse", action="store_true",
                         help="tiny sizes, any backend: control flow only")
     args = parser.parse_args(argv)
@@ -71,6 +112,12 @@ def main(argv=None) -> int:
     batch, seq = pool[0]["input_ids"].shape
     heads = m.get("n_head") or m["num_attention_heads"]
     head_dim = m.get("head_dim") or m["n_embd"] // heads
+    key = f"s{seq}_d{head_dim}"
+    if args.selected:
+        sparse = family.sparse_attn_shape(config, batch, seq, args.rehearse)
+        block_q = sparse["block"]
+        key = f"selected_q{block_q}_d{head_dim}_kv"
+        args.blocks = args.blocks or "512,512;1024,512;2048,512;2048,2048"
     if args.blocks:
         candidates = [tuple(int(b) for b in pair.split(","))
                       for pair in args.blocks.split(";")]
@@ -81,21 +128,27 @@ def main(argv=None) -> int:
     with tempfile.TemporaryDirectory() as scratch:
         table = os.path.join(scratch, "candidate.json")
         os.environ["DLROVER_TPU_FA_TUNING"] = table
-        for block_q, block_kv in candidates:
+        for first, second in candidates:
+            if args.selected:
+                line = {"block_q": block_q, "block_kv": first,
+                        "mean_block_kv": second}
+            else:
+                line = {"block_q": first, "block_kv": second}
             with open(table, "w") as f:
-                json.dump({f"s{seq}_d{head_dim}": {
-                    "block_q": block_q, "block_kv": block_kv}}, f)
+                json.dump({key: line}, f)
             tuning._load_one.cache_clear()
             # a new Trainer traces the step anew, with this candidate
             _, _, trainer = program.make_trainer(config, args.rehearse)
             trainer.state_shardings = trainer.state_sharding_for(
                 program.make_key(0), pool[0]["input_ids"])
-            line = {"block_q": block_q, "block_kv": block_kv}
             try:
                 sharded = [trainer.shard_batch(b) for b in pool[:args.steps]]
+                t0 = time.perf_counter()
                 state, metrics = trainer.train_step(state, sharded[0])
                 float(metrics["loss"])  # compiled, and one step through
-                trace_dir = os.path.join(scratch, f"t{block_q}_{block_kv}")
+                line["compile_and_first_step_s"] = round(
+                    time.perf_counter() - t0, 1)
+                trace_dir = os.path.join(scratch, f"t{first}_{second}")
                 jax.profiler.start_trace(trace_dir)
                 try:
                     t0 = time.perf_counter()
@@ -105,7 +158,8 @@ def main(argv=None) -> int:
                     step_s = (time.perf_counter() - t0) / len(sharded)
                 finally:
                     jax.profiler.stop_trace()
-                found = kernel_seconds(trace_dir)
+                found = (selected_kernel_seconds(trace_dir, sparse)
+                         if args.selected else kernel_seconds(trace_dir))
                 per_step = 1e3 / len(sharded)
                 line.update(
                     kernel_ms_per_step=round(
@@ -126,11 +180,13 @@ def main(argv=None) -> int:
                           f"(backend {jax.default_backend()!r})"}))
         return 1
     best = ranked[0]
-    runner_up = ("; %d candidates, next best %dx%d at %s" % (
-        len(ranked), ranked[1]["block_q"], ranked[1]["block_kv"],
+    named = ("block_q", "block_kv", "mean_block_kv")
+    runner_up = ("; %d candidates, next best %s at %s" % (
+        len(ranked), "x".join(str(ranked[1][n]) for n in named
+                              if n in ranked[1]),
         ranked[1]["kernel_ms_per_step"])) if len(ranked) > 1 else ""
-    print(json.dumps({f"s{seq}_d{head_dim}": {
-        "block_q": best["block_q"], "block_kv": best["block_kv"],
+    print(json.dumps({key: {
+        **{n: best[n] for n in named if n in best},
         "kernel_ms_per_step": best["kernel_ms_per_step"],
         "kernel_calls_per_step": int(best["kernel_calls_per_step"]),
         "measured": "kernel time in the device trace of a whole step "

@@ -89,16 +89,20 @@ def _attend_calls(text):
             and 'custom_call_target="tpu_custom_call"' in line]
 
 
-def _benchmark_kind_of():
-    """``benchmarks/layer_metrics/fa2_ms_per_step.py::kind_of``, loaded by
-    path as ``benchmarks/common.py::load_module`` loads it."""
+def _load_layer_metric(name):
+    """``benchmarks/layer_metrics/<name>.py``, loaded by path as
+    ``benchmarks/common.py::load_module`` loads it."""
     path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "layer_metrics", "fa2_ms_per_step.py")
-    spec = importlib.util.spec_from_file_location("fa2_ms_per_step", path)
+        "benchmarks", "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.kind_of
+    return module
+
+
+def _benchmark_kind_of():
+    return _load_layer_metric("fa2_ms_per_step").kind_of
 
 
 @pytest.mark.parametrize(
@@ -121,6 +125,43 @@ class TestFlashAttentionKernel:
         compiled = jax.jit(_fa2_fwd_bwd).lower(x, x, x).compile()
         # forward, dQ, and dK/dV kernels
         assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("keys", [512, 2560, 8192])
+def test_selected_attention_kernels_compile_and_the_reader_sees_them(
+        one_chip, keys):
+    """The Keye cell's shapes: a block of 512 queries, 32 heads on 4 kv
+    heads of 128, an early, an odd and the last block's keys.  Forward,
+    heads' mean and backward are three custom calls, and each carries the
+    block's mask ``[1, 512, keys]``, which is how
+    ``benchmarks/layer_metrics/sparse_attn_ms_per_step.py`` knows them."""
+    from dlrover_tpu.ops.pallas.selected_attention import selected_attention
+    from dlrover_tpu.ops.pallas.tuning import selected_tiling
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tiling = selected_tiling(512, 128)
+
+    def both(q, k, v, keep):
+        def loss(q, k, v):
+            out, target = selected_attention(q, k, v, keep, tiling)
+            return out.astype(jnp.float32).sum(), target
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+
+    kv = sds((1, keys, 4, 128), jnp.bfloat16)
+    text = jax.jit(both).lower(
+        sds((1, 512, 32, 128), jnp.bfloat16), kv, kv,
+        sds((1, 512, keys), jnp.bool_)).compile().as_text()
+    calls = [line.strip() for line in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    is_sparse_attn_op = _load_layer_metric(
+        "sparse_attn_ms_per_step").is_sparse_attn_op
+    shape = {"batch": 1, "block": 512, "seq": 8192}
+    assert all(is_sparse_attn_op(call, shape) for call in calls)
 
 
 @pytest.mark.parametrize(
