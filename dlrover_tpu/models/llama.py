@@ -57,6 +57,22 @@ class LlamaConfig:
     index_heads: int = 0
     index_head_dim: int = 0
     index_block: int = 512
+    # EVA attention (arXiv:2302.04542 as EvaByte's model code has it): a
+    # query sees the keys of its own window of ``eva_window`` positions
+    # exactly and every earlier window through one learned summary of each
+    # ``eva_chunk`` positions, under one softmax.  0: none
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # the norms' learned scale is ``1 + g`` with ``g`` starting at 0
+    # (EvaByte's ``norm_add_unit_offset``)
+    norm_unit_offset: bool = False
+    # the dtype the residual stream is kept and added in; ``None``: ``dtype``
+    # (EvaByte's ``fp32_skip_add``: float32 beside bfloat16 matmuls)
+    residual_dtype: Any = None
+    # prediction heads in the one output projection: head ``i`` at position
+    # ``t`` predicts token ``t + 1 + i``; the model returns the first head's
+    # logits and sows the others' loss (EvaByte's ``num_pred_heads``)
+    pred_heads: int = 1
 
     def __post_init__(self):
         valid = ("reference", "flash", "ring")
@@ -71,6 +87,13 @@ class LlamaConfig:
                              "(False, True, 'head')")
         if self.index_topk and not (self.index_heads and self.index_head_dim):
             raise ValueError("index_topk needs index_heads and index_head_dim")
+        if self.eva_window and (
+                self.index_topk or not self.eva_chunk
+                or self.eva_window % self.eva_chunk
+                or self.num_kv_heads != self.num_heads):
+            raise ValueError(
+                "eva_window needs an eva_chunk that divides it, a key head "
+                "a query head, and no indexer")
 
     def feed_forward(self):
         """The module class of the block after attention, built as
@@ -123,20 +146,26 @@ class RMSNorm(nn.Module):
     dtype: Dtype
     param_dtype: Dtype
     axis_name: str = "embed"
+    #: the parameter starts at 0 and scales by ``1 + scale``
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
         scale = self.param(
             "scale",
             nn.with_logical_partitioning(
-                nn.initializers.ones, (self.axis_name,)),
+                nn.initializers.zeros if self.unit_offset
+                else nn.initializers.ones, (self.axis_name,)),
             (x.shape[-1],),
             self.param_dtype,
         )
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         normed = x32 * jax.lax.rsqrt(var + self.eps)
-        return (normed * scale.astype(jnp.float32)).astype(self.dtype)
+        scale = scale.astype(jnp.float32)
+        if self.unit_offset:
+            scale = 1.0 + scale
+        return (normed * scale).astype(self.dtype)
 
 
 class Attention(nn.Module):
@@ -195,6 +224,8 @@ class Attention(nn.Module):
 
         if cfg.index_topk:
             out = self._attend_indexed(x, q, k, v, positions, dense)
+        elif cfg.eva_window:
+            out = self._attend_eva(q, k, v)
         else:
             out = self._attend(q, k, v, mask)
         out = nn.with_logical_constraint(
@@ -242,6 +273,32 @@ class Attention(nn.Module):
         self.sow("losses", "index", loss / cfg.num_layers)
         self.sow("stats", "index_loss", loss)
         self.sow("stats", "index_low_margin_share", low)
+        return out
+
+    def _attend_eva(self, q, k, v):
+        """Attention over the window's own keys and the learned summaries
+        of every earlier window's chunks; the two vectors a head that pool
+        a chunk's keys and values are this module's parameters."""
+        from dlrover_tpu.ops.attention import eva_attention
+
+        cfg = self.config
+
+        def pooling_vector(name):
+            # normal, clipped to [-1, 1], times head_dim ** -0.5
+            return self.param(
+                name,
+                nn.with_logical_partitioning(
+                    lambda key, shape, dtype: cfg.head_dim ** -0.5 * jnp.clip(
+                        jax.random.normal(key, shape, dtype), -1.0, 1.0),
+                    ("heads", "head_dim")),
+                (cfg.num_heads, cfg.head_dim), cfg.param_dtype,
+            )
+
+        out, summary_mass, pool_weight = eva_attention(
+            q, k, v, pooling_vector("adaptive_mu_k"),
+            pooling_vector("adaptive_phi"), cfg.eva_window, cfg.eva_chunk)
+        self.sow("stats", "eva_summary_mass_share", summary_mass)
+        self.sow("stats", "eva_pool_weight_max", pool_weight)
         return out
 
     def _attend(self, q, k, v, mask):
@@ -320,13 +377,15 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions, mask):
         cfg = self.config
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                    name="input_norm")(x)
-        x = x + Attention(cfg, name="attn")(h, positions, mask)
+        norm = partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                       unit_offset=cfg.norm_unit_offset)
+        # ``x`` is the residual stream, in ``residual_dtype`` where the
+        # configuration names one: a branch's result is added in it
+        h = norm(name="input_norm")(x)
+        x = x + Attention(cfg, name="attn")(h, positions, mask).astype(x.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                    name="post_attn_norm")(x)
-        x = x + cfg.feed_forward()(cfg, name="mlp")(h)
+        h = norm(name="post_attn_norm")(x)
+        x = x + cfg.feed_forward()(cfg, name="mlp")(h).astype(x.dtype)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
@@ -354,7 +413,10 @@ class LMHead(nn.Module):
     softmax-xent needs.  At a 32k vocab this matmul is ~10% of a 1B
     model's FLOPs, so the rate difference moves whole-model MFU by
     percentage points.  (Duck-typed over any config carrying
-    hidden_size/vocab_size/dtype/param_dtype — the MoE model reuses it.)
+    hidden_size/vocab_size/pred_heads/dtype/param_dtype — the MoE model
+    reuses it.)  With ``pred_heads`` the one projection holds that many blocks of
+    ``vocab_size`` columns, head ``i`` in columns ``[i * vocab_size, (i +
+    1) * vocab_size)``.
     """
 
     config: Any
@@ -367,7 +429,7 @@ class LMHead(nn.Module):
             nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("embed", "vocab")
             ),
-            (cfg.hidden_size, cfg.vocab_size),
+            (cfg.hidden_size, cfg.pred_heads * cfg.vocab_size),
             cfg.param_dtype,
         )
         return jax.lax.dot_general(
@@ -402,10 +464,13 @@ class LlamaForCausalLM(nn.Module):
             cfg.param_dtype,
         )
         x = embed.astype(cfg.dtype)[input_ids]
+        if cfg.residual_dtype is not None:
+            x = x.astype(cfg.residual_dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        # an indexer's attention makes its own masks, a block at a time
-        mask = None if cfg.index_topk else (
+        # an indexer's attention makes its own masks, a block at a time,
+        # and so does the attention over windows and summaries
+        mask = None if cfg.index_topk or cfg.eva_window else (
             jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :])
 
         layer_cls = _ScannedLayer
@@ -432,9 +497,33 @@ class LlamaForCausalLM(nn.Module):
                 x, _ = layer_cls(cfg, name=f"layers_{i}")(x, positions, mask)
 
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                    name="final_norm")(x)
+                    unit_offset=cfg.norm_unit_offset, name="final_norm")(x)
         logits = LMHead(cfg, name="lm_head")(x)
+        if cfg.pred_heads > 1:
+            logits = self._first_head(logits, input_ids)
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
+
+    def _first_head(self, logits, input_ids):
+        """The first prediction head's logits ``[B, S, vocab]``; the loss
+        of the others is sown into ``losses``: head ``i`` (1 on) at
+        position ``t`` predicts ``input_ids[t + 1 + i]``, its cross entropy
+        averaged over the positions that have such a token, the heads'
+        terms summed unweighted (arXiv:2404.19737, equation 2).  The model
+        sees no labels, so each of these heads goes without the one target
+        that lies beyond ``input_ids``; the first head's loss is the
+        caller's, on its labels."""
+        cfg = self.config
+        B, S = input_ids.shape
+        logits = logits.reshape(B, S, cfg.pred_heads, cfg.vocab_size)
+        later = jnp.float32(0)
+        for i in range(1, min(cfg.pred_heads, S - 1)):
+            logp = jax.nn.log_softmax(logits[:, : S - 1 - i, i], axis=-1)
+            taken = jnp.take_along_axis(
+                logp, input_ids[:, 1 + i:, None], axis=-1)
+            later = later - jnp.mean(taken)
+        self.sow("losses", "multi_byte", later)
+        self.sow("stats", "multi_byte_loss", later)
+        return logits[:, :, 0]
 
     def num_params(self) -> int:
         cfg = self.config
@@ -449,9 +538,11 @@ class LlamaForCausalLM(nn.Module):
             attn += cfg.hidden_size * (
                 cfg.index_heads * (cfg.index_head_dim + 1)
                 + cfg.index_head_dim) + 2 * cfg.index_head_dim
+        if cfg.eva_window:      # the two pooling vectors a head
+            attn += 2 * cfg.num_heads * cfg.head_dim
         per_layer = attn + cfg.feed_forward_params() + 2 * cfg.hidden_size
         return (
-            cfg.vocab_size * cfg.hidden_size * 2
+            cfg.vocab_size * cfg.hidden_size * (1 + cfg.pred_heads)
             + cfg.num_layers * per_layer
             + cfg.hidden_size
         )

@@ -15,7 +15,10 @@ whose configuration carries an indexer: each query attends to the keys a
 learned scorer ranks highest, by blocks of queries: index scores,
 selection and loss in ``jax.numpy``, the attention over the selection in
 Pallas kernels on a TPU (``ops/pallas/selected_attention.py``) and in
-``jax.numpy`` elsewhere.
+``jax.numpy`` elsewhere.  ``eva_attention`` (after it) is the attention of
+a model whose queries see the keys of their own window exactly and every
+earlier window through learned summaries of its chunks, under one softmax:
+a window of queries at a time, in ``jax.numpy``.
 """
 
 import functools
@@ -384,3 +387,100 @@ def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
         outs.append(out)
         loss, low = loss + kl, low + low_here
     return jnp.concatenate(outs, axis=1), loss / (B * S), low / (B * S)
+
+
+# --------------------------------------------------------------------------
+# Attention over a window's own keys and learned summaries of every earlier
+# window's chunks (EVA, arXiv:2302.04542, as EvaByte's model code has it)
+# --------------------------------------------------------------------------
+
+def eva_pool(k, v, mu, phi, chunk):
+    """One summary a chunk of ``chunk`` positions: ``(pooled keys, pooled
+    values [B, S / chunk, H, D], the largest pooling weight of each chunk
+    [2, B, S / chunk, H] float32)``.  Keys are pooled by ``softmax_m(mu .
+    k_m)`` and values by ``softmax_m(phi . k_m)`` over the chunk's
+    positions ``m``; ``mu``, ``phi`` [H, D] are the head's learned vectors,
+    the logits unscaled and in float32."""
+    B, S, H, D = k.shape
+    k = k.reshape(B, S // chunk, chunk, H, D)
+    v = v.reshape(B, S // chunk, chunk, H, D)
+
+    def weights(vector):
+        return jax.nn.softmax(jnp.einsum(
+            "bjmhd,hd->bjmh", k, vector.astype(k.dtype),
+            preferred_element_type=jnp.float32), axis=2)
+
+    def pooled(weight, rows):
+        # the weights in the operands' dtype, as an attention's probabilities
+        return jnp.einsum("bjmh,bjmhd->bjhd", weight.astype(rows.dtype), rows,
+                          preferred_element_type=jnp.float32).astype(rows.dtype)
+
+    a, b = weights(mu), weights(phi)
+    pooled_k, pooled_v = pooled(a, k), pooled(b, v)
+    largest = jnp.stack([a.max(axis=2), b.max(axis=2)])
+    return pooled_k, pooled_v, jax.lax.stop_gradient(largest)
+
+
+@jax.checkpoint
+def _eva_window(q, k, v, pooled_k, pooled_v):
+    """One window's queries ``q`` [B, W, H, D] over ``[its own keys, causal;
+    the summaries of every earlier window]`` under one softmax: ``(out [B,
+    W, H, D], the softmax mass on the summaries summed over the window's
+    queries and heads)``.  Rematerialised: the backward pass holds one
+    window's ``[H, W, W + summaries]`` scores at a time."""
+    W, D = q.shape[1], q.shape[-1]
+    keys = jnp.concatenate([k, pooled_k], axis=1)
+    values = jnp.concatenate([v, pooled_v], axis=1)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, keys,
+                        preferred_element_type=jnp.float32) * D ** -0.5
+    allowed = jnp.concatenate(
+        [jnp.tril(jnp.ones((W, W), bool)),
+         jnp.ones((W, pooled_k.shape[1]), bool)], axis=1)
+    logits = jnp.where(allowed, logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), values)
+    return out, jax.lax.stop_gradient(probs[..., W:].sum())
+
+
+def eva_attention(q, k, v, mu, phi, window, chunk):
+    """Causal attention in which a query attends exactly to the keys of its
+    own window of ``window`` positions and, through one learned summary (a
+    pooled key and a pooled value, ``eva_pool``) of each ``chunk`` positions,
+    to every EARLIER window, none of its own; one softmax over both:
+    ``(out [B, S, H, D], the mean over the queries past the first window of
+    the softmax mass on summaries, the mean over chunks, heads and the two
+    poolings of the largest pooling weight)``.
+
+    ``q``, ``k``, ``v`` [B, S, H, D] (as many key heads as query heads),
+    ``mu``, ``phi`` [H, D].  In the first window there is no summary and the
+    layer is plain causal attention; a sequence shorter than the window is
+    one window.  Gradients flow through the summaries into ``k``, ``v``,
+    ``mu`` and ``phi``.  A window of queries at a time against ``[its
+    window's keys ; the summaries before it]``, so nothing ``[S, S]`` is
+    whole: the largest block of scores is ``[H, window, window + (S -
+    window) / chunk]``."""
+    B, S, H, D = q.shape
+    window = min(window, S)
+    if S % window or window % chunk:
+        raise ValueError(f"seq {S} is not a multiple of the window {window}, "
+                         f"or the window of the chunk {chunk}")
+    if k.shape != q.shape:
+        raise ValueError(f"eva attention wants a key head a query head; got "
+                         f"q {q.shape}, k {k.shape}")
+    windows, per_window = S // window, window // chunk
+    trace.note_trace_time(
+        "attention.path", impl="eva", seq=S, window=window, chunk=chunk,
+        windows=windows, summaries_max=(windows - 1) * per_window, heads=H,
+        head_dim=D, exact="jnp")
+    pooled_k, pooled_v, largest = eva_pool(k, v, mu, phi, chunk)
+    outs, mass = [], jnp.float32(0)
+    for w in range(windows):
+        own = slice(w * window, (w + 1) * window)
+        out, on_summaries = _eva_window(
+            q[:, own], k[:, own], v[:, own],
+            pooled_k[:, :w * per_window], pooled_v[:, :w * per_window])
+        outs.append(out)
+        mass = mass + on_summaries
+    later_queries = B * H * (S - window)
+    return (jnp.concatenate(outs, axis=1), mass / max(later_queries, 1),
+            largest.mean())
