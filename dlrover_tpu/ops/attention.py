@@ -18,7 +18,8 @@ Pallas kernels on a TPU (``ops/pallas/selected_attention.py``) and in
 ``jax.numpy`` elsewhere.  ``eva_attention`` (after it) is the attention of
 a model whose queries see the keys of their own window exactly and every
 earlier window through learned summaries of its chunks, under one softmax:
-a window of queries at a time, in ``jax.numpy``.
+a window of queries at a time, through the same kernels' other entry point
+on a TPU and in ``jax.numpy`` elsewhere.
 """
 
 import functools
@@ -442,6 +443,47 @@ def _eva_window(q, k, v, pooled_k, pooled_v):
     return out, jax.lax.stop_gradient(probs[..., W:].sum())
 
 
+@functools.partial(jax.jit, static_argnames=("block_kv", "interpret"))
+def _eva_window_kernels(q, k, v, pooled_k, pooled_v, *, block_kv,
+                        interpret=False):
+    """``_eva_window`` with the attention in the Pallas kernels of
+    ``ops/pallas/selected_attention.py``: no ``[H, W, keys]`` tensor leaves
+    the chip's fast memory, forward or backward.  The window's keys go under
+    a causal mask, the kernels' operand; the summaries are the keys every
+    query attends to.  Not rematerialised: the kernels' custom gradient
+    keeps ``out`` and the LSE.  The mass on the summaries is ``sum exp(q .
+    pooled_k - lse)``, one product more over the summaries' columns alone.
+    Under ``jax.jit``, as ``_attend_block``: a second trace of the model
+    finds a window's kernels traced."""
+    from dlrover_tpu.ops.pallas.selected_attention import masked_attention
+
+    B, W, _, D = q.shape
+    keep = jnp.broadcast_to(jnp.tril(jnp.ones((W, W), bool)), (B, W, W))
+    if not pooled_k.shape[1]:   # the first window: no summary yet
+        out, _ = masked_attention(q, k, v, keep, None, block_kv, interpret)
+        return out, jnp.float32(0)
+    out, lse = masked_attention(
+        q, k, v, keep, (pooled_k, pooled_v), block_kv, interpret)
+    on_summaries = jnp.einsum("bqhd,bkhd->bhqk", q, pooled_k,
+                              preferred_element_type=jnp.float32) * D ** -0.5
+    mass = jnp.exp(on_summaries - lse[..., None]).sum()
+    return out, jax.lax.stop_gradient(mass)
+
+
+def eva_exact_path(backend: str, window: int, chunk: int, head_dim: int,
+                   heads: int) -> str:
+    """``"pallas"`` or ``"jnp"``: which body attends to a window's keys and
+    summaries, from what the code can observe (as ``selected_attend_path``):
+    the kernels on a TPU where they take a block of ``window`` queries and a
+    window's summaries fill whole 128-lane tiles."""
+    from dlrover_tpu.ops.pallas.flash_attention import LANES
+
+    if (window // chunk) % LANES == 0 and selected_attend_path(
+            backend, window, head_dim, heads, heads) == "pallas":
+        return "pallas"
+    return "jnp"
+
+
 def eva_attention(q, k, v, mu, phi, window, chunk):
     """Causal attention in which a query attends exactly to the keys of its
     own window of ``window`` positions and, through one learned summary (a
@@ -458,7 +500,9 @@ def eva_attention(q, k, v, mu, phi, window, chunk):
     ``mu`` and ``phi``.  A window of queries at a time against ``[its
     window's keys ; the summaries before it]``, so nothing ``[S, S]`` is
     whole: the largest block of scores is ``[H, window, window + (S -
-    window) / chunk]``."""
+    window) / chunk]`` in ``jax.numpy`` and tiles in fast memory in the
+    kernels, which run on a TPU at the shapes they take
+    (``eva_exact_path``); the pooling is ``jax.numpy`` on both paths."""
     B, S, H, D = q.shape
     window = min(window, S)
     if S % window or window % chunk:
@@ -468,15 +512,24 @@ def eva_attention(q, k, v, mu, phi, window, chunk):
         raise ValueError(f"eva attention wants a key head a query head; got "
                          f"q {q.shape}, k {k.shape}")
     windows, per_window = S // window, window // chunk
+    attend_window = _eva_window
+    path = dict(exact=eva_exact_path(
+        jax.default_backend(), window, chunk, D, H))
+    if path["exact"] == "pallas":
+        from dlrover_tpu.ops.pallas.tuning import selected_tiling
+
+        path.update(block_kv=selected_tiling(window, D)[0])
+        attend_window = functools.partial(
+            _eva_window_kernels, block_kv=path["block_kv"])
     trace.note_trace_time(
         "attention.path", impl="eva", seq=S, window=window, chunk=chunk,
         windows=windows, summaries_max=(windows - 1) * per_window, heads=H,
-        head_dim=D, exact="jnp")
+        head_dim=D, **path)
     pooled_k, pooled_v, largest = eva_pool(k, v, mu, phi, chunk)
     outs, mass = [], jnp.float32(0)
     for w in range(windows):
         own = slice(w * window, (w + 1) * window)
-        out, on_summaries = _eva_window(
+        out, on_summaries = attend_window(
             q[:, own], k[:, own], v[:, own],
             pooled_k[:, :w * per_window], pooled_v[:, :w * per_window])
         outs.append(out)

@@ -36,6 +36,16 @@ only: a head is one 128-lane column block.
 
 A row of ``keep`` with no key at all reads as the ``jax.numpy`` path
 does (the mean of ``v``); ``indexed_sparse_attention`` never makes one.
+
+Two entry points over the forward and backward kernels, by what the
+caller's model has: ``selected_attention`` (an indexer to teach: the
+heads' mean with the output) and ``masked_attention`` (none: the output
+and the LSE).  ``ops/attention.py::eva_attention`` calls the second once a
+window of 2048 queries, a key head a query head, its window's keys under
+a causal ``keep`` and the summaries of earlier windows as ``shared`` keys
+that every query attends to: ``2048 + 128 w`` keys have no even tiling
+(17, 19 and 23 times 128), the window's 2048 have, and a kernel's last
+grid step takes the summaries whole.
 """
 
 import functools
@@ -88,9 +98,20 @@ def _bias(keep_ref):
 
 
 def _scores(q, k, scale, bias):
-    return jax.lax.dot_general(
+    """``bias`` ``None``: every key attended to."""
+    s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-    ) * scale + bias
+    ) * scale
+    return s if bias is None else s + bias
+
+
+def _whole_tiles(ref, tile):
+    """A resident ``[1, keys, D]`` block in turns of at most ``tile`` keys
+    (the last one what is left): what a kernel's last grid step visits of
+    the keys every query attends to."""
+    keys = ref.shape[1]
+    return [ref[0, pl.ds(at, min(tile, keys - at)), :]
+            for at in range(0, keys, tile)]
 
 
 def _head_cols(r, head_dim):
@@ -119,10 +140,14 @@ def _each_head(group, body, carry=None, unrolled=False):
     return jax.lax.fori_loop(0, group, body, carry)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, keep_ref, out_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, group, head_dim):
+def _fwd_kernel(q_ref, k_ref, v_ref, keep_ref, *rest,
+                scale, group, head_dim):
     """grid (batch, kv head, kv tile): online softmax over the tiles for
-    the ``group`` query heads of the kv head."""
+    the ``group`` query heads of the kv head.  ``rest``: the results and
+    scratch, after ``(k, v)`` of the keys every query attends to where the
+    caller has such (``_Block.shared``), which the last grid step visits
+    after its tile."""
+    *shared, out_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     kv_idx = pl.program_id(2)
 
     @pl.when(kv_idx == 0)
@@ -131,30 +156,37 @@ def _fwd_kernel(q_ref, k_ref, v_ref, keep_ref, out_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
+    def visit(k, v, bias):
+        def one_head(r, _):
+            cols = _head_cols(r, head_dim)
+            s = _scores(q_ref[0, :, cols], k, scale, bias)
+            m_prev = m_ref[r, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # a row with no kept key so far has m_new == NEG_INF and p == 1:
+            # the first kept key's correction, exp(NEG_INF - m), wipes it
+            p = jnp.exp(s - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_new = l_ref[r, :, :1] * correction + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[:, cols] = (
+                acc_ref[:, cols] * correction + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            m_ref[r] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[r] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        _each_head(group, one_head)
+
     bias = _bias(keep_ref)
-    k, v = k_ref[0], v_ref[0]
-
-    def one_head(r, _):
-        cols = _head_cols(r, head_dim)
-        s = _scores(q_ref[0, :, cols], k, scale, bias)
-        m_prev = m_ref[r, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # a row with no kept key so far has m_new == NEG_INF and p == 1:
-        # the first kept key's correction, exp(NEG_INF - m), wipes it
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_new = l_ref[r, :, :1] * correction + jnp.sum(
-            p, axis=-1, keepdims=True)
-        acc_ref[:, cols] = acc_ref[:, cols] * correction + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[r] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[r] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-
-    _each_head(group, one_head)
+    visit(k_ref[0], v_ref[0], bias)
 
     @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finalize():
+        if shared:
+            tile = k_ref.shape[1]
+            for k, v in zip(*(_whole_tiles(ref, tile) for ref in shared)):
+                visit(k, v, None)
+
         def one_head(r, _):
             cols = _head_cols(r, head_dim)
             l = l_ref[r, :, :1]  # at least 1: the row's largest score
@@ -186,12 +218,16 @@ def _target_kernel(q_ref, k_ref, keep_ref, lse_ref, target_ref,
     target_ref[0] += total * (1.0 / heads)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, o_ref, lse_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, delta_ref,
-                *, scale, group, head_dim):
+def _bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, o_ref, lse_ref, *rest,
+                scale, group, head_dim):
     """grid (batch, kv head, kv tile): probabilities recomputed from (q,
     k, lse) under the mask; dQ of the group accumulated over the tiles,
-    the tile's dK and dV summed over the group and written."""
+    the tile's dK and dV summed over the group and written.  ``rest`` as
+    the forward's: with ``(k, v)`` of the keys every query attends to come
+    their ``(dk, dv)`` after the other results, written by the last grid
+    step."""
+    shared = rest[:(len(rest) - 5) // 2]
+    dq_ref, dk_ref, dv_ref, *shared_grads, dq_acc, delta_ref = rest[len(shared):]
     kv_idx = pl.program_id(2)
 
     @pl.when(kv_idx == 0)
@@ -208,50 +244,61 @@ def _bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, o_ref, lse_ref,
 
         _each_head(group, one_head)
 
+    def visit(k, v, bias):
+        """dQ accumulated; ``(dk, dv)`` of these keys, float32."""
+        def one_head(r, sums):
+            dk, dv = sums
+            cols = _head_cols(r, head_dim)
+            q, do = q_ref[0, :, cols], do_ref[0, :, cols]
+            p = jnp.exp(_scores(q, k, scale, bias) - lse_ref[0, r, :, :1])
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[r, :, :1]) * scale
+            dq_acc[:, cols] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dv += jax.lax.dot_general(  # P^T dO
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk += jax.lax.dot_general(  # dS^T Q
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return dk, dv
+
+        zero = jnp.zeros(k.shape, jnp.float32)
+        return _each_head(group, one_head, (zero, zero))
+
     bias = _bias(keep_ref)
-    k, v = k_ref[0], v_ref[0]
-
-    def one_head(r, sums):
-        dk, dv = sums
-        cols = _head_cols(r, head_dim)
-        q, do = q_ref[0, :, cols], do_ref[0, :, cols]
-        p = jnp.exp(_scores(q, k, scale, bias) - lse_ref[0, r, :, :1])
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[r, :, :1]) * scale
-        dq_acc[:, cols] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dv += jax.lax.dot_general(  # P^T dO
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk += jax.lax.dot_general(  # dS^T Q
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk, dv
-
-    zero = jnp.zeros(k.shape, jnp.float32)
-    dk, dv = _each_head(group, one_head, (zero, zero))
+    dk, dv = visit(k_ref[0], v_ref[0], bias)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
     @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finalize():
+        if shared:
+            tile, at = k_ref.shape[1], 0
+            for k, v in zip(*(_whole_tiles(ref, tile) for ref in shared)):
+                rows = pl.ds(at, k.shape[0])
+                for ref, grad in zip(shared_grads, visit(k, v, None)):
+                    ref[0, rows, :] = grad.astype(ref.dtype)
+                at += k.shape[0]
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 class _Block:
     """Shapes and block specs of one call: ``q`` [B, Q, H, D], ``k`` [B,
-    K, G, D], tiles of at most ``block_kv`` keys.  ``where`` gives a grid
-    step's (batch, kv head, kv tile)."""
+    K, G, D], tiles of at most ``block_kv`` keys; ``shared`` ``None`` or
+    ``(k, v)`` [B, keys, G, D] of the keys every query attends to.
+    ``where`` gives a grid step's (batch, kv head, kv tile)."""
 
-    def __init__(self, q, k, block_kv):
+    def __init__(self, q, k, block_kv, shared=None):
         self.B, self.Q, self.H, self.D = q.shape
         self.K, self.G = k.shape[1:3]
         self.group = self.H // self.G
         self.tile = kv_tile(self.K, block_kv)
         self.tiles = self.K // self.tile
+        self.shared = [_flat(x) for x in shared or ()]
         self.settings = dict(scale=self.D ** -0.5, group=self.group,
                              head_dim=self.D)
 
@@ -269,6 +316,10 @@ class _Block:
             # lane-broadcast per-row scalars, as FA2's: [B, H, Q, LANES]
             lse=pl.BlockSpec((1, self.group, self.Q, LANES),
                              at(lambda b, g, j: (b, g, 0, 0))),
+            # whole and resident while a kv head's tiles go by
+            shared=[pl.BlockSpec((1, x.shape[1], self.D),
+                                 at(lambda b, g, j: (b, 0, g)))
+                    for x in self.shared],
         )
 
 
@@ -276,16 +327,16 @@ def _flat(x):
     return x.reshape(*x.shape[:2], -1)
 
 
-def _forward(q, k, v, keep, tiling, interpret):
-    """``(out [B, Q, H, D], lse [B, H, Q, LANES], target [B, Q, K])``;
-    ``keep`` int8."""
-    blk = _Block(q, k, tiling[0])
-    B, Q, H, D, K = blk.B, blk.Q, blk.H, blk.D, blk.K
+def _attend(q, k, v, keep, shared, block_kv, interpret):
+    """``(out [B, Q, H, D], lse [B, H, Q, LANES])``; ``keep`` int8."""
+    blk = _Block(q, k, block_kv, shared)
+    B, Q, H, D = blk.B, blk.Q, blk.H, blk.D
     spec = blk.specs(lambda b, g, j: (b, g, j))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, **blk.settings),
         grid=(B, blk.G, blk.tiles),
-        in_specs=[spec["q"], spec["kv"], spec["kv"], spec["keep"]],
+        in_specs=[spec["q"], spec["kv"], spec["kv"], spec["keep"],
+                  *spec["shared"]],
         out_specs=[spec["q"], spec["lse"]],
         out_shape=[jax.ShapeDtypeStruct((B, Q, H * D), q.dtype),
                    jax.ShapeDtypeStruct((B, H, Q, LANES), jnp.float32)],
@@ -296,12 +347,17 @@ def _forward(q, k, v, keep, tiling, interpret):
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(_flat(q), _flat(k), _flat(v), keep)
-    # tiles of its own, the groups innermost; every head's LSE resident,
-    # fetched once a call
-    blk = _Block(q, k, tiling[1])
+    )(_flat(q), _flat(k), _flat(v), keep, *blk.shared)
+    return out.reshape(q.shape), lse
+
+
+def _heads_mean(q, k, keep, lse, block_kv, interpret):
+    """``target [B, Q, K]`` float32: tiles of its own, the groups
+    innermost; every head's LSE resident, fetched once a call."""
+    blk = _Block(q, k, block_kv)
+    B, Q, H, K = blk.B, blk.Q, blk.H, blk.K
     spec = blk.specs(lambda b, j, g: (b, g, j))
-    target = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_target_kernel, heads=H, **blk.settings),
         grid=(B, blk.tiles, blk.G),
         in_specs=[spec["q"], spec["kv"], spec["keep"],
@@ -311,31 +367,36 @@ def _forward(q, k, v, keep, tiling, interpret):
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(_flat(q), _flat(k), keep, lse)
-    return out.reshape(q.shape), lse, target
 
 
-def _backward(q, k, v, keep, out, lse, grad_out, tiling, interpret):
-    """``(dq, dk, dv)`` in the shapes of ``(q, k, v)``."""
-    blk = _Block(q, k, tiling[0])
+def _backward(q, k, v, keep, shared, out, lse, grad_out, block_kv, interpret):
+    """``(dq, dk, dv, the gradients of shared)`` in their operands'
+    shapes."""
+    blk = _Block(q, k, block_kv, shared)
     B, Q, H, D, K = blk.B, blk.Q, blk.H, blk.D, blk.K
     spec = blk.specs(lambda b, g, j: (b, g, j))
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv, *shared_grads = pl.pallas_call(
         functools.partial(_bwd_kernel, **blk.settings),
         grid=(B, blk.G, blk.tiles),
         in_specs=[spec["q"], spec["kv"], spec["kv"], spec["keep"],
-                  spec["q"], spec["q"], spec["lse"]],
-        out_specs=[spec["q"], spec["kv"], spec["kv"]],
+                  spec["q"], spec["q"], spec["lse"], *spec["shared"]],
+        out_specs=[spec["q"], spec["kv"], spec["kv"], *spec["shared"]],
         out_shape=[jax.ShapeDtypeStruct((B, Q, H * D), q.dtype),
                    jax.ShapeDtypeStruct((B, K, blk.G * D), k.dtype),
-                   jax.ShapeDtypeStruct((B, K, blk.G * D), v.dtype)],
+                   jax.ShapeDtypeStruct((B, K, blk.G * D), v.dtype),
+                   *(jax.ShapeDtypeStruct(x.shape, x.dtype)
+                     for x in blk.shared)],
         scratch_shapes=[
             pltpu.VMEM((Q, blk.group * D), jnp.float32),
             pltpu.VMEM((blk.group, Q, LANES), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(_flat(q), _flat(k), _flat(v), keep, _flat(grad_out), _flat(out), lse)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    )(_flat(q), _flat(k), _flat(v), keep, _flat(grad_out), _flat(out), lse,
+      *blk.shared)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            shared and tuple(
+                g.reshape(x.shape) for g, x in zip(shared_grads, shared)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -346,22 +407,50 @@ def selected_attention(q, k, v, keep, tiling, interpret: bool = False):
     attention's probabilities.  ``target`` carries no gradient: what comes
     back for it is dropped, as under ``stop_gradient``.  ``tiling``:
     ``(block_kv, mean_block_kv)`` as ``tuning.selected_tiling`` gives it."""
-    out, _, target = _forward(q, k, v, keep.astype(jnp.int8), tiling,
-                              interpret)
-    return out, target
+    return _selected_fwd(q, k, v, keep, tiling, interpret)[0]
 
 
-def _fwd(q, k, v, keep, tiling, interpret):
+def _selected_fwd(q, k, v, keep, tiling, interpret):
     keep = keep.astype(jnp.int8)  # the kernels' mask, kept for the backward
-    out, lse, target = _forward(q, k, v, keep, tiling, interpret)
+    out, lse = _attend(q, k, v, keep, None, tiling[0], interpret)
+    target = _heads_mean(q, k, keep, lse, tiling[1], interpret)
     return (out, target), (q, k, v, keep, out, lse)
 
 
-def _bwd(tiling, interpret, residuals, grads):
+def _selected_bwd(tiling, interpret, residuals, grads):
     q, k, v, keep, out, lse = residuals
-    dq, dk, dv = _backward(
-        q, k, v, keep, out, lse, grads[0], tiling, interpret)
+    dq, dk, dv, _ = _backward(
+        q, k, v, keep, None, out, lse, grads[0], tiling[0], interpret)
     return dq, dk, dv, None
 
 
-selected_attention.defvjp(_fwd, _bwd)
+selected_attention.defvjp(_selected_fwd, _selected_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def masked_attention(q, k, v, keep, shared, block_kv, interpret: bool = False):
+    """``selected_attention`` for a model with no indexer to teach: ``(out
+    [B, Q, H, D], lse [B, H, Q] float32)``, the log of each row's sum of
+    exponentiated scores, and no heads' mean.  ``shared``: ``None``, or
+    ``(k, v)`` [B, keys, G, D] of further keys that EVERY query attends
+    to (``keep`` says nothing of them): a kernel's last grid step visits
+    them, in turns of at most a tile, so ``k``'s own keys keep their even
+    tiles whatever their number.  The same kernels and residuals (``q, k,
+    v, keep, out, lse``, and ``shared``); ``lse`` carries no gradient."""
+    return _masked_fwd(q, k, v, keep, shared, block_kv, interpret)[0]
+
+
+def _masked_fwd(q, k, v, keep, shared, block_kv, interpret):
+    keep = keep.astype(jnp.int8)
+    out, lse = _attend(q, k, v, keep, shared, block_kv, interpret)
+    return (out, lse[..., 0]), (q, k, v, keep, shared, out, lse)
+
+
+def _masked_bwd(block_kv, interpret, residuals, grads):
+    q, k, v, keep, shared, out, lse = residuals
+    dq, dk, dv, shared_grads = _backward(
+        q, k, v, keep, shared, out, lse, grads[0], block_kv, interpret)
+    return dq, dk, dv, None, shared_grads
+
+
+masked_attention.defvjp(_masked_fwd, _masked_bwd)

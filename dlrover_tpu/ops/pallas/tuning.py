@@ -149,11 +149,13 @@ def selected_tiling(block_q: int, head_dim: int) -> Tuple[int, int]:
     of the heads' mean (a call takes the largest tile under it that
     divides its keys).  The table's ``selected_q<block_q>_d<head_dim>_kv``
     entry (swept in a whole step on the chip by
-    ``scripts/fa_blocks_in_step.py --selected``), else the untuned
-    default."""
+    ``scripts/fa_blocks_in_step.py --selected``; one swept where no
+    heads' mean runs names no tile for it, and that reads as the other's),
+    else the untuned default."""
     try:
         entry = _load_table().get(f"selected_q{block_q}_d{head_dim}_kv") or {}
-        tiling = int(entry["block_kv"]), int(entry["mean_block_kv"])
+        block_kv = int(entry["block_kv"])
+        tiling = block_kv, int(entry.get("mean_block_kv", block_kv))
         if min(tiling) > 0:
             return tiling
     except (TypeError, KeyError, ValueError):

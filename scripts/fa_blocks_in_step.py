@@ -14,9 +14,12 @@ the trace.  That is the time the step pays, and it is what the shipped
 (``ops/pallas/selected_attention.py``): keys a tile in the forward and
 backward and in the heads' mean (``--blocks kv,mean_kv;kv,mean_kv``; the table's
 ``selected_q<block>_d<head_dim>_kv`` entry) in a configuration with an
-indexer, forward, heads' mean and backward told apart by their results::
+indexer, forward, heads' mean and backward told apart by their results;
+or, in a configuration whose attention goes by windows and summaries, keys
+a tile alone (``--blocks kv;kv``: no indexer, no heads' mean)::
 
     python3 scripts/fa_blocks_in_step.py --config keyevl2_30b_1of8 --selected
+    python3 scripts/fa_blocks_in_step.py --config evabyte_l4 --selected
 
 Candidates reach the kernel through the table ``DLROVER_TPU_FA_TUNING``
 names, as a user's own table would.  One JSON line a candidate, the
@@ -61,23 +64,29 @@ SELECTED_CALL = re.compile(r"^%[\w.]+ = (.*) custom-call\(.*"
 def selected_kernel_seconds(trace_dir, shape):
     """kind (``fwd``, ``mean``, ``bwd``) -> [events, seconds] of the
     custom calls that carry a block's mask, known as the benchmark's
-    ``sparse_attn_ms_per_step`` knows them: the forward returns two
-    arrays, the heads' mean one, the backward three."""
+    ``sparse_attn_ms_per_step`` (or, for windows and summaries,
+    ``eva_attn_ms_per_step``) knows them: the forward returns two arrays,
+    the heads' mean one, the backward three or, with the gradients of the
+    keys every query attends to, five."""
     from benchmarks import common
     from benchmarks import trace as trace_mod
 
-    block_by_keys = common.load_module(
-        "layer_metrics", "sparse_attn_ms_per_step").block_by_keys
+    if "window" in shape:
+        carries_mask = common.load_module(
+            "layer_metrics", "eva_attn_ms_per_step").is_window_op
+    else:
+        carries_mask = common.load_module(
+            "layer_metrics", "sparse_attn_ms_per_step").block_by_keys
     loaded = trace_mod.load(trace_mod.find_xplane(trace_dir))
     found = {"fwd": [0, 0.0], "mean": [0, 0.0], "bwd": [0, 0.0]}
     if loaded.device_ops:
         for name, start, end in loaded.device_ops[min(loaded.device_ops)]:
             call = SELECTED_CALL.match(name)
-            if not call or not block_by_keys(name, shape):
+            if not call or not carries_mask(name, shape):
                 continue
             result = call.group(1)
             kind = ("mean" if not result.startswith("(") else
-                    {2: "fwd", 3: "bwd"}[result.count("[")])
+                    "fwd" if result.count("[") == 2 else "bwd")
             found[kind][0] += 1
             found[kind][1] += end - start
     return found
@@ -114,10 +123,15 @@ def main(argv=None) -> int:
     head_dim = m.get("head_dim") or m["n_embd"] // heads
     key = f"s{seq}_d{head_dim}"
     if args.selected:
-        sparse = family.sparse_attn_shape(config, batch, seq, args.rehearse)
-        block_q = sparse["block"]
+        if hasattr(family, "sparse_attn_shape"):
+            shape = family.sparse_attn_shape(config, batch, seq, args.rehearse)
+            block_q = shape["block"]
+            args.blocks = args.blocks or "512,512;1024,512;2048,512;2048,2048"
+        else:   # windows and summaries: a window of queries a call
+            shape = family.eva_attn_shape(config, batch, seq, args.rehearse)
+            block_q = shape["window"]
+            args.blocks = args.blocks or "512;1024;2048"
         key = f"selected_q{block_q}_d{head_dim}_kv"
-        args.blocks = args.blocks or "512,512;1024,512;2048,512;2048,2048"
     if args.blocks:
         candidates = [tuple(int(b) for b in pair.split(","))
                       for pair in args.blocks.split(";")]
@@ -128,10 +142,12 @@ def main(argv=None) -> int:
     with tempfile.TemporaryDirectory() as scratch:
         table = os.path.join(scratch, "candidate.json")
         os.environ["DLROVER_TPU_FA_TUNING"] = table
-        for first, second in candidates:
+        for first, *second in candidates:
+            second = second[0] if second else first
             if args.selected:
-                line = {"block_q": block_q, "block_kv": first,
-                        "mean_block_kv": second}
+                line = {"block_q": block_q, "block_kv": first}
+                if "block" in shape:   # an indexer: a heads' mean
+                    line["mean_block_kv"] = second
             else:
                 line = {"block_q": first, "block_kv": second}
             with open(table, "w") as f:
@@ -158,7 +174,7 @@ def main(argv=None) -> int:
                     step_s = (time.perf_counter() - t0) / len(sharded)
                 finally:
                     jax.profiler.stop_trace()
-                found = (selected_kernel_seconds(trace_dir, sparse)
+                found = (selected_kernel_seconds(trace_dir, shape)
                          if args.selected else kernel_seconds(trace_dir))
                 per_step = 1e3 / len(sharded)
                 line.update(
