@@ -68,43 +68,52 @@ class Block(nn.Module):
         )
 
         h = ln(name="ln_1")(x)
-        qkv = dense(
-            features=(3, cfg.n_head, head_dim),
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("embed", None, "heads", "head_dim")
-            ),
-            name="attn_qkv",
-        )(h)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        att = self._attend(q, k, v, mask)
-        att = dense(
-            features=cfg.n_embd,
-            axis=(-2, -1),
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("heads", "head_dim", "embed")
-            ),
-            name="attn_proj",
-        )(att)
-        att = nn.Dropout(cfg.dropout)(att, deterministic=deterministic)
+        # the scopes give the block's two halves the kinds the Llama code's
+        # modules have (the kind table of ``observability/trace.py``);
+        # module names are parameter names and stay
+        with jax.named_scope("attn"):
+            qkv = dense(
+                features=(3, cfg.n_head, head_dim),
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02),
+                    ("embed", None, "heads", "head_dim")
+                ),
+                name="attn_qkv",
+            )(h)
+            # the split is the kernels' layout copy: they take q, k and v
+            # as arrays of their own
+            with jax.named_scope("attn.core"):
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                att = self._attend(q, k, v, mask)
+            att = dense(
+                features=cfg.n_embd,
+                axis=(-2, -1),
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), ("heads", "head_dim", "embed")
+                ),
+                name="attn_proj",
+            )(att)
+            att = nn.Dropout(cfg.dropout)(att, deterministic=deterministic)
         x = x + att
 
         h = ln(name="ln_2")(x)
-        h = dense(
-            features=4 * cfg.n_embd,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("embed", "mlp")
-            ),
-            name="mlp_fc",
-        )(h)
-        h = nn.gelu(h)
-        h = dense(
-            features=cfg.n_embd,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("mlp", "embed")
-            ),
-            name="mlp_proj",
-        )(h)
-        h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
+        with jax.named_scope("mlp"):
+            h = dense(
+                features=4 * cfg.n_embd,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), ("embed", "mlp")
+                ),
+                name="mlp_fc",
+            )(h)
+            h = nn.gelu(h)
+            h = dense(
+                features=cfg.n_embd,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), ("mlp", "embed")
+                ),
+                name="mlp_proj",
+            )(h)
+            h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
         x = x + h
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
@@ -151,7 +160,9 @@ class GPT(nn.Module):
             (cfg.block_size, cfg.n_embd),
             cfg.param_dtype,
         )
-        x = wte.astype(cfg.dtype)[input_ids] + wpe.astype(cfg.dtype)[None, :S]
+        with jax.named_scope("embed"):
+            x = (wte.astype(cfg.dtype)[input_ids]
+                 + wpe.astype(cfg.dtype)[None, :S])
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
         if cfg.scan_layers:
@@ -187,7 +198,8 @@ class GPT(nn.Module):
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="ln_f"
         )(x)
         # weight-tied lm head, fp32 logits
-        logits = jnp.einsum(
-            "bsd,vd->bsv", x.astype(jnp.float32), wte.astype(jnp.float32)
-        )
+        with jax.named_scope("head_loss"):
+            logits = jnp.einsum(
+                "bsd,vd->bsv", x.astype(jnp.float32), wte.astype(jnp.float32)
+            )
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))

@@ -222,12 +222,17 @@ class Attention(nn.Module):
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
 
+        # ``attn.core``: from q, k, v to the attention's output (the kind
+        # table of ``observability/trace.py``); what is left under ``attn``
+        # is projections.  The scope stands AROUND ``_attend``: the device
+        # names a kernel after the innermost scope around it
         if cfg.index_topk:
             out = self._attend_indexed(x, q, k, v, positions, dense)
         elif cfg.eva_window:
             out = self._attend_eva(q, k, v)
         else:
-            out = self._attend(q, k, v, mask)
+            with jax.named_scope("attn.core"):
+                out = self._attend(q, k, v, mask)
         out = nn.with_logical_constraint(
             out, ("batch", "seq", "heads", "head_dim")
         )
@@ -267,9 +272,10 @@ class Attention(nn.Module):
         index_w = project("index_w_proj", cfg.index_heads)
         index_q = _rope(index_q, positions, cfg.rope_theta)
         index_k = _rope(index_k[:, :, None], positions, cfg.rope_theta)[:, :, 0]
-        out, loss, low = indexed_sparse_attention(
-            q, k, v, index_q, index_k, index_w, cfg.index_topk,
-            cfg.index_block)
+        with jax.named_scope("attn.core"):
+            out, loss, low = indexed_sparse_attention(
+                q, k, v, index_q, index_k, index_w, cfg.index_topk,
+                cfg.index_block)
         self.sow("losses", "index", loss / cfg.num_layers)
         self.sow("stats", "index_loss", loss)
         self.sow("stats", "index_low_margin_share", low)
@@ -294,9 +300,10 @@ class Attention(nn.Module):
                 (cfg.num_heads, cfg.head_dim), cfg.param_dtype,
             )
 
-        out, summary_mass, pool_weight = eva_attention(
-            q, k, v, pooling_vector("adaptive_mu_k"),
-            pooling_vector("adaptive_phi"), cfg.eva_window, cfg.eva_chunk)
+        mu, phi = pooling_vector("adaptive_mu_k"), pooling_vector("adaptive_phi")
+        with jax.named_scope("attn.core"):
+            out, summary_mass, pool_weight = eva_attention(
+                q, k, v, mu, phi, cfg.eva_window, cfg.eva_chunk)
         self.sow("stats", "eva_summary_mass_share", summary_mass)
         self.sow("stats", "eva_pool_weight_max", pool_weight)
         return out
@@ -463,9 +470,10 @@ class LlamaForCausalLM(nn.Module):
             (cfg.vocab_size, cfg.hidden_size),
             cfg.param_dtype,
         )
-        x = embed.astype(cfg.dtype)[input_ids]
-        if cfg.residual_dtype is not None:
-            x = x.astype(cfg.residual_dtype)
+        with jax.named_scope("embed"):
+            x = embed.astype(cfg.dtype)[input_ids]
+            if cfg.residual_dtype is not None:
+                x = x.astype(cfg.residual_dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
         # an indexer's attention makes its own masks, a block at a time,
@@ -500,7 +508,8 @@ class LlamaForCausalLM(nn.Module):
                     unit_offset=cfg.norm_unit_offset, name="final_norm")(x)
         logits = LMHead(cfg, name="lm_head")(x)
         if cfg.pred_heads > 1:
-            logits = self._first_head(logits, input_ids)
+            with jax.named_scope("head_loss"):
+                logits = self._first_head(logits, input_ids)
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
 
     def _first_head(self, logits, input_ids):

@@ -157,6 +157,7 @@ def ladder(rows, n_local, num_experts):
     return tuple(sorted(e for e in below if e < rows)) + (rows,)
 
 
+@jax.named_scope("combine")
 def _sum_by_token(rows, slot, weights=None):
     """[tokens, D] float32: for each token the sum of its assignments'
     rows, each times its weight where ``weights`` [tokens, fan] is given.
@@ -178,7 +179,8 @@ def _sum_by_token(rows, slot, weights=None):
 def _rows_of(x, picked, slot):
     """``x[picked // fan]``: for each sorted row its token's.  The
     transpose is a sum by token, where autodiff would scatter-add."""
-    return x[picked // slot.shape[1]]
+    with jax.named_scope("sort"):
+        return x[picked // slot.shape[1]]
 
 
 def _rows_of_fwd(x, picked, slot):
@@ -206,6 +208,7 @@ def _weighted_sum_fwd(rows, weights, order, slot):
         rows, weights, order, slot)
 
 
+@jax.named_scope("combine")
 def _weighted_sum_bwd(res, g):
     rows, weights, order, slot = res
     picked = order[:len(rows)]
@@ -229,18 +232,22 @@ def _rung(extent, x, weights, order, inverse, sizes, gate_w, up_w, down_w):
     ``extent`` sorted rows, which hold every row of ``sizes``.  Only the
     index vectors ``order`` and ``inverse`` have the extent of all
     assignments."""
-    picked = order[:extent]
-    slot = inverse.reshape(weights.shape)
-    # the grouped matmul leaves the rows behind the last group undefined:
-    # they are masked on the way in (so no gradient comes back through
-    # them) and on the way out
-    live = (jnp.arange(extent) < sizes.sum())[:, None]
-    rows = jnp.where(live, _rows_of(x, picked, slot), 0)
-    grouped = functools.partial(
-        jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=x.dtype
-    )
-    hidden = nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
-    out = jnp.where(live, grouped(hidden, down_w), 0)
+    with jax.named_scope("sort"):
+        picked = order[:extent]
+        slot = inverse.reshape(weights.shape)
+        # the grouped matmul leaves the rows behind the last group
+        # undefined: they are masked on the way in (so no gradient comes
+        # back through them) and on the way out
+        live = (jnp.arange(extent) < sizes.sum())[:, None]
+    rows = _rows_of(x, picked, slot)
+    with jax.named_scope("gmm"):
+        rows = jnp.where(live, rows, 0)
+        grouped = functools.partial(
+            jax.lax.ragged_dot, group_sizes=sizes,
+            preferred_element_type=x.dtype
+        )
+        hidden = nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
+        out = jnp.where(live, grouped(hidden, down_w), 0)
     return _weighted_sum(out, weights, order, slot)
 
 
@@ -287,14 +294,16 @@ def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert,
     processed, rows the passes ran over)``."""
     tokens, k = top_i.shape
     n_local = gate_w.shape[0]
-    local = top_i - first_expert
-    mine = (local >= 0) & (local < n_local)
-    # assignments of other ranks' experts sort behind every group
-    key = jnp.where(mine, local, n_local).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    inverse = jnp.argsort(order)
-    sizes = (key[:, None] == jnp.arange(n_local)).sum(axis=0, dtype=jnp.int32)
-    weights = jnp.where(mine, top_w, 0).astype(x.dtype)
+    with jax.named_scope("sort"):
+        local = top_i - first_expert
+        mine = (local >= 0) & (local < n_local)
+        # assignments of other ranks' experts sort behind every group
+        key = jnp.where(mine, local, n_local).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.argsort(order)
+        sizes = (key[:, None] == jnp.arange(n_local)).sum(
+            axis=0, dtype=jnp.int32)
+        weights = jnp.where(mine, top_w, 0).astype(x.dtype)
     extents = ladder(tokens * k, n_local, num_experts or n_local)
     args = (x, weights, order, inverse, sizes, gate_w, up_w, down_w)
     if len(extents) == 1:
@@ -316,21 +325,22 @@ class MoEMLP(nn.Module):
         B, S, D = x.shape
         E, k = cfg.num_experts, cfg.top_k
         with jax.named_scope("moe"):
-            logits = nn.DenseGeneral(
-                features=E, use_bias=False,
-                # routing decisions in float32: on a TPU a float32 matmul
-                # at the default precision multiplies in bfloat16
-                dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
-                param_dtype=cfg.param_dtype,
-                kernel_init=nn.with_logical_partitioning(
-                    nn.initializers.lecun_normal(), ("embed", None)
-                ),
-                name="router",
-            )(x)
-            probs = jax.nn.softmax(logits, axis=-1)
-            top_w, top_i = jax.lax.top_k(probs, k)
-            if cfg.norm_topk_prob:
-                top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+            with jax.named_scope("route"):
+                logits = nn.DenseGeneral(
+                    features=E, use_bias=False,
+                    # routing decisions in float32: on a TPU a float32
+                    # matmul at the default precision multiplies in bfloat16
+                    dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                    param_dtype=cfg.param_dtype,
+                    kernel_init=nn.with_logical_partitioning(
+                        nn.initializers.lecun_normal(), ("embed", None)
+                    ),
+                    name="router",
+                )(x)
+                probs = jax.nn.softmax(logits, axis=-1)
+                top_w, top_i = jax.lax.top_k(probs, k)
+                if cfg.norm_topk_prob:
+                    top_w = top_w / top_w.sum(axis=-1, keepdims=True)
 
             def expert_weight(name, shape, axes):
                 return self.param(
@@ -352,30 +362,32 @@ class MoEMLP(nn.Module):
             mixed, rows, held, chip_rows = self._experts(
                 x.astype(cfg.dtype), top_i, top_w, gate_w, up_w, down_w
             )
-            live = B * S * k
-            if cfg.experts_held:
-                # the groups count this chip's experts; the loss and the
-                # balance are the routing's, over every expert
-                live = jnp.maximum(rows.sum(), 1)
-                self.sow("stats", "share_rows_over_expected",
-                         live * (E / (B * S * k * here)))
-                rows = (top_i[..., None] == jnp.arange(E)).sum(
-                    axis=(0, 1, 2), dtype=jnp.int32)
-            assigned = rows.astype(jnp.float32) / (B * S * k)
-            self.sow(
-                "losses", "load_balance",
-                (cfg.load_balance_coef / cfg.num_layers) * E
-                * jnp.sum(assigned * probs.mean(axis=(0, 1))),
-            )
-            self.sow(
-                "losses", "router_z",
-                (cfg.router_z_coef / cfg.num_layers)
-                * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
-            )
-            self.sow("stats", "load_max_over_mean", rows.max() / rows.mean())
-            self.sow("stats", "rows_held_over_live", held / live)
-            self.sow("stats", "chip_rows_max_over_mean",
-                     chip_rows.max() / chip_rows.mean())
+            # the routing's loss terms and counts
+            with jax.named_scope("route"):
+                live = B * S * k
+                if cfg.experts_held:
+                    # the groups count this chip's experts; the loss and the
+                    # balance are the routing's, over every expert
+                    live = jnp.maximum(rows.sum(), 1)
+                    self.sow("stats", "share_rows_over_expected",
+                             live * (E / (B * S * k * here)))
+                    rows = (top_i[..., None] == jnp.arange(E)).sum(
+                        axis=(0, 1, 2), dtype=jnp.int32)
+                assigned = rows.astype(jnp.float32) / (B * S * k)
+                self.sow(
+                    "losses", "load_balance",
+                    (cfg.load_balance_coef / cfg.num_layers) * E
+                    * jnp.sum(assigned * probs.mean(axis=(0, 1))),
+                )
+                self.sow(
+                    "losses", "router_z",
+                    (cfg.router_z_coef / cfg.num_layers)
+                    * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
+                )
+                self.sow("stats", "load_max_over_mean", rows.max() / rows.mean())
+                self.sow("stats", "rows_held_over_live", held / live)
+                self.sow("stats", "chip_rows_max_over_mean",
+                         chip_rows.max() / chip_rows.mean())
         return nn.with_logical_constraint(mixed, ("batch", "seq", "embed"))
 
     def _experts(self, x, top_i, top_w, gate_w, up_w, down_w):
@@ -420,6 +432,12 @@ class MoEMLP(nn.Module):
             )
         others = tuple(a for a in chips if a != "ep")
 
+        exchange = jax.named_scope("exchange")
+
+        # the scope again: a ``shard_map``'s body does not always carry the
+        # caller's name stack to its instructions (inside the loop over
+        # ranks the compiled step's ``op_name`` starts anew)
+        @jax.named_scope("moe")
         def per_shard(x, top_i, top_w, gate_w, up_w, down_w):
             tokens = (x.reshape(-1, D), top_i.reshape(-1, k),
                       top_w.reshape(-1, k))
@@ -436,23 +454,30 @@ class MoEMLP(nn.Module):
                 # rows is alive (the backward pass recomputes each in its
                 # turn), at the extent that rank's routing asks for
                 @jax.checkpoint
+                @jax.named_scope("moe")
                 def one_rank(its_tokens):
                     out, rows, held = local_experts(
                         *its_tokens, gate_w, up_w, down_w, first,
                         cfg.num_experts)
                     return out.astype(cfg.dtype), rows, held
 
-                out, rows, held = jax.lax.map(one_rank, [
-                    jax.lax.all_gather(t, "ep", axis=0) for t in tokens])
-                out = jax.lax.psum_scatter(out, "ep", scatter_dimension=0)
-                held, live = held.sum(), rows.sum()
-                rows = jax.lax.all_gather(
-                    rows.sum(axis=0), "ep", axis=0, tiled=True)
-            if others:
-                rows = jax.lax.psum(rows, others)
-            counts = jnp.stack([held, live.astype(held.dtype)])[None]
-            if chips:
-                counts = jax.lax.all_gather(counts, chips, axis=0, tiled=True)
+                with exchange:
+                    gathered = [jax.lax.all_gather(t, "ep", axis=0)
+                                for t in tokens]
+                out, rows, held = jax.lax.map(one_rank, gathered)
+                with exchange:
+                    out = jax.lax.psum_scatter(
+                        out, "ep", scatter_dimension=0)
+                    held, live = held.sum(), rows.sum()
+                    rows = jax.lax.all_gather(
+                        rows.sum(axis=0), "ep", axis=0, tiled=True)
+            with exchange:
+                if others:
+                    rows = jax.lax.psum(rows, others)
+                counts = jnp.stack([held, live.astype(held.dtype)])[None]
+                if chips:
+                    counts = jax.lax.all_gather(
+                        counts, chips, axis=0, tiled=True)
             return (out.reshape(x.shape), rows,
                     counts[:, 0].sum(), counts[:, 1])
 

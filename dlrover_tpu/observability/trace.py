@@ -47,6 +47,7 @@ import contextvars
 import dataclasses
 import os
 import random
+import re
 import sys
 import threading
 import time
@@ -464,6 +465,189 @@ def note_trace_time(name: str, **attrs: Any) -> None:
         with span(name, attrs=attrs):
             pass
     logger.info("%s %s", name, " ".join(f"{k}={v}" for k, v in attrs.items()))
+
+
+# ---------------------------------------------------------------------------
+# Device scopes: which layer of the program asked for each instruction of a
+# compiled program.  Flax pushes every module's name onto JAX's name stack,
+# the program adds ``jax.named_scope`` where no module stands (the loss, the
+# optimizer's pass, the parts of an attention or of the routed block), and
+# the compiler carries the stack to each instruction as ``op_name``.  The
+# table below is the one place that says what a name means.
+# ---------------------------------------------------------------------------
+
+#: a scope's name, as it stands in an ``op_name`` -> the kind of work it is.
+#: The innermost scope of a path that is listed here decides.  Module names
+#: are parameter names, so the table follows them; what has no module has a
+#: ``jax.named_scope`` of the kind's own name.
+SCOPE_KINDS: Dict[str, str] = {
+    "embed": "embed",
+    "input_norm": "norm", "post_attn_norm": "norm", "final_norm": "norm",
+    "ln_1": "norm", "ln_2": "norm", "ln_f": "norm",
+    "attn": "attn.proj",
+    "attn.core": "attn.core",
+    "mlp": "mlp",
+    "moe": "moe",
+    "lm_head": "head_loss", "head_loss": "head_loss",
+    "optimizer": "optimizer",
+    "grad_sync": "grad_sync",
+}
+
+#: the parts of a kind that have a scope of their own inside it (no name
+#: twice: a sub-scope says whose it is)
+SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
+    "attn.core": ("scores", "select", "selected", "index_loss",
+                  "pool", "windows", "summary_mass"),
+    "moe": ("route", "sort", "gmm", "exchange", "combine"),
+}
+
+_SUB_SCOPE_OF = {sub: kind for kind, subs in SUB_SCOPES.items()
+                 for sub in subs}
+
+#: names the compiler gives an instruction in place of a path: the grouped
+#: matmul ``jax.lax.ragged_dot`` becomes, which only the routed block asks for
+COMPILER_NAMES: Dict[str, Tuple[str, str]] = {
+    "ragged-dot-none": ("moe", "gmm"),
+    "ragged-dot-metadata": ("moe", "gmm"),
+}
+
+OTHER, UNNAMED = "other", "unnamed"
+FORWARD, REMAT, BACKWARD = "forward", "remat", "backward"
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%?[\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPERAND = re.compile(r"%[\w.\-]+")
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+
+
+def unwrapped(part: str) -> str:
+    """One component of an ``op_name`` without the transformations around
+    it: a transformation wraps the scope it was applied under
+    (``jvp(head_loss)``, ``transpose(jvp(LlamaForCausalLM))``).  What
+    ``jit(..)`` wraps is a function's name, not a scope: left as it is."""
+    wrapped = _WRAPPED.match(part)
+    while wrapped and wrapped.group(1) not in ("jit", "pjit"):
+        part = wrapped.group(2)
+        wrapped = _WRAPPED.match(part)
+    return part
+
+
+def scope_of(op_name: str) -> Tuple[str, str, str]:
+    """``(kind, sub-scope, pass)`` of one instruction from its ``op_name``.
+
+    The path is split at ``/``; what a transformation adds (``jit(..)``,
+    ``while``, ``body``, ``closed_call``, ``checkpoint``, ...) names no
+    layer and is skipped simply by not being in :data:`SCOPE_KINDS``;
+    ``jvp(..)``, ``transpose(..)``, ``vmap(..)`` are taken off the scope
+    they wrap; the last component is the primitive.  ``pass``:
+    ``remat`` under ``rematted_computation`` (the forward pass computed
+    again inside the backward), else ``backward`` under ``transpose(``, else
+    ``forward``.  A path none of whose scopes has a kind is ``other``; no
+    path at all (no ``op_name``, a bare name the compiler made up, or an
+    argument's name on the copy that changes its layout) is ``unnamed``,
+    with no pass."""
+    # a fusion of several operations may list their paths, ``;`` between:
+    # the first is its root's
+    op_name = op_name.partition(";")[0]
+    if op_name in COMPILER_NAMES:
+        return COMPILER_NAMES[op_name] + (FORWARD,)
+    if "/" not in op_name:
+        return UNNAMED, "", ""
+    if "rematted_computation" in op_name:
+        which = REMAT
+    elif "transpose(" in op_name:
+        which = BACKWARD
+    else:
+        which = FORWARD
+    kind, sub = OTHER, ""
+    for part in op_name.split("/")[:-1]:
+        part = unwrapped(part)
+        if part in SCOPE_KINDS:
+            kind, sub = SCOPE_KINDS[part], ""
+        elif part in SUB_SCOPES.get(kind, ()):
+            sub = part
+        elif kind == OTHER and part in _SUB_SCOPE_OF:
+            # the path starts anew below its kind's scope (the body of a
+            # reduction keeps only the innermost names)
+            kind, sub = _SUB_SCOPE_OF[part], part
+    return kind, sub, which
+
+
+class DeviceScopes(NamedTuple):
+    """What :func:`device_scopes` returns; instruction names as the device
+    trace has them (``%fusion.281``)."""
+
+    #: instruction -> (kind, sub-scope, pass)
+    scopes: Dict[str, Tuple[str, str, str]]
+    #: instruction -> its first operand's name
+    first_operand: Dict[str, str]
+    #: instruction -> the instructions that take it as an operand
+    users: Dict[str, Tuple[str, ...]]
+
+
+def parse_device_scopes(hlo_text: str) -> DeviceScopes:
+    """One pass over a compiled program's text (``compiled.as_text()``):
+    every instruction's scope from its ``metadata={op_name=...}``, with its
+    first operand and its users, so that an instruction the compiler left
+    without a path (a layout ``copy``) can be given its neighbour's."""
+    scopes, first_operand, users = {}, {}, {}
+    for line in hlo_text.split("\n"):
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        name = found.group(1)
+        name = name if name.startswith("%") else "%" + name
+        body = line[found.end():]
+        # operands stand between the opcode's bracket and the attributes;
+        # a name inside the attributes (``calls=%fused_computation``) comes
+        # after every operand, so the first name is the first operand
+        operands = _OPERAND.findall(body.partition("), ")[0])
+        if operands:
+            first_operand[name] = operands[0]
+            for operand in dict.fromkeys(operands):
+                users.setdefault(operand, []).append(name)
+        op_name = _OP_NAME.search(body)
+        scopes[name] = scope_of(op_name.group(1) if op_name else "")
+    return DeviceScopes(
+        scopes, first_operand, {k: tuple(v) for k, v in users.items()})
+
+
+#: name -> a function that returns the compiled program's text, left by
+#: whoever compiled it (``Trainer``: ``trainer.step``), and what it gave
+_scope_thunks: Dict[str, Callable[[], str]] = {}
+_scope_maps: Dict[str, DeviceScopes] = {}
+
+
+def register_device_scopes(name: str, compiled_text: Callable[[], str]) -> None:
+    """Leave the way to a compiled program's text under ``name``.  Nothing
+    runs here; a later registration under the name replaces the earlier."""
+    _scope_thunks[name] = compiled_text
+    _scope_maps.pop(name, None)
+
+
+def device_scopes(name: str) -> Optional[DeviceScopes]:
+    """The scope of every instruction of the program registered as ``name``
+    (``None`` where none is).  Evaluated on request only, once: the thunk
+    fetches the program's text (``Trainer``'s asks JAX for the executable it
+    already holds: nothing is lowered or compiled again) and one pass reads
+    it, a fraction of a second that belongs outside any measured step.  One
+    ``<name>.scopes`` record says what it found and what it cost."""
+    if name in _scope_maps:
+        return _scope_maps[name]
+    thunk = _scope_thunks.get(name)
+    if thunk is None:
+        return None
+    t0 = time.time()
+    text = thunk()
+    t_text = time.time()
+    found = _scope_maps[name] = parse_device_scopes(text)
+    kinds = sorted({kind for kind, _, _ in found.scopes.values()})
+    note_trace_time(
+        name + ".scopes", instructions=len(found.scopes),
+        named=sum(kind != UNNAMED for kind, _, _ in found.scopes.values()),
+        kinds=",".join(kinds), text_s=round(t_text - t0, 3),
+        parse_s=round(time.time() - t_text, 3))
+    return found
 
 
 def span(name: str, kind: str = INTERNAL,

@@ -132,9 +132,14 @@ def flash_attention(
     from dlrover_tpu.parallel.collectives import shard_map_unchecked
 
     def per_shard(q_, k_, v_):
-        # a device trace names a kernel by the innermost scope around it,
-        # which the shard_map would make "shard_map": keep the name the
-        # unsharded call has from its module (``attn._attend``)
+        # the compiler names a kernel's instruction after the innermost
+        # scope around its call, which the shard_map would make
+        # "shard_map": keep ``_attend``, by which the by-shape reader
+        # ``fa2_ms_per_step`` finds the unsharded call (``attn._attend``).
+        # What the kernel is FOR is read off the whole path, whose
+        # ``attn.core`` the model puts around ``_attend``
+        # (``observability/trace.py::scope_of``): new scopes go around
+        # that name, never between it and the call
         with jax.named_scope("shard._attend"):
             return kernel(q_, k_, v_)
 
@@ -282,9 +287,11 @@ def _attend_selected(q, k, v, index_q, index_k, index_w, keep):
     with ``p`` the attention's own probabilities averaged over the heads,
     under ``stop_gradient``.  Rematerialised, the index scores with it: the
     backward pass holds one block's ``[heads, q, keys]`` scores at a time."""
-    out, target = _dense_selected(q, k, v, keep)
-    return out, _index_kl(index_q, index_k, index_w, keep,
-                          jax.lax.stop_gradient(target))
+    with jax.named_scope("selected"):
+        out, target = _dense_selected(q, k, v, keep)
+    with jax.named_scope("index_loss"):
+        return out, _index_kl(index_q, index_k, index_w, keep,
+                              jax.lax.stop_gradient(target))
 
 
 def _attend_selected_kernels(q, k, v, index_q, index_k, index_w, keep,
@@ -296,9 +303,11 @@ def _attend_selected_kernels(q, k, v, index_q, index_k, index_w, keep,
     index scores, keeps ``target``."""
     from dlrover_tpu.ops.pallas.selected_attention import selected_attention
 
-    out, target = selected_attention(q, k, v, keep, tiling, interpret)
-    return out, jax.checkpoint(_index_kl)(
-        index_q, index_k, index_w, keep, jax.lax.stop_gradient(target))
+    with jax.named_scope("selected"):
+        out, target = selected_attention(q, k, v, keep, tiling, interpret)
+    with jax.named_scope("index_loss"):
+        return out, jax.checkpoint(_index_kl)(
+            index_q, index_k, index_w, keep, jax.lax.stop_gradient(target))
 
 
 def selected_attend_path(backend: str, block: int, head_dim: int, heads: int,
@@ -330,9 +339,11 @@ def _attend_block(q, k, v, index_q, index_k, index_w, *, first, topk, tiling):
     if last <= topk:
         keep = jnp.broadcast_to(causal, (B, block, last))
     else:
-        keep, low_margin = select_top_keys(
-            jax.lax.stop_gradient(_index_scores(*index)), causal, topk)
-        low = low_margin.sum(dtype=jnp.float32)
+        with jax.named_scope("scores"):
+            scores = jax.lax.stop_gradient(_index_scores(*index))
+        with jax.named_scope("select"):
+            keep, low_margin = select_top_keys(scores, causal, topk)
+            low = low_margin.sum(dtype=jnp.float32)
     if tiling is None:
         out, kl = _attend_selected(q, k, v, *index, keep)
     else:
@@ -440,7 +451,8 @@ def _eva_window(q, k, v, pooled_k, pooled_v):
     logits = jnp.where(allowed, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), values)
-    return out, jax.lax.stop_gradient(probs[..., W:].sum())
+    with jax.named_scope("summary_mass"):
+        return out, jax.lax.stop_gradient(probs[..., W:].sum())
 
 
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret"))
@@ -464,9 +476,11 @@ def _eva_window_kernels(q, k, v, pooled_k, pooled_v, *, block_kv,
         return out, jnp.float32(0)
     out, lse = masked_attention(
         q, k, v, keep, (pooled_k, pooled_v), block_kv, interpret)
-    on_summaries = jnp.einsum("bqhd,bkhd->bhqk", q, pooled_k,
-                              preferred_element_type=jnp.float32) * D ** -0.5
-    mass = jnp.exp(on_summaries - lse[..., None]).sum()
+    with jax.named_scope("summary_mass"):
+        on_summaries = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, pooled_k,
+            preferred_element_type=jnp.float32) * D ** -0.5
+        mass = jnp.exp(on_summaries - lse[..., None]).sum()
     return out, jax.lax.stop_gradient(mass)
 
 
@@ -525,15 +539,19 @@ def eva_attention(q, k, v, mu, phi, window, chunk):
         "attention.path", impl="eva", seq=S, window=window, chunk=chunk,
         windows=windows, summaries_max=(windows - 1) * per_window, heads=H,
         head_dim=D, **path)
-    pooled_k, pooled_v, largest = eva_pool(k, v, mu, phi, chunk)
+    with jax.named_scope("pool"):
+        pooled_k, pooled_v, largest = eva_pool(k, v, mu, phi, chunk)
     outs, mass = [], jnp.float32(0)
-    for w in range(windows):
-        own = slice(w * window, (w + 1) * window)
-        out, on_summaries = attend_window(
-            q[:, own], k[:, own], v[:, own],
-            pooled_k[:, :w * per_window], pooled_v[:, :w * per_window])
-        outs.append(out)
-        mass = mass + on_summaries
+    # the windows' slicing, the kernels and the gathering of their outputs;
+    # the mass on summaries has its own scope inside a window's body
+    with jax.named_scope("windows"):
+        for w in range(windows):
+            own = slice(w * window, (w + 1) * window)
+            out, on_summaries = attend_window(
+                q[:, own], k[:, own], v[:, own],
+                pooled_k[:, :w * per_window], pooled_v[:, :w * per_window])
+            outs.append(out)
+            mass = mass + on_summaries
+        out = jnp.concatenate(outs, axis=1)
     later_queries = B * H * (S - window)
-    return (jnp.concatenate(outs, axis=1), mass / max(later_queries, 1),
-            largest.mean())
+    return out, mass / max(later_queries, 1), largest.mean()

@@ -718,21 +718,24 @@ class Trainer:
             {"params": params}, batch["input_ids"],
             mutable=["losses", "stats"],
         )
-        loss = cross_entropy_loss(logits, batch["labels"], batch.get("mask"))
-        for term in jax.tree.leaves(sown.get("losses", {})):
-            loss = loss + jnp.sum(term)
+        with jax.named_scope("head_loss"):
+            loss = cross_entropy_loss(
+                logits, batch["labels"], batch.get("mask"))
+            for term in jax.tree.leaves(sown.get("losses", {})):
+                loss = loss + jnp.sum(term)
         return loss, sown.get("stats", {})
 
     def _loss_and_grads(self, params, batch):
         """``((loss, stats), grads)``, optionally w.r.t. a low-precision
         param view."""
         if self.grads_dtype is not None:
-            params = jax.tree.map(
-                lambda p: p.astype(self.grads_dtype)
-                if jnp.issubdtype(p.dtype, jnp.floating)
-                else p,
-                params,
-            )
+            with jax.named_scope("optimizer"):
+                params = jax.tree.map(
+                    lambda p: p.astype(self.grads_dtype)
+                    if jnp.issubdtype(p.dtype, jnp.floating)
+                    else p,
+                    params,
+                )
         return jax.value_and_grad(self._loss_fn, has_aux=True)(params, batch)
 
     def _grad_fn(self, params, batch):
@@ -754,29 +757,31 @@ class Trainer:
             loss_sum, grad_sum, w_sum = self._accumulate_scan(
                 state.params, batch
             )
-            w_sum = jnp.maximum(w_sum, 1e-8)
-            loss = loss_sum / w_sum
-            grads = jax.tree.map(
-                lambda g: g / w_sum.astype(g.dtype), grad_sum
-            )
-
-        grad_norm = optax.global_norm(grads)
-        if self.grad_sync.clip_norm is not None:
-            # policy-level clipping also applies on the exact path, so a
-            # GradSyncPolicy(clip_norm=...) job behaves identically when
-            # the dp world (elastically) collapses to 1
-            scale = jnp.minimum(
-                1.0, self.grad_sync.clip_norm / jnp.maximum(
-                    grad_norm, 1e-12
+            with jax.named_scope("optimizer"):
+                w_sum = jnp.maximum(w_sum, 1e-8)
+                loss = loss_sum / w_sum
+                grads = jax.tree.map(
+                    lambda g: g / w_sum.astype(g.dtype), grad_sum
                 )
+
+        with jax.named_scope("optimizer"):
+            grad_norm = optax.global_norm(grads)
+            if self.grad_sync.clip_norm is not None:
+                # policy-level clipping also applies on the exact path, so
+                # a GradSyncPolicy(clip_norm=...) job behaves identically
+                # when the dp world (elastically) collapses to 1
+                scale = jnp.minimum(
+                    1.0, self.grad_sync.clip_norm / jnp.maximum(
+                        grad_norm, 1e-12
+                    )
+                )
+                grads = jax.tree.map(
+                    lambda g: g * scale.astype(g.dtype), grads
+                )
+            updates, opt_state = self.optimizer.update(
+                grads, state.opt_state, state.params
             )
-            grads = jax.tree.map(
-                lambda g: g * scale.astype(g.dtype), grads
-            )
-        updates, opt_state = self.optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+            params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1, params=params, opt_state=opt_state
         )
@@ -821,17 +826,18 @@ class Trainer:
             mb = microbatch(i, batch)
             w = self._mb_weight(mb, micro)
             loss, grads = self._grad_fn(params, mb)
-            return (
-                loss_sum + loss * w,
-                # keep the multiply in the accumulator dtype: a bf16
-                # grad times an fp32 scalar would silently promote
-                # the whole accumulated pytree back to fp32
-                jax.tree.map(
-                    lambda a, g: a + g.astype(a.dtype) * w.astype(a.dtype),
-                    grad_sum, grads,
-                ),
-                w_sum + w,
-            ), None
+            with jax.named_scope("optimizer"):
+                return (
+                    loss_sum + loss * w,
+                    # keep the multiply in the accumulator dtype: a bf16
+                    # grad times an fp32 scalar would silently promote
+                    # the whole accumulated pytree back to fp32
+                    jax.tree.map(
+                        lambda a, g: a + g.astype(a.dtype) * w.astype(a.dtype),
+                        grad_sum, grads,
+                    ),
+                    w_sum + w,
+                ), None
 
         # fp32 accumulator by default even for bf16 grads: repeated
         # bf16 summation loses late-microbatch contributions as the
@@ -890,73 +896,78 @@ class Trainer:
         loss_sum, grad_sum, w_sum = self._accumulate_local(
             state.params, batch
         )
-        w_global = jnp.maximum(lax.psum(w_sum, reduce_axes), 1e-8)
-        loss = lax.psum(loss_sum, reduce_axes) / w_global
-        ghat = jax.tree.map(
-            lambda g: g.astype(jnp.float32) / w_global, grad_sum
-        )
-        key = None
-        if policy.rounding == "stochastic":
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(policy.seed), state.step
+        # what the sync path adds to the exact one: the reduce of the
+        # replicas' sums and the (quantized) exchange of the gradients
+        with jax.named_scope("grad_sync"):
+            w_global = jnp.maximum(lax.psum(w_sum, reduce_axes), 1e-8)
+            loss = lax.psum(loss_sum, reduce_axes) / w_global
+            ghat = jax.tree.map(
+                lambda g: g.astype(jnp.float32) / w_global, grad_sum
             )
-            key = jax.random.fold_in(key, lax.axis_index(reduce_axes))
-        if self._dcn_axis is not None and self._bucket_layout is not None:
-            # r18 two-level path: quantized ICI reduce-scatter within
-            # the slice, ONE aggregated heavier-quantized DCN exchange
-            # across slices, and (below) an intra-slice all-gather —
-            # cross-slice bytes drop by the in-slice dp factor
-            synced, new_ef = collectives.sync_gradient_tree_hierarchical(
-                ghat, state.ef_residual, layout, self._bucket_layout,
-                policy, axis, self._dcn_axis, self._dcn_world, key,
-                plan=self._tuner_plan,
-            )
-        elif self._dcn_axis is not None:
-            # hierarchical mesh but zero shardable leaves (no bucket
-            # layout): every leaf rides the exact psum over both axes
-            synced, new_ef = collectives.sync_gradient_tree(
-                ghat, state.ef_residual, layout, policy, reduce_axes,
-                key,
-            )
-        elif self._bucket_layout is not None:
-            # overlapped path: one fused collective per bucket, every
-            # bucket's chain independent — the scheduler hides the
-            # exchange behind remaining backward/quantize compute
-            synced, new_ef = collectives.sync_gradient_tree_bucketed(
-                ghat, state.ef_residual, layout, self._bucket_layout,
-                policy, axis, key, plan=self._tuner_plan,
-            )
-        else:
-            synced, new_ef = collectives.sync_gradient_tree(
-                ghat, state.ef_residual, layout, policy, axis, key
-            )
-        grad_norm = collectives.global_grad_norm(synced, layout, axis)
-        if policy.clip_norm is not None:
-            scale = jnp.minimum(
-                1.0, policy.clip_norm / jnp.maximum(grad_norm, 1e-12)
-            )
-            synced = jax.tree.map(lambda g: g * scale, synced)
-        if self._bucket_layout is not None:
-            def gather(tree):
-                return collectives.all_gather_tree_bucketed(
-                    tree, layout, self._bucket_layout, axis
+            key = None
+            if policy.rounding == "stochastic":
+                key = jax.random.fold_in(
+                    jax.random.PRNGKey(policy.seed), state.step
                 )
-        else:
-            def gather(tree):
+                key = jax.random.fold_in(key, lax.axis_index(reduce_axes))
+            if self._dcn_axis is not None and self._bucket_layout is not None:
+                # r18 two-level path: quantized ICI reduce-scatter within
+                # the slice, ONE aggregated heavier-quantized DCN exchange
+                # across slices, and (below) an intra-slice all-gather —
+                # cross-slice bytes drop by the in-slice dp factor
+                synced, new_ef = collectives.sync_gradient_tree_hierarchical(
+                    ghat, state.ef_residual, layout, self._bucket_layout,
+                    policy, axis, self._dcn_axis, self._dcn_world, key,
+                    plan=self._tuner_plan,
+                )
+            elif self._dcn_axis is not None:
+                # hierarchical mesh but zero shardable leaves (no bucket
+                # layout): every leaf rides the exact psum over both axes
+                synced, new_ef = collectives.sync_gradient_tree(
+                    ghat, state.ef_residual, layout, policy, reduce_axes,
+                    key,
+                )
+            elif self._bucket_layout is not None:
+                # overlapped path: one fused collective per bucket, every
+                # bucket's chain independent — the scheduler hides the
+                # exchange behind remaining backward/quantize compute
+                synced, new_ef = collectives.sync_gradient_tree_bucketed(
+                    ghat, state.ef_residual, layout, self._bucket_layout,
+                    policy, axis, key, plan=self._tuner_plan,
+                )
+            else:
+                synced, new_ef = collectives.sync_gradient_tree(
+                    ghat, state.ef_residual, layout, policy, axis, key
+                )
+        with jax.named_scope("optimizer"):
+            grad_norm = collectives.global_grad_norm(synced, layout, axis)
+            if policy.clip_norm is not None:
+                scale = jnp.minimum(
+                    1.0, policy.clip_norm / jnp.maximum(grad_norm, 1e-12)
+                )
+                synced = jax.tree.map(lambda g: g * scale, synced)
+
+        def gather(tree):
+            with jax.named_scope("grad_sync"):
+                if self._bucket_layout is not None:
+                    return collectives.all_gather_tree_bucketed(
+                        tree, layout, self._bucket_layout, axis
+                    )
                 return collectives.all_gather_tree(tree, layout, axis)
+
+        def update(grads, params):
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, state.opt_state, params
+                )
+                return optax.apply_updates(params, updates), opt_state
+
         if policy.sharded_update:
-            p_shards = collectives.shard_like(state.params, layout, axis)
-            updates, opt_state = self.optimizer.update(
-                synced, state.opt_state, p_shards
-            )
-            new_shards = optax.apply_updates(p_shards, updates)
+            new_shards, opt_state = update(
+                synced, collectives.shard_like(state.params, layout, axis))
             params = gather(new_shards)
         else:
-            full = gather(synced)
-            updates, opt_state = self.optimizer.update(
-                full, state.opt_state, state.params
-            )
-            params = optax.apply_updates(state.params, updates)
+            params, opt_state = update(gather(synced), state.params)
         new_state = state.replace(
             step=state.step + 1,
             params=params,
@@ -1038,11 +1049,29 @@ class Trainer:
         """The step program lowered (not compiled) for these arguments,
         arrays or ``ShapeDtypeStruct``s alike: ``.as_text()`` shows
         whether the kernel is in it, ``.compile()`` what the chip's
-        compiler makes of it."""
+        compiler makes of it.  Its second user is ``trace.device_scopes``
+        (``_leave_device_scopes``), which reads each instruction's scope
+        off the compiled text."""
         if self._jit_step is None:
             self.compile_train_step()
         with self.mesh:
             return self._jit_step.lower(state, batch)
+
+    def _leave_device_scopes(self, state, batch):
+        """Leave with ``trace.device_scopes("trainer.step")`` the way to
+        the compiled step's text: the same program lowered for the same
+        abstract arguments (shapes, dtypes and shardings; no array is
+        held).  Nothing is lowered or compiled here: whoever asks pays, and
+        finds the executable in JAX's caches."""
+        abstract = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
+            (state, batch),
+        )
+        trace.register_device_scopes(
+            "trainer.step",
+            lambda: self.lower_train_step(*abstract).compile().as_text(),
+        )
 
     def _dispatch(self, state, batch, compiled: bool = False):
         # ``compiled``: the first call of a program, which compiles it
@@ -1119,6 +1148,7 @@ class Trainer:
                 from dlrover_tpu.utils.timing import hard_block
 
                 compile_t0 = _time.time()
+                self._leave_device_scopes(state, batch)
                 result = self._dispatch(state, batch, compiled=True)
                 hard_block(result)
             try:
