@@ -12,14 +12,16 @@ the two for a model that has no opinion (the GPT family): from the backend
 and the shape, once, while the step is traced.
 ``indexed_sparse_attention`` (at the end) is the attention of a model
 whose configuration carries an indexer: each query attends to the keys a
-learned scorer ranks highest, by blocks of queries: index scores,
-selection and loss in ``jax.numpy``, the attention over the selection in
-Pallas kernels on a TPU (``ops/pallas/selected_attention.py``) and in
-``jax.numpy`` elsewhere.  ``eva_attention`` (after it) is the attention of
-a model whose queries see the keys of their own window exactly and every
-earlier window through learned summaries of its chunks, under one softmax:
-a window of queries at a time, through the same kernels' other entry point
-on a TPU and in ``jax.numpy`` elsewhere.
+learned scorer ranks highest, by blocks of queries: on a TPU the index
+scores with their gradient and the attention over the selection in Pallas
+kernels (``ops/pallas/index_scores.py``, ``selected_attention.py``), both
+in ``jax.numpy`` elsewhere; the selection's searches and the elementwise
+part of the indexer's loss in ``jax.numpy`` everywhere.  ``eva_attention``
+(after it) is the attention of a model whose queries see the keys of their
+own window exactly and every earlier window through learned summaries of
+its chunks, under one softmax: a window of queries at a time, through the
+selected attention's kernels' other entry point on a TPU and in
+``jax.numpy`` elsewhere.
 """
 
 import functools
@@ -269,11 +271,11 @@ def _dense_selected(q, k, v, keep):
     return out.reshape(B, Q, H, D), probs.mean(axis=(1, 2))
 
 
-def _index_kl(index_q, index_k, index_w, keep, target):
+def _index_kl(index_scores, keep, target):
     """Sum over a block's queries of ``KL(target || softmax over the kept
-    keys of the index scores)``."""
+    keys of the index scores)``: elementwise work and row sums over ``[B,
+    q, keys]``, the same on every path."""
     lowest = jnp.finfo(jnp.float32).min
-    index_scores = _index_scores(index_q, index_k, index_w)
     log_index = jax.nn.log_softmax(jnp.where(keep, index_scores, lowest), axis=-1)
     kl = jnp.where(keep, jax.scipy.special.xlogy(target, target)
                    - target * log_index, 0.0)
@@ -281,33 +283,42 @@ def _index_kl(index_q, index_k, index_w, keep, target):
 
 
 @jax.checkpoint
-def _attend_selected(q, k, v, index_q, index_k, index_w, keep):
+def _attend_selected(q, k, v, index_scores, keep):
     """One block of queries over the keys ``keep`` allows: ``(out [B, q, H,
     D], sum over the block's queries of KL(p || softmax(index scores)))``
     with ``p`` the attention's own probabilities averaged over the heads,
-    under ``stop_gradient``.  Rematerialised, the index scores with it: the
-    backward pass holds one block's ``[heads, q, keys]`` scores at a time."""
+    under ``stop_gradient``.  Rematerialised: the backward pass holds one
+    block's ``[heads, q, keys]`` scores at a time."""
     with jax.named_scope("selected"):
         out, target = _dense_selected(q, k, v, keep)
     with jax.named_scope("index_loss"):
-        return out, _index_kl(index_q, index_k, index_w, keep,
+        return out, _index_kl(index_scores, keep,
                               jax.lax.stop_gradient(target))
 
 
-def _attend_selected_kernels(q, k, v, index_q, index_k, index_w, keep,
-                             tiling, interpret=False):
+def _attend_selected_kernels(q, k, v, index_scores, keep, tiling,
+                             interpret=False):
     """``_attend_selected`` with the attention and the heads' mean
     probabilities in Pallas kernels: no ``[heads, q, keys]`` tensor leaves
     the chip's fast memory, forward or backward.  The kernels' custom
-    gradient keeps ``out`` and the LSE; the loss, rematerialised with its
-    index scores, keeps ``target``."""
+    gradient keeps ``out`` and the LSE; the loss, rematerialised, keeps
+    ``target`` and the index scores it was given (16 MB for the last block
+    of 8192 keys), not its softmax."""
     from dlrover_tpu.ops.pallas.selected_attention import selected_attention
 
     with jax.named_scope("selected"):
         out, target = selected_attention(q, k, v, keep, tiling, interpret)
     with jax.named_scope("index_loss"):
         return out, jax.checkpoint(_index_kl)(
-            index_q, index_k, index_w, keep, jax.lax.stop_gradient(target))
+            index_scores, keep, jax.lax.stop_gradient(target))
+
+
+def _index_scores_kernels(index_q, index_k, index_w, tiling, interpret=False):
+    """``_index_scores`` in Pallas kernels, its gradient too: no ``[q,
+    index heads, keys]`` tensor leaves the chip's fast memory."""
+    from dlrover_tpu.ops.pallas.index_scores import index_scores
+
+    return index_scores(index_q, index_k, index_w, tiling, interpret)
 
 
 def selected_attend_path(backend: str, block: int, head_dim: int, heads: int,
@@ -321,33 +332,53 @@ def selected_attend_path(backend: str, block: int, head_dim: int, heads: int,
     return "jnp"
 
 
-@functools.partial(jax.jit, static_argnames=("first", "topk", "tiling"))
-def _attend_block(q, k, v, index_q, index_k, index_w, *, first, topk, tiling):
+def index_scores_path(backend: str, block: int, index_heads: int,
+                      index_dim: int) -> str:
+    """``"pallas"`` or ``"jnp"``: which body computes a block's index
+    scores and their gradient (as ``selected_attend_path``)."""
+    from dlrover_tpu.ops.pallas.index_scores import kernels_take
+
+    if backend == "tpu" and kernels_take(block, index_heads, index_dim):
+        return "pallas"
+    return "jnp"
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "first", "topk", "tiling", "index_tiling"))
+def _attend_block(q, k, v, index_q, index_k, index_w, *, first, topk, tiling,
+                  index_tiling):
     """One block of queries, the ``first``-th of the sequence on, over the
     keys up to its last query: ``(out, the block's sum of KL, its count of
-    queries with a low selection margin)``.  ``tiling`` ``None``: the
-    attention in ``jax.numpy``.  Under ``jax.jit`` so that a program which
+    queries with a low selection margin)``.  The block's index scores are
+    computed once: the selection reads them under ``stop_gradient``, the
+    loss as they are.  ``tiling`` ``None``: the attention in ``jax.numpy``;
+    ``index_tiling`` ``None``: the index scores in ``jax.numpy``, their
+    products rematerialised.  Under ``jax.jit`` so that a program which
     traces the model more than once (its initialisation, a forward pass,
     the step) traces a block's selection, kernels and loss once: on the
     host of a v5e a trace of the 48 kernel calls of one layer takes a second
     and there are five in a benchmark run's set-up."""
     B, block = q.shape[:2]
     last = k.shape[1]
-    index = (index_q, index_k, index_w)
     causal = (jnp.arange(first, last)[:, None] >= jnp.arange(last))[None]
     low = jnp.float32(0)
+    with jax.named_scope("scores"):
+        if index_tiling is None:
+            scores = jax.checkpoint(_index_scores)(index_q, index_k, index_w)
+        else:
+            scores = _index_scores_kernels(
+                index_q, index_k, index_w, index_tiling)
     if last <= topk:
         keep = jnp.broadcast_to(causal, (B, block, last))
     else:
-        with jax.named_scope("scores"):
-            scores = jax.lax.stop_gradient(_index_scores(*index))
         with jax.named_scope("select"):
-            keep, low_margin = select_top_keys(scores, causal, topk)
+            keep, low_margin = select_top_keys(
+                jax.lax.stop_gradient(scores), causal, topk)
             low = low_margin.sum(dtype=jnp.float32)
     if tiling is None:
-        out, kl = _attend_selected(q, k, v, *index, keep)
+        out, kl = _attend_selected(q, k, v, scores, keep)
     else:
-        out, kl = _attend_selected_kernels(q, k, v, *index, keep, tiling)
+        out, kl = _attend_selected_kernels(q, k, v, scores, keep, tiling)
     return out, kl, low
 
 
@@ -370,7 +401,10 @@ def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
     so nothing of size ``heads x S x S`` is ever whole: a block's selection
     is a mask ``[block, keys]``, its scores ``[H, block, keys]`` in
     ``jax.numpy`` and tiles in fast memory in the kernels, which run on a
-    TPU at the shapes they take (``selected_attend_path``)."""
+    TPU at the shapes they take (``selected_attend_path``); so the index
+    heads' products ``[block, J, keys]`` (``index_scores_path``), of which
+    ``I [block, keys]`` alone is ever whole, computed once a block and
+    pass."""
     B, S, H, D = q.shape
     J, C = index_q.shape[2:]
     block = min(block, S)
@@ -384,6 +418,13 @@ def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
 
         tiling = selected_tiling(block, D)
         path.update(block_kv=tiling[0], mean_block_kv=tiling[1])
+    index_tiling = None
+    path["index"] = index_scores_path(jax.default_backend(), block, J, C)
+    if path["index"] == "pallas":
+        from dlrover_tpu.ops.pallas.tuning import index_tiling as tuned
+
+        index_tiling = tuned(block, C)
+        path.update(index_block_kv=index_tiling[0])
     trace.note_trace_time(
         "attention.path", impl="indexed_sparse", seq=S, head_dim=D, heads=H,
         topk=topk, index_heads=J, index_dim=C, block=block,
@@ -395,7 +436,7 @@ def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
         out, kl, low_here = _attend_block(
             q[:, first:last], k[:, :last], v[:, :last],
             index_q[:, first:last], index_k[:, :last], index_w[:, first:last],
-            first=first, topk=topk, tiling=tiling)
+            first=first, topk=topk, tiling=tiling, index_tiling=index_tiling)
         outs.append(out)
         loss, low = loss + kl, low + low_here
     return jnp.concatenate(outs, axis=1), loss / (B * S), low / (B * S)

@@ -37,6 +37,13 @@ only: a head is one 128-lane column block.
 A row of ``keep`` with no key at all reads as the ``jax.numpy`` path
 does (the mean of ``v``); ``indexed_sparse_attention`` never makes one.
 
+Of a block's work in ``indexed_sparse_attention`` these kernels are the
+attention under ``keep`` and the heads' mean; the index scores ``I`` that
+``keep`` is chosen from, and their gradient, are the kernels of
+``index_scores.py``; the threshold searches that make ``keep`` from ``I``
+and the elementwise part of the indexer's loss (a masked ``log_softmax`` of
+``I`` against the heads' mean) stay ``jax.numpy``.
+
 Two entry points over the forward and backward kernels, by what the
 caller's model has: ``selected_attention`` (an indexer to teach: the
 heads' mean with the output) and ``masked_attention`` (none: the output

@@ -163,6 +163,25 @@ def selected_tiling(block_q: int, head_dim: int) -> Tuple[int, int]:
     return DEFAULT_BLOCKS[1], DEFAULT_BLOCKS[1]
 
 
+def index_tiling(block_q: int, index_dim: int) -> Tuple[int, int]:
+    """``(block_kv, unroll)`` of the index scores' kernels
+    (``index_scores.py``) for blocks of ``block_q`` queries at index heads
+    of ``index_dim``: the keys a tile at most, forward and backward, and
+    the 128-lane column blocks of ``q_I`` that are straight-line code a
+    turn of the loop over them.  The table's
+    ``index_q<block_q>_c<index_dim>_kv`` entry (swept in a whole step on
+    the chip by ``scripts/fa_blocks_in_step.py --index``), else the untuned
+    default."""
+    try:
+        entry = _load_table().get(f"index_q{block_q}_c{index_dim}_kv") or {}
+        tiling = int(entry["block_kv"]), int(entry.get("unroll", 1))
+        if min(tiling) > 0:
+            return tiling
+    except (TypeError, KeyError, ValueError):
+        pass
+    return DEFAULT_BLOCKS[1], 1
+
+
 def _current_device_kind() -> str:
     try:
         import jax

@@ -21,6 +21,15 @@ a tile alone (``--blocks kv;kv``: no indexer, no heads' mean)::
     python3 scripts/fa_blocks_in_step.py --config keyevl2_30b_1of8 --selected
     python3 scripts/fa_blocks_in_step.py --config evabyte_l4 --selected
 
+``--index`` sweeps the kernels of the indexer's scores and their gradient
+(``ops/pallas/index_scores.py``; ``--blocks kv,unroll;kv,unroll``: keys a
+tile, and the 128-lane column blocks of ``q_I``, two heads of 64 each, that
+are straight-line code a turn of the loop; the table's
+``index_q<block>_c<index_dim>_kv`` entry), forward and backward told apart
+by their results::
+
+    python3 scripts/fa_blocks_in_step.py --config keyevl2_30b_1of8 --index
+
 Candidates reach the kernel through the table ``DLROVER_TPU_FA_TUNING``
 names, as a user's own table would.  One JSON line a candidate, the
 winner's table entry last.
@@ -38,16 +47,13 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def kernel_seconds(trace_dir):
-    """kind (``fwd``, ``dq``, ``dkv``) -> [events, seconds] of the
-    attention custom calls on the first chip, told apart as the
-    benchmark's ``fa2_ms_per_step`` tells them."""
-    from benchmarks import common
+def _tally(trace_dir, kinds, kind_of):
+    """kind -> [events, seconds] over the operations the first chip ran;
+    ``kind_of(text)`` names an operation's kind, or nothing."""
     from benchmarks import trace as trace_mod
 
-    kind_of = common.load_module("layer_metrics", "fa2_ms_per_step").kind_of
     loaded = trace_mod.load(trace_mod.find_xplane(trace_dir))
-    found = {"fwd": [0, 0.0], "dq": [0, 0.0], "dkv": [0, 0.0]}
+    found = {kind: [0, 0.0] for kind in kinds}
     if loaded.device_ops:
         for name, start, end in loaded.device_ops[min(loaded.device_ops)]:
             kind = kind_of(name)
@@ -55,6 +61,17 @@ def kernel_seconds(trace_dir):
                 found[kind][0] += 1
                 found[kind][1] += end - start
     return found
+
+
+def kernel_seconds(trace_dir):
+    """kind (``fwd``, ``dq``, ``dkv``) -> [events, seconds] of the
+    attention custom calls on the first chip, told apart as the
+    benchmark's ``fa2_ms_per_step`` tells them."""
+    from benchmarks import common
+
+    return _tally(
+        trace_dir, ("fwd", "dq", "dkv"),
+        common.load_module("layer_metrics", "fa2_ms_per_step").kind_of)
 
 
 SELECTED_CALL = re.compile(r"^%[\w.]+ = (.*) custom-call\(.*"
@@ -69,7 +86,6 @@ def selected_kernel_seconds(trace_dir, shape):
     the heads' mean one, the backward three or, with the gradients of the
     keys every query attends to, five."""
     from benchmarks import common
-    from benchmarks import trace as trace_mod
 
     if "window" in shape:
         carries_mask = common.load_module(
@@ -77,19 +93,34 @@ def selected_kernel_seconds(trace_dir, shape):
     else:
         carries_mask = common.load_module(
             "layer_metrics", "sparse_attn_ms_per_step").block_by_keys
-    loaded = trace_mod.load(trace_mod.find_xplane(trace_dir))
-    found = {"fwd": [0, 0.0], "mean": [0, 0.0], "bwd": [0, 0.0]}
-    if loaded.device_ops:
-        for name, start, end in loaded.device_ops[min(loaded.device_ops)]:
-            call = SELECTED_CALL.match(name)
-            if not call or not carries_mask(name, shape):
-                continue
-            result = call.group(1)
-            kind = ("mean" if not result.startswith("(") else
-                    "fwd" if result.count("[") == 2 else "bwd")
-            found[kind][0] += 1
-            found[kind][1] += end - start
-    return found
+
+    def kind_of(name):
+        call = SELECTED_CALL.match(name)
+        if not call or not carries_mask(name, shape):
+            return None
+        result = call.group(1)
+        return ("mean" if not result.startswith("(") else
+                "fwd" if result.count("[") == 2 else "bwd")
+
+    return _tally(trace_dir, ("fwd", "mean", "bwd"), kind_of)
+
+
+def index_kernel_seconds(trace_dir, shape):
+    """kind (``fwd``, ``bwd``) -> [events, seconds] of the index scores'
+    custom calls: they alone carry the index heads' weights ``f32[batch,
+    block, index heads]``, an operand of both and, as ``dw``, a result of
+    the backward, which returns three arrays where the forward returns
+    ``I f32[batch, block, keys]`` alone."""
+    weights = "f32[%d,%d,%d]" % (
+        shape["batch"], shape["block"], shape["index_heads"])
+
+    def kind_of(name):
+        call = SELECTED_CALL.match(name)
+        if not call or weights not in name:
+            return None
+        return "bwd" if call.group(1).startswith("(") else "fwd"
+
+    return _tally(trace_dir, ("fwd", "bwd"), kind_of)
 
 
 def main(argv=None) -> int:
@@ -101,6 +132,9 @@ def main(argv=None) -> int:
     parser.add_argument("--selected", action="store_true",
                         help="sweep the selected attention's kernels; "
                              "--blocks then takes kv,mean_kv;kv,mean_kv")
+    parser.add_argument("--index", action="store_true",
+                        help="sweep the index scores' kernels; --blocks "
+                             "then takes kv,unroll;kv,unroll")
     parser.add_argument("--rehearse", action="store_true",
                         help="tiny sizes, any backend: control flow only")
     args = parser.parse_args(argv)
@@ -132,6 +166,12 @@ def main(argv=None) -> int:
             block_q = shape["window"]
             args.blocks = args.blocks or "512;1024;2048"
         key = f"selected_q{block_q}_d{head_dim}_kv"
+    if args.index:
+        shape = family.sparse_attn_shape(config, batch, seq, args.rehearse)
+        block_q = shape["block"]
+        args.blocks = args.blocks or "1024,1;2048,1;4096,1;2048,2;2048,4"
+        heads, head_dim = shape["index_heads"], shape["index_dim"]
+        key = f"index_q{block_q}_c{head_dim}_kv"
     if args.blocks:
         candidates = [tuple(int(b) for b in pair.split(","))
                       for pair in args.blocks.split(";")]
@@ -144,7 +184,10 @@ def main(argv=None) -> int:
         os.environ["DLROVER_TPU_FA_TUNING"] = table
         for first, *second in candidates:
             second = second[0] if second else first
-            if args.selected:
+            if args.index:
+                line = {"block_q": block_q, "block_kv": first,
+                        "unroll": second}
+            elif args.selected:
                 line = {"block_q": block_q, "block_kv": first}
                 if "block" in shape:   # an indexer: a heads' mean
                     line["mean_block_kv"] = second
@@ -174,7 +217,9 @@ def main(argv=None) -> int:
                     step_s = (time.perf_counter() - t0) / len(sharded)
                 finally:
                     jax.profiler.stop_trace()
-                found = (selected_kernel_seconds(trace_dir, shape)
+                found = (index_kernel_seconds(trace_dir, shape)
+                         if args.index else
+                         selected_kernel_seconds(trace_dir, shape)
                          if args.selected else kernel_seconds(trace_dir))
                 per_step = 1e3 / len(sharded)
                 line.update(
@@ -196,7 +241,7 @@ def main(argv=None) -> int:
                           f"(backend {jax.default_backend()!r})"}))
         return 1
     best = ranked[0]
-    named = ("block_q", "block_kv", "mean_block_kv")
+    named = ("block_q", "block_kv", "mean_block_kv", "unroll")
     runner_up = ("; %d candidates, next best %s at %s" % (
         len(ranked), "x".join(str(ranked[1][n]) for n in named
                               if n in ranked[1]),
@@ -207,7 +252,9 @@ def main(argv=None) -> int:
         "kernel_calls_per_step": int(best["kernel_calls_per_step"]),
         "measured": "kernel time in the device trace of a whole step "
                     f"({args.config}: forward, recomputed forward, backward) "
-                    f"by scripts/fa_blocks_in_step.py{runner_up}",
+                    "by scripts/fa_blocks_in_step.py%s%s" % (
+                        " --index" if args.index else
+                        " --selected" if args.selected else "", runner_up),
         "backend": jax.default_backend(),
         "device_kind": jax.devices()[0].device_kind,
         "date": datetime.date.today().isoformat(),

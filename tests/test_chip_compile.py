@@ -164,6 +164,53 @@ def test_selected_attention_kernels_compile_and_the_reader_sees_them(
     assert all(is_sparse_attn_op(call, shape) for call in calls)
 
 
+@pytest.mark.parametrize("keys", [512, 2560, 8192])
+def test_index_scores_kernels_compile_and_the_reader_sees_them(
+        one_chip, keys):
+    """The Keye cell's indexer: a block of 512 queries, 16 index heads of
+    64 on one key head.  The scores and their gradient are two custom
+    calls; the forward returns ``I f32[1, 512, keys]`` alone and the
+    backward takes ``dI`` of that shape, which is how
+    ``benchmarks/layer_metrics/sparse_attn_ms_per_step.py`` knows both,
+    and both carry the heads' weights ``f32[1, 512, 16]``, which is how
+    ``scripts/fa_blocks_in_step.py --index`` knows them from the selected
+    attention's."""
+    from dlrover_tpu.ops.pallas.index_scores import index_scores, kernels_take
+    from dlrover_tpu.ops.pallas.tuning import index_tiling
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert kernels_take(512, 16, 64)
+    tiling = index_tiling(512, 64)
+
+    def both(index_q, index_k, index_w, weights):
+        def loss(*index):
+            return (index_scores(*index, tiling) * weights).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(
+            index_q, index_k, index_w)
+
+    text = jax.jit(both).lower(
+        sds((1, 512, 16, 64), jnp.bfloat16), sds((1, keys, 64), jnp.bfloat16),
+        sds((1, 512, 16), jnp.float32), sds((1, 512, keys), jnp.float32),
+    ).compile().as_text()
+    calls = [line.strip() for line in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    forward, backward = sorted(calls, key=lambda call: "= (" in call)
+    assert f"= f32[1,512,{keys}]" in forward
+    assert all(f"{dtype}[1,{rows},{cols}]" in backward.split("custom-call")[0]
+               for dtype, rows, cols in (("bf16", 512, 1024),
+                                         ("bf16", keys, 64), ("f32", 512, 16)))
+    is_sparse_attn_op = _load_layer_metric(
+        "sparse_attn_ms_per_step").is_sparse_attn_op
+    shape = {"batch": 1, "block": 512, "seq": 8192}
+    assert len(calls) == 2
+    assert all(is_sparse_attn_op(call, shape) for call in calls)
+    assert all("f32[1,512,16]" in call for call in calls)
+    assert "[1,512,16,%d]" % keys not in text   # no head's products whole
+
+
 @pytest.mark.parametrize(
     "q_shape, kv_heads",
     [((3, 1024, 25, 64), 25), ((2, 1024, 4, 64), 2),

@@ -86,8 +86,8 @@ def _both(case):
     weights = jax.random.normal(ks[7], operands[0].shape)
 
     def run(attend):
-        def loss(*xs):
-            out, kl = attend(*xs, keep)
+        def loss(q, k, v, *index):
+            out, kl = attend(q, k, v, ops._index_scores(*index), keep)
             return (out * weights).sum() + 3.0 * kl, (out, kl)
 
         (_, (out, kl)), grads = jax.value_and_grad(
@@ -174,7 +174,7 @@ def test_on_the_cpu_the_event_says_jnp(monkeypatch):
     assert records == [("attention.path", dict(
         impl="indexed_sparse", seq=256, head_dim=128, heads=4, topk=96,
         index_heads=J, index_dim=C, block=128,
-        select="threshold_by_counting", attend="jnp"))]
+        select="threshold_by_counting", attend="jnp", index="jnp"))]
 
 
 def test_on_a_tpu_the_whole_sequence_goes_through_the_kernels(monkeypatch):
@@ -200,6 +200,7 @@ def test_on_a_tpu_the_whole_sequence_goes_through_the_kernels(monkeypatch):
         functools.partial(ops._attend_selected_kernels, interpret=True))
     (_, got), got_grads = run()
     assert records[0][1]["attend"] == "pallas"
+    assert records[0][1]["index"] == "jnp"   # 2 index heads of 16: no kernel
     assert (records[0][1]["block_kv"], records[0][1]["mean_block_kv"]
             ) == selected_tiling(128, 128)
     for a, b in zip(got + got_grads, want + want_grads):

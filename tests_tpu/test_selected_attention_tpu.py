@@ -1,7 +1,9 @@
 """The selected attention's Pallas kernels on the REAL TPU (compiled by
 Mosaic, not interpreted) against the ``jax.numpy`` body, at the shapes of
 ``keyevl2_30b_1of8.steady``: a block of 512 queries, 32 heads on 4 kv heads
-of 128, bfloat16; and ``indexed_sparse_attention`` whole at S 8192."""
+of 128, bfloat16; the index scores' kernels
+(``ops/pallas/index_scores.py``) against theirs, 16 index heads of 64; and
+``indexed_sparse_attention`` whole at S 8192."""
 
 import functools
 
@@ -11,7 +13,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.ops import attention as ops
-from dlrover_tpu.ops.pallas.tuning import selected_tiling
+from dlrover_tpu.ops.pallas.tuning import index_tiling, selected_tiling
 
 HEADS, KV_HEADS, HEAD_DIM, BLOCK, TOPK = 32, 4, 128, 512, 2048
 J, C = 16, 64
@@ -58,7 +60,7 @@ def test_a_block_forward_and_backward(tpu_backend, last):
 
     def run(attend):
         def loss(q, k, v, *index):
-            out, kl = attend(q, k, v, *index, keep)
+            out, kl = attend(q, k, v, ops._index_scores(*index), keep)
             return (out.astype(jnp.float32) * weights).sum() + kl, (out, kl)
 
         return jax.jit(jax.value_and_grad(
@@ -77,6 +79,64 @@ def test_a_block_forward_and_backward(tpu_backend, last):
             grads, want_grads):
         scale = float(jnp.abs(want.astype(jnp.float32)).max())
         _close(got, want, 4e-2 * scale, f"gradient of {name}")
+
+
+@pytest.mark.parametrize("last", [512, 2560, 8192],
+                         ids=["first", "early", "late"])
+def test_the_index_scores_and_their_gradient(tpu_backend, last):
+    """The kernels against the ``jax.numpy`` body at Keye's widths: the
+    same bfloat16 operands to the matrix unit and float32 after it on both
+    sides, so ``I`` differs by the order of the sum over the heads; the
+    gradients besides by the rounding of ``dI w`` to bfloat16, which the
+    ``jax.numpy`` body's products do too."""
+    *_, index_q, index_k, index_w = _operands(last, last - BLOCK, last, last)
+    index = (index_q, index_k,
+             index_w.astype(jnp.float32) * (J * C) ** -0.5)
+    weights = jax.random.normal(
+        jax.random.PRNGKey(2), (1, BLOCK, last), jnp.float32)
+
+    def run(scores):
+        def loss(*index):
+            out = scores(*index)
+            return (out * weights).sum(), out
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(*index)
+
+    (_, got), grads = run(functools.partial(
+        ops._index_scores_kernels, tiling=index_tiling(BLOCK, C)))
+    (_, want), want_grads = run(ops._index_scores)
+    assert got.dtype == jnp.float32
+    _close(got, want, 1e-5 * float(jnp.abs(want).max()), "I")
+    for name, a, b in zip(("q_I", "k_I", "w"), grads, want_grads):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        scale = float(jnp.abs(b.astype(jnp.float32)).max())
+        _close(a, b, 2e-2 * scale, f"gradient of {name}")
+
+
+def test_the_whole_sequence_by_index_kernels_and_jnp(tpu_backend):
+    """``indexed_sparse_attention`` at S 8192 with the index scores from
+    the kernels and from ``jax.numpy``, the attention through its kernels
+    on both sides.  The selection hangs on the scores' last bits, so a few
+    kept keys differ at the margin: the output and the loss agree to the
+    operands' rounding, the low-margin share to a hundredth."""
+    operands = _operands(8192, None, None, 11)
+    assert ops.index_scores_path(jax.default_backend(), BLOCK, J, C) == "pallas"
+
+    def run():
+        return jax.jit(functools.partial(
+            ops.indexed_sparse_attention, topk=TOPK, block=BLOCK))(*operands)
+
+    out, index_loss, low = run()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ops, "index_scores_path", lambda *a: "jnp")
+        want_out, want_loss, want_low = run()
+    differ = np.abs(np.asarray(out, np.float32)
+                    - np.asarray(want_out, np.float32))
+    assert float(np.median(differ)) <= 1e-3
+    assert float(differ.max()) <= 0.1
+    assert abs(float(index_loss) - float(want_loss)) <= 5e-3 * float(want_loss)
+    assert abs(float(low) - float(want_low)) <= 0.01
 
 
 def test_the_whole_sequence(tpu_backend):
