@@ -16,12 +16,15 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
 """
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from dlrover_tpu.observability import trace
 
 Dtype = Any
 
@@ -73,6 +76,25 @@ class LlamaConfig:
     # ``t`` predicts token ``t + 1 + i``; the model returns the first head's
     # logits and sows the others' loss (EvaByte's ``num_pred_heads``)
     pred_heads: int = 1
+    # the kinds of layer of one period of the stack, repeated to
+    # ``num_layers``: ``"gqa"`` (this file's softmax attention) or ``"kda"``
+    # (a gated delta-rule layer, ``DeltaAttention``).  Empty: one kind, the
+    # softmax attention, and the parameter tree ``layers/layer`` as ever
+    layer_pattern: Tuple[str, ...] = ()
+    # softmax attention without positions (``use_rope`` false) and with an
+    # elementwise sigmoid gate on its output before the output projection
+    # (arXiv:2505.06708; Solar-Open2's ``use_gqa_gate``)
+    use_rope: bool = True
+    attn_gate: bool = False
+    # a ``kda`` layer (Kimi Delta Attention, arXiv:2510.26692): heads of
+    # ``kda_head_dim`` keys and values, a causal depthwise convolution of
+    # ``kda_conv`` taps on q, k and v, ``kda_chunk`` positions a chunk of
+    # ``ops/linear_attention.py::kda``; the decay's and the output gate's
+    # low-rank projections have rank ``kda_head_dim``
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_chunk: int = 64
 
     def __post_init__(self):
         valid = ("reference", "flash", "ring")
@@ -94,6 +116,25 @@ class LlamaConfig:
             raise ValueError(
                 "eva_window needs an eva_chunk that divides it, a key head "
                 "a query head, and no indexer")
+        if self.layer_pattern and (
+                set(self.layer_pattern) - set(LAYER_KINDS)
+                or self.num_layers % len(self.layer_pattern)
+                or ("kda" in self.layer_pattern and not self.kda_heads)):
+            raise ValueError(
+                f"layer_pattern={self.layer_pattern!r}: kinds of "
+                f"{LAYER_KINDS}, a whole number of periods in num_layers="
+                f"{self.num_layers}, and kda_heads where it has a kda layer")
+
+    def layer_runs(self):
+        """One period as runs of equal layers, ``[(name, kind, length)]``:
+        a run is one scan over its stacked parameters, under ``name``."""
+        runs = []
+        for kind in self.layer_pattern:
+            if runs and runs[-1][1] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([f"{kind}_{len(runs)}", kind, 1])
+        return [tuple(run) for run in runs]
 
     def feed_forward(self):
         """The module class of the block after attention, built as
@@ -125,6 +166,10 @@ class LlamaConfig:
         )
         defaults.update(kw)
         return cls(**defaults)
+
+
+#: the kinds a ``layer_pattern`` may name
+LAYER_KINDS = ("gqa", "kda")
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -219,8 +264,9 @@ class Attention(nn.Module):
         k = nn.with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"))
         v = nn.with_logical_constraint(v, ("batch", "seq", "kv_heads", "head_dim"))
 
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if cfg.use_rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
 
         # ``attn.core``: from q, k, v to the attention's output (the kind
         # table of ``observability/trace.py``); what is left under ``attn``
@@ -233,6 +279,15 @@ class Attention(nn.Module):
         else:
             with jax.named_scope("attn.core"):
                 out = self._attend(q, k, v, mask)
+        if cfg.attn_gate:
+            gate = dense(
+                features=(cfg.num_heads, cfg.head_dim),
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(),
+                    ("embed", "heads", "head_dim")),
+                name="gate_proj",
+            )(x)
+            out = out * nn.sigmoid(gate)
         out = nn.with_logical_constraint(
             out, ("batch", "seq", "heads", "head_dim")
         )
@@ -313,7 +368,10 @@ class Attention(nn.Module):
         if cfg.attention_impl == "flash":
             from dlrover_tpu.ops.attention import flash_attention
 
-            return flash_attention(q, k, v, causal=True)
+            # what the layer does around the kernel, on the kernel's line
+            around = {**({} if cfg.use_rope else {"rope": "none"}),
+                      **({"gate": "sigmoid"} if cfg.attn_gate else {})}
+            return flash_attention(q, k, v, causal=True, path_attrs=around)
         if cfg.attention_impl == "ring":
             # NOTE: the ring path is causal-only; the surrounding model
             # always builds a causal mask, and any future padding mask
@@ -339,6 +397,160 @@ class Attention(nn.Module):
         from dlrover_tpu.ops.attention import reference_attention
 
         return reference_attention(q, k, v, mask)
+
+
+def _kda_decay_init(low, high):
+    """``log`` of a value uniform in ``[low, high]`` (fla's ``A_log``: the
+    decay's rate a head, 1 to 16)."""
+    def init(key, shape, dtype):
+        return jnp.log(jax.random.uniform(key, shape, dtype, low, high))
+    return init
+
+
+def _kda_dt_bias_init(dt_min=1e-3, dt_max=0.1, floor=1e-4):
+    """The inverse softplus of a step size log-uniform in ``[dt_min,
+    dt_max]`` (fla's ``dt_bias``, Mamba's): ``softplus(bias)`` is the step."""
+    def init(key, shape, dtype):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype) * (
+            math.log(dt_max) - math.log(dt_min)) + math.log(dt_min))
+        dt = jnp.maximum(dt, floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return init
+
+
+class DeltaAttention(nn.Module):
+    """Kimi Delta Attention (arXiv:2510.26692; flash-linear-attention's
+    ``fla/layers/kda.py``): a gated delta rule with a decay for every
+    channel, in place of softmax attention in a ``kda`` layer.  q, k and v
+    go through a causal depthwise convolution of ``kda_conv`` taps and
+    SiLU; q and k are L2-normalised (q times ``d^-1/2``); the log decay is
+    ``g = -exp(A_log) softplus((h W_f1) W_f2 + dt_bias)`` a channel and
+    ``beta = 2 sigmoid(h w_beta)`` (the 2: negative eigenvalues allowed);
+    the state's read-out is RMS-normalised a head and gated by
+    ``sigmoid((h W_g1) W_g2)`` before the output projection.  The
+    sub-scopes under ``attn.core`` are the kind table's
+    (``observability/trace.py``): ``conv``, ``decay``, ``chunk``, ``state``
+    (both inside ``ops/linear_attention.py::kda``), ``gate``."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask):
+        from dlrover_tpu.ops.linear_attention import kda
+
+        cfg = self.config
+        H, D, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+        dense = partial(nn.DenseGeneral, use_bias=False,
+                        param_dtype=cfg.param_dtype)
+
+        def heads_of(name):
+            return dense(
+                features=(H, D), dtype=cfg.dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(),
+                    ("embed", "heads", "head_dim")),
+                name=name)
+
+        def low_rank(name, dtype=cfg.dtype):
+            """``(x W_1) W_2``: hidden -> ``D`` -> heads x ``D``."""
+            down = dense(
+                features=D, dtype=cfg.dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", None)),
+                name=name + "_down")(x)
+            return dense(
+                features=(H, D), dtype=dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(),
+                    (None, "heads", "head_dim")),
+                name=name + "_up")(down)
+
+        def short_conv(name, t):
+            """SiLU of the causal depthwise convolution: tap ``i`` weighs
+            position ``t - (taps - 1) + i``, zeros before the start."""
+            weight = self.param(
+                name,
+                nn.with_logical_partitioning(
+                    # a depthwise Conv1d's default: uniform in +-1/sqrt(taps)
+                    lambda key, shape, dtype: jax.random.uniform(
+                        key, shape, dtype, -1.0, 1.0) * taps ** -0.5,
+                    (None, "heads", "head_dim")),
+                (taps, H, D), cfg.param_dtype).astype(jnp.float32)
+            padded = jnp.pad(t, ((0, 0), (taps - 1, 0), (0, 0), (0, 0)))
+            S = t.shape[1]
+            return nn.silu(sum(
+                padded[:, i: i + S].astype(jnp.float32) * weight[i]
+                for i in range(taps)))
+
+        def unit(t):
+            return t * jax.lax.rsqrt(
+                jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+        q, k, v = heads_of("q_proj")(x), heads_of("k_proj")(x), heads_of(
+            "v_proj")(x)
+        # the decay and beta steer exponentials: their last projections
+        # give float32 (operands still multiplied in the compute dtype)
+        decay_in = low_rank("f", jnp.float32)
+        beta_in = dense(
+            features=H, dtype=jnp.float32,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ("embed", "heads")),
+            name="beta_proj")(x)
+        rate = self.param(
+            "A_log", nn.with_logical_partitioning(
+                _kda_decay_init(1.0, 16.0), ("heads",)),
+            (H,), cfg.param_dtype)
+        dt_bias = self.param(
+            "dt_bias", nn.with_logical_partitioning(
+                _kda_dt_bias_init(), ("heads", "head_dim")),
+            (H, D), cfg.param_dtype)
+        gate_in = low_rank("g")
+        with jax.named_scope("attn.core"):
+            with jax.named_scope("conv"):
+                q, k, v = (short_conv(name, t) for name, t in (
+                    ("q_conv", q), ("k_conv", k), ("v_conv", v)))
+            with jax.named_scope("decay"):
+                q = (unit(q) * D ** -0.5).astype(cfg.dtype)
+                k = unit(k).astype(cfg.dtype)
+                v = v.astype(cfg.dtype)
+                g = -jnp.exp(rate.astype(jnp.float32))[:, None] * (
+                    jax.nn.softplus(decay_in + dt_bias.astype(jnp.float32)))
+                beta = 2.0 * nn.sigmoid(beta_in)
+                self.sow("stats", "kda_beta_over_one_share",
+                         jnp.mean(beta > 1.0))
+                # how far the state remembers: the median channel's half
+                # life in tokens at its mean decay
+                self.sow("stats", "kda_decay_half_life", jnp.median(
+                    math.log(2.0) / -jnp.mean(g, axis=(0, 1))))
+            q = nn.with_logical_constraint(
+                q, ("batch", "seq", "heads", "head_dim"))
+            trace.note_trace_time(
+                "attention.path", impl="kda", seq=x.shape[1], heads=H,
+                head_dim=D, chunk=min(cfg.kda_chunk, x.shape[1]), conv=taps,
+                state_dtype="float32")
+            out = kda(q, k, v, g, beta, cfg.kda_chunk)
+            with jax.named_scope("gate"):
+                out = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                              "head_dim", name="o_norm")(out)
+                out = out * nn.sigmoid(gate_in)
+        out = nn.with_logical_constraint(
+            out, ("batch", "seq", "heads", "head_dim"))
+        return nn.DenseGeneral(
+            features=x.shape[-1], axis=(-2, -1), use_bias=False,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(),
+                ("heads", "head_dim", "embed")),
+            name="o_proj")(out)
+
+    @staticmethod
+    def num_params(cfg) -> int:
+        H, D = cfg.kda_heads, cfg.kda_head_dim
+        return (4 * cfg.hidden_size * H * D          # q, k, v, o
+                + 2 * (cfg.hidden_size * D + D * H * D)   # f and g, low rank
+                + cfg.hidden_size * H                # beta
+                + 3 * cfg.kda_conv * H * D           # the three convolutions
+                + H + H * D + D)                     # A_log, dt_bias, o_norm
 
 
 class MLP(nn.Module):
@@ -380,16 +592,19 @@ class MLP(nn.Module):
 
 class DecoderLayer(nn.Module):
     config: LlamaConfig
+    #: of ``LAYER_KINDS``: which module stands at ``attn``
+    kind: str = "gqa"
 
     @nn.compact
     def __call__(self, x, positions, mask):
         cfg = self.config
+        attention = DeltaAttention if self.kind == "kda" else Attention
         norm = partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                        unit_offset=cfg.norm_unit_offset)
         # ``x`` is the residual stream, in ``residual_dtype`` where the
         # configuration names one: a branch's result is added in it
         h = norm(name="input_norm")(x)
-        x = x + Attention(cfg, name="attn")(h, positions, mask).astype(x.dtype)
+        x = x + attention(cfg, name="attn")(h, positions, mask).astype(x.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         h = norm(name="post_attn_norm")(x)
         x = x + cfg.feed_forward()(cfg, name="mlp")(h).astype(x.dtype)
@@ -400,10 +615,57 @@ class _ScannedLayer(nn.Module):
     """DecoderLayer wrapped for nn.scan (carry=x, per-layer params)."""
 
     config: LlamaConfig
+    kind: str = "gqa"
 
     @nn.compact
     def __call__(self, x, positions, mask):
-        x = DecoderLayer(self.config, name="layer")(x, positions, mask)
+        x = DecoderLayer(self.config, self.kind, name="layer")(
+            x, positions, mask)
+        return x, None
+
+
+def _stacked(layer_cls, length, axis="layers"):
+    """``layer_cls`` scanned ``length`` times over parameters stacked on a
+    leading axis (its logical name ``axis``: no name twice in one array),
+    the residual stream the carry."""
+    return nn.scan(
+        layer_cls,
+        # what a layer sows (a routed block's loss terms and counts) stacks
+        # on the layer axis beside its parameters
+        variable_axes={"params": 0, "losses": 0, "stats": 0},
+        split_rngs={"params": True},
+        in_axes=nn.broadcast,  # positions/mask shared by all layers
+        length=length,
+        metadata_params={nn.PARTITION_NAME: axis},
+    )
+
+
+def _layer_class(cfg, scanned):
+    """``_ScannedLayer``, rematerialised where the configuration says so."""
+    if not cfg.remat:
+        return _ScannedLayer
+    return nn.remat(
+        _ScannedLayer,
+        prevent_cse=not scanned,
+        static_argnums=(),
+        policy=jax.checkpoint_policies.nothing_saveable,
+    )
+
+
+class _ScannedPeriod(nn.Module):
+    """One period of ``layer_pattern`` wrapped for nn.scan: each run of
+    equal layers a scan of its own inside, so a kind's parameters are
+    stacked ``[periods, run, ...]`` under the run's name and each kind is
+    traced once."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask):
+        cfg = self.config
+        for name, kind, length in cfg.layer_runs():
+            x, _ = _stacked(_layer_class(cfg, True), length)(
+                cfg, kind, name=name)(x, positions, mask)
         return x, None
 
 
@@ -476,31 +738,26 @@ class LlamaForCausalLM(nn.Module):
                 x = x.astype(cfg.residual_dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        # an indexer's attention makes its own masks, a block at a time,
-        # and so does the attention over windows and summaries
-        mask = None if cfg.index_topk or cfg.eva_window else (
-            jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :])
+        # only the reference core reads a mask: an indexer's attention makes
+        # its own, a block at a time, so does the attention over windows and
+        # summaries, the kernel is causal by position and a ``kda`` layer
+        # has no scores
+        mask = None if (
+            cfg.index_topk or cfg.eva_window or cfg.attention_impl == "flash"
+            or (cfg.layer_pattern and "gqa" not in cfg.layer_pattern)
+        ) else jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
 
-        layer_cls = _ScannedLayer
-        if cfg.remat:
-            layer_cls = nn.remat(
-                layer_cls,
-                prevent_cse=not cfg.scan_layers,
-                static_argnums=(),
-                policy=jax.checkpoint_policies.nothing_saveable,
-            )
-        if cfg.scan_layers:
-            x, _ = nn.scan(
-                layer_cls,
-                # what a layer sows (a routed block's loss terms and
-                # counts) stacks on the layer axis beside its parameters
-                variable_axes={"params": 0, "losses": 0, "stats": 0},
-                split_rngs={"params": True},
-                in_axes=nn.broadcast,  # positions/mask shared by all layers
-                length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, name="layers")(x, positions, mask)
+        if cfg.layer_pattern:
+            # a scan over periods, each run of equal layers a scan inside
+            # (``scan_layers`` does not apply: a pattern is always stacked)
+            x, _ = _stacked(
+                _ScannedPeriod, cfg.num_layers // len(cfg.layer_pattern),
+                "periods")(cfg, name="layers")(x, positions, mask)
+        elif cfg.scan_layers:
+            x, _ = _stacked(_layer_class(cfg, True), cfg.num_layers)(
+                cfg, name="layers")(x, positions, mask)
         else:
+            layer_cls = _layer_class(cfg, False)
             for i in range(cfg.num_layers):
                 x, _ = layer_cls(cfg, name=f"layers_{i}")(x, positions, mask)
 
@@ -549,9 +806,14 @@ class LlamaForCausalLM(nn.Module):
                 + cfg.index_head_dim) + 2 * cfg.index_head_dim
         if cfg.eva_window:      # the two pooling vectors a head
             attn += 2 * cfg.num_heads * cfg.head_dim
-        per_layer = attn + cfg.feed_forward_params() + 2 * cfg.hidden_size
+        if cfg.attn_gate:       # the output gate's projection
+            attn += cfg.hidden_size * cfg.num_heads * cfg.head_dim
+        by_kind = {"gqa": attn, "kda": DeltaAttention.num_params(cfg)}
+        kinds = cfg.layer_pattern or ("gqa",)
+        per_period = sum(by_kind[kind] for kind in kinds) + len(kinds) * (
+            cfg.feed_forward_params() + 2 * cfg.hidden_size)
         return (
             cfg.vocab_size * cfg.hidden_size * (1 + cfg.pred_heads)
-            + cfg.num_layers * per_layer
+            + cfg.num_layers // len(kinds) * per_period
             + cfg.hidden_size
         )

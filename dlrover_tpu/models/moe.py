@@ -7,7 +7,10 @@ block in place of the dense SwiGLU; everything else (attention, norms,
 
 Routing (OLMoE, arXiv:2409.02060): ``softmax(x W_r)`` in float32 over all
 experts, the ``top_k`` largest kept with their softmax weights as they are,
-or divided by their sum where the model says so (``norm_topk_prob``).  No
+or divided by their sum where the model says so (``norm_topk_prob``);
+``router_scores="sigmoid"`` scores each expert by ``sigmoid(x W_r)``
+instead (DeepSeek-V3's), and ``shared_experts`` adds a dense SwiGLU every
+token visits (scope ``moe/shared``).  No
 token is ever dropped and every shape is static:
 the ``tokens x top_k`` assignments are sorted by expert, this chip's
 first, one grouped matmul (``jax.lax.ragged_dot``, which the TPU compiler
@@ -76,7 +79,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.models.llama import MLP, LlamaConfig
 from dlrover_tpu.observability import trace
 
 
@@ -96,9 +99,24 @@ class MoELlamaConfig(LlamaConfig):
     # and the layer's result is these experts' part.  0: every expert
     experts_held: int = 0
     first_expert: int = 0
+    # what the router's logits become before the ``top_k`` are taken:
+    # ``softmax`` over all experts, or ``sigmoid`` of each (DeepSeek-V3's;
+    # Solar-Open2's); the kept weights are multiplied by
+    # ``routed_scaling_factor``, after ``norm_topk_prob``'s division
+    router_scores: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # experts every token visits, beside the routed ones: one dense SwiGLU
+    # of width ``shared_experts * shared_intermediate_size`` (0: the routed
+    # experts' width), its result added unweighted.  Whole on every chip: a
+    # share (``experts_held``) adds it once
+    shared_experts: int = 0
+    shared_intermediate_size: int = 0
 
     def __post_init__(self):
         super().__post_init__()
+        if self.router_scores not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_scores={self.router_scores!r} not in "
+                             "('softmax', 'sigmoid')")
         if self.experts_held and not (
                 0 <= self.first_expert
                 <= self.num_experts - self.experts_held):
@@ -109,11 +127,15 @@ class MoELlamaConfig(LlamaConfig):
     def feed_forward(self):
         return MoEMLP
 
+    def shared_width(self) -> int:
+        return self.shared_experts * (
+            self.shared_intermediate_size or self.intermediate_size)
+
     def feed_forward_params(self) -> int:
         held = self.experts_held or self.num_experts
         return held * super().feed_forward_params() + (
             self.hidden_size * self.num_experts
-        )
+        ) + 3 * self.hidden_size * self.shared_width()
 
     @classmethod
     def tiny_moe(cls, **kw) -> "MoELlamaConfig":
@@ -337,10 +359,18 @@ class MoEMLP(nn.Module):
                     ),
                     name="router",
                 )(x)
-                probs = jax.nn.softmax(logits, axis=-1)
-                top_w, top_i = jax.lax.top_k(probs, k)
+                if cfg.router_scores == "sigmoid":
+                    scores = jax.nn.sigmoid(logits)
+                    # the balance loss reads each expert's share of the
+                    # scores: the normalised scores where no softmax did it
+                    probs = scores / scores.sum(axis=-1, keepdims=True)
+                else:
+                    scores = probs = jax.nn.softmax(logits, axis=-1)
+                top_w, top_i = jax.lax.top_k(scores, k)
                 if cfg.norm_topk_prob:
                     top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+                if cfg.routed_scaling_factor != 1.0:
+                    top_w = top_w * cfg.routed_scaling_factor
 
             def expert_weight(name, shape, axes):
                 return self.param(
@@ -388,6 +418,12 @@ class MoEMLP(nn.Module):
                 self.sow("stats", "rows_held_over_live", held / live)
                 self.sow("stats", "chip_rows_max_over_mean",
                          chip_rows.max() / chip_rows.mean())
+            if cfg.shared_experts:
+                with jax.named_scope("shared"):
+                    mixed = mixed + MLP(
+                        dataclasses.replace(
+                            cfg, intermediate_size=cfg.shared_width()),
+                        name="shared_expert")(x).astype(mixed.dtype)
         return nn.with_logical_constraint(mixed, ("batch", "seq", "embed"))
 
     def _experts(self, x, top_i, top_w, gate_w, up_w, down_w):

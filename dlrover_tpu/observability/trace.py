@@ -497,8 +497,11 @@ SCOPE_KINDS: Dict[str, str] = {
 #: twice: a sub-scope says whose it is)
 SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
     "attn.core": ("scores", "select", "selected", "index_loss",
-                  "pool", "windows", "summary_mass"),
-    "moe": ("route", "sort", "gmm", "exchange", "combine"),
+                  "pool", "windows", "summary_mass",
+                  # a gated delta-rule layer (``models/llama.py::
+                  # DeltaAttention``, ``ops/linear_attention.py``)
+                  "conv", "decay", "chunk", "state", "gate"),
+    "moe": ("route", "sort", "gmm", "exchange", "combine", "shared"),
 }
 
 _SUB_SCOPE_OF = {sub: kind for kind, subs in SUB_SCOPES.items()

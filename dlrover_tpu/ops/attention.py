@@ -82,11 +82,14 @@ def flash_attention(
     block_q: int = 0,
     block_kv: int = 0,
     interpret: bool = False,
+    path_attrs: Optional[dict] = None,
 ) -> jnp.ndarray:
     """Fused attention: the Pallas TPU kernel, never the reference.
 
     Block sizes default to the autotuned table (``ops/pallas/tuning.py``)
     for this (seq_len, head_dim); pass explicit values to override.
+    ``path_attrs``: what the caller does around the kernel (``rope=none``,
+    ``gate=sigmoid``), written at the end of the ``attention.path`` line.
     ``interpret=True`` runs the kernel in the Pallas interpreter (tests off
     the chip); without it a backend that is not a TPU is an error.  Under
     an active mesh of more than one device, outside any ``shard_map``, the
@@ -115,7 +118,7 @@ def flash_attention(
     trace.note_trace_time(
         "attention.path", impl="flash", seq=seq_len, head_dim=head_dim,
         heads=heads, blocks=(block_q, block_kv), layout="bsd",
-        heads_per_block=heads_per_block(head_dim),
+        heads_per_block=heads_per_block(head_dim), **(path_attrs or {}),
     )
 
     def kernel(q_, k_, v_):

@@ -29,6 +29,7 @@ DEFAULT_LOGICAL_RULES: List[Tuple[str, MeshAxis]] = [
     ("mlp", "tp"),
     ("expert", "ep"),
     ("layers", None),           # scanned-layer leading axis stays replicated
+    ("periods", None),          # and the axis of a layer pattern's periods
 ]
 
 

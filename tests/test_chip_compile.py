@@ -453,6 +453,52 @@ class TestTrainerStep:
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
 
+    def test_solar_open2_widths_one_period(self, topo, as_if_on_tpu):
+        """One period of Solar-Open2 at B1 S8192 and the cell's share (8
+        delta-rule heads, 8 query heads on 1 key head, 10 of 320 experts
+        beside the shared one): one FA2 layer through the kernel, three
+        delta-rule layers in ``jax.numpy`` whose scan between chunks carries
+        a float32 state, no array with two dimensions of the whole sequence,
+        every instruction of the delta rule under one of the sub-scopes the
+        benchmark's readers sum, and the step fits the chip."""
+        from dlrover_tpu.models.llama import LlamaForCausalLM
+        from dlrover_tpu.models.moe import MoELlamaConfig
+        from dlrover_tpu.observability import trace
+
+        def one_period():
+            cfg = MoELlamaConfig(
+                vocab_size=24576, hidden_size=4096, intermediate_size=1280,
+                num_layers=4, num_heads=8, num_kv_heads=1, head_dim=128,
+                max_seq_len=8192, attention_impl="flash",
+                layer_pattern=("gqa", "kda", "kda", "kda"), use_rope=False,
+                attn_gate=True, kda_heads=8, kda_head_dim=128,
+                num_experts=320, top_k=8, norm_topk_prob=True,
+                router_scores="sigmoid", shared_experts=1, experts_held=10,
+                load_balance_coef=0.001, router_z_coef=0.0)
+            return LlamaForCausalLM(cfg), (1, 8192)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh, one_period)
+        text = compiled.as_text()
+        assert "8192,8192]" not in text
+        calls = re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        # the one softmax layer: forward, dQ, dK/dV; its loops have one
+        # turn each, so the compiler finds the rematerialised forward in
+        # the forward (``families/solaropen2.py::fa2_shape`` counts so)
+        assert sum("_attend" in name for name in calls) == 3
+        found = trace.parse_device_scopes(text)
+        subs = {sub for kind, sub, _ in found.scopes.values()
+                if kind == "attn.core"}
+        assert {"conv", "decay", "chunk", "state", "gate"} <= subs
+        assert ("moe", "shared", "forward") in set(found.scopes.values())
+        # the state between chunks: float32, a head a [128, 128] matrix
+        assert re.search(r"f32\[(1,)?8,128,128\]", text)
+        # accepted for a chip of 15.75 GiB (a refusal raises): 966.7 M
+        # parameters at 8 bytes of state each are the arguments
+        mem = compiled.memory_analysis()
+        assert 7.7e9 < mem.argument_size_in_bytes < 7.8e9
+
     def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
         """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped
         matmuls are the compiler's own kernel, forward and both gradients;

@@ -1,0 +1,253 @@
+"""Layers of two kinds in one stack (``LlamaConfig.layer_pattern``): a scan
+over periods with a scan over each run of equal layers inside, parameters
+stacked by run.  The patterned stack equals the same layers unrolled;
+``num_params()`` equals the tree's count; an empty pattern gives the tree the
+program had before, name for name; a patterned training state goes through a
+Flash Checkpoint memory save and restore bit for bit."""
+
+import dataclasses
+import uuid
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from dlrover_tpu.models.llama import (
+    DecoderLayer,
+    LlamaConfig,
+    LlamaForCausalLM,
+    RMSNorm,
+)
+from dlrover_tpu.models.moe import MoELlamaConfig
+from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+from dlrover_tpu.trainer.flash_checkpoint import Checkpointer, StorageType
+from dlrover_tpu.trainer.train import Trainer
+
+PATTERN = ("gqa", "kda", "kda", "kda")
+SEQ = 48
+
+
+def _config(**changes):
+    fields = dict(
+        num_layers=8, layer_pattern=PATTERN, use_rope=False, attn_gate=True,
+        kda_heads=2, kda_head_dim=16, kda_chunk=16, dtype=jnp.float32)
+    fields.update(changes)
+    return LlamaConfig.tiny(**fields)
+
+
+def _ids(batch=2, seq=SEQ, seed=0):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.integers(0, 256, size=(batch, seq)), jnp.int32)
+
+
+def _perturbed(params):
+    """Every leaf moved: an untrained norm's scale is 1 and the decay's
+    vectors are small, so a stack that forgot one would pass."""
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    return jax.tree.unflatten(tree, [
+        leaf + 0.1 * jax.random.normal(k, leaf.shape, leaf.dtype)
+        for leaf, k in zip(leaves, keys)])
+
+
+@pytest.fixture(scope="module")
+def made():
+    cfg = _config()
+    model = LlamaForCausalLM(cfg)
+    ids = _ids()
+    params = _perturbed(nn.meta.unbox(
+        model.init(jax.random.PRNGKey(1), ids)["params"]))
+    return cfg, model, ids, params
+
+
+def test_layer_runs_of_a_pattern():
+    assert _config().layer_runs() == [("gqa_0", "gqa", 1), ("kda_1", "kda", 3)]
+    cfg = _config(layer_pattern=("kda", "gqa", "gqa", "kda"), num_layers=4)
+    assert cfg.layer_runs() == [
+        ("kda_0", "kda", 1), ("gqa_1", "gqa", 2), ("kda_2", "kda", 1)]
+    assert LlamaConfig.tiny().layer_runs() == []
+
+
+@pytest.mark.parametrize("changes,match", [
+    ({"layer_pattern": ("gqa", "mamba")}, "kinds of"),
+    ({"num_layers": 6}, "whole number of periods"),
+    ({"kda_heads": 0}, "kda_heads"),
+])
+def test_what_the_config_refuses(changes, match):
+    with pytest.raises(ValueError, match=match):
+        _config(**changes)
+
+
+def test_parameters_are_stacked_by_run(made):
+    cfg, _, _, params = made
+    assert set(params["layers"]) == {"gqa_0", "kda_1"}
+    gqa, kda = (params["layers"][name]["layer"] for name in ("gqa_0", "kda_1"))
+    # [periods, run, ...]
+    assert gqa["attn"]["gate_proj"]["kernel"].shape == (2, 1, 64, 4, 16)
+    assert kda["attn"]["q_conv"].shape == (2, 3, 4, 2, 16)
+    assert kda["attn"]["A_log"].shape == (2, 3, 2)
+    assert "gate_proj" not in kda["attn"] and "q_conv" not in gqa["attn"]
+    assert set(gqa) == set(kda) == {
+        "attn", "input_norm", "mlp", "post_attn_norm"}
+
+
+def test_patterned_stack_equals_the_same_layers_unrolled(made):
+    """Each layer applied by hand in the stack's order (period by period,
+    run by run) with its slice of the stacked parameters."""
+    cfg, model, ids, params = made
+    got = model.apply({"params": params}, ids)
+    x = params["embed_tokens"][ids]
+    positions = jnp.broadcast_to(jnp.arange(SEQ), ids.shape)
+    mask = jnp.tril(jnp.ones((SEQ, SEQ), bool))[None, None]
+    kinds = []
+    for period in range(cfg.num_layers // len(PATTERN)):
+        for name, kind, length in cfg.layer_runs():
+            for i in range(length):
+                layer = jax.tree.map(lambda t: t[period, i],
+                                     params["layers"][name]["layer"])
+                x = DecoderLayer(cfg, kind).apply(
+                    {"params": layer}, x, positions, mask)
+                kinds.append(kind)
+    assert tuple(kinds) == PATTERN * 2
+    x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype).apply(
+        {"params": params["final_norm"]}, x)
+    want = x @ params["lm_head"]["kernel"]
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+
+
+def test_a_kda_layer_is_not_a_gqa_layer(made):
+    """The same parameters cannot be read by the other kind: the kinds
+    differ in what they hold, not only in what they compute."""
+    cfg, _, ids, params = made
+    x = params["embed_tokens"][ids]
+    layer = jax.tree.map(lambda t: t[0, 0], params["layers"]["kda_1"]["layer"])
+    with pytest.raises(Exception):
+        DecoderLayer(cfg, "gqa").apply({"params": layer}, x, None, None)
+
+
+@pytest.mark.parametrize("make", [
+    lambda: _config(),
+    lambda: _config(num_layers=4),
+    lambda: _config(layer_pattern=("kda", "gqa"), num_layers=2, attn_gate=False),
+    lambda: MoELlamaConfig.tiny_moe(
+        num_layers=4, layer_pattern=PATTERN, kda_heads=2, kda_head_dim=16,
+        attn_gate=True, use_rope=False, router_scores="sigmoid",
+        shared_experts=1, num_experts=8, experts_held=2),
+    lambda: LlamaConfig.tiny(),
+    lambda: LlamaConfig.tiny(attn_gate=True),
+    lambda: MoELlamaConfig.tiny_moe(shared_experts=2,
+                                    shared_intermediate_size=48),
+], ids=["two_periods", "one_period", "kda_first", "moe_share", "no_pattern",
+        "gated", "two_shared"])
+def test_num_params_equals_the_trees_count(make):
+    cfg = make()
+    model = LlamaForCausalLM(cfg)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), _ids())
+    count = sum(int(np.prod(leaf.shape))
+                for leaf in jax.tree.leaves(nn.meta.unbox(shapes["params"])))
+    assert model.num_params() == count
+
+
+def test_an_empty_pattern_gives_todays_tree_name_for_name():
+    """The tree of the four Llama-code cells: their checkpoints and their
+    ``condition`` rules read these names."""
+    model = LlamaForCausalLM(LlamaConfig.tiny())
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), _ids())
+    paths = {"/".join(str(k.key) for k in path): leaf.shape for path, leaf in
+             jax.tree_util.tree_leaves_with_path(
+                 nn.meta.unbox(shapes["params"]))}
+    assert paths == {
+        "embed_tokens": (256, 64),
+        "final_norm/scale": (64,),
+        "lm_head/kernel": (64, 256),
+        "layers/layer/input_norm/scale": (2, 64),
+        "layers/layer/post_attn_norm/scale": (2, 64),
+        "layers/layer/attn/q_proj/kernel": (2, 64, 4, 16),
+        "layers/layer/attn/k_proj/kernel": (2, 64, 2, 16),
+        "layers/layer/attn/v_proj/kernel": (2, 64, 2, 16),
+        "layers/layer/attn/o_proj/kernel": (2, 4, 16, 64),
+        "layers/layer/mlp/gate_proj/kernel": (2, 64, 128),
+        "layers/layer/mlp/up_proj/kernel": (2, 64, 128),
+        "layers/layer/mlp/down_proj/kernel": (2, 128, 64),
+    }
+    # and the axes' logical names: one ``layers`` in front, as ever
+    boxed = jax.tree.leaves(
+        shapes["params"], is_leaf=lambda x: isinstance(x, nn.Partitioned))
+    assert {b.names[0] for b in boxed if len(b.names) > 2} == {"layers"}
+
+
+def test_no_mask_where_no_layer_reads_one():
+    """The ``[S, S]`` mask is built only for the reference core: not under
+    the kernel, not for a stack of delta-rule layers alone."""
+    ids = jax.ShapeDtypeStruct((1, 1024), jnp.int32)
+
+    def lowered(cfg):
+        model = LlamaForCausalLM(cfg)
+        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                jnp.zeros((1, 1024), jnp.int32))
+        return jax.jit(model.apply).lower(
+            {"params": nn.meta.unbox(shapes["params"])}, ids).as_text()
+
+    assert "1024x1024xi1" in lowered(LlamaConfig.tiny(max_seq_len=1024))
+    only_kda = _config(layer_pattern=("kda",), num_layers=2)
+    assert "1024x1024" not in lowered(only_kda)
+
+
+def test_patterned_model_trains(made):
+    """A few steps through ``Trainer`` on one device: the loss falls, the
+    counters a delta-rule layer sows come back a value a layer."""
+    cfg = _config(num_layers=4, dtype=jnp.bfloat16)
+    mesh = build_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    trainer = Trainer(LlamaForCausalLM(cfg), optax.adamw(3e-3), mesh)
+    ids = np.asarray(_ids(4, SEQ + 1))
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    state = trainer.create_state(jax.random.PRNGKey(0), batch["input_ids"])
+    losses = []
+    for _ in range(6):
+        state, metrics = trainer.train_step(state, trainer.shard_batch(batch))
+        losses.append(float(metrics["loss"]))
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    sown = {path[-2].key: np.asarray(leaf).ravel() for path, leaf in
+            jax.tree_util.tree_leaves_with_path(metrics["stats"])}
+    assert sown["kda_beta_over_one_share"].shape == (3,)
+    assert (0.05 < sown["kda_beta_over_one_share"]).all()
+    assert (sown["kda_decay_half_life"] > 0.3).all()
+
+
+def test_patterned_state_through_a_memory_save_and_restore(tmp_path):
+    """The checkpoint path sees the new tree: every leaf of a sharded
+    patterned state, parameters and moments, comes back bit for bit."""
+    mesh = build_mesh(MeshConfig(dp=2, fsdp=2, tp=2))
+    cfg = _config(num_layers=4)
+    trainer = Trainer(LlamaForCausalLM(cfg), optax.adamw(1e-2), mesh)
+    ids = np.asarray(_ids(8, 17))
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    state = trainer.create_state(jax.random.PRNGKey(0), batch["input_ids"])
+    state, _ = trainer.train_step(state, trainer.shard_batch(batch))
+    ckpt = Checkpointer(str(tmp_path), scope=f"t{uuid.uuid4().hex[:8]}")
+    try:
+        ckpt.save_checkpoint(7, state, StorageType.MEMORY)
+        restored, step = ckpt.load_checkpoint(
+            jax.eval_shape(lambda s: s, state), trainer.state_shardings)
+    finally:
+        ckpt.close()
+    assert step == 7
+    before, after = jax.tree.leaves(state), jax.tree.leaves(restored)
+    assert len(before) == len(after) > 40
+    for x, y in zip(before, after):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    # the run's parameters kept their sharding: heads over tp
+    conv = nn.meta.unbox(restored.params)["layers"]["kda_1"]["layer"][
+        "attn"]["q_proj"]["kernel"]
+    assert conv.shape == (1, 3, 64, 2, 16)
+    assert "tp" in str(conv.sharding.spec)
+
+
+def test_dataclass_fields_keep_their_defaults():
+    """What the other cells' families do not name keeps today's behaviour."""
+    defaults = {f.name: f.default for f in dataclasses.fields(LlamaConfig)}
+    assert defaults["layer_pattern"] == () and defaults["use_rope"] is True
+    assert defaults["attn_gate"] is False and defaults["kda_heads"] == 0

@@ -666,3 +666,106 @@ class TestFlashCheckpoint:
             np.testing.assert_array_equal(np.asarray(got), leaf)
         state2, metrics = trainer2.train_step(restored, batch)
         assert np.isfinite(float(metrics["loss"]))
+
+
+class TestSigmoidRouterAndSharedExpert:
+    """``router_scores="sigmoid"``, ``routed_scaling_factor`` and
+    ``shared_experts`` (DeepSeek-V3's router; Solar-Open2's) against
+    ``models/solar_open2_reference.py::experts``, which loops over the
+    experts plainly."""
+
+    M = {"num_experts_per_tok": 3, "experts_total": 8, "first_expert": 0,
+         "routed_scaling_factor": 1.0}
+
+    def _layer(self, x, **kw):
+        cfg = _config(router_scores="sigmoid", norm_topk_prob=True,
+                      shared_experts=1, router_z_coef=0.0, num_layers=1, **kw)
+        params = _perturbed(nn.meta.unbox(
+            MoEMLP(cfg).init(jax.random.PRNGKey(4), x)["params"]), seed=5)
+        return cfg, params
+
+    @pytest.fixture(scope="class")
+    def x(self):
+        return jax.random.normal(jax.random.PRNGKey(3), (2, 32, 64))
+
+    @pytest.mark.parametrize("scale", [1.0, 2.5])
+    def test_result_and_balance_loss_equal_the_reference(self, x, scale):
+        from dlrover_tpu.models import solar_open2_reference
+
+        cfg, params = self._layer(x, routed_scaling_factor=scale)
+        assert set(params) == {"router", "gate_proj", "up_proj", "down_proj",
+                               "shared_expert"}
+        with jax.default_matmul_precision("highest"):
+            got, sown = MoEMLP(cfg).apply(
+                {"params": params}, x, mutable=["losses", "stats"])
+            want, balance, _ = solar_open2_reference.experts(
+                x, params, {**self.M, "routed_scaling_factor": scale},
+                whole=True)
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+        np.testing.assert_allclose(
+            sown["losses"]["load_balance"][0] / cfg.load_balance_coef,
+            balance, rtol=1e-5)
+        assert float(sown["losses"]["router_z"][0]) == 0.0
+
+    def test_gradients_equal_the_references(self, x):
+        from dlrover_tpu.models import solar_open2_reference
+
+        cfg, params = self._layer(x)
+        weights = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(
+            x.shape)
+
+        def system(p, h):
+            return jnp.sum(MoEMLP(cfg).apply({"params": p}, h) * weights)
+
+        def plain(p, h):
+            return jnp.sum(solar_open2_reference.experts(
+                h, p, self.M, whole=True)[0] * weights)
+
+        with jax.default_matmul_precision("highest"):
+            got = jax.grad(system, argnums=(0, 1))(params, x)
+            want = jax.grad(plain, argnums=(0, 1))(params, x)
+        assert _max_err(got, want) < 2e-4
+        # the shared expert and the router both get a gradient
+        assert float(jnp.abs(
+            got[0]["shared_expert"]["down_proj"]["kernel"]).max()) > 1e-3
+        assert float(jnp.abs(got[0]["router"]["kernel"]).max()) > 1e-4
+
+    def test_sigmoid_scores_are_not_softmax_weights(self, x):
+        """The same parameters under the other scoring give another
+        result: the kept weights differ even where the kept experts are
+        the same."""
+        cfg, params = self._layer(x)
+        import dataclasses
+
+        other = dataclasses.replace(cfg, router_scores="softmax")
+        got = MoEMLP(cfg).apply({"params": params}, x)
+        soft = MoEMLP(other).apply({"params": params}, x)
+        assert float(jnp.abs(got - soft).max()) > 1e-2
+
+    def test_the_shared_expert_is_added_once_and_unweighted(self, x):
+        """The layer less its routed part is the shared SwiGLU of the
+        input, whatever the router says."""
+        import dataclasses
+
+        from dlrover_tpu.models.llama import MLP
+
+        cfg, params = self._layer(x)
+        without = dataclasses.replace(cfg, shared_experts=0)
+        routed = {k: v for k, v in params.items() if k != "shared_expert"}
+        with jax.default_matmul_precision("highest"):
+            both = MoEMLP(cfg).apply({"params": params}, x)
+            alone = MoEMLP(without).apply({"params": routed}, x)
+            shared = MLP(dataclasses.replace(
+                cfg, intermediate_size=cfg.shared_width())).apply(
+                    {"params": params["shared_expert"]}, x)
+        np.testing.assert_allclose(both - alone, shared, rtol=0, atol=2e-5)
+        assert cfg.shared_width() == cfg.intermediate_size
+
+    def test_what_the_config_refuses_and_counts(self):
+        with pytest.raises(ValueError, match="router_scores"):
+            _config(router_scores="tanh")
+        plain = _config()
+        two = _config(shared_experts=2, shared_intermediate_size=48)
+        assert two.shared_width() == 96 and plain.shared_width() == 0
+        assert two.feed_forward_params() - plain.feed_forward_params() == (
+            3 * 64 * 96)
