@@ -436,7 +436,7 @@ class DeltaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, mask):
-        from dlrover_tpu.ops.linear_attention import kda
+        from dlrover_tpu.ops.linear_attention import kda, kda_core
 
         cfg = self.config
         H, D, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
@@ -527,7 +527,8 @@ class DeltaAttention(nn.Module):
             trace.note_trace_time(
                 "attention.path", impl="kda", seq=x.shape[1], heads=H,
                 head_dim=D, chunk=min(cfg.kda_chunk, x.shape[1]), conv=taps,
-                state_dtype="float32")
+                state_dtype="float32",
+                **kda_core(x.shape[1], cfg.kda_chunk, D))
             out = kda(q, k, v, g, beta, cfg.kda_chunk)
             with jax.named_scope("gate"):
                 out = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,

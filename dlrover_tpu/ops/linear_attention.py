@@ -33,8 +33,21 @@ underflows to 0 is smaller in the mathematics still.
 
 Matmul operands are in the operands' own dtype (the model's compute dtype)
 with float32 accumulation; ``g``, its running sums, the solve and the state
-``S`` between chunks are float32.  The backward pass is JAX's, of the
-chunked form; the pair-by-pair part is recomputed there, not kept.
+``S`` between chunks are float32.
+
+**Which body runs where.**  ``kda`` is the one entry.  On a TPU, at heads of
+128 key and value channels, chunks of 64 and a length that is a multiple of
+the chunk (``kda_path``; the benchmark's Solar-Open2 cell), it goes through
+the Pallas kernels of ``ops/pallas/kda.py``, forward and backward behind one
+``jax.custom_vjp``: the work inside a chunk in fast memory (the sub-blocks
+halved down to single positions, so nothing is left to be taken pair by
+pair; the solve by float32 products), the float32 state resident across a
+sequence's chunks.  Everywhere else (off the chip, the tests' small heads,
+another chunk, a ragged length) it is the ``jax.numpy`` body of this file,
+``_kda_chunked``, whose backward pass is JAX's, of the chunked form, with
+the pair-by-pair part recomputed there, not kept; that body and
+``kda_recurrent`` are the kernels' yardsticks.  ``kda_core`` says which was
+taken, with the kernels' tile, for the ``attention.path`` event.
 """
 
 import functools
@@ -108,12 +121,62 @@ def _decayed_products(rows, keys, running, before, sub):
         inside.shape[:-4] + (C, C))
 
 
+def kda_path(backend: str, seq: int, chunk: int, head_dim: int) -> str:
+    """``"pallas"`` or ``"jnp"``: which body computes the chunked rule for
+    ``seq`` positions in chunks of ``chunk`` at heads of ``head_dim`` key
+    and value channels (as ``ops/attention.py::index_scores_path``)."""
+    from dlrover_tpu.ops.pallas.kda import kernels_take
+
+    if backend == "tpu" and kernels_take(seq, chunk, head_dim):
+        return "pallas"
+    return "jnp"
+
+
+def kda_core(seq: int, chunk: int, head_dim: int) -> dict:
+    """What ``kda`` takes at these shapes on this backend, as the fields of
+    the ``attention.path`` event: ``core`` and, from the kernels, the tile
+    (``tuning.kda_tiling``) and ``sub``."""
+    core = kda_path(jax.default_backend(), seq, min(chunk, seq), head_dim)
+    if core == "jnp":
+        return dict(core=core)
+    from dlrover_tpu.ops.pallas.kda import SUB as sub
+    from dlrover_tpu.ops.pallas.tuning import kda_tiling
+
+    chunks, heads, state_heads = kda_tiling(chunk, head_dim)
+    return dict(core=core, chunks_per_step=chunks, heads_per_turn=heads,
+                state_heads_per_step=state_heads, sub=sub)
+
+
 def kda(q, k, v, g, beta, chunk=64):
     """The gated delta rule with a per-channel decay, chunked: q, k ``[B,
     S, H, K]`` (the model normalises them), v ``[B, S, H, V]``, g ``[B, S,
     H, K]`` the log of the decay (at most 0), beta ``[B, S, H]`` ->
     ``[B, S, H, V]`` in ``v``'s dtype.  A length that is no multiple of the
-    chunk is padded with positions that change nothing (``k = 0``)."""
+    chunk is padded with positions that change nothing (``k = 0``).
+    Through the Pallas kernels where ``kda_path`` says so, else the
+    ``jax.numpy`` body below."""
+    S, K = q.shape[1], q.shape[3]
+    core = kda_core(S, chunk, K if v.shape[-1] == K else 0)
+    if core["core"] == "pallas":
+        return _kda_kernels(
+            q.astype(v.dtype), k.astype(v.dtype), v, g.astype(jnp.float32),
+            beta.astype(jnp.float32), (
+                core["chunks_per_step"], core["heads_per_turn"],
+                core["state_heads_per_step"]))
+    return _kda_chunked(q, k, v, g, beta, chunk)
+
+
+def _kda_kernels(q, k, v, g, beta, tile, interpret=False):
+    """The kernels' entry, a name of this module so that a test can run
+    them in the interpreter."""
+    from dlrover_tpu.ops.pallas.kda import kda_kernels
+
+    return kda_kernels(q, k, v, g, beta, tile=tile, interpret=interpret)
+
+
+def _kda_chunked(q, k, v, g, beta, chunk):
+    """``kda`` in ``jax.numpy``: off the chip and at shapes the kernels do
+    not take, and beside ``kda_recurrent`` the kernels' yardstick."""
     B, S, H, K = q.shape
     V = v.shape[-1]
     dtype = v.dtype

@@ -182,6 +182,25 @@ def index_tiling(block_q: int, index_dim: int) -> Tuple[int, int]:
     return DEFAULT_BLOCKS[1], 1
 
 
+def kda_tiling(chunk: int, head_dim: int) -> Tuple[int, int, int]:
+    """``(chunks, heads, state_heads)`` of the delta rule's kernels
+    (``kda.py``) for chunks of ``chunk`` positions at heads of
+    ``head_dim``: the chunks a grid step of every kernel, the heads a turn
+    of the chunk kernels' loop over the heads, and the heads a grid step
+    of the kernels that carry the state.  The table's
+    ``kda_c<chunk>_d<head_dim>`` entry (swept in the whole step on the
+    chip by ``scripts/fa_blocks_in_step.py --kda``), else one of each."""
+    try:
+        entry = _load_table().get(f"kda_c{chunk}_d{head_dim}") or {}
+        tiling = (int(entry["chunks"]), int(entry["heads"]),
+                  int(entry.get("state_heads", entry["heads"])))
+        if min(tiling) > 0:
+            return tiling
+    except (TypeError, KeyError, ValueError):
+        pass
+    return 1, 1, 1
+
+
 def _current_device_kind() -> str:
     try:
         import jax

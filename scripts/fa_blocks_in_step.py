@@ -30,6 +30,16 @@ by their results::
 
     python3 scripts/fa_blocks_in_step.py --config keyevl2_30b_1of8 --index
 
+``--kda`` sweeps the gated delta rule's kernels (``ops/pallas/kda.py``;
+``--blocks chunks,heads;chunks,heads``: the chunks a grid step and the
+heads a turn of the chunk kernels' loop over the heads, the heads a grid
+step of the kernels that walk the chunks as ``--state-heads``; the table's
+``kda_c<chunk>_d<head_dim>`` entry), the work inside chunks and the walk
+between them, forward and backward, told apart by their names and
+results::
+
+    python3 scripts/fa_blocks_in_step.py --config solaropen2_250b_1of32 --kda
+
 Candidates reach the kernel through the table ``DLROVER_TPU_FA_TUNING``
 names, as a user's own table would.  One JSON line a candidate, the
 winner's table entry last.
@@ -123,6 +133,23 @@ def index_kernel_seconds(trace_dir, shape):
     return _tally(trace_dir, ("fwd", "bwd"), kind_of)
 
 
+def kda_kernel_seconds(trace_dir):
+    """kind (``chunk_fwd``, ``state_fwd``, ``state_bwd``, ``chunk_bwd``) ->
+    [events, seconds] of the delta rule's custom calls: named after the
+    scope around their call (``%chunk.N``, ``%state.N``), the forward of
+    either told from its backward by how many arrays it returns."""
+    kinds = {("chunk", 7): "chunk_fwd", ("state", 3): "state_fwd",
+             ("state", 6): "state_bwd", ("chunk", 5): "chunk_bwd"}
+
+    def kind_of(name):
+        call = SELECTED_CALL.match(name)
+        if not call:
+            return None
+        return kinds.get((name[1:].split(".")[0], call.group(1).count("[")))
+
+    return _tally(trace_dir, tuple(kinds.values()), kind_of)
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--config", default="gpt2m")
@@ -135,6 +162,10 @@ def main(argv=None) -> int:
     parser.add_argument("--index", action="store_true",
                         help="sweep the index scores' kernels; --blocks "
                              "then takes kv,unroll;kv,unroll")
+    parser.add_argument("--kda", action="store_true",
+                        help="sweep the delta rule's kernels; --blocks "
+                             "then takes chunks,heads;chunks,heads")
+    parser.add_argument("--state-heads", type=int, default=4)
     parser.add_argument("--rehearse", action="store_true",
                         help="tiny sizes, any backend: control flow only")
     args = parser.parse_args(argv)
@@ -149,7 +180,7 @@ def main(argv=None) -> int:
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            args.config + ".json")) as f:
         config = json.load(f)
-    family, _, trainer = program.make_trainer(config, args.rehearse)
+    family, model, trainer = program.make_trainer(config, args.rehearse)
     pool = program.make_pool(config, args.rehearse, 0, family)
     m = family.sizes(config, args.rehearse)
     batch, seq = pool[0]["input_ids"].shape
@@ -172,6 +203,11 @@ def main(argv=None) -> int:
         args.blocks = args.blocks or "1024,1;2048,1;4096,1;2048,2;2048,4"
         heads, head_dim = shape["index_heads"], shape["index_dim"]
         key = f"index_q{block_q}_c{head_dim}_kv"
+    if args.kda:
+        shape = family.kda_shape(config, batch, seq, args.rehearse)
+        args.blocks = args.blocks or "1,8;2,8;4,8;2,4;1,2"
+        heads, head_dim = shape["heads"], shape["head_dim"]
+        key = f"kda_c{model.config.kda_chunk}_d{head_dim}"
     if args.blocks:
         candidates = [tuple(int(b) for b in pair.split(","))
                       for pair in args.blocks.split(";")]
@@ -184,7 +220,10 @@ def main(argv=None) -> int:
         os.environ["DLROVER_TPU_FA_TUNING"] = table
         for first, *second in candidates:
             second = second[0] if second else first
-            if args.index:
+            if args.kda:
+                line = {"chunks": first, "heads": second,
+                        "state_heads": args.state_heads}
+            elif args.index:
                 line = {"block_q": block_q, "block_kv": first,
                         "unroll": second}
             elif args.selected:
@@ -217,7 +256,8 @@ def main(argv=None) -> int:
                     step_s = (time.perf_counter() - t0) / len(sharded)
                 finally:
                     jax.profiler.stop_trace()
-                found = (index_kernel_seconds(trace_dir, shape)
+                found = (kda_kernel_seconds(trace_dir) if args.kda else
+                         index_kernel_seconds(trace_dir, shape)
                          if args.index else
                          selected_kernel_seconds(trace_dir, shape)
                          if args.selected else kernel_seconds(trace_dir))
@@ -241,7 +281,8 @@ def main(argv=None) -> int:
                           f"(backend {jax.default_backend()!r})"}))
         return 1
     best = ranked[0]
-    named = ("block_q", "block_kv", "mean_block_kv", "unroll")
+    named = ("block_q", "block_kv", "mean_block_kv", "unroll", "chunks",
+             "heads", "state_heads")
     runner_up = ("; %d candidates, next best %s at %s" % (
         len(ranked), "x".join(str(ranked[1][n]) for n in named
                               if n in ranked[1]),
@@ -253,6 +294,7 @@ def main(argv=None) -> int:
         "measured": "kernel time in the device trace of a whole step "
                     f"({args.config}: forward, recomputed forward, backward) "
                     "by scripts/fa_blocks_in_step.py%s%s" % (
+                        " --kda" if args.kda else
                         " --index" if args.index else
                         " --selected" if args.selected else "", runner_up),
         "backend": jax.default_backend(),

@@ -14,6 +14,7 @@ file, and the worker that is given this file is the one that loads it.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import os
 import re
@@ -457,10 +458,14 @@ class TestTrainerStep:
         """One period of Solar-Open2 at B1 S8192 and the cell's share (8
         delta-rule heads, 8 query heads on 1 key head, 10 of 320 experts
         beside the shared one): one FA2 layer through the kernel, three
-        delta-rule layers in ``jax.numpy`` whose scan between chunks carries
-        a float32 state, no array with two dimensions of the whole sequence,
-        every instruction of the delta rule under one of the sub-scopes the
-        benchmark's readers sum, and the step fits the chip."""
+        delta-rule layers through theirs (``ops/pallas/kda.py``: the work
+        inside chunks and the walk between them, forward, rematerialised
+        and backward, a head's float32 state in the walk's scratch), no
+        array with two dimensions of the whole sequence and none of the
+        ``jax.numpy`` body's decayed keys, pair-by-pair products or
+        triangular solve, every instruction of the delta rule under one of
+        the sub-scopes the benchmark's readers sum, and the step fits the
+        chip with no more temporaries than it held in ``jax.numpy``."""
         from dlrover_tpu.models.llama import LlamaForCausalLM
         from dlrover_tpu.models.moe import MoELlamaConfig
         from dlrover_tpu.observability import trace
@@ -492,12 +497,40 @@ class TestTrainerStep:
                 if kind == "attn.core"}
         assert {"conv", "decay", "chunk", "state", "gate"} <= subs
         assert ("moe", "shared", "forward") in set(found.scopes.values())
-        # the state between chunks: float32, a head a [128, 128] matrix
-        assert re.search(r"f32\[(1,)?8,128,128\]", text)
+        # the delta rule's kernels: the three layers are one loop, so one
+        # call of each kind a pass, under the sub-scopes the readers of
+        # ``kda_ms_per_step`` and ``kda_state_ms_per_step`` sum
+        kernels = sorted(
+            found.scopes["%" + name] for name in calls
+            if found.scopes["%" + name][:2] in (
+                ("attn.core", "chunk"), ("attn.core", "state")))
+        assert kernels == sorted(
+            ("attn.core", sub, which) for sub in ("chunk", "state")
+            for which in ("forward", "remat", "backward"))
+        # nothing of the ``jax.numpy`` body: a sub-block's decayed keys,
+        # the pair-by-pair products, the solve's expansion
+        assert not re.search(r"f32\[[\d,]*4,64,128\]", text)
+        assert not re.search(r"f32\[[\d,]*16,16,128\]", text)
+        assert "triangular" not in text
+        # the state between chunks: float32, a head a [128, 128] matrix,
+        # in the scratch of the kernels that walk the chunks
+        from dlrover_tpu.ops.pallas.kda import kda_kernels
+        from dlrover_tpu.ops.pallas.tuning import kda_tiling
+
+        wide = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
+        decay = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.float32)
+        beta = jax.ShapeDtypeStruct((1, 8192, 8), jnp.float32)
+        traced = str(jax.make_jaxpr(functools.partial(
+            kda_kernels, tile=kda_tiling(64, 128)))(
+                wide, wide, wide, decay, beta))
+        assert re.search(r"Ref<vmem>\{f32\[\d+,128,128\]\}", traced)
         # accepted for a chip of 15.75 GiB (a refusal raises): 966.7 M
-        # parameters at 8 bytes of state each are the arguments
+        # parameters at 8 bytes of state each are the arguments; the
+        # temporaries were 9,214,244,864 bytes with the delta rule in
+        # ``jax.numpy`` (the parent of PR 42, this test's configuration)
         mem = compiled.memory_analysis()
         assert 7.7e9 < mem.argument_size_in_bytes < 7.8e9
+        assert mem.temp_size_in_bytes <= 9_214_244_864
 
     def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
         """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped
