@@ -95,6 +95,18 @@ class LlamaConfig:
     kda_head_dim: int = 128
     kda_conv: int = 4
     kda_chunk: int = 64
+    # training by diffusion over blocks (BD3-LMs, arXiv:2503.09573; SDAR,
+    # arXiv:2510.06303): the length of a block, 0 for none.  The model then
+    # runs ``[noisy copy ; clean copy]`` of its input, ``2S`` rows at the
+    # positions ``0..S-1`` twice, under the block-diffusion mask
+    # (``ops/attention.py::block_diffusion_attention``), returns the noisy
+    # half's logits and sows its own objective, the NELBO over the tokens
+    # ``noise_blocks`` masked (to ``mask_token_id``, at rates from
+    # ``noise_eps`` to 1 a block), from the key of ``step_rngs``
+    block_diffusion: int = 0
+    mask_token_id: int = 0
+    noise_eps: float = 1e-3
+    noise_seed: int = 0
 
     def __post_init__(self):
         valid = ("reference", "flash", "ring")
@@ -124,6 +136,30 @@ class LlamaConfig:
                 f"layer_pattern={self.layer_pattern!r}: kinds of "
                 f"{LAYER_KINDS}, a whole number of periods in num_layers="
                 f"{self.num_layers}, and kda_heads where it has a kda layer")
+        if self.block_diffusion and (
+                self.index_topk or self.eva_window or self.layer_pattern
+                or self.pred_heads > 1
+                or not 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                "block_diffusion needs plain softmax layers, one prediction "
+                "head and a mask_token_id inside the vocabulary")
+
+    @property
+    def own_objective(self) -> bool:
+        """Whether the model sows its whole objective into ``losses``: a
+        trainer then adds no next-token cross entropy on top."""
+        return bool(self.block_diffusion)
+
+    def step_rngs(self, step) -> dict:
+        """The random streams ``model.apply`` wants in training step
+        ``step`` (``rngs=``; empty: none): a function of the step alone, so
+        the noise differs by step, costs the state nothing and a resumed
+        job draws what the uninterrupted one drew.  A call without them (a
+        forward check, ``model.init``) draws step 0's."""
+        if not self.block_diffusion:
+            return {}
+        return {"noise": jax.random.fold_in(
+            jax.random.PRNGKey(self.noise_seed), step)}
 
     def layer_runs(self):
         """One period as runs of equal layers, ``[(name, kind, length)]``:
@@ -184,6 +220,23 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
     return rotated.astype(x.dtype)
+
+
+def noise_blocks(ids, key, block, mask_id, eps=1e-3):
+    """Block diffusion's forward process under the linear schedule:
+    ``(noisy_ids, weights)`` of ``ids`` [B, S] in blocks of ``block``.  A
+    block draws its rate ``t = eps + (1 - eps) u``, ``u ~ U(0, 1)``; a
+    token is masked, ``m = 1``, with probability its block's ``t`` and
+    then reads ``mask_id``; ``weights = m / t`` in float32, the NELBO's
+    weight of the token's cross entropy."""
+    B, S = ids.shape
+    rate_key, token_key = jax.random.split(key)
+    t = eps + (1.0 - eps) * jax.random.uniform(
+        rate_key, (B, S // block), jnp.float32)
+    t = jnp.repeat(t, block, axis=1)
+    masked = jax.random.uniform(token_key, (B, S), jnp.float32) < t
+    return (jnp.where(masked, jnp.asarray(mask_id, ids.dtype), ids),
+            jnp.where(masked, 1.0 / t, 0.0))
 
 
 class RMSNorm(nn.Module):
@@ -276,6 +329,11 @@ class Attention(nn.Module):
             out = self._attend_indexed(x, q, k, v, positions, dense)
         elif cfg.eva_window:
             out = self._attend_eva(q, k, v)
+        elif cfg.block_diffusion:
+            from dlrover_tpu.ops.attention import block_diffusion_attention
+
+            with jax.named_scope("attn.core"):
+                out = block_diffusion_attention(q, k, v, cfg.block_diffusion)
         else:
             with jax.named_scope("attn.core"):
                 out = self._attend(q, k, v, mask)
@@ -733,18 +791,24 @@ class LlamaForCausalLM(nn.Module):
             (cfg.vocab_size, cfg.hidden_size),
             cfg.param_dtype,
         )
+        rows = input_ids
+        if cfg.block_diffusion:
+            rows, weights = self._noisy_and_clean(input_ids)
         with jax.named_scope("embed"):
-            x = embed.astype(cfg.dtype)[input_ids]
+            x = embed.astype(cfg.dtype)[rows]
             if cfg.residual_dtype is not None:
                 x = x.astype(cfg.residual_dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+        if cfg.block_diffusion:     # each copy of the sequence counts 0..S-1
+            positions = jnp.concatenate([positions, positions], axis=1)
         # only the reference core reads a mask: an indexer's attention makes
         # its own, a block at a time, so does the attention over windows and
         # summaries, the kernel is causal by position and a ``kda`` layer
         # has no scores
         mask = None if (
             cfg.index_topk or cfg.eva_window or cfg.attention_impl == "flash"
+            or cfg.block_diffusion
             or (cfg.layer_pattern and "gqa" not in cfg.layer_pattern)
         ) else jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
 
@@ -762,13 +826,41 @@ class LlamaForCausalLM(nn.Module):
             for i in range(cfg.num_layers):
                 x, _ = layer_cls(cfg, name=f"layers_{i}")(x, positions, mask)
 
+        if cfg.block_diffusion:     # the clean half yields no logits
+            x = x[:, :S]
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                     unit_offset=cfg.norm_unit_offset, name="final_norm")(x)
         logits = LMHead(cfg, name="lm_head")(x)
+        if cfg.block_diffusion:
+            with jax.named_scope("head_loss"):
+                self._sow_nelbo(logits, input_ids, weights)
         if cfg.pred_heads > 1:
             with jax.named_scope("head_loss"):
                 logits = self._first_head(logits, input_ids)
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
+
+    def _noisy_and_clean(self, input_ids):
+        """``([noisy copy ; clean copy] [B, 2S], the NELBO's weights [B,
+        S])`` from the step's ``noise`` stream, step 0's where the caller
+        gave none."""
+        cfg = self.config
+        key = (self.make_rng("noise") if self.has_rng("noise")
+               else cfg.step_rngs(0)["noise"])
+        with jax.named_scope("embed"), jax.named_scope("noise"):
+            noisy, weights = noise_blocks(
+                input_ids, key, cfg.block_diffusion, cfg.mask_token_id,
+                cfg.noise_eps)
+            self.sow("stats", "bd_masked_share", jnp.mean(weights > 0))
+            self.sow("stats", "bd_weight_max", jnp.max(weights))
+            return jnp.concatenate([noisy, input_ids], axis=1), weights
+
+    def _sow_nelbo(self, logits, input_ids, weights):
+        """The objective's first term into ``losses``: ``(1 / (B S)) sum_i
+        m_i / t_b(i) CE(logits of noisy row i, the clean token AT position
+        i)``: no shift, and nothing from a token left unmasked."""
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        taken = jnp.take_along_axis(logp, input_ids[..., None], axis=-1)[..., 0]
+        self.sow("losses", "nelbo", -jnp.mean(weights * taken))
 
     def _first_head(self, logits, input_ids):
         """The first prediction head's logits ``[B, S, vocab]``; the loss

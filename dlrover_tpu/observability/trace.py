@@ -500,7 +500,14 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
                   "pool", "windows", "summary_mass",
                   # a gated delta-rule layer (``models/llama.py::
                   # DeltaAttention``, ``ops/linear_attention.py``)
-                  "conv", "decay", "chunk", "state", "gate"),
+                  "conv", "decay", "chunk", "state", "gate",
+                  # attention under the block-diffusion mask (``ops/
+                  # attention.py::block_diffusion_attention``): the joining
+                  # of a noisy block's keys and the mask from ``iota``, the
+                  # clean half's and the noisy half's attention
+                  "bd_keys", "bd_clean", "bd_noisy"),
+    # the sampling of block diffusion's noise (``models/llama.py``)
+    "embed": ("noise",),
     "moe": ("route", "sort", "gmm", "exchange", "combine", "shared"),
 }
 

@@ -21,7 +21,10 @@ part of the indexer's loss in ``jax.numpy`` everywhere.  ``eva_attention``
 own window exactly and every earlier window through learned summaries of
 its chunks, under one softmax: a window of queries at a time, through the
 selected attention's kernels' other entry point on a TPU and in
-``jax.numpy`` elsewhere.
+``jax.numpy`` elsewhere.  ``block_diffusion_attention`` (last) is the
+attention of a model trained by diffusion over blocks: a noisy and a clean
+copy of a sequence in one row of ``2S`` positions, under a mask that is not
+causal, a block of queries at a time through the same entry point.
 """
 
 import functools
@@ -599,3 +602,128 @@ def eva_attention(q, k, v, mu, phi, window, chunk):
         out = jnp.concatenate(outs, axis=1)
     later_queries = B * H * (S - window)
     return out, mass / max(later_queries, 1), largest.mean()
+
+
+# --------------------------------------------------------------------------
+# Attention under the block-diffusion mask (BD3-LMs, arXiv:2503.09573, the
+# vectorised training step; SDAR, arXiv:2510.06303): a noisy and a clean
+# copy of one sequence in one batch row
+# --------------------------------------------------------------------------
+
+def block_diffusion_pairs(seq: int, block: int) -> int:
+    """Query-key pairs a head the mask allows: the clean half causal by
+    block (``S^2 / 2 + L S / 2``), the noisy half every earlier clean block
+    (``S^2 / 2 - L S / 2``) and its own noisy block (``L S``)."""
+    return seq * seq + block * seq
+
+
+def block_diffusion_keep(first, last, block, noisy):
+    """The mask of the queries at positions ``[first, last)``: ``keep [1,
+    last - first, keys]`` bool, from the rule by ``iota``.  A clean query
+    at position ``r`` over the clean keys ``[0, last)``: allowed where
+    ``c // block <= r // block`` (causal by block, a block both ways).  A
+    noisy one over ``[clean keys [0, last) ; noisy keys [first, last)]``:
+    a clean key where ``c // block < r // block`` (every EARLIER block), a
+    noisy key where ``c // block == r // block`` (its own block, both
+    ways).  A clean query sees no noisy key: none is among its keys."""
+    r = jnp.arange(first, last)[:, None] // block
+    c = jnp.arange(last)[None, :] // block
+    if not noisy:
+        return (c <= r)[None]
+    own = jnp.arange(first, last)[None, :] // block
+    return jnp.concatenate([c < r, own == r], axis=1)[None]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "first", "block", "noisy", "block_kv", "interpret"))
+def _block_diffusion_block(q, k, v, *, first, block, noisy, block_kv,
+                           interpret=False):
+    """One block of queries ``q`` [B, n, H, D] at positions ``[first, first
+    + n)`` over the keys the mask can allow it, ``k``/``v`` [B, keys, G, D]:
+    the clean keys ``[0, first + n)`` and, for ``noisy`` queries, their own
+    noisy keys joined behind them, ONE softmax over both parts.
+    ``block_kv`` ``None``: ``jax.numpy`` over dense ``[heads, n, keys]``
+    scores, rematerialised; else the mask-operand kernels at tiles of at
+    most so many keys.  Under ``jax.jit``, as ``_attend_block``: a second
+    trace of the model finds a block traced."""
+    B, n = q.shape[:2]
+    with jax.named_scope("bd_keys"):
+        keep = jnp.broadcast_to(
+            block_diffusion_keep(first, first + n, block, noisy),
+            (B, n, k.shape[1]))
+    with jax.named_scope("bd_noisy" if noisy else "bd_clean"):
+        if block_kv is None:
+            return jax.checkpoint(
+                lambda *operands: _dense_selected(*operands)[0])(q, k, v, keep)
+        from dlrover_tpu.ops.pallas.selected_attention import masked_attention
+
+        return masked_attention(q, k, v, keep, None, block_kv, interpret)[0]
+
+
+def block_diffusion_path(backend: str, seq: int, query_block: int,
+                         head_dim: int, heads: int, kv_heads: int) -> str:
+    """``"pallas"`` or ``"jnp"``: which body attends to a block of queries
+    under the block-diffusion mask, from what the code can observe (as
+    ``selected_attend_path``): the kernels on a TPU where they take the
+    block and every block of the sequence is a whole one."""
+    if seq % query_block == 0 and selected_attend_path(
+            backend, query_block, head_dim, heads, kv_heads) == "pallas":
+        return "pallas"
+    return "jnp"
+
+
+def block_diffusion_attention(q, k, v, block, query_block=512):
+    """Attention of the ``2S`` rows ``[noisy copy ; clean copy]`` of one
+    sequence of ``S`` positions in blocks of ``block``, ``q`` [B, 2S, H, D],
+    ``k``/``v`` [B, 2S, G, D] (GQA), under the block-diffusion mask: a clean
+    row sees the clean rows of its own and every earlier block; a noisy row
+    sees the NOISY rows of its own block and the CLEAN rows of every
+    earlier block, never its own block's clean rows; no row sees a noisy
+    row of another block.  ``S^2 + block S`` pairs a head
+    (``block_diffusion_pairs``), twice a causal layer's.
+
+    By blocks of ``query_block`` queries (a multiple of ``block``, so no
+    block of the sequence straddles two), each against only the keys the
+    mask can allow it (``block_diffusion_keep``), so nothing ``[2S, 2S]``
+    is ever whole: a block's mask is ``[query_block, keys]``, its scores
+    ``[H, query_block, keys]`` in ``jax.numpy`` and tiles in fast memory in
+    the mask-operand kernels (``ops/pallas/selected_attention.py::
+    masked_attention``), which run on a TPU at the shapes they take
+    (``block_diffusion_path``).  The kernels multiply every pair of a block
+    under its mask: ``S^2 + 2 query_block S`` a head for the allowed ``S^2 +
+    block S``."""
+    B, rows, H, D = q.shape
+    S = rows // 2
+    if rows % 2 or S % block:
+        raise ValueError(f"{rows} rows are not two copies of a whole number "
+                         f"of blocks of {block}")
+    query_block = min(query_block, S)
+    if query_block % block:
+        raise ValueError(f"a block of {query_block} queries straddles the "
+                         f"sequence's blocks of {block}")
+    path = dict(exact=block_diffusion_path(
+        jax.default_backend(), S, query_block, D, H, k.shape[2]))
+    block_kv = None
+    if path["exact"] == "pallas":
+        from dlrover_tpu.ops.pallas.tuning import selected_tiling
+
+        block_kv = selected_tiling(query_block, D)[0]
+        path.update(block_kv=block_kv)
+    trace.note_trace_time(
+        "attention.path", impl="block_diffusion", seq=S, rows=rows,
+        block=block, query_block=query_block,
+        pairs=block_diffusion_pairs(S, block), heads=H, head_dim=D, **path)
+    noisy, clean = [], []
+    for first in range(0, S, query_block):
+        last = min(first + query_block, S)
+        seen = slice(S, S + last)
+        attend = functools.partial(
+            _block_diffusion_block, first=first, block=block,
+            block_kv=block_kv)
+        clean.append(attend(
+            q[:, S + first: S + last], k[:, seen], v[:, seen], noisy=False))
+        with jax.named_scope("bd_keys"):    # [clean keys ; own noisy keys]
+            joined = [jnp.concatenate([t[:, seen], t[:, first:last]], axis=1)
+                      for t in (k, v)]
+        noisy.append(attend(q[:, first:last], *joined, noisy=True))
+    return jnp.concatenate(noisy + clean, axis=1)

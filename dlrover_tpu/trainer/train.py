@@ -713,14 +713,22 @@ class Trainer:
         """Cross entropy plus every term the model sows into its
         ``losses`` collection, weighted by the model (a routed block's
         load-balancing and z-loss; nothing for a dense model), and what
-        it sows into ``stats``."""
+        it sows into ``stats``.  A model whose configuration declares its
+        ``own_objective`` (block diffusion's NELBO) is given no cross
+        entropy on top: its loss is what it sows, and the batch's
+        ``labels`` go unused.  ``batch["rngs"]``: the step's random streams
+        for a model that draws some (``_model_step_rngs``)."""
         logits, sown = self.model.apply(
             {"params": params}, batch["input_ids"],
-            mutable=["losses", "stats"],
+            mutable=["losses", "stats"], rngs=batch.get("rngs"),
         )
+        config = getattr(self.model, "config", None)
         with jax.named_scope("head_loss"):
-            loss = cross_entropy_loss(
-                logits, batch["labels"], batch.get("mask"))
+            if getattr(config, "own_objective", False):
+                loss = jnp.float32(0)
+            else:
+                loss = cross_entropy_loss(
+                    logits, batch["labels"], batch.get("mask"))
             for term in jax.tree.leaves(sown.get("losses", {})):
                 loss = loss + jnp.sum(term)
         return loss, sown.get("stats", {})
@@ -742,7 +750,24 @@ class Trainer:
         (loss, _), grads = self._loss_and_grads(params, batch)
         return loss, grads
 
+    def _model_step_rngs(self, step) -> Dict:
+        """The random streams the model's configuration asks for in step
+        ``step`` (``step_rngs``: block diffusion's noise), ``{}`` for a
+        model that draws nothing: keys made from the step inside the
+        compiled program, so the state carries none."""
+        step_rngs = getattr(
+            getattr(self.model, "config", None), "step_rngs", None)
+        return step_rngs(step) if step_rngs else {}
+
     def _train_step(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
+        rngs = self._model_step_rngs(state.step)
+        if rngs:
+            if self._sync_active or self.grad_accum_steps != 1:
+                # both split the batch by rows, keys and all
+                raise NotImplementedError(
+                    "a model that draws noise in its step runs on the exact "
+                    "path without gradient accumulation")
+            batch = {**batch, "rngs": rngs}
         if self._sync_active:
             return self._sync_train_step(state, batch)
         return self._exact_train_step(state, batch)

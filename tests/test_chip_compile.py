@@ -532,6 +532,71 @@ class TestTrainerStep:
         assert 7.7e9 < mem.argument_size_in_bytes < 7.8e9
         assert mem.temp_size_in_bytes <= 9_214_244_864
 
+    def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
+        """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
+        widths and share (32 query heads on 4 key heads of 128, 16 of 128
+        experts, an eighth of the vocabulary), B1 S2048 = 4096 rows (the
+        cell's 8192 compile 64 kernel shapes: minutes): the attention under
+        the block-diffusion mask goes through the mask-operand kernels, a
+        block of 512 queries a call with that block's mask ``s8[1,512,
+        keys]`` among its operands, the noisy half's over 512 keys more; no
+        array has two dimensions of the rows; every call stands under the
+        sub-scopes the benchmark's readers sum, and the noise under its
+        own."""
+        from dlrover_tpu.models.llama import LlamaForCausalLM
+        from dlrover_tpu.models.moe import MoELlamaConfig
+        from dlrover_tpu.observability import trace
+
+        def one_layer():
+            cfg = MoELlamaConfig(
+                vocab_size=18992, hidden_size=2048, intermediate_size=768,
+                num_layers=1, num_heads=32, num_kv_heads=4, head_dim=128,
+                max_seq_len=2048, rope_theta=1e6, rms_norm_eps=1e-6,
+                qk_norm="head", num_experts=128, top_k=8,
+                norm_topk_prob=True, experts_held=16,
+                load_balance_coef=0.001, router_z_coef=0.0,
+                block_diffusion=4, mask_token_id=18991)
+            return LlamaForCausalLM(cfg), (1, 2048)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh, one_layer)
+        text = compiled.as_text()
+        assert "4096,4096]" not in text
+        # no block's scores are an array (the LSE is ``f32[1,32,512,128]``)
+        assert not re.search(
+            r"f32\[(1,)?32,512,(512|1024|1536|2048|2560)\]", text)
+        calls = [line.strip() for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and "s8[1,512," in line]    # not the grouped matmuls
+        # four blocks of queries, clean and noisy, forward and backward
+        # (with one layer the compiler finds the rematerialised forward in
+        # the forward)
+        assert len(calls) == 4 * 2 * 2
+        masks = sorted(int(found.group(1)) for call in calls
+                       for found in [re.search(r"s8\[1,512,(\d+)\]", call)])
+        assert masks == sorted(
+            2 * [512 * (j + 1) for j in range(4)]
+            + 2 * [512 * (j + 2) for j in range(4)])
+        found = trace.parse_device_scopes(text)
+        names = re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        kernels = sorted(found.scopes["%" + name] for name in names
+                         if found.scopes["%" + name][0] == "attn.core")
+        assert kernels == sorted(
+            4 * [("attn.core", sub, which)
+                 for sub in ("bd_clean", "bd_noisy")
+                 for which in ("forward", "backward")])
+        scopes = set(found.scopes.values())
+        assert {sub for kind, sub, _ in scopes if kind == "attn.core"} >= {
+            "bd_keys", "bd_clean", "bd_noisy"}
+        assert ("embed", "noise", "forward") in scopes
+        # the benchmark's readers take these and nothing of another model
+        reader = _load_layer_metric("bd_attn_ms_per_step")
+        assert set(reader.SUB_SCOPES) == {"bd_keys", "bd_clean", "bd_noisy"}
+        # 172.5 M parameters at 8 bytes of state each are the arguments
+        mem = compiled.memory_analysis()
+        assert 1.37e9 < mem.argument_size_in_bytes < 1.40e9
+
     def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
         """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped
         matmuls are the compiler's own kernel, forward and both gradients;
