@@ -9,7 +9,15 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
   dp/fsdp/tp/cp/ep mesh — GSPMD inserts all collectives;
 * bf16 compute on the MXU, fp32 master params and fp32 softmax/logits;
 * layers are ``nn.scan``-stacked (one trace regardless of depth) and
-  ``nn.remat``-checkpointed to trade FLOPs for HBM;
+  ``nn.remat``-checkpointed to trade FLOPs for HBM: the forward pass keeps
+  a layer's input and, where the attention core runs Pallas kernels that
+  name their results (``ops/pallas/kept.py``: the mask-operand attention's
+  ``out`` and its LSE as ``[B, H, Q]`` float32, the gated delta rule's
+  chunk and state results), those; the backward pass recomputes everything
+  ``jax.numpy`` computes in the layer (norms, projections, RoPE, masks,
+  searches, experts) and runs no forward kernel whose results were kept.
+  The FA2 kernel and every ``jax.numpy`` core name nothing: their layers
+  are recomputed whole;
 * attention is GQA with rotary embeddings; the inner kernel is pluggable
   (jnp reference path here, Pallas flash/ring attention in
   ``dlrover_tpu.ops``).
@@ -25,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.observability import trace
+from dlrover_tpu.ops.pallas.kept import LAYER_POLICY
 
 Dtype = Any
 
@@ -700,14 +709,17 @@ def _stacked(layer_cls, length, axis="layers"):
 
 
 def _layer_class(cfg, scanned):
-    """``_ScannedLayer``, rematerialised where the configuration says so."""
+    """``_ScannedLayer``, rematerialised where the configuration says so:
+    the forward pass keeps a layer's input and what its attention core's
+    forward kernels wrote under a name of ``ops/pallas/kept.py``; the
+    backward pass computes the rest of the layer again."""
     if not cfg.remat:
         return _ScannedLayer
     return nn.remat(
         _ScannedLayer,
         prevent_cse=not scanned,
         static_argnums=(),
-        policy=jax.checkpoint_policies.nothing_saveable,
+        policy=LAYER_POLICY,
     )
 
 

@@ -24,6 +24,7 @@ from dlrover_tpu.models.llama import (
     LlamaForCausalLM,
     RMSNorm,
 )
+from dlrover_tpu.ops.pallas.kept import LAYER_POLICY
 from dlrover_tpu.parallel.pipeline import pipeline_apply, stage_params
 from dlrover_tpu.parallel.sharding import unbox_params
 
@@ -71,9 +72,9 @@ class PipelinedLlama:
             return out, None
 
         if cfg.remat:
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.nothing_saveable
-            )
+            # the scanned stack's own policy (``models/llama.py::
+            # _layer_class``): the same object, so the two cannot drift
+            body = jax.checkpoint(body, policy=LAYER_POLICY)
 
         def stage(sp, x):
             h, _ = jax.lax.scan(body, x, sp)

@@ -307,9 +307,13 @@ def _attend_selected_kernels(q, k, v, index_scores, keep, tiling,
     """``_attend_selected`` with the attention and the heads' mean
     probabilities in Pallas kernels: no ``[heads, q, keys]`` tensor leaves
     the chip's fast memory, forward or backward.  The kernels' custom
-    gradient keeps ``out`` and the LSE; the loss, rematerialised, keeps
-    ``target`` and the index scores it was given (16 MB for the last block
-    of 8192 keys), not its softmax."""
+    gradient keeps ``out`` and the LSE, and names them so that the layer's
+    rematerialisation keeps them too (``ops/pallas/kept.py``; the LSE as
+    ``[B, H, q]`` float32): the backward pass of a rematerialised layer
+    runs the heads' mean kernel again, from the kept LSE, and not the
+    attention's forward kernel.  The loss, rematerialised, keeps ``target``
+    and the index scores it was given (16 MB for the last block of 8192
+    keys), not its softmax; the layer keeps neither."""
     from dlrover_tpu.ops.pallas.selected_attention import selected_attention
 
     with jax.named_scope("selected"):
@@ -325,6 +329,17 @@ def _index_scores_kernels(index_q, index_k, index_w, tiling, interpret=False):
     from dlrover_tpu.ops.pallas.index_scores import index_scores
 
     return index_scores(index_q, index_k, index_w, tiling, interpret)
+
+
+def _note_kept(core, q):
+    """``remat.kept``, beside ``attention.path``: what a rematerialised
+    layer keeps of the mask-operand kernels' calls over all of ``q``'s
+    rows.  A core on its ``jax.numpy`` body keeps nothing and notes
+    nothing."""
+    from dlrover_tpu.ops.pallas import kept
+    from dlrover_tpu.ops.pallas.selected_attention import kept_bytes
+
+    kept.note(core, **kept_bytes(q))
 
 
 def selected_attend_path(backend: str, block: int, head_dim: int, heads: int,
@@ -435,6 +450,8 @@ def indexed_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
         "attention.path", impl="indexed_sparse", seq=S, head_dim=D, heads=H,
         topk=topk, index_heads=J, index_dim=C, block=block,
         select="threshold_by_counting", **path)
+    if tiling is not None:
+        _note_kept("indexed_sparse", q)
     index_w = index_w.astype(jnp.float32) * (J * C) ** -0.5
     outs, loss, low = [], jnp.float32(0), jnp.float32(0)
     for first in range(0, S, block):
@@ -509,9 +526,13 @@ def _eva_window_kernels(q, k, v, pooled_k, pooled_v, *, block_kv,
     ``ops/pallas/selected_attention.py``: no ``[H, W, keys]`` tensor leaves
     the chip's fast memory, forward or backward.  The window's keys go under
     a causal mask, the kernels' operand; the summaries are the keys every
-    query attends to.  Not rematerialised: the kernels' custom gradient
-    keeps ``out`` and the LSE.  The mass on the summaries is ``sum exp(q .
-    pooled_k - lse)``, one product more over the summaries' columns alone.
+    query attends to.  No ``jax.checkpoint`` of its own: the kernels'
+    custom gradient keeps ``out`` and the LSE, and names them so that the
+    layer's rematerialisation, which recomputes the mask, the slices and
+    the mass below, keeps them too (``ops/pallas/kept.py``; the LSE as
+    ``[B, H, W]`` float32) and runs no forward kernel a second time.  The
+    mass on the summaries is ``sum exp(q . pooled_k - lse)``, one product
+    more over the summaries' columns alone.
     Under ``jax.jit``, as ``_attend_block``: a second trace of the model
     finds a window's kernels traced."""
     from dlrover_tpu.ops.pallas.selected_attention import masked_attention
@@ -586,6 +607,8 @@ def eva_attention(q, k, v, mu, phi, window, chunk):
         "attention.path", impl="eva", seq=S, window=window, chunk=chunk,
         windows=windows, summaries_max=(windows - 1) * per_window, heads=H,
         head_dim=D, **path)
+    if path["exact"] == "pallas":
+        _note_kept("eva", q)
     with jax.named_scope("pool"):
         pooled_k, pooled_v, largest = eva_pool(k, v, mu, phi, chunk)
     outs, mass = [], jnp.float32(0)
@@ -713,6 +736,8 @@ def block_diffusion_attention(q, k, v, block, query_block=512):
         "attention.path", impl="block_diffusion", seq=S, rows=rows,
         block=block, query_block=query_block,
         pairs=block_diffusion_pairs(S, block), heads=H, head_dim=D, **path)
+    if block_kv is not None:
+        _note_kept("block_diffusion", q)
     noisy, clean = [], []
     for first in range(0, S, query_block):
         last = min(first + query_block, S)

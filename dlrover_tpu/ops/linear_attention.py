@@ -169,8 +169,10 @@ def kda(q, k, v, g, beta, chunk=64):
 def _kda_kernels(q, k, v, g, beta, tile, interpret=False):
     """The kernels' entry, a name of this module so that a test can run
     them in the interpreter."""
-    from dlrover_tpu.ops.pallas.kda import kda_kernels
+    from dlrover_tpu.ops.pallas import kept
+    from dlrover_tpu.ops.pallas.kda import kda_kernels, kept_bytes
 
+    kept.note("kda", **kept_bytes(q))
     return kda_kernels(q, k, v, g, beta, tile=tile, interpret=interpret)
 
 

@@ -46,6 +46,16 @@ the forward kept) and the decayed products (computed again, level by
 level) to q, k, v, g and beta.  Residuals: the operands, what the chunk
 kernel wrote, the chunk-start states and ``U`` in the compute dtype.
 
+**What a rematerialised layer keeps.**  The forward rule names what the
+two forward kernels wrote (``kept.py``): the chunk kernel's ``w, u0, qg,
+ke, p, t, th`` as ``kda_chunk`` and the state kernel's ``out, u, starts``
+as ``kda_state`` (``out`` with them: the layer's gate and output
+projection are computed again from it, so without it the state kernel
+would run again whatever else is kept).  A decoder layer's
+rematerialisation keeps exactly those beside its input, so its backward
+pass recomputes the operands (projections, convolutions, the decay) in
+``jax.numpy`` and runs neither forward kernel a second time.
+
 Numerics, as the ``jax.numpy`` body's: matmul operands in the operands'
 dtype with float32 accumulation, cast where that body casts; ``g``, its
 sums, the solve and the state float32; elementwise work float32.
@@ -64,6 +74,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.pallas import kept
 from dlrover_tpu.ops.pallas.flash_attention import LANES
 from dlrover_tpu.ops.pallas.selected_attention import (
     _compiler_params,
@@ -460,6 +471,24 @@ class _Call:
         return pltpu.VMEM((self.heads, LANES, LANES), jnp.float32)
 
 
+def kept_bytes(q) -> dict:
+    """What a layer's rematerialisation keeps of the two forward kernels'
+    results for ``q`` ``[B, S, H, 128]``, in bytes by name: the chunk
+    kernel's ``w, qg, ke`` (as ``q``), ``u0`` (float32), ``p``, ``t``
+    (float32) and ``th`` (float32); the state kernel's ``out, u`` and
+    ``starts``, as ``q``."""
+    item = q.dtype.itemsize
+    wide = q.size                       # [B, S, H * 128]
+    square = wide * CHUNK // LANES      # [B, H, S, 64]
+    through = wide // CHUNK             # [B, S / 64, 1, H * 128]
+    starts = wide * LANES // CHUNK      # [B, S / 64, H, 128, 128]
+    return {
+        kept.KDA_CHUNK: (wide * (3 * item + 4) + square * (item + 4)
+                         + through * 4),
+        kept.KDA_STATE: (2 * wide + starts) * item,
+    }
+
+
 def _flat(t):
     return t.reshape(t.shape[:2] + (-1,))
 
@@ -540,10 +569,11 @@ def _kda(q, k, v, g, beta, tile, interpret):
 def _kda_fwd(q, k, v, g, beta, tile, interpret):
     call = _Call(q, tile)
     with jax.named_scope("chunk"):
-        w, u0, qg, ke, p, t, th = _chunk_forward(
-            call, q, k, v, g, beta, interpret)
+        w, u0, qg, ke, p, t, th = kept.named(kept.KDA_CHUNK, *_chunk_forward(
+            call, q, k, v, g, beta, interpret))
     with jax.named_scope("state"):
-        out, u, starts = _state_forward(call, w, u0, qg, ke, p, th, interpret)
+        out, u, starts = kept.named(kept.KDA_STATE, *_state_forward(
+            call, w, u0, qg, ke, p, th, interpret))
     return out.reshape(v.shape), (
         q, k, v, g, beta, w, u0, qg, ke, p, t, th, u, starts)
 

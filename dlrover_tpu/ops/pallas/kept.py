@@ -1,0 +1,63 @@
+"""What a rematerialised decoder layer keeps of its attention core: the
+results a core's forward KERNELS wrote that its backward kernels read.
+
+A layer of ``models/llama.py`` is rematerialised whole: the forward pass
+keeps the layer's input and the backward pass computes the layer again.
+For everything ``jax.numpy`` computes that is the trade wanted.  For a
+kernel it is the forward kernel run a second time to get back what its
+custom gradient had declared as residuals.  So a kernel-backed core names
+those results inside its custom gradient's forward rule (``named``), and
+the layer's ``nn.remat`` keeps exactly the named values (``LAYER_POLICY``)
+and nothing else.  Neither half does anything alone.
+
+It adapts by what the core runs and has no switch: a core on its
+``jax.numpy`` body names nothing, the FA2 kernel names nothing, and
+``LAYER_POLICY`` over a layer without names is ``nothing_saveable``.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from dlrover_tpu.observability import trace
+
+#: ``out`` of the mask-operand attention kernels
+#: (``selected_attention.py``), in the compute dtype: one more
+#: residual-stream tensor a layer at the heads' width
+ATTN_OUT = "attn_out"
+#: their per-row log-sum-exp as ``[B, H, Q]`` float32, never the kernels'
+#: lane-broadcast ``[B, H, Q, 128]``
+ATTN_LSE = "attn_lse"
+#: what the gated delta rule's chunk kernel wrote (``kda.py``): ``w, u0,
+#: qg, ke, p, t, th``
+KDA_CHUNK = "kda_chunk"
+#: what its state kernel wrote: ``out, u, starts``
+KDA_STATE = "kda_state"
+
+NAMES = (ATTN_OUT, ATTN_LSE, KDA_CHUNK, KDA_STATE)
+
+#: the policy of every rematerialised decoder layer (``models/llama.py::
+#: _layer_class``, ``models/pipeline_llama.py``)
+LAYER_POLICY = jax.checkpoint_policies.save_only_these_names(*NAMES)
+
+
+def named(name, *values):
+    """``values`` under ``name``, for ``LAYER_POLICY`` to keep."""
+    return tuple(checkpoint_name(value, name) for value in values)
+
+
+def nbytes(shape, dtype) -> int:
+    return math.prod(shape) * jnp.dtype(dtype).itemsize
+
+
+def note(core: str, **bytes_by_name: int) -> None:
+    """The ``remat.kept`` record of a compiled program, beside the core's
+    ``attention.path``: the names a layer of this core keeps and the bytes
+    a layer they hold (counted from shapes; the kernels' results carry the
+    names whether or not a ``remat`` stands around the layer)."""
+    trace.note_trace_time(
+        "remat.kept", core=core, names=",".join(bytes_by_name),
+        bytes_per_layer=sum(bytes_by_name.values()),
+        **{f"{name}_bytes": held for name, held in bytes_by_name.items()})

@@ -53,6 +53,17 @@ a causal ``keep`` and the summaries of earlier windows as ``shared`` keys
 that every query attends to: ``2048 + 128 w`` keys have no even tiling
 (17, 19 and 23 times 128), the window's 2048 have, and a kernel's last
 grid step takes the summaries whole.
+
+**What is kept for the backward pass.**  Both entry points' custom
+gradient holds ``(q, k, v, keep, out, lse)`` (and ``shared``), the LSE as
+``[B, H, Q]`` float32: the kernels read and write it lane-broadcast ``[B,
+H, Q, 128]``, 128 times the bytes, and the backward broadcasts it again on
+its way in.  ``out`` and that LSE carry the names ``attn_out`` and
+``attn_lse`` (``kept.py``), which a rematerialised decoder layer keeps
+beside its input: its backward pass recomputes ``q, k, v, keep`` and
+``shared`` in ``jax.numpy`` and does NOT run the forward kernel again.
+``selected_attention``'s ``target`` (``[Q, K]`` float32 a block) has no
+name: the heads' mean kernel runs again, from the kept LSE.
 """
 
 import functools
@@ -62,6 +73,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.pallas import kept
 from dlrover_tpu.ops.pallas.flash_attention import LANES, NEG_INF
 
 KERNEL_HEAD_DIM = LANES
@@ -334,6 +346,30 @@ def _flat(x):
     return x.reshape(*x.shape[:2], -1)
 
 
+def kept_bytes(q) -> dict:
+    """What a layer's rematerialisation keeps of the forward kernel's
+    calls over all of ``q``'s rows ``[B, rows, H, D]``, in bytes by name:
+    ``out`` as ``q`` and the LSE ``[B, H, rows]`` float32."""
+    B, rows, H, _ = q.shape
+    return {kept.ATTN_OUT: kept.nbytes(q.shape, q.dtype),
+            kept.ATTN_LSE: kept.nbytes((B, H, rows), jnp.float32)}
+
+
+def _lanes(lse):
+    """The kept LSE ``[B, H, Q]`` as the kernels read it: lane-broadcast
+    ``[B, H, Q, LANES]``."""
+    return jnp.broadcast_to(lse[..., None], (*lse.shape, LANES))
+
+
+def _attend_kept(q, k, v, keep, shared, block_kv, interpret):
+    """``_attend`` with its results under the names a rematerialised layer
+    keeps: ``(out [B, Q, H, D], lse [B, H, Q])``.  The kernel writes every
+    lane of a row's LSE alike, so lane 0 is all of it."""
+    out, lse = _attend(q, k, v, keep, shared, block_kv, interpret)
+    return (*kept.named(kept.ATTN_OUT, out),
+            *kept.named(kept.ATTN_LSE, lse[..., 0]))
+
+
 def _attend(q, k, v, keep, shared, block_kv, interpret):
     """``(out [B, Q, H, D], lse [B, H, Q, LANES])``; ``keep`` int8."""
     blk = _Block(q, k, block_kv, shared)
@@ -418,16 +454,19 @@ def selected_attention(q, k, v, keep, tiling, interpret: bool = False):
 
 
 def _selected_fwd(q, k, v, keep, tiling, interpret):
-    keep = keep.astype(jnp.int8)  # the kernels' mask, kept for the backward
-    out, lse = _attend(q, k, v, keep, None, tiling[0], interpret)
-    target = _heads_mean(q, k, keep, lse, tiling[1], interpret)
+    keep = keep.astype(jnp.int8)  # the kernels' mask, a residual
+    out, lse = _attend_kept(q, k, v, keep, None, tiling[0], interpret)
+    # from the kept LSE: a rematerialised layer runs this kernel again
+    # (``target`` has no name: ``[Q, K]`` float32 a block) and not the
+    # forward one
+    target = _heads_mean(q, k, keep, _lanes(lse), tiling[1], interpret)
     return (out, target), (q, k, v, keep, out, lse)
 
 
 def _selected_bwd(tiling, interpret, residuals, grads):
     q, k, v, keep, out, lse = residuals
     dq, dk, dv, _ = _backward(
-        q, k, v, keep, None, out, lse, grads[0], tiling[0], interpret)
+        q, k, v, keep, None, out, _lanes(lse), grads[0], tiling[0], interpret)
     return dq, dk, dv, None
 
 
@@ -449,14 +488,15 @@ def masked_attention(q, k, v, keep, shared, block_kv, interpret: bool = False):
 
 def _masked_fwd(q, k, v, keep, shared, block_kv, interpret):
     keep = keep.astype(jnp.int8)
-    out, lse = _attend(q, k, v, keep, shared, block_kv, interpret)
-    return (out, lse[..., 0]), (q, k, v, keep, shared, out, lse)
+    out, lse = _attend_kept(q, k, v, keep, shared, block_kv, interpret)
+    return (out, lse), (q, k, v, keep, shared, out, lse)
 
 
 def _masked_bwd(block_kv, interpret, residuals, grads):
     q, k, v, keep, shared, out, lse = residuals
     dq, dk, dv, shared_grads = _backward(
-        q, k, v, keep, shared, out, lse, grads[0], block_kv, interpret)
+        q, k, v, keep, shared, out, _lanes(lse), grads[0], block_kv,
+        interpret)
     return dq, dk, dv, None, shared_grads
 
 

@@ -90,6 +90,12 @@ def _attend_calls(text):
             and 'custom_call_target="tpu_custom_call"' in line]
 
 
+def _kernel_names(text):
+    """The names of the optimized HLO's Pallas kernel calls."""
+    return re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
 def _load_layer_metric(name):
     """``benchmarks/layer_metrics/<name>.py``, loaded by path as
     ``benchmarks/common.py::load_module`` loads it."""
@@ -486,8 +492,7 @@ class TestTrainerStep:
         compiled = _trainer_step_compiled(mesh, one_period)
         text = compiled.as_text()
         assert "8192,8192]" not in text
-        calls = re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
-                           r'custom_call_target="tpu_custom_call"', text)
+        calls = _kernel_names(text)
         # the one softmax layer: forward, dQ, dK/dV; its loops have one
         # turn each, so the compiler finds the rematerialised forward in
         # the forward (``families/solaropen2.py::fa2_shape`` counts so)
@@ -499,14 +504,16 @@ class TestTrainerStep:
         assert ("moe", "shared", "forward") in set(found.scopes.values())
         # the delta rule's kernels: the three layers are one loop, so one
         # call of each kind a pass, under the sub-scopes the readers of
-        # ``kda_ms_per_step`` and ``kda_state_ms_per_step`` sum
+        # ``kda_ms_per_step`` and ``kda_state_ms_per_step`` sum; and NO
+        # forward kernel in the rematerialised pass: the layer keeps what
+        # both wrote (``ops/pallas/kept.py``)
         kernels = sorted(
             found.scopes["%" + name] for name in calls
             if found.scopes["%" + name][:2] in (
                 ("attn.core", "chunk"), ("attn.core", "state")))
         assert kernels == sorted(
             ("attn.core", sub, which) for sub in ("chunk", "state")
-            for which in ("forward", "remat", "backward"))
+            for which in ("forward", "backward"))
         # nothing of the ``jax.numpy`` body: a sub-block's decayed keys,
         # the pair-by-pair products, the solve's expansion
         assert not re.search(r"f32\[[\d,]*4,64,128\]", text)
@@ -527,10 +534,13 @@ class TestTrainerStep:
         # accepted for a chip of 15.75 GiB (a refusal raises): 966.7 M
         # parameters at 8 bytes of state each are the arguments; the
         # temporaries were 9,214,244,864 bytes with the delta rule in
-        # ``jax.numpy`` (the parent of PR 42, this test's configuration)
+        # ``jax.numpy`` (the parent of PR 42, this test's configuration);
+        # since PR 44 the three ``kda`` layers keep what their forward
+        # kernels wrote, 176,685,056 bytes a layer (``remat.kept``):
+        # 9,378,737,152
         mem = compiled.memory_analysis()
         assert 7.7e9 < mem.argument_size_in_bytes < 7.8e9
-        assert mem.temp_size_in_bytes <= 9_214_244_864
+        assert mem.temp_size_in_bytes <= 9_214_244_864 + 3 * 176_685_056
 
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
@@ -578,8 +588,7 @@ class TestTrainerStep:
             2 * [512 * (j + 1) for j in range(4)]
             + 2 * [512 * (j + 2) for j in range(4)])
         found = trace.parse_device_scopes(text)
-        names = re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
-                           r'custom_call_target="tpu_custom_call"', text)
+        names = _kernel_names(text)
         kernels = sorted(found.scopes["%" + name] for name in names
                          if found.scopes["%" + name][0] == "attn.core")
         assert kernels == sorted(
@@ -596,6 +605,39 @@ class TestTrainerStep:
         # 172.5 M parameters at 8 bytes of state each are the arguments
         mem = compiled.memory_analysis()
         assert 1.37e9 < mem.argument_size_in_bytes < 1.40e9
+
+    def test_block_diffusion_forward_kernels_run_once_a_layer(
+            self, topo, as_if_on_tpu):
+        """Two scanned block-diffusion layers at small widths (8 query
+        heads on 2 key heads of 128, a dense block, B1 S1024 = 2048 rows:
+        two blocks of 512 queries a half): the layers are one loop a pass,
+        and the backward pass's loop holds the four backward kernels and
+        NO forward kernel: the layer's rematerialisation keeps ``out`` and
+        the LSE (``ops/pallas/kept.py``), the LSE as ``[1, 8, 512]`` and
+        never lane-broadcast in the saved stack."""
+        from dlrover_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        from dlrover_tpu.observability import trace
+
+        def two_layers():
+            cfg = LlamaConfig(
+                vocab_size=4096, hidden_size=512, intermediate_size=1024,
+                num_layers=2, num_heads=8, num_kv_heads=2, head_dim=128,
+                max_seq_len=1024, qk_norm="head", block_diffusion=4,
+                mask_token_id=4095)
+            return LlamaForCausalLM(cfg), (1, 1024)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        text = _trainer_step_compiled(mesh, two_layers).as_text()
+        found = trace.parse_device_scopes(text)
+        names = _kernel_names(text)
+        kernels = sorted(found.scopes["%" + name] for name in names)
+        assert kernels == sorted(
+            2 * [("attn.core", sub, which)
+                 for sub in ("bd_clean", "bd_noisy")
+                 for which in ("forward", "backward")])
+        # the stacks the forward loop leaves for the backward one
+        assert "bf16[2,1,512,8,128]" in text and "f32[2,1,8,512]" in text
+        assert "f32[2,1,8,512,128]" not in text
 
     def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
         """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped
@@ -618,8 +660,7 @@ class TestTrainerStep:
         mesh = build_mesh(MeshConfig(ep=4), devices=list(topo.devices))
         compiled = _trainer_step_compiled(mesh, olmoe_one_layer)
         text = compiled.as_text()
-        calls = re.findall(r"%([\w.\-]+) = [^\n]*? custom-call\([^\n]*"
-                           r'custom_call_target="tpu_custom_call"', text)
+        calls = _kernel_names(text)
         assert sum("_attend" in name for name in calls) == 4
         # gate, up, down: forward, recomputed forward, and two gradients,
         # once for each of the four extents the passes may run at

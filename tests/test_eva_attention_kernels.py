@@ -150,7 +150,12 @@ def test_on_a_tpu_the_event_says_pallas_and_its_tile(monkeypatch):
     monkeypatch.setattr(ops, "_eva_window_kernels", interpreted)
     got = ops.eva_attention(*operands, WINDOW, 2)
     block_kv = selected_tiling(WINDOW, DIM)[0]
-    assert records == [_event("pallas", block_kv=block_kv)]
+    event, (kept_name, kept) = records
+    assert event == _event("pallas", block_kv=block_kv)
+    # beside it, what a rematerialised layer keeps of the kernels' calls
+    # (tests/test_remat_kept.py holds the bytes to the residuals)
+    assert kept_name == "remat.kept" and kept["core"] == "eva"
+    assert kept["names"] == "attn_out,attn_lse"
     assert called == [block_kv] * WINDOWS
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=0)

@@ -195,8 +195,11 @@ def test_on_a_tpu_the_event_says_pallas_and_the_tile(monkeypatch):
     records = _records(monkeypatch)
     _as_on_a_tpu(monkeypatch)
     ops.indexed_sparse_attention(*_whole(256), topk=96, block=128)
-    (name, attrs), = records
+    (name, attrs), (kept_name, kept) = records
     assert name == "attention.path"
+    # beside it, what a rematerialised layer keeps of the attention's
+    # kernels (tests/test_remat_kept.py); the index kernels name nothing
+    assert kept_name == "remat.kept" and kept["core"] == "indexed_sparse"
     assert attrs["index"] == "pallas" and attrs["attend"] == "pallas"
     assert attrs["index_block_kv"] == index_tiling(128, C)[0]
     assert (attrs["block_kv"], attrs["mean_block_kv"]) == selected_tiling(

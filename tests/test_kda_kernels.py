@@ -249,9 +249,13 @@ def test_the_event_says_which_core_and_the_tile(monkeypatch, on_a_tpu,
     del records[:]
     out, _ = layer.apply(params, x, None, None, mutable=["stats"])
     assert bool(jnp.isfinite(out).all())
-    (name, attrs), = records
+    (name, attrs), *kept_records = records
     assert name == "attention.path"
     core = dict(core="jnp")
+    # the kernels' core says beside it what a rematerialised layer keeps
+    # (tests/test_remat_kept.py holds the bytes to the residuals)
+    assert [name for name, _ in kept_records] == [
+        "remat.kept"] * (on_a_tpu and head_dim == 128)
     if on_a_tpu and head_dim == 128:
         chunks, heads, state_heads = tuning.kda_tiling(64, 128)
         core = dict(core="pallas", chunks_per_step=chunks,

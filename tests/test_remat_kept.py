@@ -1,0 +1,226 @@
+"""What a rematerialised decoder layer keeps (``ops/pallas/kept.py``): a
+stack of two scanned layers under ``models/llama.py::_layer_class``'s
+policy, once for each attention core, the Pallas kernels in the interpreter
+on the CPU.
+
+For a core whose kernels name their results (``selected_attention``,
+``masked_attention`` without and with ``shared`` keys, ``_kda``): the
+forward pass keeps exactly the layer's input and the named values, the LSE
+as ``[B, H, Q]``, at the bytes a layer that ``remat.kept`` notes; the
+gradient's jaxpr holds each forward kernel once a layer, where it holds it
+twice under ``nothing_saveable``; and the loss and every gradient are those
+under ``nothing_saveable`` bit for bit.  For a core that names nothing (the
+FA2 kernel, the reference, a ``jax.numpy`` body) the two policies lower to
+the same program.
+"""
+
+import collections
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax._src.ad_checkpoint import saved_residuals
+
+from dlrover_tpu.models import llama
+from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.ops import attention as ops
+from dlrover_tpu.ops import linear_attention
+from dlrover_tpu.ops.pallas import kept
+
+LAYERS, HEADS, DIM, HIDDEN = 2, 2, 128, 64
+F32 = jnp.dtype("float32")
+
+
+def _interpreted(module, name):
+    return module, name, functools.partial(
+        getattr(module, name), interpret=True)
+
+
+def _config(**fields):
+    return LlamaConfig.tiny(**{**dict(
+        num_layers=LAYERS, num_heads=HEADS, num_kv_heads=1, head_dim=DIM,
+        hidden_size=HIDDEN, dtype=jnp.float32), **fields})
+
+
+#: core -> (configuration, the layers' kind, rows of the residual stream,
+#: the entry to run in the interpreter, the forward kernels by name with
+#: their calls a layer, the named values a layer keeps as (shape, number))
+Core = collections.namedtuple(
+    "Core", "config kind rows entry forward_kernels kept")
+KERNEL_CORES = {
+    # two blocks of 128 queries, the second selecting 96 of its 256 keys
+    "selected_attention": Core(
+        _config(index_topk=96, index_heads=2, index_head_dim=16,
+                index_block=128, max_seq_len=256), "gqa", 256,
+        (ops, "_attend_selected_kernels"), {"_fwd_kernel": 2},
+        {(1, 128, HEADS, DIM): 2, (1, HEADS, 128): 2}),
+    # 128 data tokens: one block of noisy queries and one of clean
+    "masked_attention": Core(
+        _config(block_diffusion=4, max_seq_len=128), "gqa", 256,
+        (ops, "_block_diffusion_block"), {"_fwd_kernel": 2},
+        {(1, 128, HEADS, DIM): 2, (1, HEADS, 128): 2}),
+    # two windows of 256: the second over the first's 128 summaries
+    "masked_attention_shared": Core(
+        _config(num_kv_heads=HEADS, eva_window=256, eva_chunk=2,
+                max_seq_len=512), "gqa", 512,
+        (ops, "_eva_window_kernels"), {"_fwd_kernel": 2},
+        {(1, 256, HEADS, DIM): 2, (1, HEADS, 256): 2}),
+    # two chunks of 64: w, u0, qg, ke, out, u; p, t; th; starts
+    "_kda": Core(
+        _config(kda_heads=HEADS, kda_head_dim=DIM, max_seq_len=128), "kda",
+        128, (linear_attention, "_kda_kernels"),
+        {"_chunk_fwd_kernel": 1, "_state_fwd_kernel": 1},
+        {(1, 128, HEADS * DIM): 6, (1, HEADS, 128, 64): 2,
+         (1, 2, 1, HEADS * DIM): 1, (1, 2, HEADS, DIM, DIM): 1}),
+}
+#: cores that name nothing: the FA2 kernel, the reference, and the
+#: ``jax.numpy`` bodies the CPU gives the cores above
+UNNAMED_CORES = {
+    "fa2": Core(_config(attention_impl="flash", max_seq_len=128), "gqa", 128,
+                (ops, "flash_attention"), {}, {}),
+    "reference": Core(_config(max_seq_len=64), "gqa", 64, None, {}, {}),
+    "block_diffusion_jnp": Core(
+        _config(block_diffusion=4, max_seq_len=64), "gqa", 128, None, {}, {}),
+    "kda_jnp": Core(
+        _config(kda_heads=HEADS, kda_head_dim=16, max_seq_len=64), "kda", 64,
+        None, {}, {}),
+}
+
+
+class _Stack:
+    """Two scanned layers of ``core`` under ``policy`` and a loss over
+    them; with an ``entry``, as a TPU backend would run it."""
+
+    def __init__(self, monkeypatch, core, policy):
+        self.records = []
+        monkeypatch.setattr(llama, "LAYER_POLICY", policy)
+        monkeypatch.setattr(
+            kept.trace, "note_trace_time",
+            lambda name, **attrs: self.records.append((name, attrs)))
+        if core.entry:
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            monkeypatch.setattr(*_interpreted(*core.entry))
+        cfg = core.config
+        self.stack = llama._stacked(llama._layer_class(cfg, True), LAYERS)(
+            cfg, core.kind)
+        seq = core.rows // 2 if cfg.block_diffusion else core.rows
+        at = jnp.arange(seq)
+        if cfg.block_diffusion:
+            at = jnp.concatenate([at, at])
+        self.positions = at[None]
+        # only the reference core reads it
+        self.mask = jnp.tril(
+            jnp.ones((core.rows, core.rows), bool))[None, None]
+        self.x = jax.random.normal(
+            jax.random.PRNGKey(0), (1, core.rows, HIDDEN))
+        self.params = self.stack.init(
+            jax.random.PRNGKey(1), self.x, self.positions, self.mask)["params"]
+
+    def loss(self, params, x):
+        (y, _), sown = self.stack.apply(
+            {"params": params}, x, self.positions, self.mask,
+            mutable=["losses", "stats"])
+        taught = sum(jnp.sum(t) for t in jax.tree.leaves(
+            sown.get("losses", {})))
+        return jnp.sin(y).sum() + 5.0 * taught
+
+    def value_and_grad(self):
+        """Compiled with the compiler's fusions off: the interpreter hands
+        XLA a kernel as plain instructions, and fused with its neighbours
+        differently in a program that runs it once and in one that runs it
+        twice, its results differ in their last bits.  (On the chip a
+        kernel is opaque, and the same holds of the fusions around a call
+        that is gone.)"""
+        step = jax.jit(jax.value_and_grad(self.loss, argnums=(0, 1)))
+        return step.lower(self.params, self.x).compile(compiler_options={
+            "xla_disable_hlo_passes": "fusion,cpu-instruction-fusion",
+        })(self.params, self.x)
+
+    def kept_note(self):
+        """The one ``remat.kept`` record of the traces so far."""
+        notes = [attrs for name, attrs in self.records if name == "remat.kept"]
+        assert notes and all(note == notes[0] for note in notes)
+        return notes[0]
+
+
+def _kernel_calls(jaxpr, counts=None):
+    """kernel's name -> ``pallas_call`` equations in ``jaxpr`` and every
+    jaxpr inside it (a scan's body counts once: calls a layer)."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["jaxpr"].debug_info.func_name] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CORES))
+def test_the_forward_pass_keeps_the_input_and_the_named_values(
+        monkeypatch, name):
+    core = KERNEL_CORES[name]
+    stack = _Stack(monkeypatch, core, kept.LAYER_POLICY)
+    stacked = collections.Counter(
+        aval.shape[1:] for aval, why in saved_residuals(
+            stack.loss, stack.params, stack.x)
+        if "output of scan" in why and aval.shape[0] == LAYERS)
+    layer_input = {(1, core.rows, HIDDEN): 1}
+    assert stacked == collections.Counter({**core.kept, **layer_input})
+    held = sum(math.prod(shape) * count * F32.itemsize
+               for shape, count in core.kept.items())
+    note = stack.kept_note()
+    assert note["bytes_per_layer"] == held
+    assert note["names"] == ",".join(
+        name for name in kept.NAMES if f"{name}_bytes" in note)
+    assert note["bytes_per_layer"] == sum(
+        note[f"{name}_bytes"] for name in note["names"].split(","))
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CORES))
+def test_the_gradient_runs_each_forward_kernel_once_a_layer(
+        monkeypatch, name):
+    core = KERNEL_CORES[name]
+
+    def calls(policy):
+        stack = _Stack(monkeypatch, core, policy)
+        counts = _kernel_calls(jax.make_jaxpr(
+            jax.grad(stack.loss, argnums=(0, 1)))(stack.params, stack.x).jaxpr)
+        return {kernel: counts[kernel] for kernel in core.forward_kernels}
+
+    assert calls(kept.LAYER_POLICY) == core.forward_kernels
+    # what the policy is for: without a kept name every one runs twice
+    assert calls(jax.checkpoint_policies.nothing_saveable) == {
+        kernel: 2 * n for kernel, n in core.forward_kernels.items()}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CORES))
+def test_loss_and_gradients_are_those_of_a_layer_computed_again_whole(
+        monkeypatch, name):
+    core = KERNEL_CORES[name]
+    got = _Stack(monkeypatch, core, kept.LAYER_POLICY).value_and_grad()
+    want = _Stack(
+        monkeypatch, core,
+        jax.checkpoint_policies.nothing_saveable).value_and_grad()
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want) > 2
+    for a, b in zip(got, want):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("name", list(UNNAMED_CORES))
+def test_a_core_that_names_nothing_is_computed_again_whole(monkeypatch, name):
+    core = UNNAMED_CORES[name]
+
+    def lowered(policy):
+        stack = _Stack(monkeypatch, core, policy)
+        text = jax.jit(jax.grad(stack.loss, argnums=(0, 1))).lower(
+            stack.params, stack.x).as_text()
+        assert not [r for r in stack.records if r[0] == "remat.kept"]
+        return text
+
+    assert lowered(kept.LAYER_POLICY) == lowered(
+        jax.checkpoint_policies.nothing_saveable)
