@@ -13,9 +13,11 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
   a layer's input and, where the attention core runs Pallas kernels that
   name their results (``ops/pallas/kept.py``: the mask-operand attention's
   ``out`` and its LSE as ``[B, H, Q]`` float32, the gated delta rule's
-  chunk and state results), those; the backward pass recomputes everything
-  ``jax.numpy`` computes in the layer (norms, projections, RoPE, masks,
-  searches, experts) and runs no forward kernel whose results were kept.
+  chunk and state results), those, and of a routed feed-forward the two
+  products of its first grouped matmuls (``models/moe.py``); the backward
+  pass recomputes everything else ``jax.numpy`` computes in the layer
+  (norms, projections, RoPE, masks, searches, the experts' sort, gathers
+  and activation) and runs no forward kernel whose results were kept.
   The FA2 kernel and every ``jax.numpy`` core name nothing: their layers
   are recomputed whole;
 * attention is GQA with rotary embeddings; the inner kernel is pluggable
@@ -711,8 +713,9 @@ def _stacked(layer_cls, length, axis="layers"):
 def _layer_class(cfg, scanned):
     """``_ScannedLayer``, rematerialised where the configuration says so:
     the forward pass keeps a layer's input and what its attention core's
-    forward kernels wrote under a name of ``ops/pallas/kept.py``; the
-    backward pass computes the rest of the layer again."""
+    forward kernels, or its experts' first grouped matmuls, wrote under a
+    name of ``ops/pallas/kept.py``; the backward pass computes the rest of
+    the layer again."""
     if not cfg.remat:
         return _ScannedLayer
     return nn.remat(

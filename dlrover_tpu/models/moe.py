@@ -32,9 +32,22 @@ gathered operand of the two sums by token (each token's ``top_k`` slots,
 those with no row here reading one row).  The ``switch`` sits under a
 ``custom_vjp``: differentiated as it stands it pads every branch's
 residuals to the union of all branches, which writes the worst case in the
-small rungs; the backward pass switches over ``jax.vjp`` of the same rung
-and keeps the layer's inputs alone.  Where one chip holds every expert the
-ladder has one rung and there is no ``switch``.
+small rungs; the backward pass switches too.  What the forward pass keeps
+for it is the layer's inputs and, at the ladder's FIRST extent alone, the
+two products of a pass's first grouped matmuls (the sorted rows times
+``gate_w`` and ``up_w``, ``[extent, I]`` each in the compute dtype, under
+``ops/pallas/kept.py``'s ``moe_products``, which a rematerialised decoder
+layer and ``ep``'s loop over source ranks keep, with the sort's two index
+vectors and the groups' sizes under the same name: the products are only
+those rows' under that sort): a pass at the first extent
+pulls its gradient back from them, six grouped matmuls and no forward one,
+nine a layer and pass; a pass on a higher rung pulls back through
+``jax.vjp`` of the rung, which multiplies by ``gate_w`` and ``up_w`` again,
+eleven.  No pull-back asks for the down product (``_down_and_sum``).  It
+adapts by the rung the routing picks: no field, argument or variable.  Where
+one chip holds every expert the ladder has one rung, a ``switch`` over one
+branch is a plain call (no conditional in the step), and the same rules give
+the same nine.
 
 Expert parallelism: ``ep`` ranks hold ``num_experts / ep`` experts each and
 are data ranks for everything else.  Inside a ``shard_map`` the tokens of
@@ -81,6 +94,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models.llama import MLP, LlamaConfig
 from dlrover_tpu.observability import trace
+from dlrover_tpu.ops.pallas import kept
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,37 +230,94 @@ def _rows_of_bwd(slot, g):
 _rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
 
 
+def _sorted(extent, weights, order, inverse, sizes):
+    """``(picked, slot, live)`` of a pass over the first ``extent`` sorted
+    rows: the assignment of each row, the row of each assignment
+    ``[tokens, fan]``, and ``[extent, 1]`` whether a row holds one."""
+    with jax.named_scope("sort"):
+        # the grouped matmul leaves the rows behind the last group
+        # undefined: they are masked on the way in (so no gradient comes
+        # back through them) and on the way out
+        live = (jnp.arange(extent) < sizes.sum())[:, None]
+        return order[:extent], inverse.reshape(weights.shape), live
+
+
+def _grouped(rows, expert_w, sizes):
+    return jax.lax.ragged_dot(rows, expert_w, group_sizes=sizes,
+                              preferred_element_type=rows.dtype)
+
+
 @jax.custom_vjp
-def _weighted_sum(rows, weights, order, slot):
-    """[tokens, D] float32: each token's rows times their weights, summed
-    (``order[:len(rows)]`` is the assignment of each row).  The transpose
-    gathers the token's cotangent for each row and weighs it there, so
-    nothing of the extent ``tokens x fan`` but a vector is built."""
-    return _sum_by_token(rows, slot, weights)
+def _down_and_sum(hidden, down_w, weights, order, slot, sizes, live):
+    """[tokens, D] float32: the rows of ``hidden`` [extent, I] through
+    their experts' ``down_w``, each times its weight, summed by token
+    (``order[:extent]`` is the assignment of each row).  The transpose
+    gathers the token's cotangent for each row and never asks for the
+    product again: with ``t`` the cotangent through ``down_w`` transposed,
+    a weight's gradient is ``sum(hidden * t)`` over a row (what ``sum(g *
+    product)`` is, written on the narrow side), the rows' is ``t`` times
+    the weight, so nothing of the extent ``tokens x fan`` but a vector is
+    built and no forward grouped matmul runs in a backward pass."""
+    with jax.named_scope("gmm"):
+        out = jnp.where(live, _grouped(hidden, down_w, sizes), 0)
+    return _sum_by_token(out, slot, weights)
 
 
-def _weighted_sum_fwd(rows, weights, order, slot):
-    return _weighted_sum(rows, weights, order, slot), (
-        rows, weights, order, slot)
+def _down_and_sum_fwd(*args):
+    return _down_and_sum(*args), args
 
 
-@jax.named_scope("combine")
-def _weighted_sum_bwd(res, g):
-    rows, weights, order, slot = res
-    picked = order[:len(rows)]
-    g = g[picked // slot.shape[1]]
-    by_row = weights.reshape(-1)[picked].astype(jnp.float32)[:, None]
-    d_by_row = (g * rows).sum(axis=-1)
-    # back to the assignments' order: a sort by the permutation, a tenth
-    # of the time of a gather of single elements on the chip
-    d_weights = jax.lax.sort(
-        (order, jnp.pad(d_by_row, (0, len(order) - len(rows)))),
-        num_keys=1)[1]
-    return ((g * by_row).astype(rows.dtype),
-            d_weights.reshape(slot.shape).astype(weights.dtype), None, None)
+def _down_and_sum_bwd(res, g):
+    hidden, down_w, weights, order, slot, sizes, live = res
+    picked = order[:len(hidden)]
+    with jax.named_scope("combine"):
+        g = jnp.where(live, g[picked // slot.shape[1]], 0).astype(hidden.dtype)
+        by_row = weights.reshape(-1)[picked].astype(jnp.float32)[:, None]
+    with jax.named_scope("gmm"):
+        t, = jax.linear_transpose(
+            lambda hidden: _grouped(hidden, down_w, sizes), hidden)(g)
+        d_down, = jax.linear_transpose(
+            lambda down_w: _grouped(
+                (hidden * by_row).astype(hidden.dtype), down_w, sizes),
+            down_w)(g)
+    with jax.named_scope("combine"):
+        # the rows behind the last group are undefined in both factors
+        d_by_row = jnp.where(
+            live[:, 0], (hidden * t).sum(axis=-1, dtype=jnp.float32), 0)
+        # back to the assignments' order: a sort by the permutation, a tenth
+        # of the time of a gather of single elements on the chip
+        d_weights = jax.lax.sort(
+            (order, jnp.pad(d_by_row, (0, len(order) - len(hidden)))),
+            num_keys=1)[1]
+    return ((t * by_row).astype(hidden.dtype), d_down,
+            d_weights.reshape(slot.shape).astype(weights.dtype),
+            None, None, None, None)
 
 
-_weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
+_down_and_sum.defvjp(_down_and_sum_fwd, _down_and_sum_bwd)
+
+
+def _products(extent, x, weights, order, inverse, sizes, gate_w, up_w):
+    """The first half of a pass over the first ``extent`` sorted rows,
+    which hold every row of ``sizes``: the rows gathered, masked and
+    multiplied by their experts' ``gate_w`` and ``up_w``, ``[extent, I]``
+    each in the compute dtype.  What a backward pass keeps of the forward
+    (``kept.MOE_PRODUCTS``)."""
+    picked, slot, live = _sorted(extent, weights, order, inverse, sizes)
+    rows = _rows_of(x, picked, slot)
+    with jax.named_scope("gmm"):
+        rows = jnp.where(live, rows, 0)
+        return _grouped(rows, gate_w, sizes), _grouped(rows, up_w, sizes)
+
+
+def _finish(extent, gate, up, weights, order, inverse, sizes, down_w):
+    """The second half: the experts' weighted results [tokens, D] float32
+    from the two products.  ``silu(gate) * up`` is computed here, forward
+    and backward, and kept by nobody."""
+    _, slot, live = _sorted(extent, weights, order, inverse, sizes)
+    with jax.named_scope("gmm"):
+        hidden = nn.silu(gate) * up
+    return _down_and_sum(hidden, down_w, weights, order, slot, sizes, live)
 
 
 def _rung(extent, x, weights, order, inverse, sizes, gate_w, up_w, down_w):
@@ -254,23 +325,9 @@ def _rung(extent, x, weights, order, inverse, sizes, gate_w, up_w, down_w):
     ``extent`` sorted rows, which hold every row of ``sizes``.  Only the
     index vectors ``order`` and ``inverse`` have the extent of all
     assignments."""
-    with jax.named_scope("sort"):
-        picked = order[:extent]
-        slot = inverse.reshape(weights.shape)
-        # the grouped matmul leaves the rows behind the last group
-        # undefined: they are masked on the way in (so no gradient comes
-        # back through them) and on the way out
-        live = (jnp.arange(extent) < sizes.sum())[:, None]
-    rows = _rows_of(x, picked, slot)
-    with jax.named_scope("gmm"):
-        rows = jnp.where(live, rows, 0)
-        grouped = functools.partial(
-            jax.lax.ragged_dot, group_sizes=sizes,
-            preferred_element_type=x.dtype
-        )
-        hidden = nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
-        out = jnp.where(live, grouped(hidden, down_w), 0)
-    return _weighted_sum(out, weights, order, slot)
+    index = (weights, order, inverse, sizes)
+    gate, up = _products(extent, x, *index, gate_w, up_w)
+    return _finish(extent, gate, up, *index, down_w)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -278,19 +335,52 @@ def _at_rung(extents, rung, x, weights, order, inverse, sizes, *expert_w):
     """``_rung`` at ``extents[rung]``, ``rung`` a value on the device.
     ``jax.lax.switch`` differentiated as it stands pads every branch's
     residuals to the union of all branches, which would write the worst
-    case in the small rungs: the backward pass switches over ``jax.vjp``
-    of the same rung instead, and keeps the inputs alone."""
+    case in the small rungs.  So the forward rule keeps the inputs and, at
+    the FIRST extent alone, the two products of ``_products`` (under
+    ``kept.MOE_PRODUCTS``, on the switch's result: a rematerialised layer
+    then has them without running the switch again; a higher rung hands
+    back zeros of that shape).  The backward pass switches too: on the
+    first rung it pulls back from the kept products, six grouped matmuls
+    and no forward one; on a higher rung through ``jax.vjp`` of the same
+    rung, which runs gate and up again."""
     return jax.lax.switch(
         rung, [functools.partial(_rung, extent) for extent in extents],
         x, weights, order, inverse, sizes, *expert_w)
 
 
 def _at_rung_fwd(extents, rung, *args):
-    return _at_rung(extents, rung, *args), (rung, args)
+    def first(x, weights, order, inverse, sizes, gate_w, up_w, down_w):
+        index = (weights, order, inverse, sizes)
+        products = _products(extents[0], x, *index, gate_w, up_w)
+        return _finish(extents[0], *products, *index, down_w), products
+
+    def above(extent):
+        def branch(x, *rest):
+            nothing = jnp.zeros((extents[0], rest[-1].shape[1]), x.dtype)
+            return _rung(extent, x, *rest), (nothing, nothing)
+        return branch
+
+    out, products = jax.lax.switch(
+        rung, [first] + [above(extent) for extent in extents[1:]], *args)
+    return out, (rung, args, kept.named(kept.MOE_PRODUCTS, *products))
 
 
 def _at_rung_bwd(extents, res, g):
-    def pull_back(extent):
+    rung, args, products = res
+
+    def from_products(x, weights, order, inverse, sizes, gate_w, up_w,
+                      down_w):
+        *d_products, d_weights, d_down = jax.vjp(
+            lambda gate, up, weights, down_w: _finish(
+                extents[0], gate, up, weights, order, inverse, sizes, down_w),
+            *products, weights, down_w)[1](g)
+        d_x, d_gate, d_up = jax.vjp(
+            lambda x, gate_w, up_w: _products(
+                extents[0], x, weights, order, inverse, sizes, gate_w, up_w),
+            x, gate_w, up_w)[1](tuple(d_products))
+        return d_x, d_weights, d_gate, d_up, d_down
+
+    def from_inputs(extent):
         def branch(x, weights, order, inverse, sizes, *expert_w):
             return jax.vjp(
                 lambda x, weights, *expert_w: _rung(
@@ -298,9 +388,9 @@ def _at_rung_bwd(extents, res, g):
                 x, weights, *expert_w)[1](g)
         return branch
 
-    rung, args = res
     d_x, d_weights, *d_expert_w = jax.lax.switch(
-        rung, [pull_back(extent) for extent in extents], *args)
+        rung, [from_products] + [from_inputs(extent) for extent in extents[1:]],
+        *args)
     return (None, d_x, d_weights, None, None, None, *d_expert_w)
 
 
@@ -326,14 +416,22 @@ def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert,
         sizes = (key[:, None] == jnp.arange(n_local)).sum(
             axis=0, dtype=jnp.int32)
         weights = jnp.where(mine, top_w, 0).astype(x.dtype)
+    # kept with the products, for they say which rows those are: a
+    # rematerialised layer that sorted again, by a router whose scores the
+    # compiler rounded another way in its second pass, would pair a
+    # token on a tie with another expert's row, or with a row behind the
+    # last group, which is undefined
+    order, inverse, sizes = kept.named(
+        kept.MOE_PRODUCTS, order, inverse, sizes)
     extents = ladder(tokens * k, n_local, num_experts or n_local)
-    args = (x, weights, order, inverse, sizes, gate_w, up_w, down_w)
-    if len(extents) == 1:
-        return _rung(extents[0], *args), sizes, jnp.float32(extents[0])
-    # the smallest extent that holds every row of ``sizes``
-    rung = (sizes.sum() > jnp.asarray(extents[:-1])).sum(dtype=jnp.int32)
+    # the smallest extent that holds every row of ``sizes`` (a ladder of
+    # one rung: that one, and ``jax.lax.switch`` over one branch is a call)
+    rung = (sizes.sum() > jnp.asarray(extents[:-1], jnp.int32)).sum(
+        dtype=jnp.int32)
     held = jnp.asarray(extents, jnp.float32)[rung]
-    return _at_rung(extents, rung, *args), sizes, held
+    out = _at_rung(extents, rung, x, weights, order, inverse, sizes,
+                   gate_w, up_w, down_w)
+    return out, sizes, held
 
 
 class MoEMLP(nn.Module):
@@ -487,9 +585,13 @@ class MoEMLP(nn.Module):
                 first = jax.lax.axis_index("ep") * gate_w.shape[0]
 
                 # one rank's tokens at a time: only one buffer of sorted
-                # rows is alive (the backward pass recomputes each in its
-                # turn), at the extent that rank's routing asks for
-                @jax.checkpoint
+                # rows, of masks and of ``silu(gate) * up`` is alive, at the
+                # extent that rank's routing asks for (the backward pass
+                # gathers and masks each again in its turn).  What stays
+                # alive of every rank is what the layer keeps, so the
+                # layer's policy stands here too: the products at the first
+                # extent, the loop's stacked result ``[ep, extent, I]`` twice
+                @functools.partial(jax.checkpoint, policy=kept.LAYER_POLICY)
                 @jax.named_scope("moe")
                 def one_rank(its_tokens):
                     out, rows, held = local_experts(
@@ -528,13 +630,21 @@ class MoEMLP(nn.Module):
                 out_specs=(x_spec,) + 3 * (PartitionSpec(),),
             )
         rows = x.shape[0] * x.shape[1] * k
+        # of one pass: a source rank's assignments on one chip
+        extents = ladder(rows // math.prod(mesh.shape[a] for a in chips),
+                         gate_w.shape[0] // ep, cfg.num_experts)
         trace.note_trace_time(
             "moe.path", impl="ragged_dot", experts=cfg.num_experts,
             top_k=k, ep=ep, tokens=x.shape[0] * x.shape[1], rows=rows,
-            layers=cfg.num_layers,
-            # of one pass: a source rank's assignments on one chip
-            extents=ladder(rows // math.prod(mesh.shape[a] for a in chips),
-                           gate_w.shape[0] // ep, cfg.num_experts),
+            layers=cfg.num_layers, extents=extents,
             held=gate_w.shape[0], first_expert=cfg.first_expert,
+            # grouped matmuls in the pull-back of a pass at the first
+            # extent, from the products the forward pass kept
+            backward=6, kept=kept.MOE_PRODUCTS,
         )
+        # a source rank's two products and the sort they are in
+        kept.note("moe", **{kept.MOE_PRODUCTS: ep * (
+            2 * kept.nbytes((extents[0], cfg.intermediate_size), cfg.dtype)
+            + kept.nbytes((2 * extents[-1] + gate_w.shape[0] // ep,),
+                          jnp.int32))})
         return per_shard(x, top_i, top_w, gate_w, up_w, down_w)

@@ -1,5 +1,7 @@
-"""What a rematerialised decoder layer keeps of its attention core: the
-results a core's forward KERNELS wrote that its backward kernels read.
+"""What a rematerialised decoder layer keeps of its attention core and of
+its expert layer: the results a core's forward KERNELS wrote that its
+backward kernels read, and the two products of the experts' first grouped
+matmuls that their pull-back reads.
 
 A layer of ``models/llama.py`` is rematerialised whole: the forward pass
 keeps the layer's input and the backward pass computes the layer again.
@@ -11,8 +13,12 @@ the layer's ``nn.remat`` keeps exactly the named values (``LAYER_POLICY``)
 and nothing else.  Neither half does anything alone.
 
 It adapts by what the core runs and has no switch: a core on its
-``jax.numpy`` body names nothing, the FA2 kernel names nothing, and
-``LAYER_POLICY`` over a layer without names is ``nothing_saveable``.
+``jax.numpy`` body names nothing, the FA2 kernel names nothing, a dense
+feed-forward names nothing, and ``LAYER_POLICY`` over a layer without names
+is ``nothing_saveable``.  The expert layer (``models/moe.py``) names its
+products on the result of its switch over extents, at the first extent
+alone, and gives ``LAYER_POLICY`` to its own ``jax.checkpoint`` around one
+source rank's pass under ``ep``.
 """
 
 import math
@@ -36,7 +42,14 @@ KDA_CHUNK = "kda_chunk"
 #: what its state kernel wrote: ``out, u, starts``
 KDA_STATE = "kda_state"
 
-NAMES = (ATTN_OUT, ATTN_LSE, KDA_CHUNK, KDA_STATE)
+#: the two products of an expert layer's first grouped matmuls
+#: (``models/moe.py::_products``: the sorted rows times ``gate_w`` and
+#: ``up_w``, ``[extent, I]`` each in the compute dtype), at the ladder's
+#: first extent, one pair a source rank; and the sort they are in (two
+#: index vectors of all assignments and the groups' sizes, int32)
+MOE_PRODUCTS = "moe_products"
+
+NAMES = (ATTN_OUT, ATTN_LSE, KDA_CHUNK, KDA_STATE, MOE_PRODUCTS)
 
 #: the policy of every rematerialised decoder layer (``models/llama.py::
 #: _layer_class``, ``models/pipeline_llama.py``)
@@ -54,7 +67,8 @@ def nbytes(shape, dtype) -> int:
 
 def note(core: str, **bytes_by_name: int) -> None:
     """The ``remat.kept`` record of a compiled program, beside the core's
-    ``attention.path``: the names a layer of this core keeps and the bytes
+    ``attention.path`` (``core="moe"``: the expert layer's, beside
+    ``moe.path``): the names a layer of this core keeps and the bytes
     a layer they hold (counted from shapes; the kernels' results carry the
     names whether or not a ``remat`` stands around the layer)."""
     trace.note_trace_time(

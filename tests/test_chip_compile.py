@@ -537,10 +537,14 @@ class TestTrainerStep:
         # ``jax.numpy`` (the parent of PR 42, this test's configuration);
         # since PR 44 the three ``kda`` layers keep what their forward
         # kernels wrote, 176,685,056 bytes a layer (``remat.kept``):
-        # 9,378,737,152
+        # 9,378,737,152; since PR 46 all four keep their expert share's
+        # two products at the first extent, 13,107,200 bytes a layer, and
+        # the backward of the worst-case rung asks for no down product:
+        # 8,729,937,408
         mem = compiled.memory_analysis()
         assert 7.7e9 < mem.argument_size_in_bytes < 7.8e9
-        assert mem.temp_size_in_bytes <= 9_214_244_864 + 3 * 176_685_056
+        assert mem.temp_size_in_bytes <= (
+            9_214_244_864 + 3 * 176_685_056 + 4 * 13_107_200)
 
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
@@ -662,9 +666,15 @@ class TestTrainerStep:
         text = compiled.as_text()
         calls = _kernel_names(text)
         assert sum("_attend" in name for name in calls) == 4
-        # gate, up, down: forward, recomputed forward, and two gradients,
-        # once for each of the four extents the passes may run at
-        assert sum(name.startswith("ragged-dot-none") for name in calls) == 48
+        # gate, up, down forward in each of the four rungs (12); backward
+        # the first rung holds the six gradients alone, from the products
+        # the forward pass kept, and each higher rung eight: gate and up
+        # again inside ``jax.vjp`` of the rung, never down (24 + 6)
+        assert sum(name.startswith("ragged-dot-none") for name in calls) == 42
+        # what is kept: the two products of every source rank's pass at
+        # the first extent, the loop's stacked result, and of no other
+        assert "bf16[4,20480,1024]" in text
+        assert not re.search(r"bf16\[4,(24576|32768|65536),(1024|2048)\]", text)
         # one switch in the forward pass and one in the backward pass
         switches = [[name.strip() for name in names.split(",")]
                     for names in re.findall(

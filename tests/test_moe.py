@@ -2,6 +2,8 @@
 reference (``models/olmoe_reference.py``), on one device and expert-parallel
 on the 8-device CPU mesh, and through ``Trainer``'s default loss."""
 
+import dataclasses
+import functools
 import uuid
 
 import flax.linen as nn
@@ -10,11 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax._src.interpreters import partial_eval as pe
 
+from dlrover_tpu.models import moe
 from dlrover_tpu.models import olmoe_reference as reference
 from dlrover_tpu.models.llama import Attention, LlamaConfig, LlamaForCausalLM
 from dlrover_tpu.models.moe import (
     MoELlamaConfig, MoEMLP, ladder, local_experts)
+from dlrover_tpu.ops.pallas import kept
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.train import Trainer
 
@@ -293,6 +298,145 @@ class TestEveryRung:
             assert float(jnp.abs(want).max()) > 0
             assert float(jnp.abs(got - want).max()) <= 1e-6 * max(
                 1.0, float(jnp.abs(want).max()))
+
+
+def _plain_rung(extent, x, weights, order, inverse, sizes, gate_w, up_w,
+                down_w):
+    """A pass over the sorted rows as the parent of PR 46 wrote it, with
+    nothing between it and autodiff: the reference every pull-back of
+    ``models/moe.py`` is held to (its weights' gradient is ``sum(g *
+    product)``, from the down matmul run again)."""
+    slot = inverse.reshape(weights.shape)
+    live = (jnp.arange(extent) < sizes.sum())[:, None]
+    rows = jnp.where(live, x[order[:extent] // slot.shape[1]], 0)
+
+    def grouped(rows, expert_w):
+        return jax.lax.ragged_dot(rows, expert_w, group_sizes=sizes,
+                                  preferred_element_type=x.dtype)
+
+    hidden = nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
+    out = jnp.where(live, grouped(hidden, down_w), 0)
+    mine = out.at[slot].get(mode="fill", fill_value=0)
+    return jnp.einsum("tkd,tk->td", mine, weights,
+                      preferred_element_type=jnp.float32)
+
+
+def _grouped_matmuls(jaxpr):
+    """``ragged_dot`` and its transposes in a jaxpr and every jaxpr inside
+    it."""
+    found = sum(e.primitive.name.startswith("ragged_dot")
+                for e in jaxpr.eqns)
+    return found + sum(_grouped_matmuls(j) for j in _sub_jaxprs(jaxpr))
+
+
+#: what a layer of each kind is: (configuration's fields, mesh)
+LAYERS_THAT_KEEP = {
+    "ep1_share": (dict(experts_held=2), MeshConfig(dp=1)),
+    "ep1_share_shared_expert": (
+        dict(experts_held=2, shared_experts=1), MeshConfig(dp=1)),
+    "ep4": ({}, MeshConfig(dp=2, ep=4)),
+}
+
+
+class TestTheBackwardTakesTheProductsFromTheForward:
+    """One rematerialised layer (``x + experts(x)`` under the policy of
+    ``models/llama.py::_layer_class``) over the forced routings of
+    ``TestEveryRung``: on the ladder's first rung the backward pass pulls
+    back from the two products the forward pass kept, on a higher rung
+    through ``jax.vjp`` of the rung."""
+
+    def _layer(self, kind, share):
+        fields, mesh_cfg = LAYERS_THAT_KEEP[kind]
+        cfg, _, params, x, _ = TestEveryRung()._inputs(share)
+        cfg = dataclasses.replace(cfg, router_z_coef=0.0, **fields)
+        mlp = MoEMLP(cfg)
+        if cfg.experts_held:
+            params = {**params, **{
+                name: params[name][:cfg.experts_held]
+                for name in ("gate_proj", "up_proj", "down_proj")}}
+        if cfg.shared_experts:
+            params = {**params, "shared_expert": _perturbed(nn.meta.unbox(
+                mlp.init(jax.random.PRNGKey(5), x[:1, :16])["params"]
+            ))["shared_expert"]}
+        if mesh_cfg.ep == 1:
+            x = x[:1]
+
+        @functools.partial(jax.checkpoint, policy=kept.LAYER_POLICY)
+        def layer(params, x):
+            return x + mlp.apply({"params": params}, x)
+
+        def loss(params, x):
+            out = layer(params, x)
+            return jnp.sum(out * jnp.cos(out))
+
+        return loss, params, x, _mesh(mesh_cfg)
+
+    @pytest.fixture(scope="class", params=list(LAYERS_THAT_KEEP))
+    def switches(self, request):
+        """The ``switch``es of the gradient's jaxpr that hold a grouped
+        matmul, what nothing reads removed: ``jax.vjp`` of a half traces
+        that half's forward products too, and the compiler drops them as
+        this does."""
+        loss, params, x, mesh = self._layer(request.param, 1 / 4)
+        with mesh:
+            jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+                params, x).jaxpr
+        jaxpr, _ = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+        found = [[_grouped_matmuls(b.jaxpr) for b in e.params["branches"]]
+                 for e in _conds(jaxpr)]
+        assert _grouped_matmuls(jaxpr) == sum(map(sum, found))
+        return [counts for counts in found if any(counts)]
+
+    @pytest.mark.parametrize("rung", range(4))
+    def test_grouped_matmuls_of_a_rung(self, switches, rung):
+        """Nine on the first rung, three forward and six backward: no
+        forward matmul runs a second time, under the layer's ``remat`` or
+        under ``ep``'s loop over source ranks.  Eleven on a higher one:
+        gate and up run again inside ``jax.vjp``; down never does
+        (``_down_and_sum``'s pull-back asks for no product)."""
+        forward, backward = switches    # and no third: nothing recomputed
+        assert forward[rung] == 3
+        assert backward[rung] == (8 if rung else 6)
+
+    def test_a_ladder_of_one_rung_runs_the_same_nine_and_no_switch(self):
+        """Every expert on the one chip: ``jax.lax.switch`` over one branch
+        is a call, and the same rules keep the same products."""
+        cfg, mlp, params, x, _ = TestEveryRung()._inputs(1 / 4)
+
+        @functools.partial(jax.checkpoint, policy=kept.LAYER_POLICY)
+        def layer(params, x):
+            return x + mlp.apply({"params": params}, x)
+
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(
+            lambda params, x: jnp.sum(jnp.sin(layer(params, x))),
+            argnums=(0, 1)))(params, x[:1]).jaxpr
+        jaxpr, _ = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+        assert _conds(jaxpr) == [] and _grouped_matmuls(jaxpr) == 9
+
+    @pytest.mark.parametrize("share,rung", RUNGS)
+    @pytest.mark.parametrize("kind", list(LAYERS_THAT_KEEP))
+    def test_loss_and_gradients_equal_the_plain_pull_back(
+            self, monkeypatch, kind, share, rung):
+        loss, params, x, mesh = self._layer(kind, share)
+
+        def run():
+            with mesh, jax.default_matmul_precision("highest"):
+                return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+                    params, x)
+
+        got = run()
+        monkeypatch.setattr(
+            moe, "_at_rung",
+            lambda extents, rung, *args: _plain_rung(extents[-1], *args))
+        want = run()
+        got = jax.tree_util.tree_leaves_with_path(got)
+        want = jax.tree.leaves(want)
+        assert len(got) == len(want) >= 6
+        for (path, a), b in zip(got, want):
+            # a forced router's softmax is flat: its gradient may be none
+            assert "router" in str(path) or float(jnp.abs(b).max()) > 0
+            assert float(jnp.abs(a - b).max()) <= 2e-6 * max(
+                1.0, float(jnp.abs(b).max())), path
 
 
 def _sub_jaxprs(jaxpr):

@@ -12,6 +12,10 @@ twice under ``nothing_saveable``; and the loss and every gradient are those
 under ``nothing_saveable`` bit for bit.  For a core that names nothing (the
 FA2 kernel, the reference, a ``jax.numpy`` body) the two policies lower to
 the same program.
+
+The expert layer (``models/moe.py``) names the two products of a pass's
+first grouped matmuls the same way, ``jax.numpy`` and all: the same stack
+with one chip's share of an expert layer in each.
 """
 
 import collections
@@ -26,6 +30,7 @@ from jax._src.ad_checkpoint import saved_residuals
 
 from dlrover_tpu.models import llama
 from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.models.moe import MoELlamaConfig, ladder
 from dlrover_tpu.ops import attention as ops
 from dlrover_tpu.ops import linear_attention
 from dlrover_tpu.ops.pallas import kept
@@ -224,3 +229,57 @@ def test_a_core_that_names_nothing_is_computed_again_whole(monkeypatch, name):
 
     assert lowered(kept.LAYER_POLICY) == lowered(
         jax.checkpoint_policies.nothing_saveable)
+
+
+#: 256 tokens with 2 experts each, 2 of 8 experts here: the first extent
+#: holds 256 of the 512 assignments; the two products, and the sort they
+#: are in (two index vectors of all assignments, the two groups' sizes)
+EXPERTS = Core(
+    MoELlamaConfig.tiny_moe(
+        num_layers=LAYERS, num_heads=HEADS, num_kv_heads=1, head_dim=DIM,
+        hidden_size=HIDDEN, intermediate_size=128, dtype=jnp.float32,
+        num_experts=8, top_k=2, experts_held=2, max_seq_len=256),
+    "gqa", 256, None, {}, {(256, 128): 2, (512,): 2, (2,): 1})
+
+
+def test_an_expert_layer_keeps_its_input_and_the_two_products(monkeypatch):
+    stack = _Stack(monkeypatch, EXPERTS, kept.LAYER_POLICY)
+    assert ladder(256 * 2, 2, 8) == (256, 512)
+    del stack.records[:]
+    stacked = collections.Counter(
+        aval.shape[1:] for aval, why in saved_residuals(
+            stack.loss, stack.params, stack.x)
+        if "output of scan" in why and aval.shape[0] == LAYERS)
+    assert stacked == collections.Counter(
+        {**EXPERTS.kept, (1, EXPERTS.rows, HIDDEN): 1})
+    # every trace of the layer makes one reading, so ``note_trace_time``
+    # keeps one record of each a program
+    paths = [attrs for name, attrs in stack.records if name == "moe.path"]
+    path = paths[0]
+    assert all(other == path for other in paths)
+    assert path["kept"] == kept.MOE_PRODUCTS and path["backward"] == 6
+    assert path["extents"] == (256, 512)
+    note = stack.kept_note()
+    assert note["core"] == "moe" and note["names"] == kept.MOE_PRODUCTS
+    assert note["bytes_per_layer"] == note["moe_products_bytes"] == sum(
+        math.prod(shape) * count * F32.itemsize
+        for shape, count in EXPERTS.kept.items())
+
+
+def test_an_expert_layers_gradients_are_those_computed_again_whole(
+        monkeypatch):
+    """And under ``nothing_saveable`` the forward switch runs a second
+    time, for the same products."""
+    runs = {}
+    for policy in (kept.LAYER_POLICY,
+                   jax.checkpoint_policies.nothing_saveable):
+        stack = _Stack(monkeypatch, EXPERTS, policy)
+        conds = str(jax.make_jaxpr(jax.grad(stack.loss, argnums=(0, 1)))(
+            stack.params, stack.x)).count(" cond[")
+        runs[policy] = conds, jax.tree.leaves(stack.value_and_grad())
+    (conds, got), (conds_again, want) = runs.values()
+    assert (conds, conds_again) == (2, 3)
+    assert len(got) == len(want) > 2
+    for a, b in zip(got, want):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_array_equal(a, b)
