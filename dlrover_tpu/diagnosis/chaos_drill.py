@@ -96,6 +96,83 @@ def _env(**overrides: str):
                 os.environ[key] = old
 
 
+class _DrillClock:
+    """Seconds that only the drill's own thread advances.  Inside the
+    ``with`` block ``time.perf_counter`` and ``time.sleep`` are this
+    clock's on that thread (every other thread, and everything after
+    the block, gets the real ones), so the mesh probe's timed window
+    reads the synthetic fabric op plus the chaos engine's DELAY,
+    exactly: the host's scheduler, which can hold a 1 ms sleep for
+    tens of ms beside busy neighbours, is no part of a reading."""
+
+    def __init__(self):
+        self._owner = threading.get_ident()
+        self._real = (time.perf_counter, time.sleep)
+        self._on = False
+        self.now = 0.0
+        self.slept = 0.0
+
+    def _mine(self) -> bool:
+        return self._on and threading.get_ident() == self._owner
+
+    def perf_counter(self) -> float:
+        return self.now if self._mine() else self._real[0]()
+
+    def sleep(self, seconds: float) -> None:
+        if not self._mine():
+            self._real[1](seconds)
+            return
+        self.now += seconds
+        self.slept += seconds
+
+    def __enter__(self) -> "_DrillClock":
+        self._on = True
+        time.perf_counter, time.sleep = self.perf_counter, self.sleep
+        return self
+
+    def __exit__(self, *exc) -> None:
+        time.perf_counter, time.sleep = self._real
+        self._on = False
+
+
+def _synthetic_probe_rounds(axes: Dict[str, int], op_s: float, model,
+                            store) -> None:
+    """Twelve rounds of a ``MeshProbe`` over a synthetic fabric (one op
+    takes ``op_s``) into ``model``, each fed to the master's
+    ``store`` as its own 1 s bucket.  Device-independent and without
+    the host's clock: the probe's timed window and the chaos engine's
+    DELAY (which lands inside it, as on a real mesh) run on a
+    :class:`_DrillClock`, as the feed runs on synthetic timestamps.
+    Every round is held to that: the latencies read are the ops plus
+    what the engine slept, to the rounding."""
+    from dlrover_tpu.observability import commscope
+
+    clock = _DrillClock()
+
+    def fabric_op(axis, kind):
+        clock.now += op_s
+
+    reps = 2
+    probe = commscope.MeshProbe(axes, runner=fabric_op, reps=reps)
+    rounds = 12
+    base = time.time() - rounds - 2
+    with clock:
+        for i in range(rounds):
+            slept = clock.slept
+            sample = probe.probe_once(model)
+            read_s = reps * sum(s["lat_s"] for s in sample.values())
+            due_s = reps * op_s * len(axes) + clock.slept - slept
+            if abs(read_s - due_s) > 1e-9:
+                # the probe or the engine took a clock the drill does
+                # not hold (a ``from time import ...`` would do it)
+                raise RuntimeError(
+                    f"probe round {i} read {read_s!r} s of latency where "
+                    f"ops and injected delay make {due_s!r} s: "
+                    "a reading came off the host's clock"
+                )
+            store.record_digest(0, model.digest(), ts=base + i)
+
+
 def _scope() -> str:
     return f"chaos{uuid.uuid4().hex[:8]}"
 
@@ -1021,11 +1098,12 @@ def _scenario_slow_link(ctx: Dict) -> Dict:
     and the incident must classify ``phase=comm`` naming the axis and
     culprit rank.
 
-    The probe uses a synthetic fabric runner (a fixed ~1ms op) so the
+    The probe uses a synthetic fabric runner (a fixed 1 ms op) so the
     drill is device-independent; the chaos DELAY lands inside the
     probe's timed window exactly as it does on a real mesh, and the
     master feed uses synthetic 1s-spaced timestamps so every probe
-    round is its own completed time-series bucket without sleeping."""
+    round is its own completed time-series bucket without sleeping
+    (``_synthetic_probe_rounds``: no reading is the host's clock's)."""
     from dlrover_tpu.diagnosis.diagnostician import DiagnosisManager
     from dlrover_tpu.master.timeseries import TimeSeriesStore
     from dlrover_tpu.observability import commscope
@@ -1043,21 +1121,12 @@ def _scenario_slow_link(ctx: Dict) -> Dict:
         DLROVER_TPU_INCIDENT_GRACE_S="0",
     ):
         model = commscope.FabricModel(alpha=1.0)
-        probe = commscope.MeshProbe(
-            {"dp": 2, "fsdp": 2},
-            runner=lambda axis, kind: time.sleep(0.001),
-            reps=2,
-        )
         store = TimeSeriesStore()
         manager = IncidentManager()
         diagnosis = DiagnosisManager()
         diagnosis.register(SlowLinkDiagnostician(store, res_s=1.0))
         diagnosis.set_incident_manager(manager)
-        rounds = 12
-        base = time.time() - rounds - 2
-        for i in range(rounds):
-            probe.probe_once(model)
-            store.record_digest(0, model.digest(), ts=base + i)
+        _synthetic_probe_rounds({"dp": 2, "fsdp": 2}, 0.001, model, store)
         snapshot = model.snapshot()
         _check(
             checks, "probe_detected_asymmetry",
@@ -1220,11 +1289,6 @@ def _scenario_fabric_reroute(ctx: Dict) -> Dict:
         hierarchy.register_demotion_target(holder)
         hook = hierarchy.DcnDemotionHook()
 
-        probe = commscope.MeshProbe(
-            {"dp": 2, "slice": 2},
-            runner=lambda axis, kind: time.sleep(0.0005),
-            reps=2,
-        )
         store = TimeSeriesStore()
         manager = IncidentManager()
         diagnosis = DiagnosisManager()
@@ -1232,11 +1296,7 @@ def _scenario_fabric_reroute(ctx: Dict) -> Dict:
             store, res_s=1.0, demotion_hook=hook,
         ))
         diagnosis.set_incident_manager(manager)
-        rounds = 12
-        base = time.time() - rounds - 2
-        for i in range(rounds):
-            probe.probe_once(model)
-            store.record_digest(0, model.digest(), ts=base + i)
+        _synthetic_probe_rounds({"dp": 2, "slice": 2}, 0.0005, model, store)
         snapshot = model.snapshot()
         _check(
             checks, "probe_detected_dcn_degradation",

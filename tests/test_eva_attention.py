@@ -5,6 +5,8 @@ unit offset, the float32 residual stream and the eight prediction heads
 (``models/evabyte_reference.py``) at small sizes on the CPU in float32, and
 through ``Trainer``."""
 
+import collections
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,16 @@ from dlrover_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from dlrover_tpu.ops import attention as ops
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.train import Trainer
+from against_reference import (
+    init_params,
+    inputs_and_labels,
+    jitted,
+    perturbed,
+    reference_loss_and_gradients,
+    system,
+    system_loss,
+)
+from shared_memo import shared_memo
 
 SEQ, WINDOW, CHUNK, HEADS, DIM = 32, 8, 2, 3, 4
 
@@ -52,9 +64,9 @@ def _by_the_equations(q, k, v, mu, phi, window=WINDOW, chunk=CHUNK):
 
 def test_eva_attention_is_the_equations():
     q, k, v, mu, phi = _operands()
-    with jax.default_matmul_precision("highest"):
-        got, share, weight = ops.eva_attention(q, k, v, mu, phi, WINDOW, CHUNK)
-        want, mass = _by_the_equations(q, k, v, mu, phi)
+    got, share, weight = jitted(
+        lambda *a: ops.eva_attention(*a, WINDOW, CHUNK), q, k, v, mu, phi)
+    want, mass = jitted(_by_the_equations, q, k, v, mu, phi)
     # float32 on both sides, sums in another order
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
     np.testing.assert_allclose(share, mass[..., WINDOW:].mean(), rtol=1e-5)
@@ -64,20 +76,26 @@ def test_eva_attention_is_the_equations():
     assert 0.2 < float(share) < 0.8 and float(weight) > 1.2 / CHUNK
 
 
-@pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "mu", "phi"])
-def test_eva_attention_gradients(wrt):
-    """Through the exact part and through the pooling, to every operand."""
+@shared_memo
+def _gradients():
+    """(eva_attention's, the equations') gradients of one scalar of the
+    result with respect to all five operands."""
     operands = _operands(1)
     probe = jax.random.normal(jax.random.PRNGKey(9), operands[0].shape)
 
     def through(fn):
         def scalar(*args):
             return jnp.sum(fn(*args)[0] * probe)
-        with jax.default_matmul_precision("highest"):
-            return jax.grad(scalar, argnums=wrt)(*operands)
+        return jitted(jax.grad(scalar, argnums=range(5)), *operands)
 
-    got = through(lambda *a: ops.eva_attention(*a, WINDOW, CHUNK))
-    want = through(_by_the_equations)
+    return (through(lambda *a: ops.eva_attention(*a, WINDOW, CHUNK)),
+            through(_by_the_equations))
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "mu", "phi"])
+def test_eva_attention_gradients(wrt):
+    """Through the exact part and through the pooling, to every operand."""
+    got, want = (grads[wrt] for grads in _gradients())
     assert float(jnp.abs(want).max()) > 1e-2
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
 
@@ -85,23 +103,25 @@ def test_eva_attention_gradients(wrt):
 def test_eva_attention_one_window_is_causal_attention():
     q, k, v, mu, phi = _operands(2, seq=WINDOW)
     causal = jnp.tril(jnp.ones((WINDOW, WINDOW), bool))[None, None]
-    want = ops.reference_attention(q, k, v, causal)
+    want = jax.jit(ops.reference_attention)(q, k, v, causal)
     for window in (WINDOW, 4 * WINDOW):     # a shorter sequence is one window
-        got, share, _ = ops.eva_attention(q, k, v, mu, phi, window, CHUNK)
+        got, share, _ = jax.jit(lambda *a: ops.eva_attention(
+            *a, window, CHUNK))(q, k, v, mu, phi)
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
         assert float(share) == 0.0
 
 
 def test_eva_attention_first_window_ignores_the_pooling_vectors():
     q, k, v, mu, phi = _operands(3)
-    first = lambda mu_, phi_: ops.eva_attention(  # noqa: E731
-        q, k, v, mu_, phi_, WINDOW, CHUNK)[0][:, :WINDOW]
-    later = lambda mu_, phi_: ops.eva_attention(  # noqa: E731
-        q, k, v, mu_, phi_, WINDOW, CHUNK)[0][:, WINDOW:]
+    whole = jax.jit(lambda mu_, phi_: ops.eva_attention(
+        q, k, v, mu_, phi_, WINDOW, CHUNK)[0])
+    first = lambda mu_, phi_: whole(mu_, phi_)[:, :WINDOW]  # noqa: E731
+    later = lambda mu_, phi_: whole(mu_, phi_)[:, WINDOW:]  # noqa: E731
     np.testing.assert_array_equal(first(mu, phi), first(-mu, 2 * phi))
     assert float(jnp.abs(later(mu, phi) - later(-mu, phi)).max()) > 1e-2
     assert float(jnp.abs(later(mu, phi) - later(mu, 2 * phi)).max()) > 1e-2
-    grads = jax.grad(lambda m_, p_: first(m_, p_).sum(), argnums=(0, 1))(mu, phi)
+    grads = jax.jit(jax.grad(
+        lambda m_, p_: first(m_, p_).sum(), argnums=(0, 1)))(mu, phi)
     assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
 
 
@@ -141,20 +161,14 @@ def _published(cfg):
 
 
 def _batch(cfg, rows=2, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        0, cfg.vocab_size, size=(rows, SEQ + 1))
-    return {"input_ids": jnp.asarray(ids[:, :-1], jnp.int32),
-            "labels": jnp.asarray(ids[:, 1:], jnp.int32)}
+    inputs, labels = inputs_and_labels(rows, SEQ, cfg.vocab_size, seed)
+    return {"input_ids": inputs, "labels": labels}
 
 
-def _perturbed(params, seed=2):
-    """An untrained norm's offset is 0 and the pooling vectors are small:
-    move every leaf, or a reference that forgot one would pass."""
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return jax.tree.unflatten(tree, [
-        leaf + 0.1 * jax.random.normal(k, leaf.shape, leaf.dtype)
-        for leaf, k in zip(leaves, keys)])
+#: what the fixture computed once: the system's ``((loss, (token losses,
+#: sown)), gradients)``, the reference's dictionary and gradients
+Made = collections.namedtuple(
+    "Made", "cfg model batch params got want want_grads")
 
 
 @pytest.fixture(scope="module")
@@ -162,28 +176,19 @@ def made():
     cfg = _config()
     model = LlamaForCausalLM(cfg)
     batch = _batch(cfg)
-    params = _perturbed(nn.meta.unbox(
-        model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]))
-    return cfg, model, batch, params
-
-
-def _system_loss(model, params, batch):
-    """What ``Trainer._default_loss`` minimises, and what was sown."""
-    logits, sown = model.apply({"params": params}, batch["input_ids"],
-                               mutable=["losses", "stats"])
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    token = -jnp.take_along_axis(logp, batch["labels"][..., None], -1)[..., 0]
-    loss = token.mean() + sum(
-        jnp.sum(term) for term in jax.tree.leaves(sown["losses"]))
-    return loss, (token, sown)
+    params = perturbed(init_params(model, batch["input_ids"]))
+    m = _published(cfg)
+    want, want_grads = reference_loss_and_gradients(
+        lambda p: reference.forward(
+            p, batch["input_ids"], batch["labels"], m), params)
+    got = system(model, params, batch["input_ids"], batch["labels"])
+    return Made(cfg, model, batch, params, got, want, want_grads)
 
 
 def test_eva_model_agrees_with_the_reference(made):
-    cfg, model, batch, params = made
-    with jax.default_matmul_precision("highest"):
-        loss, (token, sown) = _system_loss(model, params, batch)
-    want = reference.forward(params, batch["input_ids"], batch["labels"],
-                             _published(cfg))
+    cfg, model, batch, params, want = (
+        made.cfg, made.model, made.batch, made.params, made.want)
+    (loss, (token, sown)), _ = made.got
     # float32 on both sides; a loss of 6 resolves to 5e-7
     np.testing.assert_allclose(token, want["token_losses"], rtol=0, atol=2e-5)
     np.testing.assert_allclose(loss, want["loss"], rtol=1e-6)
@@ -198,20 +203,17 @@ def test_eva_model_agrees_with_the_reference(made):
                                want["multi_byte"], rtol=1e-6)
     # seven further heads, each near log(vocab) on random weights
     assert 7 * 4.0 < float(want["multi_byte"]) < 7 * 9.0
-    logits = model.apply({"params": params}, batch["input_ids"])
+    logits = jitted(lambda p: model.apply({"params": p}, batch["input_ids"]),
+                    params)
     assert logits.shape == (2, SEQ, cfg.vocab_size)
 
 
 def test_eva_model_gradients_agree_with_the_reference(made):
     """All eight heads' loss, through the pooling into ``adaptive_mu_k`` and
     ``adaptive_phi``, through the float32 residual and the norms' offsets."""
-    cfg, model, batch, params = made
-    with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda p: _system_loss(model, p, batch)[0])(params)
-    want = jax.grad(lambda p: reference.forward(
-        p, batch["input_ids"], batch["labels"], _published(cfg))["loss"])(params)
+    _, got = made.got
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
-    for path, leaf in jax.tree_util.tree_leaves_with_path(want):
+    for path, leaf in jax.tree_util.tree_leaves_with_path(made.want_grads):
         scale = float(jnp.abs(leaf).max())
         assert scale > 1e-4, path            # every leaf is reached
         # float32 on both sides: 1e-4 of the leaf's largest gradient
@@ -223,9 +225,7 @@ def test_eva_model_gradients_agree_with_the_reference(made):
     "no_unit_offset", "mean_pooling", "one_window", "first_head_only",
     "another_chunk"])
 def test_eva_departure_is_far_outside_float32_agreement(made, what):
-    cfg, model, batch, params = made
-    want = reference.forward(params, batch["input_ids"], batch["labels"],
-                             _published(cfg))
+    batch, params, want = made.batch, made.params, made.want
     changed = {"no_unit_offset": {"norm_unit_offset": False},
                "one_window": {"eva_window": SEQ},
                "another_chunk": {"eva_chunk": 2 * CHUNK},
@@ -238,7 +238,8 @@ def test_eva_departure_is_far_outside_float32_agreement(made, what):
             attn["adaptive_phi"])
         tree = {**params, "layers": {"layer": {
             **params["layers"]["layer"], "attn": attn}}}
-    loss, (token, sown) = _system_loss(wrong, tree, batch)
+    _, (token, _) = system_loss(
+        wrong, tree, batch["input_ids"], batch["labels"])
     if what == "first_head_only":
         assert abs(float(token.mean()) - float(want["loss"])) > 1.0
     else:
@@ -267,7 +268,7 @@ def test_eva_model_through_the_trainer(made, monkeypatch):
     """The normal path: ``Trainer`` adds the sown multi-byte term to its own
     cross entropy, carries the counters in ``metrics["stats"]``, and the
     trace of the step writes which attention ran."""
-    cfg, model, batch, _ = made
+    cfg, model, batch = made.cfg, made.model, made.batch
     records = []
     monkeypatch.setattr(ops.trace, "note_trace_time",
                         lambda name, **attrs: records.append((name, attrs)))
@@ -297,8 +298,7 @@ def test_eva_model_through_the_trainer(made, monkeypatch):
 def test_eva_norm_offset_starts_as_a_plain_norm():
     cfg = _config()
     batch = _batch(cfg)
-    params = nn.meta.unbox(LlamaForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), batch["input_ids"])["params"])
+    params = init_params(LlamaForCausalLM(cfg), batch["input_ids"], seed=0)
     assert float(jnp.abs(params["final_norm"]["scale"]).max()) == 0.0
     vectors = params["layers"]["layer"]["attn"]["adaptive_mu_k"]
     assert float(jnp.abs(vectors).max()) <= cfg.head_dim ** -0.5

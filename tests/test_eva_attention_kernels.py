@@ -8,7 +8,6 @@ that the kernels' two entry points cannot drift; and that the benchmark's
 reader, as it stands, takes the kernels' calls."""
 
 import contextlib
-import functools
 from unittest import mock
 
 import jax
@@ -20,6 +19,7 @@ from benchmarks import common
 from dlrover_tpu.ops import attention as ops
 from dlrover_tpu.ops.pallas import selected_attention as kernels
 from dlrover_tpu.ops.pallas.tuning import selected_tiling
+from shared_memo import shared_memo
 
 WINDOW, WINDOWS, HEADS, DIM = 256, 3, 2, kernels.KERNEL_HEAD_DIM
 TILE = 128   # keys a kernel tile in these cases: a window's keys are two
@@ -59,7 +59,7 @@ def _operands(seed, seq=WINDOW * WINDOWS, batch=2):
     return (q, k, v, mu, phi), jax.random.normal(ks[5], q.shape)
 
 
-@functools.lru_cache(maxsize=None)
+@shared_memo
 def _both(case):
     """quantity -> (kernels, jax.numpy): the outputs and the gradients of a
     loss that weighs every output element differently."""
@@ -70,8 +70,8 @@ def _both(case):
             out, mass, _ = ops.eva_attention(*xs, WINDOW, CASES[case])
             return (out * weights).sum(), (out, mass)
 
-        (_, (out, mass)), grads = jax.value_and_grad(
-            loss, argnums=tuple(range(5)), has_aux=True)(*operands)
+        (_, (out, mass)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(5)), has_aux=True))(*operands)
         return dict(zip(QUANTITIES, (out, mass) + grads))
 
     want = run()
@@ -179,7 +179,7 @@ def _keye_block(seed=0, queries=512, keys=1024, heads=32, kv_heads=4):
 ENTRY_QUANTITIES = ("out", "q", "k", "v")
 
 
-@functools.lru_cache(maxsize=None)
+@shared_memo
 def _entry_points():
     """quantity -> (``masked_attention``, ``selected_attention``) at one of
     Keye's blocks: 512 queries, 32 heads on 4 kv heads."""
@@ -226,7 +226,7 @@ def test_the_lse_is_the_log_of_the_row_sums_and_carries_no_gradient():
 SHARED_QUANTITIES = ("out", "lse", "q", "k", "v", "shared_k", "shared_v")
 
 
-@functools.lru_cache(maxsize=None)
+@shared_memo
 def _with_shared_keys():
     """A GQA group of two over two tiles of masked keys and 384 keys every
     query attends to, visited as 256 and 128: against dense ``jax.numpy``
@@ -258,8 +258,8 @@ def _with_shared_keys():
             out, lse = attend(*xs)
             return (out * weights).sum(), (out, lse)
 
-        (_, results), grads = jax.value_and_grad(
-            loss, argnums=tuple(range(5)), has_aux=True)(q, k, v, *shared)
+        (_, results), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(5)), has_aux=True))(q, k, v, *shared)
         return dict(zip(SHARED_QUANTITIES, results + grads))
 
     got, want = run(through_kernels), run(dense)

@@ -15,6 +15,7 @@ import pytest
 from dlrover_tpu.ops import attention as ops
 from dlrover_tpu.ops.pallas import index_scores as kernels
 from dlrover_tpu.ops.pallas.tuning import index_tiling, selected_tiling
+from shared_memo import shared_memo
 
 TILE = 128   # keys a kernel tile in these cases
 
@@ -56,7 +57,7 @@ CASES = {
 QUANTITIES = ("I", "dq_I", "dk_I", "dw")
 
 
-@functools.lru_cache(maxsize=None)
+@shared_memo
 def _both(case):
     """quantity -> (kernels, jax.numpy): the scores and the gradients of a
     loss that weighs every score differently; ``w`` of either sign."""
@@ -75,8 +76,8 @@ def _both(case):
             out = scores(*xs)
             return (out * weights).sum(), out
 
-        (_, out), grads = jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True)(*operands)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(*operands)
         return dict(zip(QUANTITIES, (out,) + grads))
 
     got = run(functools.partial(
@@ -221,7 +222,7 @@ WHOLE = ("out", "index_loss", "low_margin_share", "q", "k", "v", "index_q",
          "index_k", "index_w")
 
 
-@functools.lru_cache(maxsize=None)
+@shared_memo
 def _whole_both():
     """quantity -> (kernels, jax.numpy) of ``indexed_sparse_attention`` at
     S 512 by blocks of 128, ``topk`` 160: a block under ``topk`` and three
@@ -234,8 +235,8 @@ def _whole_both():
                 *xs, topk=160, block=128)
             return jnp.sin(out).sum() + 5.0 * index_loss, (out, index_loss, low)
 
-        (_, aux), grads = jax.value_and_grad(
-            loss, argnums=tuple(range(6)), has_aux=True)(*operands)
+        (_, aux), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(6)), has_aux=True))(*operands)
         return dict(zip(WHOLE, aux + grads))
 
     want = run()
@@ -269,7 +270,8 @@ def test_the_loss_reaches_the_indexer_and_nothing_else(monkeypatch, on_a_tpu):
     def index_loss(*xs):
         return ops.indexed_sparse_attention(*xs, topk=160, block=128)[1]
 
-    grads = jax.grad(index_loss, argnums=tuple(range(6)))(*_whole(512))
+    grads = jax.jit(jax.grad(index_loss, argnums=tuple(range(6))))(
+        *_whole(512))
     for name, grad in zip(WHOLE[3:], grads):
         if name.startswith("index_"):
             assert bool(jnp.any(grad)), name

@@ -5,12 +5,15 @@ and the gradients of all five operands, over chunk sizes, a strong decay
 chunk), betas near 0 and near 2, lengths of several chunks and lengths that
 are no multiple of the chunk."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dlrover_tpu.ops.linear_attention import SUB, kda, kda_recurrent
+from shared_memo import shared_memo
 
 OPERANDS = ("q", "k", "v", "g", "beta")
 
@@ -31,6 +34,19 @@ def _operands(seq, g_min=-1.0, beta_logit=0.0, heads=2, dim=8, seed=0,
     return q, k, v, g, beta
 
 
+def _chunked(chunk):
+    return functools.partial(kda, chunk=chunk)
+
+
+@shared_memo
+def _gradients(chunk, seq=128, **operands):
+    """The gradients of all five operands, one program: the chunked rule's
+    at ``chunk``, the recurrence's at ``chunk`` None."""
+    fn = _chunked(chunk) if chunk else kda_recurrent
+    return jax.jit(jax.grad(_weighted(fn), argnums=range(5)))(
+        *_operands(seq, **operands))
+
+
 def _weighted(fn):
     """A scalar of the output that weighs every element differently, so
     that a gradient which swaps two positions or channels does not pass."""
@@ -47,8 +63,8 @@ def _weighted(fn):
     (7, 64)])
 def test_chunked_equals_the_recurrence(seq, chunk):
     operands = _operands(seq)
-    want = kda_recurrent(*operands)
-    got = kda(*operands, chunk=chunk)
+    want = jax.jit(kda_recurrent)(*operands)
+    got = jax.jit(_chunked(chunk))(*operands)
     assert got.shape == want.shape == operands[2].shape
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
 
@@ -58,11 +74,8 @@ def test_chunked_equals_the_recurrence(seq, chunk):
 def test_gradient_of_each_operand(chunk, operand):
     """Chunks of 16 (one sub-block a chunk), 64 (four) and one chunk the
     whole sequence, a length of several chunks but for the last."""
-    operands = _operands(128, seed=1)
     at = OPERANDS.index(operand)
-    want = jax.grad(_weighted(kda_recurrent), argnums=at)(*operands)
-    got = jax.grad(_weighted(lambda *o: kda(*o, chunk=chunk)), argnums=at)(
-        *operands)
+    want, got = _gradients(None, seed=1)[at], _gradients(chunk, seed=1)[at]
     assert float(jnp.abs(want).max()) > 1e-3        # the operand matters
     np.testing.assert_allclose(got, want, rtol=0, atol=5e-5)
 
@@ -75,11 +88,10 @@ def test_a_strong_decay_neither_overflows_nor_is_clamped(g_min, chunk):
     the recurrence's, which clamps nothing."""
     operands = _operands(128, g_min=g_min, seed=2)
     np.testing.assert_allclose(
-        kda(*operands, chunk=chunk), kda_recurrent(*operands), rtol=0,
-        atol=2e-5)
-    want = jax.grad(_weighted(kda_recurrent), argnums=range(5))(*operands)
-    got = jax.grad(_weighted(lambda *o: kda(*o, chunk=chunk)),
-                   argnums=range(5))(*operands)
+        jax.jit(_chunked(chunk))(*operands), jax.jit(kda_recurrent)(*operands),
+        rtol=0, atol=2e-5)
+    want = _gradients(None, g_min=g_min, seed=2)
+    got = _gradients(chunk, g_min=g_min, seed=2)
     for name, g, w in zip(OPERANDS, got, want):
         assert bool(jnp.isfinite(g).all()), name
         np.testing.assert_allclose(g, w, rtol=0, atol=1e-4, err_msg=name)
@@ -105,11 +117,10 @@ def test_beta_near_its_ends(beta_logit, low, high, chunk):
     beta = operands[4]
     assert low <= float(beta.min()) and float(beta.max()) <= high
     np.testing.assert_allclose(
-        kda(*operands, chunk=chunk), kda_recurrent(*operands), rtol=0,
-        atol=5e-5)
-    want = jax.grad(_weighted(kda_recurrent), argnums=range(5))(*operands)
-    got = jax.grad(_weighted(lambda *o: kda(*o, chunk=chunk)),
-                   argnums=range(5))(*operands)
+        jax.jit(_chunked(chunk))(*operands), jax.jit(kda_recurrent)(*operands),
+        rtol=0, atol=5e-5)
+    want = _gradients(None, beta_logit=beta_logit, seed=3)
+    got = _gradients(chunk, beta_logit=beta_logit, seed=3)
     for name, g, w in zip(OPERANDS, got, want):
         np.testing.assert_allclose(g, w, rtol=0, atol=2e-4, err_msg=name)
 
@@ -118,7 +129,7 @@ def test_no_decay_and_beta_one_is_the_plain_delta_rule():
     """``g = 0`` and ``beta = 1``: the newest value stored under a key is
     read back exactly by that key."""
     q, k, v, g, beta = _operands(32, seed=4, heads=1, batch=1)
-    out = kda(k, k, v, jnp.zeros_like(g), jnp.ones_like(beta), chunk=16)
+    out = jax.jit(_chunked(16))(k, k, v, jnp.zeros_like(g), jnp.ones_like(beta))
     np.testing.assert_allclose(out, v, rtol=0, atol=1e-5)
 
 
@@ -127,13 +138,14 @@ def test_the_state_is_carried_in_float32_beside_bfloat16_operands():
     the decay, its running sums, the solve and the state between chunks do
     not take the operands' dtype."""
     operands = _operands(256, seed=5, dim=16)
-    want = kda_recurrent(*operands)
+    want = jax.jit(kda_recurrent)(*operands)
     low = tuple(t.astype(jnp.bfloat16) for t in operands[:3]) + operands[3:]
-    got = kda(*low, chunk=64)
+    chunked = jax.jit(_chunked(64))
+    got = chunked(*low)
     assert got.dtype == jnp.bfloat16
     err = jnp.abs(got.astype(jnp.float32) - want)
     assert float(err.max()) < 0.06 and float(err.mean()) < 0.006
-    text = jax.jit(lambda *o: kda(*o, chunk=64)).lower(*low).as_text()
+    text = chunked.lower(*low).as_text()
     # the solve is float32's, and the scan's carry is a float32 state
     assert "_solve_triangular" in text and "x16x16xf32>" in text
 

@@ -19,6 +19,7 @@ from dlrover_tpu.ops.linear_attention import kda, kda_path, kda_recurrent
 from dlrover_tpu.ops.pallas import kda as kernels
 from dlrover_tpu.ops.pallas import tuning
 from test_kda import OPERANDS, _operands, _weighted
+from shared_memo import shared_memo
 
 DIM, CHUNK = 128, 64
 CASES = {
@@ -28,9 +29,10 @@ CASES = {
     "four_chunks_two_a_step": (4, (2, 2, 2), -1.0, 0.0),
     "g_min_5": (2, (1, 1, 2), -5.0, 0.0),
     "g_min_20": (2, (2, 1, 1), -20.0, 0.0),
-    "g_min_80": (2, (1, 2, 2), -80.0, 0.0),
     "beta_near_0": (2, (1, 1, 1), -1.0, -8.0),
     "beta_near_2": (2, (2, 2, 1), -1.0, 8.0),
+    # the last: ``test_a_strong_decay_is_not_clamped`` reads it next
+    "g_min_80": (2, (1, 2, 2), -80.0, 0.0),
 }
 BODIES = {"the_recurrence": kda_recurrent,
           "the_jnp_body": linear_attention._kda_chunked}
@@ -40,23 +42,37 @@ def _through_kernels(tile):
     return functools.partial(kernels.kda_kernels, tile=tile, interpret=True)
 
 
-@functools.lru_cache(maxsize=None)
+def _jitted(fn):
+    """A new compiled function each time: what a test patches (the
+    backend, the kernels' entry) is read when this one is traced."""
+    return jax.jit(lambda *operands: fn(*operands))
+
+
+@shared_memo
 def _all(case):
     """body -> (output, the five gradients of a loss that weighs every
-    element differently), the kernels among them."""
+    element differently), the kernels among them; a body is one compiled
+    program."""
     chunks, tile, g_min, beta_logit = CASES[case]
     operands = _operands(chunks * CHUNK, g_min=g_min, beta_logit=beta_logit,
                          dim=DIM, seed=len(case))
     bodies = dict(BODIES, the_kernels=_through_kernels(tile))
     bodies["the_jnp_body"] = functools.partial(
         bodies["the_jnp_body"], chunk=CHUNK)
-    return {name: (fn(*operands), jax.grad(
-        _weighted(fn), argnums=range(5))(*operands))
+    return {name: jax.jit(lambda *o, fn=fn: (fn(*o), jax.grad(
+        _weighted(fn), argnums=range(5))(*o)))(*operands)
         for name, fn in bodies.items()}
 
 
+@pytest.fixture(scope="module", params=list(CASES))
+def case(request):
+    """A module's fixture and not a ``parametrize``, so that every test of
+    one case stands next to the others in the collection and one worker
+    computes ``_all(case)`` once."""
+    return request.param
+
+
 @pytest.mark.parametrize("body", list(BODIES))
-@pytest.mark.parametrize("case", list(CASES))
 def test_forward(case, body):
     got, want = _all(case)["the_kernels"][0], _all(case)[body][0]
     assert got.shape == want.shape and got.dtype == want.dtype
@@ -66,7 +82,6 @@ def test_forward(case, body):
 
 @pytest.mark.parametrize("operand", OPERANDS)
 @pytest.mark.parametrize("body", list(BODIES))
-@pytest.mark.parametrize("case", list(CASES))
 def test_gradient_of_each_operand(case, body, operand):
     at = OPERANDS.index(operand)
     got, want = _all(case)["the_kernels"][1][at], _all(case)[body][1][at]
@@ -123,18 +138,19 @@ def test_bfloat16_operands_beside_a_float32_state():
     recurrence as the ``jax.numpy`` body's; the state in the kernel's
     scratch and the solve's inverse are float32."""
     operands = _operands(4 * CHUNK, seed=5, dim=DIM)
-    want = kda_recurrent(*operands)
+    want = jax.jit(kda_recurrent)(*operands)
     low = tuple(t.astype(jnp.bfloat16) for t in operands[:3]) + operands[3:]
-    got = _through_kernels((2, 2, 2))(*low)
+    through = jax.jit(_through_kernels((2, 2, 2)))
+    got = through(*low)
     assert got.dtype == jnp.bfloat16
     err = jnp.abs(got.astype(jnp.float32) - want)
-    plain = jnp.abs(kda(*low).astype(jnp.float32) - want)
+    plain = jnp.abs(_jitted(kda)(*low).astype(jnp.float32) - want)
     assert float(err.max()) < 1.5 * float(plain.max()) < 0.02
     assert float(err.mean()) < 1.2 * float(plain.mean())
-    grads = jax.grad(_weighted(_through_kernels((2, 2, 2))), argnums=range(5))(
-        *low)
+    grads = jax.jit(jax.grad(
+        _weighted(_through_kernels((2, 2, 2))), argnums=range(5)))(*low)
     assert [g.dtype for g in grads] == [t.dtype for t in low]
-    text = jax.jit(_through_kernels((2, 2, 2))).lower(*low).as_text()
+    text = through.lower(*low).as_text()
     assert "2x128x128xf32" in text          # the state of two heads a step
 
 
@@ -213,14 +229,16 @@ def test_kda_takes_the_path_and_the_result_is_the_same(monkeypatch, on_a_tpu):
         _as_on_a_tpu(monkeypatch)
     operands = _operands(2 * CHUNK, dim=DIM, seed=9, batch=1)
     np.testing.assert_allclose(
-        kda(*operands), kda_recurrent(*operands), rtol=0, atol=2e-5)
+        _jitted(kda)(*operands), _jitted(kda_recurrent)(*operands), rtol=0,
+        atol=2e-5)
     assert bool(through) == on_a_tpu
     del through[:]
     ragged = _operands(2 * CHUNK + 8, dim=DIM, seed=9, batch=1)
     small = _operands(2 * CHUNK, dim=16, seed=9, batch=1)
     for operands in (ragged, small):
         np.testing.assert_allclose(
-            kda(*operands), kda_recurrent(*operands), rtol=0, atol=2e-5)
+            _jitted(kda)(*operands), _jitted(kda_recurrent)(*operands),
+            rtol=0, atol=2e-5)
     assert not through
 
 
@@ -245,9 +263,10 @@ def test_the_event_says_which_core_and_the_tile(monkeypatch, on_a_tpu,
         kda_head_dim=head_dim, dtype=jnp.float32, max_seq_len=128)
     layer = llama.DeltaAttention(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 128, cfg.hidden_size))
-    params = layer.init(jax.random.PRNGKey(1), x, None, None)
+    params = _jitted(layer.init)(jax.random.PRNGKey(1), x, None, None)
     del records[:]
-    out, _ = layer.apply(params, x, None, None, mutable=["stats"])
+    out, _ = _jitted(functools.partial(layer.apply, mutable=["stats"]))(
+        params, x, None, None)
     assert bool(jnp.isfinite(out).all())
     (name, attrs), *kept_records = records
     assert name == "attention.path"

@@ -25,6 +25,7 @@ from dlrover_tpu.models.moe import MoELlamaConfig
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.flash_checkpoint import Checkpointer, StorageType
 from dlrover_tpu.trainer.train import Trainer
+from against_reference import init_params, perturbed, token_ids
 
 PATTERN = ("gqa", "kda", "kda", "kda")
 SEQ = 48
@@ -39,18 +40,7 @@ def _config(**changes):
 
 
 def _ids(batch=2, seq=SEQ, seed=0):
-    rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.integers(0, 256, size=(batch, seq)), jnp.int32)
-
-
-def _perturbed(params):
-    """Every leaf moved: an untrained norm's scale is 1 and the decay's
-    vectors are small, so a stack that forgot one would pass."""
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
-    return jax.tree.unflatten(tree, [
-        leaf + 0.1 * jax.random.normal(k, leaf.shape, leaf.dtype)
-        for leaf, k in zip(leaves, keys)])
+    return jnp.asarray(token_ids(batch, seq, seed=seed))
 
 
 @pytest.fixture(scope="module")
@@ -58,9 +48,7 @@ def made():
     cfg = _config()
     model = LlamaForCausalLM(cfg)
     ids = _ids()
-    params = _perturbed(nn.meta.unbox(
-        model.init(jax.random.PRNGKey(1), ids)["params"]))
-    return cfg, model, ids, params
+    return cfg, model, ids, perturbed(init_params(model, ids), seed=7)
 
 
 def test_layer_runs_of_a_pattern():
@@ -98,23 +86,27 @@ def test_patterned_stack_equals_the_same_layers_unrolled(made):
     """Each layer applied by hand in the stack's order (period by period,
     run by run) with its slice of the stacked parameters."""
     cfg, model, ids, params = made
-    got = model.apply({"params": params}, ids)
-    x = params["embed_tokens"][ids]
+    got = jax.jit(lambda p: model.apply({"params": p}, ids))(params)
     positions = jnp.broadcast_to(jnp.arange(SEQ), ids.shape)
     mask = jnp.tril(jnp.ones((SEQ, SEQ), bool))[None, None]
     kinds = []
-    for period in range(cfg.num_layers // len(PATTERN)):
-        for name, kind, length in cfg.layer_runs():
-            for i in range(length):
-                layer = jax.tree.map(lambda t: t[period, i],
-                                     params["layers"][name]["layer"])
-                x = DecoderLayer(cfg, kind).apply(
-                    {"params": layer}, x, positions, mask)
-                kinds.append(kind)
+
+    def unrolled(params):
+        x = params["embed_tokens"][ids]
+        for period in range(cfg.num_layers // len(PATTERN)):
+            for name, kind, length in cfg.layer_runs():
+                for i in range(length):
+                    layer = jax.tree.map(lambda t: t[period, i],
+                                         params["layers"][name]["layer"])
+                    x = DecoderLayer(cfg, kind).apply(
+                        {"params": layer}, x, positions, mask)
+                    kinds.append(kind)
+        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype).apply(
+            {"params": params["final_norm"]}, x)
+        return x @ params["lm_head"]["kernel"]
+
+    want = jax.jit(unrolled)(params)
     assert tuple(kinds) == PATTERN * 2
-    x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype).apply(
-        {"params": params["final_norm"]}, x)
-    want = x @ params["lm_head"]["kernel"]
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
 
 
@@ -196,7 +188,7 @@ def test_no_mask_where_no_layer_reads_one():
     assert "1024x1024" not in lowered(only_kda)
 
 
-def test_patterned_model_trains(made):
+def test_patterned_model_trains():
     """A few steps through ``Trainer`` on one device: the loss falls, the
     counters a delta-rule layer sows come back a value a layer."""
     cfg = _config(num_layers=4, dtype=jnp.bfloat16)

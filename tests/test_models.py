@@ -53,8 +53,8 @@ class TestLlama:
         cfg = LlamaConfig.tiny()
         model = LlamaForCausalLM(cfg)
         ids = jnp.zeros((2, 16), jnp.int32)
-        variables = model.init(jax.random.PRNGKey(0), ids)
-        logits = model.apply(variables, ids)
+        variables = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
+        logits = jax.jit(model.apply)(variables, ids)
         assert logits.shape == (2, 16, cfg.vocab_size)
         assert logits.dtype == jnp.float32
 
@@ -64,10 +64,11 @@ class TestLlama:
         model = LlamaForCausalLM(cfg)
         rng = jax.random.PRNGKey(1)
         ids = jax.random.randint(rng, (1, 12), 0, cfg.vocab_size)
-        variables = model.init(rng, ids)
-        base = model.apply(variables, ids)
+        variables = jax.jit(model.init)(rng, ids)
+        apply = jax.jit(model.apply)
+        base = apply(variables, ids)
         changed = ids.at[0, 8].set((ids[0, 8] + 1) % cfg.vocab_size)
-        out = model.apply(variables, changed)
+        out = apply(variables, changed)
         np.testing.assert_allclose(
             np.asarray(base[0, :8], np.float32),
             np.asarray(out[0, :8], np.float32),
@@ -81,8 +82,8 @@ class TestLlama:
         cfg = LlamaConfig.tiny(num_heads=4, num_kv_heads=2)
         model = LlamaForCausalLM(cfg)
         ids = jnp.zeros((1, 8), jnp.int32)
-        variables = model.init(jax.random.PRNGKey(0), ids)
-        logits = model.apply(variables, ids)
+        variables = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
+        logits = jax.jit(model.apply)(variables, ids)
         assert logits.shape[-1] == cfg.vocab_size
 
 
@@ -172,8 +173,8 @@ class TestViT:
         cfg = ViTConfig.tiny()
         model = ViTForImageClassification(cfg)
         images = jnp.ones((2, cfg.image_size, cfg.image_size, 3))
-        params = model.init(jax.random.PRNGKey(0), images)["params"]
-        logits = model.apply({"params": params}, images)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), images)["params"]
+        logits = jax.jit(model.apply)({"params": params}, images)
         assert logits.shape == (2, cfg.num_classes)
         assert logits.dtype == jnp.float32
 
@@ -229,6 +230,7 @@ class TestViT:
         cfg = ViTConfig.tiny(scan_layers=False, remat=False)
         model = ViTForImageClassification(cfg)
         images = jnp.ones((1, cfg.image_size, cfg.image_size, 3))
-        params = model.init(jax.random.PRNGKey(0), images)["params"]
+        params = jax.eval_shape(
+            model.init, jax.random.PRNGKey(0), images)["params"]
         blocks = [k for k in params if k.startswith("encoder_")]
         assert len(blocks) == cfg.num_layers

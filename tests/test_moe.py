@@ -22,6 +22,7 @@ from dlrover_tpu.models.moe import (
 from dlrover_tpu.ops.pallas import kept
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.train import Trainer
+from against_reference import init_params, jitted, perturbed, system
 
 #: the model below as the reference reads it (published key names)
 PUBLISHED = dict(num_hidden_layers=2, num_experts=8, num_experts_per_tok=3,
@@ -41,30 +42,15 @@ def _batch(cfg, rows=8, seq=32, seed=0):
 
 
 def _perturbed(params, seed=2):
-    """Untrained scales are 1 and the router starts near uniform: move
-    every leaf, or a reference that forgot one would pass."""
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return jax.tree.unflatten(tree, [
-        leaf + 0.05 * jax.random.normal(k, leaf.shape, leaf.dtype)
-        for leaf, k in zip(leaves, keys)])
+    return perturbed(params, seed, scale=0.05)
 
 
 def _system(model, params, batch):
     """(total loss, token losses) and gradients as ``Trainer``'s
     default loss computes them."""
-    def loss_fn(p):
-        logits, sown = model.apply(
-            {"params": p}, batch["input_ids"], mutable=["losses", "stats"])
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        token = -jnp.take_along_axis(
-            logp, batch["labels"][..., None], -1)[..., 0]
-        total = token.mean() + sum(
-            jnp.sum(t) for t in jax.tree.leaves(sown["losses"]))
-        return total, token
-
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss_fn, has_aux=True)(params)
+    (total, (token, _)), grads = system(
+        model, params, batch["input_ids"], batch["labels"])
+    return (total, token), grads
 
 
 def _max_err(a, b):
@@ -77,8 +63,8 @@ class TestAgainstReference:
         cfg = MoELlamaConfig.tiny_moe()
         model = LlamaForCausalLM(cfg)
         ids = jnp.zeros((2, 16), jnp.int32)
-        params = nn.meta.unbox(model.init(jax.random.PRNGKey(0), ids))["params"]
-        assert model.apply({"params": params}, ids).shape == (
+        params = init_params(model, ids, seed=0)
+        assert jitted(model.apply, {"params": params}, ids).shape == (
             2, 16, cfg.vocab_size)
         mlp = params["layers"]["layer"]["mlp"]
         assert mlp["gate_proj"].shape == (
@@ -93,14 +79,14 @@ class TestAgainstReference:
         cfg = _config()
         model = LlamaForCausalLM(cfg)
         batch = jax.tree.map(jnp.asarray, _batch(cfg, rows=2))
-        params = _perturbed(nn.meta.unbox(
-            model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]))
+        params = _perturbed(init_params(model, batch["input_ids"]))
         (total, token), grads = _system(model, params, batch)
-        want = reference.forward(
-            params, batch["input_ids"], batch["labels"], PUBLISHED)
-        want_total, want_grads = jax.value_and_grad(reference.total_loss)(
-            params, batch["input_ids"], batch["labels"], PUBLISHED,
-            cfg.load_balance_coef, cfg.router_z_coef)
+        want = jitted(lambda p: reference.forward(
+            p, batch["input_ids"], batch["labels"], PUBLISHED), params)
+        want_total, want_grads = jitted(jax.value_and_grad(
+            lambda p: reference.total_loss(
+                p, batch["input_ids"], batch["labels"], PUBLISHED,
+                cfg.load_balance_coef, cfg.router_z_coef)), params)
         np.testing.assert_allclose(token, want["token_losses"], atol=1e-4)
         np.testing.assert_allclose(total, want_total, atol=1e-4)
         assert _max_err(grads, want_grads) < 1e-4
@@ -113,11 +99,10 @@ class TestAgainstReference:
         cfg = _config(num_layers=1)
         x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, cfg.hidden_size))
         mlp = MoEMLP(cfg)
-        params = _perturbed(nn.meta.unbox(
-            mlp.init(jax.random.PRNGKey(1), x)["params"]))
+        params = _perturbed(init_params(mlp, x))
+        got = jitted(lambda p: mlp.apply({"params": p}, x), params)
+        want = jitted(lambda p: reference.experts(x, p, PUBLISHED)[0], params)
         with jax.default_matmul_precision("highest"):
-            got = mlp.apply({"params": params}, x)
-            want = reference.experts(x, params, PUBLISHED)[0]
             probs = jax.nn.softmax(x @ params["router"]["kernel"])
             kept = jax.lax.top_k(probs, cfg.top_k)[0].sum(-1, keepdims=True)
         np.testing.assert_allclose(got, want, atol=1e-5)
@@ -129,8 +114,8 @@ class TestAgainstReference:
         logits = jax.random.normal(jax.random.PRNGKey(1), (64, 8))
         top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits), cfg.top_k)
         w = jax.random.normal(jax.random.PRNGKey(2), (3, 8, 64, 128)) * 0.1
-        out, sizes, held = local_experts(
-            x, top_i, top_w, w[0], w[1], w[2].swapaxes(1, 2), 0)
+        out, sizes, held = jax.jit(lambda *a: local_experts(*a, 0))(
+            x, top_i, top_w, w[0], w[1], w[2].swapaxes(1, 2))
         assert int(sizes.sum()) == 64 * cfg.top_k == int(held)
         np.testing.assert_array_equal(
             sizes, np.bincount(np.asarray(top_i).ravel(), minlength=8))
@@ -144,7 +129,7 @@ class TestAgainstReference:
             num_experts=experts, top_k=2, num_layers=1, dtype=jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, cfg.hidden_size))
         mlp = MoEMLP(cfg)
-        variables = mlp.init(jax.random.PRNGKey(1), x)
+        variables = jax.eval_shape(mlp.init, jax.random.PRNGKey(1), x)
         lowered = jax.jit(lambda v, x: mlp.apply(v, x)).lower(
             {"params": variables["params"]}, x).as_text()
         assert f"tensor<{2 * 64 * 2}x{cfg.hidden_size}xf32>" in lowered
@@ -178,11 +163,10 @@ class TestNoTokenIsLost:
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 16, cfg.hidden_size))
         x = x.at[..., 0].set(1.0)
         mlp = MoEMLP(cfg)
-        params = _forced_router(_perturbed(nn.meta.unbox(
-            mlp.init(jax.random.PRNGKey(1), x)["params"])), chosen)
+        params = _forced_router(_perturbed(init_params(mlp, x)), chosen)
         mesh = build_mesh(MeshConfig(dp=8 // ep, ep=ep))
+        want = jitted(lambda p: reference.experts(x, p, published)[0], params)
         with jax.default_matmul_precision("highest"):
-            want = reference.experts(x, params, published)[0]
             with mesh:
                 got, sown = jax.jit(lambda p, x: mlp.apply(
                     {"params": p}, x, mutable=["stats", "losses"]))(params, x)
@@ -208,6 +192,24 @@ def _split_router(params, flagged, others):
 RUNGS = [(1 / 4, 0), (1 / 3, 1), (1 / 2, 2), (1.0, 3)]
 
 
+@functools.lru_cache(maxsize=None)
+def _rung_inputs(seq, share):
+    """(configuration, layer, parameters, tokens, flagged tokens): the
+    first ``share`` of every sequence's ``seq`` tokens sent to experts 0
+    and 1, the rest to 2 and 4."""
+    cfg = MoELlamaConfig.tiny_moe(
+        num_experts=8, top_k=2, num_layers=1, dtype=jnp.float32)
+    flagged = int(seq * share)
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, seq, cfg.hidden_size))
+    x = x.at[..., 0].set(1.0).at[..., 1].set(
+        (jnp.arange(seq) < flagged).astype(x.dtype))
+    mlp = MoEMLP(cfg)
+    params = _split_router(
+        _perturbed(init_params(mlp, x[:1, :16])),
+        flagged=[0, 1], others=[2, 4])
+    return cfg, mlp, params, x, flagged
+
+
 class TestEveryRung:
     """``ep=4``, 2 of 8 experts a rank, 1024 tokens a source rank with 2
     experts each: extents of 640, 768, 1024 and 2048 rows."""
@@ -215,18 +217,7 @@ class TestEveryRung:
     SEQ = 1024
 
     def _inputs(self, share):
-        cfg = MoELlamaConfig.tiny_moe(
-            num_experts=8, top_k=2, num_layers=1, dtype=jnp.float32)
-        flagged = int(self.SEQ * share)
-        x = jax.random.normal(
-            jax.random.PRNGKey(0), (8, self.SEQ, cfg.hidden_size))
-        x = x.at[..., 0].set(1.0).at[..., 1].set(
-            (jnp.arange(self.SEQ) < flagged).astype(x.dtype))
-        mlp = MoEMLP(cfg)
-        params = _split_router(_perturbed(nn.meta.unbox(
-            mlp.init(jax.random.PRNGKey(1), x[:1, :16])["params"])),
-            flagged=[0, 1], others=[2, 4])
-        return cfg, mlp, params, x, flagged
+        return _rung_inputs(self.SEQ, share)
 
     def test_the_ladder_of_these_shapes(self):
         assert ladder(2 * self.SEQ, 2, 8) == (640, 768, 1024, 2048)
@@ -243,8 +234,8 @@ class TestEveryRung:
         the two counters say which extents were taken."""
         cfg, mlp, params, x, flagged = self._inputs(share)
         published = dict(PUBLISHED, num_experts_per_tok=2)
+        want = jitted(lambda p: reference.experts(x, p, published)[0], params)
         with jax.default_matmul_precision("highest"):
-            want = reference.experts(x, params, published)[0]
             with build_mesh(MeshConfig(dp=2, ep=4)):
                 got, sown = jax.jit(lambda p, x: mlp.apply(
                     {"params": p}, x, mutable=["stats", "losses"]))(params, x)
@@ -355,9 +346,8 @@ class TestTheBackwardTakesTheProductsFromTheForward:
                 name: params[name][:cfg.experts_held]
                 for name in ("gate_proj", "up_proj", "down_proj")}}
         if cfg.shared_experts:
-            params = {**params, "shared_expert": _perturbed(nn.meta.unbox(
-                mlp.init(jax.random.PRNGKey(5), x[:1, :16])["params"]
-            ))["shared_expert"]}
+            params = {**params, "shared_expert": _perturbed(init_params(
+                mlp, x[:1, :16], seed=5))["shared_expert"]}
         if mesh_cfg.ep == 1:
             x = x[:1]
 
@@ -656,9 +646,9 @@ class TestDefaultLoss:
         state = trainer.create_state(jax.random.PRNGKey(0), batch["input_ids"])
         from dlrover_tpu.trainer.train import cross_entropy_loss
 
-        want = cross_entropy_loss(
-            model.apply({"params": state.params}, batch["input_ids"]),
-            batch["labels"])
+        want = jax.jit(lambda p: cross_entropy_loss(
+            model.apply({"params": p}, batch["input_ids"]),
+            batch["labels"]))(state.params)
         state, metrics = trainer.train_step(state, batch)
         assert set(metrics) == {"loss", "grad_norm"}
         np.testing.assert_allclose(metrics["loss"], want, rtol=1e-6)
@@ -722,8 +712,7 @@ class TestQKNorm:
         positions = jnp.broadcast_to(jnp.arange(16), (2, 16))
         mask = jnp.tril(jnp.ones((16, 16), bool))[None, None]
         attn = Attention(cfg)
-        params = nn.meta.unbox(
-            attn.init(jax.random.PRNGKey(1), x, positions, mask)["params"])
+        params = init_params(attn, x, positions, mask)
         assert set(params) == {"q_proj", "k_proj", "v_proj", "o_proj"}
         got = attn.apply({"params": params}, x, positions, mask)
 
@@ -747,15 +736,14 @@ class TestQKNorm:
         positions = jnp.broadcast_to(jnp.arange(16), (2, 16))
         mask = jnp.tril(jnp.ones((16, 16), bool))[None, None]
         attn = Attention(cfg)
-        params = _perturbed(nn.meta.unbox(
-            attn.init(jax.random.PRNGKey(1), x, positions, mask)["params"]))
+        params = _perturbed(init_params(attn, x, positions, mask))
         assert params["q_norm"]["scale"].shape == (
             cfg.num_heads * cfg.head_dim,)
         assert params["k_norm"]["scale"].shape == (
             cfg.num_kv_heads * cfg.head_dim,)
-        with jax.default_matmul_precision("highest"):
-            got = attn.apply({"params": params}, x, positions, mask)
-            want = reference.attention(x, params, PUBLISHED)
+        got = jitted(lambda p: attn.apply({"params": p}, x, positions, mask),
+                     params)
+        want = jitted(lambda p: reference.attention(x, p, PUBLISHED), params)
         np.testing.assert_allclose(got, want, atol=1e-5)
 
 
@@ -824,8 +812,7 @@ class TestSigmoidRouterAndSharedExpert:
     def _layer(self, x, **kw):
         cfg = _config(router_scores="sigmoid", norm_topk_prob=True,
                       shared_experts=1, router_z_coef=0.0, num_layers=1, **kw)
-        params = _perturbed(nn.meta.unbox(
-            MoEMLP(cfg).init(jax.random.PRNGKey(4), x)["params"]), seed=5)
+        params = _perturbed(init_params(MoEMLP(cfg), x, seed=4), seed=5)
         return cfg, params
 
     @pytest.fixture(scope="class")
@@ -839,12 +826,11 @@ class TestSigmoidRouterAndSharedExpert:
         cfg, params = self._layer(x, routed_scaling_factor=scale)
         assert set(params) == {"router", "gate_proj", "up_proj", "down_proj",
                                "shared_expert"}
-        with jax.default_matmul_precision("highest"):
-            got, sown = MoEMLP(cfg).apply(
-                {"params": params}, x, mutable=["losses", "stats"])
-            want, balance, _ = solar_open2_reference.experts(
-                x, params, {**self.M, "routed_scaling_factor": scale},
-                whole=True)
+        got, sown = jitted(lambda p: MoEMLP(cfg).apply(
+            {"params": p}, x, mutable=["losses", "stats"]), params)
+        want, balance, _ = jitted(lambda p: solar_open2_reference.experts(
+            x, p, {**self.M, "routed_scaling_factor": scale}, whole=True),
+            params)
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
         np.testing.assert_allclose(
             sown["losses"]["load_balance"][0] / cfg.load_balance_coef,
@@ -865,9 +851,8 @@ class TestSigmoidRouterAndSharedExpert:
             return jnp.sum(solar_open2_reference.experts(
                 h, p, self.M, whole=True)[0] * weights)
 
-        with jax.default_matmul_precision("highest"):
-            got = jax.grad(system, argnums=(0, 1))(params, x)
-            want = jax.grad(plain, argnums=(0, 1))(params, x)
+        got = jitted(jax.grad(system, argnums=(0, 1)), params, x)
+        want = jitted(jax.grad(plain, argnums=(0, 1)), params, x)
         assert _max_err(got, want) < 2e-4
         # the shared expert and the router both get a gradient
         assert float(jnp.abs(
@@ -882,8 +867,8 @@ class TestSigmoidRouterAndSharedExpert:
         import dataclasses
 
         other = dataclasses.replace(cfg, router_scores="softmax")
-        got = MoEMLP(cfg).apply({"params": params}, x)
-        soft = MoEMLP(other).apply({"params": params}, x)
+        got = jitted(lambda p: MoEMLP(cfg).apply({"params": p}, x), params)
+        soft = jitted(lambda p: MoEMLP(other).apply({"params": p}, x), params)
         assert float(jnp.abs(got - soft).max()) > 1e-2
 
     def test_the_shared_expert_is_added_once_and_unweighted(self, x):
@@ -896,12 +881,12 @@ class TestSigmoidRouterAndSharedExpert:
         cfg, params = self._layer(x)
         without = dataclasses.replace(cfg, shared_experts=0)
         routed = {k: v for k, v in params.items() if k != "shared_expert"}
-        with jax.default_matmul_precision("highest"):
-            both = MoEMLP(cfg).apply({"params": params}, x)
-            alone = MoEMLP(without).apply({"params": routed}, x)
-            shared = MLP(dataclasses.replace(
-                cfg, intermediate_size=cfg.shared_width())).apply(
-                    {"params": params["shared_expert"]}, x)
+        both = jitted(lambda p: MoEMLP(cfg).apply({"params": p}, x), params)
+        alone = jitted(lambda p: MoEMLP(without).apply({"params": p}, x),
+                       routed)
+        shared = jitted(lambda p: MLP(dataclasses.replace(
+            cfg, intermediate_size=cfg.shared_width())).apply(
+                {"params": p}, x), params["shared_expert"])
         np.testing.assert_allclose(both - alone, shared, rtol=0, atol=2e-5)
         assert cfg.shared_width() == cfg.intermediate_size
 

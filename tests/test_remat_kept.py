@@ -34,6 +34,7 @@ from dlrover_tpu.models.moe import MoELlamaConfig, ladder
 from dlrover_tpu.ops import attention as ops
 from dlrover_tpu.ops import linear_attention
 from dlrover_tpu.ops.pallas import kept
+from shared_memo import shared_memo
 
 LAYERS, HEADS, DIM, HIDDEN = 2, 2, 128, 64
 F32 = jnp.dtype("float32")
@@ -121,7 +122,8 @@ class _Stack:
             jnp.ones((core.rows, core.rows), bool))[None, None]
         self.x = jax.random.normal(
             jax.random.PRNGKey(0), (1, core.rows, HIDDEN))
-        self.params = self.stack.init(
+        # one program, of which the compiler keeps the parameters alone
+        self.params = jax.jit(self.stack.init)(
             jax.random.PRNGKey(1), self.x, self.positions, self.mask)["params"]
 
     def loss(self, params, x):
@@ -163,20 +165,54 @@ def _kernel_calls(jaxpr, counts=None):
     return counts
 
 
-@pytest.mark.parametrize("name", list(KERNEL_CORES))
-def test_the_forward_pass_keeps_the_input_and_the_named_values(
-        monkeypatch, name):
+POLICIES = {"kept": kept.LAYER_POLICY,
+            "again": jax.checkpoint_policies.nothing_saveable}
+
+
+@shared_memo
+def _measured(name):
+    """What the tests of one kernel core read, from one stack a policy:
+    policy -> the forward kernels' calls in the gradient's jaxpr and the
+    loss with every gradient; under the layer's policy also the stacked
+    residuals of the forward pass and the ``remat.kept`` note of every
+    trace made."""
     core = KERNEL_CORES[name]
-    stack = _Stack(monkeypatch, core, kept.LAYER_POLICY)
-    stacked = collections.Counter(
-        aval.shape[1:] for aval, why in saved_residuals(
-            stack.loss, stack.params, stack.x)
-        if "output of scan" in why and aval.shape[0] == LAYERS)
+    measured = {}
+    for policy_name, policy in POLICIES.items():
+        with pytest.MonkeyPatch.context() as patch:
+            stack = _Stack(patch, core, policy)
+            facts = measured[policy_name] = {}
+            if policy_name == "kept":
+                facts["residuals"] = collections.Counter(
+                    aval.shape[1:] for aval, why in saved_residuals(
+                        stack.loss, stack.params, stack.x)
+                    if "output of scan" in why and aval.shape[0] == LAYERS)
+            counts = _kernel_calls(jax.make_jaxpr(jax.grad(
+                stack.loss, argnums=(0, 1)))(stack.params, stack.x).jaxpr)
+            facts["calls"] = {
+                kernel: counts[kernel] for kernel in core.forward_kernels}
+            facts["loss_and_gradients"] = jax.tree.leaves(
+                stack.value_and_grad())
+            if policy_name == "kept":
+                facts["note"] = stack.kept_note()
+    return measured
+
+
+@pytest.fixture(scope="module", params=list(KERNEL_CORES))
+def name(request):
+    """A module's fixture and not a ``parametrize``: the three tests of
+    one core stand next to each other in the collection."""
+    return request.param
+
+
+def test_the_forward_pass_keeps_the_input_and_the_named_values(name):
+    core, facts = KERNEL_CORES[name], _measured(name)["kept"]
     layer_input = {(1, core.rows, HIDDEN): 1}
-    assert stacked == collections.Counter({**core.kept, **layer_input})
+    assert facts["residuals"] == collections.Counter(
+        {**core.kept, **layer_input})
     held = sum(math.prod(shape) * count * F32.itemsize
                for shape, count in core.kept.items())
-    note = stack.kept_note()
+    note = facts["note"]
     assert note["bytes_per_layer"] == held
     assert note["names"] == ",".join(
         name for name in kept.NAMES if f"{name}_bytes" in note)
@@ -184,41 +220,28 @@ def test_the_forward_pass_keeps_the_input_and_the_named_values(
         note[f"{name}_bytes"] for name in note["names"].split(","))
 
 
-@pytest.mark.parametrize("name", list(KERNEL_CORES))
-def test_the_gradient_runs_each_forward_kernel_once_a_layer(
-        monkeypatch, name):
-    core = KERNEL_CORES[name]
-
-    def calls(policy):
-        stack = _Stack(monkeypatch, core, policy)
-        counts = _kernel_calls(jax.make_jaxpr(
-            jax.grad(stack.loss, argnums=(0, 1)))(stack.params, stack.x).jaxpr)
-        return {kernel: counts[kernel] for kernel in core.forward_kernels}
-
-    assert calls(kept.LAYER_POLICY) == core.forward_kernels
+def test_the_gradient_runs_each_forward_kernel_once_a_layer(name):
+    core, measured = KERNEL_CORES[name], _measured(name)
+    assert measured["kept"]["calls"] == core.forward_kernels
     # what the policy is for: without a kept name every one runs twice
-    assert calls(jax.checkpoint_policies.nothing_saveable) == {
+    assert measured["again"]["calls"] == {
         kernel: 2 * n for kernel, n in core.forward_kernels.items()}
 
 
-@pytest.mark.parametrize("name", list(KERNEL_CORES))
-def test_loss_and_gradients_are_those_of_a_layer_computed_again_whole(
-        monkeypatch, name):
-    core = KERNEL_CORES[name]
-    got = _Stack(monkeypatch, core, kept.LAYER_POLICY).value_and_grad()
-    want = _Stack(
-        monkeypatch, core,
-        jax.checkpoint_policies.nothing_saveable).value_and_grad()
-    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+def test_loss_and_gradients_are_those_of_a_layer_computed_again_whole(name):
+    measured = _measured(name)
+    got = measured["kept"]["loss_and_gradients"]
+    want = measured["again"]["loss_and_gradients"]
     assert len(got) == len(want) > 2
     for a, b in zip(got, want):
         assert float(jnp.abs(b).max()) > 0
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("name", list(UNNAMED_CORES))
-def test_a_core_that_names_nothing_is_computed_again_whole(monkeypatch, name):
-    core = UNNAMED_CORES[name]
+@pytest.mark.parametrize("unnamed", list(UNNAMED_CORES))
+def test_a_core_that_names_nothing_is_computed_again_whole(monkeypatch,
+                                                           unnamed):
+    core = UNNAMED_CORES[unnamed]
 
     def lowered(policy):
         stack = _Stack(monkeypatch, core, policy)

@@ -10,6 +10,7 @@ properties, **the shares add up**, the noise differs by step and a resumed
 job repeats the uninterrupted one's losses bit for bit, and nothing moves
 for a model without the field."""
 
+import collections
 import dataclasses
 import uuid
 
@@ -31,6 +32,15 @@ from dlrover_tpu.ops import attention as attention_ops
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.flash_checkpoint import Checkpointer, StorageType
 from dlrover_tpu.trainer.train import Trainer
+from against_reference import (
+    init_params,
+    jitted,
+    perturbed,
+    reference_loss_and_gradients,
+    system,
+    system_loss,
+    token_ids,
+)
 
 SEQ, BLOCK, MASK = 40, 4, 255
 
@@ -54,17 +64,8 @@ def _published(cfg, **changes):
             "block_length": cfg.block_diffusion, "query_block": 16, **changes}
 
 
-def _perturbed(params, seed=2, scale=0.1):
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return jax.tree.unflatten(tree, [
-        leaf + scale * jax.random.normal(k, leaf.shape, leaf.dtype)
-        for leaf, k in zip(leaves, keys)])
-
-
 def _ids(batch=2, seq=SEQ, seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(
-        0, MASK, size=(batch, seq)), jnp.int32)
+    return jnp.asarray(token_ids(batch, seq, MASK, seed))
 
 
 def _noise(cfg, ids, step=0):
@@ -72,18 +73,18 @@ def _noise(cfg, ids, step=0):
                         cfg.block_diffusion, cfg.mask_token_id, cfg.noise_eps)
 
 
-def _system(model, params, ids, rngs=None):
-    """((the step's loss, (logits, what the model sowed)), gradients) as
-    ``Trainer``'s default loss computes them for a model with its own
-    objective: the sum of what it sows into ``losses``."""
-    def loss_fn(p):
-        logits, sown = model.apply(
-            {"params": p}, ids, mutable=["losses", "stats"], rngs=rngs)
-        total = sum(jnp.sum(t) for t in jax.tree.leaves(sown["losses"]))
-        return total, (logits, sown)
+def _reference(cfg, params, ids, noisy, weights):
+    """(the reference's dictionary, its loss's gradients)."""
+    m = _published(cfg)
+    return reference_loss_and_gradients(
+        lambda p: reference.forward(p, noisy, ids, weights, m), params)
 
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss_fn, has_aux=True)(params)
+
+#: what the fixture computed, once a parameter: the system's ``((the
+#: step's loss, (logits, sown)), gradients)``, the noise the reference is
+#: given, the reference's dictionary and gradients
+Made = collections.namedtuple(
+    "Made", "cfg model params ids got noisy weights want want_grads")
 
 
 @pytest.fixture(scope="module", params=[0, 2], ids=["every_expert", "a_share"])
@@ -91,17 +92,16 @@ def made(request):
     cfg = _config(experts_held=request.param, first_expert=request.param * 2)
     model = LlamaForCausalLM(cfg)
     ids = _ids()
-    params = _perturbed(nn.meta.unbox(
-        model.init(jax.random.PRNGKey(1), ids)["params"]))
-    return cfg, model, params, ids
+    params = perturbed(init_params(model, ids))
+    noisy, weights = _noise(cfg, ids)
+    return Made(cfg, model, params, ids, system(model, params, ids),
+                noisy, weights, *_reference(cfg, params, ids, noisy, weights))
 
 
 class TestAgainstReference:
     def test_logits_objective_and_counters(self, made):
-        cfg, model, params, ids = made
-        (total, (logits, sown)), _ = _system(model, params, ids)
-        noisy, weights = _noise(cfg, ids)
-        want = reference.forward(params, noisy, ids, weights, _published(cfg))
+        cfg, ids, weights, want = made.cfg, made.ids, made.weights, made.want
+        (total, (logits, sown)), _ = made.got
         assert logits.shape == ids.shape + (cfg.vocab_size,)
         np.testing.assert_allclose(logits, want["logits"], rtol=0, atol=5e-5)
         np.testing.assert_allclose(
@@ -118,15 +118,9 @@ class TestAgainstReference:
             want["load_balance"], rtol=1e-5)
 
     def test_gradients_of_every_parameter(self, made):
-        cfg, model, params, ids = made
-        _, got = _system(model, params, ids)
-        noisy, weights = _noise(cfg, ids)
-        m = _published(cfg)
-        with jax.default_matmul_precision("highest"):
-            want = jax.grad(lambda p: reference.forward(
-                p, noisy, ids, weights, m)["loss"])(params)
+        _, got = made.got
         flat = jax.tree_util.tree_leaves_with_path(got)
-        for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        for (path, g), w in zip(flat, jax.tree.leaves(made.want_grads)):
             name = "/".join(str(k.key) for k in path)
             assert float(jnp.abs(w).max()) > 0, name     # every leaf is used
             np.testing.assert_allclose(
@@ -139,14 +133,17 @@ class TestAgainstReference:
         (its first ``make_rng``: the key with the count 1 folded in)."""
         from flax.core.scope import LazyRng
 
-        cfg, model, params, ids = made
-        (_, (logits, _)), _ = _system(model, params, ids, cfg.step_rngs(3))
+        cfg, model, params, ids = made[:4]
+        _, (logits, _) = system_loss(
+            model, params, ids, rngs=cfg.step_rngs(3))
         drawn = LazyRng.create(cfg.step_rngs(3)["noise"], 1).as_jax_rng()
         noisy, weights = noise_blocks(
             ids, drawn, cfg.block_diffusion, cfg.mask_token_id, cfg.noise_eps)
-        want = reference.forward(params, noisy, ids, weights, _published(cfg))
-        np.testing.assert_allclose(logits, want["logits"], rtol=0, atol=5e-5)
-        assert not np.array_equal(noisy, _noise(cfg, ids)[0])
+        m = _published(cfg)
+        want = jitted(lambda p: reference.forward(
+            p, noisy, ids, weights, m)["logits"], params)
+        np.testing.assert_allclose(logits, want, rtol=0, atol=5e-5)
+        assert not np.array_equal(noisy, made.noisy)
 
     @pytest.mark.parametrize("planted", [
         "causal_by_token", "own_clean_block_seen", "positions_run_on",
@@ -156,8 +153,8 @@ class TestAgainstReference:
         """The reference with one line of the rule changed reads far from
         the system: the agreement above is of this mask and these
         positions, not of any."""
-        cfg, model, params, ids = made
-        (_, (logits, _)), _ = _system(model, params, ids)
+        cfg, params, ids = made.cfg, made.params, made.ids
+        _, (logits, _) = made.got[0]
         seq = ids.shape[1]
         true_allowed, true_rope = reference.allowed, reference.rope
 
@@ -181,10 +178,10 @@ class TestAgainstReference:
 
         monkeypatch.setattr(reference, "allowed", allowed)
         monkeypatch.setattr(reference, "rope", rope)
-        noisy, weights = _noise(cfg, ids)
-        other = reference.forward(
-            params, noisy, ids, weights, _published(cfg))
-        assert float(jnp.abs(logits - other["logits"]).max()) > 1e-2
+        m = _published(cfg)
+        other = jitted(lambda p: reference.forward(
+            p, made.noisy, ids, made.weights, m)["logits"], params)
+        assert float(jnp.abs(logits - other).max()) > 1e-2
 
 
 def _table(seq, block):
@@ -222,10 +219,9 @@ class TestTheMask:
         k = jax.random.normal(keys[1], (2, 2 * seq, 2, 16))
         v = jax.random.normal(keys[2], (2, 2 * seq, 2, 16))
         table = _table(seq, block)
-        with jax.default_matmul_precision("highest"):
-            got = attention_ops.block_diffusion_attention(
-                q, k, v, block, query_block)
-            want = _dense(q, k, v, table)
+        got = jitted(lambda *a: attention_ops.block_diffusion_attention(
+            *a, block, query_block), q, k, v)
+        want = jitted(lambda *a: _dense(*a, table), q, k, v)
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
         assert table.sum() == attention_ops.block_diffusion_pairs(seq, block)
         # the reference's function is the same table
@@ -253,12 +249,11 @@ class TestTheMask:
         keys = jax.random.split(jax.random.PRNGKey(1), 3)
         q, k, v = (jax.random.normal(key, (1, 48, 2, 16)) for key in keys)
         table = _table(24, 4)
-        with jax.default_matmul_precision("highest"):
-            got = jax.grad(lambda *a: jnp.sum(jnp.square(
-                attention_ops.block_diffusion_attention(*a, 4, 8))),
-                (0, 1, 2))(q, k, v)
-            want = jax.grad(lambda *a: jnp.sum(jnp.square(
-                _dense(*a, table))), (0, 1, 2))(q, k, v)
+        got = jitted(jax.grad(lambda *a: jnp.sum(jnp.square(
+            attention_ops.block_diffusion_attention(*a, 4, 8))),
+            (0, 1, 2)), q, k, v)
+        want = jitted(jax.grad(lambda *a: jnp.sum(jnp.square(
+            _dense(*a, table))), (0, 1, 2)), q, k, v)
         for g, w in zip(got, want):
             np.testing.assert_allclose(g, w, rtol=0, atol=5e-5)
             assert float(jnp.abs(g[:, :24]).max()) > 0
@@ -323,22 +318,20 @@ class TestTheSharesAddUp:
     def test_expert_shares_sum_to_the_uncut_layer(self):
         cfg = _config()
         x = jax.random.normal(jax.random.PRNGKey(3), (2, 2 * SEQ, 64))
-        full = _perturbed(nn.meta.unbox(
-            MoEMLP(cfg).init(jax.random.PRNGKey(6), x)["params"]))
+        full = perturbed(init_params(MoEMLP(cfg), x, seed=6))
         m = _published(cfg)
-        with jax.default_matmul_precision("highest"):
-            want, balance, _ = reference.experts(x, full, m, whole=True)
+        want, balance, _ = jitted(
+            lambda p: reference.experts(x, p, m, whole=True), full)
         parts = []
         for first in (0, 2, 4, 6):
             share = dataclasses.replace(cfg, experts_held=2,
                                         first_expert=first)
             held = {**full, **{name: full[name][first: first + 2] for name in
                                ("gate_proj", "up_proj", "down_proj")}}
-            with jax.default_matmul_precision("highest"):
-                out, sown = MoEMLP(share).apply(
-                    {"params": held}, x, mutable=["losses", "stats"])
-                alone = reference.experts(
-                    x, held, {**m, "first_expert": first})[0]
+            out, sown = jitted(lambda p: MoEMLP(share).apply(
+                {"params": p}, x, mutable=["losses", "stats"]), held)
+            alone = jitted(lambda p: reference.experts(
+                x, p, {**m, "first_expert": first})[0], held)
             np.testing.assert_allclose(out, alone, rtol=0, atol=2e-5)
             np.testing.assert_allclose(
                 sown["losses"]["load_balance"][0] * cfg.num_layers
@@ -408,15 +401,12 @@ class TestTheTrainer:
         cfg = _config()
         trainer = _trainer(cfg)
         batch = {k: jnp.asarray(v) for k, v in _batch().items()}
-        params = trainer.model.init(
-            jax.random.PRNGKey(0), batch["input_ids"])["params"]
-        with jax.default_matmul_precision("highest"):
-            loss, stats = trainer._default_loss(params, batch)
-            (total, _), _ = _system(
-                trainer.model, nn.meta.unbox(params), batch["input_ids"])
-            # the labels go unused
-            other, _ = trainer._default_loss(
-                params, {**batch, "labels": batch["labels"][:, ::-1]})
+        params = init_params(trainer.model, batch["input_ids"], seed=0)
+        loss, stats = jitted(trainer._default_loss, params, batch)
+        total, _ = system_loss(trainer.model, params, batch["input_ids"])
+        # the labels go unused
+        other, _ = jitted(trainer._default_loss, params, {
+            **batch, "labels": batch["labels"][:, ::-1]})
         np.testing.assert_allclose(loss, total, rtol=1e-6)
         assert float(loss) == float(other)
         assert "bd_masked_share" in stats
@@ -425,11 +415,10 @@ class TestTheTrainer:
         cfg = _config(block_diffusion=0)
         trainer = _trainer(cfg)
         batch = {k: jnp.asarray(v) for k, v in _batch().items()}
-        params = trainer.model.init(
-            jax.random.PRNGKey(0), batch["input_ids"])["params"]
-        loss, _ = trainer._default_loss(params, batch)
-        logits, sown = trainer.model.apply(
-            {"params": params}, batch["input_ids"], mutable=["losses"])
+        params = init_params(trainer.model, batch["input_ids"], seed=0)
+        loss, _ = jitted(trainer._default_loss, params, batch)
+        logits, sown = jitted(lambda p: trainer.model.apply(
+            {"params": p}, batch["input_ids"], mutable=["losses"]), params)
         logp = jax.nn.log_softmax(logits.astype(jnp.float32))
         token = -jnp.take_along_axis(
             logp, batch["labels"][..., None], -1)[..., 0]

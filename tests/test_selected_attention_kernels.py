@@ -15,6 +15,7 @@ import pytest
 from dlrover_tpu.ops import attention as ops
 from dlrover_tpu.ops.pallas import selected_attention as kernels
 from dlrover_tpu.ops.pallas.tuning import selected_tiling
+from shared_memo import shared_memo
 
 TILE = 128   # keys a kernel tile in these cases: every block has several
 J, C = 2, 16
@@ -66,7 +67,7 @@ CASES = {
 QUANTITIES = ("out", "kl", "q", "k", "v", "index_q", "index_k", "index_w")
 
 
-@functools.lru_cache(maxsize=None)
+@shared_memo
 def _both(case):
     """quantity -> (kernels, jax.numpy) for one case: the outputs and the
     gradients of a loss that weighs every output element differently."""
@@ -90,8 +91,8 @@ def _both(case):
             out, kl = attend(q, k, v, ops._index_scores(*index), keep)
             return (out * weights).sum() + 3.0 * kl, (out, kl)
 
-        (_, (out, kl)), grads = jax.value_and_grad(
-            loss, argnums=tuple(range(6)), has_aux=True)(*operands)
+        (_, (out, kl)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(6)), has_aux=True))(*operands)
         return dict(zip(QUANTITIES, (out, kl) + grads))
 
     got = run(functools.partial(

@@ -7,6 +7,7 @@ counters.  And **the shares add up**: the head shares of a delta-rule layer
 and of a softmax layer sum to the whole layer's result, and the expert
 shares, with the shared expert counted once, to the uncut layer's."""
 
+import collections
 import dataclasses
 
 import flax.linen as nn
@@ -22,6 +23,15 @@ from dlrover_tpu.models.llama import (
     LlamaForCausalLM,
 )
 from dlrover_tpu.models.moe import MoELlamaConfig, MoEMLP
+from against_reference import (
+    init_params,
+    inputs_and_labels,
+    jitted,
+    perturbed,
+    reference_loss_and_gradients,
+    system,
+    system_loss,
+)
 
 PATTERN = ("gqa", "kda", "kda", "kda")
 SEQ = 48
@@ -47,34 +57,15 @@ def _published(cfg, **changes):
             "query_block": 16, **changes}
 
 
-def _perturbed(params, seed=2, scale=0.1):
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return jax.tree.unflatten(tree, [
-        leaf + scale * jax.random.normal(k, leaf.shape, leaf.dtype)
-        for leaf, k in zip(leaves, keys)])
-
-
 def _ids(seed=0):
-    ids = np.random.default_rng(seed).integers(0, 256, size=(2, SEQ + 1))
-    return jnp.asarray(ids[:, :-1], jnp.int32), jnp.asarray(
-        ids[:, 1:], jnp.int32)
+    return inputs_and_labels(2, SEQ, seed=seed)
 
 
-def _system(model, params, inputs, labels):
-    """((total loss, (token losses, what the model sowed)), gradients) as
-    ``Trainer``'s default loss computes them."""
-    def loss_fn(p):
-        logits, sown = model.apply(
-            {"params": p}, inputs, mutable=["losses", "stats"])
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        token = -jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
-        total = token.mean() + sum(
-            jnp.sum(t) for t in jax.tree.leaves(sown["losses"]))
-        return total, (token, sown["stats"])
-
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss_fn, has_aux=True)(params)
+#: what the fixture computed, once a parameter: the system's
+#: ``((total, (token losses, sown)), gradients)`` and the reference's
+#: dictionary and gradients
+Made = collections.namedtuple(
+    "Made", "cfg model params inputs labels got want want_grads")
 
 
 @pytest.fixture(scope="module", params=[0, 2], ids=["every_expert", "a_share"])
@@ -82,20 +73,22 @@ def made(request):
     cfg = _config(experts_held=request.param, first_expert=request.param * 2)
     model = LlamaForCausalLM(cfg)
     inputs, labels = _ids()
-    params = _perturbed(nn.meta.unbox(
-        model.init(jax.random.PRNGKey(1), inputs)["params"]))
-    return cfg, model, params, inputs, labels
+    params = perturbed(init_params(model, inputs))
+    m = _published(cfg)
+    want, want_grads = reference_loss_and_gradients(
+        lambda p: reference.forward(p, inputs, labels, m), params)
+    return Made(cfg, model, params, inputs, labels,
+                system(model, params, inputs, labels), want, want_grads)
 
 
 class TestAgainstReference:
     def test_losses_and_every_counter(self, made):
-        cfg, model, params, inputs, labels = made
-        (total, (token, stats)), _ = _system(model, params, inputs, labels)
-        want = reference.forward(params, inputs, labels, _published(cfg))
+        (total, (token, sown)), _ = made.got
+        want = made.want
         np.testing.assert_allclose(token, want["token_losses"], rtol=0,
                                    atol=2e-5)
         np.testing.assert_allclose(total, want["loss"], rtol=1e-6)
-        kda = stats["layers"]["kda_1"]["layer"]["attn"]
+        kda = sown["stats"]["layers"]["kda_1"]["layer"]["attn"]
         np.testing.assert_allclose(
             kda["kda_beta_over_one_share"][0].ravel(),
             want["beta_over_one_share"], atol=1e-6)
@@ -107,14 +100,9 @@ class TestAgainstReference:
         assert float(want["beta_over_one_share"].max()) < 0.8
 
     def test_gradients_of_every_parameter(self, made):
-        cfg, model, params, inputs, labels = made
-        _, got = _system(model, params, inputs, labels)
-        m = _published(cfg)
-        with jax.default_matmul_precision("highest"):
-            want = jax.grad(lambda p: reference.forward(
-                p, inputs, labels, m)["loss"])(params)
+        _, got = made.got
         flat = jax.tree_util.tree_leaves_with_path(got)
-        for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        for (path, g), w in zip(flat, jax.tree.leaves(made.want_grads)):
             name = "/".join(str(k.key) for k in path)
             assert float(jnp.abs(w).max()) > 0, name     # every leaf is used
             np.testing.assert_allclose(
@@ -127,9 +115,9 @@ class TestAgainstReference:
         {"router_scores": "softmax"}, {"kda_conv": 1}],
         ids=lambda c: next(iter(c)))
     def test_a_departure_is_far_outside_float32_agreement(self, made, changes):
-        cfg, model, params, inputs, labels = made
-        want = reference.forward(
-            params, inputs, labels, _published(cfg))["token_losses"]
+        cfg, params, inputs, labels = (
+            made.cfg, made.params, made.inputs, made.labels)
+        want = made.want["token_losses"]
         other = LlamaForCausalLM(dataclasses.replace(cfg, **changes))
         shapes = jax.eval_shape(other.init, jax.random.PRNGKey(1), inputs)
         # the other model's tree from this one's leaves where they exist
@@ -140,20 +128,20 @@ class TestAgainstReference:
                 tuple(slice(0, n) for n in s.shape)]
             if jax.tree_util.keystr(p) in have else jnp.ones(s.shape, s.dtype),
             nn.meta.unbox(shapes["params"]))
-        (_, (token, _)), _ = _system(other, theirs, inputs, labels)
+        _, (token, _) = system_loss(other, theirs, inputs, labels)
         assert float(jnp.abs(token - want).max()) > 1e-2
 
 
 def _delta(cfg, params, x):
-    with jax.default_matmul_precision("highest"):
-        return DeltaAttention(cfg).apply({"params": params}, x, None, None)
+    return jitted(lambda p, x: DeltaAttention(cfg).apply(
+        {"params": p}, x, None, None), params, x)
 
 
 def _softmax(cfg, params, x):
     positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
     mask = jnp.tril(jnp.ones((x.shape[1],) * 2, bool))[None, None]
-    with jax.default_matmul_precision("highest"):
-        return Attention(cfg).apply({"params": params}, x, positions, mask)
+    return jitted(lambda p, x: Attention(cfg).apply(
+        {"params": p}, x, positions, mask), params, x)
 
 
 #: which axis of a leaf counts heads, by the leaf's name
@@ -187,8 +175,8 @@ class TestTheSharesAddUp:
 
     def test_head_shares_of_a_delta_rule_layer(self, x):
         cfg = _config(kda_heads=8)
-        full = _perturbed(nn.meta.unbox(DeltaAttention(cfg).init(
-            jax.random.PRNGKey(4), x, None, None)["params"]))
+        full = perturbed(init_params(
+            DeltaAttention(cfg), x, None, None, seed=4))
         whole = _delta(cfg, full, x)
         share_cfg = dataclasses.replace(cfg, kda_heads=2)
         parts = [_delta(share_cfg, _head_share(full, first, 2), x)
@@ -198,24 +186,23 @@ class TestTheSharesAddUp:
             assert 0.05 < float(jnp.abs(part).mean() / jnp.abs(whole).mean())
         # and against the reference given the same share
         m = _published(cfg)
-        with jax.default_matmul_precision("highest"):
-            want = reference.delta_attention(x, _head_share(full, 4, 2), m)[0]
+        want = jitted(lambda p: reference.delta_attention(x, p, m)[0],
+                      _head_share(full, 4, 2))
         np.testing.assert_allclose(parts[2], want, rtol=0, atol=2e-5)
 
     def test_head_shares_of_a_softmax_layer(self, x):
         """Eight query heads on two key heads: a share is four query heads
         and the one key head that serves them, so the share is whole."""
         cfg = _config(num_heads=8, num_kv_heads=2)
-        full = _perturbed(nn.meta.unbox(Attention(cfg).init(
-            jax.random.PRNGKey(5), x, None, None)["params"]))
+        full = perturbed(init_params(Attention(cfg), x, None, None, seed=5))
         whole = _softmax(cfg, full, x)
         share_cfg = dataclasses.replace(cfg, num_heads=4, num_kv_heads=1)
         parts = [_softmax(share_cfg, _head_share(
             full, 4 * i, 4, kv=(i, 1)), x) for i in (0, 1)]
         np.testing.assert_allclose(sum(parts), whole, rtol=0, atol=2e-5)
-        with jax.default_matmul_precision("highest"):
-            want = reference.gated_attention(
-                x, _head_share(full, 4, 4, kv=(1, 1)), _published(cfg))
+        want = jitted(
+            lambda p: reference.gated_attention(x, p, _published(cfg)),
+            _head_share(full, 4, 4, kv=(1, 1)))
         np.testing.assert_allclose(parts[1], want, rtol=0, atol=2e-5)
 
     def test_expert_shares_with_the_shared_expert_counted_once(self, x):
@@ -223,23 +210,21 @@ class TestTheSharesAddUp:
         shared expert alike, so the sum of the shares holds it four times;
         counted once, the shares sum to the uncut reference's layer."""
         cfg = _config(num_layers=1, layer_pattern=())
-        full = _perturbed(nn.meta.unbox(
-            MoEMLP(cfg).init(jax.random.PRNGKey(6), x)["params"]))
+        full = perturbed(init_params(MoEMLP(cfg), x, seed=6))
         m = _published(cfg)
-        with jax.default_matmul_precision("highest"):
-            want, balance, _ = reference.experts(x, full, m, whole=True)
-            shared = reference.swiglu(x, full["shared_expert"])
+        want, balance, _ = jitted(
+            lambda p: reference.experts(x, p, m, whole=True), full)
+        shared = jitted(reference.swiglu, x, full["shared_expert"])
         parts = []
         for first in (0, 2, 4, 6):
             share = dataclasses.replace(cfg, experts_held=2,
                                         first_expert=first)
             held = {**full, **{name: full[name][first: first + 2] for name in
                                ("gate_proj", "up_proj", "down_proj")}}
-            with jax.default_matmul_precision("highest"):
-                out, sown = MoEMLP(share).apply(
-                    {"params": held}, x, mutable=["losses", "stats"])
-                alone = reference.experts(
-                    x, held, {**m, "first_expert": first})[0]
+            out, sown = jitted(lambda p: MoEMLP(share).apply(
+                {"params": p}, x, mutable=["losses", "stats"]), held)
+            alone = jitted(lambda p: reference.experts(
+                x, p, {**m, "first_expert": first})[0], held)
             np.testing.assert_allclose(out, alone, rtol=0, atol=2e-5)
             # every share computes the same loss: the routing's, over all
             np.testing.assert_allclose(

@@ -5,6 +5,7 @@ renormalised router weights and one chip's share of an expert layer
 (``models/keye_reference.py``) at small sizes on the CPU in float32, and
 through ``Trainer``."""
 
+import collections
 import dataclasses
 
 import flax.linen as nn
@@ -20,6 +21,15 @@ from dlrover_tpu.models.moe import MoELlamaConfig, MoEMLP
 from dlrover_tpu.ops import attention as ops
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.train import Trainer
+from against_reference import (
+    init_params,
+    inputs_and_labels,
+    jitted,
+    perturbed,
+    reference_loss_and_gradients,
+    system,
+    system_loss,
+)
 
 SEQ, TOPK, BLOCK = 32, 8, 8
 
@@ -45,44 +55,24 @@ def _published(cfg):
 
 
 def _batch(cfg, rows=2, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        0, cfg.vocab_size, size=(rows, SEQ + 1))
-    return {"input_ids": jnp.asarray(ids[:, :-1], jnp.int32),
-            "labels": jnp.asarray(ids[:, 1:], jnp.int32)}
-
-
-def _perturbed(params, seed=2):
-    """Untrained scales are 1, the LayerNorm's bias 0 and the router near
-    uniform: move every leaf, or a reference that forgot one would pass."""
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return jax.tree.unflatten(tree, [
-        leaf + 0.05 * jax.random.normal(k, leaf.shape, leaf.dtype)
-        for leaf, k in zip(leaves, keys)])
+    inputs, labels = inputs_and_labels(rows, SEQ, cfg.vocab_size, seed)
+    return {"input_ids": inputs, "labels": labels}
 
 
 def _params(model, batch):
-    return _perturbed(nn.meta.unbox(
-        model.init(jax.random.PRNGKey(1), batch["input_ids"]))["params"])
+    return perturbed(init_params(model, batch["input_ids"]), scale=0.05)
 
 
-def _system(model, params, batch, with_index_loss=True, with_lm_loss=True):
-    """((total, (token losses, sown losses, sown stats)), gradients) as
-    ``Trainer``'s default loss computes them."""
-    def loss_fn(p):
-        logits, sown = model.apply(
-            {"params": p}, batch["input_ids"], mutable=["losses", "stats"])
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        token = -jnp.take_along_axis(
-            logp, batch["labels"][..., None], -1)[..., 0]
-        terms = sown["losses"]["layers"]["layer"]
-        total = with_lm_loss * (
-            token.mean() + jnp.sum(terms["mlp"]["load_balance"][0]))
-        total = total + with_index_loss * jnp.sum(terms["attn"]["index"][0])
-        return total, (token, terms, sown["stats"]["layers"]["layer"])
+def _reference(cfg, params, batch, **kw):
+    m = _published(cfg)
+    return lambda p: reference.forward(
+        p, batch["input_ids"], batch["labels"], m, **kw)
 
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss_fn, has_aux=True)(params)
+
+#: what the fixture computed once: the system's ``((total, (token losses,
+#: sown)), gradients)``, the reference's dictionary and gradients
+Made = collections.namedtuple(
+    "Made", "cfg model batch params got want want_grads")
 
 
 @pytest.fixture(scope="module")
@@ -90,7 +80,10 @@ def made():
     cfg = _config()
     model = LlamaForCausalLM(cfg)
     batch = _batch(cfg)
-    return cfg, model, batch, _params(model, batch)
+    params = _params(model, batch)
+    got = system(model, params, batch["input_ids"], batch["labels"])
+    return Made(cfg, model, batch, params, got, *reference_loss_and_gradients(
+        _reference(cfg, params, batch), params))
 
 
 INDEXER = ("index_q_proj", "index_k_proj", "index_k_norm", "index_w_proj")
@@ -98,10 +91,10 @@ INDEXER = ("index_q_proj", "index_k_proj", "index_k_norm", "index_w_proj")
 
 class TestAgainstReference:
     def test_losses_and_every_sown_term(self, made):
-        cfg, model, batch, params = made
-        (total, (token, terms, stats)), _ = _system(model, params, batch)
-        want = reference.forward(params, batch["input_ids"], batch["labels"],
-                                 _published(cfg))
+        cfg, want = made.cfg, made.want
+        (total, (token, sown)), _ = made.got
+        terms = sown["losses"]["layers"]["layer"]
+        stats = sown["stats"]["layers"]["layer"]
         np.testing.assert_allclose(token, want["token_losses"], atol=2e-5)
         np.testing.assert_allclose(total, want["loss"], atol=2e-5)
         layers = cfg.num_layers
@@ -119,14 +112,9 @@ class TestAgainstReference:
         assert float(want["index_loss"].min()) > 1e-3
 
     def test_gradients_of_every_parameter(self, made):
-        cfg, model, batch, params = made
-        _, got = _system(model, params, batch)
-        with jax.default_matmul_precision("highest"):
-            want = jax.grad(lambda p: reference.forward(
-                p, batch["input_ids"], batch["labels"], _published(cfg)
-            )["loss"])(params)
+        _, got = made.got
         worst = jax.tree.map(
-            lambda a, b: float(jnp.abs(a - b).max()), got, want)
+            lambda a, b: float(jnp.abs(a - b).max()), got, made.want_grads)
         assert max(jax.tree.leaves(worst)) < 5e-5, worst
         attn = got["layers"]["layer"]["attn"]
         for name in INDEXER:        # and none of them is a gradient of zero
@@ -136,9 +124,11 @@ class TestAgainstReference:
     def test_which_loss_reaches_which_parameter(self, made):
         """The indexer's parameters get no gradient from the language
         model's loss, and ``L_I`` gives one to them and to nothing else."""
-        cfg, model, batch, params = made
-        _, lm = _system(model, params, batch, with_index_loss=False)
-        _, index = _system(model, params, batch, with_lm_loss=False)
+        model, batch, params = made.model, made.batch, made.params
+        _, lm = system(model, params, batch["input_ids"], batch["labels"],
+                       terms=lambda path: "index" not in path)
+        _, index = system(model, params, batch["input_ids"],
+                          terms=lambda path: "index" in path)
 
         def largest(tree):
             return max(float(jnp.abs(g).max()) for g in jax.tree.leaves(tree))
@@ -159,13 +149,10 @@ class TestAgainstReference:
     def test_the_nearest_keys_in_place_of_the_highest_are_far_off(self, made):
         """The fault the chip's control plants: visible at this size at a
         hundred times the float32 agreement above."""
-        cfg, model, batch, params = made
-        want = reference.forward(params, batch["input_ids"], batch["labels"],
-                                 _published(cfg))
-        wrong = reference.forward(params, batch["input_ids"], batch["labels"],
-                                  _published(cfg), nearest=True)
+        wrong = jitted(_reference(
+            made.cfg, made.params, made.batch, nearest=True), made.params)
         assert float(jnp.abs(
-            want["token_losses"] - wrong["token_losses"]).max()) > 2e-3
+            made.want["token_losses"] - wrong["token_losses"]).max()) > 2e-3
 
 
 class TestSelection:
@@ -225,12 +212,13 @@ class TestSelection:
     def test_blocks_see_the_same_keys_as_the_whole_sequence(self, made):
         """The result does not depend on the block of queries worked at a
         time (``q_chunk_size`` is a tile size, not mathematics)."""
-        cfg, model, batch, params = made
+        cfg, batch, params = made.cfg, made.batch, made.params
         token = []
         for block in (4, 16, 32):
             other = LlamaForCausalLM(
                 dataclasses.replace(cfg, index_block=block))
-            token.append(_system(other, params, batch)[0][1][0])
+            token.append(system_loss(
+                other, params, batch["input_ids"], batch["labels"])[1][0])
         np.testing.assert_allclose(token[0], token[1], atol=2e-6)
         np.testing.assert_allclose(token[0], token[2], atol=2e-6)
 
@@ -246,15 +234,14 @@ class TestAgainstTheDenseLayer:
         x = jax.random.normal(jax.random.PRNGKey(0), (2, SEQ, 64))
         positions = jnp.broadcast_to(jnp.arange(SEQ), (2, SEQ))
         mask = jnp.tril(jnp.ones((SEQ, SEQ), bool))[None, None]
-        params = _perturbed(Attention(sparse).init(
-            jax.random.PRNGKey(1), x, positions, None)["params"])
-        shared = {k: v for k, v in nn.meta.unbox(params).items()
-                  if k not in INDEXER}
-        with jax.default_matmul_precision("highest"):
-            got, sown = Attention(sparse).apply(
-                {"params": params}, x, positions, None,
-                mutable=["losses", "stats"])
-            want = Attention(dense).apply({"params": shared}, x, positions, mask)
+        params = perturbed(init_params(
+            Attention(sparse), x, positions, None), scale=0.05)
+        shared = {k: v for k, v in params.items() if k not in INDEXER}
+        got, sown = jitted(lambda p: Attention(sparse).apply(
+            {"params": p}, x, positions, None, mutable=["losses", "stats"]),
+            params)
+        want = jitted(lambda p: Attention(dense).apply(
+            {"params": p}, x, positions, mask), shared)
         np.testing.assert_allclose(got, want, atol=2e-6)
         assert float(sown["stats"]["index_low_margin_share"][0]) == 0.0
 
@@ -263,8 +250,8 @@ class TestAgainstTheDenseLayer:
         x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 64))
         positions = jnp.broadcast_to(jnp.arange(16), (2, 16))
         mask = jnp.tril(jnp.ones((16, 16), bool))[None, None]
-        params = _perturbed(nn.meta.unbox(Attention(cfg).init(
-            jax.random.PRNGKey(1), x, positions, mask)["params"]))
+        params = perturbed(init_params(
+            Attention(cfg), x, positions, mask), scale=0.05)
         assert params["q_norm"]["scale"].shape == (cfg.head_dim,)
         assert params["k_norm"]["scale"].shape == (cfg.head_dim,)
 
@@ -273,8 +260,9 @@ class TestAgainstTheDenseLayer:
             rms = np.sqrt((t ** 2).mean(-1, keepdims=True) + cfg.rms_norm_eps)
             return t / rms * np.asarray(scale, np.float64)
 
+        got = jitted(lambda p: Attention(cfg).apply(
+            {"params": p}, x, positions, mask), params)
         with jax.default_matmul_precision("highest"):
-            got = Attention(cfg).apply({"params": params}, x, positions, mask)
             q = by_hand(jnp.einsum("bse,ehd->bshd", x,
                                    params["q_proj"]["kernel"]),
                         params["q_norm"]["scale"])
@@ -290,8 +278,8 @@ class TestAgainstTheDenseLayer:
         np.testing.assert_allclose(got, want, atol=1e-5)
         # OLMoE's norm over the whole width is another layer
         whole = dataclasses.replace(cfg, qk_norm=True)
-        assert Attention(whole).init(
-            jax.random.PRNGKey(1), x, positions, mask
+        assert jax.eval_shape(
+            Attention(whole).init, jax.random.PRNGKey(1), x, positions, mask
         )["params"]["q_norm"]["scale"].value.shape == (
             cfg.num_heads * cfg.head_dim,)
 
@@ -316,17 +304,15 @@ class TestAShareOfTheExpertLayer:
             "router": full["router"],
             **{name: full[name][first: first + held]
                for name in ("gate_proj", "up_proj", "down_proj")}}
-        with jax.default_matmul_precision("highest"):
-            out, sown = MoEMLP(cfg).apply(
-                {"params": params}, x, mutable=["losses", "stats"])
+        out, sown = jitted(lambda p: MoEMLP(cfg).apply(
+            {"params": p}, x, mutable=["losses", "stats"]), params)
         return cfg, out, sown
 
     @pytest.fixture(scope="class")
     def whole(self):
         cfg = _config(experts_held=0, num_layers=1)
         x = jax.random.normal(jax.random.PRNGKey(3), (2, SEQ, 64))
-        full = _perturbed(nn.meta.unbox(
-            MoEMLP(cfg).init(jax.random.PRNGKey(4), x)["params"]))
+        full = perturbed(init_params(MoEMLP(cfg), x, seed=4), scale=0.05)
         return cfg, x, full
 
     def test_the_shares_add_up_to_the_uncut_layer(self, whole):
@@ -334,8 +320,8 @@ class TestAShareOfTheExpertLayer:
         6) sum to what the uncut reference gives for the whole layer."""
         cfg, x, full = whole
         m = _published(cfg)
-        with jax.default_matmul_precision("highest"):
-            want, balance, _ = reference.experts(x, full, m, whole=True)
+        want, balance, _ = jitted(
+            lambda p: reference.experts(x, p, m, whole=True), full)
         parts = [self._layer(2, first, x, full) for first in (0, 2, 4, 6)]
         np.testing.assert_allclose(
             sum(out for _, out, _ in parts), want, atol=1e-5)
@@ -354,8 +340,8 @@ class TestAShareOfTheExpertLayer:
         held = {"router": full["router"], **{
             name: full[name][4:6]
             for name in ("gate_proj", "up_proj", "down_proj")}}
-        with jax.default_matmul_precision("highest"):
-            want, _, _ = reference.experts(x, held, _published(share_cfg))
+        want, _, _ = jitted(lambda p: reference.experts(
+            x, p, _published(share_cfg)), held)
         np.testing.assert_allclose(out, want, atol=1e-5)
 
     def test_what_a_share_sows(self, whole):
