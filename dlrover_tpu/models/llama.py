@@ -88,10 +88,18 @@ class LlamaConfig:
     # logits and sows the others' loss (EvaByte's ``num_pred_heads``)
     pred_heads: int = 1
     # the kinds of layer of one period of the stack, repeated to
-    # ``num_layers``: ``"gqa"`` (this file's softmax attention) or ``"kda"``
-    # (a gated delta-rule layer, ``DeltaAttention``).  Empty: one kind, the
-    # softmax attention, and the parameter tree ``layers/layer`` as ever
+    # ``num_layers``: ``"gqa"`` (this file's softmax attention), ``"kda"``
+    # (a gated delta-rule layer, ``DeltaAttention``) or ``"mla"`` (latent
+    # attention, ``LatentAttention``).  Empty: one kind, the softmax
+    # attention, and the parameter tree ``layers/layer`` as ever.  An entry
+    # ``"<kind>:dense"`` is a layer of that kind whose feed-forward is the
+    # dense SwiGLU of ``dense_intermediate_size`` whatever
+    # ``feed_forward()`` names (a routed model's leading dense layers)
     layer_pattern: Tuple[str, ...] = ()
+    # layers that stand ONCE before the periods (entries as the pattern's),
+    # counted in ``num_layers``; their parameters under ``prefix/<run>``
+    layer_prefix: Tuple[str, ...] = ()
+    dense_intermediate_size: int = 0
     # softmax attention without positions (``use_rope`` false) and with an
     # elementwise sigmoid gate on its output before the output projection
     # (arXiv:2505.06708; Solar-Open2's ``use_gqa_gate``)
@@ -106,6 +114,32 @@ class LlamaConfig:
     kda_head_dim: int = 128
     kda_conv: int = 4
     kda_chunk: int = 64
+    # the decay's and the output gate's projections at full rank, hidden ->
+    # heads x ``kda_head_dim`` (``f_proj``, ``g_proj``; Ling-3.0's
+    # ``no_kda_lora``) in place of the two low-rank pairs
+    kda_full_rank_gates: bool = False
+    # the log decay bounded below: ``g = bound * sigmoid(exp(A_log) (f +
+    # dt_bias))`` in ``(bound, 0)`` (fla's ``safe_gate`` with
+    # ``lower_bound``; -5 in Ling-3.0) in place of ``-exp(A_log)
+    # softplus(..)``.  0: unbounded
+    kda_decay_lower_bound: float = 0.0
+    # ``beta = 2 sigmoid(..)`` (negative eigenvalues allowed) or, false,
+    # ``sigmoid(..)``
+    kda_neg_eigval: bool = True
+    # an ``mla`` layer (DeepSeek-V2's latent attention, arXiv:2405.04434,
+    # without a query bottleneck): ``num_heads`` query heads of
+    # ``mla_nope_dim + mla_rope_dim``; keys and values from one latent of
+    # ``mla_kv_rank`` a token (RMS-normalised) through an up-projection to
+    # ``mla_nope_dim + mla_v_dim`` a head; ONE rotary key of
+    # ``mla_rope_dim`` a token that every head shares; RoPE (``rope_theta``)
+    # on it and on each head's last ``mla_rope_dim`` query columns; scores
+    # over ``sqrt(mla_nope_dim + mla_rope_dim)``; ``mla_head_gate``: the
+    # output times ``sigmoid(h w_gate)`` a HEAD before the output projection
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    mla_head_gate: bool = False
     # training by diffusion over blocks (BD3-LMs, arXiv:2503.09573; SDAR,
     # arXiv:2510.06303): the length of a block, 0 for none.  The model then
     # runs ``[noisy copy ; clean copy]`` of its input, ``2S`` rows at the
@@ -139,14 +173,26 @@ class LlamaConfig:
             raise ValueError(
                 "eva_window needs an eva_chunk that divides it, a key head "
                 "a query head, and no indexer")
-        if self.layer_pattern and (
-                set(self.layer_pattern) - set(LAYER_KINDS)
-                or self.num_layers % len(self.layer_pattern)
-                or ("kda" in self.layer_pattern and not self.kda_heads)):
+        entries = self.layer_prefix + self.layer_pattern
+        kinds = {layer_kind(entry)[0] for entry in entries}
+        if entries and (
+                not self.layer_pattern
+                or kinds - set(LAYER_KINDS)
+                or any(entry.partition(":")[2] not in ("", "dense")
+                       for entry in entries)
+                or (self.num_layers - len(self.layer_prefix))
+                % len(self.layer_pattern)
+                or ("kda" in kinds and not self.kda_heads)
+                or ("mla" in kinds and not self.mla_kv_rank)
+                or (any(layer_kind(entry)[1] for entry in entries)
+                    and not self.dense_intermediate_size)):
             raise ValueError(
-                f"layer_pattern={self.layer_pattern!r}: kinds of "
-                f"{LAYER_KINDS}, a whole number of periods in num_layers="
-                f"{self.num_layers}, and kda_heads where it has a kda layer")
+                f"layer_prefix={self.layer_prefix!r} layer_pattern="
+                f"{self.layer_pattern!r}: entries '<kind>' or '<kind>:dense',"
+                f" kinds of {LAYER_KINDS}, a whole number of periods in "
+                f"num_layers={self.num_layers} less the prefix, kda_heads "
+                "where there is a kda layer, mla_kv_rank where there is an "
+                "mla layer and dense_intermediate_size where one is dense")
         if self.block_diffusion and (
                 self.index_topk or self.eva_window or self.layer_pattern
                 or self.pred_heads > 1
@@ -172,16 +218,24 @@ class LlamaConfig:
         return {"noise": jax.random.fold_in(
             jax.random.PRNGKey(self.noise_seed), step)}
 
-    def layer_runs(self):
-        """One period as runs of equal layers, ``[(name, kind, length)]``:
-        a run is one scan over its stacked parameters, under ``name``."""
+    def layer_runs(self, entries=None):
+        """One period (or ``entries``: the prefix) as runs of equal layers,
+        ``[(name, entry, length)]``: a run is one scan over its stacked
+        parameters, under ``name`` (``<kind>_<run>``, ``<kind>_dense_<run>``
+        for a dense entry)."""
         runs = []
-        for kind in self.layer_pattern:
-            if runs and runs[-1][1] == kind:
+        for entry in self.layer_pattern if entries is None else entries:
+            if runs and runs[-1][1] == entry:
                 runs[-1][2] += 1
             else:
-                runs.append([f"{kind}_{len(runs)}", kind, 1])
+                runs.append(
+                    [f"{entry.replace(':', '_')}_{len(runs)}", entry, 1])
         return [tuple(run) for run in runs]
+
+    @property
+    def periods(self) -> int:
+        return (self.num_layers - len(self.layer_prefix)) // len(
+            self.layer_pattern)
 
     def feed_forward(self):
         """The module class of the block after attention, built as
@@ -216,7 +270,14 @@ class LlamaConfig:
 
 
 #: the kinds a ``layer_pattern`` may name
-LAYER_KINDS = ("gqa", "kda")
+LAYER_KINDS = ("gqa", "kda", "mla")
+
+
+def layer_kind(entry: str):
+    """``(kind, dense)`` of a pattern's entry ``"<kind>"`` or
+    ``"<kind>:dense"``."""
+    kind, _, ffn = entry.partition(":")
+    return kind, ffn == "dense"
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -496,7 +557,11 @@ class DeltaAttention(nn.Module):
     ``g = -exp(A_log) softplus((h W_f1) W_f2 + dt_bias)`` a channel and
     ``beta = 2 sigmoid(h w_beta)`` (the 2: negative eigenvalues allowed);
     the state's read-out is RMS-normalised a head and gated by
-    ``sigmoid((h W_g1) W_g2)`` before the output projection.  The
+    ``sigmoid((h W_g1) W_g2)`` before the output projection.  By the
+    configuration (Ling-3.0's): ``kda_full_rank_gates``, one full-rank
+    projection each for the decay and the gate; ``kda_decay_lower_bound``,
+    the decay ``bound * sigmoid(exp(A_log) (f + dt_bias))``;
+    ``kda_neg_eigval`` false, beta without the 2.  The
     sub-scopes under ``attn.core`` are the kind table's
     (``observability/trace.py``): ``conv``, ``decay``, ``chunk``, ``state``
     (both inside ``ops/linear_attention.py::kda``), ``gate``."""
@@ -559,7 +624,17 @@ class DeltaAttention(nn.Module):
             "v_proj")(x)
         # the decay and beta steer exponentials: their last projections
         # give float32 (operands still multiplied in the compute dtype)
-        decay_in = low_rank("f", jnp.float32)
+        def gate_input(name, dtype=cfg.dtype):
+            if not cfg.kda_full_rank_gates:
+                return low_rank(name, dtype)
+            return dense(
+                features=(H, D), dtype=dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(),
+                    ("embed", "heads", "head_dim")),
+                name=name + "_proj")(x)
+
+        decay_in = gate_input("f", jnp.float32)
         beta_in = dense(
             features=H, dtype=jnp.float32,
             kernel_init=nn.with_logical_partitioning(
@@ -573,7 +648,7 @@ class DeltaAttention(nn.Module):
             "dt_bias", nn.with_logical_partitioning(
                 _kda_dt_bias_init(), ("heads", "head_dim")),
             (H, D), cfg.param_dtype)
-        gate_in = low_rank("g")
+        gate_in = gate_input("g")
         with jax.named_scope("attn.core"):
             with jax.named_scope("conv"):
                 q, k, v = (short_conv(name, t) for name, t in (
@@ -582,9 +657,17 @@ class DeltaAttention(nn.Module):
                 q = (unit(q) * D ** -0.5).astype(cfg.dtype)
                 k = unit(k).astype(cfg.dtype)
                 v = v.astype(cfg.dtype)
-                g = -jnp.exp(rate.astype(jnp.float32))[:, None] * (
-                    jax.nn.softplus(decay_in + dt_bias.astype(jnp.float32)))
-                beta = 2.0 * nn.sigmoid(beta_in)
+                if cfg.kda_decay_lower_bound:
+                    g = cfg.kda_decay_lower_bound * nn.sigmoid(
+                        jnp.exp(rate.astype(jnp.float32))[:, None]
+                        * (decay_in + dt_bias.astype(jnp.float32)))
+                else:
+                    g = -jnp.exp(rate.astype(jnp.float32))[:, None] * (
+                        jax.nn.softplus(
+                            decay_in + dt_bias.astype(jnp.float32)))
+                beta = nn.sigmoid(beta_in)
+                if cfg.kda_neg_eigval:
+                    beta = 2.0 * beta
                 self.sow("stats", "kda_beta_over_one_share",
                          jnp.mean(beta > 1.0))
                 # how far the state remembers: the median channel's half
@@ -616,11 +699,86 @@ class DeltaAttention(nn.Module):
     @staticmethod
     def num_params(cfg) -> int:
         H, D = cfg.kda_heads, cfg.kda_head_dim
+        gates = (2 * cfg.hidden_size * H * D if cfg.kda_full_rank_gates
+                 else 2 * (cfg.hidden_size * D + D * H * D))
         return (4 * cfg.hidden_size * H * D          # q, k, v, o
-                + 2 * (cfg.hidden_size * D + D * H * D)   # f and g, low rank
+                + gates                              # f and g
                 + cfg.hidden_size * H                # beta
                 + 3 * cfg.kda_conv * H * D           # the three convolutions
                 + H + H * D + D)                     # A_log, dt_bias, o_norm
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2's MLA, arXiv:2405.04434,
+    without a query bottleneck; Ling-3.0's softmax layers): ``q = h W_q``,
+    a head ``[q_nope | q_pe]``; ``[c | k_pe] = h W_kva``, the latent ``c``
+    RMS-normalised; ``[k_nope | v] = c W_kvb`` a head; RoPE on ``q_pe`` a
+    head and on the ONE ``k_pe``, which every head shares; ``o = softmax_
+    causal((q_nope k_nope^T + q_pe k_pe^T) / sqrt(nope + rope)) v``
+    (``ops/attention.py::latent_attention``: scores in two products, never
+    a key of ``nope + rope`` a head in HBM); with ``mla_head_gate`` ``o_h *
+    sigmoid(h w_gate)_h``, one gate a head; the output projection.  Scope
+    ``attn`` / ``latent`` holds the latent's projections, norm and the
+    rotary part (kind ``attn.proj``), ``attn.core`` / ``latent`` the
+    core."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask):
+        from dlrover_tpu.ops.attention import latent_attention
+
+        cfg = self.config
+        H, nope, rope, wide = (cfg.num_heads, cfg.mla_nope_dim,
+                               cfg.mla_rope_dim, cfg.mla_v_dim)
+        dense = partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype)
+
+        def init(*axes):
+            return nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), axes)
+
+        q = dense(features=(H, nope + rope), name="q_proj",
+                  kernel_init=init("embed", "heads", "head_dim"))(x)
+        with jax.named_scope("latent"):
+            # on every chip of a layer: the down-projection and its norm
+            down = dense(features=cfg.mla_kv_rank + rope, name="kv_a_proj",
+                         kernel_init=init("embed", None))(x)
+            latent = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                             None, name="kv_a_norm")(
+                                 down[..., :cfg.mla_kv_rank])
+            up = dense(features=(H, nope + wide), name="kv_b_proj",
+                       kernel_init=init(None, "heads", "head_dim"))(latent)
+            k_pe = _rope(down[..., None, cfg.mla_kv_rank:], positions,
+                         cfg.rope_theta)[:, :, 0]
+            q_pe = _rope(q[..., nope:], positions, cfg.rope_theta)
+        q_nope = nn.with_logical_constraint(
+            q[..., :nope], ("batch", "seq", "heads", "head_dim"))
+        with jax.named_scope("attn.core"):
+            out = latent_attention(
+                q_nope, q_pe, up[..., :nope], k_pe, up[..., nope:])
+        if cfg.mla_head_gate:
+            gate = dense(features=H, name="gate_proj",
+                         kernel_init=init("embed", "heads"))(x)
+            out = out * nn.sigmoid(gate)[..., None]
+        out = nn.with_logical_constraint(
+            out, ("batch", "seq", "heads", "head_dim"))
+        return nn.DenseGeneral(
+            features=x.shape[-1], axis=(-2, -1), use_bias=False,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=init("heads", "head_dim", "embed"),
+            name="o_proj")(out)
+
+    @staticmethod
+    def num_params(cfg) -> int:
+        H, nope, rope, wide = (cfg.num_heads, cfg.mla_nope_dim,
+                               cfg.mla_rope_dim, cfg.mla_v_dim)
+        return (cfg.hidden_size * H * (nope + rope)            # q
+                + cfg.hidden_size * (cfg.mla_kv_rank + rope)   # down
+                + cfg.mla_kv_rank                              # its norm
+                + cfg.mla_kv_rank * H * (nope + wide)          # up
+                + (cfg.hidden_size * H if cfg.mla_head_gate else 0)
+                + H * wide * cfg.hidden_size)                  # o
 
 
 class MLP(nn.Module):
@@ -660,15 +818,26 @@ class MLP(nn.Module):
         )(h)
 
 
+#: the module at ``attn`` by the layer's kind
+ATTENTION_OF = {"gqa": Attention, "kda": DeltaAttention,
+                "mla": LatentAttention}
+
+
 class DecoderLayer(nn.Module):
     config: LlamaConfig
-    #: of ``LAYER_KINDS``: which module stands at ``attn``
+    #: a pattern's entry: of ``LAYER_KINDS``, which module stands at
+    #: ``attn``; with ``:dense``, the dense SwiGLU at ``mlp``
     kind: str = "gqa"
 
     @nn.compact
     def __call__(self, x, positions, mask):
         cfg = self.config
-        attention = DeltaAttention if self.kind == "kda" else Attention
+        kind, dense = layer_kind(self.kind)
+        attention = ATTENTION_OF[kind]
+        feed_forward, ffn_cfg = cfg.feed_forward(), cfg
+        if dense:
+            feed_forward, ffn_cfg = MLP, dataclasses.replace(
+                cfg, intermediate_size=cfg.dense_intermediate_size)
         norm = partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                        unit_offset=cfg.norm_unit_offset)
         # ``x`` is the residual stream, in ``residual_dtype`` where the
@@ -677,7 +846,7 @@ class DecoderLayer(nn.Module):
         x = x + attention(cfg, name="attn")(h, positions, mask).astype(x.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         h = norm(name="post_attn_norm")(x)
-        x = x + cfg.feed_forward()(cfg, name="mlp")(h).astype(x.dtype)
+        x = x + feed_forward(ffn_cfg, name="mlp")(h).astype(x.dtype)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
@@ -702,7 +871,8 @@ def _stacked(layer_cls, length, axis="layers"):
         layer_cls,
         # what a layer sows (a routed block's loss terms and counts) stacks
         # on the layer axis beside its parameters
-        variable_axes={"params": 0, "losses": 0, "stats": 0},
+        # and its buffers (a router's selection bias: ``models/moe.py``)
+        variable_axes={"params": 0, "losses": 0, "stats": 0, "buffers": 0},
         split_rngs={"params": True},
         in_axes=nn.broadcast,  # positions/mask shared by all layers
         length=length,
@@ -733,11 +903,13 @@ class _ScannedPeriod(nn.Module):
     traced once."""
 
     config: LlamaConfig
+    #: ``None``: the pattern's period; else the entries of the prefix
+    entries: Optional[Tuple[str, ...]] = None
 
     @nn.compact
     def __call__(self, x, positions, mask):
         cfg = self.config
-        for name, kind, length in cfg.layer_runs():
+        for name, kind, length in cfg.layer_runs(self.entries):
             x, _ = _stacked(_layer_class(cfg, True), length)(
                 cfg, kind, name=name)(x, positions, mask)
         return x, None
@@ -824,15 +996,19 @@ class LlamaForCausalLM(nn.Module):
         mask = None if (
             cfg.index_topk or cfg.eva_window or cfg.attention_impl == "flash"
             or cfg.block_diffusion
-            or (cfg.layer_pattern and "gqa" not in cfg.layer_pattern)
+            or (cfg.layer_pattern and "gqa" not in {
+                layer_kind(entry)[0]
+                for entry in cfg.layer_prefix + cfg.layer_pattern})
         ) else jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
 
         if cfg.layer_pattern:
+            if cfg.layer_prefix:    # once, before the periods
+                x, _ = _ScannedPeriod(cfg, cfg.layer_prefix, name="prefix")(
+                    x, positions, mask)
             # a scan over periods, each run of equal layers a scan inside
             # (``scan_layers`` does not apply: a pattern is always stacked)
-            x, _ = _stacked(
-                _ScannedPeriod, cfg.num_layers // len(cfg.layer_pattern),
-                "periods")(cfg, name="layers")(x, positions, mask)
+            x, _ = _stacked(_ScannedPeriod, cfg.periods, "periods")(
+                cfg, name="layers")(x, positions, mask)
         elif cfg.scan_layers:
             x, _ = _stacked(_layer_class(cfg, True), cfg.num_layers)(
                 cfg, name="layers")(x, positions, mask)
@@ -916,12 +1092,22 @@ class LlamaForCausalLM(nn.Module):
             attn += 2 * cfg.num_heads * cfg.head_dim
         if cfg.attn_gate:       # the output gate's projection
             attn += cfg.hidden_size * cfg.num_heads * cfg.head_dim
-        by_kind = {"gqa": attn, "kda": DeltaAttention.num_params(cfg)}
-        kinds = cfg.layer_pattern or ("gqa",)
-        per_period = sum(by_kind[kind] for kind in kinds) + len(kinds) * (
-            cfg.feed_forward_params() + 2 * cfg.hidden_size)
+        by_kind = {"gqa": lambda: attn,
+                   "kda": lambda: DeltaAttention.num_params(cfg),
+                   "mla": lambda: LatentAttention.num_params(cfg)}
+
+        def layers(entries):
+            total = 0
+            for kind, dense in map(layer_kind, entries):
+                total += by_kind[kind]() + 2 * cfg.hidden_size + (
+                    3 * cfg.hidden_size * cfg.dense_intermediate_size
+                    if dense else cfg.feed_forward_params())
+            return total
+
+        pattern = cfg.layer_pattern or ("gqa",)
+        periods = (cfg.num_layers - len(cfg.layer_prefix)) // len(pattern)
         return (
             cfg.vocab_size * cfg.hidden_size * (1 + cfg.pred_heads)
-            + cfg.num_layers // len(kinds) * per_period
+            + layers(cfg.layer_prefix) + periods * layers(pattern)
             + cfg.hidden_size
         )

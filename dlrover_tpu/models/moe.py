@@ -10,7 +10,19 @@ experts, the ``top_k`` largest kept with their softmax weights as they are,
 or divided by their sum where the model says so (``norm_topk_prob``);
 ``router_scores="sigmoid"`` scores each expert by ``sigmoid(x W_r)``
 instead (DeepSeek-V3's), and ``shared_experts`` adds a dense SwiGLU every
-token visits (scope ``moe/shared``).  No
+token visits (scope ``moe/shared``).  With ``n_group`` the choice is made
+by groups (DeepSeek-V3's, arXiv:2412.19437): the experts in ``n_group``
+runs of consecutive columns, a group's score the sum of its two largest
+scores, the ``topk_group`` best groups kept and the ``top_k`` largest
+scores inside them chosen.  With ``selection_bias`` the CHOICE (groups and
+experts) is made on ``scores + b`` and the weights stay the scores' own:
+``b`` is a buffer a layer (collection ``buffers``, no parameter: no
+gradient, no optimizer moment, no weight decay), moved by the load after
+each step, ``b_e += bias_update_rate * sign(mean(n) - n_e)`` with ``n_e``
+the tokens the step routed to expert ``e`` over ALL columns (section 2.1.2
+there: balance without a loss term); the layer writes the moved bias back
+where the caller made ``buffers`` mutable (``Trainer``'s step does, scope
+``optimizer/bias``) and leaves it alone elsewhere.  No
 token is ever dropped and every shape is static:
 the ``tokens x top_k`` assignments are sorted by expert, this chip's
 first, one grouped matmul (``jax.lax.ragged_dot``, which the TPU compiler
@@ -125,9 +137,28 @@ class MoELlamaConfig(LlamaConfig):
     # share (``experts_held``) adds it once
     shared_experts: int = 0
     shared_intermediate_size: int = 0
+    # the choice by groups: ``n_group`` runs of consecutive experts, the
+    # ``topk_group`` with the largest sum of their two best scores kept,
+    # the ``top_k`` chosen inside them.  0: no groups
+    n_group: int = 0
+    topk_group: int = 0
+    # a bias a layer added to the scores for the CHOICE alone, a buffer the
+    # load moves by ``bias_update_rate`` a step (the module's text)
+    selection_bias: bool = False
+    bias_update_rate: float = 0.001
 
     def __post_init__(self):
         super().__post_init__()
+        if self.n_group and not (
+                self.num_experts % self.n_group == 0
+                and 0 < self.topk_group <= self.n_group
+                and self.num_experts // self.n_group >= 2
+                and self.top_k <= self.topk_group
+                * (self.num_experts // self.n_group)):
+            raise ValueError(
+                f"n_group={self.n_group} topk_group={self.topk_group}: "
+                f"groups of at least two that divide {self.num_experts} "
+                f"experts and hold top_k={self.top_k} in those kept")
         if self.router_scores not in ("softmax", "sigmoid"):
             raise ValueError(f"router_scores={self.router_scores!r} not in "
                              "('softmax', 'sigmoid')")
@@ -434,6 +465,71 @@ def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert,
     return out, sizes, held
 
 
+def _choose(module, scores):
+    """Of ``module``, a ``MoEMLP`` inside its ``__call__`` (functions and no
+    methods: a method would put its own name into every instruction's
+    path).  ``(weights, experts)`` [B, S, k] of each token's ``top_k``: the
+    largest ``scores``, or with a selection bias the largest ``scores +
+    b``, or with groups the largest inside the ``topk_group`` groups
+    whose two best add up highest.  The weights are the scores' own."""
+    cfg = module.config
+    k = cfg.top_k
+    if not (cfg.n_group or cfg.selection_bias):
+        return jax.lax.top_k(scores, k)
+    # the choice carries no gradient: the weights are gathered from
+    # ``scores`` at the chosen experts
+    choice = jax.lax.stop_gradient(scores)
+    if cfg.selection_bias:
+        bias = module.variable(
+            "buffers", "selection_bias", jnp.zeros, (cfg.num_experts,),
+            jnp.float32).value
+        choice = choice + jax.lax.stop_gradient(bias)
+        module.sow("stats", "bias_abs_max", jnp.abs(bias).max())
+    if not cfg.n_group:
+        top_i = jax.lax.top_k(choice, k)[1]
+        return jnp.take_along_axis(scores, top_i, axis=-1), top_i
+    # ONE ``top_k`` over the columns (a sort on the chip, 20 ms a layer
+    # and pass at 16384 x 512: PERF.md, PR 48); the groups by passes of
+    # ``max``: a group's two best as its maximum and the maximum of the
+    # rest, the groups kept as those fewer than ``topk_group`` others beat
+    # (a tie at the edge keeps both, as a threshold would)
+    grouped = choice.reshape(*choice.shape[:-1], cfg.n_group, -1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, grouped.shape,
+                                     grouped.ndim - 1)
+    first = jnp.argmax(grouped, axis=-1, keepdims=True)
+    group_score = grouped.max(axis=-1) + jnp.where(
+        lanes == first, -jnp.inf, grouped).max(axis=-1)
+    beaten = (group_score[..., None, :] > group_score[..., :, None]).sum(
+        axis=-1)
+    kept_group = (beaten < cfg.topk_group)[..., None]
+    top_c, top_i = jax.lax.top_k(
+        jnp.where(kept_group, grouped, -jnp.inf).reshape(choice.shape), k)
+    # the tokens whose k best experts are not all in the groups kept (an
+    # expert outside them beats the last one chosen): where the groups
+    # decide something
+    outside = jnp.where(kept_group, -jnp.inf, grouped).max(axis=(-2, -1))
+    module.sow("stats", "group_dropped_share",
+               jnp.mean(outside > top_c[..., -1]))
+    return jnp.take_along_axis(scores, top_i, axis=-1), top_i
+
+
+def _move_bias(module, rows):
+    """After the step's routing: ``b_e += rate * sign(mean(n) - n_e)``
+    from the rows ``n`` each of the E experts took, written back where
+    the caller made ``buffers`` mutable (a training step), nowhere
+    else."""
+    cfg = module.config
+    if not (cfg.selection_bias and module.is_mutable_collection("buffers")
+            ) or module.is_initializing():
+        return
+    with jax.named_scope("optimizer"), jax.named_scope("bias"):
+        bias = module.get_variable("buffers", "selection_bias")
+        load = jax.lax.stop_gradient(rows.astype(jnp.float32))
+        module.put_variable(
+            "buffers", "selection_bias",
+            bias + cfg.bias_update_rate * jnp.sign(load.mean() - load))
+
+
 class MoEMLP(nn.Module):
     """Top-k routed SwiGLU experts, expert-sharded over ``ep``."""
 
@@ -464,7 +560,7 @@ class MoEMLP(nn.Module):
                     probs = scores / scores.sum(axis=-1, keepdims=True)
                 else:
                     scores = probs = jax.nn.softmax(logits, axis=-1)
-                top_w, top_i = jax.lax.top_k(scores, k)
+                top_w, top_i = _choose(self, scores)
                 if cfg.norm_topk_prob:
                     top_w = top_w / top_w.sum(axis=-1, keepdims=True)
                 if cfg.routed_scaling_factor != 1.0:
@@ -512,6 +608,7 @@ class MoEMLP(nn.Module):
                     (cfg.router_z_coef / cfg.num_layers)
                     * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
                 )
+                _move_bias(self, rows)
                 self.sow("stats", "load_max_over_mean", rows.max() / rows.mean())
                 self.sow("stats", "rows_held_over_live", held / live)
                 self.sow("stats", "chip_rows_max_over_mean",

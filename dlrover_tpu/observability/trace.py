@@ -505,13 +505,23 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
                   # attention.py::block_diffusion_attention``): the joining
                   # of a noisy block's keys and the mask from ``iota``, the
                   # clean half's and the noisy half's attention
-                  "bd_keys", "bd_clean", "bd_noisy"),
+                  "bd_keys", "bd_clean", "bd_noisy",
+                  # the core of latent attention (``ops/attention.py::
+                  # latent_attention``)
+                  "latent"),
+    # what latent attention adds around its core (``models/llama.py::
+    # LatentAttention``): the latent's projections, its norm, the rotary
+    # part.  The one name two kinds have: a kind each, and a path that
+    # starts anew at it is the core's (``_SUB_SCOPE_OF``: kernels)
+    "attn.proj": ("latent",),
     # the sampling of block diffusion's noise (``models/llama.py``)
     "embed": ("noise",),
     "moe": ("route", "sort", "gmm", "exchange", "combine", "shared"),
+    # a router's selection bias moved by the load (``models/moe.py``)
+    "optimizer": ("bias",),
 }
 
-_SUB_SCOPE_OF = {sub: kind for kind, subs in SUB_SCOPES.items()
+_SUB_SCOPE_OF = {sub: kind for kind, subs in reversed(SUB_SCOPES.items())
                  for sub in subs}
 
 #: names the compiler gives an instruction in place of a path: the grouped

@@ -25,6 +25,10 @@ selected attention's kernels' other entry point on a TPU and in
 attention of a model trained by diffusion over blocks: a noisy and a clean
 copy of a sequence in one row of ``2S`` positions, under a mask that is not
 causal, a block of queries at a time through the same entry point.
+``latent_attention`` (at the very end) is the core of latent attention
+(MLA): scores that are the sum of a product a head and a product against
+one rotary key every head shares, values narrower than the scores, in
+kernels of its own on a TPU (``ops/pallas/latent_attention.py``).
 """
 
 import functools
@@ -752,3 +756,72 @@ def block_diffusion_attention(q, k, v, block, query_block=512):
                       for t in (k, v)]
         noisy.append(attend(q[:, first:last], *joined, noisy=True))
     return jnp.concatenate(noisy + clean, axis=1)
+
+
+# --------------------------------------------------------------------------
+# The core of latent attention (MLA, arXiv:2405.04434): every head's score
+# is q_nope k_nope^T + q_pe k_pe^T, the second against ONE rotary key
+# --------------------------------------------------------------------------
+
+def latent_attention_path(backend: str, seq: int, heads: int,
+                          nope_dim: int = 128, rope_dim: int = 64,
+                          v_dim: int = 128) -> str:
+    """``"pallas"`` or ``"reference"``: the kernels of
+    ``ops/pallas/latent_attention.py`` on a TPU at the widths and lengths
+    they take, the ``jax.numpy`` body everywhere else; from what the code can observe and nothing
+    else."""
+    from dlrover_tpu.ops.pallas.latent_attention import kernels_take
+
+    del heads   # any count: a head is a grid step of the kernels
+    if backend == "tpu" and kernels_take(seq, nope_dim, rope_dim, v_dim):
+        return "pallas"
+    return "reference"
+
+
+def _latent_reference(q_nope, q_pe, k_nope, k_pe, v):
+    """The ``jax.numpy`` body: float32 scores and softmax whole (``[B, H,
+    S, S]``: the CPU's tests and rehearsals, never the chip's cell)."""
+    S = q_nope.shape[1]
+    scale = (q_nope.shape[-1] + q_pe.shape[-1]) ** -0.5
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe,
+                           preferred_element_type=jnp.float32)) * scale
+    causal = jnp.tril(jnp.ones((S, S), dtype=bool))
+    scores = jnp.where(causal, scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def latent_attention(q_nope, q_pe, k_nope, k_pe, v, interpret: bool = False):
+    """Causal self-attention a head with scores ``(q_nope k_nope^T + q_pe
+    k_pe^T) / sqrt(D + R)``: ``q_nope, k_nope`` [B, S, H, D], ``q_pe`` [B,
+    S, H, R], ``k_pe`` [B, S, R] (one rotary key head, shared by every
+    query head), ``v`` [B, S, H, Dv] -> [B, S, H, Dv].  Softmax in float32.
+    On a TPU through the Pallas kernels (no ``[H, S, S]`` array in HBM,
+    forward or backward), ``jax.numpy`` elsewhere; ``interpret``: the
+    kernels in the Pallas interpreter (tests off the chip).  Sub-scope
+    ``latent`` of the caller's ``attn.core``."""
+    B, S, H, D = q_nope.shape
+    R, Dv = q_pe.shape[-1], v.shape[-1]
+    exact = "pallas" if interpret else latent_attention_path(
+        jax.default_backend(), S, H, D, R, Dv)
+    attrs = dict(impl="latent", seq=S, heads=H, qk=f"{D}+{R}", v=Dv)
+    with jax.named_scope("latent"):
+        if exact != "pallas":
+            trace.note_trace_time("attention.path", blocks=None,
+                                  exact="reference", **attrs)
+            return _latent_reference(q_nope, q_pe, k_nope, k_pe, v)
+        from dlrover_tpu.ops.pallas import kept
+        from dlrover_tpu.ops.pallas.latent_attention import (
+            blocks_for,
+            kept_bytes,
+            latent_attention_kernels,
+        )
+
+        blocks = blocks_for(S)
+        trace.note_trace_time("attention.path", blocks=blocks,
+                              exact="pallas", **attrs)
+        kept.note("latent", **kept_bytes(v))
+        return latent_attention_kernels(
+            q_nope, q_pe, k_nope, k_pe, v, *blocks, interpret)

@@ -32,12 +32,21 @@ class TrainState(flax.struct.PyTreeNode):
     int8-quantized gradient sync: a dict of per-param ``(dp, *leaf)``
     stacks, dp-sharded, holding each replica's un-injected quantization
     error.  None unless the trainer runs a quantized ``grad_sync``
-    policy (docs/migration.md)."""
+    policy (docs/migration.md).
+
+    ``buffers`` is what the model keeps in its ``buffers`` collection:
+    arrays that are state and no parameters (a router's selection bias,
+    ``models/moe.py``).  The step hands them to the model, mutable, and
+    stores what it hands back; no gradient is taken with respect to them
+    and the optimizer never sees them (no moment, no weight decay, not in
+    the gradient norm).  Saved and restored with the rest of the state.
+    None for a model that declares none (docs/migration.md)."""
 
     step: jnp.ndarray
     params: Any
     opt_state: Any
     ef_residual: Any = None
+    buffers: Any = None
 
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray,
@@ -164,8 +173,8 @@ class Trainer:
             self._configure_grad_sync()
         self._warn_fp32_accum_if_needed()
         # (params, batch) -> (loss, what the model sowed into ``stats``)
-        self._loss_fn = self._default_loss if loss_fn is None else (
-            lambda params, batch: (loss_fn(params, batch), {})
+        self._loss_fn = self._model_loss if loss_fn is None else (
+            lambda params, batch: (loss_fn(params, batch), ({}, None))
         )
         self.state_shardings = None
         self._jit_step = None
@@ -611,6 +620,7 @@ class Trainer:
             params=params,
             opt_state=self.optimizer.init(params),
             ef_residual=ef,
+            buffers=variables.get("buffers"),
         )
 
     def state_sharding_for(self, rng, sample_input):
@@ -710,6 +720,11 @@ class Trainer:
     # -- train step ----------------------------------------------------------
 
     def _default_loss(self, params, batch):
+        """``(loss, stats)`` of ``_model_loss``."""
+        loss, (stats, _) = self._model_loss(params, batch)
+        return loss, stats
+
+    def _model_loss(self, params, batch):
         """Cross entropy plus every term the model sows into its
         ``losses`` collection, weighted by the model (a routed block's
         load-balancing and z-loss; nothing for a dense model), and what
@@ -717,10 +732,17 @@ class Trainer:
         ``own_objective`` (block diffusion's NELBO) is given no cross
         entropy on top: its loss is what it sows, and the batch's
         ``labels`` go unused.  ``batch["rngs"]``: the step's random streams
-        for a model that draws some (``_model_step_rngs``)."""
+        for a model that draws some (``_model_step_rngs``).
+        ``batch["buffers"]``: the state's buffers for a model that declares
+        some, handed over mutable; the auxiliary result is ``(stats, the
+        buffers as the model left them)``."""
+        variables, mutable = {"params": params}, ["losses", "stats"]
+        if batch.get("buffers") is not None:
+            variables["buffers"] = batch["buffers"]
+            mutable.append("buffers")
         logits, sown = self.model.apply(
-            {"params": params}, batch["input_ids"],
-            mutable=["losses", "stats"], rngs=batch.get("rngs"),
+            variables, batch["input_ids"],
+            mutable=mutable, rngs=batch.get("rngs"),
         )
         config = getattr(self.model, "config", None)
         with jax.named_scope("head_loss"):
@@ -731,11 +753,18 @@ class Trainer:
                     logits, batch["labels"], batch.get("mask"))
             for term in jax.tree.leaves(sown.get("losses", {})):
                 loss = loss + jnp.sum(term)
-        return loss, sown.get("stats", {})
+        return loss, (sown.get("stats", {}), sown.get("buffers"))
 
     def _loss_and_grads(self, params, batch):
         """``((loss, stats), grads)``, optionally w.r.t. a low-precision
         param view."""
+        (loss, (stats, _)), grads = self._loss_buffers_and_grads(params, batch)
+        return (loss, stats), grads
+
+    def _loss_buffers_and_grads(self, params, batch):
+        """``((loss, (stats, the model's buffers after the step)), grads)``:
+        ``_loss_and_grads`` with what a model that declares buffers hands
+        back (``None`` for any other)."""
         if self.grads_dtype is not None:
             with jax.named_scope("optimizer"):
                 params = jax.tree.map(
@@ -761,13 +790,17 @@ class Trainer:
 
     def _train_step(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
         rngs = self._model_step_rngs(state.step)
-        if rngs:
+        if rngs or state.buffers is not None:
             if self._sync_active or self.grad_accum_steps != 1:
-                # both split the batch by rows, keys and all
+                # both split the batch by rows, keys and all, and would
+                # move a buffer once a part
                 raise NotImplementedError(
-                    "a model that draws noise in its step runs on the exact "
-                    "path without gradient accumulation")
-            batch = {**batch, "rngs": rngs}
+                    "a model that draws noise or moves buffers in its step "
+                    "runs on the exact path without gradient accumulation")
+            if rngs:
+                batch = {**batch, "rngs": rngs}
+            if state.buffers is not None:
+                batch = {**batch, "buffers": state.buffers}
         if self._sync_active:
             return self._sync_train_step(state, batch)
         return self._exact_train_step(state, batch)
@@ -775,9 +808,12 @@ class Trainer:
     def _exact_train_step(
         self, state: TrainState, batch
     ) -> Tuple[TrainState, Dict]:
-        stats = {}
+        stats, buffers = {}, state.buffers
         if self.grad_accum_steps == 1:
-            (loss, stats), grads = self._loss_and_grads(state.params, batch)
+            (loss, (stats, moved)), grads = self._loss_buffers_and_grads(
+                state.params, batch)
+            if buffers is not None:
+                buffers = moved
         else:
             loss_sum, grad_sum, w_sum = self._accumulate_scan(
                 state.params, batch
@@ -808,7 +844,8 @@ class Trainer:
             )
             params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
-            step=state.step + 1, params=params, opt_state=opt_state
+            step=state.step + 1, params=params, opt_state=opt_state,
+            buffers=buffers,
         )
         metrics = {"loss": loss, "grad_norm": grad_norm}
         if stats:

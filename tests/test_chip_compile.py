@@ -171,6 +171,42 @@ def test_selected_attention_kernels_compile_and_the_reader_sees_them(
     assert all(is_sparse_attn_op(call, shape) for call in calls)
 
 
+def test_latent_attention_kernels_compile_and_hold_nothing_seq_by_seq(
+        one_chip):
+    """The Ling-3.0 cell's shapes: 16384 positions, 8 heads, scores over
+    128 + 64 against one shared rotary key, values of 128.  Forward, dQ and
+    dK/dV are three custom calls named after the ``latent`` scope, and no
+    array of the compiled program has two dimensions of the sequence."""
+    from dlrover_tpu.ops.pallas.latent_attention import (
+        blocks_for,
+        latent_attention_kernels,
+    )
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    S, H = 16384, 8
+    blocks = blocks_for(S)
+
+    def both(q_nope, q_pe, k_nope, k_pe, v):
+        def loss(*operands):
+            with jax.named_scope("latent"):
+                out = latent_attention_kernels(*operands, *blocks)
+            return out.astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+            q_nope, q_pe, k_nope, k_pe, v)
+
+    wide = sds(1, S, H, 128)
+    text = jax.jit(both).lower(
+        wide, sds(1, S, H, 64), wide, sds(1, S, 64), wide).compile().as_text()
+    names = _kernel_names(text)
+    assert len(names) == 3 and all("latent" in name for name in names)
+    assert not re.search(r"\[[\d,]*16384,16384[\d,]*\]", text)
+    # the shared key's gradient: a head at a time out of the kernel, summed
+    assert f"f32[1,{H},{S},64]" in text
+
+
 @pytest.mark.parametrize("keys", [512, 2560, 8192])
 def test_index_scores_kernels_compile_and_the_reader_sees_them(
         one_chip, keys):

@@ -59,6 +59,21 @@ FAMILIES = {
             residual_dtype=jnp.float32, norm_unit_offset=True)),
         COMMON | {"mlp"},
         {"attn.core": {"pool", "windows", "summary_mass"}}),
+    "ling_latent": (
+        lambda: LlamaForCausalLM(MoELlamaConfig.tiny_moe(
+            num_layers=3, layer_prefix=("kda:dense",),
+            layer_pattern=("kda", "mla"), dense_intermediate_size=96,
+            kda_heads=2, kda_head_dim=16, kda_chunk=16,
+            kda_full_rank_gates=True, kda_decay_lower_bound=-5.0,
+            kda_neg_eigval=False, mla_kv_rank=24, mla_nope_dim=16,
+            mla_rope_dim=8, mla_v_dim=16, mla_head_gate=True,
+            num_experts=16, top_k=4, experts_held=4, norm_topk_prob=True,
+            router_scores="sigmoid", shared_experts=1, n_group=4,
+            topk_group=2, selection_bias=True, max_seq_len=SEQ)),
+        COMMON | {"moe", "mlp"},
+        {"attn.core": {"latent", "conv", "decay", "chunk", "state", "gate"},
+         "attn.proj": {"latent"}, "optimizer": {"bias"},
+         "moe": {"route", "sort", "gmm", "combine", "shared"}}),
 }
 
 #: a path may be ``other`` where it names nothing but the layer stack and
@@ -66,7 +81,9 @@ FAMILIES = {
 #: the stacked parameters, the positions and the causal mask
 STACK = {"layers", "layer", "h", "block", "jit(wrapped)", "LlamaForCausalLM",
          "GPT", "while", "body", "cond", "closed_call", "checkpoint",
-         "rematted_computation", "jit(tril)"}
+         "rematted_computation", "jit(tril)",
+         # a pattern's runs (``ling_latent``)
+         "prefix", "kda_dense_0", "kda_0", "mla_1"}
 
 def _step_text(model, steps=0):
     mesh = build_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])

@@ -57,12 +57,27 @@ def test_layer_runs_of_a_pattern():
     assert cfg.layer_runs() == [
         ("kda_0", "kda", 1), ("gqa_1", "gqa", 2), ("kda_2", "kda", 1)]
     assert LlamaConfig.tiny().layer_runs() == []
+    # a prefix stands once before the periods; a dense entry names its run
+    cfg = _config(layer_prefix=("kda:dense", "kda:dense", "gqa"),
+                  layer_pattern=("kda", "mla"), num_layers=7,
+                  dense_intermediate_size=96, mla_kv_rank=24)
+    assert cfg.layer_runs(cfg.layer_prefix) == [
+        ("kda_dense_0", "kda:dense", 2), ("gqa_1", "gqa", 1)]
+    assert cfg.layer_runs() == [("kda_0", "kda", 1), ("mla_1", "mla", 1)]
+    assert cfg.periods == 2
 
 
 @pytest.mark.parametrize("changes,match", [
     ({"layer_pattern": ("gqa", "mamba")}, "kinds of"),
     ({"num_layers": 6}, "whole number of periods"),
     ({"kda_heads": 0}, "kda_heads"),
+    ({"layer_pattern": ("gqa", "mla"), "num_layers": 2}, "mla_kv_rank"),
+    ({"layer_pattern": ("gqa:dense", "kda"), "num_layers": 2},
+     "dense_intermediate_size"),
+    ({"layer_pattern": ("gqa:sparse", "kda"), "num_layers": 2}, "entries"),
+    ({"layer_prefix": ("kda",), "num_layers": 8}, "less the prefix"),
+    ({"layer_prefix": ("kda",), "layer_pattern": (), "num_layers": 1},
+     "entries"),
 ])
 def test_what_the_config_refuses(changes, match):
     with pytest.raises(ValueError, match=match):
@@ -238,8 +253,128 @@ def test_patterned_state_through_a_memory_save_and_restore(tmp_path):
     assert "tp" in str(conv.sharding.spec)
 
 
+#: sha256 (16 hex digits) of the jaxpr of ``value_and_grad`` of a loss of
+#: the model on zeros at [2, 48] ids, addresses struck out, taken at the
+#: commit BEFORE the pattern learnt its prefix, its dense entries and its
+#: third kind (PR 47's tree, in a clone beside this one, PR 48): the program
+#: that computes a loss is the old one instruction for instruction, so the
+#: loss is the old one to the bit on whatever machine runs it
+BEFORE = {"empty": "483fc5aaffc3fc24", "solar": "def5410a2c443246",
+          "solar_routed": "35dbeac905ed37c7"}
+
+
+def _before_and_now(which):
+    from dlrover_tpu.models.moe import MoELlamaConfig
+
+    if which == "empty":
+        return LlamaConfig.tiny()
+    if which == "solar":
+        return _config()
+    return MoELlamaConfig.tiny_moe(
+        num_layers=4, layer_pattern=PATTERN, use_rope=False, attn_gate=True,
+        kda_heads=2, kda_head_dim=16, kda_chunk=16, dtype=jnp.float32,
+        num_experts=8, top_k=3, norm_topk_prob=True, router_scores="sigmoid",
+        shared_experts=1, experts_held=2)
+
+
+@pytest.mark.parametrize("which", sorted(BEFORE))
+def test_todays_patterns_compute_their_losses_by_the_same_program(which):
+    import hashlib
+    import re
+
+    model = LlamaForCausalLM(_before_and_now(which))
+    ids = jnp.zeros((2, SEQ), jnp.int32)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
+    assert "buffers" not in shapes              # none where none is asked
+    params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                          nn.meta.unbox(shapes["params"]))
+
+    def loss(p):
+        logits, sown = model.apply(
+            {"params": p}, ids, mutable=["losses", "stats"])
+        return logits.astype(jnp.float32).mean() + sum(
+            jnp.sum(t) for t in jax.tree.leaves(sown.get("losses", {})))
+
+    text = re.sub(r"0x[0-9a-f]+", "0x",
+                  str(jax.make_jaxpr(jax.value_and_grad(loss))(params)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == BEFORE[which]
+
+
+def test_solars_pattern_gives_todays_tree_name_for_name():
+    """The tree of ``solaropen2_250b_1of32.steady``: its ``condition`` rule
+    and a checkpoint of it read these names."""
+    shapes = jax.eval_shape(LlamaForCausalLM(_config()).init,
+                            jax.random.PRNGKey(0), _ids())
+    paths = {"/".join(str(k.key) for k in path): leaf.shape for path, leaf in
+             jax.tree_util.tree_leaves_with_path(
+                 nn.meta.unbox(shapes["params"]))}
+    both = {"input_norm/scale": (64,), "post_attn_norm/scale": (64,),
+            "mlp/gate_proj/kernel": (64, 128), "mlp/up_proj/kernel": (64, 128),
+            "mlp/down_proj/kernel": (128, 64), "attn/o_proj/kernel": None}
+    gqa = {"attn/q_proj/kernel": (64, 4, 16), "attn/k_proj/kernel": (64, 2, 16),
+           "attn/v_proj/kernel": (64, 2, 16),
+           "attn/gate_proj/kernel": (64, 4, 16),
+           "attn/o_proj/kernel": (4, 16, 64)}
+    kda = {**{f"attn/{n}_proj/kernel": (64, 2, 16) for n in "qkv"},
+           **{f"attn/{n}_conv": (4, 2, 16) for n in "qkv"},
+           **{f"attn/{n}_down/kernel": (64, 16) for n in "fg"},
+           **{f"attn/{n}_up/kernel": (16, 2, 16) for n in "fg"},
+           "attn/beta_proj/kernel": (64, 2), "attn/A_log": (2,),
+           "attn/dt_bias": (2, 16), "attn/o_norm/scale": (16,),
+           "attn/o_proj/kernel": (2, 16, 64)}
+    want = {"embed_tokens": (256, 64), "final_norm/scale": (64,),
+            "lm_head/kernel": (64, 256)}
+    for run, length, own in (("gqa_0", 1, gqa), ("kda_1", 3, kda)):
+        for name, shape in {**both, **own}.items():
+            want[f"layers/{run}/layer/{name}"] = (2, length) + shape
+    assert paths == want
+
+
+def test_a_prefix_is_the_same_layers_before_the_periods(made):
+    """Two periods of the pattern, or the first period's layers as a prefix
+    and one period after it: one stack of eight layers either way."""
+    cfg, model, ids, params = made
+    as_prefix = LlamaForCausalLM(dataclasses.replace(
+        cfg, layer_prefix=PATTERN))
+    layers = params["layers"]
+    moved = {**params,
+             "prefix": jax.tree.map(lambda t: t[0], layers),
+             "layers": jax.tree.map(lambda t: t[1:], layers)}
+    shapes = jax.eval_shape(as_prefix.init, jax.random.PRNGKey(0), ids)
+    assert jax.tree.map(lambda t: t.shape, moved) == jax.tree.map(
+        lambda s: s.shape, nn.meta.unbox(shapes["params"]))
+    assert as_prefix.num_params() == model.num_params()
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(model.apply)({"params": params}, ids)
+        got = jax.jit(as_prefix.apply)({"params": moved}, ids)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+
+
+def test_a_dense_entry_has_the_dense_feed_forward():
+    from dlrover_tpu.models.moe import MoELlamaConfig
+
+    cfg = MoELlamaConfig.tiny_moe(
+        num_layers=3, layer_prefix=("gqa:dense",), layer_pattern=("gqa",),
+        dense_intermediate_size=96, num_experts=4, top_k=2)
+    model = LlamaForCausalLM(cfg)
+    shapes = nn.meta.unbox(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), _ids())["params"])
+    dense = shapes["prefix"]["gqa_dense_0"]["layer"]["mlp"]
+    assert set(dense) == {"gate_proj", "up_proj", "down_proj"}
+    assert dense["gate_proj"]["kernel"].shape == (1, 64, 96)
+    routed = shapes["layers"]["gqa_0"]["layer"]["mlp"]
+    assert routed["gate_proj"].shape == (2, 1, 4, 64, 128)
+    assert "router" in routed
+    assert model.num_params() == sum(
+        int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(shapes))
+
+
 def test_dataclass_fields_keep_their_defaults():
     """What the other cells' families do not name keeps today's behaviour."""
     defaults = {f.name: f.default for f in dataclasses.fields(LlamaConfig)}
     assert defaults["layer_pattern"] == () and defaults["use_rope"] is True
     assert defaults["attn_gate"] is False and defaults["kda_heads"] == 0
+    assert defaults["layer_prefix"] == () and defaults["mla_kv_rank"] == 0
+    assert defaults["kda_full_rank_gates"] is False
+    assert defaults["kda_decay_lower_bound"] == 0.0
+    assert defaults["kda_neg_eigval"] is True
