@@ -59,7 +59,11 @@ eleven.  No pull-back asks for the down product (``_down_and_sum``).  It
 adapts by the rung the routing picks: no field, argument or variable.  Where
 one chip holds every expert the ladder has one rung, a ``switch`` over one
 branch is a plain call (no conditional in the step), and the same rules give
-the same nine.
+the same nine.  The router's float32 logits, the experts it chose and a
+share's count of rows carry ``kept.py``'s ``moe_route`` the same way: the
+second pass of a rematerialised layer computes scores and weights from
+them, elementwise (``_at_kept``), with no router matmul, no ``top_k``, no pass
+over groups and no gather, and the sort it kept is of the choice it kept.
 
 Expert parallelism: ``ep`` ranks hold ``num_experts / ep`` experts each and
 are data ranks for everything else.  Inside a ``shard_map`` the tokens of
@@ -451,7 +455,9 @@ def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert,
     # rematerialised layer that sorted again, by a router whose scores the
     # compiler rounded another way in its second pass, would pair a
     # token on a tie with another expert's row, or with a row behind the
-    # last group, which is undefined
+    # last group, which is undefined.  The choice the sort is of is kept
+    # with it (``MoEMLP``, ``kept.MOE_ROUTE``): a second pass weights a
+    # kept row by the score of the expert the first pass gave it
     order, inverse, sizes = kept.named(
         kept.MOE_PRODUCTS, order, inverse, sizes)
     extents = ladder(tokens * k, n_local, num_experts or n_local)
@@ -471,13 +477,10 @@ def _choose(module, scores):
     path).  ``(weights, experts)`` [B, S, k] of each token's ``top_k``: the
     largest ``scores``, or with a selection bias the largest ``scores +
     b``, or with groups the largest inside the ``topk_group`` groups
-    whose two best add up highest.  The weights are the scores' own."""
+    whose two best add up highest.  The choice carries no gradient and is
+    KEPT (``_at_kept``): the weights are the scores' own at those experts."""
     cfg = module.config
     k = cfg.top_k
-    if not (cfg.n_group or cfg.selection_bias):
-        return jax.lax.top_k(scores, k)
-    # the choice carries no gradient: the weights are gathered from
-    # ``scores`` at the chosen experts
     choice = jax.lax.stop_gradient(scores)
     if cfg.selection_bias:
         bias = module.variable(
@@ -486,8 +489,7 @@ def _choose(module, scores):
         choice = choice + jax.lax.stop_gradient(bias)
         module.sow("stats", "bias_abs_max", jnp.abs(bias).max())
     if not cfg.n_group:
-        top_i = jax.lax.top_k(choice, k)[1]
-        return jnp.take_along_axis(scores, top_i, axis=-1), top_i
+        return _at_kept(scores, jax.lax.top_k(choice, k)[1])
     # ONE ``top_k`` over the columns (a sort on the chip, 20 ms a layer
     # and pass at 16384 x 512: PERF.md, PR 48); the groups by passes of
     # ``max``: a group's two best as its maximum and the maximum of the
@@ -510,7 +512,27 @@ def _choose(module, scores):
     outside = jnp.where(kept_group, -jnp.inf, grouped).max(axis=(-2, -1))
     module.sow("stats", "group_dropped_share",
                jnp.mean(outside > top_c[..., -1]))
-    return jnp.take_along_axis(scores, top_i, axis=-1), top_i
+    return _at_kept(scores, top_i)
+
+
+def _at_kept(scores, top_i):
+    """``(scores at top_i, top_i)``, both [B, S, k], of ``scores`` [B, S,
+    E]: the experts under ``kept.MOE_ROUTE``, so that the second pass of a
+    rematerialised layer reads the choice and runs no ``top_k`` or pass
+    over groups for it, and the weights FROM the kept experts: what
+    ``take_along_axis`` gives, by k passes of compare and sum over the
+    columns.  Those are elementwise, and so is their pull-back (a token's
+    k gradients selected into its row); the chip's gather of one float a
+    slot and the scatter its pull-back is each cost what the router's
+    matmul or its ``top_k`` does (1.40 and 1.31 ms against under 0.25 at
+    16384 x 512: PERF.md, PR 49).  One term of a sum is not zero: the
+    gather's values to the bit."""
+    (top_i,) = kept.named(kept.MOE_ROUTE, top_i)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, scores.shape,
+                                     scores.ndim - 1)
+    return jnp.stack([
+        jnp.where(lanes == top_i[..., j:j + 1], scores, 0).sum(axis=-1)
+        for j in range(top_i.shape[-1])], axis=-1), top_i
 
 
 def _move_bias(module, rows):
@@ -553,6 +575,10 @@ class MoEMLP(nn.Module):
                     ),
                     name="router",
                 )(x)
+                # what a rematerialised layer keeps of its router
+                # (``kept.MOE_ROUTE``; the choice in ``_choose``): its
+                # second pass reads them and runs no matmul for them
+                (logits,) = kept.named(kept.MOE_ROUTE, logits)
                 if cfg.router_scores == "sigmoid":
                     scores = jax.nn.sigmoid(logits)
                     # the balance loss reads each expert's share of the
@@ -595,8 +621,9 @@ class MoEMLP(nn.Module):
                     live = jnp.maximum(rows.sum(), 1)
                     self.sow("stats", "share_rows_over_expected",
                              live * (E / (B * S * k * here)))
-                    rows = (top_i[..., None] == jnp.arange(E)).sum(
-                        axis=(0, 1, 2), dtype=jnp.int32)
+                    (rows,) = kept.named(kept.MOE_ROUTE, (
+                        top_i[..., None] == jnp.arange(E)).sum(
+                            axis=(0, 1, 2), dtype=jnp.int32))
                 assigned = rows.astype(jnp.float32) / (B * S * k)
                 self.sow(
                     "losses", "load_balance",
@@ -727,9 +754,9 @@ class MoEMLP(nn.Module):
                 out_specs=(x_spec,) + 3 * (PartitionSpec(),),
             )
         rows = x.shape[0] * x.shape[1] * k
-        # of one pass: a source rank's assignments on one chip
-        extents = ladder(rows // math.prod(mesh.shape[a] for a in chips),
-                         gate_w.shape[0] // ep, cfg.num_experts)
+        # a source rank's assignments on one chip, and of one pass
+        chip = rows // math.prod(mesh.shape[a] for a in chips)
+        extents = ladder(chip, gate_w.shape[0] // ep, cfg.num_experts)
         trace.note_trace_time(
             "moe.path", impl="ragged_dot", experts=cfg.num_experts,
             top_k=k, ep=ep, tokens=x.shape[0] * x.shape[1], rows=rows,
@@ -737,11 +764,18 @@ class MoEMLP(nn.Module):
             held=gate_w.shape[0], first_expert=cfg.first_expert,
             # grouped matmuls in the pull-back of a pass at the first
             # extent, from the products the forward pass kept
-            backward=6, kept=kept.MOE_PRODUCTS,
+            backward=6, kept=f"{kept.MOE_PRODUCTS},{kept.MOE_ROUTE}",
         )
-        # a source rank's two products and the sort they are in
-        kept.note("moe", **{kept.MOE_PRODUCTS: ep * (
-            2 * kept.nbytes((extents[0], cfg.intermediate_size), cfg.dtype)
-            + kept.nbytes((2 * extents[-1] + gate_w.shape[0] // ep,),
-                          jnp.int32))})
+        # a source rank's two products and the sort they are in; this
+        # chip's tokens' logits and choice, and a share's count of rows
+        kept.note("moe", **{
+            kept.MOE_PRODUCTS: ep * (
+                2 * kept.nbytes((extents[0], cfg.intermediate_size),
+                                cfg.dtype)
+                + kept.nbytes((2 * extents[-1] + gate_w.shape[0] // ep,),
+                              jnp.int32)),
+            kept.MOE_ROUTE: (
+                kept.nbytes((chip // k, cfg.num_experts), jnp.float32)
+                + kept.nbytes((chip + bool(cfg.experts_held)
+                               * cfg.num_experts,), jnp.int32))})
         return per_shard(x, top_i, top_w, gate_w, up_w, down_w)

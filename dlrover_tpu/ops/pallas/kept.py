@@ -1,7 +1,8 @@
 """What a rematerialised decoder layer keeps of its attention core and of
 its expert layer: the results a core's forward KERNELS wrote that its
-backward kernels read, and the two products of the experts' first grouped
-matmuls that their pull-back reads.
+backward kernels read, the two products of the experts' first grouped
+matmuls that their pull-back reads, and the router's logits with the choice
+it made of them.
 
 A layer of ``models/llama.py`` is rematerialised whole: the forward pass
 keeps the layer's input and the backward pass computes the layer again.
@@ -18,7 +19,11 @@ feed-forward names nothing, and ``LAYER_POLICY`` over a layer without names
 is ``nothing_saveable``.  The expert layer (``models/moe.py``) names its
 products on the result of its switch over extents, at the first extent
 alone, and gives ``LAYER_POLICY`` to its own ``jax.checkpoint`` around one
-source rank's pass under ``ep``.
+source rank's pass under ``ep``.  Its router names the float32 logits, the
+experts chosen and, where it counts them itself, the rows each expert took:
+the second pass then has no router matmul, no ``top_k`` and no pass over
+groups, and weights every kept row by the score of the expert the FIRST
+pass chose for it.
 """
 
 import math
@@ -48,8 +53,13 @@ KDA_STATE = "kda_state"
 #: first extent, one pair a source rank; and the sort they are in (two
 #: index vectors of all assignments and the groups' sizes, int32)
 MOE_PRODUCTS = "moe_products"
+#: what an expert layer's router decided (``models/moe.py::MoEMLP``): the
+#: logits ``[B, S, E]`` float32 (32 MiB a layer at 16,384 tokens and 512
+#: experts), the chosen experts ``[B, S, k]`` int32 and, of one chip's share
+#: of the experts, the rows ``[E]`` int32 each expert took
+MOE_ROUTE = "moe_route"
 
-NAMES = (ATTN_OUT, ATTN_LSE, KDA_CHUNK, KDA_STATE, MOE_PRODUCTS)
+NAMES = (ATTN_OUT, ATTN_LSE, KDA_CHUNK, KDA_STATE, MOE_PRODUCTS, MOE_ROUTE)
 
 #: the policy of every rematerialised decoder layer (``models/llama.py::
 #: _layer_class``, ``models/pipeline_llama.py``)

@@ -679,6 +679,60 @@ class TestTrainerStep:
         assert "bf16[2,1,512,8,128]" in text and "f32[2,1,8,512]" in text
         assert "f32[2,1,8,512,128]" not in text
 
+    def test_a_rematerialised_router_runs_no_matmul_and_no_sort(
+            self, topo, as_if_on_tpu, monkeypatch):
+        """Two scanned layers with Ling-3.0-flash-VL's routed block
+        (16,384 tokens x 2560, 512 router columns in 8 groups of which 4
+        are kept, 8 experts a token under a selection bias, 16 experts
+        held): the layers are one loop a pass, and what the backward
+        pass's loop computes again of ``moe/route`` holds no sort (what
+        ``top_k`` is on the chip), no float32 ``[16384, 2560] x [2560,
+        512]`` product and no gather: the layer keeps the router's logits
+        and its choice (``kept.MOE_ROUTE``) and reads the weights at the
+        choice by compare and sum.  Under the policy without that name, the
+        parent's, it holds one product and one sort."""
+        from dlrover_tpu.models import llama
+        from dlrover_tpu.models.moe import MoELlamaConfig
+        from dlrover_tpu.observability import trace
+        from dlrover_tpu.ops.pallas import kept
+
+        def two_layers():
+            cfg = MoELlamaConfig(
+                vocab_size=4096, hidden_size=2560, intermediate_size=768,
+                num_layers=2, num_heads=8, num_kv_heads=8, head_dim=128,
+                max_seq_len=16384, attention_impl="flash", num_experts=512,
+                top_k=8, norm_topk_prob=True, router_scores="sigmoid",
+                routed_scaling_factor=2.5, n_group=8, topk_group=4,
+                selection_bias=True, shared_experts=1, experts_held=16)
+            return llama.LlamaForCausalLM(cfg), (1, 16384)
+
+        def computed_again():
+            """The router's products and sorts among the instructions of
+            ``moe/route`` in the rematerialised pass."""
+            mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+            text = _trainer_step_compiled(mesh, two_layers).as_text()
+            found = trace.parse_device_scopes(text)
+            again = [
+                line for line in text.split("\n")
+                for name in re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+                if found.scopes["%" + name] == ("moe", "route", trace.REMAT)]
+            assert again                # the sigmoid, the losses' terms
+            assert not [line for line in again if " gather(" in line]
+            return (
+                [line for line in again if re.search(
+                    r"= f32\[16384,512\]\S* (convolution|dot)\(", line)],
+                [line for line in again if re.search(
+                    r" sort\(|TopK|top_k", line.split("metadata=")[0])])
+
+        assert computed_again() == ([], [])
+        monkeypatch.setattr(
+            llama, "LAYER_POLICY",
+            jax.checkpoint_policies.save_only_these_names(
+                *(name for name in kept.NAMES if name != kept.MOE_ROUTE)))
+        products, sorts = computed_again()
+        assert len(products) == 1 and "highest" in products[0]
+        assert len(sorts) == 1
+
     def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
         """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped
         matmuls are the compiler's own kernel, forward and both gradients;

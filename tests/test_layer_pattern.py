@@ -258,9 +258,14 @@ def test_patterned_state_through_a_memory_save_and_restore(tmp_path):
 #: commit BEFORE the pattern learnt its prefix, its dense entries and its
 #: third kind (PR 47's tree, in a clone beside this one, PR 48): the program
 #: that computes a loss is the old one instruction for instruction, so the
-#: loss is the old one to the bit on whatever machine runs it
+#: loss is the old one to the bit on whatever machine runs it.  The routed
+#: model's was taken again at PR 49 (35dbeac905ed37c7 until then): its router
+#: names its logits, its choice and its count of rows for a rematerialised
+#: layer to keep (``kept.MOE_ROUTE``: three ``name`` equations a layer) and
+#: reads its weights at the kept choice by compare and sum (``moe._at_kept``)
+#: where it gathered them
 BEFORE = {"empty": "483fc5aaffc3fc24", "solar": "def5410a2c443246",
-          "solar_routed": "35dbeac905ed37c7"}
+          "solar_routed": "e58e89a0a600250d"}
 
 
 def _before_and_now(which):

@@ -14,8 +14,9 @@ FA2 kernel, the reference, a ``jax.numpy`` body) the two policies lower to
 the same program.
 
 The expert layer (``models/moe.py``) names the two products of a pass's
-first grouped matmuls the same way, ``jax.numpy`` and all: the same stack
-with one chip's share of an expert layer in each.
+first grouped matmuls the same way, ``jax.numpy`` and all, and its router's
+logits, choice and count of rows: the same stack with one chip's share of
+an expert layer in each, once for each way the router chooses.
 """
 
 import collections
@@ -123,12 +124,14 @@ class _Stack:
         self.x = jax.random.normal(
             jax.random.PRNGKey(0), (1, core.rows, HIDDEN))
         # one program, of which the compiler keeps the parameters alone
-        self.params = jax.jit(self.stack.init)(
-            jax.random.PRNGKey(1), self.x, self.positions, self.mask)["params"]
+        # (and a biased router's buffers: never differentiated)
+        self.buffers = dict(jax.jit(self.stack.init)(
+            jax.random.PRNGKey(1), self.x, self.positions, self.mask))
+        self.params = self.buffers.pop("params")
 
     def loss(self, params, x):
         (y, _), sown = self.stack.apply(
-            {"params": params}, x, self.positions, self.mask,
+            {"params": params, **self.buffers}, x, self.positions, self.mask,
             mutable=["losses", "stats"])
         taught = sum(jnp.sum(t) for t in jax.tree.leaves(
             sown.get("losses", {})))
@@ -254,39 +257,70 @@ def test_a_core_that_names_nothing_is_computed_again_whole(monkeypatch,
         jax.checkpoint_policies.nothing_saveable)
 
 
+#: what a router keeps of 256 tokens with 2 of 8 experts each: the logits,
+#: the choice and a share's count of rows
+ROUTE = {(1, 256, 8): 1, (1, 256, 2): 1, (8,): 1}
 #: 256 tokens with 2 experts each, 2 of 8 experts here: the first extent
 #: holds 256 of the 512 assignments; the two products, and the sort they
 #: are in (two index vectors of all assignments, the two groups' sizes)
-EXPERTS = Core(
-    MoELlamaConfig.tiny_moe(
-        num_layers=LAYERS, num_heads=HEADS, num_kv_heads=1, head_dim=DIM,
-        hidden_size=HIDDEN, intermediate_size=128, dtype=jnp.float32,
-        num_experts=8, top_k=2, experts_held=2, max_seq_len=256),
-    "gqa", 256, None, {}, {(256, 128): 2, (512,): 2, (2,): 1})
+PRODUCTS = {(256, 128): 2, (512,): 2, (2,): 1}
+
+
+def _experts(**router):
+    return Core(
+        MoELlamaConfig.tiny_moe(
+            num_layers=LAYERS, num_heads=HEADS, num_kv_heads=1, head_dim=DIM,
+            hidden_size=HIDDEN, intermediate_size=128, dtype=jnp.float32,
+            num_experts=8, top_k=2, experts_held=2, max_seq_len=256,
+            **router),
+        "gqa", 256, None, {}, {**PRODUCTS, **ROUTE})
+
+
+EXPERTS = _experts()
+#: the ways ``models/moe.py::_choose`` chooses: the largest softmax scores;
+#: the largest sigmoid scores under a selection bias; those inside the 2 of
+#: 4 groups whose two best add up highest
+ROUTERS = {
+    "plain": EXPERTS,
+    "biased": _experts(router_scores="sigmoid", selection_bias=True,
+                       norm_topk_prob=True),
+    "grouped": _experts(router_scores="sigmoid", selection_bias=True,
+                        norm_topk_prob=True, routed_scaling_factor=2.5,
+                        n_group=4, topk_group=2),
+}
+
+
+def _stacked_residuals(stack):
+    return collections.Counter(
+        aval.shape[1:] for aval, why in saved_residuals(
+            stack.loss, stack.params, stack.x)
+        if "output of scan" in why and aval.shape[0] == LAYERS)
+
+
+def _bytes(shapes):
+    return sum(math.prod(shape) * count * F32.itemsize
+               for shape, count in shapes.items())
 
 
 def test_an_expert_layer_keeps_its_input_and_the_two_products(monkeypatch):
     stack = _Stack(monkeypatch, EXPERTS, kept.LAYER_POLICY)
     assert ladder(256 * 2, 2, 8) == (256, 512)
     del stack.records[:]
-    stacked = collections.Counter(
-        aval.shape[1:] for aval, why in saved_residuals(
-            stack.loss, stack.params, stack.x)
-        if "output of scan" in why and aval.shape[0] == LAYERS)
-    assert stacked == collections.Counter(
+    assert _stacked_residuals(stack) == collections.Counter(
         {**EXPERTS.kept, (1, EXPERTS.rows, HIDDEN): 1})
     # every trace of the layer makes one reading, so ``note_trace_time``
     # keeps one record of each a program
     paths = [attrs for name, attrs in stack.records if name == "moe.path"]
     path = paths[0]
     assert all(other == path for other in paths)
-    assert path["kept"] == kept.MOE_PRODUCTS and path["backward"] == 6
+    both = f"{kept.MOE_PRODUCTS},{kept.MOE_ROUTE}"
+    assert path["kept"] == both and path["backward"] == 6
     assert path["extents"] == (256, 512)
     note = stack.kept_note()
-    assert note["core"] == "moe" and note["names"] == kept.MOE_PRODUCTS
-    assert note["bytes_per_layer"] == note["moe_products_bytes"] == sum(
-        math.prod(shape) * count * F32.itemsize
-        for shape, count in EXPERTS.kept.items())
+    assert note["core"] == "moe" and note["names"] == both
+    assert note["moe_products_bytes"] == _bytes(PRODUCTS)
+    assert note["moe_route_bytes"] == _bytes(ROUTE)
+    assert note["bytes_per_layer"] == _bytes(EXPERTS.kept)
 
 
 def test_an_expert_layers_gradients_are_those_computed_again_whole(
@@ -306,3 +340,90 @@ def test_an_expert_layers_gradients_are_those_computed_again_whole(
     for a, b in zip(got, want):
         assert float(jnp.abs(b).max()) > 0
         np.testing.assert_array_equal(a, b)
+
+
+def _top_k_eqns(stack):
+    """``top_k`` equations in the gradient's jaxpr (a scan's body counts
+    once: one a layer and pass that runs it)."""
+    return str(jax.make_jaxpr(jax.grad(stack.loss, argnums=(0, 1)))(
+        stack.params, stack.x)).count(" top_k[")
+
+
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_a_router_keeps_its_logits_its_choice_and_its_count(monkeypatch,
+                                                           router):
+    """Whichever way it chooses: the forward pass keeps the router's three
+    arrays beside the products and nothing else, the second pass runs no
+    ``top_k``, and loss and gradients are those computed again whole."""
+    runs = {}
+    for policy in (kept.LAYER_POLICY,
+                   jax.checkpoint_policies.nothing_saveable):
+        stack = _Stack(monkeypatch, ROUTERS[router], policy)
+        if policy is kept.LAYER_POLICY:
+            assert _stacked_residuals(stack) == collections.Counter(
+                {**PRODUCTS, **ROUTE, (1, EXPERTS.rows, HIDDEN): 1})
+            assert stack.kept_note()["moe_route_bytes"] == _bytes(ROUTE)
+        runs[policy] = _top_k_eqns(stack), jax.tree.leaves(
+            stack.value_and_grad())
+    (sorts, got), (sorts_again, want) = runs.values()
+    assert (sorts, sorts_again) == (1, 2)
+    assert len(got) == len(want) > 2
+    for a, b in zip(got, want):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_array_equal(a, b)
+
+
+def _top_k_on_the_host(flips_after):
+    """A ``jax.lax.top_k`` that runs on the host and counts its runs: the
+    first ``flips_after`` break a tie for the lower column, as ``top_k``
+    does, every later one for the higher, as a second pass would whose
+    scores the compiler had rounded the other way."""
+    runs = []
+
+    def on_host(k, x):
+        later = len(runs) >= flips_after
+        runs.append(later)
+        x = np.asarray(x)
+        columns = np.arange(x.shape[-1])[::-1] if later else np.arange(
+            x.shape[-1])
+        first = columns[np.argsort(-x[..., columns], axis=-1, kind="stable")]
+        chosen = first[..., :k].astype(np.int32)
+        return np.take_along_axis(x, chosen, axis=-1), chosen
+
+    def top_k(x, k):
+        return jax.pure_callback(
+            functools.partial(on_host, k),
+            (jax.ShapeDtypeStruct((*x.shape[:-1], k), x.dtype),
+             jax.ShapeDtypeStruct((*x.shape[:-1], k), jnp.int32)), x)
+
+    return top_k, runs
+
+
+def test_on_a_tie_the_second_pass_weights_the_experts_the_first_chose(
+        monkeypatch):
+    """Two experts with one router column: wherever they tie at the edge of
+    a token's two best, a ``top_k`` run again could choose the other one.
+    The kept layer never runs it again, so its gradients are those of a
+    ``top_k`` that always breaks ties one way; a layer computed again whole
+    pairs the second choice's weights with nothing the first pass did."""
+    def gradients(policy, flips_after):
+        stack = _Stack(monkeypatch, EXPERTS, policy)
+        router = stack.params["layer"]["mlp"]["router"]
+        columns = router["kernel"].value
+        router["kernel"] = router["kernel"].replace_boxed(
+            columns.at[..., 4].set(columns[..., 3]))
+        top_k, runs = _top_k_on_the_host(flips_after)
+        monkeypatch.setattr(jax.lax, "top_k", top_k)
+        return jax.tree.leaves(stack.value_and_grad()), runs
+
+    again = jax.checkpoint_policies.nothing_saveable
+    want, _ = gradients(again, flips_after=math.inf)
+    got, runs = gradients(kept.LAYER_POLICY, flips_after=LAYERS)
+    assert runs == LAYERS * [False]
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    # and the ties are there: a second ``top_k`` that breaks them the other
+    # way moves the gradients of a layer that runs one
+    other, runs = gradients(again, flips_after=LAYERS)
+    assert runs == LAYERS * [False] + LAYERS * [True]
+    assert any(float(jnp.abs(a - b).max()) > 0 for a, b in zip(other, want))
