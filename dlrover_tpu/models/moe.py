@@ -39,10 +39,13 @@ in use (``sizes.sum()``, a value on the device).  The last rung holds
 whatever the router does, so dropless still holds, and every rung is the
 same mathematics: ``tests/test_moe.py`` holds a forced routing in each rung
 to the reference and to the last rung's gradients.  Inside a rung only the
-two index vectors of the sort have the extent of all assignments, and the
-gathered operand of the two sums by token (each token's ``top_k`` slots,
-those with no row here reading one row).  The ``switch`` sits under a
-``custom_vjp``: differentiated as it stands it pads every branch's
+two index vectors of the sort have the extent of all assignments, and,
+where the rung holds more than a sixth as many rows as it serves slots,
+the gathered operand of the two sums by token (each token's ``top_k``
+slots, those with no row here reading one row); a smaller rung adds up the
+rows it holds, in the order of their tokens (``_sum_by_token``: which of
+the two is a rule on the static shapes, rung by rung).  The ``switch`` sits
+under a ``custom_vjp``: differentiated as it stands it pads every branch's
 residuals to the union of all branches, which writes the worst case in the
 small rungs; the backward pass switches too.  What the forward pass keeps
 for it is the layer's inputs and, at the ladder's FIRST extent alone, the
@@ -228,14 +231,34 @@ def ladder(rows, n_local, num_experts):
     return tuple(sorted(e for e in below if e < rows)) + (rows,)
 
 
+def _combine_body(extent, slots):
+    """``rows`` or ``slots``: how a pass over ``extent`` sorted rows that
+    serves ``slots`` slots sums by token.  A rule on static shapes, from
+    the two bodies timed alone on the chip at the benchmark's shapes and
+    then in the step (``scripts/combine_alone.py``; PERF.md, PR 50): the
+    rows' body takes a fifth of the slots' time at 5/128, a half to the
+    whole of it at 5/32, by how far the rows outgrow the chip's fast
+    memory, and twice at 5/16."""
+    return "rows" if 6 * extent <= slots else "slots"
+
+
 @jax.named_scope("combine")
-def _sum_by_token(rows, slot, weights=None):
+def _sum_by_token(rows, picked, slot, live, weights=None):
     """[tokens, D] float32: for each token the sum of its assignments'
     rows, each times its weight where ``weights`` [tokens, fan] is given.
-    ``slot`` [tokens, fan] is the row of each assignment, ``len(rows)`` or
-    more for one that has no row here and adds nothing.  A gather and a
-    sum over ``fan``; on the chip a segment sum of the rows (a
-    scatter-add) takes twice as long (PERF.md, PR 28)."""
+    ``picked`` [extent] is the assignment of each row, ``live`` [extent, 1]
+    whether it holds one, ``slot`` [tokens, fan] the row of each
+    assignment, ``len(rows)`` or more for one that has no row here and
+    adds nothing.  Where the rows are few beside the slots
+    (``_combine_body``) the sum runs over the rows (``_sum_over_rows``).
+    Elsewhere a gather of every slot and a sum over ``fan``, as it has
+    been since PR 28: the rows behind the last group are then read at
+    weight 0 and must hold 0.  (A scatter-add of the rows takes three to
+    ten times either: PERF.md, PR 28 and PR 50.)"""
+    if _combine_body(len(rows), slot.size) == "rows":
+        by_row = () if weights is None else (
+            weights.reshape(-1)[picked].astype(jnp.float32),)
+        return _sum_over_rows(rows, picked, slot, live, by_row)
     if len(rows) < slot.size:
         mine = rows.at[slot].get(mode="fill", fill_value=0)
     else:
@@ -246,20 +269,52 @@ def _sum_by_token(rows, slot, weights=None):
                       preferred_element_type=jnp.float32)
 
 
+# a program of its own shapes: a step meets it six times a routed layer
+# and is traced five times a run, and Python reads this body once
+@jax.jit
+def _sum_over_rows(rows, picked, slot, live, by_row):
+    """``_sum_by_token`` over the rows held, ``by_row`` the rows' weights
+    ``([extent] float32,)`` or ``()``.  Sorted by assignment, the rows that
+    hold none behind every token, a token's rows stand in one run of at
+    most ``fan``; one pass adds to each row the ``fan - 1`` behind it that
+    are the same token's, and a token reads the first row of its run, or a
+    row of zeros.  What a row without an assignment holds is never read."""
+    tokens, fan = slot.shape
+    extent = len(rows)
+    key, at, *by_row = jax.lax.sort(
+        (jnp.where(live[:, 0], picked, tokens * fan),
+         jnp.arange(extent, dtype=jnp.int32), *by_row), num_keys=1)
+    token = key // fan
+    theirs = jnp.pad(token, (0, fan - 1), constant_values=-1)
+    behind = jnp.pad(rows[at], ((0, fan - 1), (0, 0)))
+    by_row = [jnp.pad(w, (0, fan - 1)) for w in by_row]
+    total = 0
+    for j in range(fan):
+        term = behind[j:j + extent].astype(jnp.float32)
+        for w in by_row:
+            term = term * w[j:j + extent, None]
+        total = total + jnp.where(
+            (theirs[j:j + extent] == token)[:, None], term, 0)
+    here = (slot < live.sum()).sum(axis=1)
+    first = jnp.where(here > 0, jnp.cumsum(here) - here, extent)
+    return jnp.pad(total, ((0, 1), (0, 0))).at[first].get(
+        mode="promise_in_bounds")
+
+
 @jax.custom_vjp
-def _rows_of(x, picked, slot):
+def _rows_of(x, picked, slot, live):
     """``x[picked // fan]``: for each sorted row its token's.  The
     transpose is a sum by token, where autodiff would scatter-add."""
     with jax.named_scope("sort"):
         return x[picked // slot.shape[1]]
 
 
-def _rows_of_fwd(x, picked, slot):
-    return _rows_of(x, picked, slot), slot
+def _rows_of_fwd(x, picked, slot, live):
+    return _rows_of(x, picked, slot, live), (picked, slot, live)
 
 
-def _rows_of_bwd(slot, g):
-    return _sum_by_token(g, slot).astype(g.dtype), None, None
+def _rows_of_bwd(index, g):
+    return _sum_by_token(g, *index).astype(g.dtype), None, None, None
 
 
 _rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
@@ -295,7 +350,7 @@ def _down_and_sum(hidden, down_w, weights, order, slot, sizes, live):
     built and no forward grouped matmul runs in a backward pass."""
     with jax.named_scope("gmm"):
         out = jnp.where(live, _grouped(hidden, down_w, sizes), 0)
-    return _sum_by_token(out, slot, weights)
+    return _sum_by_token(out, order[:len(hidden)], slot, live, weights)
 
 
 def _down_and_sum_fwd(*args):
@@ -339,7 +394,7 @@ def _products(extent, x, weights, order, inverse, sizes, gate_w, up_w):
     each in the compute dtype.  What a backward pass keeps of the forward
     (``kept.MOE_PRODUCTS``)."""
     picked, slot, live = _sorted(extent, weights, order, inverse, sizes)
-    rows = _rows_of(x, picked, slot)
+    rows = _rows_of(x, picked, slot, live)
     with jax.named_scope("gmm"):
         rows = jnp.where(live, rows, 0)
         return _grouped(rows, gate_w, sizes), _grouped(rows, up_w, sizes)
@@ -765,6 +820,8 @@ class MoEMLP(nn.Module):
             # grouped matmuls in the pull-back of a pass at the first
             # extent, from the products the forward pass kept
             backward=6, kept=f"{kept.MOE_PRODUCTS},{kept.MOE_ROUTE}",
+            # how the passes at each extent sum by token
+            combine=",".join(_combine_body(e, chip) for e in extents),
         )
         # a source rank's two products and the sort they are in; this
         # chip's tokens' logits and choice, and a share's count of rows

@@ -96,6 +96,29 @@ def _kernel_names(text):
                       r'custom_call_target="tpu_custom_call"', text)
 
 
+def _switch_branches(text, count=4):
+    """The bodies of every ``switch`` over ``count`` branches, first
+    branch first."""
+    return [[text[text.index(f"\n{name.strip()} ("):].split("\n}\n")[0]
+             for name in names.split(",")]
+            for names in re.findall(r"branch_computations=\{([^}]*)\}", text)
+            if names.count(",") == count - 1]
+
+
+def _sums_by_token_as_the_path_says(text, combine, slots, width):
+    """Every switch over the ladder: a rung whose sums by token run over
+    the rows held (``moe.path``'s ``combine=``) holds no array of the
+    slots' extent times the width, a rung that gathers the slots does."""
+    switches = _switch_branches(text, len(combine))
+    assert len(switches) >= 2           # forward and backward
+    tokens = slots // 8
+    whole = re.compile(
+        rf"\[({slots},{width}|{tokens},8,{width}|{tokens},{width},8)\]")
+    for branches in switches:
+        assert [bool(whole.search(body)) for body in branches] == [
+            body == "slots" for body in combine]
+
+
 def _load_layer_metric(name):
     """``benchmarks/layer_metrics/<name>.py``, loaded by path as
     ``benchmarks/common.py::load_module`` loads it."""
@@ -496,7 +519,8 @@ class TestTrainerStep:
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
 
-    def test_solar_open2_widths_one_period(self, topo, as_if_on_tpu):
+    def test_solar_open2_widths_one_period(self, topo, as_if_on_tpu,
+                                           monkeypatch):
         """One period of Solar-Open2 at B1 S8192 and the cell's share (8
         delta-rule heads, 8 query heads on 1 key head, 10 of 320 experts
         beside the shared one): one FA2 layer through the kernel, three
@@ -507,10 +531,18 @@ class TestTrainerStep:
         ``jax.numpy`` body's decayed keys, pair-by-pair products or
         triangular solve, every instruction of the delta rule under one of
         the sub-scopes the benchmark's readers sum, and the step fits the
-        chip with no more temporaries than it held in ``jax.numpy``."""
+        chip with no more temporaries than it held in ``jax.numpy``; at
+        the ladder's three small extents the sums by token run over the
+        rows held and nothing has the extent of the 65,536 slots times the
+        width."""
         from dlrover_tpu.models.llama import LlamaForCausalLM
         from dlrover_tpu.models.moe import MoELlamaConfig
         from dlrover_tpu.observability import trace
+
+        paths = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: paths.append(attrs.get("combine")))
 
         def one_period():
             cfg = MoELlamaConfig(
@@ -528,6 +560,9 @@ class TestTrainerStep:
         compiled = _trainer_step_compiled(mesh, one_period)
         text = compiled.as_text()
         assert "8192,8192]" not in text
+        assert set(filter(None, paths)) == {"rows,rows,rows,slots"}
+        _sums_by_token_as_the_path_says(
+            text, ["rows", "rows", "rows", "slots"], 65536, 4096)
         calls = _kernel_names(text)
         # the one softmax layer: forward, dQ, dK/dV; its loops have one
         # turn each, so the compiler finds the rematerialised forward in
@@ -690,11 +725,18 @@ class TestTrainerStep:
         512]`` product and no gather: the layer keeps the router's logits
         and its choice (``kept.MOE_ROUTE``) and reads the weights at the
         choice by compare and sum.  Under the policy without that name, the
-        parent's, it holds one product and one sort."""
+        parent's, it holds one product and one sort.  At the ladder's three
+        small extents the sums by token run over the rows held: nothing
+        there has the extent of the 131,072 slots times the width."""
         from dlrover_tpu.models import llama
         from dlrover_tpu.models.moe import MoELlamaConfig
         from dlrover_tpu.observability import trace
         from dlrover_tpu.ops.pallas import kept
+
+        paths = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: paths.append(attrs.get("combine")))
 
         def two_layers():
             cfg = MoELlamaConfig(
@@ -711,6 +753,9 @@ class TestTrainerStep:
             ``moe/route`` in the rematerialised pass."""
             mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
             text = _trainer_step_compiled(mesh, two_layers).as_text()
+            (combine,) = set(filter(None, paths))
+            _sums_by_token_as_the_path_says(
+                text, combine.split(","), 131072, 2560)
             found = trace.parse_device_scopes(text)
             again = [
                 line for line in text.split("\n")
@@ -725,6 +770,7 @@ class TestTrainerStep:
                     r" sort\(|TopK|top_k", line.split("metadata=")[0])])
 
         assert computed_again() == ([], [])
+        assert set(filter(None, paths)) == {"rows,rows,rows,slots"}
         monkeypatch.setattr(
             llama, "LAYER_POLICY",
             jax.checkpoint_policies.save_only_these_names(

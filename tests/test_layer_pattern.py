@@ -263,9 +263,12 @@ def test_patterned_state_through_a_memory_save_and_restore(tmp_path):
 #: names its logits, its choice and its count of rows for a rematerialised
 #: layer to keep (``kept.MOE_ROUTE``: three ``name`` equations a layer) and
 #: reads its weights at the kept choice by compare and sum (``moe._at_kept``)
-#: where it gathered them
+#: where it gathered them; and again at PR 50 (e58e89a0a600250d until then):
+#: the two sums by token are told which rows hold an assignment
+#: (``moe._rows_of`` carries ``live``), at these shapes they gather the
+#: slots as they did
 BEFORE = {"empty": "483fc5aaffc3fc24", "solar": "def5410a2c443246",
-          "solar_routed": "e58e89a0a600250d"}
+          "solar_routed": "b522af41a96bbbe9"}
 
 
 def _before_and_now(which):

@@ -291,6 +291,193 @@ class TestEveryRung:
                 1.0, float(jnp.abs(want).max()))
 
 
+#: one chip's share of a wide layer, routed by hand: (tokens, top_k,
+#: experts, experts held, the body of the sums by token at each extent of
+#: the ladder).  ``1of8``: ``top_k`` no power of two
+SHARES = {
+    "1of32": (2048, 8, 256, 8, ("rows", "rows", "rows", "slots")),
+    "1of8": (2720, 3, 32, 4, ("rows", "slots", "slots", "slots")),
+}
+SHARE_RUNGS = [(share, rung) for share in SHARES for rung in range(4)]
+
+
+@functools.lru_cache(maxsize=None)
+def _share_inputs(share, rung):
+    """(tokens, chosen experts, their weights, the held experts' three
+    matrices, the ladder): the routing fills this chip's rows to a few
+    under the ladder's extent ``rung``.  Token 0 has all its experts here
+    and two equal weights (a tie), token 1 none, the rest one or two."""
+    tokens, k, experts, held, _ = SHARES[share]
+    extents = ladder(tokens * k, held, experts)
+    rng = np.random.default_rng(rung)
+    here = np.zeros(tokens, np.int64)
+    here[0] = k
+    here[2:] = 1 + np.arange(tokens - 2) % 2
+    target = min(extents[rung], tokens * k // 2) - 5
+    here[np.cumsum(here) > target] = 0
+    assert here.sum() > ([0] + list(extents))[rung]
+    top_i = np.stack([rng.permutation(np.concatenate([
+        rng.choice(held, n, replace=False),
+        held + rng.choice(experts - held, k - n, replace=False)]))
+        for n in here]).astype(np.int32)
+    top_w = rng.uniform(0.5, 1.0, size=top_i.shape).astype(np.float32)
+    top_w[0, 1] = top_w[0, 0]
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(keys[0], (tokens, 64))
+    gate_w, up_w = (0.2 * jax.random.normal(key, (held, 64, 128))
+                    for key in keys[1:3])
+    down_w = 0.2 * jax.random.normal(keys[3], (held, 128, 64))
+    return (x, jnp.asarray(top_i), jnp.asarray(top_w),
+            (gate_w, up_w, down_w), extents)
+
+
+def _share_reference(x, top_i, top_w, gate_w, up_w, down_w):
+    """Every held expert over every token, each result weighted where the
+    token chose that expert: no sort, no rows, no sum by token."""
+    hidden = nn.silu(jnp.einsum("td,edf->etf", x, gate_w)) * jnp.einsum(
+        "td,edf->etf", x, up_w)
+    chose = top_i[None] == jnp.arange(len(gate_w))[:, None, None]
+    return jnp.einsum("etf,efd,et->td", hidden, down_w,
+                      jnp.where(chose, top_w[None], 0).sum(axis=-1))
+
+
+def _value_and_grads(fn, x, top_w, experts):
+    def loss(x, top_w, *experts):
+        out = fn(x, top_w, *experts)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(x, top_w, *experts)
+    return (out,) + grads
+
+
+class TestTheSumsByTokenOverRows:
+    """Where a pass holds few rows beside the slots it serves the sums by
+    token run over the rows (``moe._sum_by_token``): the same function as
+    the gather of every slot, at every extent of a share's ladder."""
+
+    @pytest.mark.parametrize("share,rung", SHARE_RUNGS)
+    def test_a_share_equals_the_slots_body_and_the_reference(
+            self, monkeypatch, share, rung):
+        """The result and the gradient of the tokens, of the weights and
+        of every expert matrix: to 2e-6 of the largest entry against the
+        same pass with every sum over the slots, to 1e-5 against the dense
+        reference; the extent is the one the routing asks for and the body
+        the one the shapes ask for."""
+        tokens, k, experts, held, bodies = SHARES[share]
+        x, top_i, top_w, expert_w, extents = _share_inputs(share, rung)
+        taken = []
+
+        def system(x, top_w, *expert_w):
+            out, _, at = local_experts(
+                x, top_i, top_w, *expert_w, 0, experts)
+            taken.append(at)
+            return out
+
+        assert tuple(moe._combine_body(e, tokens * k)
+                     for e in extents) == bodies
+        got = _value_and_grads(system, x, top_w, expert_w)
+        asked = []
+        monkeypatch.setattr(
+            moe, "_combine_body",
+            lambda extent, slots: asked.append(extent) or "slots")
+        by_slots = _value_and_grads(system, x, top_w, expert_w)
+        assert set(asked) == set(extents)
+        want = _value_and_grads(
+            lambda x, top_w, *expert_w: _share_reference(
+                x, top_i, top_w, *expert_w), x, top_w, expert_w)
+        for a, b, c in zip(got, by_slots, want):
+            scale = max(1.0, float(jnp.abs(c).max()))
+            assert float(jnp.abs(c).max()) > 0
+            assert float(jnp.abs(a - b).max()) <= 2e-6 * scale
+            assert float(jnp.abs(a - c).max()) <= 1e-5 * scale
+        # token 1 has no expert here, token 0 all of its own
+        assert float(jnp.abs(got[0][1]).max()) == 0
+        assert float(jnp.abs(got[0][0]).max()) > 0
+
+    @pytest.mark.parametrize("weighted", [True, False])
+    def test_a_row_without_an_assignment_is_never_read(self, weighted):
+        """The rows behind the last group are undefined after a grouped
+        matmul: NaN planted there does not reach a token's sum, with
+        weights (the forward sum) or without (the backward one)."""
+        tokens, k, experts, held, bodies = SHARES["1of32"]
+        _, top_i, top_w, _, extents = _share_inputs("1of32", 0)
+        assert bodies[0] == "rows"
+        mine = np.asarray(top_i) < held
+        key = np.where(mine, top_i, held).reshape(-1)
+        order = np.argsort(key, kind="stable")
+        inverse = np.argsort(order)
+        used = int(mine.sum())
+        rows = np.random.default_rng(0).normal(
+            size=(extents[0], 64)).astype(np.float32)
+        want = np.zeros((tokens, 64), np.float32)
+        scale = np.where(mine, top_w, 0).astype(np.float32)
+        for row, assignment in enumerate(order[:used]):
+            want[assignment // k] += rows[row] * (
+                scale.reshape(-1)[assignment] if weighted else 1.0)
+        rows[used:] = np.nan
+        got = jax.jit(moe._sum_by_token)(
+            jnp.asarray(rows), jnp.asarray(order[:extents[0]], jnp.int32),
+            jnp.asarray(inverse.reshape(tokens, k), jnp.int32),
+            (jnp.arange(extents[0]) < used)[:, None],
+            *([jnp.asarray(scale)] if weighted else []))
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(got, want, atol=1e-5)
+
+    def test_the_body_of_every_extent_is_in_the_path(self, monkeypatch):
+        """``moe.path``'s ``combine=`` from the shapes alone: the slots at
+        the benchmark's ``ep=4`` shapes and on every ladder's last rung,
+        the rows at the first extents of a share of 1/32 (16 of 512
+        experts at 16,384 tokens x 2560; 10 of 320 at 8,192 x 4096) and
+        at the first extent alone of a share of 1/8 (16 of 128 at 2048
+        wide: 5/32 of its slots, the next extent 6/32)."""
+        from dlrover_tpu.observability import trace
+
+        records = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: records.append((name, attrs)))
+
+        def path(mesh_cfg, batch, seq, **fields):
+            cfg = MoELlamaConfig.tiny_moe(
+                num_layers=1, top_k=8, dtype=jnp.bfloat16, **fields)
+            mlp = MoEMLP(cfg)
+            x = jax.ShapeDtypeStruct((batch, seq, cfg.hidden_size),
+                                     jnp.bfloat16)
+            del records[:]
+            with _mesh(mesh_cfg):
+                variables = jax.eval_shape(
+                    mlp.init, jax.random.PRNGKey(0), x)
+                jax.eval_shape(mlp.apply, variables, x)
+            (attrs,) = {tuple(sorted(attrs.items()))
+                        for name, attrs in records if name == "moe.path"}
+            return dict(attrs)
+
+        olmoe = path(MeshConfig(ep=4), 8, 4096, hidden_size=2048,
+                     intermediate_size=1024, num_experts=64)
+        assert olmoe["extents"] == (20480, 24576, 32768, 65536)
+        assert olmoe["combine"] == "slots,slots,slots,slots"
+        ling = path(MeshConfig(dp=1), 1, 16384, hidden_size=2560,
+                    intermediate_size=768, num_experts=512, experts_held=16)
+        assert ling["extents"] == (5120, 6144, 8192, 131072)
+        assert ling["combine"] == "rows,rows,rows,slots"
+        solar = path(MeshConfig(dp=1), 1, 8192, hidden_size=4096,
+                     intermediate_size=1280, num_experts=320,
+                     experts_held=10)
+        assert solar["extents"] == (2560, 3072, 4096, 65536)
+        assert solar["combine"] == "rows,rows,rows,slots"
+        for tokens in (8192, 16384):        # Keye's cell, SDAR's
+            eighth = path(MeshConfig(dp=1), 1, tokens, hidden_size=2048,
+                          intermediate_size=768, num_experts=128,
+                          experts_held=16)
+            assert eighth["extents"][0] == tokens * 8 * 5 // 32
+            assert eighth["combine"] == "rows,slots,slots,slots"
+        # every expert on the one chip: one rung, the slots
+        assert path(MeshConfig(dp=1), 1, 64, num_experts=8)[
+            "combine"] == "slots"
+
+
 def _plain_rung(extent, x, weights, order, inverse, sizes, gate_w, up_w,
                 down_w):
     """A pass over the sorted rows as the parent of PR 46 wrote it, with
