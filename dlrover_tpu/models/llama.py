@@ -28,11 +28,12 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops.pallas.kept import LAYER_POLICY
@@ -88,9 +89,10 @@ class LlamaConfig:
     # logits and sows the others' loss (EvaByte's ``num_pred_heads``)
     pred_heads: int = 1
     # the kinds of layer of one period of the stack, repeated to
-    # ``num_layers``: ``"gqa"`` (this file's softmax attention), ``"kda"``
-    # (a gated delta-rule layer, ``DeltaAttention``) or ``"mla"`` (latent
-    # attention, ``LatentAttention``).  Empty: one kind, the softmax
+    # ``num_layers``: ``"gqa"`` (this file's softmax attention), ``"swa"``
+    # (the same under a causal window, with numbers of its own: below),
+    # ``"kda"`` (a gated delta-rule layer, ``DeltaAttention``) or ``"mla"``
+    # (latent attention, ``LatentAttention``).  Empty: one kind, the softmax
     # attention, and the parameter tree ``layers/layer`` as ever.  An entry
     # ``"<kind>:dense"`` is a layer of that kind whose feed-forward is the
     # dense SwiGLU of ``dense_intermediate_size`` whatever
@@ -105,6 +107,34 @@ class LlamaConfig:
     # (arXiv:2505.06708; Solar-Open2's ``use_gqa_gate``)
     use_rope: bool = True
     attn_gate: bool = False
+    # the same gate a HEAD: ``o_h * sigmoid(h w_g)_h``, ``w_g`` hidden x
+    # heads (that paper's head-wise form, ``mla_head_gate``'s), on ``gqa``
+    # and ``swa`` layers alike
+    attn_head_gate: bool = False
+    # a ``swa`` layer: this file's softmax attention in which a query sees
+    # itself and the ``sliding_window - 1`` positions before it, with
+    # ``swa_heads`` query heads (0: ``num_heads``) over the same
+    # ``num_kv_heads`` and plain RoPE over the whole head at base
+    # ``swa_rope_theta`` (0: ``rope_theta``).  A kind that differs from
+    # ``gqa`` only in numbers: ``attention_numbers``
+    sliding_window: int = 0
+    swa_heads: int = 0
+    swa_rope_theta: float = 0.0
+    # RoPE of a ``gqa`` layer on the FIRST ``partial_rotary_factor`` of each
+    # head's columns, the rest unrotated (Hugging Face's key of that name)
+    partial_rotary_factor: float = 1.0
+    # YaRN on a ``gqa`` layer's RoPE (arXiv:2309.00071 as ``transformers``'
+    # ``_compute_yarn_parameters`` has it; static, at every length): the
+    # frequencies of the rotary columns blended between ``f`` and ``f /
+    # yarn_factor`` by a ramp between the pairs that turn ``yarn_beta_fast``
+    # and ``yarn_beta_slow`` times in ``yarn_original_max_len`` positions,
+    # cos and sin times ``yarn_attention_factor`` (0: ``0.1 ln(factor) +
+    # 1``).  ``yarn_factor`` 0: none
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 0.0
     # a ``kda`` layer (Kimi Delta Attention, arXiv:2510.26692): heads of
     # ``kda_head_dim`` keys and values, a causal depthwise convolution of
     # ``kda_conv`` taps on q, k and v, ``kda_chunk`` positions a chunk of
@@ -184,6 +214,7 @@ class LlamaConfig:
                 % len(self.layer_pattern)
                 or ("kda" in kinds and not self.kda_heads)
                 or ("mla" in kinds and not self.mla_kv_rank)
+                or ("swa" in kinds and self.sliding_window < 1)
                 or (any(layer_kind(entry)[1] for entry in entries)
                     and not self.dense_intermediate_size)):
             raise ValueError(
@@ -192,7 +223,25 @@ class LlamaConfig:
                 f" kinds of {LAYER_KINDS}, a whole number of periods in "
                 f"num_layers={self.num_layers} less the prefix, kda_heads "
                 "where there is a kda layer, mla_kv_rank where there is an "
-                "mla layer and dense_intermediate_size where one is dense")
+                "mla layer, sliding_window where there is a swa layer and "
+                "dense_intermediate_size where one is dense")
+        if self.sliding_window and (
+                self.index_topk or self.eva_window or self.block_diffusion
+                or (self.swa_heads or self.num_heads) % self.num_kv_heads):
+            raise ValueError(
+                "sliding_window goes with no index_topk, eva_window or "
+                "block_diffusion (each makes a mask of its own that knows no "
+                "window), and swa_heads must be a multiple of num_kv_heads")
+        rotary = self.head_dim * self.partial_rotary_factor
+        if not 0 < self.partial_rotary_factor <= 1 or rotary % 2:
+            raise ValueError(
+                f"partial_rotary_factor={self.partial_rotary_factor!r} must "
+                "leave an even number of a head's columns, at least two")
+        if self.yarn_factor and (
+                self.yarn_factor < 1 or self.yarn_original_max_len < 1):
+            raise ValueError(
+                "yarn_factor needs a factor of at least 1 and "
+                "yarn_original_max_len")
         if self.block_diffusion and (
                 self.index_topk or self.eva_window or self.layer_pattern
                 or self.pred_heads > 1
@@ -217,6 +266,23 @@ class LlamaConfig:
             return {}
         return {"noise": jax.random.fold_in(
             jax.random.PRNGKey(self.noise_seed), step)}
+
+    def attention_numbers(self, kind: str) -> "AttentionNumbers":
+        """What a softmax layer of ``kind`` (``gqa`` or ``swa``) runs at:
+        the two kinds are one module and differ here alone."""
+        if kind == "swa":
+            return AttentionNumbers(
+                self.swa_heads or self.num_heads, self.sliding_window,
+                self.swa_rope_theta or self.rope_theta, self.head_dim, None)
+        yarn = None
+        if self.yarn_factor:
+            yarn = (self.yarn_factor, self.yarn_original_max_len,
+                    self.yarn_beta_fast, self.yarn_beta_slow,
+                    self.yarn_attention_factor
+                    or 0.1 * math.log(self.yarn_factor) + 1.0)
+        return AttentionNumbers(
+            self.num_heads, None, self.rope_theta,
+            int(self.head_dim * self.partial_rotary_factor), yarn)
 
     def layer_runs(self, entries=None):
         """One period (or ``entries``: the prefix) as runs of equal layers,
@@ -270,7 +336,21 @@ class LlamaConfig:
 
 
 #: the kinds a ``layer_pattern`` may name
-LAYER_KINDS = ("gqa", "kda", "mla")
+LAYER_KINDS = ("gqa", "kda", "mla", "swa")
+
+
+class AttentionNumbers(NamedTuple):
+    """``LlamaConfig.attention_numbers``: a softmax layer's own numbers."""
+
+    heads: int
+    #: positions a query sees, itself among them; ``None``: every earlier
+    window: Optional[int]
+    rope_theta: float
+    #: a head's leading columns that RoPE turns
+    rotary_dim: int
+    #: ``None`` or (factor, original length, beta_fast, beta_slow,
+    #: attention factor)
+    yarn: Optional[Tuple[float, int, float, float, float]]
 
 
 def layer_kind(entry: str):
@@ -280,13 +360,49 @@ def layer_kind(entry: str):
     return kind, ffn == "dense"
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary position embedding; x: [B, S, H, D]."""
+def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float):
+    """YaRN's ``dim // 2`` inverse frequencies, float64, with ``(low,
+    high)``, the pairs its ramp runs between: over the pairs ``i`` of
+    ``dim`` rotary columns, ``f_i = theta^(-2i/dim)``; a pair that turns
+    ``n`` times in ``original`` positions lies at ``dim ln(original / (2 pi
+    n)) / (2 ln theta)``; ``low`` is ``beta_fast`` turns' pair rounded
+    down, ``high`` ``beta_slow`` turns' rounded up; ``ramp_i = clip((i -
+    low) / (high - low), 0, 1)``; the frequency is ``f_i (1 - ramp_i) +
+    (f_i / factor) ramp_i``: fast pairs as they were, slow pairs
+    stretched."""
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return freq * (1 - ramp) + freq / factor * ramp, (low, high)
+
+
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+          rotary_dim: Optional[int] = None, yarn=None) -> jnp.ndarray:
+    """Rotary position embedding; x: [B, S, H, D].  ``rotary_dim``: the
+    head's leading columns that turn (halves convention within them), the
+    rest pass as they are; ``yarn`` (``AttentionNumbers.yarn``): YaRN's
+    frequencies, cos and sin both times its attention factor."""
     d = x.shape[-1]
-    freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if rotary_dim is not None and rotary_dim < d:
+        turned = _rope(x[..., :rotary_dim], positions, theta, None, yarn)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
+    if yarn is None:
+        freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        freq = jnp.asarray(
+            yarn_frequencies(d, theta, *yarn[:4])[0], jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freq  # [B, S, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -340,10 +456,14 @@ class RMSNorm(nn.Module):
 
 class Attention(nn.Module):
     config: LlamaConfig
+    #: ``"gqa"`` or ``"swa"``: whose numbers of the configuration it runs
+    #: at (``LlamaConfig.attention_numbers``)
+    kind: str = "gqa"
 
     @nn.compact
     def __call__(self, x, positions, mask):
         cfg = self.config
+        own = cfg.attention_numbers(self.kind)
         dense = partial(
             nn.DenseGeneral,
             use_bias=False,
@@ -351,7 +471,7 @@ class Attention(nn.Module):
             param_dtype=cfg.param_dtype,
         )
         q = dense(
-            features=(cfg.num_heads, cfg.head_dim),
+            features=(own.heads, cfg.head_dim),
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("embed", "heads", "head_dim")
             ),
@@ -390,8 +510,8 @@ class Attention(nn.Module):
         v = nn.with_logical_constraint(v, ("batch", "seq", "kv_heads", "head_dim"))
 
         if cfg.use_rope:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = _rope(q, positions, own.rope_theta, own.rotary_dim, own.yarn)
+            k = _rope(k, positions, own.rope_theta, own.rotary_dim, own.yarn)
 
         # ``attn.core``: from q, k, v to the attention's output (the kind
         # table of ``observability/trace.py``); what is left under ``attn``
@@ -406,12 +526,24 @@ class Attention(nn.Module):
 
             with jax.named_scope("attn.core"):
                 out = block_diffusion_attention(q, k, v, cfg.block_diffusion)
+        elif own.window is not None:
+            # the sub-scope too stands around ``_attend``
+            with jax.named_scope("attn.core"), jax.named_scope("window"):
+                out = self._attend(q, k, v, mask, own.window)
         else:
             with jax.named_scope("attn.core"):
                 out = self._attend(q, k, v, mask)
+        if cfg.attn_head_gate:
+            gate = dense(
+                features=own.heads,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", "heads")),
+                name="head_gate_proj",
+            )(x)
+            out = out * nn.sigmoid(gate)[..., None]
         if cfg.attn_gate:
             gate = dense(
-                features=(cfg.num_heads, cfg.head_dim),
+                features=(own.heads, cfg.head_dim),
                 kernel_init=nn.with_logical_partitioning(
                     nn.initializers.lecun_normal(),
                     ("embed", "heads", "head_dim")),
@@ -493,16 +625,23 @@ class Attention(nn.Module):
         self.sow("stats", "eva_pool_weight_max", pool_weight)
         return out
 
-    def _attend(self, q, k, v, mask):
+    def _attend(self, q, k, v, mask, window=None):
         cfg = self.config
         if cfg.attention_impl == "flash":
             from dlrover_tpu.ops.attention import flash_attention
 
             # what the layer does around the kernel, on the kernel's line
             around = {**({} if cfg.use_rope else {"rope": "none"}),
-                      **({"gate": "sigmoid"} if cfg.attn_gate else {})}
-            return flash_attention(q, k, v, causal=True, path_attrs=around)
+                      **({"gate": "sigmoid"} if cfg.attn_gate else {}),
+                      **({"gate": "sigmoid_a_head"}
+                         if cfg.attn_head_gate else {})}
+            return flash_attention(q, k, v, causal=True, path_attrs=around,
+                                   window=window)
         if cfg.attention_impl == "ring":
+            if window is not None:
+                raise NotImplementedError(
+                    "attention_impl='ring' knows no window: a swa layer "
+                    "runs under 'flash' or 'reference'")
             # NOTE: the ring path is causal-only; the surrounding model
             # always builds a causal mask, and any future padding mask
             # must extend ring_attention before being honored here.
@@ -526,7 +665,7 @@ class Attention(nn.Module):
             return reference_attention(q, k, v, mask)
         from dlrover_tpu.ops.attention import reference_attention
 
-        return reference_attention(q, k, v, mask)
+        return reference_attention(q, k, v, mask, window)
 
 
 def _kda_decay_init(low, high):
@@ -820,7 +959,7 @@ class MLP(nn.Module):
 
 #: the module at ``attn`` by the layer's kind
 ATTENTION_OF = {"gqa": Attention, "kda": DeltaAttention,
-                "mla": LatentAttention}
+                "mla": LatentAttention, "swa": partial(Attention, kind="swa")}
 
 
 class DecoderLayer(nn.Module):
@@ -996,7 +1135,7 @@ class LlamaForCausalLM(nn.Module):
         mask = None if (
             cfg.index_topk or cfg.eva_window or cfg.attention_impl == "flash"
             or cfg.block_diffusion
-            or (cfg.layer_pattern and "gqa" not in {
+            or (cfg.layer_pattern and not {"gqa", "swa"} & {
                 layer_kind(entry)[0]
                 for entry in cfg.layer_prefix + cfg.layer_pattern})
         ) else jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
@@ -1077,22 +1216,29 @@ class LlamaForCausalLM(nn.Module):
 
     def num_params(self) -> int:
         cfg = self.config
-        attn = cfg.hidden_size * cfg.head_dim * (
-            cfg.num_heads * 2 + cfg.num_kv_heads * 2
-        )
-        if cfg.qk_norm == "head":
-            attn += 2 * cfg.head_dim
-        elif cfg.qk_norm:
-            attn += cfg.head_dim * (cfg.num_heads + cfg.num_kv_heads)
-        if cfg.index_topk:      # q, k and weight projections, the LayerNorm
-            attn += cfg.hidden_size * (
-                cfg.index_heads * (cfg.index_head_dim + 1)
-                + cfg.index_head_dim) + 2 * cfg.index_head_dim
-        if cfg.eva_window:      # the two pooling vectors a head
-            attn += 2 * cfg.num_heads * cfg.head_dim
-        if cfg.attn_gate:       # the output gate's projection
-            attn += cfg.hidden_size * cfg.num_heads * cfg.head_dim
-        by_kind = {"gqa": lambda: attn,
+        def softmax(kind):
+            heads = cfg.attention_numbers(kind).heads
+            attn = cfg.hidden_size * cfg.head_dim * (
+                heads * 2 + cfg.num_kv_heads * 2
+            )
+            if cfg.qk_norm == "head":
+                attn += 2 * cfg.head_dim
+            elif cfg.qk_norm:
+                attn += cfg.head_dim * (heads + cfg.num_kv_heads)
+            if cfg.index_topk:  # q, k and weight projections, the LayerNorm
+                attn += cfg.hidden_size * (
+                    cfg.index_heads * (cfg.index_head_dim + 1)
+                    + cfg.index_head_dim) + 2 * cfg.index_head_dim
+            if cfg.eva_window:      # the two pooling vectors a head
+                attn += 2 * heads * cfg.head_dim
+            if cfg.attn_gate:       # the output gate's projection
+                attn += cfg.hidden_size * heads * cfg.head_dim
+            if cfg.attn_head_gate:  # one column a head
+                attn += cfg.hidden_size * heads
+            return attn
+
+        by_kind = {"gqa": lambda: softmax("gqa"),
+                   "swa": lambda: softmax("swa"),
                    "kda": lambda: DeltaAttention.num_params(cfg),
                    "mla": lambda: LatentAttention.num_params(cfg)}
 

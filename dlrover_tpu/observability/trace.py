@@ -508,7 +508,11 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
                   "bd_keys", "bd_clean", "bd_noisy",
                   # the core of latent attention (``ops/attention.py::
                   # latent_attention``)
-                  "latent"),
+                  "latent",
+                  # a softmax layer under a causal window (``models/
+                  # llama.py::Attention`` of kind ``swa``): the FA2 kernels
+                  # with ``window``, or the reference core under the band
+                  "window"),
     # what latent attention adds around its core (``models/llama.py::
     # LatentAttention``): the latent's projections, its norm, the rotary
     # part.  The one name two kinds have: a kind each, and a path that

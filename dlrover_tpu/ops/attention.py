@@ -9,7 +9,11 @@ interpreter by argument; under a mesh of several devices it runs the kernel
 per shard through ``shard_map`` (a Mosaic kernel cannot be partitioned
 automatically).  ``causal_attention`` is the one place that chooses between
 the two for a model that has no opinion (the GPT family): from the backend
-and the shape, once, while the step is traced.
+and the shape, once, while the step is traced.  All four take a causal
+``window`` (a query sees itself and the ``window - 1`` positions before
+it; ``None``: every earlier key): the kernel masks by position and visits
+only the blocks the band touches, the reference core applies the same band
+to its mask.
 ``indexed_sparse_attention`` (at the end) is the attention of a model
 whose configuration carries an indexer: each query attends to the keys a
 learned scorer ranks highest, by blocks of queries: on a TPU the index
@@ -45,12 +49,18 @@ def reference_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     mask: Optional[jnp.ndarray] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Plain attention; q,k,v: [B, S, H, D] (k/v heads may be fewer: GQA).
 
     fp32 logits + softmax regardless of input dtype; mask is broadcastable
-    to [B, H, Sq, Sk] with True = attend.
+    to [B, H, Sq, Sk] with True = attend.  ``window``: self-attention in
+    which query ``t`` sees the keys ``t - window < s <= t`` alone, whatever
+    else the mask allows.
     """
+    if window is not None:
+        mask = band_mask(q.shape[1], window) if mask is None else (
+            mask & band_mask(q.shape[1], window))
     if k.shape[2] != q.shape[2]:
         groups = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, groups, axis=2)
@@ -63,6 +73,12 @@ def reference_attention(
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def band_mask(seq_len: int, window: int) -> jnp.ndarray:
+    """[1, 1, S, S] bool: the causal band of ``window`` positions."""
+    ahead = jnp.arange(seq_len)[:, None] - jnp.arange(seq_len)[None, :]
+    return ((ahead >= 0) & (ahead < window))[None, None]
 
 
 def _flash_shard_specs(mesh):
@@ -90,11 +106,16 @@ def flash_attention(
     block_kv: int = 0,
     interpret: bool = False,
     path_attrs: Optional[dict] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention: the Pallas TPU kernel, never the reference.
 
     Block sizes default to the autotuned table (``ops/pallas/tuning.py``)
-    for this (seq_len, head_dim); pass explicit values to override.
+    for this (seq_len, head_dim), a windowed call's under a key of its own;
+    pass explicit values to override.  ``window`` (causal calls): the band
+    a query sees; the record then also says how many key blocks a query
+    block visits at most and the pairs a head's forward pass multiplies
+    beside those the band allows.
     ``path_attrs``: what the caller does around the kernel (``rope=none``,
     ``gate=sigmoid``), written at the end of the ``attention.path`` line.
     ``interpret=True`` runs the kernel in the Pallas interpreter (tests off
@@ -109,6 +130,8 @@ def flash_attention(
             "off the chip, or pass interpret=True"
         )
     from dlrover_tpu.ops.pallas.flash_attention import (
+        band_pairs,
+        band_steps,
         heads_per_block,
         pallas_flash_attention,
     )
@@ -117,20 +140,29 @@ def flash_attention(
 
     seq_len, heads, head_dim = q.shape[1:]
     if not block_q or not block_kv:
-        tuned_q, tuned_kv = tuned_blocks(seq_len, head_dim)
+        tuned_q, tuned_kv = tuned_blocks(seq_len, head_dim, window)
         block_q = block_q or tuned_q
         block_kv = block_kv or tuned_kv
+    banded = {}
+    if window is not None:
+        multiplied, allowed = band_pairs(seq_len, block_q, block_kv, window)
+        banded = dict(
+            window=window,
+            kv_blocks_visited=band_steps(
+                seq_len, block_q, block_kv, window)[0],
+            pairs_multiplied=multiplied, pairs_allowed=allowed)
     # ``layout``: the kernels read and write [B, S, H*D] in column blocks
     # of 128 lanes, ``heads_per_block`` heads in each
     trace.note_trace_time(
         "attention.path", impl="flash", seq=seq_len, head_dim=head_dim,
         heads=heads, blocks=(block_q, block_kv), layout="bsd",
-        heads_per_block=heads_per_block(head_dim), **(path_attrs or {}),
+        heads_per_block=heads_per_block(head_dim), **banded,
+        **(path_attrs or {}),
     )
 
     def kernel(q_, k_, v_):
         return pallas_flash_attention(
-            q_, k_, v_, causal, block_q, block_kv, interpret
+            q_, k_, v_, causal, block_q, block_kv, interpret, window
         )
 
     mesh = active_mesh()
@@ -163,31 +195,37 @@ def flash_attention(
 
 
 def attention_path(backend: str, seq_len: int, head_dim: int, heads: int,
-                   kv_heads: int) -> str:
+                   kv_heads: int, window: Optional[int] = None) -> str:
     """``"flash"`` or ``"reference"``: the kernel wherever it can run, from
     what the code can observe and nothing else."""
     from dlrover_tpu.ops.pallas.flash_attention import kernel_takes
 
-    if backend == "tpu" and kernel_takes(seq_len, head_dim, heads, kv_heads):
+    if backend == "tpu" and kernel_takes(seq_len, head_dim, heads, kv_heads,
+                                         window):
         return "flash"
     return "reference"
 
 
 def causal_attention(
-    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mask: jnp.ndarray
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mask: jnp.ndarray,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Causal self-attention, q/k/v: [B, S, H, D]: the FA2 kernel on a TPU
     at a shape it takes (no [B, H, S, S] tensor in HBM, forward or
     backward), the reference core with the caller's causal ``mask``
-    everywhere else.  Both compute float32 scores and softmax from the
-    operands as given and accumulate in float32."""
+    everywhere else, both under the causal ``window`` where one is given.
+    Both compute float32 scores and softmax from the operands as given and
+    accumulate in float32."""
     seq_len, heads, head_dim = q.shape[1:]
     if attention_path(jax.default_backend(), seq_len, head_dim, heads,
-                      k.shape[2]) == "flash":
-        return flash_attention(q, k, v, causal=True)  # writes the record
-    trace.note_trace_time("attention.path", impl="reference", seq=seq_len,
-                          head_dim=head_dim, heads=heads, blocks=None)
-    return reference_attention(q, k, v, mask)
+                      k.shape[2], window) == "flash":
+        # writes the record
+        return flash_attention(q, k, v, causal=True, window=window)
+    trace.note_trace_time(
+        "attention.path", impl="reference", seq=seq_len, head_dim=head_dim,
+        heads=heads, blocks=None,
+        **({} if window is None else {"window": window}))
+    return reference_attention(q, k, v, mask, window)
 
 
 # --------------------------------------------------------------------------

@@ -30,6 +30,18 @@ multiply by zero, keeps them out).
 
 Grids are sequential on TPU, so VMEM scratch carries accumulators across
 the innermost dimension.  Causal masking skips fully-masked blocks.
+
+A causal ``window`` (a query at ``t`` sees the keys ``t - window < s <=
+t``: itself and the ``window - 1`` before it) is a second condition of
+the mask, made in the kernel from positions, and it SHORTENS the streamed
+axis of every grid: a resident block's band touches few blocks of the
+other kind, so the axis has only as many steps as the widest band needs
+(``band_steps``), a step counts from the band's first block
+(``_first_kv_block``; in dK/dV from the causal first q block) through the
+index maps, which clamp as the causal ones do, and a step past the band's
+last block or the sequence's edge is skipped.  At 16,384 positions, a
+window of 512 and blocks of 512 a query block visits 2 key blocks of 32.
+``window=None`` is the causal kernel as it was, the same program.
 """
 
 import functools
@@ -70,12 +82,15 @@ def heads_per_block(head_dim: int) -> int:
 
 
 def kernel_takes(seq_len: int, head_dim: int, heads: int,
-                 kv_heads: int) -> bool:
+                 kv_heads: int, window=None) -> bool:
     """Whether the kernel runs causal self-attention at this shape.  Any
-    head count goes, odd ones too, as long as the kv heads divide it."""
+    head count goes, odd ones too, as long as the kv heads divide it; so
+    does any causal ``window`` of at least one position (``None``: every
+    earlier key), under, at or over a block and the sequence."""
     return (head_dim in KERNEL_HEAD_DIMS
             and seq_len % KERNEL_MIN_BLOCK == 0
-            and heads % kv_heads == 0)
+            and heads % kv_heads == 0
+            and (window is None or window >= 1))
 
 
 def _compiler_params(per_block: int):
@@ -149,9 +164,9 @@ def _each_head(q_head_block, heads: int, per_block: int, q_head_blocks: int,
 
 
 def _masked_scores(q, k, scale, causal, q_start, kv_start, block_q,
-                   block_kv):
+                   block_kv, window=None):
     """The one numerical core shared by forward and both backward
-    kernels: fp32 scores with the causal mask applied."""
+    kernels: fp32 scores with the causal mask, and the window's, applied."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -163,7 +178,10 @@ def _masked_scores(q, k, scale, causal, q_start, kv_start, block_q,
         cols = kv_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_kv), 1
         )
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        allowed = rows >= cols
+        if window is not None:
+            allowed &= rows - cols < window
+        s = jnp.where(allowed, s, NEG_INF)
     return s
 
 
@@ -175,16 +193,17 @@ def _masked_scores(q, k, scale, causal, q_start, kv_start, block_q,
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, out_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, block_q: int, block_kv: int, causal: bool, scale: float,
-    head_dim: int, heads: int, groups: int,
+    head_dim: int, heads: int, groups: int, window=None,
 ):
     head_block = pl.program_id(1)
     q_idx = pl.program_id(2)
-    kv_idx = pl.program_id(3)
-    num_kv = pl.num_programs(3)
+    step = pl.program_id(3)
+    # under a window the streamed axis counts from the band's first block
+    kv_idx = _streamed_kv_block(q_idx, step, block_q, block_kv, window)
     width = acc_ref.shape[-1]
     per_block = width // head_dim
 
-    @pl.when(kv_idx == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -193,6 +212,10 @@ def _flash_fwd_kernel(
     q_start = q_idx * block_q
     kv_start = kv_idx * block_kv
 
+    # a block the window masks whole for SOME of its rows, before their
+    # first live one, leaves them a running maximum of NEG_INF and sums of
+    # exp(0): the first live block's correction, exp(NEG_INF - m), is 0
+    # and wipes both (every row has a live key, itself)
     needed = jnp.logical_or(
         jnp.logical_not(causal), kv_start <= q_start + block_q - 1
     )
@@ -203,7 +226,7 @@ def _flash_fwd_kernel(
         k = _head_tile(k_ref, kv_at, i, head_dim)
         v = _head_tile(v_ref, kv_at, i, head_dim)
         s = _masked_scores(q, k, scale, causal, q_start, kv_start,
-                           block_q, block_kv)
+                           block_q, block_kv, window)
 
         m_prev = m_ref[i, :, :1]
         l_prev = l_ref[i, :, :1]
@@ -228,7 +251,7 @@ def _flash_fwd_kernel(
         _each_head(head_block, heads, per_block, pl.num_programs(1),
                    one_head)
 
-    @pl.when(kv_idx == num_kv - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _finalize():
         out = None
         for i in range(per_block):
@@ -253,6 +276,54 @@ def _first_q_block(kv_block, block_q: int, block_kv: int):
     return (kv_block * block_kv) // block_q
 
 
+def _first_kv_block(q_block, block_q: int, block_kv: int, window: int):
+    """The first kv block the band of a q block touches: the one that
+    holds the first key of the block's first query."""
+    return jnp.maximum(q_block * block_q - (window - 1), 0) // block_kv
+
+
+def _last_q_block(kv_block, block_q: int, block_kv: int, window: int,
+                  num_q: int):
+    """The last q block whose band touches a kv block: the one that holds
+    the last query of the block's last key, or the sequence's last."""
+    return jnp.minimum(
+        ((kv_block + 1) * block_kv + window - 2) // block_q, num_q - 1)
+
+
+def _streamed_kv_block(q_block, step, block_q: int, block_kv: int, window):
+    """The kv block of a grid step of a resident q block."""
+    if window is None:
+        return step
+    return _first_kv_block(q_block, block_q, block_kv, window) + step
+
+
+def _kv_blocks_visited(seq_len: int, block_q: int, block_kv: int,
+                       window: int):
+    """The kv blocks each q block's band touches, a count a q block."""
+    return [((i + 1) * block_q - 1) // block_kv
+            - max(i * block_q - (window - 1), 0) // block_kv + 1
+            for i in range(seq_len // block_q)]
+
+
+def band_steps(seq_len: int, block_q: int, block_kv: int, window: int):
+    """``(kv blocks a q block's band touches at most, q blocks a kv
+    block's)``: the lengths of the streamed axes under a causal window."""
+    num_q = seq_len // block_q
+    q_steps = max(
+        min(((j + 1) * block_kv + window - 2) // block_q, num_q - 1)
+        - (j * block_kv) // block_q + 1
+        for j in range(seq_len // block_kv))
+    return max(_kv_blocks_visited(seq_len, block_q, block_kv, window)), q_steps
+
+
+def band_pairs(seq_len: int, block_q: int, block_kv: int, window: int):
+    """``(multiplied, allowed)`` query-key pairs of one head's forward
+    pass: the scores of every block a q block visits, and the band's."""
+    visited = sum(_kv_blocks_visited(seq_len, block_q, block_kv, window))
+    w = min(window, seq_len)
+    return visited * block_q * block_kv, seq_len * w - w * (w - 1) // 2
+
+
 def _checked_blocks(seq_len: int, block_q: int, block_kv: int):
     block_q = min(block_q, seq_len)
     block_kv = min(block_kv, seq_len)
@@ -265,8 +336,13 @@ def _checked_blocks(seq_len: int, block_q: int, block_kv: int):
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
-                   interpret: bool = False, with_residuals: bool = False):
+                   interpret: bool = False, with_residuals: bool = False,
+                   window=None):
     """q: [B, S, H, D]; k/v: [B, S, H_kv, D] (GQA via KV index mapping)."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal attention and at least one "
+            "position")
     B, S, H, D = q.shape
     H_kv = k.shape[2]
     if H % H_kv:
@@ -280,9 +356,14 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
         return b, i, h
 
     def kv_index(b, h, i, j):
+        j = _streamed_kv_block(i, j, block_q, block_kv, window)
         if causal:  # a masked step asks for the block it already has
             j = jnp.minimum(j, _last_kv_block(i, block_q, block_kv))
         return b, j, h // groups
+
+    kv_steps = S // block_kv
+    if window is not None:
+        kv_steps, _ = band_steps(S, block_q, block_kv, window)
 
     kernel = functools.partial(
         _flash_fwd_kernel,
@@ -293,6 +374,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
         head_dim=D,
         heads=H,
         groups=groups,
+        window=window,
     )
     if with_residuals:
         # lane-broadcast residual: [B, H, S, LANES] (see LANES)
@@ -304,7 +386,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
         lse_spec, lse_shape = None, None
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B, pl.cdiv(H, per_block), S // block_q, S // block_kv),
+        grid=(B, pl.cdiv(H, per_block), S // block_q, kv_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, width), q_index),
             pl.BlockSpec((1, block_kv, width), kv_index),
@@ -339,12 +421,12 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
 
 
 def _recomputed(q, k, v, do, o, lse, scale, causal, q_start, kv_start,
-                block_q, block_kv):
+                block_q, block_kv, window=None):
     """``(p, ds)`` of one head from its tiles, all on the same lanes, and
     its LSE ``[block_q, 1]``."""
     delta = jnp.sum(do * o, axis=-1, keepdims=True)
     s = _masked_scores(q, k, scale, causal, q_start, kv_start,
-                       block_q, block_kv)
+                       block_q, block_kv, window)
     p = jnp.exp(s - lse)  # exact probabilities via saved LSE
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -356,15 +438,15 @@ def _recomputed(q, k, v, do, o, lse, scale, causal, q_start, kv_start,
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, acc_ref,
     *, block_q: int, block_kv: int, causal: bool, scale: float,
-    head_dim: int, heads: int, groups: int,
+    head_dim: int, heads: int, groups: int, window=None,
 ):
     head_block = pl.program_id(1)
     q_idx = pl.program_id(2)
-    kv_idx = pl.program_id(3)
-    num_kv = pl.num_programs(3)
+    step = pl.program_id(3)
+    kv_idx = _streamed_kv_block(q_idx, step, block_q, block_kv, window)
     per_block = acc_ref.shape[-1] // head_dim
 
-    @pl.when(kv_idx == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -383,7 +465,7 @@ def _flash_bwd_dq_kernel(
         v = _head_tile(v_ref, kv_at, i, head_dim)
         _, ds = _recomputed(
             q, k, v, do, o, lse_ref[0, i, :, :1], scale, causal, q_start,
-            kv_start, block_q, block_kv)
+            kv_start, block_q, block_kv, window)
         acc_ref[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -394,7 +476,7 @@ def _flash_bwd_dq_kernel(
         _each_head(head_block, heads, per_block, pl.num_programs(1),
                    one_head)
 
-    @pl.when(kv_idx == num_kv - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _finalize():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -403,13 +485,18 @@ def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
     *, block_q: int, block_kv: int, causal: bool, scale: float,
-    head_dim: int, heads: int, groups: int, num_q: int,
+    head_dim: int, heads: int, groups: int, num_q: int, window=None,
+    seq_q_blocks: int = 0,
 ):
     # the streamed axis walks the q blocks of every q head-block whose kv
-    # heads lie in this kv block, so a GQA group is summed here
+    # heads lie in this kv block, so a GQA group is summed here; under a
+    # window ``num_q`` is the q blocks a kv block's band touches at most,
+    # counted from the causal first one, of ``seq_q_blocks`` in all
     kv_idx = pl.program_id(2)
     q_head_block = pl.program_id(1) * groups + pl.program_id(3) // num_q
     q_idx = pl.program_id(3) % num_q
+    if window is not None:
+        q_idx += _first_q_block(kv_idx, block_q, block_kv)
     per_block = dk_acc.shape[-1] // head_dim
 
     @pl.when(pl.program_id(3) == 0)
@@ -422,6 +509,9 @@ def _flash_bwd_dkv_kernel(
     needed = jnp.logical_or(
         jnp.logical_not(causal), kv_start <= q_start + block_q - 1
     )
+    if window is not None:  # past the band's last q block, or the edge
+        needed &= q_idx <= _last_q_block(
+            kv_idx, block_q, block_kv, window, seq_q_blocks)
 
     def one_head(i):
         # everything on the kv head's lanes: dK and dV land where k lies
@@ -432,7 +522,7 @@ def _flash_bwd_dkv_kernel(
         v = _head_tile(v_ref, kv_at, kv_at, head_dim)
         p, ds = _recomputed(
             q, k, v, do, o, lse_ref[0, i, :, :1], scale, causal, q_start,
-            kv_start, block_q, block_kv)
+            kv_start, block_q, block_kv, window)
         # dV += P^T dO
         dv_acc[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -456,7 +546,7 @@ def _flash_bwd_dkv_kernel(
 
 
 def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
-                    interpret):
+                    interpret, window=None):
     """q, out, do: [B, S, H, D]; k, v: [B, S, H_kv, D]; lse:
     [B, H, S, LANES].  Returns (dq, dk, dv) in the shapes of (q, k, v):
     the dK/dV kernel sums a GQA group itself."""
@@ -471,7 +561,12 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
     operands = [x.reshape(B, S, -1) for x in (q, k, v, grad_out, out)]
     operands.append(lse)
     settings = dict(block_q=block_q, block_kv=block_kv, causal=causal,
-                    scale=D ** -0.5, head_dim=D, heads=H, groups=groups)
+                    scale=D ** -0.5, head_dim=D, heads=H, groups=groups,
+                    window=window)
+    # the streamed axes: every block, or the widest band's under a window
+    kv_steps, q_steps = num_kv, num_q
+    if window is not None:
+        kv_steps, q_steps = band_steps(S, block_q, block_kv, window)
 
     def in_specs(where):
         """Block specs of (q, k, v, do, o, lse) from ``where``, which gives
@@ -499,6 +594,7 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
 
     # dq grid: q blocks resident, kv blocks streamed
     def dq_step(b, h, i, j):
+        j = _streamed_kv_block(i, j, block_q, block_kv, window)
         if causal:
             j = jnp.minimum(j, _last_kv_block(i, block_q, block_kv))
         return b, i, j, h, h // groups
@@ -506,7 +602,7 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
     specs = in_specs(dq_step)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **settings),
-        grid=(B, q_head_blocks, num_q, num_kv),
+        grid=(B, q_head_blocks, num_q, kv_steps),
         in_specs=specs,
         out_specs=specs[0],
         out_shape=jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
@@ -520,16 +616,21 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
     def dkv_step(b, h_kv, j, x):
         # an odd head count: the last kv head-block's last q head-block
         # may not be there (the kernel skips it), so ask for none past it
-        h = jnp.minimum(h_kv * groups + x // num_q, q_head_blocks - 1)
-        i = x % num_q
-        if causal:
+        h = jnp.minimum(h_kv * groups + x // q_steps, q_head_blocks - 1)
+        i = x % q_steps
+        if window is not None:
+            i = jnp.minimum(
+                i + _first_q_block(j, block_q, block_kv),
+                _last_q_block(j, block_q, block_kv, window, num_q))
+        elif causal:
             i = jnp.maximum(i, _first_q_block(j, block_q, block_kv))
         return b, i, j, h, h_kv
 
     specs = in_specs(dkv_step)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, num_q=num_q, **settings),
-        grid=(B, pl.cdiv(H_kv, per_block), num_kv, groups * num_q),
+        functools.partial(_flash_bwd_dkv_kernel, num_q=q_steps,
+                          seq_q_blocks=num_q, **settings),
+        grid=(B, pl.cdiv(H_kv, per_block), num_kv, groups * q_steps),
         in_specs=specs,
         out_specs=[specs[1], specs[1]],
         out_shape=[jax.ShapeDtypeStruct((B, S, H_kv * D), k.dtype),
@@ -549,23 +650,27 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def pallas_flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
-                           block_kv: int = 512, interpret: bool = False):
-    return _flash_forward(q, k, v, causal, block_q, block_kv, interpret)
+                           block_kv: int = 512, interpret: bool = False,
+                           window=None):
+    return _flash_forward(q, k, v, causal, block_q, block_kv, interpret,
+                          window=window)
 
 
-def _fwd(q, k, v, causal, block_q, block_kv, interpret):
+def _fwd(q, k, v, causal, block_q, block_kv, interpret, window):
     out, lse = _flash_forward(
-        q, k, v, causal, block_q, block_kv, interpret, with_residuals=True
+        q, k, v, causal, block_q, block_kv, interpret, with_residuals=True,
+        window=window,
     )
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, block_q, block_kv, interpret, residuals, grad_out):
+def _bwd(causal, block_q, block_kv, interpret, window, residuals, grad_out):
     q, k, v, out, lse = residuals
     return _flash_backward(
-        q, k, v, out, lse, grad_out, causal, block_q, block_kv, interpret
+        q, k, v, out, lse, grad_out, causal, block_q, block_kv, interpret,
+        window,
     )
 
 

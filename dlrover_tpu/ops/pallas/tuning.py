@@ -78,8 +78,15 @@ def _load_table() -> Dict:
     return table
 
 
-def _key(seq_len: int, head_dim: int) -> str:
-    return f"s{seq_len}_d{head_dim}"
+def _shape_suffix(head_dim: int, window: Optional[int] = None) -> str:
+    """What a key ends in: the head size and, of a windowed call, the
+    window.  Such entries stand apart (``s16384_d128_w512``): the grid is
+    another shape, and no causal call's blocks move for them."""
+    return f"_d{head_dim}" + ("" if window is None else f"_w{window}")
+
+
+def _key(seq_len: int, head_dim: int, window: Optional[int] = None) -> str:
+    return f"s{seq_len}" + _shape_suffix(head_dim, window)
 
 
 def _shrink_to_divisor(seq_len: int, block: int) -> int:
@@ -100,10 +107,12 @@ def _entry_blocks(entry) -> Optional[Tuple[int, int]]:
     return block_q, block_kv
 
 
-def tuned_blocks(seq_len: int, head_dim: int) -> Tuple[int, int]:
+def tuned_blocks(seq_len: int, head_dim: int,
+                 window: Optional[int] = None) -> Tuple[int, int]:
     """Best-known (block_q, block_kv) for this shape: exact table hit,
     else the entry with the nearest sequence length at the same head
-    dim, else the untuned default.  A malformed table (hand-edited)
+    dim (and the same window, or none), else the untuned default.  A
+    malformed table (hand-edited)
     must degrade to the default, never crash the forward pass —
     same fail-safe contract as ``_load_one``."""
     fallback = (
@@ -112,7 +121,8 @@ def tuned_blocks(seq_len: int, head_dim: int) -> Tuple[int, int]:
     )
     try:
         table = _load_table()
-        blocks = _entry_blocks(table.get(_key(seq_len, head_dim)) or {})
+        blocks = _entry_blocks(
+            table.get(_key(seq_len, head_dim, window)) or {})
         if blocks:
             return (
                 _shrink_to_divisor(seq_len, blocks[0]),
@@ -120,7 +130,7 @@ def tuned_blocks(seq_len: int, head_dim: int) -> Tuple[int, int]:
             )
         same_dim = []
         for k, v in table.items():
-            if not k.endswith(f"_d{head_dim}"):
+            if not k.endswith(_shape_suffix(head_dim, window)):
                 continue
             try:
                 dist = abs(int(k.split("_")[0][1:]) - seq_len)

@@ -157,6 +157,34 @@ class TestFlashAttentionKernel:
         assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize(
+    "heads, blocks, window", [(64, None, 512), (64, (256, 256), 512),
+                              (64, (1024, 512), 512), (48, None, None)],
+    ids=["window_512_shipped_blocks", "window_512_blocks_256",
+         "window_512_blocks_1024_512", "full_groups_of_6"])
+def test_windowed_fa2_kernels_compile_at_the_cells_shape(
+        one_chip, heads, blocks, window):
+    """The Laguna cell's two kinds of call at one sequence of 16,384 on 8
+    key heads: 64 query heads under a window of 512 (the streamed axis has
+    the band's blocks alone) and 48 causal ones (groups of 6, a count no
+    other cell has).  Three custom calls, nothing ``[S, S]``."""
+    S = 16384
+    q = jax.ShapeDtypeStruct((1, S, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, S, 8, 128), jnp.bfloat16, sharding=one_chip)
+    block_q, block_kv = blocks or tuned_blocks(S, 128, window)
+
+    def loss(q, k, v):
+        out = pallas_flash_attention(
+            q, k, v, True, block_q, block_kv, False, window)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert len(_kernel_names(text)) == 3
+    assert not re.search(r"\[[\d,]*16384,16384[\d,]*\]", text)
+
+
 @pytest.mark.parametrize("keys", [512, 2560, 8192])
 def test_selected_attention_kernels_compile_and_the_reader_sees_them(
         one_chip, keys):
@@ -616,6 +644,63 @@ class TestTrainerStep:
         assert 7.7e9 < mem.argument_size_in_bytes < 7.8e9
         assert mem.temp_size_in_bytes <= (
             9_214_244_864 + 3 * 176_685_056 + 4 * 13_107_200)
+
+    def test_laguna_widths_the_dense_layer_and_one_period(
+            self, topo, as_if_on_tpu, monkeypatch):
+        """The Laguna cell's configuration file through its family at B1
+        S4096 (a quarter of the cell's length, for the test's time; the
+        cell's own 16,384 is ``benchmarks/tests/compile_described.py
+        laguna_xs2_33b_1of8``: 5.15 GiB of arguments, 8.71 of
+        temporaries): the three window layers' calls under ``attn.core`` /
+        ``window`` (one forward, one rematerialised, dQ and dK/dV inside
+        the run's loop of three), the two full layers' outside any
+        sub-scope (loops of one turn: no second forward), nothing ``[S,
+        S]``, the windowed call's record with the pairs its blocks
+        multiply, and the step fits the chip."""
+        from benchmarks.common import HERE, load_module, read_json
+        from dlrover_tpu.observability import trace
+
+        notes = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: notes.append((name, attrs)))
+        config = read_json(HERE, "configs", "laguna_xs2_33b_1of8.json")
+        family = load_module("families", "laguna")
+        S = 4096
+
+        def cell():
+            return family.build(config, False, S), (1, S)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh, cell)
+        text = compiled.as_text()
+        assert f"{S},{S}]" not in text
+        found = trace.parse_device_scopes(text)
+        # (the grouped matmuls are custom calls too, under ``moe/gmm``)
+        attend = [name for name in _kernel_names(text) if "_attend" in name]
+        kernels = sorted(found.scopes["%" + name] for name in attend)
+        assert len(attend) < len(_kernel_names(text))
+        assert kernels == sorted(
+            [("attn.core", "window", which) for which in (
+                "forward", "remat", "backward", "backward")]
+            + [("attn.core", "", "forward")] * 2
+            + [("attn.core", "", "backward")] * 4)
+        # what a kernel is FOR is read off its path; its name is the
+        # innermost scope's (``_attend``), where the by-shape reader looks
+        windowed = [attrs for name, attrs in notes
+                    if name == "attention.path" and "window" in attrs]
+        causal = [attrs for name, attrs in notes
+                  if name == "attention.path" and "window" not in attrs]
+        assert windowed and causal
+        assert windowed[0]["heads"] == 64 and causal[0]["heads"] == 48
+        assert windowed[0]["window"] == 512
+        assert windowed[0]["gate"] == "sigmoid_a_head"
+        blocks = windowed[0]["blocks"]
+        assert windowed[0]["pairs_allowed"] == S * 512 - 512 * 511 // 2
+        assert windowed[0]["pairs_multiplied"] <= 2.0 * windowed[0][
+            "pairs_allowed"], blocks
+        mem = compiled.memory_analysis()
+        assert 5.5e9 < mem.argument_size_in_bytes < 5.6e9
 
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's

@@ -74,6 +74,20 @@ FAMILIES = {
         {"attn.core": {"latent", "conv", "decay", "chunk", "state", "gate"},
          "attn.proj": {"latent"}, "optimizer": {"bias"},
          "moe": {"route", "sort", "gmm", "combine", "shared"}}),
+    "laguna_window": (
+        lambda: LlamaForCausalLM(MoELlamaConfig.tiny_moe(
+            num_layers=3, layer_prefix=("gqa:dense",),
+            layer_pattern=("swa", "gqa"), dense_intermediate_size=96,
+            num_heads=6, swa_heads=8, num_kv_heads=2, sliding_window=8,
+            swa_rope_theta=10000.0, rope_theta=500000.0,
+            partial_rotary_factor=0.5, yarn_factor=64.0,
+            yarn_original_max_len=64, yarn_beta_fast=8.0,
+            attn_head_gate=True, num_experts=16, top_k=4, experts_held=4,
+            norm_topk_prob=True, router_scores="sigmoid", shared_experts=1,
+            routed_scaling_factor=2.5, max_seq_len=SEQ)),
+        COMMON | {"moe", "mlp"},
+        {"attn.core": {"window"},
+         "moe": {"route", "sort", "gmm", "combine", "shared"}}),
 }
 
 #: a path may be ``other`` where it names nothing but the layer stack and
@@ -83,7 +97,9 @@ STACK = {"layers", "layer", "h", "block", "jit(wrapped)", "LlamaForCausalLM",
          "GPT", "while", "body", "cond", "closed_call", "checkpoint",
          "rematted_computation", "jit(tril)",
          # a pattern's runs (``ling_latent``)
-         "prefix", "kda_dense_0", "kda_0", "mla_1"}
+         "prefix", "kda_dense_0", "kda_0", "mla_1",
+         # (``laguna_window``)
+         "gqa_dense_0", "swa_0", "gqa_1"}
 
 def _step_text(model, steps=0):
     mesh = build_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])

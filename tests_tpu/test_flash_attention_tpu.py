@@ -124,3 +124,35 @@ def test_dispatch_uses_pallas_on_tpu(tpu_backend):
     )
     want = reference_attention(q, k, v, _causal_mask(1024))
     _assert_close(out, want, atol=3e-2)
+
+
+@pytest.mark.parametrize(
+    "seq, heads, kv_heads, dim, window, blocks",
+    [(2048, 16, 2, 128, 512, (512, 512)),    # Laguna's groups of 8
+     (2048, 12, 2, 128, 512, (256, 256)),    # groups of 6, under a window
+     (1024, 8, 8, 64, 100, (128, 256)),      # no multiple of a block
+     (1024, 4, 2, 128, 5000, (256, 128))],   # over the sequence: causal
+    ids=["groups_of_8", "groups_of_6", "window_100_d64", "over_the_sequence"])
+def test_windowed_kernels_match_reference(tpu_backend, seq, heads, kv_heads,
+                                          dim, window, blocks):
+    """Forward, dQ and dK/dV under a causal window (PR 51), Mosaic-compiled,
+    against the reference core under the same band."""
+    q, k, v = _qkv(1, seq, heads, kv_heads, dim, seed=5)
+    mask = _causal_mask(seq)
+
+    def flash_loss(q, k, v):
+        out = pallas_flash_attention(q, k, v, True, *blocks, False, window)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+
+    def ref_loss(q, k, v):
+        out = reference_attention(q, k, v, mask, window)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+
+    (_, out), got = jax.jit(jax.value_and_grad(
+        flash_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, ref), want = jax.jit(jax.value_and_grad(
+        ref_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    _assert_close(out, ref, atol=3e-2)
+    for g, w in zip(got, want):
+        scale = max(1.0, float(jnp.abs(w.astype(jnp.float32)).max()))
+        _assert_close(g, w, atol=0.05 * scale)
