@@ -113,9 +113,19 @@ def flash_attention(
     Block sizes default to the autotuned table (``ops/pallas/tuning.py``)
     for this (seq_len, head_dim), a windowed call's under a key of its own;
     pass explicit values to override.  ``window`` (causal calls): the band
-    a query sees; the record then also says how many key blocks a query
-    block visits at most and the pairs a head's forward pass multiplies
-    beside those the band allows.
+    a query sees.  Which kernels then run is one rule over the shapes
+    (``ops/pallas/flash_attention.py::band_path``): where a query block
+    and the ``window - 1`` keys before it (rounded up to ``block_kv``,
+    which tiles ``block_q``) are no more than the sequence and fit the
+    band kernels' VMEM budget, a query block meets all the keys it can
+    see in ONE grid step under a plain softmax, ``block_kv`` rows at a
+    time against the ``block_kv + back`` keys they can see; a wider band
+    (Mistral's 4,096 on a longer sequence, a window over the sequence)
+    streams its key blocks under the online softmax as the causal kernels
+    do.  The record says which (``band=one_visit`` or ``streamed``), how
+    many key blocks a query block visits at most and the pairs a head's
+    forward pass multiplies beside those the band allows: the numbers of
+    the kernels that run.
     ``path_attrs``: what the caller does around the kernel (``rope=none``,
     ``gate=sigmoid``), written at the end of the ``attention.path`` line.
     ``interpret=True`` runs the kernel in the Pallas interpreter (tests off
@@ -130,8 +140,7 @@ def flash_attention(
             "off the chip, or pass interpret=True"
         )
     from dlrover_tpu.ops.pallas.flash_attention import (
-        band_pairs,
-        band_steps,
+        band_record,
         heads_per_block,
         pallas_flash_attention,
     )
@@ -145,12 +154,8 @@ def flash_attention(
         block_kv = block_kv or tuned_kv
     banded = {}
     if window is not None:
-        multiplied, allowed = band_pairs(seq_len, block_q, block_kv, window)
-        banded = dict(
-            window=window,
-            kv_blocks_visited=band_steps(
-                seq_len, block_q, block_kv, window)[0],
-            pairs_multiplied=multiplied, pairs_allowed=allowed)
+        banded = dict(window=window, **band_record(
+            seq_len, block_q, block_kv, window, head_dim))
     # ``layout``: the kernels read and write [B, S, H*D] in column blocks
     # of 128 lanes, ``heads_per_block`` heads in each
     trace.note_trace_time(

@@ -42,9 +42,41 @@ index maps, which clamp as the causal ones do, and a step past the band's
 last block or the sequence's edge is skipped.  At 16,384 positions, a
 window of 512 and blocks of 512 a query block visits 2 key blocks of 32.
 ``window=None`` is the causal kernel as it was, the same program.
+
+A band in ONE visit (the ``_band_*`` kernels).  A band is not a long row
+of key blocks: a query block of ``block_q`` rows sees the keys ``[start
+- (window - 1), start + block_q)`` and no others, and where they fit the
+fast memory at once the online softmax buys nothing.  There a grid step
+holds the query block, its own keys and the ``back`` keys before them
+(``window - 1`` rounded up to ``block_kv``; fetched through block specs
+of their own, clamped at block 0 and masked there by position:
+``_band_reach``), and takes ``block_kv`` rows at a time against the
+``block_kv + back`` keys those rows can see, static slices of the
+resident tiles that cost no grid step: ``m = max(s)``, ``p = exp(s -
+m)``, ``l = sum(p)``, ``out = p v / l``, the LSE written once.  No
+running maximum or sum, no correction, no rescaled accumulator, no
+scratch, no streamed axis; dQ likewise (``dq = ds k`` written directly),
+and in dK/dV a resident key block meets the queries ``[start, start +
+block_q + back)`` of one q head-block in one step, the streamed axis the
+GQA group alone.  Scores, softmax and accumulation are float32 from the
+operands as given, the band the same to the position, the residuals
+``(q, k, v, out, lse)`` the same: the streamed kernels' result to
+float32 rounding.  At 16,384 positions, a window of 512 and a head of
+128 a forward call alone went from 11.8 ms (streamed, 512 x 512) to 5.2
+(1,024 rows a step, 256 at a time: PERF.md, PR 52).
+
+Which kernels a windowed call runs is ``band_path``, one rule over the
+shapes: one visit where ``block_kv`` tiles ``block_q``, the keys a step
+holds are no more than the sequence, and the step's tiles
+(``band_vmem_bytes``) fit ``BAND_VMEM_LIMIT_BYTES``, whose arithmetic
+stands beside it.  The streamed windowed kernels stay for every wider
+band: Mistral's 4,096 on a longer sequence (six score tiles of ``[4608,
+512]`` alone are 54 MiB), a window at or over the sequence, key blocks
+that do not tile the query block.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +106,21 @@ KERNEL_MIN_BLOCK = 128
 # the calls of a shared block ask for more: what a kernel may take, the
 # program around it may not keep there.
 SHARED_BLOCK_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+# Which kernels a windowed call runs (``band_path``).
+ONE_VISIT, STREAMED = "one_visit", "streamed"
+
+# What a band kernel, which holds all of a resident block's band at once,
+# may take of VMEM (``band_vmem_bytes`` has the count).  At the Laguna
+# cell's shape (a window of 512, a head of 128 a block, float32 counted
+# throughout) and whole blocks of 512 rows: two buffers of q, dO, O and
+# the LSE over 1,024 rows, of k, v, dK and dV over 512, 2 x 3 MiB; their
+# float32 tiles, 3 MiB; six ``[1024, 512]`` float32 tiles of scores, 12
+# MiB: 21 MiB.  As shipped (1,024 rows a block, 256 at a time against
+# their 768): 2 x 5 + 5 + 4.5 = 19.5 MiB.  Half as much again for what
+# the compiler keeps beside them: twice Mosaic's default, a quarter of a
+# v5e core's 128 MiB.
+BAND_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def heads_per_block(head_dim: int) -> int:
@@ -125,12 +172,13 @@ def _lanes_at(place, head_dim: int, width: int):
     return (lane >= place * head_dim) & (lane < (place + 1) * head_dim)
 
 
-def _head_tile(ref, place, onto, head_dim: int):
-    """The float32 tile of ``ref`` with the head at ``place`` of the block
-    turned onto the lanes of place ``onto`` and every other lane read as
-    zero: a select, so that what the other lanes hold (another head, or
-    nothing at all past the array's edge) cannot reach the result."""
-    x = ref[0].astype(jnp.float32)
+def _head_tile(ref, place, onto, head_dim: int, rows=None):
+    """The float32 tile of ``ref`` (its ``rows`` alone, a static slice,
+    where given) with the head at ``place`` of the block turned onto the
+    lanes of place ``onto`` and every other lane read as zero: a select,
+    so that what the other lanes hold (another head, or nothing at all
+    past the array's edge) cannot reach the result."""
+    x = (ref[0] if rows is None else ref[0, rows]).astype(jnp.float32)
     width = x.shape[-1]
     if head_dim == width:
         return x
@@ -338,7 +386,9 @@ def _checked_blocks(seq_len: int, block_q: int, block_kv: int):
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
                    interpret: bool = False, with_residuals: bool = False,
                    window=None):
-    """q: [B, S, H, D]; k/v: [B, S, H_kv, D] (GQA via KV index mapping)."""
+    """q: [B, S, H, D]; k/v: [B, S, H_kv, D] (GQA via KV index mapping).
+    A windowed call whose band fits one visit (``band_path``) runs the
+    band kernel, every other call the streamed one."""
     if window is not None and (not causal or window < 1):
         raise ValueError(
             f"window={window!r} needs causal attention and at least one "
@@ -347,6 +397,19 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_kv: int,
     H_kv = k.shape[2]
     if H % H_kv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {H_kv}")
+    forward = (_band_forward if _takes_one_visit(S, D, block_q, block_kv,
+                                                 window)
+               else _streamed_forward)
+    return forward(q, k, v, causal, block_q, block_kv, interpret,
+                   with_residuals, window)
+
+
+def _streamed_forward(q, k, v, causal, block_q, block_kv, interpret,
+                      with_residuals, window):
+    """Q blocks resident, KV blocks streamed under the online softmax:
+    every causal block, or under a window the band's."""
+    B, S, H, D = q.shape
+    H_kv = k.shape[2]
     groups = H // H_kv
     block_q, block_kv = _checked_blocks(S, block_q, block_kv)
     width = _block_lanes(D)
@@ -549,7 +612,19 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
                     interpret, window=None):
     """q, out, do: [B, S, H, D]; k, v: [B, S, H_kv, D]; lse:
     [B, H, S, LANES].  Returns (dq, dk, dv) in the shapes of (q, k, v):
-    the dK/dV kernel sums a GQA group itself."""
+    the dK/dV kernel sums a GQA group itself.  The band kernels where the
+    forward took them (``band_path``: the same shapes, the same answer)."""
+    backward = (_band_backward if _takes_one_visit(
+        q.shape[1], q.shape[3], block_q, block_kv, window)
+                else _streamed_backward)
+    return backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
+                    interpret, window)
+
+
+def _streamed_backward(q, k, v, out, lse, grad_out, causal, block_q,
+                       block_kv, interpret, window):
+    """The FA2 split: dQ streams KV blocks past a resident Q block, dK/dV
+    stream the Q blocks of a GQA group past a resident KV block."""
     B, S, H, D = q.shape
     H_kv = k.shape[2]
     groups = H // H_kv
@@ -643,6 +718,386 @@ def _flash_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
         interpret=interpret,
     )(*operands)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+# ---------------------------------------------------------------------------
+# a window's band in one visit (``band_path``): plain softmax, no streamed
+# axis in forward and dQ, the GQA group alone in dK/dV
+# ---------------------------------------------------------------------------
+
+
+def band_tiles(block_q: int, block_kv: int, window: int):
+    """``(back, fetch)``: the rows next to its own that a resident block
+    holds in a band step, ``window - 1`` rounded up to ``block_kv``, and
+    the rows of one block of their fetch, the largest that tiles both
+    ``back`` and ``block_q`` (so a block index says where they start)."""
+    back = -(-(window - 1) // block_kv) * block_kv
+    return back, math.gcd(block_q, back)
+
+
+def band_vmem_bytes(block_q: int, block_kv: int, window: int,
+                    head_dim: int) -> int:
+    """What the hungriest band step (dK/dV) holds at once, counted at
+    float32 operands: the pipeline's two buffers of a head's q, dO, O
+    and LSE over ``block_q + back`` rows, of k and v and of the two
+    results over ``block_q``; the float32 tiles the body casts them to;
+    the two accumulators; and, ``[block_kv + back, block_kv]`` each, the
+    scores, the probabilities, dP, dS and the two position grids of the
+    mask."""
+    back, _ = band_tiles(block_q, block_kv, window)
+    width = _block_lanes(head_dim)
+    rows = block_q + back
+    operands = rows * (3 * width + heads_per_block(head_dim) * LANES) \
+        + 2 * block_q * width
+    results = 2 * block_q * width
+    tiles = 6 * (block_kv + back) * block_kv
+    return 4 * (2 * (operands + results) + operands + results + tiles)
+
+
+def band_path(seq_len: int, block_q: int, block_kv: int, window: int,
+              head_dim: int) -> str:
+    """``ONE_VISIT`` or ``STREAMED``: which kernels a windowed call runs,
+    from its shapes and nothing else.  One visit where ``block_kv`` tiles
+    ``block_q``, the keys a step holds (``block_q + back``) are no more
+    than the sequence (past it every step would hold a clamped, masked
+    fetch; the streamed kernels visit a block once) and the step fits
+    ``BAND_VMEM_LIMIT_BYTES``."""
+    back, _ = band_tiles(block_q, block_kv, window)
+    if (block_q % block_kv == 0 and block_q + back <= seq_len
+            and band_vmem_bytes(block_q, block_kv, window, head_dim)
+            <= BAND_VMEM_LIMIT_BYTES):
+        return ONE_VISIT
+    return STREAMED
+
+
+def _takes_one_visit(seq_len: int, head_dim: int, block_q: int,
+                     block_kv: int, window) -> bool:
+    """Whether a call, its blocks as handed over, runs the band kernels."""
+    return window is not None and band_path(
+        seq_len, *_checked_blocks(seq_len, block_q, block_kv), window,
+        head_dim) == ONE_VISIT
+
+
+def band_record(seq_len: int, block_q: int, block_kv: int, window: int,
+                head_dim: int) -> dict:
+    """What ``attention.path`` says of a windowed call, the numbers of
+    the kernels that run: ``band``, the key blocks a query block visits
+    at most, and the query-key pairs of one head's forward pass, those
+    every score tile holds and those the band allows.  One visit: every
+    ``block_kv`` rows of a query block against ``block_kv + back`` keys,
+    the sequence's first blocks too (their clamped fetch is multiplied
+    and masked)."""
+    path = band_path(seq_len, block_q, block_kv, window, head_dim)
+    multiplied, allowed = band_pairs(seq_len, block_q, block_kv, window)
+    if path == ONE_VISIT:
+        back, _ = band_tiles(block_q, block_kv, window)
+        visited, multiplied = 1, seq_len * (block_kv + back)
+    else:
+        visited = band_steps(seq_len, block_q, block_kv, window)[0]
+    return dict(band=path, kv_blocks_visited=visited,
+                pairs_multiplied=multiplied, pairs_allowed=allowed)
+
+
+def _band_rows(refs, lo: int, hi: int, read):
+    """Rows ``[lo, hi)`` of what the tiles ``refs`` hold one after the
+    other along their second-to-last axis: ``read(ref, rows)`` of each
+    tile the range touches, joined."""
+    pieces, at = [], 0
+    for ref in refs:
+        n = ref.shape[-2]
+        if max(lo, at) < min(hi, at + n):
+            pieces.append(read(ref, slice(max(lo, at) - at,
+                                          min(hi, at + n) - at)))
+        at += n
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=0)
+
+
+def _band_tile(refs, lo: int, hi: int, place, onto, head_dim: int):
+    """``_head_tile`` of the rows ``[lo, hi)`` of the tiles ``refs``."""
+    return _band_rows(refs, lo, hi, lambda ref, rows: _head_tile(
+        ref, place, onto, head_dim, rows))
+
+
+def _band_reach(start, rows: int, window: int, seq_len: int):
+    """``[rows, 1]``: how many keys, itself the last, each of ``rows``
+    queries from position ``start`` sees: ``window``; fewer where the
+    sequence has no earlier ones, which is what masks the keys a fetch
+    clamped at block 0 holds at positions under 0; none for a query past
+    the sequence's end (a fetch clamped at the last block).  Handed to
+    ``_masked_scores`` as its window, a row's own."""
+    at = start + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    return jnp.where(at < seq_len, jnp.minimum(window, at + 1), 0)
+
+
+def _band_compiler_params(streamed_axes: int = 0):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * 3 + ("arbitrary",) * streamed_axes,
+        vmem_limit_bytes=BAND_VMEM_LIMIT_BYTES)
+
+
+def _band_fwd_kernel(
+    q_ref, k_refs, v_refs, out_ref, lse_ref,
+    *, block_q: int, block_kv: int, back: int, scale: float, head_dim: int,
+    heads: int, groups: int, window: int, seq_len: int,
+):
+    # k_refs, v_refs: the ``back`` keys before the block's own, then its
+    # own.  ``block_kv`` rows at a time meet the ``block_kv + back`` keys
+    # they can see, all at once: the softmax is the plain one
+    head_block = pl.program_id(1)
+    q_start = pl.program_id(2) * block_q
+    per_block = q_ref.shape[-1] // head_dim
+    held = block_kv + back
+
+    def one_head(i):
+        kv_at = _kv_place(head_block, i, groups, per_block)
+        for lo in range(0, block_q, block_kv):
+            rows = slice(lo, lo + block_kv)
+            s = _masked_scores(
+                _head_tile(q_ref, i, i, head_dim, rows),
+                _band_tile(k_refs, lo, lo + held, kv_at, i, head_dim),
+                scale, True, q_start + lo, q_start + lo - back, block_kv,
+                held, _band_reach(q_start + lo, block_kv, window, seq_len))
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)  # at least 1: itself
+            mine = (jax.lax.dot_general(
+                p, _band_tile(v_refs, lo, lo + held, kv_at, i, head_dim),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) / l).astype(out_ref.dtype)
+            # the other heads' lanes of ``mine`` are zero
+            out_ref[0, rows] = mine if i == 0 else out_ref[0, rows] + mine
+            if lse_ref is not None:
+                lse_ref[0, i, rows] = jnp.broadcast_to(
+                    m + jnp.log(l), (block_kv, LANES))
+
+    _each_head(head_block, heads, per_block, pl.num_programs(1), one_head)
+
+
+def _band_dq_kernel(
+    q_ref, k_refs, v_refs, do_ref, o_ref, lse_ref, dq_ref,
+    *, block_q: int, block_kv: int, back: int, scale: float, head_dim: int,
+    heads: int, groups: int, window: int, seq_len: int,
+):
+    head_block = pl.program_id(1)
+    q_start = pl.program_id(2) * block_q
+    per_block = q_ref.shape[-1] // head_dim
+    held = block_kv + back
+
+    def one_head(i):
+        # everything on the q head's lanes: dQ lands where q lies
+        kv_at = _kv_place(head_block, i, groups, per_block)
+        for lo in range(0, block_q, block_kv):
+            rows = slice(lo, lo + block_kv)
+            q, do, o = (_head_tile(ref, i, i, head_dim, rows)
+                        for ref in (q_ref, do_ref, o_ref))
+            k, v = (_band_tile(refs, lo, lo + held, kv_at, i, head_dim)
+                    for refs in (k_refs, v_refs))
+            _, ds = _recomputed(
+                q, k, v, do, o, lse_ref[0, i, rows, :1],
+                scale, True, q_start + lo, q_start + lo - back, block_kv,
+                held, _band_reach(q_start + lo, block_kv, window, seq_len))
+            mine = jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ).astype(dq_ref.dtype)
+            dq_ref[0, rows] = mine if i == 0 else dq_ref[0, rows] + mine
+
+    _each_head(head_block, heads, per_block, pl.num_programs(1), one_head)
+
+
+def _band_dkv_kernel(
+    q_refs, k_ref, v_ref, do_refs, o_refs, lse_refs, dk_ref, dv_ref,
+    dk_acc, dv_acc,
+    *, block_q: int, block_kv: int, back: int, scale: float, head_dim: int,
+    heads: int, groups: int, window: int, seq_len: int,
+):
+    # q_refs, do_refs, o_refs, lse_refs: the rows of the key block's own
+    # positions, then the ``back`` after them.  The streamed axis walks
+    # the q head-blocks whose kv heads lie in this kv block, one visit
+    # each: ``block_kv`` keys at a time meet the ``block_kv + back``
+    # queries that can see them
+    kv_start = pl.program_id(2) * block_q
+    q_head_block = pl.program_id(1) * groups + pl.program_id(3)
+    per_block = k_ref.shape[-1] // head_dim
+    met = block_kv + back
+
+    @pl.when(pl.program_id(3) == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def one_head(i):
+        # everything on the kv head's lanes: dK and dV land where k lies
+        kv_at = _kv_place(q_head_block, i, groups, per_block)
+        for lo in range(0, block_q, block_kv):
+            cols = slice(lo, lo + block_kv)
+            q, do, o = (_band_tile(refs, lo, lo + met, i, kv_at, head_dim)
+                        for refs in (q_refs, do_refs, o_refs))
+            lse = _band_rows(lse_refs, lo, lo + met,
+                             lambda ref, rows: ref[0, i, rows])[:, :1]
+            p, ds = _recomputed(
+                q, _head_tile(k_ref, kv_at, kv_at, head_dim, cols),
+                _head_tile(v_ref, kv_at, kv_at, head_dim, cols), do, o, lse,
+                scale, True, kv_start + lo, kv_start + lo, met, block_kv,
+                _band_reach(kv_start + lo, met, window, seq_len))
+            # dV += P^T dO
+            dv_acc[cols] += jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            # dK += dS^T Q
+            dk_acc[cols] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+    _each_head(q_head_block, heads, per_block, pl.num_programs(1) * groups,
+               one_head)
+
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _band_specs(where, S, block_q, block_kv, window, width, ahead,
+                lse_heads=0):
+    """Block specs of the tiles of one operand of a band step, in the
+    order their rows lie: the resident block's own rows, and the ``back``
+    rows beside them, one spec a fetched block: after the block's own
+    (``ahead``, clamped at the sequence's last block) or before them
+    (clamped at block 0).  ``where`` gives a grid step's (batch, resident
+    block, head block of the operand); ``lse_heads``: the operand is
+    ``[B, H, S, LANES]``, that many heads a block."""
+    back, fetch = band_tiles(block_q, block_kv, window)
+    n, per = back // fetch, block_q // fetch
+
+    def spec(rows, block_of):
+        def at(*ids):
+            b, i, h = where(*ids)
+            return (b, h, block_of(i), 0) if lse_heads else (
+                b, block_of(i), h)
+        return pl.BlockSpec(
+            (1, lse_heads, rows, LANES) if lse_heads else (1, rows, width),
+            at)
+
+    own = [spec(block_q, lambda i: i)]
+    if ahead:
+        return own + [
+            spec(fetch, lambda i, t=t: jnp.minimum(
+                (i + 1) * per + t, S // fetch - 1)) for t in range(n)]
+    return [spec(fetch, lambda i, t=t: jnp.maximum(i * per - n + t, 0))
+            for t in range(n)] + own
+
+
+def _band_forward(q, k, v, causal, block_q, block_kv, interpret,
+                  with_residuals, window):
+    """Grid (batch, head block, query block), every axis parallel: a step
+    holds a query block and the keys ``[start - back, start + block_q)``
+    and writes its output and LSE once."""
+    B, S, H, D = q.shape
+    H_kv = k.shape[2]
+    groups = H // H_kv
+    block_q, block_kv = _checked_blocks(S, block_q, block_kv)
+    width = _block_lanes(D)
+    per_block = width // D
+    specs = functools.partial(_band_specs, S=S, block_q=block_q,
+                              block_kv=block_kv, window=window, width=width,
+                              ahead=False)
+    q_spec = specs(lambda b, h, i: (b, i, h))[-1]
+    kv_specs = specs(lambda b, h, i: (b, i, h // groups))
+    k = k.reshape(B, S, H_kv * D)
+    v = v.reshape(B, S, H_kv * D)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _band_fwd_kernel, block_q=block_q, block_kv=block_kv,
+            back=band_tiles(block_q, block_kv, window)[0], scale=D ** -0.5,
+            head_dim=D, heads=H, groups=groups, window=window, seq_len=S),
+        grid=(B, pl.cdiv(H, per_block), S // block_q),
+        in_specs=[q_spec, kv_specs, kv_specs],
+        out_specs=[
+            q_spec,
+            specs(lambda b, h, i: (b, i, h), lse_heads=per_block)[-1]
+            if with_residuals else None,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
+            # lane-broadcast residual: [B, H, S, LANES] (see LANES)
+            jax.ShapeDtypeStruct((B, H, S, LANES), jnp.float32)
+            if with_residuals else None,
+        ],
+        compiler_params=_band_compiler_params(),
+        interpret=interpret,
+    )(q.reshape(B, S, H * D), [k] * len(kv_specs), [v] * len(kv_specs))
+    out = out.reshape(B, S, H, D)
+    if with_residuals:
+        return out, lse
+    return out
+
+
+def _band_backward(q, k, v, out, lse, grad_out, causal, block_q, block_kv,
+                   interpret, window):
+    """dQ on the forward's grid, written once a step; dK/dV with a key
+    block resident and the q head-blocks of its GQA group streamed, each
+    in one visit over the queries ``[start, start + block_q + back)``."""
+    shapes = q.shape, k.shape, v.shape
+    B, S, H, D = q.shape
+    H_kv = k.shape[2]
+    groups = H // H_kv
+    block_q, block_kv = _checked_blocks(S, block_q, block_kv)
+    width = _block_lanes(D)
+    per_block = width // D
+    q_head_blocks = pl.cdiv(H, per_block)
+    q, k, v, grad_out, out = (
+        x.reshape(B, S, -1) for x in (q, k, v, grad_out, out))
+    settings = dict(block_q=block_q, block_kv=block_kv,
+                    back=band_tiles(block_q, block_kv, window)[0],
+                    scale=D ** -0.5, head_dim=D, heads=H, groups=groups,
+                    window=window, seq_len=S)
+    specs = functools.partial(_band_specs, S=S, block_q=block_q,
+                              block_kv=block_kv, window=window, width=width)
+
+    def at_q(b, h, i):
+        return b, i, h
+
+    q_spec = specs(at_q, ahead=False)[-1]
+    kv_specs = specs(lambda b, h, i: (b, i, h // groups), ahead=False)
+    dq = pl.pallas_call(
+        functools.partial(_band_dq_kernel, **settings),
+        grid=(B, q_head_blocks, S // block_q),
+        in_specs=[q_spec, kv_specs, kv_specs, q_spec, q_spec,
+                  specs(at_q, ahead=False, lse_heads=per_block)[-1]],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_band_compiler_params(),
+        interpret=interpret,
+    )(q, [k] * len(kv_specs), [v] * len(kv_specs), grad_out, out, lse)
+
+    def at_group(b, h_kv, j, x):
+        # an odd head count: the last kv head-block's last q head-block
+        # may not be there (the kernel skips it), so ask for none past it
+        return b, j, jnp.minimum(h_kv * groups + x, q_head_blocks - 1)
+
+    q_specs = specs(at_group, ahead=True)
+    kv_spec = specs(lambda b, h_kv, j, x: (b, j, h_kv), ahead=True)[0]
+    n = len(q_specs)
+    dk, dv = pl.pallas_call(
+        functools.partial(_band_dkv_kernel, **settings),
+        grid=(B, pl.cdiv(H_kv, per_block), S // block_q, groups),
+        in_specs=[q_specs, kv_spec, kv_spec, q_specs, q_specs,
+                  specs(at_group, ahead=True, lse_heads=per_block)],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, width), jnp.float32),
+            pltpu.VMEM((block_q, width), jnp.float32),
+        ],
+        compiler_params=_band_compiler_params(streamed_axes=1),
+        interpret=interpret,
+    )([q] * n, k, v, [grad_out] * n, [out] * n, [lse] * n)
+    return tuple(g.reshape(shape) for g, shape in zip((dq, dk, dv), shapes))
 
 
 # ---------------------------------------------------------------------------

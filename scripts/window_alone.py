@@ -1,6 +1,7 @@
 """The FA2 kernels under a causal window alone at one cell's shape, on the
 chip: ``ops/pallas/flash_attention.py`` with ``window`` at each candidate
-pair of blocks, beside the causal kernels at the shape of the same model's
+pair of blocks, the band kernels (one visit a query block) beside the
+streamed ones, beside the causal kernels at the shape of the same model's
 full layers.
 
 For each, the forward pass and forward + backward (dQ and dK/dV both: the
@@ -11,9 +12,14 @@ allowed; and how far the output and the three gradients are from the
 reference core under the same band on a shorter sequence of float32
 operands.  One JSON line a candidate::
 
-    python3 scripts/window_alone.py --blocks "512,512;256,256;128,128"
+    python3 scripts/window_alone.py --blocks "512,128;512,512;512,512,streamed"
 
-A candidate is ``block_q,block_kv``.  ``--full`` times the causal kernels
+A candidate is ``block_q,block_kv`` (the kernels the shapes choose,
+``flash_attention.py::band_path``: in one visit ``block_kv`` is the rows a
+query block takes at a time and the granule of the band's fetch) or
+``block_q,block_kv,streamed`` (the streamed windowed kernels whatever the
+rule says); a line names the path it ran (``band``), and its visits and
+pairs are ``band_record``'s, the record's own.  ``--full`` times the causal kernels
 at ``--full-heads`` query heads instead (no window).  ``--rehearse``: the
 interpreter on the CPU at a tiny shape, to walk the script before it costs
 chip time.
@@ -56,8 +62,7 @@ def main(argv=None):
     import numpy as np
 
     from dlrover_tpu.ops.attention import reference_attention
-    from dlrover_tpu.ops.pallas.flash_attention import (
-        band_pairs, band_steps, pallas_flash_attention)
+    from dlrover_tpu.ops.pallas import flash_attention as fa
 
     batch, seq, heads, kv_heads, head_dim = (
         int(n) for n in args.shape.split(","))
@@ -69,10 +74,23 @@ def main(argv=None):
         window = None if args.full else 48
     device = jax.devices()[0]
 
-    def core(blocks, interpret):
+    def core(blocks, streamed, interpret):
+        if not streamed:  # what the shapes choose
+            return lambda q, k, v: fa.pallas_flash_attention(
+                q, k, v, True, *blocks, interpret, window)
+
+        @jax.custom_vjp
         def run(q, k, v):
-            return pallas_flash_attention(
-                q, k, v, True, blocks[0], blocks[1], interpret, window)
+            return fa._streamed_forward(
+                q, k, v, True, *blocks, interpret, False, window)
+
+        def forward(q, k, v):
+            out, lse = fa._streamed_forward(
+                q, k, v, True, *blocks, interpret, True, window)
+            return out, (q, k, v, out, lse)
+
+        run.defvjp(forward, lambda kept, grad: fa._streamed_backward(
+            *kept, grad, True, *blocks, interpret, window))
         return run
 
     def both(run, weight):
@@ -97,18 +115,27 @@ def main(argv=None):
         q_, k_, v_, jnp.tril(jnp.ones((short, short), bool))[None, None],
         window), small[3])(*small[:3])
     for candidate in args.blocks.split(";"):
-        blocks = tuple(int(n) for n in candidate.split(","))
+        *blocks, streamed = (candidate.split(",") + [""])[:3]
+        blocks = tuple(int(n) for n in blocks)
         if args.rehearse:
             blocks = tuple(min(b, 64) for b in blocks)
         line = {"blocks": blocks, "window": window,
                 "shape": [batch, seq, heads, kv_heads, head_dim],
                 "device_kind": device.device_kind}
         if window is not None:
-            multiplied, allowed = band_pairs(seq, *blocks, window)
-            line.update(kv_blocks_visited=band_steps(seq, *blocks, window)[0],
-                        pairs_multiplied_over_allowed=multiplied / allowed)
+            record = fa.band_record(seq, *blocks, window, head_dim)
+            if streamed:
+                multiplied, _ = fa.band_pairs(seq, *blocks, window)
+                record.update(
+                    band=fa.STREAMED, pairs_multiplied=multiplied,
+                    kv_blocks_visited=fa.band_steps(seq, *blocks, window)[0])
+            line.update(
+                band=record["band"],
+                kv_blocks_visited=record["kv_blocks_visited"],
+                pairs_multiplied_over_allowed=record["pairs_multiplied"]
+                / record["pairs_allowed"])
         try:
-            run = core(blocks, args.rehearse)
+            run = core(blocks, bool(streamed), args.rehearse)
             line["forward_ms"] = timed(jax.jit(run), q, k, v)
             line["forward_backward_ms"] = timed(both(run, weight), q, k, v)
             got = both(run, small[3])(*small[:3])

@@ -158,21 +158,30 @@ class TestFlashAttentionKernel:
 
 
 @pytest.mark.parametrize(
-    "heads, blocks, window", [(64, None, 512), (64, (256, 256), 512),
-                              (64, (1024, 512), 512), (48, None, None)],
+    "heads, blocks, window, band",
+    [(64, None, 512, "one_visit"), (64, (256, 256), 512, "one_visit"),
+     (64, (1024, 512), 512, "one_visit"), (64, (512, 128), 512, "one_visit"),
+     (64, (512, 1024), 512, "streamed"), (48, None, None, None)],
     ids=["window_512_shipped_blocks", "window_512_blocks_256",
-         "window_512_blocks_1024_512", "full_groups_of_6"])
+         "window_512_blocks_1024_512", "window_512_blocks_512_128",
+         "window_512_streamed_512_1024", "full_groups_of_6"])
 def test_windowed_fa2_kernels_compile_at_the_cells_shape(
-        one_chip, heads, blocks, window):
+        one_chip, heads, blocks, window, band):
     """The Laguna cell's two kinds of call at one sequence of 16,384 on 8
-    key heads: 64 query heads under a window of 512 (the streamed axis has
-    the band's blocks alone) and 48 causal ones (groups of 6, a count no
-    other cell has).  Three custom calls, nothing ``[S, S]``."""
+    key heads: 64 query heads under a window of 512 (the band kernels, one
+    visit a query block, at the shipped blocks and three more; the
+    streamed ones, whose axis has the band's blocks alone, where the key
+    block does not tile the query block) and 48 causal ones (groups of 6,
+    a count no other cell has).  Three custom calls, nothing ``[S, S]``."""
+    from dlrover_tpu.ops.pallas.flash_attention import band_path
+
     S = 16384
     q = jax.ShapeDtypeStruct((1, S, heads, 128), jnp.bfloat16,
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, S, 8, 128), jnp.bfloat16, sharding=one_chip)
     block_q, block_kv = blocks or tuned_blocks(S, 128, window)
+    if window is not None:
+        assert band_path(S, block_q, block_kv, window, 128) == band
 
     def loss(q, k, v):
         out = pallas_flash_attention(
@@ -697,7 +706,10 @@ class TestTrainerStep:
         assert windowed[0]["gate"] == "sigmoid_a_head"
         blocks = windowed[0]["blocks"]
         assert windowed[0]["pairs_allowed"] == S * 512 - 512 * 511 // 2
-        assert windowed[0]["pairs_multiplied"] <= 2.0 * windowed[0][
+        assert windowed[0]["band"] == "one_visit"
+        assert windowed[0]["kv_blocks_visited"] == 1
+        # a part of a block at a time: under the streamed kernels' 2.0
+        assert windowed[0]["pairs_multiplied"] <= 1.7 * windowed[0][
             "pairs_allowed"], blocks
         mem = compiled.memory_analysis()
         assert 5.5e9 < mem.argument_size_in_bytes < 5.6e9
