@@ -37,8 +37,15 @@ Design constraints, in order:
 ``DLROVER_TPU_RECORDER=0`` turns every append into a flag check (the
 flag is read when the rings are built; :meth:`FlightRecorder.reset`
 re-reads it).
+
+With the process's recorder goes one ``gc.callbacks`` hook: the garbage
+collector's pauses summed by the thread that ran them
+(:func:`gc_pause_ns`), and every pause of a millisecond or more as a span
+``runtime.gc`` in the span ring.  :func:`explain` reads the span ring the
+other way round: what one thread did in one interval, as a partition.
 """
 
+import gc
 import json
 import logging
 import os
@@ -47,7 +54,7 @@ import threading
 import time
 import traceback
 from collections import deque
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Dict, Iterable, List, Optional, Union
 
 from dlrover_tpu.common import envs
 from dlrover_tpu.common.log import logger
@@ -279,7 +286,124 @@ def recorder() -> FlightRecorder:
         with _RECORDER_MU:
             if _RECORDER is None:
                 _RECORDER = FlightRecorder()
+                if _on_gc not in gc.callbacks:
+                    gc.callbacks.append(_on_gc)
     return _RECORDER
+
+
+# -- the collector's pauses ----------------------------------------------------
+
+#: a pause at least this long is a ``runtime.gc`` span of its own
+GC_SPAN_MIN_NS = 1_000_000
+
+_gc_started: Dict[int, int] = {}  # thread -> when its collection began
+_gc_paused: Dict[int, int] = {}  # thread -> its pauses so far, summed
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """The ``gc.callbacks`` hook.  A collection runs on the thread whose
+    allocation set it off and holds the interpreter until it is done: two
+    clock reads and two dictionary writes a collection."""
+    try:
+        tid = threading.get_ident()
+        if phase == "start":
+            _gc_started[tid] = time.time_ns()
+            return
+        start_ns = _gc_started.pop(tid, None)
+        if start_ns is None:
+            return
+        end_ns = time.time_ns()
+        _gc_paused[tid] = _gc_paused.get(tid, 0) + end_ns - start_ns
+        if end_ns - start_ns >= GC_SPAN_MIN_NS:
+            from dlrover_tpu.observability import trace
+
+            recorder().record_span(trace.SpanTuple(
+                "runtime.gc", start_ns, end_ns, tid,
+                # a root of its own: an incident's timeline holds every
+                # span of the ring to a connected tree
+                threading.current_thread().name, trace.new_trace_id(),
+                trace.new_span_id(), "", trace.INTERNAL, "ok", "",
+                {"generation": info.get("generation"),
+                 "collected": info.get("collected")}, [],
+            ))
+    except Exception:  # noqa: BLE001 - never raise into an allocation
+        pass
+
+
+def gc_pause_ns(tid: Optional[int] = None) -> int:
+    """The collector's pauses on thread ``tid`` (the caller's where none
+    is named) since the hook was installed, summed, in nanoseconds."""
+    return _gc_paused.get(threading.get_ident() if tid is None else tid, 0)
+
+
+# -- what a thread did in an interval ------------------------------------------
+
+
+def explain(start_ns: int, end_ns: int, tid: int,
+            spans: Optional[Iterable[tuple]] = None) -> Dict[str, Any]:
+    """The interval ``[start_ns, end_ns)`` of thread ``tid`` by the
+    finished spans of the ring (or of ``spans``), as a partition by self
+    time: an instant belongs to the innermost span of that thread open at
+    it, and what no span covers is ``outside_spans_ns``, so ``parts_ns``
+    (by span name) and ``outside_spans_ns`` sum to ``interval_ns``.  Spans
+    of other threads that overlap the interval are listed beside it
+    (``others_ns``: the nanoseconds of overlap by ``<name>@<thread>``; read
+    from the ring, also those still open, as a stage behind the steps is)
+    and summed into nothing."""
+    mine: List[tuple] = []
+    others: Dict[str, int] = {}
+
+    def beside(name: str, thread: str, overlap_ns: int) -> None:
+        key = f"{name}@{thread}"
+        others[key] = others.get(key, 0) + overlap_ns
+
+    if spans is None:
+        spans = list(recorder().spans)
+        from dlrover_tpu.observability import trace
+
+        for sp in trace.open_spans():
+            opened_ns = int(sp["start_ts"] * 1e9)
+            if sp["tid"] != tid and opened_ns < end_ns:
+                beside(sp["name"], sp["thread"],
+                       end_ns - max(opened_ns, start_ns))
+    for s in spans:
+        if type(s) is dict or s.end_ns <= start_ns or s.start_ns >= end_ns:
+            continue
+        lo, hi = max(s.start_ns, start_ns), min(s.end_ns, end_ns)
+        if s.tid == tid:
+            mine.append((lo, hi, s.name))
+        else:
+            beside(s.name, s.thread, hi - lo)
+    parts: Dict[str, int] = {}
+    outside = 0
+    at, open_now = start_ns, []  # a thread's spans nest: a stack of (name, end)
+
+    def credit(until: int) -> None:
+        nonlocal at, outside
+        if until > at:
+            if open_now:
+                name = open_now[-1][0]
+                parts[name] = parts.get(name, 0) + until - at
+            else:
+                outside += until - at
+            at = until
+
+    for lo, hi, name in sorted(mine, key=lambda m: (m[0], -m[1])):
+        while open_now and open_now[-1][1] <= lo:
+            credit(open_now[-1][1])
+            open_now.pop()
+        credit(lo)
+        open_now.append((name, hi))
+    while open_now:
+        credit(open_now[-1][1])
+        open_now.pop()
+    credit(end_ns)
+    return {
+        "interval_ns": end_ns - start_ns,
+        "parts_ns": parts,
+        "outside_spans_ns": outside,
+        "others_ns": others,
+    }
 
 
 # -- feed helpers (called from trace/emitter/chaos/trainer; every caller

@@ -29,7 +29,12 @@ Design constraints:
    recorder's ring plus a per-name aggregate.  Spans of the step and
    stager path (:data:`IN_MEMORY_PREFIXES`) stop there: they are never
    serialised one by one, ``flight_recorder.snapshot()`` renders them
-   when an incident asks.
+   when an incident asks.  Those of them that are not opened every
+   step (:data:`PER_STEP_SPANS`) also say how much of their wall time
+   their thread spent on a CPU (the attribute ``cpu_ns``, from
+   ``time.thread_time_ns()`` at open and close): a long span whose
+   ``cpu_ns`` is small waited, one whose ``cpu_ns`` is near its length
+   computed.
 4. **One clock with the device trace.**  In a process that has already
    imported ``jax`` (this module never imports it) a span also enters a
    ``jax.profiler.TraceAnnotation`` of its name, so while ANY profiler
@@ -169,6 +174,9 @@ class SpanTuple(NamedTuple):
 
 _new_tuple = tuple.__new__  # SpanTuple's own __new__ costs a Python call
 
+#: the calling thread's CPU time, where the platform has such a clock
+_thread_time_ns = getattr(time, "thread_time_ns", None)
+
 
 def record_of(t: SpanTuple) -> Dict[str, Any]:
     """The JSONL record the timeline assembler and the incident dump
@@ -198,7 +206,7 @@ class Span:
     __slots__ = (
         "name", "kind", "trace_id", "span_id", "parent_span_id",
         "start_ns", "end_ns", "tid", "thread", "attrs", "events",
-        "status", "error", "sampled", "_token", "_anno",
+        "status", "error", "sampled", "_token", "_anno", "_cpu0",
     )
 
     def __init__(self, name: str, kind: str, trace_id: str, span_id: str,
@@ -219,6 +227,14 @@ class Span:
         self._anno = None
         self.end_ns = 0
         self.start_ns = time.time_ns()
+        # a hot-path span that is not opened every step reads its
+        # thread's CPU clock at both ends, inside its wall stamps
+        self._cpu0 = (
+            _thread_time_ns()
+            if _thread_time_ns is not None
+            and name.startswith(IN_MEMORY_PREFIXES)
+            and name not in PER_STEP_SPANS else None
+        )
 
     # -- mutation ----------------------------------------------------------
 
@@ -241,6 +257,8 @@ class Span:
     def end(self, status: Optional[str] = None, error: str = "") -> None:
         if self.end_ns:
             return
+        if self._cpu0 is not None:
+            self.attrs["cpu_ns"] = _thread_time_ns() - self._cpu0
         self.end_ns = time.time_ns()
         if status is not None:
             self.status = status
@@ -725,6 +743,15 @@ def server_span(name: str, traceparent: str,
 IN_MEMORY_PREFIXES: Tuple[str, ...] = (
     "trainer.", "flash.save", "flash.stage",
 )
+
+#: the three of them a step opens: they take no CPU clock of their own.
+#: The thread's CPU clock is a system call, 0.3 us on a plain kernel and 7 us
+#: with a tick of 10 ms on the sandboxed one the benchmark's machines run
+#: (PERF.md, PR 53): six reads a step cost 45 us there and read 0 on spans of
+#: a millisecond.  The step's account covers them at one read a step
+#: (``interval_cpu_ns``, ``trainer/step_account.py``).
+PER_STEP_SPANS = frozenset(
+    {"trainer.step", "trainer.step.dispatch", "trainer.shard_batch"})
 
 _sink_mu = threading.Lock()
 _sink: Optional[Callable[[Dict[str, Any]], None]] = None

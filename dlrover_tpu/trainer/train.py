@@ -256,6 +256,12 @@ class Trainer:
         self._step_clock = get_step_clock()
         self._last_step_ts = None
         self._events = get_default_emitter("trainer")
+        from dlrover_tpu.trainer.step_account import StepAccount
+
+        # what the stepping thread did between two ``trainer.step`` closes,
+        # and the record of a slow one
+        self._step_account = StepAccount(self._step_clock, self._events)
+        self._ticked = False  # the tick ran in the step that is open
         self._events.instant(
             TrainerEvents.INIT,
             {"mesh": {k: int(v) for k, v in mesh.shape.items()}
@@ -1146,10 +1152,18 @@ class Trainer:
         """One optimizer step.  All of it is the span ``trainer.step``
         (``step``: calls of this trainer so far); its child
         ``trainer.step.dispatch`` is the jitted call, and the rest is
-        the host's bookkeeping around it."""
+        the host's bookkeeping around it.  At its close the span takes
+        what its thread did since the last one closed
+        (``trainer/step_account.py``)."""
         self._step_calls += 1
-        with trace.span("trainer.step", attrs={"step": self._step_calls}):
-            return self._step_on_host(state, batch)
+        with trace.span(
+            "trainer.step", attrs={"step": self._step_calls}
+        ) as span:
+            result = self._step_on_host(state, batch)
+            ticked, self._ticked = self._ticked, False
+            if span is not trace.NOOP_SPAN:
+                self._step_account.close(span, self._step_calls, ticked)
+            return result
 
     def _step_on_host(self, state: TrainState, batch):
         import time as _time
@@ -1194,6 +1208,7 @@ class Trainer:
             # a new program invalidates the step-time baseline the
             # checkpoint-staging pacer calibrates against
             self._step_clock.reset()
+            self._step_account.reset()
             self._last_step_ts = None
             # the real XLA compile happens on the first dispatch; the
             # span makes "where did the first minute go" answerable from
@@ -1249,9 +1264,7 @@ class Trainer:
                 dur = now - self._last_step_ts
                 self._step_clock.record(dur)
                 self._digest_steps += 1
-                self._note_step_time(self._digest_steps, dur)
-                self._note_model_stats(self._digest_steps, result[1])
-                self._maybe_probe_comm(self._digest_steps)
+                self._after_step(self._digest_steps, dur, result[1])
             self._last_step_ts = now
         if self._timer is not None:
             self._steps_done += 1
@@ -1327,13 +1340,47 @@ class Trainer:
 
             logger.debug("comm probe failed: %s", e)
 
-    def _note_step_time(self, step: int, dur_s: float):
+    def _after_step(self, step: int, dur_s: float, metrics):
+        """The bookkeeping after a dispatch.  Most steps feed two rings;
+        every ``DLROVER_TPU_DIGEST_EVERY`` steps the *tick* runs (the
+        polls, the memory sample, the digests and their file, the read
+        of the model's sown ``stats``, the comm probe where its own
+        cadence falls on it) under one span ``trainer.step.tick`` whose
+        attributes time its parts and carry ``host_pressure``'s
+        counters."""
+        from dlrover_tpu.common import envs
+
+        every = envs.get_int("DLROVER_TPU_DIGEST_EVERY")
+        if every <= 0 or step % every != 0:
+            self._note_step_time(step, dur_s)
+            self._maybe_probe_comm(step)
+            return
+        self._ticked = True
+        with trace.span("trainer.step.tick", attrs={"step": step}) as tick:
+            parts: Dict[str, Any] = {}
+            self._note_step_time(step, dur_s, parts)
+            self._note_model_stats(step, metrics, parts)
+            self._maybe_probe_comm(step)
+            if tick is not trace.NOOP_SPAN:
+                from dlrover_tpu.trainer.step_account import host_pressure
+
+                parts.update(host_pressure())
+                tick.set_attrs(parts)
+
+    def _note_step_time(self, step: int, dur_s: float,
+                        parts: Optional[Dict[str, Any]] = None):
         """Feed the flight recorder's step ring and, every
         ``DLROVER_TPU_DIGEST_EVERY`` steps, drop this rank's step-time
         digest file (``ConfigPath.RUNTIME_METRICS``.rank<id>) — the file
         the agent folds into its heartbeat digest, which is what the
-        master's straggler/stall screens read.  Never raises into the
-        training loop."""
+        master's straggler/stall screens read.  ``parts``, where given,
+        takes the seconds of the drop's parts: ``poll_s``, ``memscope_s``,
+        ``digests_s``, ``write_s``.  Never raises into the training
+        loop."""
+        import time as _time
+
+        if parts is None:
+            parts = {}
         try:
             from dlrover_tpu.observability import flight_recorder, goodput
 
@@ -1344,6 +1391,7 @@ class Trainer:
             every = envs.get_int("DLROVER_TPU_DIGEST_EVERY")
             if every <= 0 or step % every != 0:
                 return
+            t_poll = _time.perf_counter()
             # brain action channel: apply any cross-process DCN
             # demotion the agent staged since the last digest window
             if getattr(self, "_dcn_axis", None) is not None:
@@ -1360,6 +1408,8 @@ class Trainer:
             self._reshard_seq = _reshard.poll_staged_reshard(
                 self, getattr(self, "_reshard_seq", None)
             )
+            t_digests = _time.perf_counter()
+            parts["poll_s"] = t_digests - t_poll
             import json
             import os
 
@@ -1382,7 +1432,9 @@ class Trainer:
             # attribution, mm_/mms_ keys)
             from dlrover_tpu.observability import memscope
 
+            t_memscope = _time.perf_counter()
             memscope.sample()
+            parts["memscope_s"] = _time.perf_counter() - t_memscope
             digest.update(memscope.scope().digest())
             # ... and the compile observatory (cumulative compile
             # seconds / cache hits+misses / stalls, js_ keys)
@@ -1390,6 +1442,10 @@ class Trainer:
 
             if jitscope.enabled():
                 digest.update(jitscope.scope().digest())
+            t_write = _time.perf_counter()
+            # the four observatories' digests, less the memory sample
+            parts["digests_s"] = (
+                t_write - t_digests - parts["memscope_s"])
             path = (
                 envs.get_str(ConfigPath.ENV_RUNTIME_METRICS)
                 + f".rank{envs.get_int(NodeEnv.PROCESS_ID)}"
@@ -1399,26 +1455,26 @@ class Trainer:
             with open(tmp, "w") as f:
                 json.dump(digest, f)
             os.replace(tmp, path)
+            parts["write_s"] = _time.perf_counter() - t_write
         except Exception as e:  # noqa: BLE001 - telemetry must not
             # break a training step
             from dlrover_tpu.common.log import logger
 
             logger.debug("step digest drop failed: %s", e)
 
-    def _note_model_stats(self, step: int, metrics):
-        """Every ``DLROVER_TPU_DIGEST_EVERY`` steps, keep what the model
-        sowed into ``stats`` in this step (still in flight) and record
-        what was kept the time before, finished long since, as one
-        ``trainer.model_stats`` span whose attributes are the sown names
-        with their values layer by layer.  The stepping thread never
-        waits for the device here."""
+    def _note_model_stats(self, step: int, metrics,
+                          parts: Dict[str, Any]):
+        """On the tick, keep what the model sowed into ``stats`` in this
+        step (still in flight) and record what was kept the tick before,
+        finished long since, as one ``trainer.model_stats`` span whose
+        attributes are the sown names with their values layer by layer.
+        The read of the kept leaves is inside the span and timed
+        (``parts``: ``stats_read_s``, ``stats_leaves``).  The stepping
+        thread never waits for the device here."""
+        import time as _time
+
         stats = metrics.get("stats")
         if not stats:
-            return
-        from dlrover_tpu.common import envs
-
-        every = envs.get_int("DLROVER_TPU_DIGEST_EVERY")
-        if every <= 0 or step % every != 0:
             return
         kept, self._stats_kept = self._stats_kept, (step, stats)
         if kept is None:
@@ -1426,15 +1482,20 @@ class Trainer:
         leaves = jax.tree_util.tree_leaves_with_path(kept[1])
         if not all(leaf.is_ready() for _, leaf in leaves):
             return
-        attrs = {"step": kept[0]}
-        for path, leaf in leaves:
-            name = next(str(key.key) for key in reversed(path)
-                        if hasattr(key, "key"))
-            attrs.setdefault(name, []).extend(
-                jax.device_get(leaf).ravel().tolist()
-            )
-        with trace.span("trainer.model_stats", attrs=attrs):
-            pass
+        with trace.span(
+            "trainer.model_stats", attrs={"step": kept[0]}
+        ) as span:
+            t_read = _time.perf_counter()
+            attrs: Dict[str, Any] = {}
+            for path, leaf in leaves:
+                name = next(str(key.key) for key in reversed(path)
+                            if hasattr(key, "key"))
+                attrs.setdefault(name, []).extend(
+                    jax.device_get(leaf).ravel().tolist()
+                )
+            parts["stats_read_s"] = _time.perf_counter() - t_read
+            parts["stats_leaves"] = len(leaves)
+            span.set_attrs(attrs)
 
     # -- data --------------------------------------------------------------
 
@@ -1649,6 +1710,7 @@ class Trainer:
             except Exception:  # noqa: BLE001 - telemetry must not
                 self._comm_probe = None  # break the transition
         self._step_clock.reset()
+        self._step_account.reset()
         self._last_step_ts = None
 
     def stage_live_reshard(self, axes, reason: str = ""):

@@ -259,6 +259,9 @@ class TrainerEvents:
     # blocking path; the CKPT_SAVE for the actual save follows separately
     CKPT_SYNC_FALLBACK = "trainer.ckpt.sync_fallback"
     CKPT_LOAD = "trainer.ckpt.load"
+    # an interval between two steps over twice the calm baseline, explained
+    # from the span ring one step later (trainer/step_account.py)
+    SLOW_STEP = "trainer.slow_step"
 
 
 _default: Optional[Process] = None
