@@ -170,6 +170,11 @@ class LlamaConfig:
     mla_rope_dim: int = 64
     mla_v_dim: int = 128
     mla_head_gate: bool = False
+    # the rotary part of an ``mla`` layer turns NEIGHBOURING columns,
+    # ``(x[2i], x[2i+1])`` at ``f_i = theta^(-2i/rope)`` (DeepSeek-V3's
+    # checkpoint layout, Hugging Face's ``rope_interleave``), where false
+    # turns column ``i`` with column ``i + rope/2``
+    mla_rope_interleave: bool = False
     # training by diffusion over blocks (BD3-LMs, arXiv:2503.09573; SDAR,
     # arXiv:2510.06303): the length of a block, 0 for none.  The model then
     # runs ``[noisy copy ; clean copy]`` of its input, ``2S`` rows at the
@@ -384,14 +389,23 @@ def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-          rotary_dim: Optional[int] = None, yarn=None) -> jnp.ndarray:
+          rotary_dim: Optional[int] = None, yarn=None,
+          interleave: bool = False) -> jnp.ndarray:
     """Rotary position embedding; x: [B, S, H, D].  ``rotary_dim``: the
     head's leading columns that turn (halves convention within them), the
     rest pass as they are; ``yarn`` (``AttentionNumbers.yarn``): YaRN's
-    frequencies, cos and sin both times its attention factor."""
+    frequencies, cos and sin both times its attention factor.
+    ``interleave``: pair ``i`` is the neighbours ``(x[2i], x[2i+1])`` where
+    it is ``(x[i], x[i + D/2])`` otherwise.  The result stands in the
+    halves' layout either way (pair ``i``'s two results at ``i`` and ``i +
+    D/2``): one strided read of the operand and no interleaving write, and
+    a score is the same under any one permutation of both its operands'
+    columns (Hugging Face's ``apply_rotary_pos_emb_interleave`` leaves the
+    same layout)."""
     d = x.shape[-1]
     if rotary_dim is not None and rotary_dim < d:
-        turned = _rope(x[..., :rotary_dim], positions, theta, None, yarn)
+        turned = _rope(x[..., :rotary_dim], positions, theta, None, yarn,
+                       interleave)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     if yarn is None:
         freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
@@ -403,7 +417,9 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
     sin = jnp.sin(angles)[:, :, None, :]
     if yarn is not None:
         cos, sin = cos * yarn[4], sin * yarn[4]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = ((x32[..., 0::2], x32[..., 1::2]) if interleave
+              else jnp.split(x32, 2, axis=-1))
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
@@ -852,8 +868,9 @@ class LatentAttention(nn.Module):
     without a query bottleneck; Ling-3.0's softmax layers): ``q = h W_q``,
     a head ``[q_nope | q_pe]``; ``[c | k_pe] = h W_kva``, the latent ``c``
     RMS-normalised; ``[k_nope | v] = c W_kvb`` a head; RoPE on ``q_pe`` a
-    head and on the ONE ``k_pe``, which every head shares; ``o = softmax_
-    causal((q_nope k_nope^T + q_pe k_pe^T) / sqrt(nope + rope)) v``
+    head and on the ONE ``k_pe``, which every head shares (by halves, or
+    with ``mla_rope_interleave`` by neighbouring pairs: ``_rope``); ``o =
+    softmax_causal((q_nope k_nope^T + q_pe k_pe^T) / sqrt(nope + rope)) v``
     (``ops/attention.py::latent_attention``: scores in two products, never
     a key of ``nope + rope`` a head in HBM); with ``mla_head_gate`` ``o_h *
     sigmoid(h w_gate)_h``, one gate a head; the output projection.  Scope
@@ -888,14 +905,17 @@ class LatentAttention(nn.Module):
                                  down[..., :cfg.mla_kv_rank])
             up = dense(features=(H, nope + wide), name="kv_b_proj",
                        kernel_init=init(None, "heads", "head_dim"))(latent)
+            pairs = cfg.mla_rope_interleave
             k_pe = _rope(down[..., None, cfg.mla_kv_rank:], positions,
-                         cfg.rope_theta)[:, :, 0]
-            q_pe = _rope(q[..., nope:], positions, cfg.rope_theta)
+                         cfg.rope_theta, interleave=pairs)[:, :, 0]
+            q_pe = _rope(q[..., nope:], positions, cfg.rope_theta,
+                         interleave=pairs)
         q_nope = nn.with_logical_constraint(
             q[..., :nope], ("batch", "seq", "heads", "head_dim"))
         with jax.named_scope("attn.core"):
             out = latent_attention(
-                q_nope, q_pe, up[..., :nope], k_pe, up[..., nope:])
+                q_nope, q_pe, up[..., :nope], k_pe, up[..., nope:],
+                rope="pairs" if pairs else "halves")
         if cfg.mla_head_gate:
             gate = dense(features=H, name="gate_proj",
                          kernel_init=init("embed", "heads"))(x)

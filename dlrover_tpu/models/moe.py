@@ -146,7 +146,8 @@ class MoELlamaConfig(LlamaConfig):
     shared_intermediate_size: int = 0
     # the choice by groups: ``n_group`` runs of consecutive experts, the
     # ``topk_group`` with the largest sum of their two best scores kept,
-    # the ``top_k`` chosen inside them.  0: no groups
+    # the ``top_k`` chosen inside them.  0: no groups (and so is one group,
+    # or every group kept: the plain ``top_k``)
     n_group: int = 0
     topk_group: int = 0
     # a bias a layer added to the scores for the CHOICE alone, a buffer the
@@ -543,7 +544,9 @@ def _choose(module, scores):
             jnp.float32).value
         choice = choice + jax.lax.stop_gradient(bias)
         module.sow("stats", "bias_abs_max", jnp.abs(bias).max())
-    if not cfg.n_group:
+    # no groups, or every group kept (``n_group`` 1, DeepSeek-V3's own
+    # degenerate case): the plain top-k, and no pass over groups runs
+    if cfg.topk_group >= cfg.n_group:
         return _at_kept(scores, jax.lax.top_k(choice, k)[1])
     # ONE ``top_k`` over the columns (a sort on the chip, 20 ms a layer
     # and pass at 16384 x 512: PERF.md, PR 48); the groups by passes of
@@ -822,6 +825,8 @@ class MoEMLP(nn.Module):
             backward=6, kept=f"{kept.MOE_PRODUCTS},{kept.MOE_ROUTE}",
             # how the passes at each extent sum by token
             combine=",".join(_combine_body(e, chip) for e in extents),
+            # the dense SwiGLU every token visits beside the routed experts
+            shared_experts=cfg.shared_experts, shared_width=cfg.shared_width(),
         )
         # a source rank's two products and the sort they are in; this
         # chip's tokens' logits and choice, and a share's count of rows

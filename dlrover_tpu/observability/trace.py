@@ -460,6 +460,17 @@ def add_event(name: str, **attrs: Any) -> bool:
 #: trace-time records already made with no span open (a bare
 #: ``model.init``): one for each distinct reading, not one a layer
 _noted_without_span = set()
+#: name -> every distinct reading of the process, oldest first, kept for
+#: the run: the recorder's ring rotates the span that carried one out
+#: after some hundred steps, and what was chosen at trace time still holds
+_trace_time_notes: Dict[str, List[Dict[str, Any]]] = {}
+
+
+def trace_time_notes(name: str) -> List[Dict[str, Any]]:
+    """Every distinct ``note_trace_time(name, ...)`` reading since the
+    process began, oldest first (as many as programs were traced, not as
+    steps ran)."""
+    return [dict(attrs) for attrs in _trace_time_notes.get(name, ())]
 
 
 def note_trace_time(name: str, **attrs: Any) -> None:
@@ -467,8 +478,12 @@ def note_trace_time(name: str, **attrs: Any) -> None:
     step was being traced (``attention.path``, ``moe.path``): an event on
     the span open at the time (``trainer.step.dispatch``), a span of its
     own where none is open.  The layers of one trace make the same
-    reading; only the first is kept.  It runs while tracing and costs a
-    step nothing."""
+    reading; only the first is kept, in the span and in
+    ``trace_time_notes``.  It runs while tracing and costs a step
+    nothing."""
+    kept = _trace_time_notes.setdefault(name, [])
+    if attrs not in kept:
+        kept.append(dict(attrs))
     open_span = _CURRENT.get()
     if open_span is not None:
         if any(e["name"] == name and e["attrs"] == attrs

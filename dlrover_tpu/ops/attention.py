@@ -844,20 +844,25 @@ def _latent_reference(q_nope, q_pe, k_nope, k_pe, v):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def latent_attention(q_nope, q_pe, k_nope, k_pe, v, interpret: bool = False):
+def latent_attention(q_nope, q_pe, k_nope, k_pe, v, interpret: bool = False,
+                     rope: Optional[str] = None):
     """Causal self-attention a head with scores ``(q_nope k_nope^T + q_pe
     k_pe^T) / sqrt(D + R)``: ``q_nope, k_nope`` [B, S, H, D], ``q_pe`` [B,
     S, H, R], ``k_pe`` [B, S, R] (one rotary key head, shared by every
     query head), ``v`` [B, S, H, Dv] -> [B, S, H, Dv].  Softmax in float32.
     On a TPU through the Pallas kernels (no ``[H, S, S]`` array in HBM,
     forward or backward), ``jax.numpy`` elsewhere; ``interpret``: the
-    kernels in the Pallas interpreter (tests off the chip).  Sub-scope
+    kernels in the Pallas interpreter (tests off the chip);
+    ``rope``: how the caller turned the rotary parts (``pairs`` or
+    ``halves``), for the ``attention.path`` record alone.  Sub-scope
     ``latent`` of the caller's ``attn.core``."""
     B, S, H, D = q_nope.shape
     R, Dv = q_pe.shape[-1], v.shape[-1]
     exact = "pallas" if interpret else latent_attention_path(
         jax.default_backend(), S, H, D, R, Dv)
     attrs = dict(impl="latent", seq=S, heads=H, qk=f"{D}+{R}", v=Dv)
+    if rope is not None:
+        attrs["rope"] = rope
     with jax.named_scope("latent"):
         if exact != "pallas":
             trace.note_trace_time("attention.path", blocks=None,

@@ -724,6 +724,66 @@ class TestTrainerStep:
         mem = compiled.memory_analysis()
         assert 5.5e9 < mem.argument_size_in_bytes < 5.6e9
 
+    def test_kanana2_widths_the_dense_layer_and_two_periods_of_one(
+            self, topo, as_if_on_tpu, monkeypatch):
+        """The Kanana-2 cell's configuration file through its family at B1
+        S4096 and three of its six layers (a quarter of the cell's length
+        and half its depth, for the test's time; the cell's own is
+        ``benchmarks/tests/compile_described.py kanana2_30b_1of8``: 5.122
+        GiB of arguments, 9.601 of temporaries at 16,384 x 6 layers,
+        accepted): ONE kind all the way down, so the prefix's dense latent
+        layer is a loop of one turn (forward, dQ, dK/dV: no second forward)
+        and the periods of one layer are one loop a pass whose
+        rematerialised pass runs NO latent kernel (the layer keeps ``out``
+        and the LSE, ``ops/pallas/kept.py``); every kernel under
+        ``attn.core`` / ``latent`` at all 32 heads, nothing ``[S, S]``, the
+        record says the rotary part turned pairs, the routed block names
+        its two shared experts and ran no pass over groups."""
+        from benchmarks.common import HERE, load_module, read_json
+        from dlrover_tpu.observability import trace
+
+        notes = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: notes.append((name, attrs)))
+        config = {**read_json(HERE, "configs", "kanana2_30b_1of8.json"),
+                  "num_hidden_layers": 3}
+        family = load_module("families", "kanana2")
+        S = 4096
+
+        def cell():
+            return family.build(config, False, S), (1, S)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh, cell)
+        text = compiled.as_text()
+        # no head's scores are an array ([1, S, 32 x 128] is the attention's
+        # output, as wide as the sequence is long here)
+        assert not re.search(rf"32,{S},{S}\]|{S},32,{S}\]|{S},{S},32\]", text)
+        found = trace.parse_device_scopes(text)
+        # (the grouped matmuls are custom calls too, under ``moe/gmm``)
+        latent = sorted(
+            found.scopes["%" + name] for name in _kernel_names(text)
+            if found.scopes["%" + name][:2] == ("attn.core", "latent"))
+        assert latent == sorted(
+            [("attn.core", "latent", "forward")] * 2
+            + [("attn.core", "latent", "backward")] * 4)
+        (path,) = [attrs for name, attrs in notes
+                   if name == "attention.path"][:1]
+        assert path["impl"] == "latent" and path["exact"] == "pallas"
+        assert path["heads"] == 32 and path["qk"] == "128+64"
+        assert path["rope"] == "pairs"
+        (moe,) = {tuple(sorted(attrs.items())) for name, attrs in notes
+                  if name == "moe.path"}
+        assert dict(moe)["shared_experts"] == 2
+        assert dict(moe)["shared_width"] == 1536
+        assert dict(moe)["held"] == 16 and dict(moe)["experts"] == 128
+        assert ("moe", "shared", "forward") in set(found.scopes.values())
+        # the dense layer, two routed layers, an eighth of the vocabulary:
+        # 352.9 M parameters at 8 bytes of state
+        mem = compiled.memory_analysis()
+        assert 2.8e9 < mem.argument_size_in_bytes < 2.9e9
+
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
         widths and share (32 query heads on 4 key heads of 128, 16 of 128
