@@ -854,8 +854,10 @@ def latent_attention(q_nope, q_pe, k_nope, k_pe, v, interpret: bool = False,
     forward or backward), ``jax.numpy`` elsewhere; ``interpret``: the
     kernels in the Pallas interpreter (tests off the chip);
     ``rope``: how the caller turned the rotary parts (``pairs`` or
-    ``halves``), for the ``attention.path`` record alone.  Sub-scope
-    ``latent`` of the caller's ``attn.core``."""
+    ``halves``), for the ``attention.path`` record alone, which on the
+    kernels' path also says ``backward=one_call`` (a live pair of blocks
+    scored once, all five gradients from one kernel, as FA2's record says
+    of its calls).  Sub-scope ``latent`` of the caller's ``attn.core``."""
     B, S, H, D = q_nope.shape
     R, Dv = q_pe.shape[-1], v.shape[-1]
     exact = "pallas" if interpret else latent_attention_path(
@@ -877,7 +879,7 @@ def latent_attention(q_nope, q_pe, k_nope, k_pe, v, interpret: bool = False,
 
         blocks = blocks_for(S)
         trace.note_trace_time("attention.path", blocks=blocks,
-                              exact="pallas", **attrs)
+                              exact="pallas", backward="one_call", **attrs)
         kept.note("latent", **kept_bytes(v))
         return latent_attention_kernels(
             q_nope, q_pe, k_nope, k_pe, v, *blocks, interpret)

@@ -2,11 +2,14 @@
 arXiv:2405.04434): causal softmax attention whose score is the SUM OF TWO
 PRODUCTS, ``q_nope k_nope^T`` a head (128 wide) and ``q_pe k_pe^T`` against
 ONE rotary key head that every query head shares (64 wide), and whose
-values are 128 wide.  Forward and backward in FA2's split
-(``flash_attention.py``, whose numerics these share: float32 scores and
-softmax, float32 accumulation, the per-row log-sum-exp the backward's
-residual); a ``[block_q, block_kv]`` tile of scores lives and dies in VMEM,
-so no ``[heads, S, S]`` array reaches HBM in either pass.
+values are 128 wide.  Two kernels: the forward under the online softmax,
+and ONE backward call that scores a live pair of blocks once and yields
+that pair's part of all five gradients (nine block products; FA2's split
+into a dQ and a dK/dV kernel, which this file ran until PR 56, scores a
+pair twice: eleven).  The numerics are ``flash_attention.py``'s: float32
+scores and softmax, float32 accumulation, the per-row log-sum-exp the
+backward's residual; a ``[block_q, block_kv]`` tile of scores lives and
+dies in VMEM, so no ``[heads, S, S]`` array reaches HBM in either pass.
 
 Why kernels of their own: FA2's takes one head size for q, k and v and one
 key head a query head (or a GQA group); here the contraction is 128 + 64 =
@@ -24,8 +27,13 @@ as ``[B, S, H*128]``, a head one 128-lane column block, as FA2's.  The
 are a multiple of 128 or the whole axis): ``q_pe`` and its gradient go
 head-major, ``[B, H, S, 64]`` (a transpose of a sixth of q's bytes), the
 shared ``k_pe`` is ``[B, S, 64]`` as it is, and its gradient leaves the
-dK/dV kernel a head at a time as float32 ``[B, H, S, 64]`` and is summed
-over the heads outside (the kernel's grid is parallel over heads).
+backward kernel a head at a time as float32 ``[B, H, S, 64]`` and is summed
+over the heads outside (the kernel's grid is parallel over heads).  The
+backward kernel walks key blocks outermost, so dQ gathers across grid
+steps that are not neighbours: its two parts are float32 accumulators in
+HBM, ``[B, S, H*128]`` and head-major ``[B, H, S, 128]`` (the 64-wide part
+in the lower lanes: a 64-lane array is stored 128 lanes wide in HBM anyway,
+and Mosaic takes no 64-lane slice of one), cast once after the call.
 
 What a rematerialised layer keeps (``kept.py``): ``out`` and the LSE as
 ``[B, H, S]`` float32; its backward pass recomputes the projections in
@@ -51,9 +59,10 @@ from dlrover_tpu.ops.pallas.flash_attention import (
 #: the widths the kernels take: a head's 128-wide part is one column block
 NOPE_DIM = V_DIM = LANES
 
-# the backward kernels hold q, dO and O blocks, two key blocks and a
-# tile's scores, probabilities and their gradients in float32: 1024 x 1024
-# tiles compile for a v5e under 48 MiB of its core's 128
+# the backward kernel holds q, dO and O blocks, two key blocks, a q
+# block's two dQ tiles and a tile's scores, probabilities and their
+# gradients in float32: 1024 x 1024 tiles compile for a v5e under 48 MiB
+# of its core's 128
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 #: (block_q, block_kv) on a v5e (PERF.md section 6, PR 48, has the sweep)
@@ -78,9 +87,9 @@ def blocks_for(seq_len: int):
     return tuple(out)
 
 
-def _compiler_params():
+def _compiler_params(third_axis: str):
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", third_axis, "arbitrary"),
         vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
@@ -134,77 +143,76 @@ def _fwd_kernel(qn_ref, qp_ref, kn_ref, kp_ref, v_ref, out_ref, lse_ref,
             m_ref[:, :1] + jnp.log(l), lse_ref.shape[2:])
 
 
-def _recomputed(qn, qp, kn, kp, v, do, o, lse, scale, q_start, kv_start):
-    """``(p, ds)`` of a tile, float32, from the saved LSE."""
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    p = jnp.exp(_scores(qn, qp, kn, kp, scale, q_start, kv_start) - lse)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    return p, p * (dp - delta) * scale
-
-
-def _bwd_dq_kernel(qn_ref, qp_ref, kn_ref, kp_ref, v_ref, do_ref, o_ref,
-                   lse_ref, dqn_ref, dqp_ref, dqn_acc, dqp_acc,
-                   *, block_q, block_kv, scale):
-    """grid (batch, head, q block, kv block): dQ of both parts accumulated
-    over the kv blocks up to the diagonal."""
-    q_start = pl.program_id(2) * block_q
-    kv_idx = pl.program_id(3)
-    kv_start = kv_idx * block_kv
-
-    @pl.when(kv_idx == 0)
-    def _init():
-        dqn_acc[:] = jnp.zeros_like(dqn_acc)
-        dqp_acc[:] = jnp.zeros_like(dqp_acc)
-
-    @pl.when(kv_start <= q_start + block_q - 1)
-    def _compute():
-        kn, kp = kn_ref[0], kp_ref[0]
-        _, ds = _recomputed(
-            qn_ref[0], qp_ref[0, 0], kn, kp, v_ref[0], do_ref[0], o_ref[0],
-            lse_ref[0, 0, :, :1], scale, q_start, kv_start)
-        nn = (((1,), (0,)), ((), ()))
-        dqn_acc[:] += jax.lax.dot_general(
-            ds.astype(kn.dtype), kn, nn, preferred_element_type=jnp.float32)
-        dqp_acc[:] += jax.lax.dot_general(
-            ds.astype(kp.dtype), kp, nn, preferred_element_type=jnp.float32)
-
-    @pl.when(kv_idx == pl.num_programs(3) - 1)
-    def _finalize():
-        dqn_ref[0] = dqn_acc[:].astype(dqn_ref.dtype)
-        dqp_ref[0, 0] = dqp_acc[:].astype(dqp_ref.dtype)
-
-
-def _bwd_dkv_kernel(qn_ref, qp_ref, kn_ref, kp_ref, v_ref, do_ref, o_ref,
-                    lse_ref, dkn_ref, dkp_ref, dv_ref, dkn_acc, dkp_acc,
-                    dv_acc, *, block_q, block_kv, scale):
-    """grid (batch, head, kv block, q block): one head's dK of both parts
-    and dV accumulated over the q blocks from the diagonal on."""
-    kv_start = pl.program_id(2) * block_kv
-    q_idx = pl.program_id(3)
-    q_start = q_idx * block_q
+def _bwd_kernel(qn_ref, qp_ref, kn_ref, kp_ref, v_ref, do_ref, o_ref, lse_ref,
+                dqn_hbm, dqp_hbm, dkn_ref, dkp_ref, dv_ref, dkn_acc, dkp_acc,
+                dv_acc, dqn_tile, dqp_tile, zeros, arrived, left,
+                *, block_q, block_kv, scale):
+    """grid (batch, head, kv block, q block): a key block resident, the
+    head's q blocks streamed past it from the diagonal on.  A live step
+    scores its pair ONCE and adds its part to dV and both parts of dK in
+    their accumulators and to both parts of the q block's dQ, which live in
+    HBM as float32 between key blocks (``flash_attention.py::
+    _flash_bwd_kernel``'s way): the step fetches the two blocks, adds, and
+    writes them back before it ends, so the next step that names them reads
+    what this one wrote; a masked step touches nothing.  Key block 0, which
+    every query block sees, adds to zeros: nothing zero-fills the
+    accumulators."""
+    batch, head = pl.program_id(0), pl.program_id(1)
+    kv_idx, q_idx = pl.program_id(2), pl.program_id(3)
+    kv_start, q_start = kv_idx * block_kv, q_idx * block_q
 
     @pl.when(q_idx == 0)
     def _init():
-        dkn_acc[:] = jnp.zeros_like(dkn_acc)
-        dkp_acc[:] = jnp.zeros_like(dkp_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        for ref in (dkn_acc, dkp_acc, dv_acc, zeros):
+            ref[:] = jnp.zeros_like(ref)
+
+    # dQ's two parts, each (fetch, the same from zeros, write-back, tile)
+    rows = pl.ds(q_start, block_q)
+    parts = [
+        (pltpu.make_async_copy(block, tile, arrived.at[n]),
+         pltpu.make_async_copy(zeros, tile, arrived.at[n]),
+         pltpu.make_async_copy(tile, block, left.at[n]), tile)
+        for n, (block, tile) in enumerate((
+            (dqn_hbm.at[batch, rows, pl.ds(head * NOPE_DIM, NOPE_DIM)],
+             dqn_tile),
+            (dqp_hbm.at[batch, head, rows], dqp_tile)))]
 
     @pl.when(kv_start <= q_start + block_q - 1)
     def _compute():
-        qn, qp, do = qn_ref[0], qp_ref[0, 0], do_ref[0]
-        p, ds = _recomputed(
-            qn, qp, kn_ref[0], kp_ref[0], v_ref[0], do, o_ref[0],
-            lse_ref[0, 0, :, :1], scale, q_start, kv_start)
+        # what dQ holds so far is on its way while the pair is scored: the
+        # blocks from HBM or, at key block 0, zeros from VMEM by the same
+        # semaphores, so that the waits and the adds below are
+        # straight-line code whatever the key block (PERF.md section 6,
+        # PR 54: in a region of their own they cost FA2's call 3%)
+        for fetch, from_zeros, _, _ in parts:
+            pl.when(kv_idx > 0)(fetch.start)
+            pl.when(kv_idx == 0)(from_zeros.start)
+        qn, qp, kn, kp = qn_ref[0], qp_ref[0, 0], kn_ref[0], kp_ref[0]
+        do, o = do_ref[0], o_ref[0]
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        p = jnp.exp(_scores(qn, qp, kn, kp, scale, q_start, kv_start)
+                    - lse_ref[0, 0, :, :1])
+        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * scale).astype(qn.dtype)
+        # dQ first: its write-back runs under the three products after it
+        nn = (((1,), (0,)), ((), ()))
+        for (fetch, _, write_back, tile), k in zip(parts, (kn, kp)):
+            mine = jax.lax.dot_general(
+                ds, k, nn, preferred_element_type=jnp.float32)
+            fetch.wait()
+            tile[:, :k.shape[-1]] += mine
+            write_back.start()
         tn = (((0,), (0,)), ((), ()))
         dv_acc[:] += jax.lax.dot_general(       # P^T dO
             p.astype(do.dtype), do, tn, preferred_element_type=jnp.float32)
-        ds = ds.astype(qn.dtype)
         dkn_acc[:] += jax.lax.dot_general(      # dS^T Q, a part each
             ds, qn, tn, preferred_element_type=jnp.float32)
         dkp_acc[:] += jax.lax.dot_general(
             ds, qp, tn, preferred_element_type=jnp.float32)
+        for _, _, write_back, _ in parts:
+            write_back.wait()
 
     @pl.when(q_idx == pl.num_programs(3) - 1)
     def _finalize():
@@ -279,7 +287,7 @@ def _forward(q_nope, q_pe, k_nope, k_pe, v, block_q, block_kv, interpret):
         scratch_shapes=[pltpu.VMEM((call.block_q, D), jnp.float32),
                         pltpu.VMEM((call.block_q, LANES), jnp.float32),
                         pltpu.VMEM((call.block_q, LANES), jnp.float32)],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params("parallel"),
         interpret=interpret,
     )(_flat(q_nope), q_pe, _flat(k_nope), k_pe, _flat(v))
     return out.reshape(v.shape), lse
@@ -287,56 +295,50 @@ def _forward(q_nope, q_pe, k_nope, k_pe, v, block_q, block_kv, interpret):
 
 def _backward(q_nope, q_pe, k_nope, k_pe, v, out, lse, grad_out, block_q,
               block_kv, interpret):
-    """``(dq_nope, dq_pe [B, H, S, R], dk_nope, dk_pe [B, S, R], dv)``."""
+    """``(dq_nope, dq_pe [B, H, S, R], dk_nope, dk_pe [B, S, R], dv)`` from
+    ONE call.  Both parts of dQ gather over the key blocks, the grid's
+    third axis, in float32 results that stay in HBM (no block spec: the
+    kernel copies a block in and out itself, so no pipeline stands between
+    a write and the next read of one block) and are cast once after the
+    call.  The key-block axis is ``"arbitrary"`` for them (a v5e chip has
+    one core: nothing is lost)."""
     call = _Call(q_nope, q_pe, block_q, block_kv)
     B, S, H, D, R = call.B, call.S, call.H, call.D, call.R
-    operands = (_flat(q_nope), q_pe, _flat(k_nope), k_pe, _flat(v),
-                _flat(grad_out), _flat(out), lse)
+    bq, bkv = call.block_q, call.block_kv
 
-    def in_specs(spec):
-        return [spec["q"], spec["q_pe"], spec["kv"], spec["k_pe"],
-                spec["kv"], spec["q"], spec["q"], spec["lse"]]
+    # kv blocks resident, q blocks streamed from the diagonal on: a masked
+    # step asks for the block the next live step takes
+    def step(b, h, j, i):
+        return b, h, jnp.maximum(i, _first_q_block(j, bq, bkv)), j
 
-    def dq_step(b, h, i, j):
-        return b, h, i, jnp.minimum(
-            j, _last_kv_block(i, call.block_q, call.block_kv))
-
-    spec = call.specs(dq_step)
-    dq_nope, dq_pe = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **call.settings),
-        grid=(B, H, call.num_q, call.num_kv),
-        in_specs=in_specs(spec),
-        out_specs=[spec["q"], spec["q_pe"]],
-        out_shape=[jax.ShapeDtypeStruct((B, S, H * D), q_nope.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, R), q_pe.dtype)],
-        scratch_shapes=[pltpu.VMEM((call.block_q, D), jnp.float32),
-                        pltpu.VMEM((call.block_q, R), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(*operands)
-
-    # kv blocks resident, q blocks streamed from the diagonal on
-    def dkv_step(b, h, j, i):
-        return b, h, jnp.maximum(
-            i, _first_q_block(j, call.block_q, call.block_kv)), j
-
-    spec = call.specs(dkv_step)
-    dk_nope, dk_pe, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **call.settings),
+    spec = call.specs(step)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dq_nope, dq_pe, dk_nope, dk_pe, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, **call.settings),
         grid=(B, H, call.num_kv, call.num_q),
-        in_specs=in_specs(spec),
-        out_specs=[spec["kv"], spec["dk_pe"], spec["kv"]],
-        out_shape=[jax.ShapeDtypeStruct((B, S, H * D), k_nope.dtype),
+        in_specs=[spec["q"], spec["q_pe"], spec["kv"], spec["k_pe"],
+                  spec["kv"], spec["q"], spec["q"], spec["lse"]],
+        out_specs=[in_hbm, in_hbm, spec["kv"], spec["dk_pe"], spec["kv"]],
+        out_shape=[jax.ShapeDtypeStruct((B, S, H * D), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, S, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((B, S, H * D), k_nope.dtype),
                    jax.ShapeDtypeStruct((B, H, S, R), jnp.float32),
                    jax.ShapeDtypeStruct((B, S, H * D), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((call.block_kv, D), jnp.float32),
-                        pltpu.VMEM((call.block_kv, R), jnp.float32),
-                        pltpu.VMEM((call.block_kv, D), jnp.float32)],
-        compiler_params=_compiler_params(),
+        scratch_shapes=[pltpu.VMEM((bkv, D), jnp.float32),
+                        pltpu.VMEM((bkv, R), jnp.float32),
+                        pltpu.VMEM((bkv, D), jnp.float32),
+                        # a q block's dQ, and the zeros key block 0 adds to
+                        pltpu.VMEM((bq, D), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=_compiler_params("arbitrary"),
         interpret=interpret,
-    )(*operands)
-    return (dq_nope.reshape(q_nope.shape), dq_pe,
-            dk_nope.reshape(k_nope.shape),
+    )(_flat(q_nope), q_pe, _flat(k_nope), k_pe, _flat(v), _flat(grad_out),
+      _flat(out), lse)
+    return (dq_nope.astype(q_nope.dtype).reshape(q_nope.shape),
+            dq_pe[..., :R].astype(q_pe.dtype), dk_nope.reshape(k_nope.shape),
             dk_pe.sum(axis=1).astype(k_pe.dtype), dv.reshape(v.shape))
 
 

@@ -244,9 +244,10 @@ def test_selected_attention_kernels_compile_and_the_reader_sees_them(
 def test_latent_attention_kernels_compile_and_hold_nothing_seq_by_seq(
         one_chip):
     """The Ling-3.0 cell's shapes: 16384 positions, 8 heads, scores over
-    128 + 64 against one shared rotary key, values of 128.  Forward, dQ and
-    dK/dV are three custom calls named after the ``latent`` scope, and no
-    array of the compiled program has two dimensions of the sequence."""
+    128 + 64 against one shared rotary key, values of 128.  The forward
+    and the ONE backward call are two custom calls named after the
+    ``latent`` scope, and no array of the compiled program has two
+    dimensions of the sequence."""
     from dlrover_tpu.ops.pallas.latent_attention import (
         blocks_for,
         latent_attention_kernels,
@@ -271,7 +272,7 @@ def test_latent_attention_kernels_compile_and_hold_nothing_seq_by_seq(
     text = jax.jit(both).lower(
         wide, sds(1, S, H, 64), wide, sds(1, S, 64), wide).compile().as_text()
     names = _kernel_names(text)
-    assert len(names) == 3 and all("latent" in name for name in names)
+    assert len(names) == 2 and all("latent" in name for name in names)
     assert not re.search(r"\[[\d,]*16384,16384[\d,]*\]", text)
     # the shared key's gradient: a head at a time out of the kernel, summed
     assert f"f32[1,{H},{S},64]" in text
@@ -730,10 +731,10 @@ class TestTrainerStep:
         S4096 and three of its six layers (a quarter of the cell's length
         and half its depth, for the test's time; the cell's own is
         ``benchmarks/tests/compile_described.py kanana2_30b_1of8``: 5.122
-        GiB of arguments, 9.601 of temporaries at 16,384 x 6 layers,
+        GiB of arguments, 9.524 of temporaries at 16,384 x 6 layers,
         accepted): ONE kind all the way down, so the prefix's dense latent
-        layer is a loop of one turn (forward, dQ, dK/dV: no second forward)
-        and the periods of one layer are one loop a pass whose
+        layer is a loop of one turn (forward, ONE backward call: no second
+        forward) and the periods of one layer are one loop a pass whose
         rematerialised pass runs NO latent kernel (the layer keeps ``out``
         and the LSE, ``ops/pallas/kept.py``); every kernel under
         ``attn.core`` / ``latent`` at all 32 heads, nothing ``[S, S]``, the
@@ -767,12 +768,12 @@ class TestTrainerStep:
             if found.scopes["%" + name][:2] == ("attn.core", "latent"))
         assert latent == sorted(
             [("attn.core", "latent", "forward")] * 2
-            + [("attn.core", "latent", "backward")] * 4)
+            + [("attn.core", "latent", "backward")] * 2)
         (path,) = [attrs for name, attrs in notes
                    if name == "attention.path"][:1]
         assert path["impl"] == "latent" and path["exact"] == "pallas"
         assert path["heads"] == 32 and path["qk"] == "128+64"
-        assert path["rope"] == "pairs"
+        assert path["rope"] == "pairs" and path["backward"] == "one_call"
         (moe,) = {tuple(sorted(attrs.items())) for name, attrs in notes
                   if name == "moe.path"}
         assert dict(moe)["shared_experts"] == 2
