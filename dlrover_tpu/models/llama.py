@@ -25,6 +25,7 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
   ``dlrover_tpu.ops``).
 """
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -187,6 +188,51 @@ class LlamaConfig:
     mask_token_id: int = 0
     noise_eps: float = 1e-3
     noise_seed: int = 0
+    # the norms: ``"rms"`` (``RMSNorm``) or ``"layer"`` (``LayerNorm``: mean
+    # taken off, a learned scale and bias; eps ``rms_norm_eps``)
+    norm: str = "rms"
+    # the output head reads the embedding table (logits ``x E^T``): one
+    # table in the tree, no ``lm_head`` (``pred_heads`` 1 alone)
+    tie_embeddings: bool = False
+    # biases on a softmax layer's q, k, v and output projections
+    attention_bias: bool = False
+    # differential attention in every ``gqa``, ``swa`` and ``xattn`` layer
+    # (arXiv:2410.05258 in the head-paired form of Phi-4-mini-flash's model
+    # code): query heads ``(2j, 2j+1)`` are ``q1_j, q2_j``, key heads ``(2m,
+    # 2m+1)`` ``k1_m, k2_m``, value heads ``(2m, 2m+1)`` side by side ``V_m``
+    # of ``2 head_dim``; ``O_j = (softmax(q1 k1^T) - lambda softmax(q2
+    # k2^T)) V_m`` under the layer's mask, RMS-normalised over its ``2
+    # head_dim`` (a learned scale) and times ``1 - lambda_init``; ``lambda =
+    # exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, ``lambda_init = 0.8 -
+    # 0.6 exp(-0.3 i)`` at layer ``i`` of the stack
+    diff_attention: bool = False
+    # a ``mamba`` layer (Mamba-1's mixer, arXiv:2312.00752): ``[a | z] = h
+    # W_in`` of ``mamba_expand * hidden_size`` each; ``a = silu(conv(a) +
+    # b)``, causal and depthwise over ``mamba_conv`` taps; ``[r | B | C] = a
+    # W_x`` (``mamba_dt_rank`` + ``mamba_state`` + ``mamba_state``); ``delta
+    # = softplus(r W_dt + b_dt)``; ``A = -exp(A_log)``; the selective scan
+    # (``ops/selective_scan.py``) gives ``Y``; ``out = (Y * silu(z))
+    # W_out``.  ``mamba_state`` 0: no such layer; ``mamba_dt_rank`` 0:
+    # ``ceil(hidden_size / 16)``
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # a decoder-hybrid-decoder stack (SambaY, arXiv:2507.06607; YOCO,
+    # arXiv:2405.05254): after the periods of ``layer_pattern`` the layers
+    # of ``memory_layers`` stand ONCE (a ``mamba`` layer and a softmax layer,
+    # parameters under ``memory/<run>``) and hand on the ``mamba`` layer's
+    # scan output ``Y`` (before its gate) and the softmax layer's keys and
+    # values; then ``cross_periods`` periods of ``cross_pattern`` (under
+    # ``cross/<run>``, stacked ``[cross_periods, run, ...]``), whose kinds
+    # read that memory: ``gmu`` (a gated memory unit, ``out = (Y * silu(h
+    # W_in)) W_out``: no scan, no convolution) and ``xattn`` (a softmax layer
+    # with a query and an output projection alone, over the handed keys and
+    # values, causal).  All counted in ``num_layers``.
+    # ``hybrid_layout`` derives all five from a depth and a period
+    memory_layers: Tuple[str, ...] = ()
+    cross_pattern: Tuple[str, ...] = ()
+    cross_periods: int = 0
 
     def __post_init__(self):
         valid = ("reference", "flash", "ring")
@@ -208,15 +254,16 @@ class LlamaConfig:
             raise ValueError(
                 "eva_window needs an eva_chunk that divides it, a key head "
                 "a query head, and no indexer")
-        entries = self.layer_prefix + self.layer_pattern
+        entries = (self.layer_prefix + self.layer_pattern
+                   + self.memory_layers + self.cross_pattern)
         kinds = {layer_kind(entry)[0] for entry in entries}
         if entries and (
                 not self.layer_pattern
                 or kinds - set(LAYER_KINDS)
                 or any(entry.partition(":")[2] not in ("", "dense")
                        for entry in entries)
-                or (self.num_layers - len(self.layer_prefix))
-                % len(self.layer_pattern)
+                or self._body_layers() < 1
+                or self._body_layers() % len(self.layer_pattern)
                 or ("kda" in kinds and not self.kda_heads)
                 or ("mla" in kinds and not self.mla_kv_rank)
                 or ("swa" in kinds and self.sliding_window < 1)
@@ -230,6 +277,36 @@ class LlamaConfig:
                 "where there is a kda layer, mla_kv_rank where there is an "
                 "mla layer, sliding_window where there is a swa layer and "
                 "dense_intermediate_size where one is dense")
+        reads = {"gmu", "xattn"}
+        own = {layer_kind(e)[0] for e in
+               self.layer_prefix + self.layer_pattern + self.memory_layers}
+        if (self.norm not in ("rms", "layer")
+                or (self.tie_embeddings and self.pred_heads > 1)
+                or ("mamba" in kinds and not self.mamba_state)
+                or own & reads
+                or {layer_kind(e)[0] for e in self.cross_pattern} - reads
+                or bool(self.cross_periods) != bool(self.cross_pattern)
+                or (self.cross_pattern and sorted(
+                    layer_kind(e)[0] in ("gqa", "swa")
+                    for e in self.memory_layers) != [False, True])
+                or (self.memory_layers and ("mamba" not in {
+                    layer_kind(e)[0] for e in self.memory_layers}
+                    or not self.diff_attention))
+                or (self.diff_attention and (
+                    self.num_heads % 2 or self.num_kv_heads % 2
+                    or (self.num_heads // 2) % (self.num_kv_heads // 2)
+                    or self.swa_heads or self.qk_norm or self.index_topk
+                    or self.eva_window or self.block_diffusion
+                    or self.attn_gate or self.attn_head_gate))):
+            raise ValueError(
+                "norm is 'rms' or 'layer'; tie_embeddings goes with one "
+                "prediction head; a mamba layer needs mamba_state; gmu and "
+                "xattn layers stand in cross_pattern alone, which needs "
+                "cross_periods and memory_layers of one mamba and one "
+                "softmax layer (under diff_attention: the plain softmax "
+                "layer hands nothing on); diff_attention pairs an even number of query "
+                "and of key heads and goes with no qk_norm, gate, indexer, "
+                "eva_window, block_diffusion or swa_heads")
         if self.sliding_window and (
                 self.index_topk or self.eva_window or self.block_diffusion
                 or (self.swa_heads or self.num_heads) % self.num_kv_heads):
@@ -303,10 +380,27 @@ class LlamaConfig:
                     [f"{entry.replace(':', '_')}_{len(runs)}", entry, 1])
         return [tuple(run) for run in runs]
 
+    def _body_layers(self) -> int:
+        """The layers the periods of ``layer_pattern`` make up."""
+        return (self.num_layers - len(self.layer_prefix)
+                - len(self.memory_layers)
+                - self.cross_periods * len(self.cross_pattern))
+
     @property
     def periods(self) -> int:
-        return (self.num_layers - len(self.layer_prefix)) // len(
-            self.layer_pattern)
+        return self._body_layers() // len(self.layer_pattern)
+
+    @property
+    def hybrid(self) -> bool:
+        """Whether a layer is handed more than ``(x, positions, mask)``:
+        the memory of a decoder-hybrid-decoder stack, and its own index in
+        the stack (differential attention's ``lambda_init`` reads it)."""
+        return bool(self.diff_attention or self.memory_layers)
+
+    def layer_kinds(self):
+        """The entry of every layer of a patterned stack, in order."""
+        return (self.layer_prefix + self.layer_pattern * self.periods
+                + self.memory_layers + self.cross_pattern * self.cross_periods)
 
     def feed_forward(self):
         """The module class of the block after attention, built as
@@ -340,8 +434,46 @@ class LlamaConfig:
         return cls(**defaults)
 
 
-#: the kinds a ``layer_pattern`` may name
-LAYER_KINDS = ("gqa", "kda", "mla", "swa")
+#: the kinds a ``layer_pattern`` may name (``gmu`` and ``xattn``: a
+#: ``cross_pattern`` alone)
+LAYER_KINDS = ("gqa", "kda", "mla", "swa", "mamba", "gmu", "xattn")
+
+
+def hybrid_layout(num_layers: int, mb_per_layer: int = 2) -> dict:
+    """The fields of ``LlamaConfig`` that lay out a decoder-hybrid-decoder
+    stack of ``num_layers`` by Phi-4-mini-flash's rule (``phi4flash``'s
+    model code, ``L = num_layers``): layer ``i`` is a ``mamba`` layer where
+    ``i % mb_per_layer == 0``, else attention, under the window where ``i <
+    L/2`` and ``i`` is odd, else whole; the layers ``i >= L/2`` are the YOCO
+    half: ``L/2`` (``mamba``) and ``L/2 + 1`` (whole attention) hand on their
+    scan output and their keys and values, and from ``L/2 + 2`` an even
+    ``i`` is a ``gmu`` layer and an odd one ``xattn``.  Returns
+    ``layer_pattern``, ``memory_layers``, ``cross_pattern`` and
+    ``cross_periods`` (the self-decoder's periods follow from
+    ``num_layers``)."""
+    half = num_layers // 2
+    if num_layers % 4 or mb_per_layer < 1 or half % mb_per_layer:
+        raise ValueError(
+            f"num_layers={num_layers} must be a multiple of 4 and half of "
+            f"it of mb_per_layer={mb_per_layer}")
+
+    def kind(i):
+        if i >= half + 2:
+            return "xattn" if i % 2 else "gmu"
+        if i % mb_per_layer == 0:
+            return "mamba"
+        return "swa" if i < half and i % 2 else "gqa"
+
+    kinds = [kind(i) for i in range(num_layers)]
+    pattern = tuple(kinds[:mb_per_layer])
+    memory, cross = tuple(kinds[half: half + 2]), tuple(kinds[half + 2:])
+    if (kinds[:half] != list(pattern) * (half // mb_per_layer)
+            or "mamba" not in memory or "gqa" not in memory):
+        raise ValueError(
+            f"mb_per_layer={mb_per_layer} at {num_layers} layers gives "
+            f"{kinds}: no whole periods before the pair that hands on")
+    return dict(layer_pattern=pattern, memory_layers=memory,
+                cross_pattern=cross[:2], cross_periods=len(cross) // 2)
 
 
 class AttentionNumbers(NamedTuple):
@@ -470,22 +602,69 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(self.dtype)
 
 
+class LayerNorm(nn.Module):
+    """``(x - mean) / sqrt(var + eps) * scale + bias`` in float32 (the
+    configuration's ``norm`` ``"layer"``)."""
+
+    eps: float
+    dtype: Dtype
+    param_dtype: Dtype
+
+    @nn.compact
+    def __call__(self, x):
+        def param(name, init):
+            return self.param(
+                name, nn.with_logical_partitioning(init, ("embed",)),
+                (x.shape[-1],), self.param_dtype).astype(jnp.float32)
+
+        scale = param("scale", nn.initializers.ones)
+        bias = param("bias", nn.initializers.zeros)
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+        return (centred * jax.lax.rsqrt(var + self.eps) * scale
+                + bias).astype(self.dtype)
+
+
+def _norm_of(cfg):
+    """The configuration's norm as ``norm(name=...)``."""
+    if cfg.norm == "layer":
+        return partial(LayerNorm, cfg.rms_norm_eps, cfg.dtype,
+                       cfg.param_dtype)
+    return partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                   unit_offset=cfg.norm_unit_offset)
+
+
+def diff_lambda_init(depth):
+    """Differential attention's ``lambda_init`` at layer ``depth`` of the
+    stack (arXiv:2410.05258, equation 3)."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+
+
 class Attention(nn.Module):
     config: LlamaConfig
     #: ``"gqa"`` or ``"swa"``: whose numbers of the configuration it runs
-    #: at (``LlamaConfig.attention_numbers``)
+    #: at (``LlamaConfig.attention_numbers``); ``"xattn"``: ``gqa``'s, with
+    #: no key or value projection: it reads the handed ``memory``'s
     kind: str = "gqa"
 
     @nn.compact
-    def __call__(self, x, positions, mask):
+    def __call__(self, x, positions, mask, memory=None, depth=None,
+                 keep=False):
+        """``keep``: also return the keys and values, as projected (a
+        memory layer of a decoder-hybrid-decoder stack hands them on)."""
         cfg = self.config
-        own = cfg.attention_numbers(self.kind)
+        own = cfg.attention_numbers(
+            "gqa" if self.kind == "xattn" else self.kind)
         dense = partial(
             nn.DenseGeneral,
-            use_bias=False,
+            use_bias=cfg.attention_bias,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
         )
+        if cfg.diff_attention:
+            return self._differential(
+                x, positions, mask, memory, depth, keep, own, dense)
         q = dense(
             features=(own.heads, cfg.head_dim),
             kernel_init=nn.with_logical_partitioning(
@@ -572,7 +751,7 @@ class Attention(nn.Module):
         return nn.DenseGeneral(
             features=x.shape[-1],
             axis=(-2, -1),
-            use_bias=False,
+            use_bias=cfg.attention_bias,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             kernel_init=nn.with_logical_partitioning(
@@ -580,6 +759,84 @@ class Attention(nn.Module):
             ),
             name="o_proj",
         )(out)
+
+    def _differential(self, x, positions, mask, memory, depth, keep, own,
+                      dense):
+        """The layer under ``diff_attention`` (the configuration's comment
+        has the equations): q, k, v as ever (an ``xattn`` layer: q alone,
+        over the memory's keys and values), the core in
+        ``ops/attention.py::differential_attention``, the sub-norm, ``1 -
+        lambda_init`` and the output projection over ``heads / 2`` heads of
+        ``2 head_dim``."""
+        from dlrover_tpu.ops.attention import differential_attention
+
+        cfg, D = self.config, self.config.head_dim
+
+        def heads_of(name, heads, axis):
+            return dense(
+                features=(heads, D), name=name,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(),
+                    ("embed", axis, "head_dim")))(x)
+
+        q = heads_of("q_proj", own.heads, "heads")
+        if self.kind == "xattn":
+            k, v = memory[1], memory[2]
+        else:
+            k = heads_of("k_proj", cfg.num_kv_heads, "kv_heads")
+            v = heads_of("v_proj", cfg.num_kv_heads, "kv_heads")
+        q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
+        if cfg.use_rope:
+            q = _rope(q, positions, own.rope_theta, own.rotary_dim, own.yarn)
+            if self.kind != "xattn":    # the memory's keys are turned
+                k = _rope(k, positions, own.rope_theta, own.rotary_dim,
+                          own.yarn)
+        handed = (k, v)
+
+        def vector(name):
+            return self.param(
+                name, nn.with_logical_partitioning(
+                    nn.initializers.normal(stddev=0.1), ("head_dim",)),
+                (D,), cfg.param_dtype).astype(jnp.float32)
+
+        first = diff_lambda_init(depth)
+        lam = (jnp.exp(jnp.sum(vector("lambda_q1") * vector("lambda_k1")))
+               - jnp.exp(jnp.sum(vector("lambda_q2") * vector("lambda_k2")))
+               + first)
+        self.sow("stats", "diff_lambda", lam)
+        # ``window`` (where there is one) around ``diff``: the innermost
+        # sub-scope names the work, and both stand AROUND the core's call
+        band = (contextlib.nullcontext() if own.window is None
+                else jax.named_scope("window"))
+        with jax.named_scope("attn.core"), band, jax.named_scope("diff"):
+            out = differential_attention(
+                q, k, v, lam, mask, own.window, cfg.attention_impl)
+            out = RMSNorm(1e-5, jnp.float32, cfg.param_dtype, "head_dim",
+                          name="sub_norm")(out)
+            out = (out * (1.0 - first)).astype(cfg.dtype)
+        out = nn.with_logical_constraint(
+            out, ("batch", "seq", "heads", "head_dim"))
+        out = nn.DenseGeneral(
+            features=x.shape[-1], axis=(-2, -1),
+            use_bias=cfg.attention_bias, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(),
+                ("heads", "head_dim", "embed")),
+            name="o_proj")(out)
+        return (out, handed) if keep else out
+
+    @staticmethod
+    def differential_params(cfg, kind) -> int:
+        heads = cfg.attention_numbers(
+            "gqa" if kind == "xattn" else kind).heads
+        bias = int(cfg.attention_bias)
+        D, h = cfg.head_dim, cfg.hidden_size
+        own_kv = 0 if kind == "xattn" else 2 * cfg.num_kv_heads * D * (
+            h + bias)
+        return (heads * D * (h + bias) + own_kv          # q, then k and v
+                + heads * D * h + bias * h               # o
+                + 4 * D + 2 * D)                         # lambdas, sub-norm
 
     def _attend_indexed(self, x, q, k, v, positions, dense):
         """Attention over the keys the indexer selects.  The indexer reads
@@ -940,6 +1197,159 @@ class LatentAttention(nn.Module):
                 + H * wide * cfg.hidden_size)                  # o
 
 
+def _ssm_inner(cfg) -> int:
+    return cfg.mamba_expand * cfg.hidden_size
+
+
+def _ssm_dt_rank(cfg) -> int:
+    return cfg.mamba_dt_rank or -(-cfg.hidden_size // 16)
+
+
+def _projection(cfg, features, name, axes, **kw):
+    """A bias-free projection in the compute dtype, its kernel
+    lecun-normal under the logical ``axes``."""
+    return nn.DenseGeneral(
+        features=features, name=name,
+        **{"use_bias": False, "dtype": cfg.dtype,
+           "param_dtype": cfg.param_dtype,
+           "kernel_init": nn.with_logical_partitioning(
+               nn.initializers.lecun_normal(), axes), **kw})
+
+
+class MambaMixer(nn.Module):
+    """Mamba-1's mixer (arXiv:2312.00752; the configuration's comment has
+    the equations), in place of attention in a ``mamba`` layer.  The state,
+    ``delta``, ``A`` and the scan's arithmetic are float32; ``W_x`` and
+    ``W_dt`` give float32 (operands multiplied as the backend multiplies
+    float32: bfloat16 with float32 accumulation on a TPU); the other
+    projections run in the compute dtype.  Sub-scopes under ``attn.core``:
+    ``conv``, ``decay`` (``delta``, ``A`` and the counter), ``scan``
+    (``ops/selective_scan.py``), ``gate``."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask, memory=None, depth=None,
+                 keep=False):
+        """``keep``: also return ``Y``, the scan's output before the gate
+        (float32)."""
+        from dlrover_tpu.ops.selective_scan import scan_core, selective_scan
+
+        cfg = self.config
+        inner, N, taps = _ssm_inner(cfg), cfg.mamba_state, cfg.mamba_conv
+        rank = _ssm_dt_rank(cfg)
+        S = x.shape[1]
+
+        def channel_param(name, init, *lead):
+            return self.param(
+                name, nn.with_logical_partitioning(
+                    init, (None,) * len(lead) + ("mlp",)),
+                lead + (inner,), cfg.param_dtype).astype(jnp.float32)
+
+        def conv_init(key, shape, dtype):
+            # a depthwise Conv1d's default, weight and bias alike
+            return jax.random.uniform(key, shape, dtype, -1.0, 1.0) * (
+                taps ** -0.5)
+
+        both = _projection(cfg, 2 * inner, "in_proj", ("embed", "mlp"))(x)
+        a, z = both[..., :inner], both[..., inner:]
+        weight = channel_param("conv_weight", conv_init, taps)
+        bias = channel_param("conv_bias", conv_init)
+        with jax.named_scope("attn.core"), jax.named_scope("conv"):
+            # tap ``i`` weighs position ``t - (taps - 1) + i``, zeros before
+            # the start
+            lead = jnp.pad(a, ((0, 0), (taps - 1, 0), (0, 0)))
+            a = nn.silu(bias + sum(
+                lead[:, i: i + S].astype(jnp.float32) * weight[i]
+                for i in range(taps))).astype(cfg.dtype)
+        a = nn.with_logical_constraint(a, ("batch", "seq", "mlp"))
+        steer = _projection(cfg, rank + 2 * N, "x_proj", ("mlp", None),
+                            dtype=jnp.float32)(a)
+        step_in = _projection(
+            cfg, inner, "dt_proj", (None, "mlp"), dtype=jnp.float32,
+            use_bias=True, bias_init=nn.with_logical_partitioning(
+                _kda_dt_bias_init(), ("mlp",)),
+            # Mamba's ``dt_init`` "random": uniform in +-rank^-1/2
+            kernel_init=nn.with_logical_partitioning(
+                lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, dtype, -1.0, 1.0) * rank ** -0.5,
+                (None, "mlp")))(steer[..., :rank])
+        rate = self.param(
+            "A_log", nn.with_logical_partitioning(
+                lambda key, shape, dtype: jnp.broadcast_to(jnp.log(
+                    jnp.arange(1, shape[1] + 1, dtype=dtype)), shape),
+                ("mlp", None)),
+            (inner, N), cfg.param_dtype)
+        skip = channel_param("D", nn.initializers.ones)
+        with jax.named_scope("attn.core"):
+            with jax.named_scope("decay"):
+                delta = jax.nn.softplus(step_in)
+                A = -jnp.exp(rate.astype(jnp.float32))
+                # how far the state hears: the median of ``exp(delta A)``
+                # over 16 positions of the sequence and every eighth channel
+                # (all of its columns): a sort of a hundred thousand numbers,
+                # where every channel's took 5 ms a layer on a v5e
+                sample = jax.lax.stop_gradient(
+                    delta[:, :: max(S // 16, 1), ::8][..., None] * A[::8])
+                self.sow("stats", "ssm_decay_p50",
+                         jnp.median(jnp.exp(sample)))
+            trace.note_trace_time(
+                "attention.path", impl="mamba", seq=S, channels=inner,
+                state=N, conv=taps, dt_rank=rank, state_dtype="float32",
+                **scan_core(S, inner, N))
+            y = selective_scan(a, delta, A, steer[..., rank: rank + N],
+                               steer[..., rank + N:], skip, cfg.dtype)
+            with jax.named_scope("gate"):
+                out = (y * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+        out = nn.with_logical_constraint(out, ("batch", "seq", "mlp"))
+        out = _projection(cfg, x.shape[-1], "out_proj", ("mlp", "embed"))(out)
+        return (out, y) if keep else out
+
+    @staticmethod
+    def num_params(cfg) -> int:
+        inner, N, rank = _ssm_inner(cfg), cfg.mamba_state, _ssm_dt_rank(cfg)
+        return (cfg.hidden_size * 2 * inner             # in
+                + cfg.mamba_conv * inner + inner        # the convolution
+                + inner * (rank + 2 * N)                # x
+                + rank * inner + inner                  # dt
+                + inner * N + inner                     # A_log, D
+                + inner * cfg.hidden_size)              # out
+
+
+class GatedMemoryUnit(nn.Module):
+    """A gated memory unit (SambaY, arXiv:2507.06607), in place of attention
+    in a ``gmu`` layer: ``out = (Y * silu(h W_in)) W_out`` with ``Y`` the
+    scan output the stack's ``mamba`` memory layer handed on: no scan, no
+    convolution.  Sub-scope ``gmu`` under ``attn.core``."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask, memory=None, depth=None,
+                 keep=False):
+        cfg = self.config
+        inner = _ssm_inner(cfg)
+        gate = _projection(cfg, inner, "in_proj", ("embed", "mlp"))(x)
+        with jax.named_scope("attn.core"), jax.named_scope("gmu"):
+            # an operation of its own between two barriers: left to the
+            # compiler the product is fused into the input projection's
+            # epilogue, or into the output projection's operand, and takes
+            # the projection's name; here ``gmu_ms_per_step`` can read it
+            # (forward and rematerialised pass; its gradient is fused into
+            # what is ``handed``).  The price is the gate written and read
+            # once more a pass, 0.17 GB at 16,384 positions
+            gate = jax.lax.optimization_barrier(gate)
+            out = jax.lax.optimization_barrier(
+                (memory[0].astype(jnp.float32)
+                 * nn.silu(gate.astype(jnp.float32))).astype(cfg.dtype))
+        out = nn.with_logical_constraint(out, ("batch", "seq", "mlp"))
+        return _projection(cfg, x.shape[-1], "out_proj", ("mlp", "embed"))(out)
+
+    @staticmethod
+    def num_params(cfg) -> int:
+        return 2 * cfg.hidden_size * _ssm_inner(cfg)
+
+
 class MLP(nn.Module):
     config: LlamaConfig
 
@@ -979,7 +1389,11 @@ class MLP(nn.Module):
 
 #: the module at ``attn`` by the layer's kind
 ATTENTION_OF = {"gqa": Attention, "kda": DeltaAttention,
-                "mla": LatentAttention, "swa": partial(Attention, kind="swa")}
+                "mla": LatentAttention, "swa": partial(Attention, kind="swa"),
+                "mamba": MambaMixer, "gmu": GatedMemoryUnit,
+                "xattn": partial(Attention, kind="xattn")}
+#: the kinds whose module takes ``memory``, ``depth`` and ``keep``
+HYBRID_KINDS = ("gqa", "swa", "mamba", "gmu", "xattn")
 
 
 class DecoderLayer(nn.Module):
@@ -987,9 +1401,12 @@ class DecoderLayer(nn.Module):
     #: a pattern's entry: of ``LAYER_KINDS``, which module stands at
     #: ``attn``; with ``:dense``, the dense SwiGLU at ``mlp``
     kind: str = "gqa"
+    #: a memory layer of a decoder-hybrid-decoder stack: the call returns
+    #: ``(x, what its mixer hands on)``
+    keeps: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, mask):
+    def __call__(self, x, positions, mask, memory=None, depth=None):
         cfg = self.config
         kind, dense = layer_kind(self.kind)
         attention = ATTENTION_OF[kind]
@@ -997,35 +1414,57 @@ class DecoderLayer(nn.Module):
         if dense:
             feed_forward, ffn_cfg = MLP, dataclasses.replace(
                 cfg, intermediate_size=cfg.dense_intermediate_size)
-        norm = partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                       unit_offset=cfg.norm_unit_offset)
+        norm = _norm_of(cfg)
         # ``x`` is the residual stream, in ``residual_dtype`` where the
         # configuration names one: a branch's result is added in it
         h = norm(name="input_norm")(x)
-        x = x + attention(cfg, name="attn")(h, positions, mask).astype(x.dtype)
+        handed = None
+        if cfg.hybrid and kind in HYBRID_KINDS:
+            mixed = attention(cfg, name="attn")(
+                h, positions, mask, memory, depth, self.keeps)
+            if self.keeps:
+                mixed, handed = mixed
+        else:
+            mixed = attention(cfg, name="attn")(h, positions, mask)
+        x = x + mixed.astype(x.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         h = norm(name="post_attn_norm")(x)
         x = x + feed_forward(ffn_cfg, name="mlp")(h).astype(x.dtype)
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        return (x, handed) if self.keeps else x
 
 
 class _ScannedLayer(nn.Module):
-    """DecoderLayer wrapped for nn.scan (carry=x, per-layer params)."""
+    """DecoderLayer wrapped for nn.scan (carry=x, per-layer params; in a
+    ``hybrid`` stack the memory broadcast and the layer's index scanned)."""
 
     config: LlamaConfig
     kind: str = "gqa"
 
     @nn.compact
-    def __call__(self, x, positions, mask):
+    def __call__(self, x, positions, mask, memory=None, depth=None):
         x = DecoderLayer(self.config, self.kind, name="layer")(
-            x, positions, mask)
+            x, positions, mask, memory, depth)
         return x, None
 
 
-def _stacked(layer_cls, length, axis="layers"):
+class _KeepingLayer(nn.Module):
+    """A memory layer: ``DecoderLayer`` that returns ``(x, handed)``."""
+
+    config: LlamaConfig
+    kind: str = "gqa"
+
+    @nn.compact
+    def __call__(self, x, positions, mask, depth):
+        return DecoderLayer(self.config, self.kind, True, name="layer")(
+            x, positions, mask, None, depth)
+
+
+def _stacked(layer_cls, length, axis="layers", hybrid=False):
     """``layer_cls`` scanned ``length`` times over parameters stacked on a
     leading axis (its logical name ``axis``: no name twice in one array),
-    the residual stream the carry."""
+    the residual stream the carry.  ``hybrid``: the call's last argument,
+    the layers' indices, is scanned beside the parameters."""
     return nn.scan(
         layer_cls,
         # what a layer sows (a routed block's loss terms and counts) stacks
@@ -1033,22 +1472,23 @@ def _stacked(layer_cls, length, axis="layers"):
         # and its buffers (a router's selection bias: ``models/moe.py``)
         variable_axes={"params": 0, "losses": 0, "stats": 0, "buffers": 0},
         split_rngs={"params": True},
-        in_axes=nn.broadcast,  # positions/mask shared by all layers
+        # positions, mask and the memory are shared by all layers
+        in_axes=(nn.broadcast,) * 3 + (0,) if hybrid else nn.broadcast,
         length=length,
         metadata_params={nn.PARTITION_NAME: axis},
     )
 
 
-def _layer_class(cfg, scanned):
-    """``_ScannedLayer``, rematerialised where the configuration says so:
+def _layer_class(cfg, scanned, layer=_ScannedLayer):
+    """``layer``, rematerialised where the configuration says so:
     the forward pass keeps a layer's input and what its attention core's
     forward kernels, or its experts' first grouped matmuls, wrote under a
     name of ``ops/pallas/kept.py``; the backward pass computes the rest of
     the layer again."""
     if not cfg.remat:
-        return _ScannedLayer
+        return layer
     return nn.remat(
-        _ScannedLayer,
+        layer,
         prevent_cse=not scanned,
         static_argnums=(),
         policy=LAYER_POLICY,
@@ -1066,12 +1506,39 @@ class _ScannedPeriod(nn.Module):
     entries: Optional[Tuple[str, ...]] = None
 
     @nn.compact
-    def __call__(self, x, positions, mask):
+    def __call__(self, x, positions, mask, memory=None, depths=None):
         cfg = self.config
+        first = 0
         for name, kind, length in cfg.layer_runs(self.entries):
-            x, _ = _stacked(_layer_class(cfg, True), length)(
-                cfg, kind, name=name)(x, positions, mask)
+            more = () if depths is None else (
+                memory, depths[first: first + length])
+            x, _ = _stacked(_layer_class(cfg, True), length,
+                            hybrid=depths is not None)(
+                cfg, kind, name=name)(x, positions, mask, *more)
+            first += length
         return x, None
+
+
+class _MemoryLayers(nn.Module):
+    """The layers of ``memory_layers``, each once, from index ``first`` of
+    the stack: ``(x, (Y, K, V))``, what the cross-decoder's layers read.
+    What passes on stands under ``attn.core`` / ``handed``: ``Y`` taken to
+    the compute dtype and, in the backward pass, the memory's gradient
+    summed over its readers."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask, first):
+        cfg = self.config
+        handed = {}
+        for i, (name, kind, _) in enumerate(
+                cfg.layer_runs(cfg.memory_layers)):
+            x, kept = _layer_class(cfg, False, _KeepingLayer)(
+                cfg, kind, name=name)(x, positions, mask, first + i)
+            handed["y" if layer_kind(kind)[0] == "mamba" else "kv"] = kept
+        with jax.named_scope("attn.core"), jax.named_scope("handed"):
+            return x, (handed["y"].astype(cfg.dtype),) + tuple(handed["kv"])
 
 
 class LMHead(nn.Module):
@@ -1106,10 +1573,16 @@ class LMHead(nn.Module):
             (cfg.hidden_size, cfg.pred_heads * cfg.vocab_size),
             cfg.param_dtype,
         )
+        return self.project(cfg, x, kernel)
+
+    @staticmethod
+    def project(cfg, x, kernel, transposed=False):
+        """``x kernel`` (``transposed``: ``x kernel^T``, a tied head over
+        the embedding table ``[vocab, hidden]``) as the class comment says."""
         return jax.lax.dot_general(
             x.astype(cfg.dtype),
             kernel.astype(cfg.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
+            (((x.ndim - 1,), (1 if transposed else 0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -1155,12 +1628,13 @@ class LlamaForCausalLM(nn.Module):
         mask = None if (
             cfg.index_topk or cfg.eva_window or cfg.attention_impl == "flash"
             or cfg.block_diffusion
-            or (cfg.layer_pattern and not {"gqa", "swa"} & {
-                layer_kind(entry)[0]
-                for entry in cfg.layer_prefix + cfg.layer_pattern})
+            or (cfg.layer_pattern and not {"gqa", "swa", "xattn"} & {
+                layer_kind(entry)[0] for entry in cfg.layer_kinds()})
         ) else jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
 
-        if cfg.layer_pattern:
+        if cfg.hybrid:
+            x = self._hybrid_stack(x, positions, mask)
+        elif cfg.layer_pattern:
             if cfg.layer_prefix:    # once, before the periods
                 x, _ = _ScannedPeriod(cfg, cfg.layer_prefix, name="prefix")(
                     x, positions, mask)
@@ -1178,9 +1652,12 @@ class LlamaForCausalLM(nn.Module):
 
         if cfg.block_diffusion:     # the clean half yields no logits
             x = x[:, :S]
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                    unit_offset=cfg.norm_unit_offset, name="final_norm")(x)
-        logits = LMHead(cfg, name="lm_head")(x)
+        x = _norm_of(cfg)(name="final_norm")(x)
+        if cfg.tie_embeddings:
+            with jax.named_scope("lm_head"):
+                logits = LMHead.project(cfg, x, embed, transposed=True)
+        else:
+            logits = LMHead(cfg, name="lm_head")(x)
         if cfg.block_diffusion:
             with jax.named_scope("head_loss"):
                 self._sow_nelbo(logits, input_ids, weights)
@@ -1188,6 +1665,44 @@ class LlamaForCausalLM(nn.Module):
             with jax.named_scope("head_loss"):
                 logits = self._first_head(logits, input_ids)
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
+
+    def _hybrid_stack(self, x, positions, mask):
+        """A patterned stack whose layers are handed the memory and their
+        own index: the prefix once, the periods of ``layer_pattern``, then
+        (a decoder-hybrid-decoder stack) the memory layers once and the
+        periods of ``cross_pattern``, every layer of which reads what the
+        memory layers handed on: a broadcast operand of the scan over
+        periods, its gradient the sum over the readers."""
+        cfg = self.config
+        if not cfg.layer_pattern:
+            raise ValueError("diff_attention needs a layer_pattern")
+        first = 0
+
+        def periods_of(entries, periods, name, memory=None):
+            nonlocal first
+            depths = first + jnp.arange(
+                periods * len(entries), dtype=jnp.int32).reshape(
+                    periods, len(entries))
+            first += periods * len(entries)
+            return _stacked(_ScannedPeriod, periods, "periods", True)(
+                cfg, entries, name=name)(x, positions, mask, memory, depths)[0]
+
+        if cfg.layer_prefix:
+            x, _ = _ScannedPeriod(cfg, cfg.layer_prefix, name="prefix")(
+                x, positions, mask, None,
+                jnp.arange(len(cfg.layer_prefix), dtype=jnp.int32))
+            first = len(cfg.layer_prefix)
+        x = periods_of(cfg.layer_pattern, cfg.periods, "layers")
+        if cfg.memory_layers:
+            x, memory = _MemoryLayers(cfg, name="memory")(
+                x, positions, mask, jnp.int32(first))
+            first += len(cfg.memory_layers)
+            if cfg.cross_periods:
+                self.sow("stats", "memory_readers", jnp.int32(
+                    cfg.cross_periods * len(cfg.cross_pattern)))
+                x = periods_of(cfg.cross_pattern, cfg.cross_periods, "cross",
+                               memory)
+        return x
 
     def _noisy_and_clean(self, input_ids):
         """``([noisy copy ; clean copy] [B, 2S], the NELBO's weights [B,
@@ -1257,23 +1772,28 @@ class LlamaForCausalLM(nn.Module):
                 attn += cfg.hidden_size * heads
             return attn
 
+        if cfg.diff_attention:
+            softmax = partial(Attention.differential_params, cfg)
         by_kind = {"gqa": lambda: softmax("gqa"),
                    "swa": lambda: softmax("swa"),
+                   "xattn": lambda: softmax("xattn"),
                    "kda": lambda: DeltaAttention.num_params(cfg),
-                   "mla": lambda: LatentAttention.num_params(cfg)}
+                   "mla": lambda: LatentAttention.num_params(cfg),
+                   "mamba": lambda: MambaMixer.num_params(cfg),
+                   "gmu": lambda: GatedMemoryUnit.num_params(cfg)}
+        # a norm's parameters: a scale, with ``norm`` "layer" a bias too
+        norm = cfg.hidden_size * (2 if cfg.norm == "layer" else 1)
 
         def layers(entries):
             total = 0
             for kind, dense in map(layer_kind, entries):
-                total += by_kind[kind]() + 2 * cfg.hidden_size + (
+                total += by_kind[kind]() + 2 * norm + (
                     3 * cfg.hidden_size * cfg.dense_intermediate_size
                     if dense else cfg.feed_forward_params())
             return total
 
-        pattern = cfg.layer_pattern or ("gqa",)
-        periods = (cfg.num_layers - len(cfg.layer_prefix)) // len(pattern)
-        return (
-            cfg.vocab_size * cfg.hidden_size * (1 + cfg.pred_heads)
-            + layers(cfg.layer_prefix) + periods * layers(pattern)
-            + cfg.hidden_size
-        )
+        entries = (cfg.layer_kinds() if cfg.layer_pattern
+                   else ("gqa",) * cfg.num_layers)
+        tables = cfg.pred_heads + (0 if cfg.tie_embeddings else 1)
+        return (cfg.vocab_size * cfg.hidden_size * tables
+                + layers(entries) + norm)

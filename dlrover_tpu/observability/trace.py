@@ -545,7 +545,18 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
                   # a softmax layer under a causal window (``models/
                   # llama.py::Attention`` of kind ``swa``): the FA2 kernels
                   # with ``window``, or the reference core under the band
-                  "window"),
+                  "window",
+                  # a state-space layer (``models/llama.py::MambaMixer``:
+                  # ``conv``, ``decay`` and ``gate`` as above, and the
+                  # selective scan, ``ops/selective_scan.py``), the core of
+                  # differential attention (``Attention._differential``;
+                  # under ``window`` where there is one: the innermost
+                  # names the work), a gated memory unit's gate, and what the
+                  # memory layers of a decoder-hybrid-decoder stack hand to
+                  # its cross-decoder (``_MemoryLayers``, the module
+                  # ``memory``: the cast of ``Y``; backward the memory's
+                  # gradient summed over its readers)
+                  "scan", "diff", "gmu", "handed"),
     # what latent attention adds around its core (``models/llama.py::
     # LatentAttention``): the latent's projections, its norm, the rotary
     # part.  The one name two kinds have: a kind each, and a path that

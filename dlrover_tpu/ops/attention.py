@@ -29,10 +29,14 @@ selected attention's kernels' other entry point on a TPU and in
 attention of a model trained by diffusion over blocks: a noisy and a clean
 copy of a sequence in one row of ``2S`` positions, under a mask that is not
 causal, a block of queries at a time through the same entry point.
-``latent_attention`` (at the very end) is the core of latent attention
+``latent_attention`` is the core of latent attention
 (MLA): scores that are the sum of a product a head and a product against
 one rotary key every head shares, values narrower than the scores, in
 kernels of its own on a TPU (``ops/pallas/latent_attention.py``).
+``differential_attention`` (at the very end) is the core of differential
+attention in its head-paired form: two softmax maps of one head size over
+one value of twice that, their difference; on a TPU both maps in ONE call
+of the FA2 kernels at the value's width.
 """
 
 import functools
@@ -883,3 +887,60 @@ def latent_attention(q_nope, q_pe, k_nope, k_pe, v, interpret: bool = False,
         kept.note("latent", **kept_bytes(v))
         return latent_attention_kernels(
             q_nope, q_pe, k_nope, k_pe, v, *blocks, interpret)
+
+
+# --------------------------------------------------------------------------
+# Differential attention (arXiv:2410.05258; the head-paired form of
+# Phi-4-mini-flash's model code)
+# --------------------------------------------------------------------------
+
+def differential_attention(q, k, v, lam, mask=None, window=None,
+                           impl: str = "reference",
+                           interpret: bool = False) -> jnp.ndarray:
+    """``(softmax(q1 k1^T / sqrt(D)) - lam softmax(q2 k2^T / sqrt(D))) V``
+    under the causal mask (and ``window``), float32 ``[B, S, H/2, 2D]``: q
+    ``[B, S, H, D]`` whose heads ``(2j, 2j+1)`` are ``q1_j, q2_j``; k, v
+    ``[B, S, G, D]`` whose heads ``(2m, 2m+1)`` are ``k1_m, k2_m`` and, side
+    by side, ``V_m`` of ``2D``; ``H/2`` a multiple of ``G/2`` (query pair
+    ``j`` reads key pair ``j // (H / G)``); ``lam`` a scalar.
+
+    ``impl`` ``"reference"``: the reference core twice, ``(q1, k1, V)`` and
+    ``(q2, k2, V)``, the value at its own width.  ``"flash"``: ONE call of
+    the FA2 kernels (the band kernels under a window) at head size ``2D``
+    over ``H`` query heads and ``G`` key heads: map 1's pairs then map 2's,
+    ``q`` and ``k`` with ``D`` columns of zeros beside them (``q`` times
+    ``sqrt(2)`` first, in float32: the kernel divides by ``sqrt(2D)``), ``V``
+    once for each map.  Against the model code's four calls at head size
+    ``D`` (each map against each half of ``V``: every score computed twice,
+    and a head of 64 fills half of the matrix unit's 128 lanes) this scores
+    each pair once a map; what it multiplies beyond the model's pairs is the
+    scores' contraction over ``2D`` lanes of which ``D`` hold zeros (512
+    multiply-adds a pair and map where the model's count is 384) and the
+    masked part of the blocks on the diagonal and the band's edge."""
+    B, S, H, D = q.shape
+    G = k.shape[2]
+    q1, q2 = q[:, :, 0::2], q[:, :, 1::2]
+    k1, k2 = k[:, :, 0::2], k[:, :, 1::2]
+    wide = v.reshape(B, v.shape[1], G // 2, 2 * D)
+    if impl != "flash":
+        trace.note_trace_time(
+            "attention.path", impl="differential", seq=S, heads=H,
+            head_dim=D, exact="reference",
+            **({} if window is None else {"window": window}))
+        first = reference_attention(q1, k1, wide, mask, window)
+        second = reference_attention(q2, k2, wide, mask, window)
+    else:
+        def padded(t, factor=None):
+            if factor is not None:
+                t = (t.astype(jnp.float32) * factor).astype(t.dtype)
+            return jnp.pad(t, ((0, 0),) * 3 + ((0, D),))
+
+        out = flash_attention(
+            jnp.concatenate(
+                [padded(q1, 2.0 ** 0.5), padded(q2, 2.0 ** 0.5)], axis=2),
+            jnp.concatenate([padded(k1), padded(k2)], axis=2),
+            jnp.concatenate([wide, wide], axis=2),
+            causal=True, window=window, interpret=interpret,
+            path_attrs={"maps": "differential", "scores_over": D})
+        first, second = out[:, :, : H // 2], out[:, :, H // 2:]
+    return first.astype(jnp.float32) - lam * second.astype(jnp.float32)

@@ -59,7 +59,12 @@ MOE_PRODUCTS = "moe_products"
 #: of the experts, the rows ``[E]`` int32 each expert took
 MOE_ROUTE = "moe_route"
 
-NAMES = (ATTN_OUT, ATTN_LSE, KDA_CHUNK, KDA_STATE, MOE_PRODUCTS, MOE_ROUTE)
+#: what a selective scan's forward kernel wrote (``selective_scan.py``):
+#: ``y`` float32 and the state each chunk started from
+SSM_SCAN = "ssm_scan"
+
+NAMES = (ATTN_OUT, ATTN_LSE, KDA_CHUNK, KDA_STATE, MOE_PRODUCTS, MOE_ROUTE,
+         SSM_SCAN)
 
 #: the policy of every rematerialised decoder layer (``models/llama.py::
 #: _layer_class``, ``models/pipeline_llama.py``)

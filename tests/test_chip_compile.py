@@ -785,6 +785,77 @@ class TestTrainerStep:
         mem = compiled.memory_analysis()
         assert 2.8e9 < mem.argument_size_in_bytes < 2.9e9
 
+    def test_phi4flash_widths_every_kind_of_layer(
+            self, topo, as_if_on_tpu, monkeypatch):
+        """The Phi-4-mini-flash cell's configuration file through its family
+        at B1 S2048 (an eighth of the cell's length, for the test's time;
+        the cell's own is ``benchmarks/tests/compile_described.py
+        phi4miniflash_l8``: 6.82 GiB of arguments, 9.67 of temporaries at
+        16,384, accepted) and all eight layers, the least depth with every
+        kind: the selective scan goes through its Pallas kernels
+        (``ops/pallas/selective_scan.py``) under ``attn.core`` / ``scan``,
+        a forward and a backward call a loop and NO forward call in the
+        rematerialised pass (the layer keeps ``y`` and the chunks' starts);
+        the differential cores through the FA2 kernels under ``attn.core``
+        / ``diff``, the window's too; no array of the compiled step holds
+        the state's history ``[S, 5120, 16]`` in any layout; the head reads
+        the embedding table."""
+        from benchmarks.common import HERE, load_module, read_json
+        from dlrover_tpu.observability import trace
+
+        notes = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: notes.append((name, attrs)))
+        config = read_json(HERE, "configs", "phi4miniflash_l8.json")
+        family = load_module("families", "phi4flash")
+        S = 2048
+
+        def cell():
+            return family.build(config, False, S), (1, S)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh, cell)
+        text = compiled.as_text()
+        # the state's history, whole or by lane groups, and no head's scores
+        assert not re.search(
+            rf"{S},5120,16\]|5120,16,{S}\]|{S},40,16,128\]|{S},{S}\]", text)
+        found = trace.parse_device_scopes(text)
+        kernels = [found.scopes["%" + name] for name in _kernel_names(text)]
+        # the periods' loop and the memory layer: a call each a pass
+        assert sorted(k for k in kernels if k[1] == "scan") == sorted(
+            [("attn.core", "scan", "forward")] * 2
+            + [("attn.core", "scan", "backward")] * 2)
+        # window (the periods' loop), whole (the memory layer), cross: FA2
+        # names nothing, so each runs forward, again, and backward
+        diff = [k for k in kernels if k[1] == "diff"]
+        assert {k[2] for k in diff} == {"forward", "remat", "backward"}
+        assert len([k for k in diff if k[2] == "forward"]) == 3
+        assert len(diff) == len(kernels) - 4
+        paths = [attrs for name, attrs in notes if name == "attention.path"]
+        mamba = next(a for a in paths if a["impl"] == "mamba")
+        assert mamba["core"] == "pallas" and mamba["channels"] == 5120
+        assert (mamba["state"], mamba["conv"], mamba["dt_rank"]) == (16, 4, 160)
+        flash = [a for a in paths if a["impl"] == "flash"]
+        assert {a.get("window") for a in flash} == {512, None}
+        assert all(a["heads"] == 40 and a["head_dim"] == 128
+                   and a["maps"] == "differential" and a["scores_over"] == 64
+                   for a in flash)
+        kept = [attrs for name, attrs in notes if name == "remat.kept"]
+        assert kept and kept[0]["core"] == "ssm"
+        # y in bfloat16 and the starts of 32 chunks in float32
+        assert kept[0]["bytes_per_layer"] == (
+            S * 5120 * 2 + (S // 64) * 5120 * 16 * 4)
+        assert "lm_head" not in "".join(
+            jax.tree_util.keystr(path) for path, _ in
+            jax.tree_util.tree_leaves_with_path(
+                jax.eval_shape(family.build(config, False, S).init,
+                               jax.random.PRNGKey(0),
+                               jnp.zeros((1, 128), jnp.int32))["params"]))
+        # 915.3 M parameters at 8 bytes of state
+        mem = compiled.memory_analysis()
+        assert 7.3e9 < mem.argument_size_in_bytes < 7.4e9
+
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
         widths and share (32 query heads on 4 key heads of 128, 16 of 128

@@ -68,7 +68,7 @@ def test_layer_runs_of_a_pattern():
 
 
 @pytest.mark.parametrize("changes,match", [
-    ({"layer_pattern": ("gqa", "mamba")}, "kinds of"),
+    ({"layer_pattern": ("gqa", "lstm")}, "kinds of"),
     ({"num_layers": 6}, "whole number of periods"),
     ({"kda_heads": 0}, "kda_heads"),
     ({"layer_pattern": ("gqa", "mla"), "num_layers": 2}, "mla_kv_rank"),
