@@ -535,9 +535,11 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
                   # DeltaAttention``, ``ops/linear_attention.py``)
                   "conv", "decay", "chunk", "state", "gate",
                   # attention under the block-diffusion mask (``ops/
-                  # attention.py::block_diffusion_attention``): the joining
-                  # of a noisy block's keys and the mask from ``iota``, the
-                  # clean half's and the noisy half's attention
+                  # attention.py::block_diffusion_attention``).  On a TPU
+                  # ONE kernel call a pass for both halves, under
+                  # ``bd_noisy``; ``bd_keys`` (the joining of a noisy
+                  # block's keys and the mask from ``iota``) and
+                  # ``bd_clean`` hold the ``jax.numpy`` path's work alone
                   "bd_keys", "bd_clean", "bd_noisy",
                   # the core of latent attention (``ops/attention.py::
                   # latent_attention``)

@@ -28,7 +28,9 @@ selected attention's kernels' other entry point on a TPU and in
 ``jax.numpy`` elsewhere.  ``block_diffusion_attention`` (last) is the
 attention of a model trained by diffusion over blocks: a noisy and a clean
 copy of a sequence in one row of ``2S`` positions, under a mask that is not
-causal, a block of queries at a time through the same entry point.
+causal: ONE call a pass of kernels of its own on a TPU (``ops/pallas/
+block_diffusion_attention.py``: the mask made from positions, only live
+tiles walked), a block of queries at a time in ``jax.numpy`` elsewhere.
 ``latent_attention`` is the core of latent attention
 (MLA): scores that are the sum of a product a head and a product against
 one rotary key every head shares, values narrower than the scores, in
@@ -716,45 +718,42 @@ def block_diffusion_keep(first, last, block, noisy):
     return jnp.concatenate([c < r, own == r], axis=1)[None]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "first", "block", "noisy", "block_kv", "interpret"))
-def _block_diffusion_block(q, k, v, *, first, block, noisy, block_kv,
-                           interpret=False):
+@functools.partial(jax.jit, static_argnames=("first", "block", "noisy"))
+def _block_diffusion_block(q, k, v, *, first, block, noisy):
     """One block of queries ``q`` [B, n, H, D] at positions ``[first, first
     + n)`` over the keys the mask can allow it, ``k``/``v`` [B, keys, G, D]:
     the clean keys ``[0, first + n)`` and, for ``noisy`` queries, their own
     noisy keys joined behind them, ONE softmax over both parts.
-    ``block_kv`` ``None``: ``jax.numpy`` over dense ``[heads, n, keys]``
-    scores, rematerialised; else the mask-operand kernels at tiles of at
-    most so many keys.  Under ``jax.jit``, as ``_attend_block``: a second
-    trace of the model finds a block traced."""
+    ``jax.numpy`` over dense ``[heads, n, keys]`` scores, rematerialised:
+    the body of every backend but a TPU (there the kernels take the whole
+    arrays, ``block_diffusion_attention``).  Under ``jax.jit``, as
+    ``_attend_block``: a second trace of the model finds a block traced."""
     B, n = q.shape[:2]
     with jax.named_scope("bd_keys"):
         keep = jnp.broadcast_to(
             block_diffusion_keep(first, first + n, block, noisy),
             (B, n, k.shape[1]))
     with jax.named_scope("bd_noisy" if noisy else "bd_clean"):
-        if block_kv is None:
-            return jax.checkpoint(
-                lambda *operands: _dense_selected(*operands)[0])(q, k, v, keep)
-        from dlrover_tpu.ops.pallas.selected_attention import masked_attention
-
-        return masked_attention(q, k, v, keep, None, block_kv, interpret)[0]
+        return jax.checkpoint(
+            lambda *operands: _dense_selected(*operands)[0])(q, k, v, keep)
 
 
 def block_diffusion_path(backend: str, seq: int, query_block: int,
                          head_dim: int, heads: int, kv_heads: int) -> str:
-    """``"pallas"`` or ``"jnp"``: which body attends to a block of queries
-    under the block-diffusion mask, from what the code can observe (as
-    ``selected_attend_path``): the kernels on a TPU where they take the
-    block and every block of the sequence is a whole one."""
+    """``"pallas"`` or ``"jnp"``: which body attends under the
+    block-diffusion mask, from what the code can observe (as
+    ``selected_attend_path``, whose rule on a block's shape is the rule on
+    a tile's here): the kernels of ``ops/pallas/
+    block_diffusion_attention.py`` on a TPU where they take a tile of
+    ``query_block`` positions and the sequence is a whole number of them."""
     if seq % query_block == 0 and selected_attend_path(
             backend, query_block, head_dim, heads, kv_heads) == "pallas":
         return "pallas"
     return "jnp"
 
 
-def block_diffusion_attention(q, k, v, block, query_block=512):
+def block_diffusion_attention(q, k, v, block, query_block=None,
+                              interpret: bool = False):
     """Attention of the ``2S`` rows ``[noisy copy ; clean copy]`` of one
     sequence of ``S`` positions in blocks of ``block``, ``q`` [B, 2S, H, D],
     ``k``/``v`` [B, 2S, G, D] (GQA), under the block-diffusion mask: a clean
@@ -764,46 +763,59 @@ def block_diffusion_attention(q, k, v, block, query_block=512):
     row of another block.  ``S^2 + block S`` pairs a head
     (``block_diffusion_pairs``), twice a causal layer's.
 
-    By blocks of ``query_block`` queries (a multiple of ``block``, so no
-    block of the sequence straddles two), each against only the keys the
-    mask can allow it (``block_diffusion_keep``), so nothing ``[2S, 2S]``
-    is ever whole: a block's mask is ``[query_block, keys]``, its scores
-    ``[H, query_block, keys]`` in ``jax.numpy`` and tiles in fast memory in
-    the mask-operand kernels (``ops/pallas/selected_attention.py::
-    masked_attention``), which run on a TPU at the shapes they take
-    (``block_diffusion_path``).  The kernels multiply every pair of a block
-    under its mask: ``S^2 + 2 query_block S`` a head for the allowed ``S^2 +
-    block S``."""
+    By tiles of ``query_block`` queries (a multiple of ``block``, so no
+    block of the sequence straddles two; ``None``: the kernels' tile on a
+    TPU, 512 in ``jax.numpy``),
+    each against only the keys the mask can allow it, so nothing ``[2S,
+    2S]`` is ever whole.  On a TPU at the shapes they take
+    (``block_diffusion_path``) the kernels of
+    ``ops/pallas/block_diffusion_attention.py``: ONE call a pass over the
+    whole arrays, the mask made from positions inside it, only the live
+    tiles walked (every tile under the diagonal without a mask, the cut
+    ones by sub-tiles with the dead ones skipped), under the sub-scope
+    ``bd_noisy`` for both halves; the ``attention.path`` record then says
+    what a head's forward pass multiplies (``pairs_multiplied``: ``S^2 +
+    (2 sub + tile) S / 2`` where the cut tiles go by sub-tiles of ``sub``)
+    beside the allowed ``pairs`` and the ``calls`` a layer and pass;
+    ``interpret``: as on a TPU, the kernels in the Pallas interpreter
+    (tests off the chip).  Elsewhere ``jax.numpy`` block by block: a block's mask is
+    ``[query_block, keys]`` (``block_diffusion_keep``) and its scores
+    ``[H, query_block, keys]``."""
+    from dlrover_tpu.ops.pallas import block_diffusion_attention as kernels
+
     B, rows, H, D = q.shape
     S = rows // 2
     if rows % 2 or S % block:
         raise ValueError(f"{rows} rows are not two copies of a whole number "
                          f"of blocks of {block}")
-    query_block = min(query_block, S)
+    backend = "tpu" if interpret else jax.default_backend()
+    query_block = min(S, query_block or (
+        kernels.tile_for(q.dtype) if backend == "tpu" else 512))
     if query_block % block:
         raise ValueError(f"a block of {query_block} queries straddles the "
                          f"sequence's blocks of {block}")
     path = dict(exact=block_diffusion_path(
-        jax.default_backend(), S, query_block, D, H, k.shape[2]))
-    block_kv = None
+        backend, S, query_block, D, H, k.shape[2]))
     if path["exact"] == "pallas":
-        from dlrover_tpu.ops.pallas.tuning import selected_tiling
-
-        block_kv = selected_tiling(query_block, D)[0]
-        path.update(block_kv=block_kv)
+        sub = kernels.sub_tile(query_block, block)
+        path.update(
+            sub=sub, calls=1,
+            pairs_multiplied=kernels.pairs_multiplied(S, query_block, sub))
     trace.note_trace_time(
         "attention.path", impl="block_diffusion", seq=S, rows=rows,
         block=block, query_block=query_block,
         pairs=block_diffusion_pairs(S, block), heads=H, head_dim=D, **path)
-    if block_kv is not None:
+    if path["exact"] == "pallas":
         _note_kept("block_diffusion", q)
+        with jax.named_scope("bd_noisy"):   # one call for both halves
+            return kernels.block_diffusion_kernels(
+                q, k, v, block, query_block, sub, interpret)
     noisy, clean = [], []
     for first in range(0, S, query_block):
         last = min(first + query_block, S)
         seen = slice(S, S + last)
         attend = functools.partial(
-            _block_diffusion_block, first=first, block=block,
-            block_kv=block_kv)
+            _block_diffusion_block, first=first, block=block)
         clean.append(attend(
             q[:, S + first: S + last], k[:, seen], v[:, seen], noisy=False))
         with jax.named_scope("bd_keys"):    # [clean keys ; own noisy keys]

@@ -47,7 +47,11 @@ and the elementwise part of the indexer's loss (a masked ``log_softmax`` of
 Two entry points over the forward and backward kernels, by what the
 caller's model has: ``selected_attention`` (an indexer to teach: the
 heads' mean with the output) and ``masked_attention`` (none: the output
-and the LSE).  ``ops/attention.py::eva_attention`` calls the second once a
+and the LSE).  The second has one model caller, ``ops/attention.py::
+eva_attention`` (the attention under the block-diffusion mask, which called
+it without ``shared`` once a block and half until PR 58, has kernels of its
+own: a mask that a rule on positions gives is made inside them,
+``block_diffusion_attention.py``): once a
 window of 2048 queries, a key head a query head, its window's keys under
 a causal ``keep`` and the summaries of earlier windows as ``shared`` keys
 that every query attends to: ``2048 + 128 w`` keys have no even tiling

@@ -859,14 +859,14 @@ class TestTrainerStep:
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
         widths and share (32 query heads on 4 key heads of 128, 16 of 128
-        experts, an eighth of the vocabulary), B1 S2048 = 4096 rows (the
-        cell's 8192 compile 64 kernel shapes: minutes): the attention under
-        the block-diffusion mask goes through the mask-operand kernels, a
-        block of 512 queries a call with that block's mask ``s8[1,512,
-        keys]`` among its operands, the noisy half's over 512 keys more; no
-        array has two dimensions of the rows; every call stands under the
-        sub-scopes the benchmark's readers sum, and the noise under its
-        own."""
+        experts, an eighth of the vocabulary), B1 S2048 = 4096 rows: the
+        attention under the block-diffusion mask is ONE kernel call a pass
+        over the whole ``[1, 4096, heads * 128]`` arrays (``ops/pallas/
+        block_diffusion_attention.py``), its mask made inside: no mask
+        operand, no joined keys, no block of rows sliced out or
+        concatenated; no array has two dimensions of the rows; both calls
+        stand under a sub-scope the benchmark's readers sum, and the noise
+        under its own."""
         from dlrover_tpu.models.llama import LlamaForCausalLM
         from dlrover_tpu.models.moe import MoELlamaConfig
         from dlrover_tpu.observability import trace
@@ -885,37 +885,37 @@ class TestTrainerStep:
         mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
         compiled = _trainer_step_compiled(mesh, one_layer)
         text = compiled.as_text()
-        assert "4096,4096]" not in text
-        # no block's scores are an array (the LSE is ``f32[1,32,512,128]``)
-        assert not re.search(
-            r"f32\[(1,)?32,512,(512|1024|1536|2048|2560)\]", text)
-        calls = [line.strip() for line in text.splitlines()
-                 if 'custom_call_target="tpu_custom_call"' in line
-                 and "s8[1,512," in line]    # not the grouped matmuls
-        # four blocks of queries, clean and noisy, forward and backward
-        # (with one layer the compiler finds the rematerialised forward in
-        # the forward)
-        assert len(calls) == 4 * 2 * 2
-        masks = sorted(int(found.group(1)) for call in calls
-                       for found in [re.search(r"s8\[1,512,(\d+)\]", call)])
-        assert masks == sorted(
-            2 * [512 * (j + 1) for j in range(4)]
-            + 2 * [512 * (j + 2) for j in range(4)])
+        # (``[1, 4096, 4096]`` is q itself here: 32 heads of 128)
+        assert "32,4096,4096]" not in text
+        # no tile's scores are an array (the LSE is ``f32[1,32,4096,128]``)
+        assert not re.search(r"f32\[(1,)?32,512,(256|512)\]", text)
+        # the mask-operand kernels are gone, and their masks with them
+        assert "s8[1,512," not in text
         found = trace.parse_device_scopes(text)
-        names = _kernel_names(text)
-        kernels = sorted(found.scopes["%" + name] for name in names
-                         if found.scopes["%" + name][0] == "attn.core")
-        assert kernels == sorted(
-            4 * [("attn.core", sub, which)
-                 for sub in ("bd_clean", "bd_noisy")
-                 for which in ("forward", "backward")])
+        kernels = {name: found.scopes["%" + name]
+                   for name in _kernel_names(text)
+                   if found.scopes["%" + name][0] == "attn.core"}
+        # one call forward and one backward (with one layer the compiler
+        # finds the rematerialised forward in the forward)
+        assert sorted(kernels.values()) == [
+            ("attn.core", "bd_noisy", "backward"),
+            ("attn.core", "bd_noisy", "forward")]
+        calls = {kernels[name][2]: line
+                 for line in text.splitlines()
+                 for name in re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = ", line)
+                 if name in kernels}
+        # the whole arrays as the model has them, heads as column blocks;
+        # the backward's dK and dV leave it as float32 accumulators
+        for call in calls.values():
+            assert "bf16[1,4096,4096]" in call and "bf16[1,4096,512]" in call
+        assert calls["backward"].count("f32[1,4096,512]") == 2
+        # nothing of the attention stands outside the names the readers sum
         scopes = set(found.scopes.values())
-        assert {sub for kind, sub, _ in scopes if kind == "attn.core"} >= {
-            "bd_keys", "bd_clean", "bd_noisy"}
+        assert {sub for kind, sub, _ in scopes if kind == "attn.core"} <= {
+            "", "bd_noisy"}
         assert ("embed", "noise", "forward") in scopes
-        # the benchmark's readers take these and nothing of another model
         reader = _load_layer_metric("bd_attn_ms_per_step")
-        assert set(reader.SUB_SCOPES) == {"bd_keys", "bd_clean", "bd_noisy"}
+        assert "bd_noisy" in reader.SUB_SCOPES
         # 172.5 M parameters at 8 bytes of state each are the arguments
         mem = compiled.memory_analysis()
         assert 1.37e9 < mem.argument_size_in_bytes < 1.40e9
@@ -924,11 +924,12 @@ class TestTrainerStep:
             self, topo, as_if_on_tpu):
         """Two scanned block-diffusion layers at small widths (8 query
         heads on 2 key heads of 128, a dense block, B1 S1024 = 2048 rows:
-        two blocks of 512 queries a half): the layers are one loop a pass,
-        and the backward pass's loop holds the four backward kernels and
-        NO forward kernel: the layer's rematerialisation keeps ``out`` and
-        the LSE (``ops/pallas/kept.py``), the LSE as ``[1, 8, 512]`` and
-        never lane-broadcast in the saved stack."""
+        two tiles of 512 queries a half): the layers are one loop a pass,
+        and the backward pass's loop holds the ONE backward kernel and NO
+        forward kernel: the layer's rematerialisation keeps ``out`` and
+        the LSE (``ops/pallas/kept.py``), one pair of whole arrays a layer,
+        the LSE as ``[1, 8, 2048]`` and never lane-broadcast in the saved
+        stack."""
         from dlrover_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         from dlrover_tpu.observability import trace
 
@@ -945,13 +946,11 @@ class TestTrainerStep:
         found = trace.parse_device_scopes(text)
         names = _kernel_names(text)
         kernels = sorted(found.scopes["%" + name] for name in names)
-        assert kernels == sorted(
-            2 * [("attn.core", sub, which)
-                 for sub in ("bd_clean", "bd_noisy")
-                 for which in ("forward", "backward")])
+        assert kernels == [("attn.core", "bd_noisy", "backward"),
+                           ("attn.core", "bd_noisy", "forward")]
         # the stacks the forward loop leaves for the backward one
-        assert "bf16[2,1,512,8,128]" in text and "f32[2,1,8,512]" in text
-        assert "f32[2,1,8,512,128]" not in text
+        assert "bf16[2,1,2048,8,128]" in text and "f32[2,1,8,2048]" in text
+        assert "f32[2,1,8,2048,128]" not in text
 
     def test_a_rematerialised_router_runs_no_matmul_and_no_sort(
             self, topo, as_if_on_tpu, monkeypatch):

@@ -4,7 +4,8 @@ policy, once for each attention core, the Pallas kernels in the interpreter
 on the CPU.
 
 For a core whose kernels name their results (``selected_attention``,
-``masked_attention`` without and with ``shared`` keys, ``_kda``): the
+``block_diffusion_kernels``, ``masked_attention`` with ``shared`` keys,
+``_kda``): the
 forward pass keeps exactly the layer's input and the named values, the LSE
 as ``[B, H, Q]``, at the bytes a layer that ``remat.kept`` notes; the
 gradient's jaxpr holds each forward kernel once a layer, where it holds it
@@ -64,11 +65,12 @@ KERNEL_CORES = {
                 index_block=128, max_seq_len=256), "gqa", 256,
         (ops, "_attend_selected_kernels"), {"_fwd_kernel": 2},
         {(1, 128, HEADS, DIM): 2, (1, HEADS, 128): 2}),
-    # 128 data tokens: one block of noisy queries and one of clean
-    "masked_attention": Core(
+    # 128 data tokens: a tile of noisy queries and one of clean, ONE call
+    # over both, one pair of whole arrays kept
+    "block_diffusion_kernels": Core(
         _config(block_diffusion=4, max_seq_len=128), "gqa", 256,
-        (ops, "_block_diffusion_block"), {"_fwd_kernel": 2},
-        {(1, 128, HEADS, DIM): 2, (1, HEADS, 128): 2}),
+        (ops, "block_diffusion_attention"), {"_fwd_kernel": 1},
+        {(1, 256, HEADS, DIM): 1, (1, HEADS, 256): 1}),
     # two windows of 256: the second over the first's 128 summaries
     "masked_attention_shared": Core(
         _config(num_kv_heads=HEADS, eva_window=256, eva_chunk=2,
