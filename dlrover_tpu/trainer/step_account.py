@@ -74,7 +74,11 @@ def verdict(interval_ns: int, parts_ns: Dict[str, int], cpu_ns: int,
     program, by self time), ``runnable_not_run`` (the thread waited for a
     CPU), ``caller_cpu`` (outside the program's spans, computing: the
     loop's own code), else ``waiting`` (outside the spans, asleep: the
-    loss came late)."""
+    loss came late).  Waiting for a CPU and computing hold it together: a
+    thread that did one or the other for over half the interval was not
+    asleep (a busy loop on a loaded host computes for half its time and
+    stands in the run queue for the other half, and neither alone holds
+    half), and the word is the larger one's."""
     half = interval_ns / 2
     if gc_ns > half:
         return "gc"
@@ -82,10 +86,10 @@ def verdict(interval_ns: int, parts_ns: Dict[str, int], cpu_ns: int,
     name = max(program, key=program.get, default=None)
     if name is not None and program[name] > half:
         return f"program:{name}"
-    if run_delay_ns > half:
-        return "runnable_not_run"
-    if cpu_ns - sum(program.values()) > half:
-        return "caller_cpu"
+    caller_cpu_ns = max(0, cpu_ns - sum(program.values()))
+    if run_delay_ns + caller_cpu_ns > half:
+        return ("runnable_not_run" if run_delay_ns > caller_cpu_ns
+                else "caller_cpu")
     return "waiting"
 
 

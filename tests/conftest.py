@@ -35,6 +35,14 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 os.environ.setdefault("DLROVER_TPU_SOCKET_DIR", "/tmp/dlrover_tpu_test/sockets")
 os.environ["DLROVER_TPU_PLATFORM"] = "cpu"
+# Tier-1 compiles for a CPU to check what a program computes, never to run
+# it fast: the CPU backend is asked for correct code, not for optimised code
+# (XLA's backend level 0, LLVM's expensive passes off).  In the environment,
+# so that every process the suite starts inherits it (the rehearsals,
+# ``tpurun``'s workers, the drills), and set, not defaulted: a constant of
+# the suite.  ``test_chip_compile.py`` reads what the TPU compiler makes of
+# a program and takes it out again for its module (its ``topo`` fixture).
+os.environ["JAX_DISABLE_MOST_OPTIMIZATIONS"] = "1"
 
 import jax  # noqa: E402
 import pytest  # noqa: E402

@@ -50,7 +50,13 @@ def topo():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # this file asserts what the TPU compiler makes of a program (kernel
+    # counts, what a loop holds, that a cut fits), so the suite's constant
+    # (``conftest.py``: most optimisations off) is out for the module too
+    unoptimised = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
     yield desc
+    jax.config.update("jax_disable_most_optimizations", unoptimised)
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
@@ -962,14 +968,17 @@ class TestTrainerStep:
         ``top_k`` is on the chip), no float32 ``[16384, 2560] x [2560,
         512]`` product and no gather: the layer keeps the router's logits
         and its choice (``kept.MOE_ROUTE``) and reads the weights at the
-        choice by compare and sum.  Under the policy without that name, the
-        parent's, it holds one product and one sort.  At the ladder's three
-        small extents the sums by token run over the rows held: nothing
-        there has the extent of the 131,072 slots times the width."""
+        choice by compare and sum.  The forward pass of the same text is
+        the control: there ``moe/route`` holds the one product, at
+        ``highest``, and the one sort (that the kept name is what takes
+        them out of the other pass is held on the CPU:
+        ``test_remat_kept.py::test_a_router_keeps_its_logits_its_choice_and_its_count``,
+        three routers).  At the ladder's three small extents the sums by
+        token run over the rows held: nothing there has the extent of the
+        131,072 slots times the width."""
         from dlrover_tpu.models import llama
         from dlrover_tpu.models.moe import MoELlamaConfig
         from dlrover_tpu.observability import trace
-        from dlrover_tpu.ops.pallas import kept
 
         paths = []
         monkeypatch.setattr(
@@ -986,36 +995,36 @@ class TestTrainerStep:
                 selection_bias=True, shared_experts=1, experts_held=16)
             return llama.LlamaForCausalLM(cfg), (1, 16384)
 
-        def computed_again():
-            """The router's products and sorts among the instructions of
-            ``moe/route`` in the rematerialised pass."""
-            mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
-            text = _trainer_step_compiled(mesh, two_layers).as_text()
-            (combine,) = set(filter(None, paths))
-            _sums_by_token_as_the_path_says(
-                text, combine.split(","), 131072, 2560)
-            found = trace.parse_device_scopes(text)
-            again = [
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        text = _trainer_step_compiled(mesh, two_layers).as_text()
+        (combine,) = set(filter(None, paths))
+        assert combine == "rows,rows,rows,slots"
+        _sums_by_token_as_the_path_says(
+            text, combine.split(","), 131072, 2560)
+        found = trace.parse_device_scopes(text)
+
+        def route(in_pass):
+            """The instructions of ``moe/route`` in one pass."""
+            return [
                 line for line in text.split("\n")
                 for name in re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", line)
-                if found.scopes["%" + name] == ("moe", "route", trace.REMAT)]
-            assert again                # the sigmoid, the losses' terms
-            assert not [line for line in again if " gather(" in line]
-            return (
-                [line for line in again if re.search(
-                    r"= f32\[16384,512\]\S* (convolution|dot)\(", line)],
-                [line for line in again if re.search(
-                    r" sort\(|TopK|top_k", line.split("metadata=")[0])])
+                if found.scopes["%" + name] == ("moe", "route", in_pass)]
 
-        assert computed_again() == ([], [])
-        assert set(filter(None, paths)) == {"rows,rows,rows,slots"}
-        monkeypatch.setattr(
-            llama, "LAYER_POLICY",
-            jax.checkpoint_policies.save_only_these_names(
-                *(name for name in kept.NAMES if name != kept.MOE_ROUTE)))
-        products, sorts = computed_again()
-        assert len(products) == 1 and "highest" in products[0]
-        assert len(sorts) == 1
+        def products(lines):
+            return [line for line in lines if re.search(
+                r"= f32\[16384,512\]\S* (convolution|dot)\(", line)]
+
+        def sorts(lines):
+            return [line for line in lines if re.search(
+                r" sort\(|TopK|top_k", line.split("metadata=")[0])]
+
+        forward, again = route(trace.FORWARD), route(trace.REMAT)
+        (product,) = products(forward)
+        assert "highest" in product
+        assert len(sorts(forward)) == 1
+        assert again                # the sigmoid, the losses' terms
+        assert not products(again) and not sorts(again)
+        assert not [line for line in again if " gather(" in line]
 
     def test_olmoe_widths_ep4(self, topo, as_if_on_tpu):
         """One layer of OLMoE-1B-7B at B8 S4096 over ``ep=4``: the grouped

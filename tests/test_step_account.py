@@ -5,10 +5,12 @@ parts, and the ``trainer.slow_step`` record for a stall planted in each of
 four places."""
 
 import gc
+import itertools
 import json
 import os
 import threading
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,10 @@ from dlrover_tpu.utils.step_clock import StepClock
 
 MS = 1_000_000
 T0 = 1_790_000_000 * 1_000_000_000
+
+
+def ns(ms):
+    return round(ms * MS)
 
 
 def span(name, start_ms, dur_ms, tid=1, thread="main", **attrs):
@@ -114,6 +120,10 @@ class TestExplain:
             "outside_spans_ns"] == found["interval_ns"]
 
 
+#: among a case's parts: its interval in ms, where that is not 1000
+INTERVAL = "(interval)"
+
+
 class TestVerdict:
     @pytest.mark.parametrize("parts,cpu,delay,gc_ns,want", [
         ({"trainer.step.tick": 600, "trainer.step": 100}, 50, 0, 0,
@@ -127,11 +137,23 @@ class TestVerdict:
         ({"trainer.step": 3}, 950, 10, 0, "caller_cpu"),
         ({"trainer.step": 3}, 5, 10, 0, "waiting"),
         ({}, 0, 0, 0, "waiting"),
+        # the busy loop of the driver's run of PR 58's tree, six saturated
+        # workers beside it: it never slept, was on a CPU for half of its
+        # 404.553 ms and in the run queue for the other half, and neither
+        # alone holds half; then the same with the two the other way round
+        ({INTERVAL: 404.553, "trainer.step": 1.428}, 202.44, 200.917, 0,
+         "caller_cpu"),
+        ({INTERVAL: 404.553, "trainer.step": 1.428}, 202.345, 201.012, 0,
+         "runnable_not_run"),
+        # and a thread that did sleep: the two together under half
+        ({INTERVAL: 404.553, "trainer.step": 1.428}, 102.44, 100.917, 0,
+         "waiting"),
     ])
     def test_one_word(self, parts, cpu, delay, gc_ns, want):
         assert step_account.verdict(
-            1000 * MS, {k: v * MS for k, v in parts.items()}, cpu * MS,
-            delay * MS, gc_ns * MS) == want
+            ns(parts.get(INTERVAL, 1000)),
+            {k: ns(v) for k, v in parts.items() if k is not INTERVAL},
+            ns(cpu), ns(delay), ns(gc_ns)) == want
 
 
 @pytest.fixture
@@ -173,23 +195,35 @@ class TestCollectorHook:
         flight_recorder.recorder()
         assert gc.callbacks.count(flight_recorder._on_gc) == 1
 
-    def test_a_long_pause_is_summed_and_is_a_span_on_its_thread(self, rec):
+    def test_a_long_pause_is_summed_and_is_a_span_on_its_thread(
+            self, rec, monkeypatch):
+        """The hook is handed its times, as ``test_one_word`` hands
+        ``verdict`` its numbers: how long a collection takes is the
+        host's, and what the hook makes of a long one is what is held."""
         flight_recorder.recorder()
-        graph = _Graph(400_000)
+        long_ns = 2 * flight_recorder.GC_SPAN_MIN_NS
+        clock = itertools.count(T0, long_ns)    # every read a pause later
         before = flight_recorder.gc_pause_ns()
-        t0 = time.time_ns()
-        gc.collect()
-        took = time.time_ns() - t0
+        was = gc.isenabled()
+        gc.disable()       # no collection but the one asked for
+        try:
+            with monkeypatch.context() as patch:
+                patch.setattr(
+                    flight_recorder, "time", types.SimpleNamespace(**{
+                        **vars(time), "time_ns": lambda: next(clock)}))
+                gc.collect()
+        finally:
+            if was:
+                gc.enable()
         paused = flight_recorder.gc_pause_ns() - before
-        del graph
-        assert flight_recorder.GC_SPAN_MIN_NS <= paused <= took
-        pauses = [s for s in _named(rec, "runtime.gc") if s.start_ns >= t0]
-        assert pauses and pauses[0].tid == threading.get_ident()
-        assert pauses[0].attrs["generation"] == 2
-        assert pauses[0].attrs["collected"] >= 0
-        assert sum(s.end_ns - s.start_ns for s in pauses) <= paused
+        pauses = [s for s in _named(rec, "runtime.gc") if s.start_ns == T0]
+        (pause,) = pauses
+        assert pause.tid == threading.get_ident()
+        assert pause.attrs["generation"] == 2
+        assert pause.attrs["collected"] >= 0
+        assert paused == pause.end_ns - pause.start_ns == long_ns
         # kept in memory as the step's spans are: a tuple, no record
-        assert type(pauses[0]) is trace.SpanTuple
+        assert type(pause) is trace.SpanTuple
         # and a root of its own, so that an incident's timeline, which
         # holds the ring's spans to connected trees, takes it
         from dlrover_tpu.observability import timeline
