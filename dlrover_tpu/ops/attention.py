@@ -37,8 +37,8 @@ one rotary key every head shares, values narrower than the scores, in
 kernels of its own on a TPU (``ops/pallas/latent_attention.py``).
 ``differential_attention`` (at the very end) is the core of differential
 attention in its head-paired form: two softmax maps of one head size over
-one value of twice that, their difference; on a TPU both maps in ONE call
-of the FA2 kernels at the value's width.
+one value of twice that, their difference; on a TPU both maps in ONE visit
+of a pair of tiles (``ops/pallas/differential_attention.py``).
 """
 
 import functools
@@ -917,42 +917,45 @@ def differential_attention(q, k, v, lam, mask=None, window=None,
     ``j`` reads key pair ``j // (H / G)``); ``lam`` a scalar.
 
     ``impl`` ``"reference"``: the reference core twice, ``(q1, k1, V)`` and
-    ``(q2, k2, V)``, the value at its own width.  ``"flash"``: ONE call of
-    the FA2 kernels (the band kernels under a window) at head size ``2D``
-    over ``H`` query heads and ``G`` key heads: map 1's pairs then map 2's,
-    ``q`` and ``k`` with ``D`` columns of zeros beside them (``q`` times
-    ``sqrt(2)`` first, in float32: the kernel divides by ``sqrt(2D)``), ``V``
-    once for each map.  Against the model code's four calls at head size
-    ``D`` (each map against each half of ``V``: every score computed twice,
-    and a head of 64 fills half of the matrix unit's 128 lanes) this scores
-    each pair once a map; what it multiplies beyond the model's pairs is the
-    scores' contraction over ``2D`` lanes of which ``D`` hold zeros (512
-    multiply-adds a pair and map where the model's count is 384) and the
-    masked part of the blocks on the diagonal and the band's edge."""
+    ``(q2, k2, V)``, the value at its own width.  ``"flash"``: the kernels
+    of ``ops/pallas/differential_attention.py`` (heads of 64: a pair, and
+    its value, are one 128-lane column block of the arrays as they are), one
+    forward call that visits a pair of tiles once for both maps against ONE
+    value tile, and one backward call of eight block products a pair of
+    tiles (``dO V^T`` and ``(P1 - lam P2)^T dO`` serve both maps), the mask
+    and a window's band made from positions in the tiles they cut and
+    nowhere else; never the reference: off a TPU it raises unless
+    ``interpret`` asks for the Pallas interpreter.  The ``attention.path``
+    record says what runs (``tiles_live`` a head of ``tiles_walked`` grid
+    steps of its forward pass), ``remat.kept`` what a rematerialised layer
+    keeps of it (``O1``, ``O2`` and the two LSEs)."""
     B, S, H, D = q.shape
     G = k.shape[2]
-    q1, q2 = q[:, :, 0::2], q[:, :, 1::2]
-    k1, k2 = k[:, :, 0::2], k[:, :, 1::2]
-    wide = v.reshape(B, v.shape[1], G // 2, 2 * D)
+    attrs = dict(impl="differential", seq=S, heads=H, head_dim=D)
+    band = {} if window is None else {"window": window}
     if impl != "flash":
         trace.note_trace_time(
-            "attention.path", impl="differential", seq=S, heads=H,
-            head_dim=D, exact="reference",
-            **({} if window is None else {"window": window}))
+            "attention.path", **attrs, exact="reference", **band)
+        q1, q2 = q[:, :, 0::2], q[:, :, 1::2]
+        k1, k2 = k[:, :, 0::2], k[:, :, 1::2]
+        wide = v.reshape(B, v.shape[1], G // 2, 2 * D)
         first = reference_attention(q1, k1, wide, mask, window)
         second = reference_attention(q2, k2, wide, mask, window)
-    else:
-        def padded(t, factor=None):
-            if factor is not None:
-                t = (t.astype(jnp.float32) * factor).astype(t.dtype)
-            return jnp.pad(t, ((0, 0),) * 3 + ((0, D),))
+        return first.astype(jnp.float32) - lam * second.astype(jnp.float32)
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "the differential kernels need a TPU backend (found "
+            f"{jax.default_backend()!r}); use attention_impl='reference' "
+            "off the chip, or pass interpret=True")
+    from dlrover_tpu.ops.pallas import differential_attention as kernels
+    from dlrover_tpu.ops.pallas import kept
 
-        out = flash_attention(
-            jnp.concatenate(
-                [padded(q1, 2.0 ** 0.5), padded(q2, 2.0 ** 0.5)], axis=2),
-            jnp.concatenate([padded(k1), padded(k2)], axis=2),
-            jnp.concatenate([wide, wide], axis=2),
-            causal=True, window=window, interpret=interpret,
-            path_attrs={"maps": "differential", "scores_over": D})
-        first, second = out[:, :, : H // 2], out[:, :, H // 2:]
-    return first.astype(jnp.float32) - lam * second.astype(jnp.float32)
+    tiles = kernels.tiles_for(S, window)
+    walk = kernels.Walk(S, *tiles, window)
+    trace.note_trace_time(
+        "attention.path", **attrs, core="pallas", maps=2, scores_over=D,
+        value=2 * D, backward_products=8, **band, tiles=tiles,
+        tiles_live=walk.tiles_live, tiles_walked=walk.tiles_walked)
+    kept.note("diff", **kernels.kept_bytes(q))
+    return kernels.differential_attention_kernels(
+        q, k, v, jnp.asarray(lam, jnp.float32), window, tiles, interpret)

@@ -802,10 +802,14 @@ class TestTrainerStep:
         (``ops/pallas/selective_scan.py``) under ``attn.core`` / ``scan``,
         a forward and a backward call a loop and NO forward call in the
         rematerialised pass (the layer keeps ``y`` and the chunks' starts);
-        the differential cores through the FA2 kernels under ``attn.core``
-        / ``diff``, the window's too; no array of the compiled step holds
-        the state's history ``[S, 5120, 16]`` in any layout; the head reads
-        the embedding table."""
+        the differential cores through kernels of their own
+        (``ops/pallas/differential_attention.py``) under ``attn.core`` /
+        ``diff`` (``window`` / ``diff`` for the band), ONE forward and ONE
+        backward call a layer over the model's own arrays and NO forward
+        call in the rematerialised pass (the layer keeps ``O1``, ``O2`` and
+        the LSEs a row a head); no array of the compiled step holds the
+        state's history ``[S, 5120, 16]`` in any layout; the head reads the
+        embedding table."""
         from benchmarks.common import HERE, load_module, read_json
         from dlrover_tpu.observability import trace
 
@@ -832,26 +836,59 @@ class TestTrainerStep:
         assert sorted(k for k in kernels if k[1] == "scan") == sorted(
             [("attn.core", "scan", "forward")] * 2
             + [("attn.core", "scan", "backward")] * 2)
-        # window (the periods' loop), whole (the memory layer), cross: FA2
-        # names nothing, so each runs forward, again, and backward
+        # window (the periods' loop), whole (the memory layer), cross: a
+        # forward and a backward call each, and none under ``remat``
         diff = [k for k in kernels if k[1] == "diff"]
-        assert {k[2] for k in diff} == {"forward", "remat", "backward"}
-        assert len([k for k in diff if k[2] == "forward"]) == 3
+        assert sorted(diff) == sorted(
+            [("attn.core", "diff", "forward")] * 3
+            + [("attn.core", "diff", "backward")] * 3)
         assert len(diff) == len(kernels) - 4
+        # the band's stand under ``window`` too
+        names = set(_kernel_names(text))
+        paths_of = {name: line for line in text.splitlines()
+                    for name in re.findall(
+                        r"^\s*(?:ROOT )?%([\w.\-]+) = ", line)
+                    if name in names}
+        assert sum("/window/diff/" in line
+                   for line in paths_of.values()) == 2
+        # the model's own arrays, a pair a column block: nothing padded to
+        # 128 a head, no second copy of V, no lane-broadcast LSE
+        calls = [line for line in paths_of.values() if "/diff/" in line]
+        assert len(calls) == 6
+        for call in calls:
+            assert f"bf16[1,{S},2560]" in call and f"bf16[1,{S},1280]" in call
+            assert not re.search(rf"\[1,{S},(40|20),128\]|\[1,{S},5120\]",
+                                 call.split("custom-call(")[1])
+        assert f"f32[1,40,{S},128]" not in text
         paths = [attrs for name, attrs in notes if name == "attention.path"]
         mamba = next(a for a in paths if a["impl"] == "mamba")
         assert mamba["core"] == "pallas" and mamba["channels"] == 5120
         assert (mamba["state"], mamba["conv"], mamba["dt_rank"]) == (16, 4, 160)
-        flash = [a for a in paths if a["impl"] == "flash"]
-        assert {a.get("window") for a in flash} == {512, None}
-        assert all(a["heads"] == 40 and a["head_dim"] == 128
-                   and a["maps"] == "differential" and a["scores_over"] == 64
-                   for a in flash)
-        kept = [attrs for name, attrs in notes if name == "remat.kept"]
-        assert kept and kept[0]["core"] == "ssm"
+        assert not [a for a in paths if a["impl"] == "flash"]
+        cores = [a for a in paths if a["impl"] == "differential"]
+        assert {a.get("window") for a in cores} == {512, None}
+        assert all(a["heads"] == 40 and a["head_dim"] == 64
+                   and a["core"] == "pallas" and a["maps"] == 2
+                   and a["scores_over"] == 64 and a["value"] == 128
+                   and a["backward_products"] == 8 for a in cores)
+        whole = next(a for a in cores if "window" not in a)
+        band = next(a for a in cores if "window" in a)
+        # two tiles of 1,024: three live of four steps, the diagonal's cut;
+        # the band's one tile of 2,048, cut by sub-tiles
+        assert (whole["tiles_live"], whole["tiles_walked"]) == (3, 4)
+        assert (band["tiles_live"], band["tiles_walked"]) == (1, 1)
+        kept = {attrs["core"]: attrs for name, attrs in notes
+                if name == "remat.kept"}
+        assert set(kept) == {"ssm", "diff"}
         # y in bfloat16 and the starts of 32 chunks in float32
-        assert kept[0]["bytes_per_layer"] == (
+        assert kept["ssm"]["bytes_per_layer"] == (
             S * 5120 * 2 + (S // 64) * 5120 * 16 * 4)
+        # O1 and O2 in bfloat16 and a row a head of LSE in float32
+        assert kept["diff"]["names"] == "attn_out,attn_lse"
+        assert kept["diff"]["bytes_per_layer"] == (
+            2 * S * 2560 * 2 + 40 * S * 4)
+        # what the forward loop leaves the backward one: the LSEs as rows
+        assert re.search(rf"f32\[(\d,)?1,40,(1,)?{S}\]", text)
         assert "lm_head" not in "".join(
             jax.tree_util.keystr(path) for path, _ in
             jax.tree_util.tree_leaves_with_path(
