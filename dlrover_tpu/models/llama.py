@@ -20,6 +20,12 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
   and activation) and runs no forward kernel whose results were kept.
   The FA2 kernel and every ``jax.numpy`` core name nothing: their layers
   are recomputed whole;
+* a looped stack (``loop_steps``) is one more ``nn.scan`` AROUND the scan
+  over layers, its parameters broadcast: a weight is in the tree once and
+  used once a loop step, what the forward pass keeps is every layer
+  application's input, and the heads of its exits are worked by blocks of
+  rows (``token_losses``) so that no ``[B, S, vocab]`` array stands in a
+  training step;
 * attention is GQA with rotary embeddings; the inner kernel is pluggable
   (jnp reference path here, Pallas flash/ring attention in
   ``dlrover_tpu.ops``).
@@ -233,6 +239,26 @@ class LlamaConfig:
     memory_layers: Tuple[str, ...] = ()
     cross_pattern: Tuple[str, ...] = ()
     cross_periods: int = 0
+    # a looped stack (Ouro, arXiv:2510.25741; Universal Transformers): the
+    # whole stack of ``num_layers`` run ``loop_steps`` times over the SAME
+    # parameters, the final norm inside the loop: ``h_t = norm(layers(h_{t
+    # - 1}))``, ``h_0`` the embeddings; the model returns the head's logits
+    # of ``h_T``.  1: the stack once, the program as it was
+    loop_steps: int = 1
+    # each branch's OUTPUT normalised before it is added to the residual
+    # stream (``x + norm(attn(norm(x)))``, and the feed-forward alike): two
+    # more norms a layer, ``attn_out_norm`` and ``mlp_out_norm``
+    sandwich_norm: bool = False
+    # an exit gate read after every loop step, ``lam_t = sigmoid(h_t w_g +
+    # b_g)`` a token, the head read after every loop step, and the model's
+    # OWN objective sown into ``losses``: the expected cross entropy over
+    # the exit distribution ``p_t = lam_t prod_{j<t} (1 - lam_j)`` (``p_T``:
+    # the mass that is left) less ``exit_entropy_weight`` times its entropy
+    # (Ouro's first training stage: a uniform prior over the exits).  The
+    # model sees no labels: position ``i``'s target is ``input_ids[i + 1]``,
+    # and the last position goes without one
+    exit_gate: bool = False
+    exit_entropy_weight: float = 0.0
 
     def __post_init__(self):
         valid = ("reference", "flash", "ring")
@@ -331,12 +357,23 @@ class LlamaConfig:
             raise ValueError(
                 "block_diffusion needs plain softmax layers, one prediction "
                 "head and a mask_token_id inside the vocabulary")
+        if self.loop_steps < 1 or (self.exit_gate and self.loop_steps < 2) or (
+                self.loop_steps > 1 and (
+                    self.layer_pattern or self.block_diffusion
+                    or self.pred_heads > 1 or self.tie_embeddings
+                    or not self.scan_layers
+                    or self.feed_forward() is not MLP)):
+            raise ValueError(
+                "loop_steps > 1 loops the one scanned stack of equal dense "
+                "layers (no layer_pattern, no block_diffusion, no routed "
+                "feed-forward, one untied prediction head), and exit_gate "
+                "needs a loop to leave")
 
     @property
     def own_objective(self) -> bool:
         """Whether the model sows its whole objective into ``losses``: a
         trainer then adds no next-token cross entropy on top."""
-        return bool(self.block_diffusion)
+        return bool(self.block_diffusion or self.exit_gate)
 
     def step_rngs(self, step) -> dict:
         """The random streams ``model.apply`` wants in training step
@@ -1426,10 +1463,15 @@ class DecoderLayer(nn.Module):
                 mixed, handed = mixed
         else:
             mixed = attention(cfg, name="attn")(h, positions, mask)
+        if cfg.sandwich_norm:
+            mixed = norm(name="attn_out_norm")(mixed)
         x = x + mixed.astype(x.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         h = norm(name="post_attn_norm")(x)
-        x = x + feed_forward(ffn_cfg, name="mlp")(h).astype(x.dtype)
+        out = feed_forward(ffn_cfg, name="mlp")(h)
+        if cfg.sandwich_norm:
+            out = norm(name="mlp_out_norm")(out)
+        x = x + out.astype(x.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         return (x, handed) if self.keeps else x
 
@@ -1573,7 +1615,9 @@ class LMHead(nn.Module):
             (cfg.hidden_size, cfg.pred_heads * cfg.vocab_size),
             cfg.param_dtype,
         )
-        return self.project(cfg, x, kernel)
+        # ``x`` None: the kernel alone, for a caller that reads the head
+        # more than once (a looped stack's exits)
+        return kernel if x is None else self.project(cfg, x, kernel)
 
     @staticmethod
     def project(cfg, x, kernel, transposed=False):
@@ -1585,6 +1629,75 @@ class LMHead(nn.Module):
             (((x.ndim - 1,), (1 if transposed else 0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+
+#: the most float32 logits ``token_losses`` holds at once
+HEAD_BLOCK_BYTES = 2 ** 30
+
+
+def token_losses(cfg, x, kernel, targets):
+    """``-log softmax(x kernel)[targets]``, ``[B, S]`` float32, without
+    ``[B, S, vocab]`` ever standing whole: the head (``LMHead.project``) and
+    its cross entropy a block of rows at a time, each block rematerialised,
+    so that the forward pass keeps ``x`` and the backward pass makes a
+    block's logits again, takes their gradient and lets them go.  The block
+    is the largest power-of-two share of ``S`` whose float32 logits stay
+    under ``HEAD_BLOCK_BYTES`` (4096 rows of 16,384 at a vocabulary of
+    49,152; a small model's rows all at once), from the shapes alone."""
+    B, S, E = x.shape
+    rows = S
+    while rows % 2 == 0 and 4 * B * rows * kernel.shape[-1] > HEAD_BLOCK_BYTES:
+        rows //= 2
+
+    @jax.checkpoint
+    def block(first):
+        # sliced from the whole inside: what is kept is ``x`` as it stands,
+        # not a second copy of it in blocks
+        logits = LMHead.project(
+            cfg, jax.lax.dynamic_slice_in_dim(x, first, rows, 1), kernel)
+        taken = jnp.take_along_axis(
+            logits, jax.lax.dynamic_slice_in_dim(
+                targets, first, rows, 1)[..., None], axis=-1)[..., 0]
+        return jax.nn.logsumexp(logits, axis=-1) - taken
+
+    if rows == S:
+        return block(0)
+    losses = jax.lax.map(block, jnp.arange(0, S, rows))
+    return jnp.moveaxis(losses, 0, 1).reshape(B, S)
+
+
+class ExitGate(nn.Module):
+    """A looped stack's exit gate: the logit of ``lam = sigmoid(h w_g +
+    b_g)``, one scalar a token, ``[B, S]`` float32 (accumulated in float32
+    from operands in the compute dtype, as the head's logits are)."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        kernel = self.param(
+            "kernel", nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ("embed", None)),
+            (cfg.hidden_size, 1), cfg.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros, (1,),
+                          cfg.param_dtype)
+        # operands in the stream's dtype, so that what the backward pass
+        # keeps of a loop step is the stream itself and no float32 copy
+        return jnp.einsum(
+            "bse,e->bs", x, kernel[:, 0].astype(x.dtype),
+            preferred_element_type=jnp.float32) + bias[0].astype(jnp.float32)
+
+
+def exit_distribution(gate_logits):
+    """``log p_t`` ``[T, ...]`` from the gates' logits ``[T, ...]``: ``p_t =
+    lam_t prod_{j<t} (1 - lam_j)`` for ``t < T`` and ``p_T = prod_{j<T} (1 -
+    lam_j)``, the mass no earlier exit took (the last gate's own value
+    decides nothing), in logarithms so that no product underflows."""
+    stay = jax.nn.log_sigmoid(-gate_logits)
+    before = jnp.cumsum(stay, axis=0) - stay        # sum over j < t
+    return jnp.concatenate([
+        before[:-1] + jax.nn.log_sigmoid(gate_logits[:-1]), before[-1:]])
 
 
 class LlamaForCausalLM(nn.Module):
@@ -1632,6 +1745,8 @@ class LlamaForCausalLM(nn.Module):
                 layer_kind(entry)[0] for entry in cfg.layer_kinds()})
         ) else jnp.tril(jnp.ones((S, S), dtype=bool))[None, None, :, :]
 
+        if cfg.loop_steps > 1:
+            return self._looped_stack(x, positions, mask, input_ids)
         if cfg.hybrid:
             x = self._hybrid_stack(x, positions, mask)
         elif cfg.layer_pattern:
@@ -1703,6 +1818,85 @@ class LlamaForCausalLM(nn.Module):
                 x = periods_of(cfg.cross_pattern, cfg.cross_periods, "cross",
                                memory)
         return x
+
+    def _looped_stack(self, x, positions, mask, input_ids):
+        """The stack ``loop_steps`` times over the same parameters, the
+        final norm inside the loop; the logits ``z_T`` of the last loop
+        step's stream.  One ``nn.scan`` over loop steps whose parameters are
+        BROADCAST (the tree is the plain model's: ``layers/layer/...``
+        stacked on the layer axis, ``final_norm``, ``lm_head``), around the
+        scan over layers; a weight's gradient is the sum of its uses',
+        taken by the scan's transpose in the cotangent's dtype (under a
+        trainer's ``grads_dtype`` bfloat16: bfloat16).  Each layer is
+        rematerialised as ever: the forward pass keeps ``loop_steps x
+        num_layers`` layer inputs.
+
+        With ``exit_gate`` a loop step also reads the gate and the head's
+        token losses (``token_losses``: by blocks of rows, so what survives
+        a loop step is ``[B, S]`` losses and gate logits, never logits over
+        the vocabulary), and ``_sow_exit_objective`` sows the objective."""
+        cfg = self.config
+        kernel = LMHead(cfg, name="lm_head")(None)
+        # position i's target is token i + 1; the last has none (weight 0)
+        with jax.named_scope("head_loss"):
+            targets = jnp.roll(input_ids, -1, axis=1)
+
+        def loop_step(model, x, _):
+            x, _ = _stacked(_layer_class(cfg, True), cfg.num_layers)(
+                cfg, name="layers")(x, positions, mask)
+            # rematerialised: a loop step keeps the stack's output in the
+            # compute dtype, not the norm's float32 intermediates (two
+            # [B, S, E] float32 arrays a loop step)
+            x = nn.remat(
+                lambda model, x: _norm_of(cfg)(name="final_norm")(x),
+                prevent_cse=False)(model, x)
+            if not cfg.exit_gate:
+                return x, None
+            with jax.named_scope("head_loss"):
+                losses = token_losses(cfg, x, kernel, targets)
+                with jax.named_scope("exit"):
+                    gate = ExitGate(cfg, name="exit_gate")(x)
+            return x, (losses, gate)
+
+        x, exits = nn.scan(
+            loop_step, variable_broadcast="params",
+            split_rngs={"params": False}, length=cfg.loop_steps)(
+                self, x, None)
+        if cfg.exit_gate:
+            with jax.named_scope("head_loss"), jax.named_scope("exit"):
+                self._sow_exit_objective(*exits)
+        with jax.named_scope("lm_head"):
+            logits = LMHead.project(cfg, x, kernel)
+        return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
+
+    def _sow_exit_objective(self, losses, gate_logits):
+        """The model's objective into ``losses`` from every exit's token
+        losses ``CE_t`` and gate logits, ``[T, B, S]`` each: ``mean_i [sum_t
+        p_t,i CE_t,i - beta H(p_.,i)]`` over the positions that have a
+        target (all but a sequence's last), ``H`` the exit distribution's
+        entropy in nats; and into ``stats`` its mean entropy, the mean mass
+        of the last exit and each exit's mean cross entropy.  A caller that
+        asks for the collection ``exits`` is handed both arrays token by
+        token (``token_losses`` and ``log_p``: the comparison with the
+        reference reads them; nothing in training does)."""
+        cfg = self.config
+        log_p = exit_distribution(gate_logits)
+        self.sow("exits", "token_losses", losses)
+        self.sow("exits", "log_p", log_p)
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        has_target = jnp.arange(losses.shape[-1]) < losses.shape[-1] - 1
+
+        def mean(per_token):     # over [.., B, S]'s last two axes
+            return jnp.sum(jnp.where(has_target, per_token, 0.0),
+                           axis=(-2, -1)) / (
+                per_token.shape[-2] * (per_token.shape[-1] - 1))
+
+        self.sow("losses", "exit_objective", mean(
+            jnp.sum(p * losses, axis=0) - cfg.exit_entropy_weight * entropy))
+        self.sow("stats", "loop_exit_entropy", mean(entropy))
+        self.sow("stats", "loop_exit_mass_last", mean(p[-1]))
+        self.sow("stats", "loop_ce_by_step", mean(losses))
 
     def _noisy_and_clean(self, input_ids):
         """``([noisy copy ; clean copy] [B, 2S], the NELBO's weights [B,
@@ -1787,7 +1981,8 @@ class LlamaForCausalLM(nn.Module):
         def layers(entries):
             total = 0
             for kind, dense in map(layer_kind, entries):
-                total += by_kind[kind]() + 2 * norm + (
+                total += by_kind[kind]() + (
+                    4 if cfg.sandwich_norm else 2) * norm + (
                     3 * cfg.hidden_size * cfg.dense_intermediate_size
                     if dense else cfg.feed_forward_params())
             return total
@@ -1795,5 +1990,8 @@ class LlamaForCausalLM(nn.Module):
         entries = (cfg.layer_kinds() if cfg.layer_pattern
                    else ("gqa",) * cfg.num_layers)
         tables = cfg.pred_heads + (0 if cfg.tie_embeddings else 1)
+        # a looped stack's weights count once; its exit gate: a column
+        # and a bias
+        gate = cfg.hidden_size + 1 if cfg.exit_gate else 0
         return (cfg.vocab_size * cfg.hidden_size * tables
-                + layers(entries) + norm)
+                + layers(entries) + norm + gate)

@@ -516,6 +516,8 @@ def note_trace_time(name: str, **attrs: Any) -> None:
 SCOPE_KINDS: Dict[str, str] = {
     "embed": "embed",
     "input_norm": "norm", "post_attn_norm": "norm", "final_norm": "norm",
+    # the sandwich norms on a branch's output (``sandwich_norm``)
+    "attn_out_norm": "norm", "mlp_out_norm": "norm",
     "ln_1": "norm", "ln_2": "norm", "ln_f": "norm",
     "attn": "attn.proj",
     "attn.core": "attn.core",
@@ -569,6 +571,11 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
     "moe": ("route", "sort", "gmm", "exchange", "combine", "shared"),
     # a router's selection bias moved by the load (``models/moe.py``)
     "optimizer": ("bias",),
+    # a looped stack's exits (``models/llama.py::_looped_stack``): the gate
+    # after every loop step, the exit distribution, the weighting of the
+    # exits' losses and the entropy; the heads and their cross entropies
+    # stand under ``head_loss`` itself
+    "head_loss": ("exit",),
 }
 
 _SUB_SCOPE_OF = {sub: kind for kind, subs in reversed(SUB_SCOPES.items())

@@ -899,6 +899,52 @@ class TestTrainerStep:
         mem = compiled.memory_analysis()
         assert 7.3e9 < mem.argument_size_in_bytes < 7.4e9
 
+    def test_ouro_cell_whole_fits_the_chip(self, topo, as_if_on_tpu,
+                                           monkeypatch):
+        """The Ouro cell's own step, B1 S16384 and all 8 layers run 4 times
+        (the compile takes a quarter of a minute): the described chip's
+        compiler takes it (it refuses what does not fit: 12 layers, or the
+        four heads' logits whole), with room: under 10.5 GiB of temporaries
+        beside 4.56 of state.  What the forward loop leaves the backward
+        one is every layer application's input, 2 GiB, and no ``[16384,
+        vocab]`` array stands in the step: the head is worked by blocks of
+        4096 rows.  The FA2 kernels by scope: a forward call in the forward
+        loop, one in the rematerialised pass and ONE backward call."""
+        from benchmarks.common import HERE, load_module, read_json
+        from dlrover_tpu.observability import trace
+
+        notes = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: notes.append((name, attrs)))
+        config = read_json(HERE, "configs", "ouro2b6_l8.json")
+        family = load_module("families", "ouro")
+        S = config["run"]["seq"]
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(
+            mesh, lambda: (family.build(config, False, S), (1, S)))
+        mem = compiled.memory_analysis()
+        # 612.4 M parameters at 8 bytes of state
+        assert 4.89e9 < mem.argument_size_in_bytes < 4.91e9
+        assert mem.temp_size_in_bytes < 10.5 * 2 ** 30
+        text = compiled.as_text()
+        assert f"bf16[4,8,1,{S},2048]" in text       # the kept layer inputs
+        assert not re.search(rf"\[(\d,)*{S},49152\]", text)
+        assert re.search(r"f32\[(\d,)*4096,49152\]", text)
+        found = trace.parse_device_scopes(text)
+        kernels = sorted(found.scopes["%" + name]
+                         for name in _kernel_names(text))
+        assert kernels == [("attn.core", "", "backward"),
+                           ("attn.core", "", "forward"),
+                           ("attn.core", "", "remat")]
+        # the new scopes stand in the table: nothing of theirs is ``other``
+        kinds = {(kind, sub) for kind, sub, _ in found.scopes.values()}
+        assert ("head_loss", "exit") in kinds and ("norm", "") in kinds
+        (path,) = {tuple(sorted(attrs.items())) for name, attrs in notes
+                   if name == "attention.path"}
+        assert dict(path)["backward"] == "one_call"
+        assert (dict(path)["seq"], dict(path)["heads"]) == (S, 16)
+
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
         widths and share (32 query heads on 4 key heads of 128, 16 of 128

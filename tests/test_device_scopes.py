@@ -88,6 +88,11 @@ FAMILIES = {
         COMMON | {"moe", "mlp"},
         {"attn.core": {"window"},
          "moe": {"route", "sort", "gmm", "combine", "shared"}}),
+    "ouro_loop": (
+        lambda: LlamaForCausalLM(LlamaConfig.tiny(
+            loop_steps=4, sandwich_norm=True, exit_gate=True,
+            exit_entropy_weight=0.05)),
+        COMMON | {"mlp"}, {"head_loss": {"exit"}}),
 }
 
 #: a path may be ``other`` where it names nothing but the layer stack and
@@ -99,7 +104,9 @@ STACK = {"layers", "layer", "h", "block", "jit(wrapped)", "LlamaForCausalLM",
          # a pattern's runs (``ling_latent``)
          "prefix", "kda_dense_0", "kda_0", "mla_1",
          # (``laguna_window``)
-         "gqa_dense_0", "swa_0", "gqa_1"}
+         "gqa_dense_0", "swa_0", "gqa_1",
+         # a looped stack's scan over loop steps (``ouro_loop``)
+         "LlamaForCausalLM.loop_step", "LlamaForCausalLM._looped_stack"}
 
 def _step_text(model, steps=0):
     mesh = build_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
