@@ -14,10 +14,15 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
   name their results (``ops/pallas/kept.py``: the mask-operand attention's
   ``out`` and its LSE as ``[B, H, Q]`` float32, the gated delta rule's
   chunk and state results), those, and of a routed feed-forward the two
-  products of its first grouped matmuls (``models/moe.py``); the backward
-  pass recomputes everything else ``jax.numpy`` computes in the layer
-  (norms, projections, RoPE, masks, searches, the experts' sort, gathers
-  and activation) and runs no forward kernel whose results were kept.
+  products of its first grouped matmuls (``models/moe.py``), and of the
+  dense SwiGLU its gate and up products where those of all layer
+  applications fit 1/24 of the device's memory (``kept.py``'s
+  ``keeps_mlp_products``, read from the rows a chip holds, the width, the
+  layers and the device; elsewhere the dense layer names nothing); the
+  backward pass recomputes everything else ``jax.numpy`` computes in the
+  layer (norms, projections, RoPE, masks, searches, the experts' sort,
+  gathers and activation) and runs no forward kernel whose results were
+  kept.
   The FA2 kernel names its ``out`` and LSE over a long stream of keys
   (``ops/pallas/flash_attention.py::backward_path``: at least 16,384, no
   window, a head a block) and nothing over a shorter one, and every
@@ -45,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dlrover_tpu.observability import trace
+from dlrover_tpu.ops.pallas import kept
 from dlrover_tpu.ops.pallas.kept import LAYER_POLICY
 
 Dtype = Any
@@ -1389,8 +1395,32 @@ class GatedMemoryUnit(nn.Module):
         return 2 * cfg.hidden_size * _ssm_inner(cfg)
 
 
+def _chip_rows(x):
+    """``(the rows of ``x`` ``[B, S, E]`` a chip holds after the mesh's
+    sharding of ``batch`` and ``seq``, a device of this process)``: the
+    active mesh's, and without one the whole of ``x`` on the default
+    device."""
+    from dlrover_tpu.ops.ring_attention import active_mesh
+    from dlrover_tpu.parallel.sharding import ways_split
+
+    rows = x.shape[0] * x.shape[1]
+    mesh = active_mesh()
+    if mesh is None:
+        return rows, jax.local_devices()[0]
+    # inside a ``shard_map`` over the mesh ``x`` is a chip's share already
+    if not jax.sharding.get_abstract_mesh().manual_axes:
+        rows //= ways_split(mesh, ("batch", "seq"),
+                            list(nn.get_logical_axis_rules()) or None)
+    return rows, mesh.local_devices[0]
+
+
 class MLP(nn.Module):
     config: LlamaConfig
+    #: a decoder layer's own feed-forward, whose gate and up products the
+    #: layer's rematerialisation keeps where they fit (``kept.py``'s
+    #: ``MLP_PRODUCTS``); not a shared expert beside routed ones, whose
+    #: layer keeps the routed products
+    keeps_products: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -1415,6 +1445,15 @@ class MLP(nn.Module):
             ),
             name="up_proj",
         )(x)
+        if self.keeps_products and cfg.remat:
+            rows, device = _chip_rows(x)
+            if kept.keeps_mlp_products(
+                    cfg.num_layers * cfg.loop_steps, rows,
+                    cfg.intermediate_size, cfg.dtype,
+                    kept.device_bytes(device)):
+                gate, up = kept.named(kept.MLP_PRODUCTS, gate, up)
+                kept.note("mlp", **{kept.MLP_PRODUCTS: kept.mlp_products_bytes(
+                    rows, cfg.intermediate_size, cfg.dtype)})
         h = nn.silu(gate) * up
         h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
         return dense(

@@ -703,6 +703,7 @@ class MoEMLP(nn.Module):
                     mixed = mixed + MLP(
                         dataclasses.replace(
                             cfg, intermediate_size=cfg.shared_width()),
+                        keeps_products=False,
                         name="shared_expert")(x).astype(mixed.dtype)
         return nn.with_logical_constraint(mixed, ("batch", "seq", "embed"))
 

@@ -7,6 +7,7 @@ XLA inserts the collectives (psum/all-gather/reduce-scatter over ICI) from
 the annotations — nothing here issues a collective by hand.
 """
 
+import math
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 MeshAxis = Union[str, Tuple[str, ...], None]
@@ -99,6 +100,19 @@ def spec_on_mesh(
             tuple(a for a in names if mesh.shape.get(a, 1) > 1) or None
         )
     return PartitionSpec(*out)
+
+
+def ways_split(
+    mesh,
+    logical_axes: Sequence[Optional[str]],
+    rules: Optional[Sequence[Tuple[str, MeshAxis]]] = None,
+) -> int:
+    """Over how many chips of ``mesh`` an array of these logical axes is
+    split: a chip holds that share of its elements."""
+    return math.prod(
+        mesh.shape[a] for axes in spec_on_mesh(mesh, logical_axes, rules)
+        if axes for a in (axes if isinstance(axes, tuple) else (axes,))
+    )
 
 
 def shard_batch(mesh, batch, data_axes: Tuple[str, ...] = DATA_AXES):

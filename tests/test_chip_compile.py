@@ -879,7 +879,12 @@ class TestTrainerStep:
         assert (band["tiles_live"], band["tiles_walked"]) == (1, 1)
         kept = {attrs["core"]: attrs for name, attrs in notes
                 if name == "remat.kept"}
-        assert set(kept) == {"ssm", "diff"}
+        assert set(kept) == {"ssm", "diff", "mlp"}
+        # at an eighth of the cell's rows the eight SwiGLUs' gate and up
+        # products, 640 MiB, fit 1/24 of the described chip's 15.75 GiB
+        # (``kept.keeps_mlp_products``; the cell's own 5 GiB do not)
+        assert kept["mlp"]["names"] == "mlp_products"
+        assert kept["mlp"]["bytes_per_layer"] == 2 * S * 10240 * 2
         # y in bfloat16 and the starts of 32 chunks in float32
         assert kept["ssm"]["bytes_per_layer"] == (
             S * 5120 * 2 + (S // 64) * 5120 * 16 * 4)

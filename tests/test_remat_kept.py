@@ -19,11 +19,20 @@ The expert layer (``models/moe.py``) names the two products of a pass's
 first grouped matmuls the same way, ``jax.numpy`` and all, and its router's
 logits, choice and count of rows: the same stack with one chip's share of
 an expert layer in each, once for each way the router chooses.
+
+The dense SwiGLU (``models/llama.py::MLP``) names its gate and up products
+where ``kept.keeps_mlp_products`` says that all layer applications' fit a
+share of the device's memory: the same three readings of the stack on a
+device of a v5e's memory, no matmul of the pair in the second pass at the
+Mistral cell's own shapes, and the rule alone over the shapes of the
+benchmark's other Llama-path cells, each of which it refuses.
 """
 
 import collections
 import functools
+import json
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +51,10 @@ from shared_memo import shared_memo
 
 LAYERS, HEADS, DIM, HIDDEN = 2, 2, 128, 64
 F32 = jnp.dtype("float32")
+#: what a v5e's runtime gives its programs, for ``kept.device_bytes`` where
+#: a test stands for the chip (the CPU's says nothing: 0, and every dense
+#: feed-forward of this file's other stacks names nothing)
+V5E_BYTES = kept.DESCRIBED_DEVICE_BYTES["TPU v5 lite"]
 
 
 def _interpreted(module, name):
@@ -96,6 +109,11 @@ KERNEL_CORES = {
         (ops, "flash_attention"), {"_flash_fwd_kernel": 1},
         {(1, 256, HEADS * DIM): 1, (1, HEADS, 256): 1},
         ((fa, "ONE_CALL_MIN_KEYS", 256),)),
+    # no kernel: the reference core and the dense SwiGLU of 128 over 64
+    # rows, on a device that says what a v5e does: gate and up
+    "mlp_products_taken": Core(
+        _config(max_seq_len=64), "gqa", 64, None, {}, {(1, 64, 128): 2},
+        ((kept, "device_bytes", lambda device: V5E_BYTES),)),
 }
 #: cores that name nothing: the FA2 kernel where the rule leaves it (128
 #: keys are under ``ONE_CALL_MIN_KEYS``, as shipped and as brought down
@@ -445,3 +463,89 @@ def test_on_a_tie_the_second_pass_weights_the_experts_the_first_chose(
     other, runs = gradients(again, flips_after=LAYERS)
     assert runs == LAYERS * [False] + LAYERS * [True]
     assert any(float(jnp.abs(a - b).max()) > 0 for a, b in zip(other, want))
+
+
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks", "configs")
+
+
+def _cell_asks(config):
+    """What ``keeps_mlp_products`` reads of a benchmark configuration's
+    cell, from its file alone: layer applications, the rows a chip holds
+    (a block-diffusion step's are twice these: further from fitting), the
+    dense feed-forward's width."""
+    with open(os.path.join(CONFIGS, config + ".json")) as f:
+        m = json.load(f)
+    run = m["run"]
+    return (m["num_hidden_layers"] * m.get("total_ut_steps", 1),
+            run["batch"] * run["seq"] // math.prod(run["mesh"].values()),
+            m["intermediate_size"])
+
+
+def test_mlp_products_taken_at_the_mistral_cells_shapes(monkeypatch):
+    """Two layers of Mistral-7B's widths over 4,096 rows on a v5e: the
+    rule takes the pair with a quarter to spare, 224 MiB a layer; the
+    forward pass keeps it and the gradient's jaxpr holds the gate and the
+    up matmul once a layer, where on a device of half the memory, which
+    the rule refuses, it holds them twice.  Shapes alone: nothing is
+    computed."""
+    asks = _cell_asks("mistral7b_l2")
+    assert asks == (2, 4096, 14336)
+    assert kept.keeps_mlp_products(*asks, jnp.bfloat16, V5E_BYTES / 1.25)
+    assert kept.mlp_products_bytes(4096, 14336, jnp.bfloat16) == 224 * 2 ** 20
+    cfg = LlamaConfig(
+        vocab_size=256, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=2048)
+    model = llama.LlamaForCausalLM(cfg)
+    ids = jnp.zeros((2, 2048), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
+
+    def loss(params):
+        return model.apply(params, ids).astype(jnp.float32).sum()
+
+    def products_made(of_device):
+        """(matmuls onto ``[B, S, I]`` in the gradient's jaxpr, a scan's
+        body once: gate and up wherever they are made, and the pull-back
+        of the down projection; the stacked arrays of that shape the
+        forward pass keeps; the layer's ``remat.kept`` records)"""
+        records = []
+        monkeypatch.setattr(kept, "device_bytes", lambda device: of_device)
+        monkeypatch.setattr(
+            kept.trace, "note_trace_time",
+            lambda name, **attrs: records.append(attrs))
+
+        def count(jaxpr):
+            return sum(
+                (eqn.primitive.name == "dot_general"
+                 and eqn.outvars[0].aval.shape == (2, 2048, 14336))
+                + sum(map(count, jax.core.jaxprs_in_params(eqn.params)))
+                for eqn in jaxpr.eqns)
+
+        made = count(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        held = [aval for aval, _ in saved_residuals(loss, params)
+                if aval.shape == (2, 2, 2048, 14336)]
+        return made, held, [r for r in records if r.get("core") == "mlp"]
+
+    made, held, notes = products_made(V5E_BYTES)
+    assert made == 2 + 1 and len(held) == 2
+    assert all(aval.dtype == jnp.bfloat16 for aval in held)
+    assert notes and all(note == {
+        "core": "mlp", "names": "mlp_products",
+        "bytes_per_layer": 224 * 2 ** 20,
+        "mlp_products_bytes": 224 * 2 ** 20} for note in notes)
+    assert products_made(V5E_BYTES // 2) == (2 + 2 + 1, [], [])
+
+
+@pytest.mark.parametrize("config", [
+    "keyevl2_30b_1of8", "evabyte_l4", "solaropen2_250b_1of32",
+    "sdar_30b_1of8", "ling3flashvl_125b_1of32", "laguna_xs2_33b_1of8",
+    "kanana2_30b_1of8", "phi4miniflash_l8", "ouro2b6_l8"])
+def test_mlp_products_refused(config):
+    """The benchmark's other Llama-path cells, whose steps fill the chip:
+    every one asks over half as much again as the share gives."""
+    applications, rows, width = _cell_asks(config)
+    assert not kept.keeps_mlp_products(
+        applications, rows, width, jnp.bfloat16, 1.5 * V5E_BYTES)
+    # and a device that does not say how much it has keeps nothing
+    assert not kept.keeps_mlp_products(1, 8, 8, jnp.bfloat16, 0)
