@@ -56,6 +56,10 @@ from dlrover_tpu.ops.pallas.kept import LAYER_POLICY
 Dtype = Any
 
 
+#: ``mlp_activation`` -> the function
+ACTIVATIONS = {"silu": nn.silu, "relu2": lambda t: jnp.square(nn.relu(t))}
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -111,7 +115,11 @@ class LlamaConfig:
     # attention, and the parameter tree ``layers/layer`` as ever.  An entry
     # ``"<kind>:dense"`` is a layer of that kind whose feed-forward is the
     # dense SwiGLU of ``dense_intermediate_size`` whatever
-    # ``feed_forward()`` names (a routed model's leading dense layers)
+    # ``feed_forward()`` names (a routed model's leading dense layers).  A
+    # layer of ONE branch (Nemotron-H's: one norm, ``x + branch(norm(x))``):
+    # ``"<kind>:alone"``, the mixer with no feed-forward, and ``"ffn"``
+    # (``"ffn:dense"``), the feed-forward with no mixer; its tree holds
+    # ``input_norm`` and the one branch, nothing else
     layer_pattern: Tuple[str, ...] = ()
     # layers that stand ONCE before the periods (entries as the pattern's),
     # counted in ``num_layers``; their parameters under ``prefix/<run>``
@@ -232,6 +240,31 @@ class LlamaConfig:
     mamba_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
+    # a ``mamba2`` layer (Mamba-2's mixer, arXiv:2405.21060 as Nemotron-H's
+    # model code has it): ``mamba2_heads`` heads of ``mamba2_head_dim``
+    # (inner ``H P``), ``mamba2_groups`` groups that share a ``B`` and a
+    # ``C`` of ``mamba2_state`` columns; ``[z | u | dt] = h W_in`` (inner,
+    # inner + 2 G n, H); ``u = silu(conv(u) + b)``, causal and depthwise
+    # over ``mamba_conv`` taps, over ``x``, ``B`` and ``C`` together; ``d =
+    # softplus(dt + dt_bias)``; ``A = -exp(A_log)``, one number a head; the
+    # scan in chunks of ``mamba2_chunk`` (``ops/ssd.py``) gives ``y``; ``out
+    # = GroupRMSNorm(y * silu(z)) W_out``, the norm over each group's ``H P
+    # / G`` channels under one learned scale.  ``mamba2_heads`` 0: no such
+    # layer
+    mamba2_heads: int = 0
+    mamba2_head_dim: int = 64
+    mamba2_groups: int = 1
+    mamba2_state: int = 128
+    mamba2_chunk: int = 128
+    # layers that stand ONCE after the periods (entries as the pattern's),
+    # counted in ``num_layers``; their parameters under ``suffix/<run>``
+    layer_suffix: Tuple[str, ...] = ()
+    # the dense feed-forward (``MLP``; a routed model's experts and shared
+    # expert read the same two): 3 matrices, ``(act(h W_gate) * h W_up)
+    # W_down``, or 2, ``act(h W_up) W_down``; ``mlp_activation`` ``"silu"``
+    # or ``"relu2"`` (``relu(.)^2``, Nemotron-H's ``mlp_hidden_act``)
+    mlp_matrices: int = 3
+    mlp_activation: str = "silu"
     # a decoder-hybrid-decoder stack (SambaY, arXiv:2507.06607; YOCO,
     # arXiv:2405.05254): after the periods of ``layer_pattern`` the layers
     # of ``memory_layers`` stand ONCE (a ``mamba`` layer and a softmax layer,
@@ -288,29 +321,42 @@ class LlamaConfig:
             raise ValueError(
                 "eva_window needs an eva_chunk that divides it, a key head "
                 "a query head, and no indexer")
-        entries = (self.layer_prefix + self.layer_pattern
+        entries = (self.layer_prefix + self.layer_pattern + self.layer_suffix
                    + self.memory_layers + self.cross_pattern)
         kinds = {layer_kind(entry)[0] for entry in entries}
         if entries and (
                 not self.layer_pattern
                 or kinds - set(LAYER_KINDS)
-                or any(entry.partition(":")[2] not in ("", "dense")
-                       for entry in entries)
+                or any(entry.partition(":")[2] not in ("", "dense", "alone")
+                       or entry == "ffn:alone" for entry in entries)
                 or self._body_layers() < 1
                 or self._body_layers() % len(self.layer_pattern)
                 or ("kda" in kinds and not self.kda_heads)
                 or ("mla" in kinds and not self.mla_kv_rank)
                 or ("swa" in kinds and self.sliding_window < 1)
+                or ("mamba2" in kinds and (
+                    self.mamba2_heads < 1
+                    or self.mamba2_heads % self.mamba2_groups))
+                or (self.layer_suffix and self.hybrid)
                 or (any(layer_kind(entry)[1] for entry in entries)
                     and not self.dense_intermediate_size)):
             raise ValueError(
                 f"layer_prefix={self.layer_prefix!r} layer_pattern="
-                f"{self.layer_pattern!r}: entries '<kind>' or '<kind>:dense',"
-                f" kinds of {LAYER_KINDS}, a whole number of periods in "
-                f"num_layers={self.num_layers} less the prefix, kda_heads "
+                f"{self.layer_pattern!r} layer_suffix={self.layer_suffix!r}: "
+                "entries '<kind>', '<kind>:dense', '<kind>:alone', 'ffn' or "
+                f"'ffn:dense', kinds of {LAYER_KINDS}, a whole number of "
+                f"periods in num_layers={self.num_layers} less the prefix "
+                "and the suffix, kda_heads "
                 "where there is a kda layer, mla_kv_rank where there is an "
-                "mla layer, sliding_window where there is a swa layer and "
+                "mla layer, sliding_window where there is a swa layer, "
+                "mamba2_heads a multiple of mamba2_groups where there is a "
+                "mamba2 layer, no suffix on a hybrid stack and "
                 "dense_intermediate_size where one is dense")
+        if (self.mlp_matrices not in (2, 3)
+                or self.mlp_activation not in ACTIVATIONS):
+            raise ValueError(
+                f"mlp_matrices={self.mlp_matrices!r} is 2 or 3 and "
+                f"mlp_activation={self.mlp_activation!r} 'silu' or 'relu2'")
         reads = {"gmu", "xattn"}
         own = {layer_kind(e)[0] for e in
                self.layer_prefix + self.layer_pattern + self.memory_layers}
@@ -428,7 +474,7 @@ class LlamaConfig:
     def _body_layers(self) -> int:
         """The layers the periods of ``layer_pattern`` make up."""
         return (self.num_layers - len(self.layer_prefix)
-                - len(self.memory_layers)
+                - len(self.layer_suffix) - len(self.memory_layers)
                 - self.cross_periods * len(self.cross_pattern))
 
     @property
@@ -445,6 +491,7 @@ class LlamaConfig:
     def layer_kinds(self):
         """The entry of every layer of a patterned stack, in order."""
         return (self.layer_prefix + self.layer_pattern * self.periods
+                + self.layer_suffix
                 + self.memory_layers + self.cross_pattern * self.cross_periods)
 
     def feed_forward(self):
@@ -454,7 +501,7 @@ class LlamaConfig:
         return MLP
 
     def feed_forward_params(self) -> int:
-        return 3 * self.hidden_size * self.intermediate_size
+        return self.mlp_matrices * self.hidden_size * self.intermediate_size
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
@@ -480,8 +527,9 @@ class LlamaConfig:
 
 
 #: the kinds a ``layer_pattern`` may name (``gmu`` and ``xattn``: a
-#: ``cross_pattern`` alone)
-LAYER_KINDS = ("gqa", "kda", "mla", "swa", "mamba", "gmu", "xattn")
+#: ``cross_pattern`` alone; ``ffn``: a feed-forward with no mixer)
+LAYER_KINDS = ("gqa", "kda", "mla", "swa", "mamba", "gmu", "xattn", "mamba2",
+               "ffn")
 
 
 def hybrid_layout(num_layers: int, mb_per_layer: int = 2) -> dict:
@@ -536,10 +584,18 @@ class AttentionNumbers(NamedTuple):
 
 
 def layer_kind(entry: str):
-    """``(kind, dense)`` of a pattern's entry ``"<kind>"`` or
-    ``"<kind>:dense"``."""
+    """``(kind, dense)`` of a pattern's entry ``"<kind>"``,
+    ``"<kind>:dense"`` or ``"<kind>:alone"``."""
     kind, _, ffn = entry.partition(":")
     return kind, ffn == "dense"
+
+
+def layer_branches(entry: str):
+    """``(mixer, feed_forward)`` of a pattern's entry: the kind of module
+    at ``attn`` or ``None`` (``"ffn"``: no mixer), and whether one stands at
+    ``mlp`` (``"<kind>:alone"``: none)."""
+    kind, _, ffn = entry.partition(":")
+    return None if kind == "ffn" else kind, ffn != "alone"
 
 
 def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
@@ -1261,6 +1317,26 @@ def _projection(cfg, features, name, axes, **kw):
                nn.initializers.lecun_normal(), axes), **kw})
 
 
+def _tap_init(taps):
+    """A depthwise Conv1d's default, weight and bias alike: uniform in
+    ``+-taps^-1/2``."""
+    def init(key, shape, dtype):
+        return jax.random.uniform(key, shape, dtype, -1.0, 1.0) * (
+            taps ** -0.5)
+    return init
+
+
+def _tap_conv(t, weight, bias):
+    """SiLU of the causal depthwise convolution of ``t`` ``[B, S,
+    channels]`` in float32: ``weight`` ``[taps, channels]``, tap ``i``
+    weighs position ``t - (taps - 1) + i``, zeros before the start."""
+    taps, S = weight.shape[0], t.shape[1]
+    lead = jnp.pad(t, ((0, 0), (taps - 1, 0), (0, 0)))
+    return nn.silu(bias + sum(
+        lead[:, i: i + S].astype(jnp.float32) * weight[i]
+        for i in range(taps)))
+
+
 class MambaMixer(nn.Module):
     """Mamba-1's mixer (arXiv:2312.00752; the configuration's comment has
     the equations), in place of attention in a ``mamba`` layer.  The state,
@@ -1291,22 +1367,12 @@ class MambaMixer(nn.Module):
                     init, (None,) * len(lead) + ("mlp",)),
                 lead + (inner,), cfg.param_dtype).astype(jnp.float32)
 
-        def conv_init(key, shape, dtype):
-            # a depthwise Conv1d's default, weight and bias alike
-            return jax.random.uniform(key, shape, dtype, -1.0, 1.0) * (
-                taps ** -0.5)
-
         both = _projection(cfg, 2 * inner, "in_proj", ("embed", "mlp"))(x)
         a, z = both[..., :inner], both[..., inner:]
-        weight = channel_param("conv_weight", conv_init, taps)
-        bias = channel_param("conv_bias", conv_init)
+        weight = channel_param("conv_weight", _tap_init(taps), taps)
+        bias = channel_param("conv_bias", _tap_init(taps))
         with jax.named_scope("attn.core"), jax.named_scope("conv"):
-            # tap ``i`` weighs position ``t - (taps - 1) + i``, zeros before
-            # the start
-            lead = jnp.pad(a, ((0, 0), (taps - 1, 0), (0, 0)))
-            a = nn.silu(bias + sum(
-                lead[:, i: i + S].astype(jnp.float32) * weight[i]
-                for i in range(taps))).astype(cfg.dtype)
+            a = _tap_conv(a, weight, bias).astype(cfg.dtype)
         a = nn.with_logical_constraint(a, ("batch", "seq", "mlp"))
         steer = _projection(cfg, rank + 2 * N, "x_proj", ("mlp", None),
                             dtype=jnp.float32)(a)
@@ -1358,6 +1424,96 @@ class MambaMixer(nn.Module):
                 + inner * (rank + 2 * N)                # x
                 + rank * inner + inner                  # dt
                 + inner * N + inner                     # A_log, D
+                + inner * cfg.hidden_size)              # out
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2's mixer (arXiv:2405.21060; the configuration's comment has
+    the equations), in place of attention in a ``mamba2`` layer.  ONE input
+    projection to ``[z | x B C | dt]``; the convolution (``_tap_conv``,
+    ``MambaMixer``'s) over ``x``, ``B`` and ``C`` together; ``B`` and ``C``
+    a GROUP of heads, not a head; the scan's decay one number a head
+    (``ops/ssd.py``); the gate BEFORE the norm, which is RMS over each
+    group's channels.  ``dt``, ``A``, the decay's running sums, the state
+    between chunks and the norm are float32; the projections and the
+    scan's products run in the compute dtype with float32 accumulation.
+    Sub-scopes under ``attn.core``: ``conv``, ``decay`` (``d``, ``A`` and
+    the counter), ``ssd`` (``ops/ssd.py``), ``gate``."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask):
+        from dlrover_tpu.ops.ssd import ssd, ssd_core
+
+        cfg = self.config
+        H, P, G, N = (cfg.mamba2_heads, cfg.mamba2_head_dim,
+                      cfg.mamba2_groups, cfg.mamba2_state)
+        inner, taps = H * P, cfg.mamba_conv
+        wide = inner + 2 * G * N        # what the convolution runs over
+        B, S = x.shape[:2]
+
+        def param(name, init, shape, axes):
+            return self.param(
+                name, nn.with_logical_partitioning(init, axes), shape,
+                cfg.param_dtype).astype(jnp.float32)
+
+        all_three = _projection(cfg, inner + wide + H, "in_proj",
+                                ("embed", None))(x)
+        z, u, dt = (all_three[..., :inner], all_three[..., inner: inner + wide],
+                    all_three[..., inner + wide:])
+        weight = param("conv_weight", _tap_init(taps), (taps, wide),
+                       (None, "mlp"))
+        bias = param("conv_bias", _tap_init(taps), (wide,), ("mlp",))
+        rate = param("A_log", _kda_decay_init(1.0, 16.0), (H,), ("heads",))
+        dt_bias = param("dt_bias", _kda_dt_bias_init(), (H,), ("heads",))
+        skip = param("D", nn.initializers.ones, (H,), ("heads",))
+        with jax.named_scope("attn.core"):
+            with jax.named_scope("conv"):
+                u = _tap_conv(u, weight, bias).astype(cfg.dtype)
+            with jax.named_scope("decay"):
+                step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+                A = -jnp.exp(rate)
+                # whether the state carries: the median of ``exp(d A)`` over
+                # the heads at 1024 positions of the sequence (a sort of
+                # some ten thousand numbers)
+                sample = jax.lax.stop_gradient(
+                    step[:, :: max(S // 1024, 1)] * A)
+                self.sow("stats", "ssd_decay_p50",
+                         jnp.median(jnp.exp(sample)))
+            heads = nn.with_logical_constraint(
+                u[..., :inner].reshape(B, S, H, P),
+                ("batch", "seq", "heads", "head_dim"))
+            trace.note_trace_time(
+                "attention.path", impl="mamba2", seq=S, heads=H, head_dim=P,
+                groups=G, state=N, conv=taps, state_dtype="float32",
+                **ssd_core(S, cfg.mamba2_chunk))
+            y = ssd(heads, step, A,
+                    u[..., inner: inner + G * N].reshape(B, S, G, N),
+                    u[..., inner + G * N:].reshape(B, S, G, N), skip,
+                    cfg.mamba2_chunk)
+            with jax.named_scope("gate"):
+                # gate first, then RMS over each group's channels
+                gated = (y.reshape(B, S, inner)
+                         * nn.silu(z.astype(jnp.float32))).reshape(
+                             B, S, G, inner // G)
+                scale = param("norm_scale", nn.initializers.ones, (inner,),
+                              ("mlp",))
+                out = (gated * jax.lax.rsqrt(
+                    jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+                    + cfg.rms_norm_eps)).reshape(B, S, inner) * scale
+                out = out.astype(cfg.dtype)
+        out = nn.with_logical_constraint(out, ("batch", "seq", "mlp"))
+        return _projection(cfg, x.shape[-1], "out_proj", ("mlp", "embed"))(out)
+
+    @staticmethod
+    def num_params(cfg) -> int:
+        H, G, N = cfg.mamba2_heads, cfg.mamba2_groups, cfg.mamba2_state
+        inner = H * cfg.mamba2_head_dim
+        wide = inner + 2 * G * N
+        return (cfg.hidden_size * (inner + wide + H)    # in
+                + cfg.mamba_conv * wide + wide          # the convolution
+                + 3 * H + inner                         # A_log, dt_bias, D; norm
                 + inner * cfg.hidden_size)              # out
 
 
@@ -1431,6 +1587,12 @@ class MLP(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
         )
+        if cfg.mlp_matrices == 2:   # ``act(x W_up) W_down``: no gate
+            h = ACTIVATIONS[cfg.mlp_activation](_projection(
+                cfg, cfg.intermediate_size, "up_proj", ("embed", "mlp"))(x))
+            h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
+            return _projection(
+                cfg, x.shape[-1], "down_proj", ("mlp", "embed"))(h)
         gate = dense(
             features=cfg.intermediate_size,
             kernel_init=nn.with_logical_partitioning(
@@ -1454,7 +1616,7 @@ class MLP(nn.Module):
                 gate, up = kept.named(kept.MLP_PRODUCTS, gate, up)
                 kept.note("mlp", **{kept.MLP_PRODUCTS: kept.mlp_products_bytes(
                     rows, cfg.intermediate_size, cfg.dtype)})
-        h = nn.silu(gate) * up
+        h = ACTIVATIONS[cfg.mlp_activation](gate) * up
         h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
         return dense(
             features=x.shape[-1],
@@ -1469,7 +1631,8 @@ class MLP(nn.Module):
 ATTENTION_OF = {"gqa": Attention, "kda": DeltaAttention,
                 "mla": LatentAttention, "swa": partial(Attention, kind="swa"),
                 "mamba": MambaMixer, "gmu": GatedMemoryUnit,
-                "xattn": partial(Attention, kind="xattn")}
+                "xattn": partial(Attention, kind="xattn"),
+                "mamba2": Mamba2Mixer}
 #: the kinds whose module takes ``memory``, ``depth`` and ``keep``
 HYBRID_KINDS = ("gqa", "swa", "mamba", "gmu", "xattn")
 
@@ -1477,7 +1640,8 @@ HYBRID_KINDS = ("gqa", "swa", "mamba", "gmu", "xattn")
 class DecoderLayer(nn.Module):
     config: LlamaConfig
     #: a pattern's entry: of ``LAYER_KINDS``, which module stands at
-    #: ``attn``; with ``:dense``, the dense SwiGLU at ``mlp``
+    #: ``attn``; with ``:dense``, the dense feed-forward at ``mlp``; with
+    #: ``:alone`` no ``mlp``, and ``ffn`` no ``attn`` (``layer_branches``)
     kind: str = "gqa"
     #: a memory layer of a decoder-hybrid-decoder stack: the call returns
     #: ``(x, what its mixer hands on)``
@@ -1486,8 +1650,8 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions, mask, memory=None, depth=None):
         cfg = self.config
-        kind, dense = layer_kind(self.kind)
-        attention = ATTENTION_OF[kind]
+        _, dense = layer_kind(self.kind)
+        mixer, has_ffn = layer_branches(self.kind)
         feed_forward, ffn_cfg = cfg.feed_forward(), cfg
         if dense:
             feed_forward, ffn_cfg = MLP, dataclasses.replace(
@@ -1497,18 +1661,22 @@ class DecoderLayer(nn.Module):
         # configuration names one: a branch's result is added in it
         h = norm(name="input_norm")(x)
         handed = None
-        if cfg.hybrid and kind in HYBRID_KINDS:
-            mixed = attention(cfg, name="attn")(
-                h, positions, mask, memory, depth, self.keeps)
-            if self.keeps:
-                mixed, handed = mixed
-        else:
-            mixed = attention(cfg, name="attn")(h, positions, mask)
-        if cfg.sandwich_norm:
-            mixed = norm(name="attn_out_norm")(mixed)
-        x = x + mixed.astype(x.dtype)
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        h = norm(name="post_attn_norm")(x)
+        if mixer is not None:
+            attention = ATTENTION_OF[mixer]
+            if cfg.hybrid and mixer in HYBRID_KINDS:
+                mixed = attention(cfg, name="attn")(
+                    h, positions, mask, memory, depth, self.keeps)
+                if self.keeps:
+                    mixed, handed = mixed
+            else:
+                mixed = attention(cfg, name="attn")(h, positions, mask)
+            if cfg.sandwich_norm:
+                mixed = norm(name="attn_out_norm")(mixed)
+            x = x + mixed.astype(x.dtype)
+            x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+            if not has_ffn:     # a layer of one branch: the mixer alone
+                return (x, handed) if self.keeps else x
+            h = norm(name="post_attn_norm")(x)
         out = feed_forward(ffn_cfg, name="mlp")(h)
         if cfg.sandwich_norm:
             out = norm(name="mlp_out_norm")(out)
@@ -1798,6 +1966,9 @@ class LlamaForCausalLM(nn.Module):
             # (``scan_layers`` does not apply: a pattern is always stacked)
             x, _ = _stacked(_ScannedPeriod, cfg.periods, "periods")(
                 cfg, name="layers")(x, positions, mask)
+            if cfg.layer_suffix:    # once, after the periods
+                x, _ = _ScannedPeriod(cfg, cfg.layer_suffix, name="suffix")(
+                    x, positions, mask)
         elif cfg.scan_layers:
             x, _ = _stacked(_layer_class(cfg, True), cfg.num_layers)(
                 cfg, name="layers")(x, positions, mask)
@@ -2015,17 +2186,25 @@ class LlamaForCausalLM(nn.Module):
                    "kda": lambda: DeltaAttention.num_params(cfg),
                    "mla": lambda: LatentAttention.num_params(cfg),
                    "mamba": lambda: MambaMixer.num_params(cfg),
-                   "gmu": lambda: GatedMemoryUnit.num_params(cfg)}
+                   "gmu": lambda: GatedMemoryUnit.num_params(cfg),
+                   "mamba2": lambda: Mamba2Mixer.num_params(cfg)}
         # a norm's parameters: a scale, with ``norm`` "layer" a bias too
         norm = cfg.hidden_size * (2 if cfg.norm == "layer" else 1)
 
         def layers(entries):
             total = 0
-            for kind, dense in map(layer_kind, entries):
-                total += by_kind[kind]() + (
-                    4 if cfg.sandwich_norm else 2) * norm + (
-                    3 * cfg.hidden_size * cfg.dense_intermediate_size
-                    if dense else cfg.feed_forward_params())
+            for entry in entries:
+                dense = layer_kind(entry)[1]
+                mixer, has_ffn = layer_branches(entry)
+                # a branch: its norm, under ``sandwich_norm`` one more
+                branches = (mixer is not None) + has_ffn
+                total += (2 if cfg.sandwich_norm else 1) * branches * norm
+                if mixer is not None:
+                    total += by_kind[mixer]()
+                if has_ffn:
+                    total += (cfg.mlp_matrices * cfg.hidden_size
+                              * cfg.dense_intermediate_size
+                              if dense else cfg.feed_forward_params())
             return total
 
         entries = (cfg.layer_kinds() if cfg.layer_pattern

@@ -34,7 +34,9 @@ operands, SwiGLU, the weighted sum) run at an extent chosen from the
 routing.  ``ladder`` derives a few static extents from the shapes alone:
 1.25, 1.5 and 2 times the rows expected on a chip that holds ``n_local``
 of ``num_experts`` experts, and the worst case, every assignment on this
-chip's experts.  ``jax.lax.switch`` takes the smallest that holds the rows
+chip's experts (where a token takes more experts than the chip holds, one
+row a held expert and token: a token meets an expert once).
+``jax.lax.switch`` takes the smallest that holds the rows
 in use (``sizes.sum()``, a value on the device).  The last rung holds
 whatever the router does, so dropless still holds, and every rung is the
 same mathematics: ``tests/test_moe.py`` holds a forced routing in each rung
@@ -86,6 +88,19 @@ model too large for its chips): the stacked expert arrays hold
 ``num_experts`` exist, and the layer's result is these experts' part.
 Nothing stands in for the absent chips or their traffic.
 
+Experts of another form (``mlp_matrices`` 2, ``mlp_activation``
+``"relu2"``: ``relu(x W_up)^2 W_down``, no gate; Nemotron-H's) run through
+the same passes with ONE first product where SwiGLU has two (``_products``
+returns a tuple, ``_finish`` takes it): the kept products, the pull-back
+from them (four grouped matmuls where SwiGLU's runs six) and the ladder are
+the same code.  The shared expert is the dense ``MLP`` of the same form.
+With ``moe_latent_size`` the routed experts work in a latent (LatentMoE):
+``c = h W_down`` before the sort, the experts' matrices at the latent's
+width, ``r W_up`` after the weighted sum, both projections whole on every
+chip (scope ``moe/latent``), while the router and the shared expert read
+the full ``h``; ``W_up`` is linear, so a share's part through it still adds
+up with the other shares' to the whole layer's result.
+
 Each layer sows two loss terms into the ``losses`` collection, already
 weighted and divided by the number of layers (``Trainer``'s default loss
 adds whatever a model sows there): the load-balancing loss
@@ -111,7 +126,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import MLP, LlamaConfig
+from dlrover_tpu.models.llama import (
+    ACTIVATIONS, MLP, LlamaConfig, _projection)
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops.pallas import kept
 
@@ -154,6 +170,16 @@ class MoELlamaConfig(LlamaConfig):
     # load moves by ``bias_update_rate`` a step (the module's text)
     selection_bias: bool = False
     bias_update_rate: float = 0.001
+    # the routed experts work in a latent (Nemotron 3's LatentMoE): ``c = h
+    # W_down`` of this width before the sort, the experts' matrices at it,
+    # ``r W_up`` back to ``hidden_size`` after the weighted sum; both whole
+    # on every chip and linear, so a share's part (``experts_held``) through
+    # ``W_up`` adds up with the other shares'.  The router and the shared
+    # expert read the full ``h``.  0: the experts at ``hidden_size``.  The
+    # experts' and the shared expert's form is ``mlp_matrices`` and
+    # ``mlp_activation`` (``LlamaConfig``): three matrices under SiLU, or
+    # two under ``relu(.)^2``
+    moe_latent_size: int = 0
 
     def __post_init__(self):
         super().__post_init__()
@@ -186,9 +212,12 @@ class MoELlamaConfig(LlamaConfig):
 
     def feed_forward_params(self) -> int:
         held = self.experts_held or self.num_experts
-        return held * super().feed_forward_params() + (
-            self.hidden_size * self.num_experts
-        ) + 3 * self.hidden_size * self.shared_width()
+        latent = self.moe_latent_size
+        return (held * self.mlp_matrices * (latent or self.hidden_size)
+                * self.intermediate_size
+                + self.hidden_size * self.num_experts
+                + self.mlp_matrices * self.hidden_size * self.shared_width()
+                + 2 * self.hidden_size * latent)
 
     @classmethod
     def tiny_moe(cls, **kw) -> "MoELlamaConfig":
@@ -218,18 +247,23 @@ class MoELlamaConfig(LlamaConfig):
 _TILE = 128
 
 
-def ladder(rows, n_local, num_experts):
+def ladder(rows, n_local, num_experts, top_k=None):
     """The static extents a pass over ``rows`` sorted assignments may run
     at when ``n_local`` of ``num_experts`` experts are here: 1.25, 1.5 and
     2 times the rows expected under even routing, each rounded up to a
-    tile, then ``rows`` itself, which holds whatever the routing does.
-    Where every expert is local, or the buffer is a few tiles, ``rows``
-    alone."""
+    tile, then the worst case, which holds whatever the routing does:
+    ``rows`` itself, or, where a token's ``top_k`` assignments outnumber
+    the ``n_local`` experts here, ``n_local`` a token (a token meets an
+    expert once).  Where every expert is local, or the buffer is a few
+    tiles, the worst case alone."""
+    worst = rows
+    if top_k is not None and top_k > n_local:
+        worst = rows // top_k * n_local
     below = {
         -(-rows * n_local * num // (num_experts * den * _TILE)) * _TILE
         for num, den in ((5, 4), (3, 2), (2, 1))
     }
-    return tuple(sorted(e for e in below if e < rows)) + (rows,)
+    return tuple(sorted(e for e in below if e < worst)) + (worst,)
 
 
 def _combine_body(extent, slots):
@@ -388,41 +422,46 @@ def _down_and_sum_bwd(res, g):
 _down_and_sum.defvjp(_down_and_sum_fwd, _down_and_sum_bwd)
 
 
-def _products(extent, x, weights, order, inverse, sizes, gate_w, up_w):
+def _products(extent, x, weights, order, inverse, sizes, *in_w):
     """The first half of a pass over the first ``extent`` sorted rows,
     which hold every row of ``sizes``: the rows gathered, masked and
-    multiplied by their experts' ``gate_w`` and ``up_w``, ``[extent, I]``
-    each in the compute dtype.  What a backward pass keeps of the forward
-    (``kept.MOE_PRODUCTS``)."""
+    multiplied by their experts' first matrices ``in_w`` (``gate_w`` and
+    ``up_w``, or a two-matrix expert's ``up_w`` alone), a tuple of
+    ``[extent, I]`` in the compute dtype.  What a backward pass keeps of the
+    forward (``kept.MOE_PRODUCTS``)."""
     picked, slot, live = _sorted(extent, weights, order, inverse, sizes)
     rows = _rows_of(x, picked, slot, live)
     with jax.named_scope("gmm"):
         rows = jnp.where(live, rows, 0)
-        return _grouped(rows, gate_w, sizes), _grouped(rows, up_w, sizes)
+        return tuple(_grouped(rows, w, sizes) for w in in_w)
 
 
-def _finish(extent, gate, up, weights, order, inverse, sizes, down_w):
+def _finish(extent, activation, products, weights, order, inverse, sizes,
+            down_w):
     """The second half: the experts' weighted results [tokens, D] float32
-    from the two products.  ``silu(gate) * up`` is computed here, forward
-    and backward, and kept by nobody."""
+    from the products.  ``act(gate) * up`` (one product: ``act(up)``) is
+    computed here, forward and backward, and kept by nobody."""
     _, slot, live = _sorted(extent, weights, order, inverse, sizes)
     with jax.named_scope("gmm"):
-        hidden = nn.silu(gate) * up
+        hidden = ACTIVATIONS[activation](products[0])
+        if len(products) == 2:
+            hidden = hidden * products[1]
     return _down_and_sum(hidden, down_w, weights, order, slot, sizes, live)
 
 
-def _rung(extent, x, weights, order, inverse, sizes, gate_w, up_w, down_w):
+def _rung(extent, activation, x, weights, order, inverse, sizes, *expert_w):
     """The experts' weighted results [tokens, D] float32 from the first
     ``extent`` sorted rows, which hold every row of ``sizes``.  Only the
     index vectors ``order`` and ``inverse`` have the extent of all
-    assignments."""
+    assignments.  ``expert_w``: the first matrices, then ``down_w``."""
     index = (weights, order, inverse, sizes)
-    gate, up = _products(extent, x, *index, gate_w, up_w)
-    return _finish(extent, gate, up, *index, down_w)
+    products = _products(extent, x, *index, *expert_w[:-1])
+    return _finish(extent, activation, products, *index, expert_w[-1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _at_rung(extents, rung, x, weights, order, inverse, sizes, *expert_w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _at_rung(extents, activation, rung, x, weights, order, inverse, sizes,
+             *expert_w):
     """``_rung`` at ``extents[rung]``, ``rung`` a value on the device.
     ``jax.lax.switch`` differentiated as it stands pads every branch's
     residuals to the union of all branches, which would write the worst
@@ -435,20 +474,23 @@ def _at_rung(extents, rung, x, weights, order, inverse, sizes, *expert_w):
     and no forward one; on a higher rung through ``jax.vjp`` of the same
     rung, which runs gate and up again."""
     return jax.lax.switch(
-        rung, [functools.partial(_rung, extent) for extent in extents],
+        rung, [functools.partial(_rung, extent, activation)
+               for extent in extents],
         x, weights, order, inverse, sizes, *expert_w)
 
 
-def _at_rung_fwd(extents, rung, *args):
-    def first(x, weights, order, inverse, sizes, gate_w, up_w, down_w):
+def _at_rung_fwd(extents, activation, rung, *args):
+    def first(x, weights, order, inverse, sizes, *expert_w):
         index = (weights, order, inverse, sizes)
-        products = _products(extents[0], x, *index, gate_w, up_w)
-        return _finish(extents[0], *products, *index, down_w), products
+        products = _products(extents[0], x, *index, *expert_w[:-1])
+        return _finish(extents[0], activation, products, *index,
+                       expert_w[-1]), products
 
     def above(extent):
         def branch(x, *rest):
             nothing = jnp.zeros((extents[0], rest[-1].shape[1]), x.dtype)
-            return _rung(extent, x, *rest), (nothing, nothing)
+            return (_rung(extent, activation, x, *rest),
+                    (nothing,) * (len(rest) - 5))   # a product a first matrix
         return branch
 
     out, products = jax.lax.switch(
@@ -456,26 +498,27 @@ def _at_rung_fwd(extents, rung, *args):
     return out, (rung, args, kept.named(kept.MOE_PRODUCTS, *products))
 
 
-def _at_rung_bwd(extents, res, g):
+def _at_rung_bwd(extents, activation, res, g):
     rung, args, products = res
 
-    def from_products(x, weights, order, inverse, sizes, gate_w, up_w,
-                      down_w):
+    def from_products(x, weights, order, inverse, sizes, *expert_w):
         *d_products, d_weights, d_down = jax.vjp(
-            lambda gate, up, weights, down_w: _finish(
-                extents[0], gate, up, weights, order, inverse, sizes, down_w),
-            *products, weights, down_w)[1](g)
-        d_x, d_gate, d_up = jax.vjp(
-            lambda x, gate_w, up_w: _products(
-                extents[0], x, weights, order, inverse, sizes, gate_w, up_w),
-            x, gate_w, up_w)[1](tuple(d_products))
-        return d_x, d_weights, d_gate, d_up, d_down
+            lambda *at: _finish(
+                extents[0], activation, at[:-2], at[-2], order, inverse,
+                sizes, at[-1]),
+            *products, weights, expert_w[-1])[1](g)
+        d_x, *d_in = jax.vjp(
+            lambda x, *in_w: _products(
+                extents[0], x, weights, order, inverse, sizes, *in_w),
+            x, *expert_w[:-1])[1](tuple(d_products))
+        return (d_x, d_weights, *d_in, d_down)
 
     def from_inputs(extent):
         def branch(x, weights, order, inverse, sizes, *expert_w):
             return jax.vjp(
                 lambda x, weights, *expert_w: _rung(
-                    extent, x, weights, order, inverse, sizes, *expert_w),
+                    extent, activation, x, weights, order, inverse, sizes,
+                    *expert_w),
                 x, weights, *expert_w)[1](g)
         return branch
 
@@ -489,14 +532,16 @@ _at_rung.defvjp(_at_rung_fwd, _at_rung_bwd)
 
 
 def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert,
-                  num_experts=None):
-    """What the experts ``[first_expert, first_expert + len(gate_w))`` of
+                  num_experts=None, activation="silu"):
+    """What the experts ``[first_expert, first_expert + len(down_w))`` of
     ``num_experts`` (default: these are all) add to the layer's result
     for tokens ``x`` [T, D] routed by ``top_i`` and weighted by ``top_w``
     (both [T, k]): ``([T, D] float32, rows each of these experts
-    processed, rows the passes ran over)``."""
+    processed, rows the passes ran over)``.  ``gate_w`` ``None``: experts of
+    two matrices, ``activation(x up_w) down_w``."""
     tokens, k = top_i.shape
-    n_local = gate_w.shape[0]
+    n_local = down_w.shape[0]
+    expert_w = tuple(w for w in (gate_w, up_w, down_w) if w is not None)
     with jax.named_scope("sort"):
         local = top_i - first_expert
         mine = (local >= 0) & (local < n_local)
@@ -516,14 +561,14 @@ def local_experts(x, top_i, top_w, gate_w, up_w, down_w, first_expert,
     # kept row by the score of the expert the first pass gave it
     order, inverse, sizes = kept.named(
         kept.MOE_PRODUCTS, order, inverse, sizes)
-    extents = ladder(tokens * k, n_local, num_experts or n_local)
+    extents = ladder(tokens * k, n_local, num_experts or n_local, k)
     # the smallest extent that holds every row of ``sizes`` (a ladder of
     # one rung: that one, and ``jax.lax.switch`` over one branch is a call)
     rung = (sizes.sum() > jnp.asarray(extents[:-1], jnp.int32)).sum(
         dtype=jnp.int32)
     held = jnp.asarray(extents, jnp.float32)[rung]
-    out = _at_rung(extents, rung, x, weights, order, inverse, sizes,
-                   gate_w, up_w, down_w)
+    out = _at_rung(extents, activation, rung, x, weights, order, inverse,
+                   sizes, *expert_w)
     return out, sizes, held
 
 
@@ -611,7 +656,8 @@ def _move_bias(module, rows):
 
 
 class MoEMLP(nn.Module):
-    """Top-k routed SwiGLU experts, expert-sharded over ``ep``."""
+    """Top-k routed experts (SwiGLU, or by the configuration two matrices
+    under another activation, in a latent), expert-sharded over ``ep``."""
 
     config: MoELlamaConfig
 
@@ -661,15 +707,25 @@ class MoEMLP(nn.Module):
 
             F = cfg.intermediate_size
             here = cfg.experts_held or E
-            gate_w = expert_weight("gate_proj", (here, D, F),
-                                   ("expert", "embed", "mlp"))
-            up_w = expert_weight("up_proj", (here, D, F),
-                                 ("expert", "embed", "mlp"))
-            down_w = expert_weight("down_proj", (here, F, D),
-                                   ("expert", "mlp", "embed"))
+            wide = cfg.moe_latent_size or D     # what the experts work at
+            expert_w = tuple(
+                expert_weight(name, (here,) + shape, ("expert",) + axes)
+                for name, shape, axes in (
+                    ("gate_proj", (wide, F), ("embed", "mlp")),
+                    ("up_proj", (wide, F), ("embed", "mlp")),
+                    ("down_proj", (F, wide), ("mlp", "embed")))
+                if cfg.mlp_matrices == 3 or name != "gate_proj")
+            inside = x
+            if cfg.moe_latent_size:
+                with jax.named_scope("latent"):
+                    inside = _projection(cfg, wide, "latent_down",
+                                         ("embed", None))(x)
             mixed, rows, held, chip_rows = self._experts(
-                x.astype(cfg.dtype), top_i, top_w, gate_w, up_w, down_w
-            )
+                inside.astype(cfg.dtype), top_i, top_w, *expert_w)
+            if cfg.moe_latent_size:
+                with jax.named_scope("latent"):
+                    mixed = _projection(cfg, D, "latent_up",
+                                        (None, "embed"))(mixed)
             # the routing's loss terms and counts
             with jax.named_scope("route"):
                 live = B * S * k
@@ -707,7 +763,7 @@ class MoEMLP(nn.Module):
                         name="shared_expert")(x).astype(mixed.dtype)
         return nn.with_logical_constraint(mixed, ("batch", "seq", "embed"))
 
-    def _experts(self, x, top_i, top_w, gate_w, up_w, down_w):
+    def _experts(self, x, top_i, top_w, *expert_w):
         """``(the experts' weighted results [B, S, D], rows each of the E
         experts processed over the whole batch, rows the chips' passes ran
         over in all, live rows of each chip)``."""
@@ -715,6 +771,10 @@ class MoEMLP(nn.Module):
 
         cfg = self.config
         D, k = x.shape[-1], cfg.top_k
+        down_w = expert_w[-1]
+        # ``local_experts`` takes (gate_w, up_w, down_w), ``gate_w`` ``None``
+        # for experts of two matrices
+        absent = (None,) * (3 - len(expert_w))
         mesh = active_mesh()
         sharded = (
             mesh is not None and mesh.size > 1
@@ -755,17 +815,17 @@ class MoEMLP(nn.Module):
         # caller's name stack to its instructions (inside the loop over
         # ranks the compiled step's ``op_name`` starts anew)
         @jax.named_scope("moe")
-        def per_shard(x, top_i, top_w, gate_w, up_w, down_w):
+        def per_shard(x, top_i, top_w, *expert_w):
             tokens = (x.reshape(-1, D), top_i.reshape(-1, k),
                       top_w.reshape(-1, k))
             if ep == 1:
                 out, rows, held = local_experts(
-                    *tokens, gate_w, up_w, down_w, cfg.first_expert,
-                    cfg.num_experts)
+                    *tokens, *absent, *expert_w, cfg.first_expert,
+                    cfg.num_experts, cfg.mlp_activation)
                 out = out.astype(cfg.dtype)
                 live = rows.sum()
             else:
-                first = jax.lax.axis_index("ep") * gate_w.shape[0]
+                first = jax.lax.axis_index("ep") * expert_w[-1].shape[0]
 
                 # one rank's tokens at a time: only one buffer of sorted
                 # rows, of masks and of ``silu(gate) * up`` is alive, at the
@@ -778,8 +838,8 @@ class MoEMLP(nn.Module):
                 @jax.named_scope("moe")
                 def one_rank(its_tokens):
                     out, rows, held = local_experts(
-                        *its_tokens, gate_w, up_w, down_w, first,
-                        cfg.num_experts)
+                        *its_tokens, *absent, *expert_w, first,
+                        cfg.num_experts, cfg.mlp_activation)
                     return out.astype(cfg.dtype), rows, held
 
                 with exchange:
@@ -809,36 +869,41 @@ class MoEMLP(nn.Module):
 
             per_shard = shard_map_unchecked(
                 per_shard, mesh=mesh,
-                in_specs=(x_spec, x_spec, x_spec, w_spec, w_spec, w_spec),
+                in_specs=(x_spec,) * 3 + (w_spec,) * len(expert_w),
                 out_specs=(x_spec,) + 3 * (PartitionSpec(),),
             )
         rows = x.shape[0] * x.shape[1] * k
         # a source rank's assignments on one chip, and of one pass
         chip = rows // math.prod(mesh.shape[a] for a in chips)
-        extents = ladder(chip, gate_w.shape[0] // ep, cfg.num_experts)
+        extents = ladder(chip, down_w.shape[0] // ep, cfg.num_experts, k)
         trace.note_trace_time(
             "moe.path", impl="ragged_dot", experts=cfg.num_experts,
             top_k=k, ep=ep, tokens=x.shape[0] * x.shape[1], rows=rows,
             layers=cfg.num_layers, extents=extents,
-            held=gate_w.shape[0], first_expert=cfg.first_expert,
+            held=down_w.shape[0], first_expert=cfg.first_expert,
             # grouped matmuls in the pull-back of a pass at the first
-            # extent, from the products the forward pass kept
-            backward=6, kept=f"{kept.MOE_PRODUCTS},{kept.MOE_ROUTE}",
+            # extent, from the products the forward pass kept (two a matrix)
+            backward=2 * len(expert_w),
+            kept=f"{kept.MOE_PRODUCTS},{kept.MOE_ROUTE}",
             # how the passes at each extent sum by token
             combine=",".join(_combine_body(e, chip) for e in extents),
             # the dense SwiGLU every token visits beside the routed experts
             shared_experts=cfg.shared_experts, shared_width=cfg.shared_width(),
+            # experts of two matrices, or in a latent: said where it is so
+            **({"matrices": len(expert_w), "activation": cfg.mlp_activation}
+               if len(expert_w) != 3 else {}),
+            **({"latent": cfg.moe_latent_size} if cfg.moe_latent_size else {}),
         )
         # a source rank's two products and the sort they are in; this
         # chip's tokens' logits and choice, and a share's count of rows
         kept.note("moe", **{
             kept.MOE_PRODUCTS: ep * (
-                2 * kept.nbytes((extents[0], cfg.intermediate_size),
-                                cfg.dtype)
-                + kept.nbytes((2 * extents[-1] + gate_w.shape[0] // ep,),
+                (len(expert_w) - 1) * kept.nbytes(
+                    (extents[0], cfg.intermediate_size), cfg.dtype)
+                + kept.nbytes((2 * chip + down_w.shape[0] // ep,),
                               jnp.int32)),
             kept.MOE_ROUTE: (
                 kept.nbytes((chip // k, cfg.num_experts), jnp.float32)
                 + kept.nbytes((chip + bool(cfg.experts_held)
                                * cfg.num_experts,), jnp.int32))})
-        return per_shard(x, top_i, top_w, gate_w, up_w, down_w)
+        return per_shard(x, top_i, top_w, *expert_w)

@@ -560,7 +560,13 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
                   # its cross-decoder (``_MemoryLayers``, the module
                   # ``memory``: the cast of ``Y``; backward the memory's
                   # gradient summed over its readers)
-                  "scan", "diff", "gmu", "handed"),
+                  "scan", "diff", "gmu", "handed",
+                  # a Mamba-2 layer (``models/llama.py::Mamba2Mixer``:
+                  # ``conv``, ``decay`` and ``gate`` as above, the gate with
+                  # its group norm): the chunked scan of ``ops/ssd.py``, the
+                  # scores, the decay mask, the two products and the state's
+                  # carry between chunks
+                  "ssd"),
     # what latent attention adds around its core (``models/llama.py::
     # LatentAttention``): the latent's projections, its norm, the rotary
     # part.  The one name two kinds have: a kind each, and a path that
@@ -568,7 +574,11 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
     "attn.proj": ("latent",),
     # the sampling of block diffusion's noise (``models/llama.py``)
     "embed": ("noise",),
-    "moe": ("route", "sort", "gmm", "exchange", "combine", "shared"),
+    # ``latent``: the two projections around experts that work in a latent
+    # (``models/moe.py``, ``moe_latent_size``); the name is ``attn.core``'s
+    # where a path starts anew at it
+    "moe": ("route", "sort", "gmm", "exchange", "combine", "shared",
+            "latent"),
     # a router's selection bias moved by the load (``models/moe.py``)
     "optimizer": ("bias",),
     # a looped stack's exits (``models/llama.py::_looped_stack``): the gate

@@ -970,6 +970,79 @@ class TestTrainerStep:
         # ``out`` 64 MiB and the LSE 1 MiB a layer application
         assert dict(held)["bytes_per_layer"] == 67108864 + 1048576
 
+    def test_nemotron3super_widths_one_layer_of_each_kind(
+            self, topo, as_if_on_tpu, monkeypatch):
+        """The Nemotron-3-Super cell's configuration file through its family
+        at B1 S2048 and three of its eleven layers, ``EM*`` (a quarter of the
+        cell's length, one layer of each kind, for the test's time; the
+        cell's own is ``benchmarks/tests/compile_described.py
+        nemotron3super_120b_1of32``: 6.86 GiB of arguments, 9.27 of
+        temporaries at 8192 x 11 layers; 16,384 is refused, 1.57 GiB over):
+        the described chip's compiler takes the chunked scan as
+        ``jax.numpy`` wrote it (no kernel asked for), every instruction of
+        the mixer's core under ``conv``, ``decay``, ``ssd`` or ``gate``, the
+        latent's two projections under ``moe`` / ``latent``; the attention
+        layer alone runs Pallas kernels, the FA2 split triple at 4 query
+        heads on 1; nothing has a chunk's mask at the sequence's extent
+        squared; the ladder's worst case is 16 rows a token, not 22."""
+        from benchmarks.common import HERE, load_module, read_json
+        from dlrover_tpu.observability import trace
+
+        notes = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: notes.append((name, attrs)))
+        config = {**read_json(HERE, "configs",
+                              "nemotron3super_120b_1of32.json"),
+                  "num_hidden_layers": 3, "hybrid_override_pattern": "EM*"}
+        family = load_module("families", "nemotronh")
+        S = 2048
+
+        def cell():
+            return family.build(config, False, S), (1, S)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh, cell)
+        text = compiled.as_text()
+        assert not re.search(rf"\[(\d+,)*{S},{S}\]", text)
+        found = trace.parse_device_scopes(text)
+        kernels = sorted(found.scopes["%" + name]
+                         for name in _kernel_names(text))
+        # (the grouped matmuls are custom calls too, under ``moe/gmm``)
+        assert {scope[:2] for scope in kernels} == {
+            ("attn.core", ""), ("moe", "gmm")}
+        assert {scope[2] for scope in kernels
+                if scope[0] == "attn.core"} >= {"forward", "backward"}
+        kinds = {(kind, sub) for kind, sub, _ in found.scopes.values()}
+        assert {("attn.core", "conv"), ("attn.core", "decay"),
+                ("attn.core", "ssd"), ("attn.core", "gate"),
+                ("moe", "latent"), ("moe", "route"), ("moe", "gmm"),
+                ("moe", "shared"), ("optimizer", "bias")} <= kinds
+        # a chunk's mask a head: [chunks, heads, 128, 128], float32
+        assert re.search(r"f32\[(\d+,)*16,16,128,128\]|"
+                         r"f32\[(\d+,)*16,1,16,128,128\]", text)
+        paths = {attrs["impl"]: attrs for name, attrs in notes
+                 if name == "attention.path"}
+        assert paths["mamba2"]["core"] == "jnp"
+        assert (paths["mamba2"]["heads"], paths["mamba2"]["groups"],
+                paths["mamba2"]["state"], paths["mamba2"]["chunk"],
+                paths["mamba2"]["chunks"]) == (16, 1, 128, 128, 16)
+        assert paths["mamba2"]["state_dtype"] == "float32"
+        assert (paths["flash"]["heads"], paths["flash"]["rope"],
+                paths["flash"]["backward"]) == (4, "none", "split")
+        (moe,) = {tuple(sorted(attrs.items())) for name, attrs in notes
+                  if name == "moe.path"}
+        moe = dict(moe)
+        assert (moe["experts"], moe["top_k"], moe["held"]) == (512, 22, 16)
+        assert (moe["matrices"], moe["activation"], moe["latent"]) == (
+            2, "relu2", 1024)
+        assert moe["backward"] == 4 and moe["shared_width"] == 5376
+        # 22 x 16 / 512 of 2048 x 22 rows expected; the worst case 16 a token
+        assert moe["extents"] == (1792, 2176, 2816, 16 * S)
+        mem = compiled.memory_analysis()
+        # (142.6 M + 13.7 M + 5.2 M + 134.2 M) x 8 bytes of state
+        assert 2.36e9 < mem.argument_size_in_bytes < 2.37e9
+
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
         widths and share (32 query heads on 4 key heads of 128, 16 of 128

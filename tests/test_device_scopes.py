@@ -93,6 +93,21 @@ FAMILIES = {
             loop_steps=4, sandwich_norm=True, exit_gate=True,
             exit_entropy_weight=0.05)),
         COMMON | {"mlp"}, {"head_loss": {"exit"}}),
+    "nemotron_one_branch": (
+        lambda: LlamaForCausalLM(MoELlamaConfig.tiny_moe(
+            num_layers=5, layer_pattern=("ffn", "mamba2:alone"),
+            layer_suffix=("gqa:alone",), use_rope=False, num_kv_heads=1,
+            mamba2_heads=4, mamba2_head_dim=8, mamba2_groups=2,
+            mamba2_state=16, mamba2_chunk=8, num_experts=8, top_k=3,
+            experts_held=4, intermediate_size=24, moe_latent_size=16,
+            mlp_matrices=2, mlp_activation="relu2", shared_experts=1,
+            shared_intermediate_size=48, norm_topk_prob=True,
+            router_scores="sigmoid", routed_scaling_factor=5.0,
+            selection_bias=True, max_seq_len=SEQ)),
+        COMMON | {"moe"},
+        {"attn.core": {"conv", "decay", "ssd", "gate"},
+         "optimizer": {"bias"},
+         "moe": {"route", "sort", "gmm", "combine", "shared", "latent"}}),
 }
 
 #: a path may be ``other`` where it names nothing but the layer stack and
@@ -106,7 +121,9 @@ STACK = {"layers", "layer", "h", "block", "jit(wrapped)", "LlamaForCausalLM",
          # (``laguna_window``)
          "gqa_dense_0", "swa_0", "gqa_1",
          # a looped stack's scan over loop steps (``ouro_loop``)
-         "LlamaForCausalLM.loop_step", "LlamaForCausalLM._looped_stack"}
+         "LlamaForCausalLM.loop_step", "LlamaForCausalLM._looped_stack",
+         # layers of one branch and the suffix (``nemotron_one_branch``)
+         "suffix", "ffn_0", "mamba2_alone_1", "gqa_alone_0"}
 
 def _step_text(model, steps=0):
     mesh = build_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])

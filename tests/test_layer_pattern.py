@@ -266,9 +266,13 @@ def test_patterned_state_through_a_memory_save_and_restore(tmp_path):
 #: where it gathered them; and again at PR 50 (e58e89a0a600250d until then):
 #: the two sums by token are told which rows hold an assignment
 #: (``moe._rows_of`` carries ``live``), at these shapes they gather the
-#: slots as they did
+#: slots as they did; and again at PR 64 (b522af41a96bbbe9 until then, and
+#: still with ``moe.ladder``'s new rule switched off): this model has 3
+#: experts a token and HOLDS 2, so the ladder's worst case is 2 rows a token
+#: and no longer 3 (extents (128, 192) where they were (128, 192, 288)); no
+#: cell of the benchmark holds fewer experts than a token takes
 BEFORE = {"empty": "483fc5aaffc3fc24", "solar": "def5410a2c443246",
-          "solar_routed": "b522af41a96bbbe9"}
+          "solar_routed": "55d34056c602b346"}
 
 
 def _before_and_now(which):
@@ -386,3 +390,97 @@ def test_dataclass_fields_keep_their_defaults():
     assert defaults["kda_full_rank_gates"] is False
     assert defaults["kda_decay_lower_bound"] == 0.0
     assert defaults["kda_neg_eigval"] is True
+    # what PR 64 added keeps the older programs as they were
+    assert defaults["layer_suffix"] == () and defaults["mamba2_heads"] == 0
+    assert defaults["mlp_matrices"] == 3
+    assert defaults["mlp_activation"] == "silu"
+    moe = {f.name: f.default for f in dataclasses.fields(MoELlamaConfig)}
+    assert moe["moe_latent_size"] == 0
+
+
+def _one_branch(**changes):
+    fields = dict(
+        num_layers=7, layer_pattern=("ffn:dense", "mamba2:alone"),
+        layer_prefix=("gqa:alone",), layer_suffix=("kda:alone", "ffn:dense"),
+        dense_intermediate_size=96, mamba2_heads=4, mamba2_head_dim=8,
+        mamba2_groups=2, mamba2_state=16, mamba2_chunk=16, kda_heads=2,
+        kda_head_dim=16, kda_chunk=16, dtype=jnp.float32)
+    fields.update(changes)
+    return LlamaConfig.tiny(**fields)
+
+
+def test_a_one_branch_entrys_tree_has_no_dead_leaf():
+    """A mixer with no feed-forward and a feed-forward with no mixer: one
+    norm and one branch a layer, in the prefix, the periods and the suffix;
+    ``num_params()`` counts what the tree holds."""
+    cfg = _one_branch()
+    assert cfg.periods == 2
+    assert cfg.layer_runs(cfg.layer_suffix) == [
+        ("kda_alone_0", "kda:alone", 1), ("ffn_dense_1", "ffn:dense", 1)]
+    assert cfg.layer_kinds() == (
+        ("gqa:alone",) + ("ffn:dense", "mamba2:alone") * 2
+        + ("kda:alone", "ffn:dense"))
+    model = LlamaForCausalLM(cfg)
+    shapes = nn.meta.unbox(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), _ids())["params"])
+    assert set(shapes) == {"embed_tokens", "prefix", "layers", "suffix",
+                           "final_norm", "lm_head"}
+    for part, run, branch in (
+            ("prefix", "gqa_alone_0", "attn"),
+            ("layers", "ffn_dense_0", "mlp"),
+            ("layers", "mamba2_alone_1", "attn"),
+            ("suffix", "kda_alone_0", "attn"),
+            ("suffix", "ffn_dense_1", "mlp")):
+        assert set(shapes[part][run]["layer"]) == {"input_norm", branch}
+    dense = shapes["layers"]["ffn_dense_0"]["layer"]["mlp"]
+    assert dense["gate_proj"]["kernel"].shape == (2, 1, 64, 96)
+    assert model.num_params() == sum(
+        int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(shapes))
+
+
+def test_one_branch_layers_are_the_same_layers_unrolled():
+    """The stack with a prefix, periods and a suffix of one-branch layers
+    equals ``x + branch(norm(x))`` layer after layer."""
+    cfg = _one_branch()
+    model = LlamaForCausalLM(cfg)
+    ids = _ids()
+    params = perturbed(init_params(model, ids), seed=9)
+    S = ids.shape[1]
+    positions = jnp.broadcast_to(jnp.arange(S), ids.shape)
+    mask = jnp.tril(jnp.ones((S, S), bool))[None, None]
+
+    def unrolled(params):
+        x = params["embed_tokens"][ids]
+        at = lambda tree, *i: jax.tree.map(lambda t: t[i], tree)  # noqa: E731
+        layers = [("gqa:alone", at(params["prefix"]["gqa_alone_0"], 0))]
+        for period in range(2):
+            layers += [
+                ("ffn:dense", at(params["layers"]["ffn_dense_0"], period, 0)),
+                ("mamba2:alone",
+                 at(params["layers"]["mamba2_alone_1"], period, 0))]
+        layers += [("kda:alone", at(params["suffix"]["kda_alone_0"], 0)),
+                   ("ffn:dense", at(params["suffix"]["ffn_dense_1"], 0))]
+        for entry, p in layers:
+            x = DecoderLayer(cfg, entry).apply(
+                {"params": p["layer"]}, x, positions, mask)
+        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype).apply(
+            {"params": params["final_norm"]}, x)
+        return x @ params["lm_head"]["kernel"]
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(unrolled)(params)
+        got = jax.jit(model.apply)({"params": params}, ids)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+
+
+@pytest.mark.parametrize("changes,match", [
+    ({"layer_suffix": ("ffn:alone",)}, "entries"),
+    ({"layer_suffix": ("mamba2",), "mamba2_heads": 0, "num_layers": 6},
+     "mamba2_heads"),
+    ({"num_layers": 8}, "and the suffix"),
+    ({"mlp_matrices": 4}, "mlp_matrices"),
+])
+def test_what_the_config_refuses_of_one_branch_layers(changes, match):
+    with pytest.raises(ValueError, match=match):
+        _one_branch(**changes)
+

@@ -604,7 +604,8 @@ class TestTheBackwardTakesTheProductsFromTheForward:
         got = run()
         monkeypatch.setattr(
             moe, "_at_rung",
-            lambda extents, rung, *args: _plain_rung(extents[-1], *args))
+            lambda extents, activation, rung, *args: _plain_rung(
+                extents[-1], *args))
         want = run()
         got = jax.tree_util.tree_leaves_with_path(got)
         want = jax.tree.leaves(want)
@@ -654,9 +655,10 @@ class TestExtentsInTheLoweredStep:
                 state.params, trainer.shard_batch(batch)).jaxpr
 
     def test_small_rungs_hold_no_buffer_of_the_worst_case(self):
-        """``ep=4``, 576 assignments a source rank: extents of 256, 384
-        and 576 rows, one ``switch`` in the forward pass and one in the
-        backward pass.  Inside the two small rungs the rows and the
+        """``ep=4``, 576 assignments a source rank at 3 a token, 2 experts
+        a rank: extents of 256 and 384 rows (the worst case is 2 a token: a
+        token meets an expert once), one ``switch`` in the forward pass and
+        one in the backward pass.  Inside the two small rungs the rows and the
         experts' hidden rows have the rung's extent; what has the extent
         of all assignments is an index or weight vector ([rows] or
         [tokens, k]) or the gathered operand of a sum by token ([tokens,
@@ -664,8 +666,9 @@ class TestExtentsInTheLoweredStep:
         cfg, jaxpr = self._step_jaxpr(MeshConfig(dp=2, ep=4))
         tokens, k = self.TOKENS, cfg.top_k
         rows, hidden, width = tokens * k, cfg.hidden_size, cfg.intermediate_size
-        extents = ladder(rows, 2, cfg.num_experts)
-        assert extents == (256, 384, 576)
+        extents = ladder(rows, 2, cfg.num_experts, k)
+        assert extents == (256, 384)
+        assert ladder(rows, 2, cfg.num_experts) == (256, 384, 576)
         switches = [e for e in _conds(jaxpr)
                     if len(e.params["branches"]) == len(extents)]
         assert len(switches) == 2
@@ -679,7 +682,7 @@ class TestExtentsInTheLoweredStep:
                 whole = {s for s in shapes
                          if rows in s or s[:2] == (tokens, k)}
                 assert whole <= allowed, whole - allowed
-            assert {(rows, hidden), (rows, width)} <= top
+            assert {(extents[-1], hidden), (extents[-1], width)} <= top
 
     def test_one_rank_of_experts_has_no_conditional(self):
         """``ep=1``: every assignment is local, the rows in use are the
