@@ -30,9 +30,11 @@ torch+Megatron; here the model is in-tree and mesh-native).  Design notes:
 * a looped stack (``loop_steps``) is one more ``nn.scan`` AROUND the scan
   over layers, its parameters broadcast: a weight is in the tree once and
   used once a loop step, what the forward pass keeps is every layer
-  application's input (and what its core names), and the heads of its
-  exits are worked by blocks of rows (``token_losses``) so that no
-  ``[B, S, vocab]`` array stands in a training step;
+  application's input (and what its core names); a loop step hands out its
+  normed stream and its gate's logits, and the heads of its exits run
+  AFTER the loop, by blocks of rows (``weighted_token_losses``: no ``[B, S,
+  vocab]`` array stands in a training step) and with their gradient taken
+  in their forward pass, so that a block's logits are made once;
 * attention is GQA with rotary embeddings; the inner kernel is pluggable
   (jnp reference path here, Pallas flash/ring attention in
   ``dlrover_tpu.ops``).
@@ -1840,39 +1842,123 @@ class LMHead(nn.Module):
         )
 
 
-#: the most float32 logits ``token_losses`` holds at once
+#: the most float32 logits ``weighted_token_losses`` holds at once
 HEAD_BLOCK_BYTES = 2 ** 30
 
 
-def token_losses(cfg, x, kernel, targets):
-    """``-log softmax(x kernel)[targets]``, ``[B, S]`` float32, without
-    ``[B, S, vocab]`` ever standing whole: the head (``LMHead.project``) and
-    its cross entropy a block of rows at a time, each block rematerialised,
-    so that the forward pass keeps ``x`` and the backward pass makes a
-    block's logits again, takes their gradient and lets them go.  The block
-    is the largest power-of-two share of ``S`` whose float32 logits stay
-    under ``HEAD_BLOCK_BYTES`` (4096 rows of 16,384 at a vocabulary of
-    49,152; a small model's rows all at once), from the shapes alone."""
-    B, S, E = x.shape
-    rows = S
-    while rows % 2 == 0 and 4 * B * rows * kernel.shape[-1] > HEAD_BLOCK_BYTES:
+def head_block_rows(batch, seq, vocab):
+    """The rows of a sequence ``weighted_token_losses`` works at once: the
+    largest power-of-two share of ``seq`` whose float32 logits stay under
+    ``HEAD_BLOCK_BYTES`` (4096 rows of 16,384 at a vocabulary of 49,152; a
+    small model's rows all at once), from the shapes alone."""
+    rows = seq
+    while rows % 2 == 0 and 4 * batch * rows * vocab > HEAD_BLOCK_BYTES:
         rows //= 2
+    return rows
 
-    @jax.checkpoint
-    def block(first):
-        # sliced from the whole inside: what is kept is ``x`` as it stands,
-        # not a second copy of it in blocks
-        logits = LMHead.project(
-            cfg, jax.lax.dynamic_slice_in_dim(x, first, rows, 1), kernel)
-        taken = jnp.take_along_axis(
-            logits, jax.lax.dynamic_slice_in_dim(
-                targets, first, rows, 1)[..., None], axis=-1)[..., 0]
-        return jax.nn.logsumexp(logits, axis=-1) - taken
 
-    if rows == S:
-        return block(0)
-    losses = jax.lax.map(block, jnp.arange(0, S, rows))
-    return jnp.moveaxis(losses, 0, 1).reshape(B, S)
+def _walk_head(cfg, x, kernel, targets, weights, transposed, with_grads):
+    """``weighted_token_losses``' one walk over every exit's blocks of rows:
+    ``((L, CE [T, B, S]), (gx, gK))``, the two gradients of ``L = sum
+    weights * CE`` made in the same visit of a block while its logits stand
+    (``with_grads``; else ``None`` both, and none of their products)."""
+    T, B, S, E = x.shape
+    vocab_axis = 0 if transposed else 1
+    vocab = kernel.shape[vocab_axis]
+    rows = head_block_rows(B, S, vocab)
+
+    def block(grad_kernel, at):
+        exit_, first = at
+
+        # sliced from the whole inside: no second copy of it in blocks
+        x_block = jax.lax.dynamic_slice(
+            x, (exit_, 0, first, 0), (1, B, rows, E))[0]
+        target = jax.lax.dynamic_slice_in_dim(targets, first, rows, 1)
+        logits = LMHead.project(cfg, x_block, kernel, transposed)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        losses = lse - jnp.take_along_axis(
+            logits, target[..., None], axis=-1)[..., 0]
+        if not with_grads:
+            return grad_kernel, (losses, None)
+        # float32 beside operands in the compute dtype, as the transpose of
+        # ``LMHead.project`` hands its two products their cotangent
+        d = jax.lax.dynamic_slice(
+            weights, (exit_, 0, first), (1, B, rows))[0][..., None] * (
+            jnp.exp(logits - lse[..., None])
+            - jax.nn.one_hot(target, vocab, dtype=jnp.float32))
+        grad_x = jax.lax.dot_general(
+            d, kernel, (((2,), (vocab_axis,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(x.dtype)
+        # [hidden, vocab], a tied table's [vocab, hidden]
+        ahead, behind = (d, x_block) if transposed else (x_block, d)
+        return grad_kernel + jax.lax.dot_general(
+            ahead, behind, (((0, 1), (0, 1)), ((), ())),
+            preferred_element_type=jnp.float32), (losses, grad_x)
+
+    blocks = S // rows
+    grad_kernel, (losses, grad_x) = jax.lax.scan(
+        block, jnp.zeros(kernel.shape, jnp.float32) if with_grads else None,
+        (jnp.repeat(jnp.arange(T), blocks),
+         jnp.tile(jnp.arange(0, S, rows), T)))
+
+    def whole(by_block):    # [T * blocks, B, rows, ..] -> [T, B, S, ..]
+        rest = by_block.shape[3:]
+        return jnp.moveaxis(
+            by_block.reshape((T, blocks, B, rows) + rest), 1, 2).reshape(
+                (T, B, S) + rest)
+
+    losses = whole(losses)
+    return (jnp.sum(weights * losses), losses), (
+        whole(grad_x) if with_grads else None, grad_kernel)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 5))
+def _weighted_head(cfg, x, kernel, targets, weights, transposed):
+    """``weighted_token_losses`` of operands in the compute dtype; called
+    without differentiation, the walk for ``L`` and ``CE`` alone."""
+    return _walk_head(cfg, x, kernel, targets, weights, transposed, False)[0]
+
+
+def _weighted_head_fwd(cfg, x, kernel, targets, weights, transposed):
+    out, grads = _walk_head(
+        cfg, x, kernel, targets, weights, transposed, True)
+    return out, grads + (out[1],)
+
+
+def _weighted_head_bwd(cfg, transposed, kept, cotangents):
+    grad_x, grad_kernel, losses = kept
+    g, _ = cotangents       # a scalar; ``CE``'s cotangent is not read
+    return ((g * grad_x).astype(cfg.dtype),
+            (g * grad_kernel).astype(cfg.dtype), None, g * losses)
+
+
+_weighted_head.defvjp(_weighted_head_fwd, _weighted_head_bwd)
+
+
+def weighted_token_losses(cfg, x, kernel, targets, weights, transposed=False):
+    """``(L, CE)`` of a looped stack's exits: ``CE = -log softmax(x
+    kernel)[targets]``, ``[T, B, S]`` float32, of every exit's normed
+    stream ``x [T, B, S, E]`` under the one head (``LMHead.project``;
+    ``transposed``: a tied table ``[vocab, hidden]``), and ``L = sum weights
+    * CE``, a float32 scalar, ``weights [T, B, S]`` float32.  ``[B, S,
+    vocab]`` never stands whole: the head and its cross entropy run a block
+    of ``head_block_rows`` rows at a time, every exit's blocks in one walk.
+
+    ``L`` is what is differentiated, and its gradient is taken in the
+    FORWARD pass: a block's ``d = weights * (softmax - onehot)`` and its two
+    products ``d kernel^T`` (``x``'s gradient, in the compute dtype) and
+    ``x^T d`` (the kernel's, summed over blocks and exits in float32 and
+    rounded to the compute dtype once) are made while the block's logits
+    stand, so no block's logits are made a second time and what is kept for
+    the backward pass is those two gradients and ``CE`` (the weights'
+    gradient); the backward rule multiplies the three by ``L``'s scalar
+    cotangent.  ``CE`` is for reading only: the rule gives it NO gradient,
+    and a caller takes it through ``stop_gradient``.  A call that is not
+    differentiated walks the same blocks for ``L`` and ``CE`` and makes
+    neither product."""
+    return _weighted_head(
+        cfg, x.astype(cfg.dtype), kernel.astype(cfg.dtype), targets, weights,
+        transposed)
 
 
 class ExitGate(nn.Module):
@@ -2043,15 +2129,14 @@ class LlamaForCausalLM(nn.Module):
         rematerialised as ever: the forward pass keeps ``loop_steps x
         num_layers`` layer inputs.
 
-        With ``exit_gate`` a loop step also reads the gate and the head's
-        token losses (``token_losses``: by blocks of rows, so what survives
-        a loop step is ``[B, S]`` losses and gate logits, never logits over
-        the vocabulary), and ``_sow_exit_objective`` sows the objective."""
+        With ``exit_gate`` a loop step also reads the gate and hands out
+        (the scan's ``ys``) its normed stream and its gate's logits, ``[B, S,
+        E]`` in the compute dtype and ``[B, S]``; the heads run AFTER the
+        loop, all exits' in one walk (``_sow_exit_objective``), so that the
+        forward pass ends on them and the backward pass starts from their
+        gradients, made already."""
         cfg = self.config
         kernel = LMHead(cfg, name="lm_head")(None)
-        # position i's target is token i + 1; the last has none (weight 0)
-        with jax.named_scope("head_loss"):
-            targets = jnp.roll(input_ids, -1, axis=1)
 
         def loop_step(model, x, _):
             x, _ = _stacked(_layer_class(cfg, True), cfg.num_layers)(
@@ -2064,51 +2149,65 @@ class LlamaForCausalLM(nn.Module):
                 prevent_cse=False)(model, x)
             if not cfg.exit_gate:
                 return x, None
-            with jax.named_scope("head_loss"):
-                losses = token_losses(cfg, x, kernel, targets)
-                with jax.named_scope("exit"):
-                    gate = ExitGate(cfg, name="exit_gate")(x)
-            return x, (losses, gate)
+            with jax.named_scope("head_loss"), jax.named_scope("exit"):
+                return x, (x, ExitGate(cfg, name="exit_gate")(x))
 
         x, exits = nn.scan(
             loop_step, variable_broadcast="params",
             split_rngs={"params": False}, length=cfg.loop_steps)(
                 self, x, None)
         if cfg.exit_gate:
-            with jax.named_scope("head_loss"), jax.named_scope("exit"):
-                self._sow_exit_objective(*exits)
+            with jax.named_scope("head_loss"):
+                self._sow_exit_objective(*exits, kernel, input_ids)
         with jax.named_scope("lm_head"):
             logits = LMHead.project(cfg, x, kernel)
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
 
-    def _sow_exit_objective(self, losses, gate_logits):
-        """The model's objective into ``losses`` from every exit's token
-        losses ``CE_t`` and gate logits, ``[T, B, S]`` each: ``mean_i [sum_t
-        p_t,i CE_t,i - beta H(p_.,i)]`` over the positions that have a
-        target (all but a sequence's last), ``H`` the exit distribution's
-        entropy in nats; and into ``stats`` its mean entropy, the mean mass
-        of the last exit and each exit's mean cross entropy.  A caller that
-        asks for the collection ``exits`` is handed both arrays token by
-        token (``token_losses`` and ``log_p``: the comparison with the
-        reference reads them; nothing in training does)."""
+    def _sow_exit_objective(self, streams, gate_logits, kernel, input_ids):
+        """The model's objective into ``losses`` from every exit's normed
+        stream ``[T, B, S, E]`` and gate logits ``[T, B, S]``: ``mean_i
+        [sum_t p_t,i CE_t,i - beta H(p_.,i)]`` over the positions that have
+        a target (all but a sequence's last), ``H`` the exit distribution's
+        entropy in nats.  The weight of a token's loss at an exit, ``p_t,i /
+        N`` (0 where there is no target), is known once the gates are read,
+        so the four heads are ONE call of ``weighted_token_losses``, whose
+        gradient is taken as its forward pass walks the blocks; the gates'
+        gradient reaches them through the weights and through the entropy.
+        Into ``stats`` the mean entropy, the mean mass of the last exit and
+        each exit's mean cross entropy.  A caller that asks for the
+        collection ``exits`` is handed ``CE`` and ``log p`` token by token
+        (``token_losses`` and ``log_p``: the comparison with the reference
+        reads them; nothing in training does).  ``CE`` out of the weighted
+        head carries no gradient: it is read here, never differentiated."""
         cfg = self.config
-        log_p = exit_distribution(gate_logits)
-        self.sow("exits", "token_losses", losses)
-        self.sow("exits", "log_p", log_p)
-        p = jnp.exp(log_p)
-        entropy = -jnp.sum(p * log_p, axis=0)
-        has_target = jnp.arange(losses.shape[-1]) < losses.shape[-1] - 1
+        T, B, S, _ = streams.shape
+        has_target = jnp.arange(S) < S - 1
 
         def mean(per_token):     # over [.., B, S]'s last two axes
             return jnp.sum(jnp.where(has_target, per_token, 0.0),
-                           axis=(-2, -1)) / (
-                per_token.shape[-2] * (per_token.shape[-1] - 1))
+                           axis=(-2, -1)) / (B * (S - 1))
 
-        self.sow("losses", "exit_objective", mean(
-            jnp.sum(p * losses, axis=0) - cfg.exit_entropy_weight * entropy))
-        self.sow("stats", "loop_exit_entropy", mean(entropy))
-        self.sow("stats", "loop_exit_mass_last", mean(p[-1]))
-        self.sow("stats", "loop_ce_by_step", mean(losses))
+        with jax.named_scope("exit"):
+            log_p = exit_distribution(gate_logits)
+            p = jnp.exp(log_p)
+            weights = jnp.where(has_target, p, 0.0) / (B * (S - 1))
+        # position i's target is token i + 1; the last has none (weight 0)
+        targets = jnp.roll(input_ids, -1, axis=1)
+        rows = head_block_rows(B, S, kernel.shape[-1])
+        trace.note_trace_time("head.path", exits=T, rows=rows,
+                              blocks=T * S // rows, grad="forward")
+        expected, losses = weighted_token_losses(
+            cfg, streams, kernel, targets, weights)
+        losses = jax.lax.stop_gradient(losses)
+        with jax.named_scope("exit"):
+            entropy = -jnp.sum(p * log_p, axis=0)
+            self.sow("exits", "token_losses", losses)
+            self.sow("exits", "log_p", log_p)
+            self.sow("losses", "exit_objective",
+                     expected - cfg.exit_entropy_weight * mean(entropy))
+            self.sow("stats", "loop_exit_entropy", mean(entropy))
+            self.sow("stats", "loop_exit_mass_last", mean(p[-1]))
+            self.sow("stats", "loop_ce_by_step", mean(losses))
 
     def _noisy_and_clean(self, input_ids):
         """``([noisy copy ; clean copy] [B, 2S], the NELBO's weights [B,

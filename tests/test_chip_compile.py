@@ -915,13 +915,17 @@ class TestTrainerStep:
         (``flash_attention.py::backward_path``), its ``out``, 2 GiB more,
         and its LSE as ``[B, H, S]`` float32, 32 MiB; no ``[16384,
         vocab]`` array stands in the step: the head is worked by blocks of
-        4096 rows.  The FA2 kernels by scope: a forward call in the forward
+        4096 rows, each block ONCE (``weighted_token_losses`` takes the
+        gradient while a block's logits stand: nothing ``[4096, vocab]``
+        under ``rematted_computation``, and the ``head.path`` record says
+        so).  The FA2 kernels by scope: a forward call in the forward
         loop and ONE backward call, none in the rematerialised pass.
-        ``memory_analysis()`` reads 14.31 GiB of temporaries beside 4.56 of
-        state (9.57 before ``out`` was kept: that sum is no guide to what
-        fits, 18.9 of 15.75 GiB here; the compiler's own assignment holds
-        state and temporaries in 14.62 GiB, 11.76 before: PERF.md section
-        6, PR 62)."""
+        ``memory_analysis()`` reads 14.62 GiB of temporaries beside 4.56 of
+        state (14.31 with the heads inside the loop, 9.57 before ``out``
+        was kept: that sum is no guide to what fits, 19.2 of 15.75 GiB
+        here; the compiler's own assignment holds state and temporaries in
+        14.68 GiB, 14.62 and 11.76 before: PERF.md section 6, PR 62 and PR
+        65)."""
         from benchmarks.common import HERE, load_module, read_json
         from dlrover_tpu.observability import trace
 
@@ -951,6 +955,14 @@ class TestTrainerStep:
         assert f"f32[4,8,1,16,{S},128]" not in text
         assert not re.search(rf"\[(\d,)*{S},49152\]", text)
         assert re.search(r"f32\[(\d,)*4096,49152\]", text)
+        # the head's products are counted: no block's logits a second time
+        assert not [line for line in text.splitlines()
+                    if "rematted_computation" in line
+                    and re.search(r" = f32\[(\d+,)*4096,49152\]", line)]
+        (head,) = {tuple(sorted(attrs.items())) for name, attrs in notes
+                   if name == "head.path"}
+        assert dict(head) == {"exits": 4, "rows": 4096, "blocks": 16,
+                              "grad": "forward"}
         found = trace.parse_device_scopes(text)
         kernels = sorted(found.scopes["%" + name]
                          for name in _kernel_names(text))

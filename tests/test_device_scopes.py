@@ -158,6 +158,9 @@ class TestKindsOfACompiledStep:
         text = family[0]
         strays = set()
         for op_name in re.findall(r'op_name="([^"]*)"', text):
+            # a looped stack's heads, forward walk and backward rule alike
+            assert ("_sow_exit_objective" not in op_name
+                    or trace.scope_of(op_name)[0] == "head_loss")
             if trace.scope_of(op_name)[0] != trace.OTHER:
                 continue
             if not {trace.unwrapped(part)

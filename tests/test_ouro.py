@@ -227,39 +227,90 @@ def test_a_planted_fault_comes_out_over_the_limit(worked, fault):
     assert system < LIMIT < faulty, (fault, system, faulty)
 
 
-def test_the_head_by_blocks_of_rows_is_the_head_whole(monkeypatch):
-    """``token_losses`` walks blocks of rows where a sequence's float32
-    logits pass ``HEAD_BLOCK_BYTES`` (the cell: 4096 rows of 16,384): the
-    same losses and the same gradients as the rows at once."""
+#: how ``weighted_token_losses`` is asked: every row at once, four blocks of
+#: 8 rows an exit, and the blocks over a tied table ``[vocab, hidden]``
+HEAD_CASES = {"whole": (2 ** 30, False), "blocks": (None, False),
+              "tied_blocks": (None, True)}
+
+
+@pytest.fixture(scope="module")
+def heads():
+    """The weighted head and the plain ``jax.numpy`` formula, value and
+    gradients, on two exits of different streams under uneven weights that
+    hold zeros (a sequence's last position) and an upstream factor that is
+    not 1: the rule's scalar cotangent is what is tested."""
     from dlrover_tpu.models import llama
 
     cfg = _config()
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, cfg.hidden_size))
+    T, B, S = 2, 2, 32
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, B, S, cfg.hidden_size))
     kernel = jax.random.normal(
         jax.random.PRNGKey(1), (cfg.hidden_size, cfg.vocab_size)) * 0.1
-    targets = jnp.asarray(token_ids(2, 32, cfg.vocab_size, seed=5))
+    targets = jnp.asarray(token_ids(B, S, cfg.vocab_size, seed=5))
+    weights = jax.random.uniform(
+        jax.random.PRNGKey(2), (T, B, S)).at[..., -1].set(0.0)
 
-    def worked(limit):
-        monkeypatch.setattr(llama, "HEAD_BLOCK_BYTES", limit)
+    def plain(x, kernel, weights):
+        logits = jnp.einsum("tbse,ev->tbsv", x, kernel, precision="highest")
+        losses = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+            logits, jnp.broadcast_to(targets, (T, B, S))[..., None],
+            axis=-1)[..., 0]
+        return 3.0 * jnp.sum(weights * losses), losses
 
-        def total(x, kernel):
-            losses = llama.token_losses(cfg, x, kernel, targets)
-            return jnp.sum(losses * jnp.arange(32.0)), losses
+    def both(function, kernel):
+        (total, losses), grads = jitted(jax.value_and_grad(
+            function, argnums=(0, 1, 2), has_aux=True), x, kernel, weights)
+        return {"total": total, "losses": losses,
+                **dict(zip(("x", "kernel", "weights"), grads))}
 
-        (_, losses), grads = jitted(
-            jax.value_and_grad(total, argnums=(0, 1), has_aux=True), x, kernel)
-        return losses, grads
+    worked = {"plain": both(plain, kernel)}
+    with pytest.MonkeyPatch.context() as patch:
+        for case, (limit, tied) in HEAD_CASES.items():
+            patch.setattr(llama, "HEAD_BLOCK_BYTES",
+                          limit or 4 * B * 8 * cfg.vocab_size)
 
-    whole, blocks = worked(2 ** 30), worked(4 * 2 * 8 * cfg.vocab_size)
-    jaxpr = str(jax.make_jaxpr(
-        lambda x: llama.token_losses(cfg, x, kernel, targets))(x))
-    assert "while" in jaxpr or "scan" in jaxpr      # four blocks of 8 rows
-    for got, want in zip(jax.tree.leaves(blocks), jax.tree.leaves(whole)):
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-5)
-    want = -jnp.take_along_axis(jax.nn.log_softmax(
-        jnp.einsum("bse,ev->bsv", x, kernel, precision="highest"), axis=-1),
-        targets[..., None], axis=-1)[..., 0]
-    np.testing.assert_allclose(whole[0], want, rtol=0, atol=2e-5)
+            def system(x, kernel, weights, tied=tied):
+                total, losses = llama.weighted_token_losses(
+                    cfg, x, kernel, targets, weights, tied)
+                return 3.0 * total, losses
+
+            table = kernel.T if tied else kernel
+            worked[case] = both(system, table)
+            if tied:
+                worked[case]["kernel"] = worked[case]["kernel"].T
+            # not differentiated: the same walk, neither gradient product
+            worked[case]["read"] = jitted(system, x, table, weights)
+            worked[case]["read_jaxpr"] = str(jax.make_jaxpr(system)(
+                x, table, weights))
+            worked[case]["through_losses"] = jitted(jax.grad(
+                lambda x: jnp.sum(system(x, table, weights)[1])), x)
+    return worked
+
+
+@pytest.mark.parametrize("what", ["x", "kernel", "weights", "losses"])
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+def test_the_weighted_head_is_the_plain_formula(heads, case, what):
+    """``weighted_token_losses`` takes its gradient in its forward pass, a
+    block of rows at a time where a sequence's float32 logits pass
+    ``HEAD_BLOCK_BYTES`` (the cell: 4096 rows of 16,384): the same ``L``,
+    ``CE`` and gradients as ``jax.grad`` of ``sum(w * (logsumexp -
+    taken))`` under a cotangent of 3; ``CE`` itself carries none."""
+    got, want = heads[case], heads["plain"]
+    if what != "losses":
+        assert np.abs(want[what]).max() > 1e-3
+        np.testing.assert_allclose(
+            got[what], want[what], rtol=1e-5, atol=2e-5)
+        return
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=0,
+                               atol=2e-5)
+    np.testing.assert_allclose(got["total"], want["total"], rtol=1e-6)
+    for mine, differentiated in zip(got["read"], (got["total"],
+                                                   got["losses"])):
+        np.testing.assert_allclose(mine, differentiated, rtol=1e-6)
+    # one product a block where the differentiated walk makes three
+    assert got["read_jaxpr"].count("dot_general") == 1
+    assert ("while" in got["read_jaxpr"] or "scan" in got["read_jaxpr"])
+    assert not np.asarray(got["through_losses"]).any()
 
 
 class TestThePlainProgramIsTheParents:
