@@ -258,6 +258,14 @@ class LlamaConfig:
     mamba2_groups: int = 1
     mamba2_state: int = 128
     mamba2_chunk: int = 128
+    # a ``conv`` layer (LFM2's ``Lfm2ShortConv``): the mixer is a
+    # double-gated short convolution and nothing else: ``[B | C | u] = h
+    # W_in`` (three runs of ``hidden_size`` columns, in that order); ``v = B
+    # * u``; ``c`` the causal depthwise convolution of ``v`` over
+    # ``conv_taps`` taps a channel (``conv_L_cache``), zeros before the
+    # start, no bias and NO activation; ``out = (C * c) W_out``.  No
+    # positions, no softmax, no state beyond ``conv_taps - 1`` positions
+    conv_taps: int = 3
     # layers that stand ONCE after the periods (entries as the pattern's),
     # counted in ``num_layers``; their parameters under ``suffix/<run>``
     layer_suffix: Tuple[str, ...] = ()
@@ -339,6 +347,7 @@ class LlamaConfig:
                 or ("mamba2" in kinds and (
                     self.mamba2_heads < 1
                     or self.mamba2_heads % self.mamba2_groups))
+                or ("conv" in kinds and self.conv_taps < 1)
                 or (self.layer_suffix and self.hybrid)
                 or (any(layer_kind(entry)[1] for entry in entries)
                     and not self.dense_intermediate_size)):
@@ -352,7 +361,8 @@ class LlamaConfig:
                 "where there is a kda layer, mla_kv_rank where there is an "
                 "mla layer, sliding_window where there is a swa layer, "
                 "mamba2_heads a multiple of mamba2_groups where there is a "
-                "mamba2 layer, no suffix on a hybrid stack and "
+                "mamba2 layer, conv_taps where there is a conv layer, no "
+                "suffix on a hybrid stack and "
                 "dense_intermediate_size where one is dense")
         if (self.mlp_matrices not in (2, 3)
                 or self.mlp_activation not in ACTIVATIONS):
@@ -531,7 +541,7 @@ class LlamaConfig:
 #: the kinds a ``layer_pattern`` may name (``gmu`` and ``xattn``: a
 #: ``cross_pattern`` alone; ``ffn``: a feed-forward with no mixer)
 LAYER_KINDS = ("gqa", "kda", "mla", "swa", "mamba", "gmu", "xattn", "mamba2",
-               "ffn")
+               "ffn", "conv")
 
 
 def hybrid_layout(num_layers: int, mb_per_layer: int = 2) -> dict:
@@ -1332,11 +1342,9 @@ def _tap_conv(t, weight, bias):
     """SiLU of the causal depthwise convolution of ``t`` ``[B, S,
     channels]`` in float32: ``weight`` ``[taps, channels]``, tap ``i``
     weighs position ``t - (taps - 1) + i``, zeros before the start."""
-    taps, S = weight.shape[0], t.shape[1]
-    lead = jnp.pad(t, ((0, 0), (taps - 1, 0), (0, 0)))
-    return nn.silu(bias + sum(
-        lead[:, i: i + S].astype(jnp.float32) * weight[i]
-        for i in range(taps)))
+    from dlrover_tpu.ops.short_conv import taps_sum
+
+    return nn.silu(bias + taps_sum(t, weight))
 
 
 class MambaMixer(nn.Module):
@@ -1519,6 +1527,58 @@ class Mamba2Mixer(nn.Module):
                 + inner * cfg.hidden_size)              # out
 
 
+class ShortConvMixer(nn.Module):
+    """A double-gated short convolution (LFM2's ``Lfm2ShortConv``; the
+    configuration's comment has the equations), in place of attention in a
+    ``conv`` layer.  The two projections run in the compute dtype and stand
+    under the layer's ``attn`` scope; the gates and the taps, float32
+    arithmetic on their operands (``ops/short_conv.py``, with the backward
+    pass's own rule), under ``attn.core`` / ``gconv``.  The taps are
+    ``_tap_init``'s and go through the scans' shifting routine, WITHOUT its
+    bias and SiLU."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask):
+        from dlrover_tpu.ops.short_conv import (
+            gated_short_conv, past_tap_share)
+
+        cfg = self.config
+        wide, taps = x.shape[-1], cfg.conv_taps
+        all_three = _projection(cfg, 3 * wide, "in_proj",
+                                ("embed", "mlp"))(x)
+        weight = self.param(
+            "conv_weight", nn.with_logical_partitioning(
+                _tap_init(taps), (None, "mlp")),
+            (taps, wide), cfg.param_dtype).astype(jnp.float32)
+        trace.note_trace_time(
+            "attention.path", impl="short_conv", seq=x.shape[1],
+            channels=wide, conv=taps, core="jnp")
+        with jax.named_scope("attn.core"), jax.named_scope("gconv"):
+            # an operation of its own between two barriers, as a ``gmu``
+            # layer's gate: left to the compiler the second gate may be
+            # fused into the output projection's operand and take its name,
+            # and ``gconv_ms_per_step`` reads part of the core
+            b, c, u = jax.lax.optimization_barrier((
+                all_three[..., :wide], all_three[..., wide: 2 * wide],
+                all_three[..., 2 * wide:]))
+            # whether a position hears the ones before it: the share of
+            # the convolution's result that the earlier taps make
+            self.sow("stats", "gconv_past_tap_share",
+                     past_tap_share(b, u, weight))
+            out = jax.lax.optimization_barrier(
+                gated_short_conv(b, c, u, weight))
+        out = nn.with_logical_constraint(out, ("batch", "seq", "mlp"))
+        return _projection(cfg, wide, "out_proj", ("mlp", "embed"))(out)
+
+    @staticmethod
+    def num_params(cfg) -> int:
+        return (cfg.hidden_size * 3 * cfg.hidden_size       # in
+                + cfg.conv_taps * cfg.hidden_size           # the taps
+                + cfg.hidden_size * cfg.hidden_size)        # out
+
+
 class GatedMemoryUnit(nn.Module):
     """A gated memory unit (SambaY, arXiv:2507.06607), in place of attention
     in a ``gmu`` layer: ``out = (Y * silu(h W_in)) W_out`` with ``Y`` the
@@ -1634,7 +1694,7 @@ ATTENTION_OF = {"gqa": Attention, "kda": DeltaAttention,
                 "mla": LatentAttention, "swa": partial(Attention, kind="swa"),
                 "mamba": MambaMixer, "gmu": GatedMemoryUnit,
                 "xattn": partial(Attention, kind="xattn"),
-                "mamba2": Mamba2Mixer}
+                "mamba2": Mamba2Mixer, "conv": ShortConvMixer}
 #: the kinds whose module takes ``memory``, ``depth`` and ``keep``
 HYBRID_KINDS = ("gqa", "swa", "mamba", "gmu", "xattn")
 
@@ -2286,7 +2346,8 @@ class LlamaForCausalLM(nn.Module):
                    "mla": lambda: LatentAttention.num_params(cfg),
                    "mamba": lambda: MambaMixer.num_params(cfg),
                    "gmu": lambda: GatedMemoryUnit.num_params(cfg),
-                   "mamba2": lambda: Mamba2Mixer.num_params(cfg)}
+                   "mamba2": lambda: Mamba2Mixer.num_params(cfg),
+                   "conv": lambda: ShortConvMixer.num_params(cfg)}
         # a norm's parameters: a scale, with ``norm`` "layer" a bias too
         norm = cfg.hidden_size * (2 if cfg.norm == "layer" else 1)
 

@@ -140,8 +140,11 @@ class MoELlamaConfig(LlamaConfig):
     top_k: int = 2
     load_balance_coef: float = 0.01
     router_z_coef: float = 0.001
-    # the kept router weights divided by their sum (``norm_topk_prob``)
+    # the kept router weights divided by their sum (``norm_topk_prob``),
+    # by their sum plus ``norm_topk_eps`` where a model's code adds one
+    # (LFM2's ``+ 1e-6``).  0: the sum alone, the program as it was
     norm_topk_prob: bool = False
+    norm_topk_eps: float = 0.0
     # one chip's share of an expert layer that further chips hold the rest
     # of: the experts ``[first_expert, first_expert + experts_held)`` of
     # ``num_experts`` are here, the router keeps its ``num_experts`` columns
@@ -692,7 +695,10 @@ class MoEMLP(nn.Module):
                     scores = probs = jax.nn.softmax(logits, axis=-1)
                 top_w, top_i = _choose(self, scores)
                 if cfg.norm_topk_prob:
-                    top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+                    total = top_w.sum(axis=-1, keepdims=True)
+                    if cfg.norm_topk_eps:
+                        total = total + cfg.norm_topk_eps
+                    top_w = top_w / total
                 if cfg.routed_scaling_factor != 1.0:
                     top_w = top_w * cfg.routed_scaling_factor
 

@@ -566,7 +566,13 @@ SUB_SCOPES: Dict[str, Tuple[str, ...]] = {
                   # its group norm): the chunked scan of ``ops/ssd.py``, the
                   # scores, the decay mask, the two products and the state's
                   # carry between chunks
-                  "ssd"),
+                  "ssd",
+                  # a double-gated short convolution (``models/llama.py::
+                  # ShortConvMixer``, ``ops/short_conv.py``): the two gates
+                  # and the taps between them, all passes.  NOT ``conv``,
+                  # which is the SiLU'd taps in front of a scan's or the
+                  # delta rule's core: a sub-scope says whose work it is
+                  "gconv"),
     # what latent attention adds around its core (``models/llama.py::
     # LatentAttention``): the latent's projections, its norm, the rotary
     # part.  The one name two kinds have: a kind each, and a path that

@@ -1055,6 +1055,79 @@ class TestTrainerStep:
         # (142.6 M + 13.7 M + 5.2 M + 134.2 M) x 8 bytes of state
         assert 2.36e9 < mem.argument_size_in_bytes < 2.37e9
 
+    def test_lfm2_widths_one_layer_of_each_kind(
+            self, topo, as_if_on_tpu, monkeypatch):
+        """The LFM2 cell's configuration file through its family at B1 S2048
+        and three of its nine layers: the dense ``conv`` layer, a routed
+        softmax layer and a routed ``conv`` layer (an eighth of the cell's
+        length, one layer of each kind, for the test's time; the cell's own
+        is ``benchmarks/tests/compile_described.py lfm2_24b_1of8``: 6.20 GiB
+        of arguments and 10.62 of temporaries, which alias the donated state,
+        at 16,384 x 9 layers, accepted).  The described chip's compiler
+        takes the gated convolution as ``jax.numpy`` wrote it (no kernel
+        asked for): every instruction of the core under ``attn.core`` /
+        ``gconv``, forward and in the backward rule's own pass; the softmax layer alone runs Pallas kernels, the FA2 split
+        triple at two heads of 64 a block; a ``conv`` layer keeps nothing
+        by name."""
+        from benchmarks.common import HERE, load_module, read_json
+        from dlrover_tpu.observability import trace
+
+        notes = []
+        monkeypatch.setattr(
+            trace, "note_trace_time",
+            lambda name, **attrs: notes.append((name, attrs)))
+        config = {**read_json(HERE, "configs", "lfm2_24b_1of8.json"),
+                  "num_hidden_layers": 3,
+                  "layer_types": ["conv", "conv", "full_attention", "conv"]}
+        family = load_module("families", "lfm2")
+        S = 2048
+
+        def cell():
+            return family.build(config, False, S), (1, S)
+
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+        compiled = _trainer_step_compiled(mesh, cell)
+        text = compiled.as_text()
+        found = trace.parse_device_scopes(text)
+        kernels = sorted(found.scopes["%" + name]
+                         for name in _kernel_names(text))
+        # (the grouped matmuls are custom calls too, under ``moe/gmm``)
+        assert {scope[:2] for scope in kernels} == {
+            ("attn.core", ""), ("moe", "gmm")}
+        core = [which for kind, sub, which in found.scopes.values()
+                if (kind, sub) == ("attn.core", "gconv")]
+        # (loops of one turn here: the compiler unrolls them and finds the
+        # rematerialised forward in the forward, as the softmax layer's;
+        # ``tests/test_device_scopes.py`` holds the pass ``remat`` where a
+        # run of three is a loop)
+        assert set(core) >= {"forward", "backward"}
+        kinds = {(kind, sub) for kind, sub, _ in found.scopes.values()}
+        assert {("moe", "route"), ("moe", "gmm"), ("optimizer", "bias"),
+                ("mlp", "")} <= kinds
+        assert not {("attn.core", "conv"), ("moe", "shared")} & kinds
+        paths = {attrs["impl"]: attrs for name, attrs in notes
+                 if name == "attention.path"}
+        assert paths["short_conv"] == {
+            "impl": "short_conv", "seq": S, "channels": 2048, "conv": 3,
+            "core": "jnp"}
+        assert (paths["flash"]["heads"], paths["flash"]["head_dim"],
+                paths["flash"]["heads_per_block"],
+                paths["flash"]["backward"]) == (32, 64, 2, "split")
+        (moe,) = {tuple(sorted(attrs.items())) for name, attrs in notes
+                  if name == "moe.path"}
+        moe = dict(moe)
+        assert (moe["experts"], moe["top_k"], moe["held"]) == (64, 4, 8)
+        assert moe["backward"] == 6 and moe["shared_width"] == 0
+        # what a rematerialised layer keeps by name: the routed block's
+        # products and route, the dense layer's gate and up products (PR
+        # 63's rule: one dense layer's fit); the mixers nothing (a split
+        # FA2 backward keeps no ``out``, a ``conv`` layer has nothing named)
+        assert {attrs["core"] for name, attrs in notes
+                if name == "remat.kept"} == {"moe", "mlp"}
+        mem = compiled.memory_analysis()
+        # (89.1 M + 86.1 M + 92.4 M + 16.8 M) x 8 bytes of state
+        assert 2.27e9 < mem.argument_size_in_bytes < 2.29e9
+
     def test_sdar_widths_one_layer(self, topo, as_if_on_tpu):
         """One layer of SDAR-30B-A3B's block-diffusion step at the cell's
         widths and share (32 query heads on 4 key heads of 128, 16 of 128

@@ -108,6 +108,17 @@ FAMILIES = {
         {"attn.core": {"conv", "decay", "ssd", "gate"},
          "optimizer": {"bias"},
          "moe": {"route", "sort", "gmm", "combine", "shared", "latent"}}),
+    "lfm2_conv": (
+        lambda: LlamaForCausalLM(MoELlamaConfig.tiny_moe(
+            num_layers=5, layer_prefix=("conv:dense",),
+            layer_pattern=("gqa", "conv", "conv", "conv"),
+            dense_intermediate_size=96, qk_norm="head", tie_embeddings=True,
+            num_experts=16, top_k=4, experts_held=4, norm_topk_prob=True,
+            norm_topk_eps=1e-6, router_scores="sigmoid",
+            selection_bias=True, max_seq_len=SEQ)),
+        COMMON | {"moe", "mlp"},
+        {"attn.core": {"gconv"}, "optimizer": {"bias"},
+         "moe": {"route", "sort", "gmm", "combine"}}),
 }
 
 #: a path may be ``other`` where it names nothing but the layer stack and
@@ -123,7 +134,10 @@ STACK = {"layers", "layer", "h", "block", "jit(wrapped)", "LlamaForCausalLM",
          # a looped stack's scan over loop steps (``ouro_loop``)
          "LlamaForCausalLM.loop_step", "LlamaForCausalLM._looped_stack",
          # layers of one branch and the suffix (``nemotron_one_branch``)
-         "suffix", "ffn_0", "mamba2_alone_1", "gqa_alone_0"}
+         "suffix", "ffn_0", "mamba2_alone_1", "gqa_alone_0",
+         # a dense ``conv`` layer, then (softmax, three ``conv`` layers)
+         # (``lfm2_conv``)
+         "conv_dense_0", "gqa_0", "conv_1"}
 
 def _step_text(model, steps=0):
     mesh = build_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
@@ -173,6 +187,12 @@ class TestKindsOfACompiledStep:
         found = trace.parse_device_scopes(text).scopes.values()
         for kind in ("attn.proj", "norm"):      # inside the remat layer
             assert {which for k, _, which in found if k == kind} == {
+                trace.FORWARD, trace.REMAT, trace.BACKWARD}
+        # a core with a backward rule of its own (``ops/short_conv.py``):
+        # the rule's passes carry the core's scope too
+        if "gconv" in family[2].get("attn.core", ()):
+            assert {which for k, sub, which in found
+                    if (k, sub) == ("attn.core", "gconv")} == {
                 trace.FORWARD, trace.REMAT, trace.BACKWARD}
         # the optimizer's pass is differentiated by nobody
         assert {which for k, _, which in found if k == "optimizer"} == {

@@ -396,6 +396,8 @@ def test_dataclass_fields_keep_their_defaults():
     assert defaults["mlp_activation"] == "silu"
     moe = {f.name: f.default for f in dataclasses.fields(MoELlamaConfig)}
     assert moe["moe_latent_size"] == 0
+    # and what PR 66 added: no epsilon under the renormalised weights
+    assert moe["norm_topk_eps"] == 0.0 and defaults["conv_taps"] == 3
 
 
 def _one_branch(**changes):
@@ -484,3 +486,62 @@ def test_what_the_config_refuses_of_one_branch_layers(changes, match):
     with pytest.raises(ValueError, match=match):
         _one_branch(**changes)
 
+
+
+def _conv(**changes):
+    """The tenth kind in small, as LFM2 lays it out: a dense ``conv`` layer
+    once, then two periods of a softmax layer and a run of THREE ``conv``
+    layers; per-head q/k norms and a tied head beside a pattern."""
+    fields = dict(
+        num_layers=9, layer_prefix=("conv:dense",),
+        layer_pattern=("gqa", "conv", "conv", "conv"),
+        dense_intermediate_size=96, qk_norm="head", tie_embeddings=True,
+        dtype=jnp.float32)
+    fields.update(changes)
+    return LlamaConfig.tiny(**fields)
+
+
+@pytest.mark.parametrize("changes, runs, tree", [
+    # the ``conv`` entry alone: one run, every layer of it
+    ({"num_layers": 3, "layer_prefix": (), "layer_pattern": ("conv",)},
+     [("conv_0", "conv", 1)], {"layers/conv_0": (3, 1)}),
+    # ``conv:dense`` standing once before periods of its own kind
+    ({"num_layers": 3, "layer_pattern": ("conv",)},
+     [("conv_0", "conv", 1)],
+     {"prefix/conv_dense_0": (1,), "layers/conv_0": (2, 1)}),
+    # inside a period beside ``gqa``: a run of one and a run of three
+    ({}, [("gqa_0", "gqa", 1), ("conv_1", "conv", 3)],
+     {"prefix/conv_dense_0": (1,), "layers/gqa_0": (2, 1),
+      "layers/conv_1": (2, 3)})],
+    ids=["alone", "dense_prefix", "beside_gqa"])
+def test_a_conv_entrys_runs_and_tree(changes, runs, tree):
+    cfg = _conv(**changes)
+    assert cfg.layer_runs() == runs
+    model = LlamaForCausalLM(cfg)
+    shapes = nn.meta.unbox(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), _ids())["params"])
+    assert "lm_head" not in shapes              # the head is the table
+    for path, lead in tree.items():
+        top, run = path.split("/")
+        layer = shapes[top][run]["layer"]
+        if "conv" in run:   # no bias, no norm, nothing else in the mixer
+            assert {name: leaf.shape for name, leaf in
+                    jax.tree_util.tree_leaves_with_path(layer["attn"])} == {
+                (jax.tree_util.DictKey("in_proj"),
+                 jax.tree_util.DictKey("kernel")): lead + (64, 192),
+                (jax.tree_util.DictKey("conv_weight"),): lead + (3, 64),
+                (jax.tree_util.DictKey("out_proj"),
+                 jax.tree_util.DictKey("kernel")): lead + (64, 64)}
+        else:
+            assert layer["attn"]["q_norm"]["scale"].shape == lead + (16,)
+        width = 96 if "dense" in run else 128
+        assert layer["mlp"]["gate_proj"]["kernel"].shape == lead + (64, width)
+    assert model.num_params() == sum(
+        int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(shapes))
+
+
+def test_what_the_config_refuses_of_conv_layers():
+    with pytest.raises(ValueError, match="conv_taps"):
+        _conv(conv_taps=0)
+    with pytest.raises(ValueError, match="dense_intermediate_size"):
+        _conv(dense_intermediate_size=0)
